@@ -4,7 +4,8 @@ ONE device-peaks table for the whole repo: ``_bench_impl.py``'s
 ``peak_flops()/peak_bw()`` MFU math, ``tools/perf_budget.py``'s
 compile-time roofline, and ds-perf's predicted-time gate all read
 :data:`DEVICE_PEAKS` — a perf number printed anywhere in this codebase
-traces back to exactly one set of constants.
+traces back to exactly one set of constants, and a device that is not
+in the table is an error (:func:`peaks_for` raises), not a default.
 
 The model is a lower bound, deliberately: for one dispatch of a program
 whose inventory reports ``flops``, ``bytes_accessed`` and per-kind
@@ -56,34 +57,32 @@ class DevicePeaks:
 
 # Substring-matched against ``jax.devices()[0].device_kind.lower()`` in
 # declaration order — "v5 lite" is what the runtime reports for v5e, so
-# both spellings ride the same row. The flops/hbm_bw columns are the
-# numbers _bench_impl.py's MFU math always used; ici_bw is the per-chip
-# one-direction ICI rate of the same generation.
+# both spellings ride the same row. flops/hbm_bw are the published
+# per-chip peaks (Google Cloud TPU documentation: v5e 197 TFLOP/s bf16,
+# 819 GB/s HBM); ici_bw is the per-chip one-direction ICI rate of the
+# same generation. There is deliberately no row for a host CPU and no
+# default: a rate priced on the wrong part is worse than no rate.
 DEVICE_PEAKS = (
     DevicePeaks("v5 lite", 197e12, 819e9, 200e9),
     DevicePeaks("v5e", 197e12, 819e9, 200e9),
     DevicePeaks("v5p", 459e12, 2765e9, 600e9),
     DevicePeaks("v4", 275e12, 1228e9, 300e9),
     DevicePeaks("v6e", 918e12, 1640e9, 448e9),
-    # nominal host rates so every tool still runs (and the bound stays a
-    # visible underestimate) off-TPU
-    DevicePeaks("cpu", 1e12, 100e9, 10e9),
 )
-
-# unknown device kinds predict at v5e rates — the fleet's default part,
-# and the historical behavior of _bench_impl.peak_flops()/peak_bw()
-DEFAULT_PEAKS = DEVICE_PEAKS[1]
 
 
 def peaks_for(device_kind: str) -> DevicePeaks:
     """The peaks row for a ``device_kind`` string (case-insensitive
-    substring match, e.g. 'TPU v5 lite' -> the v5e row); the v5e default
-    when nothing matches."""
+    substring match, e.g. 'TPU v5 lite' -> the v5e row). A kind the
+    table does not know raises ``ValueError`` — never a default row."""
     kind = (device_kind or "").lower()
     for row in DEVICE_PEAKS:
         if row.kind in kind:
             return row
-    return DEFAULT_PEAKS
+    raise ValueError(
+        f"no peaks row for device kind {device_kind!r} (known: "
+        f"{', '.join(r.kind for r in DEVICE_PEAKS)}); add the part to "
+        f"DEVICE_PEAKS with its source rather than pricing it as another")
 
 
 def roofline_ms(flops: float, bytes_accessed: float,
@@ -114,11 +113,13 @@ def overlap_readiness(collectives: dict):
     return round(ready / total, 4)
 
 
-def predict(inventory: dict, device_kind: str = "") -> dict:
+def predict(inventory: dict, device_kind: str) -> dict:
     """Roofline prediction block for one program inventory dict (see
-    :mod:`.inventory` for the shape): the per-resource bounds, the
-    binding resource, and overlap-readiness."""
-    peaks = peaks_for(device_kind or inventory.get("device_kind", ""))
+    :mod:`.inventory` for the shape) on the NAMED target ``device_kind``
+    — where the program happened to be lowered says nothing about where
+    it will run: the per-resource bounds, the binding resource, and
+    overlap-readiness."""
+    peaks = peaks_for(device_kind)
     coll = inventory.get("collectives") or {}
     coll_bytes = sum(int(c.get("bytes", 0)) for c in coll.values())
     bounds = roofline_ms(inventory.get("flops", 0.0),

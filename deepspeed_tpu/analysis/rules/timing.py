@@ -70,7 +70,8 @@ _HOST_FETCH_MODULES = {"np", "numpy", "onp"}
 def _is_sync_call(node):
     """Explicit syncs AND host fetches — `float(jnp.sum(out))`,
     `np.asarray(out)`, `.item()` — which force completion just as hard as
-    block_until_ready (and are this repo's relay-safe idiom, bench.py)."""
+    block_until_ready: the value cannot reach the host before the
+    program that makes it has run."""
     if not isinstance(node, ast.Call):
         return False
     func = node.func

@@ -312,8 +312,8 @@ def barrier(group: GroupLike = None):
     result to host: per-device program queues are FIFO, so completion implies
     every earlier program on those devices finished; in multi-controller mode
     all processes execute the same global program, which is the rendezvous.
-    The host fetch matters — on relayed backends block_until_ready can ack
-    before execution.
+    The host fetch is the wait: the scalar cannot arrive before the program
+    that makes it has run.
     """
     mesh = get_mesh()
     token = jax.jit(

@@ -39,7 +39,6 @@ def op_compatibility():
     on_tpu = platform == "tpu"
     rows.append(("flash_attention (pallas)", True, "TPU kernel; XLA fallback elsewhere"))
     rows.append(("block_sparse_attention (pallas)", True, "TPU kernel; XLA fallback elsewhere"))
-    rows.append(("fused_layernorm/rmsnorm (pallas)", True, "TPU kernel; XLA fallback elsewhere"))
     rows.append(("quantizer ops", True, "jnp everywhere"))
     rows.append(("fused_adam / fused_lamb", True, "whole-pytree jit"))
     rows.append(("1-bit optimizers", True, "int8 wire over shard_map"))

@@ -106,8 +106,8 @@ class InferenceConfig:
     min_out_tokens: int = 1
     # fuse the whole generation (prefill + lax.scan over decode steps) into
     # ONE compiled program: a single dispatch per generate() call instead of
-    # one per token — per-token host dispatch dominates decode latency on
-    # remote-dispatch links and costs ~100us/token even locally. Retraces per
+    # one per token — per-token host dispatch is pure overhead on a decode
+    # step that is a few ms of device work. Retraces per
     # distinct (batch, cache_len, max_new_tokens, sampling) combination;
     # disable for workloads that sweep many generation lengths.
     fused_generate: bool = True
@@ -121,7 +121,7 @@ class InferenceConfig:
     # chunked prefill: stream the prompt through a fixed (B, chunk) prefill
     # program instead of one program per prompt length. Serving workloads
     # with varied prompt lengths compile ONE prefill (each distinct length
-    # otherwise pays its own 20-40s remote compile) and prefill peak memory
+    # otherwise pays its own compile) and prefill peak memory
     # is bounded by the chunk. Trades the fused single-dispatch generate
     # for ceil(S/chunk) + per-token dispatches; token streams unchanged.
     prefill_chunk_size: Optional[int] = None

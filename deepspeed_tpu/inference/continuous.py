@@ -1593,8 +1593,8 @@ class ContinuousBatchingEngine:
     def precompile_tick_programs(self, progress: Optional[Callable] = None) -> int:
         """Compile (and block on) the FULL tick-program family — every
         (pool, read bucket, {plain/burst, fused chunk widths}) variant a
-        serve could dispatch — so first serve-time requests don't pay the
-        20-40 s remote compile per variant (dstpu_prewarm --continuous).
+        serve could dispatch — so first serve-time requests don't pay a
+        compile per variant (dstpu_prewarm --continuous).
         Runs each program once on throwaway state. Returns the count."""
         from deepspeed_tpu.models import transformer as tf
 

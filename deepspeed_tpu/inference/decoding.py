@@ -144,9 +144,8 @@ def compile_generate_fn(mesh, cfg, param_shardings, batch_size: int, cache_len: 
                         top_p: float, read_floor: Optional[int] = None):
     """Whole-generation jit: prefill + ``lax.scan`` over the decode steps in
     ONE compiled program — one dispatch per ``generate()`` call instead of
-    one per token (the per-token host round trip dominates decode wall time
-    on remote-dispatch links: r5 measured 22.3 ms/token at 350M against a
-    ~1 ms roofline). Token stream is bitwise-identical to ``decode_loop``:
+    one per token (a per-token host round trip is pure overhead on a decode
+    step that is a few ms of device work). Token stream is bitwise-identical to ``decode_loop``:
     same rng split order, same select_token calls.
 
     ``read_floor`` enables tight cache reads inside the fused program: the
@@ -294,8 +293,8 @@ def chunked_generate(ragged_prefill_fn, segment_fn, params, tokens, cache,
                      tight_read: bool = False) -> jnp.ndarray:
     """Generate with CHUNKED prefill: the prompt streams through a fixed
     (B, chunk) prefill program, so ONE compiled program serves every prompt
-    length (each distinct length otherwise compiles its own prefill — 20-40s
-    per variant through a remote-compile link) and prefill peak memory is
+    length (each distinct length otherwise compiles its own prefill, seconds
+    each) and prefill peak memory is
     bounded by the chunk, not the prompt. The final (padded) chunk drops its
     pad writes via out-of-range positions; decode then shares the ragged
     per-row segment tail. Token streams are identical to the unchunked path

@@ -1,14 +1,14 @@
 """``dstpu_prewarm`` — precompile a serving program set into the persistent
 XLA compile cache, so servers cold-start warm.
 
-On TPU every distinct compiled program costs tens of seconds (20-40 s each
-through a remote-compile link); a serving stack touches several per
-configuration: the fused generate (per prompt-length/new-tokens combo), or
-the chunked-prefill + per-token decode pair, plus the continuous engine's
-per-bucket prefill/insert and burst programs. Run this once per model
-configuration with ``JAX_COMPILATION_CACHE_DIR`` pointing at a shared
-directory (the engine honours ``jax_compilation_cache_dir`` config too) and
-every later process reuses the executables.
+On TPU every distinct compiled program costs seconds to tens of seconds,
+and a serving stack touches several per configuration: the fused generate
+(per prompt-length/new-tokens combo), or the chunked-prefill + per-token
+decode pair, plus the continuous engine's per-bucket prefill/insert and
+burst programs. Run this once per model configuration with
+``JAX_COMPILATION_CACHE_DIR`` (or ``--cache-dir``) pointing at the
+directory the server will use and every later process reuses the
+executables (placement rule: ``deepspeed_tpu/utils/compile_cache.py``).
 
 The reference has no analogue (CUDA kernels load from prebuilt .so); this
 is the XLA-world equivalent of shipping compiled kernels.
@@ -67,7 +67,7 @@ def main(argv=None):
                         "FULL tick family: every (read bucket x {plain/"
                         "burst, fused-prefill chunk width}) variant a serve "
                         "could dispatch, so serve-time requests never pay "
-                        "the 20-40s remote compile per variant")
+                        "a compile")
     p.add_argument("--slots", type=int, default=8)
     p.add_argument("--cache-len", type=int, default=512)
     p.add_argument("--burst", type=int, default=1)
@@ -96,8 +96,8 @@ def main(argv=None):
                         "executables), so warm every width the serve will "
                         "run or the first sharded request pays the compile")
     p.add_argument("--cache-dir", default=None,
-                   help="persistent XLA cache dir (defaults to jax config / "
-                        "JAX_COMPILATION_CACHE_DIR)")
+                   help="persistent XLA cache dir (default: "
+                        "JAX_COMPILATION_CACHE_DIR, else <repo>/.jax_cache)")
     p.add_argument("--audit", action="store_true",
                    help="run ds-audit over every program this warm "
                         "compiles (the REAL serving configuration, not "
@@ -112,24 +112,13 @@ def main(argv=None):
     args = p.parse_args(argv)
 
     import jax
-
-    if args.cache_dir:
-        jax.config.update("jax_compilation_cache_dir", args.cache_dir)
-        # persist EVERYTHING: skipping fast-compiling programs would defeat
-        # the tool (the server would still pay those compiles)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:  # an already-initialized cache instance ignores config updates
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            pass
-
     import numpy as np
 
     import deepspeed_tpu
     from deepspeed_tpu.models.transformer import TransformerModel
+    from deepspeed_tpu.utils.compile_cache import configure_compile_cache
 
+    cache_dir = configure_compile_cache(args.cache_dir)
     overrides = {}
     for item in args.override:
         key, sep, val = item.partition("=")
@@ -253,7 +242,7 @@ def main(argv=None):
                 # then the FULL tick-program family (bucket x read_len x {plain,
                 # burst, fused-prefill}) under THIS mesh: a live serve dispatches
                 # whichever variant its mix demands — every one missing
-                # cold-costs a remote compile
+                # costs a compile mid-serve
                 n_fns = serve.precompile_tick_programs(
                     progress=lambda msg: print(f"prewarm: {msg}", flush=True))
                 print(f"prewarm: tick-program family complete "
@@ -284,8 +273,7 @@ def main(argv=None):
         print_text(report)
         if result.findings:
             return 1
-    print("prewarm: done — executables persisted to the XLA compile cache",
-          flush=True)
+    print(f"prewarm: done — executables persisted to {cache_dir}", flush=True)
     return 0
 
 

@@ -17,7 +17,7 @@ import signal
 import subprocess
 import sys
 
-from deepspeed_tpu.launcher.runner import decode_world_info
+from deepspeed_tpu.launcher.runner import assert_no_backend_in_parent, decode_world_info
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -67,6 +67,7 @@ def main(argv=None):
     assert 0 <= args.node_rank < len(hosts), f"node_rank {args.node_rank} out of range"
     my_slots = world[hosts[args.node_rank]]
 
+    assert_no_backend_in_parent()
     procs = []
     for idx, slot in enumerate(my_slots):
         env = build_child_env(args, world, local_slot=slot, local_index=idx)

@@ -165,6 +165,21 @@ def decode_world_info(encoded: str) -> Dict[str, List[int]]:
 # command construction
 # ---------------------------------------------------------------------------
 
+def assert_no_backend_in_parent():
+    """A chip belongs to one process at a time: a launcher parent that
+    has initialised a JAX backend holds the chip, and the child it spawns
+    then fails or hangs at its first device contact. Importing the
+    package imports jax, which is harmless; *using* it here is not. Both
+    launchers call this right before they spawn."""
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            "the launcher process has initialised a JAX backend and would "
+            "hold the accelerator its children need; keep device queries "
+            "out of deepspeed_tpu.launcher")
+
+
 def _python_exec(args) -> List[str]:
     if args.no_python:
         return []
@@ -288,6 +303,7 @@ def main(argv=None):
         try:
             cmd = build_mpi_cmd(args, active, master_addr, tf.name)
             logger.info(f"dstpu {args.launcher} launch: {' '.join(cmd[:8])} ...")
+            assert_no_backend_in_parent()
             rc = subprocess.call(cmd)
         finally:
             try:
@@ -300,10 +316,12 @@ def main(argv=None):
     if not multi_node:
         cmd = build_launch_cmd(args, active, node_rank=0, master_addr="127.0.0.1")
         logger.info(f"dstpu single-node launch: {' '.join(cmd)}")
+        assert_no_backend_in_parent()
         result = subprocess.call(cmd)
         sys.exit(result)
 
     cmds = build_multinode_cmds(args, active, master_addr)
+    assert_no_backend_in_parent()
     procs = []
     for host, argv_ in cmds:
         logger.info(f"dstpu launching on {host}: {' '.join(argv_[:6])} ...")

@@ -543,14 +543,14 @@ def _attention(q, k, v, cfg: TransformerConfig, segment_positions, window=None):
             v = jnp.repeat(v, nh // nkv, axis=2)
         layout, block = _sparse_layout(cfg.sparse_attention or (("mode", "fixed"),), nh, S)
         # kernel convention matches the model: (B, S, H, hd)
-        info = _tp_head_shard(B, nh, nh)
+        info = _kernel_shard(B, nh, nh)
         if info is not None:
-            # same GSPMD-unpartitionable story as flash (_head_shard_map):
-            # heads and their layout rows shard over 'tensor'
+            # same GSPMD-unpartitionable story as flash (_kernel_shard):
+            # the per-head layout rows shard the way the heads do
             from jax.sharding import PartitionSpec
 
             mesh, spec = info
-            lspec = PartitionSpec("tensor", None, None)
+            lspec = PartitionSpec(spec[2], None, None)
             fn = _head_shard_map(
                 mesh,
                 lambda q_, k_, v_, l_: block_sparse_attention(
@@ -586,13 +586,21 @@ def _attention(q, k, v, cfg: TransformerConfig, segment_positions, window=None):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _tp_head_shard(B, nh, nkv):
-    """(mesh, qkv_spec) when a live mesh has tensor>1 and the head counts
-    divide it — the precondition for running a Pallas attention kernel
-    per-shard under shard_map; None otherwise. The spec shards (B, S, H,
-    hd): heads over 'tensor' (the qkv projections' output sharding, so the
-    common case reshards nothing), batch over its data-parallel axes when
-    it divides them."""
+def _kernel_shard(B, nh, nkv):
+    """(mesh, qkv_spec) for running a Pallas attention kernel per-shard
+    under shard_map, or None when no mesh of more than one device is live.
+
+    GSPMD cannot partition a Mosaic kernel: the TPU lowering refuses one
+    left inside a multi-device program outright ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map") —
+    whatever the mesh axes are, pure ZeRO/data-parallel meshes included —
+    and the interpreter, which GSPMD does partition, all-gathers the
+    operands and computes every head on every chip. So on ANY multi-device
+    mesh the kernel runs under shard_map. The spec shards (B, S, H, hd):
+    batch over its data-parallel axes when it divides them, heads over
+    'tensor' when the head counts divide it (the qkv projections' output
+    sharding, so the common case reshards nothing), replicated over
+    whatever is left."""
     from jax.sharding import PartitionSpec
 
     from deepspeed_tpu import comm
@@ -600,46 +608,34 @@ def _tp_head_shard(B, nh, nkv):
     if not comm.is_initialized():
         return None
     mesh = comm.get_mesh()
-    tp = mesh.shape.get("tensor", 1)
-    if tp <= 1 or nh % tp or nkv % tp:
+    if mesh.size == 1:
         return None
+    tp = mesh.shape.get("tensor", 1)
+    head_axis = "tensor" if tp > 1 and nh % tp == 0 and nkv % tp == 0 else None
     batch_axes = tuple(a for a in ("data", "fsdp") if mesh.shape.get(a, 1) > 1)
     if batch_axes and B % math.prod(mesh.shape[a] for a in batch_axes):
         batch_axes = ()
-    return mesh, PartitionSpec(batch_axes or None, None, "tensor", None)
+    return mesh, PartitionSpec(batch_axes or None, None, head_axis, None)
 
 
 def _head_shard_map(mesh, fn, in_specs, out_spec):
-    """shard_map wrapper for Pallas attention kernels (GSPMD cannot
-    partition a pallas_call custom call: left alone it ALL-GATHERS the
-    operands and computes every head replicated on every chip — measured
-    as 15 all-gathers and full-head operand shapes in a TP-2 step's HLO).
+    """shard_map wrapper for Pallas attention kernels (see _kernel_shard).
     Semantics are preserved for every caller — shard_map reshards inputs
     to the stated specs and back, so a mismatched sharding pays a
     reshard, never a wrong answer."""
-    import inspect
-
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
-    check_kw = ({"check_vma": False}
-                if "check_vma" in inspect.signature(shard_map).parameters
-                else {"check_rep": False})
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
-                     **check_kw)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
+                         check_vma=False)
 
 
 def _flash_sharded(q, k, v, cfg: TransformerConfig, causal: bool, window=None):
-    """Flash attention, partitioned under tensor parallelism when a mesh
-    is live (see _head_shard_map)."""
+    """Flash attention, run per-shard when a multi-device mesh is live
+    (see _kernel_shard)."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
     blk = {"block_q": cfg.flash_block, "block_k": cfg.flash_block} if cfg.flash_block else {}
     kwargs = dict(causal=causal, sm_scale=cfg.attn_scale, window=window, **blk)
 
-    info = _tp_head_shard(q.shape[0], q.shape[2], k.shape[2])
+    info = _kernel_shard(q.shape[0], q.shape[2], k.shape[2])
     if info is None:
         return flash_attention(q, k, v, **kwargs)
     mesh, spec = info
@@ -829,14 +825,15 @@ def _constrain_tp(p, logical_names):
     spec — its only plan for that is a replicate-then-repartition of the
     whole tensor ("[SPMD] Involuntary full rematerialization")."""
     from deepspeed_tpu import comm
-    from deepspeed_tpu.runtime.zero.sharding import logical_to_mesh_spec
+    from deepspeed_tpu.runtime.zero.sharding import drop_indivisible_axes, logical_to_mesh_spec
 
     # is_initialized guard: get_mesh() would auto-create a default all-data
     # mesh, silently initializing global comm state from a bare forward()
     if not comm.is_initialized():
         return p
     mesh = comm.get_mesh()
-    spec = logical_to_mesh_spec(logical_names)
+    # same degradation as the stored sharding (ShardingPolicy._tp_spec)
+    spec = drop_indivisible_axes(logical_to_mesh_spec(logical_names), p.shape, mesh)
     return jax.lax.with_sharding_constraint(p, jax.sharding.NamedSharding(mesh, spec))
 
 

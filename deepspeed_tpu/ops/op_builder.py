@@ -9,8 +9,7 @@ a python module of jitted callables instead of a compiled extension.
 
 import importlib
 
-import jax
-
+from deepspeed_tpu.ops.pallas.interpret import resolve_interpret
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -42,7 +41,7 @@ class PallasOpBuilder(OpBuilder):
         return True
 
     def interpret_mode(self) -> bool:
-        return jax.default_backend() == "cpu"
+        return resolve_interpret()
 
 
 class FusedAdamBuilder(OpBuilder):

@@ -19,13 +19,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.pallas.interpret import resolve_interpret
+
 NEG_INF = -1e30
-
-
-def _auto_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() == "cpu"
 
 
 def _sparse_fwd_kernel(layout_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, sm_scale, causal, bq, bk, nk):
@@ -246,7 +242,7 @@ def block_sparse_attention(
     int32 from a SparsityConfig. Differentiable."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    interpret = _auto_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     layout = jnp.asarray(layout, jnp.int32)
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))

@@ -26,17 +26,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):
-    # jax < 0.5 spells this TPUCompilerParams; same fields
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
+from deepspeed_tpu.ops.pallas.interpret import resolve_interpret
 
 NEG_INF = -1e30
-
-
-def _auto_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() == "cpu"
 
 
 def _blk(size: int, cap: int) -> int:
@@ -479,7 +471,7 @@ def flash_attention(
         # normalizes np.int64-style configs at trace time, no host sync
         window = int(window)  # ds-lint: disable=jit-boundary-sync
         assert window >= 1, f"window must be >= 1, got {window}"
-    interpret = _auto_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     vma = tuple(vma) if vma else None
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))

@@ -2,14 +2,12 @@
 
 Reference: the csrc/transformer CUDA inventory — softmax_kernels.cu,
 gelu_kernels.cu, normalize_kernels.cu, dropout_kernels.cu (SURVEY §2.4 #5).
-Each maps to a jnp expression XLA fuses into its consumers; the Pallas
-fused-norm kernels cover the cases worth hand-scheduling.
+Each maps to a jnp expression XLA fuses into its consumers.
 """
 
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.ops.pallas.fused_norm import fused_layernorm, fused_rmsnorm
 from deepspeed_tpu.ops.transformer.transformer import (
     DeepSpeedTransformerConfig,
     DeepSpeedTransformerLayer,
@@ -45,6 +43,4 @@ __all__ = [
     "fused_softmax",
     "fused_bias_gelu",
     "fused_bias_dropout_residual",
-    "fused_layernorm",
-    "fused_rmsnorm",
 ]

@@ -34,11 +34,9 @@ NEG_INF = -1e30
 
 
 def _pcast_varying(tree, axis_name):
-    """Mark arrays as device-varying over ``axis_name`` (JAX >= 0.9 VMA
-    typing for shard_map carries); no-op on older versions."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(tree, (axis_name,), to="varying")
-    return tree
+    """Mark arrays as device-varying over ``axis_name`` (VMA typing for
+    shard_map carries)."""
+    return jax.lax.pcast(tree, (axis_name,), to="varying")
 
 
 def ring_attention(q, k, v, causal: bool = True, axis_name: str = "sequence",
@@ -149,7 +147,7 @@ def sequence_parallel_attention(
     # combined sequence x tensor meshes: the ring and the xla Ulysses local
     # step are jnp einsums GSPMD partitions over 'tensor' on its own, but a
     # pallas_call is GSPMD-unpartitionable (it would all-gather and compute
-    # every head replicated — see models/transformer._head_shard_map). When
+    # every head replicated — see models/transformer._kernel_shard). When
     # Ulysses runs the flash kernel and a tensor axis is live, take that
     # axis manual too: heads shard over 'tensor' AND redistribute over
     # 'sequence' via the all-to-all, so each device runs H/(n*tp) heads.
@@ -180,18 +178,7 @@ def sequence_parallel_attention(
 
 
 def _partial_manual_shard_map(fn, mesh, manual_axes, in_specs, out_specs):
-    """shard_map manual over ``manual_axes`` only (other mesh axes stay
-    under GSPMD): jax >= 0.8 spells that ``axis_names=``. Older jax's
-    partial-auto support raises NotImplementedError on the collectives
-    inside, so the fallback goes full-manual over every mesh axis — the
-    specs only name seq/tensor axes, so inputs reshard (replicate) over
-    the rest; a perf cost on combined meshes, never a wrong answer."""
-    try:
-        return jax.shard_map(fn, mesh=mesh, axis_names=manual_axes,
-                             in_specs=in_specs, out_specs=out_specs)
-    except (AttributeError, TypeError):
-        # no jax.shard_map at all, or one without axis_names support
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
+    """shard_map manual over ``manual_axes`` only; the other mesh axes
+    stay under GSPMD."""
+    return jax.shard_map(fn, mesh=mesh, axis_names=manual_axes,
+                         in_specs=in_specs, out_specs=out_specs)

@@ -10,8 +10,8 @@ machinery collapses into a compile-and-ask. What survives:
   - per-step triggering from config (``flops_profiler.profile_step``,
     reference engine.py:1646-1664) — `FlopsProfiler` attached to the engine;
   - ``get_model_profile(model, args)`` standalone API (reference :1112);
-  - duration via timed execution (with a host-sync fetch — device timing on
-    relayed backends acks early otherwise);
+  - duration via timed execution, ended by a host fetch of the result
+    (dispatch is asynchronous: without a wait the span is the enqueue);
   - params from the pytree (no hooks needed).
 
 Per-module breakdown (the reference's depth-wise table) maps to per-jaxpr-
@@ -34,10 +34,7 @@ def _cost_analysis(fn: Callable, *args, **kwargs):
     so callers reuse the compilation instead of jitting twice."""
     lowered = jax.jit(fn).lower(*args, **kwargs)
     compiled = lowered.compile()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
-    return dict(cost or {}), compiled
+    return dict(compiled.cost_analysis() or {}), compiled
 
 
 def count_params(tree) -> int:
@@ -112,8 +109,8 @@ class FlopsProfiler:
         out = compiled(*args, **kwargs)  # warmup (dispatch path)
         t0 = time.time()
         out = compiled(*args, **kwargs)
-        # force a host transfer: block_until_ready can ack early on relayed
-        # backends (see bench.py)
+        # fetch one element to the host: dispatch is asynchronous, and the
+        # value cannot arrive before the program has run
         np.asarray(jax.tree.leaves(out)[0]).ravel()[:1]
         self.duration = time.time() - t0
         return out

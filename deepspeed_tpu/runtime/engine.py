@@ -1414,10 +1414,7 @@ class TpuEngine:
                 capture.notify_lowered("train_micro", "", lowered,
                                        meta=self._audit_meta,
                                        compiled=compiled)
-            cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):
-                cost = cost[0] if cost else {}
-            self._micro_cost_cache = (dict(cost or {}), compiled)
+            self._micro_cost_cache = (dict(compiled.cost_analysis() or {}), compiled)
         return self._micro_cost_cache
 
     def _profile_flops(self, batch, rng):

@@ -58,14 +58,8 @@ def _wire_axis() -> tuple:
 
 
 def _shard_map_no_repcheck(fn, mesh, in_specs, out_specs):
-    try:
-        sm = jax.shard_map  # jax >= 0.8
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    except TypeError:  # older shard_map API
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _compressed_sync_leaf(m, cs, mesh, axis, world):

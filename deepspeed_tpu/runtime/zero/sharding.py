@@ -69,6 +69,26 @@ def logical_to_mesh_spec(logical_names: Optional[Sequence[Optional[str]]], rules
     return PartitionSpec(*out)
 
 
+def drop_indivisible_axes(spec: PartitionSpec, shape, mesh: Mesh) -> PartitionSpec:
+    """Replicate a dim over the mesh axes its size does not divide.
+
+    The logical rules are written once per model family; a weight whose
+    dim an axis cannot split evenly (GPT-2's vocab of 50257 over any
+    ``tensor`` > 1) must degrade to replicated on that dim — jax refuses
+    an uneven ``NamedSharding`` at placement, and the whole engine with
+    it. Axes of size 1 are kept: they split nothing and cost nothing."""
+    out = []
+    for dim, entry in zip(shape, tuple(spec)):
+        axes = () if entry is None else (entry,) if isinstance(entry, str) else tuple(entry)
+        keep, factor = [], 1
+        for ax in axes:
+            if dim % (factor * mesh.shape[ax]) == 0:
+                keep.append(ax)
+                factor *= mesh.shape[ax]
+        out.append(None if not keep else keep[0] if len(keep) == 1 else tuple(keep))
+    return PartitionSpec(*out)
+
+
 def _spec_axes(spec: PartitionSpec):
     used = set()
     for entry in spec:
@@ -144,23 +164,24 @@ class ShardingPolicy:
         self.min_shard_elems = min_shard_elems
 
     # -- per-leaf spec resolution ---------------------------------------
-    def _tp_spec(self, leaf_logical) -> PartitionSpec:
-        return logical_to_mesh_spec(leaf_logical, self.rules)
+    def _tp_spec(self, shape, leaf_logical) -> PartitionSpec:
+        return drop_indivisible_axes(
+            logical_to_mesh_spec(leaf_logical, self.rules), shape, self.mesh)
 
     def param_spec(self, shape, leaf_logical=None) -> PartitionSpec:
-        spec = self._tp_spec(leaf_logical)
+        spec = self._tp_spec(shape, leaf_logical)
         if self.stage >= 3:
             spec = add_fsdp_axis(tuple(shape), spec, self.mesh, self.min_shard_elems)
         return spec
 
     def opt_spec(self, shape, leaf_logical=None) -> PartitionSpec:
-        spec = self._tp_spec(leaf_logical)
+        spec = self._tp_spec(shape, leaf_logical)
         if self.stage >= 1:
             spec = add_fsdp_axis(tuple(shape), spec, self.mesh, 0)
         return spec
 
     def grad_spec(self, shape, leaf_logical=None) -> PartitionSpec:
-        spec = self._tp_spec(leaf_logical)
+        spec = self._tp_spec(shape, leaf_logical)
         if self.stage >= 2:
             spec = add_fsdp_axis(tuple(shape), spec, self.mesh, 0)
         return spec

@@ -22,8 +22,7 @@ def _sync():
     """Block until previously dispatched device work completes (cuda-event
     analogue): execute a trivial program on the local devices — queued FIFO
     after outstanding work — and fetch the result to host. A bare
-    block_until_ready on a fresh transfer would not drain compute (and some
-    relayed backends ack it early)."""
+    block_until_ready on a fresh transfer would not drain compute."""
     import jax
     import jax.numpy as jnp
 

@@ -8,11 +8,13 @@ import numpy as np
 
 from deepspeed_tpu.inference import ContinuousBatchingEngine
 from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+from deepspeed_tpu.utils.compile_cache import configure_compile_cache
 
 SMOKE = os.environ.get("EXAMPLE_SMOKE") == "1"
 
 
 def main():
+    configure_compile_cache()  # JAX_COMPILATION_CACHE_DIR, else <repo>/.jax_cache
     if SMOKE:
         cfg = TransformerConfig(vocab_size=128, hidden_size=64, num_layers=2,
                                 num_heads=4, max_seq_len=64, dtype="float32")

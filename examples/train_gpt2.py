@@ -9,11 +9,13 @@ import numpy as np
 
 import deepspeed_tpu
 from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+from deepspeed_tpu.utils.compile_cache import configure_compile_cache
 
 SMOKE = os.environ.get("EXAMPLE_SMOKE") == "1"
 
 
 def main():
+    configure_compile_cache()  # JAX_COMPILATION_CACHE_DIR, else <repo>/.jax_cache
     if SMOKE:
         model = TransformerModel(TransformerConfig(
             vocab_size=256, hidden_size=64, num_layers=2, num_heads=4,

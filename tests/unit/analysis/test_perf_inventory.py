@@ -22,7 +22,6 @@ from deepspeed_tpu.analysis.program.artifact import (
     parse_collectives,
 )
 from deepspeed_tpu.analysis.program.costmodel import (
-    DEFAULT_PEAKS,
     overlap_readiness,
     peaks_for,
     predict,
@@ -182,12 +181,16 @@ class TestCostModel:
     def test_peaks_for_substring_match(self):
         assert peaks_for("TPU v5 lite").flops == 197e12
         assert peaks_for("TPU v5p").hbm_bw == 2765e9
-        assert peaks_for("cpu").flops == 1e12
+        assert peaks_for("TPU v5 lite").hbm_bw == peaks_for("v5e").hbm_bw
 
-    def test_unknown_kind_predicts_at_v5e(self):
-        assert peaks_for("warp9") is DEFAULT_PEAKS
-        assert peaks_for("") is DEFAULT_PEAKS
-        assert DEFAULT_PEAKS.kind == "v5e"
+    @pytest.mark.parametrize("kind", ["weird", "", "cpu"])
+    def test_unknown_kind_raises(self, kind):
+        # no default row and no host-CPU row: a device the table does
+        # not know is an error, never priced as a v5e
+        with pytest.raises(ValueError, match="no peaks row"):
+            peaks_for(kind)
+        with pytest.raises(ValueError, match="no peaks row"):
+            predict(_inv(), kind)
 
     def test_roofline_is_max_of_resource_bounds(self):
         peaks = peaks_for("v5e")
@@ -405,18 +408,31 @@ class TestCli:
         results = json.loads(proc.stdout)["runs"][0]["results"]
         assert [r["ruleId"] for r in results] == ["hot-dot-upcast"]
 
-    def test_diff_json_out_feeds_trace_report(self, tmp_path):
+    @pytest.mark.parametrize("device_args, kind", [
+        ((), "v5e"),  # the documents say "cpu" (where they were lowered);
+                      # predictions are for the named target, default v5e
+        (("--device", "v5p"), "v5p"),
+    ])
+    def test_diff_json_out_feeds_trace_report(self, tmp_path, device_args,
+                                              kind):
         cur = _write_doc(tmp_path / "cur.json", {KEY: _inv()})
         base = _write_doc(tmp_path / "base.json", {KEY: _inv()})
         out = tmp_path / "report.json"
         proc = run_cli("--diff", cur, "--baseline", base,
-                       "--json-out", str(out), "--device", "v5e")
+                       "--json-out", str(out), *device_args)
         assert proc.returncode == 0
         report = json.loads(out.read_text())
+        assert report["device_kind"] == kind
         pred = report["programs"][KEY]["predicted"]
-        assert pred["device_kind"] == "v5e"
+        assert pred["device_kind"] == kind
         assert pred["lb_ms"] >= 0
         assert pred["bound_by"] in ("mxu", "hbm", "ici")
+
+    def test_unknown_device_is_usage_error(self, tmp_path):
+        cur = _write_doc(tmp_path / "cur.json", {KEY: _inv()})
+        proc = run_cli("--diff", cur, "--device", "cpu")
+        assert proc.returncode == 2
+        assert "no peaks row" in proc.stderr
 
     def test_write_baseline_plus_diff_is_usage_error(self, tmp_path):
         cur = _write_doc(tmp_path / "cur.json", {KEY: _inv()})

@@ -109,23 +109,11 @@ def run(out_path: str, ckpt_dir: str):
 
 
 if __name__ == "__main__":
-    # 4 virtual CPU devices per process, BEFORE the backend initializes.
-    # jax < 0.4.38 has no jax_num_cpu_devices config option — there the
-    # device count is only reachable through the XLA flag, which must be
-    # in the environment before the first jax import touches the backend
-    # (same fallback as tests/conftest.py).
+    # 4 virtual CPU devices per process, BEFORE the backend initializes
     os.environ["JAX_PLATFORMS"] = "cpu"
-    if "--xla_force_host_platform_device_count" not in os.environ.get(
-            "XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=4")
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 4)
-    except AttributeError:
-        pass  # older jax: the XLA flag above already set the count
+    jax.config.update("jax_num_cpu_devices", 4)
     sys.path.insert(0, os.environ["DSTPU_REPO_ROOT"])
     run(sys.argv[1], sys.argv[2])

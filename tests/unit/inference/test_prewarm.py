@@ -1,6 +1,6 @@
 """dstpu_prewarm CLI: precompile the serving program set into the
-persistent XLA cache (cold-start cost on TPU is 20-40s per program through
-the remote compiler; the reference ships prebuilt CUDA .so instead)."""
+persistent XLA cache (cold-start cost on TPU is seconds to tens of seconds
+per program; the reference ships prebuilt CUDA .so instead)."""
 
 import os
 

@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention, mha_reference
-from deepspeed_tpu.ops.pallas.fused_norm import fused_layernorm, fused_rmsnorm
 from deepspeed_tpu.ops.quantizer import (
     dequantize,
     fake_quantize,
@@ -228,58 +227,6 @@ class TestSlidingWindowFlash:
         batch = {"input_ids": tokens}
         np.testing.assert_allclose(float(m_scan.loss(params, batch)),
                                    float(m_unroll.loss(params, batch)), rtol=1e-4)
-
-
-class TestFusedNorm:
-    def test_layernorm_parity(self):
-        rs = np.random.RandomState(0)
-        x = jnp.asarray(rs.randn(4, 16, 128).astype(np.float32))
-        scale = jnp.asarray(rs.randn(128).astype(np.float32))
-        bias = jnp.asarray(rs.randn(128).astype(np.float32))
-        out = fused_layernorm(x, scale, bias)
-        mu = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.var(x, axis=-1, keepdims=True)
-        ref = (x - mu) * jax.lax.rsqrt(var + 1e-5) * scale + bias
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-5)
-
-    def test_rmsnorm_parity(self):
-        rs = np.random.RandomState(1)
-        x = jnp.asarray(rs.randn(64, 256).astype(np.float32))
-        scale = jnp.asarray(rs.randn(256).astype(np.float32))
-        out = fused_rmsnorm(x, scale)
-        ref = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-5) * scale
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-5)
-
-    def test_layernorm_gradients(self):
-        rs = np.random.RandomState(2)
-        x = jnp.asarray(rs.randn(32, 128).astype(np.float32))
-        scale = jnp.asarray(1.0 + 0.1 * rs.randn(128).astype(np.float32))
-        bias = jnp.asarray(0.1 * rs.randn(128).astype(np.float32))
-
-        def f_fused(x, s, b):
-            return jnp.sum(fused_layernorm(x, s, b) ** 2)
-
-        def f_ref(x, s, b):
-            mu = jnp.mean(x, axis=-1, keepdims=True)
-            var = jnp.var(x, axis=-1, keepdims=True)
-            return jnp.sum(((x - mu) * jax.lax.rsqrt(var + 1e-5) * s + b) ** 2)
-
-        gf = jax.grad(f_fused, argnums=(0, 1, 2))(x, scale, bias)
-        gr = jax.grad(f_ref, argnums=(0, 1, 2))(x, scale, bias)
-        for a, b in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-4)
-
-    def test_rmsnorm_gradients(self):
-        rs = np.random.RandomState(3)
-        x = jnp.asarray(rs.randn(16, 128).astype(np.float32))
-        scale = jnp.asarray(1.0 + 0.1 * rs.randn(128).astype(np.float32))
-        gf = jax.grad(lambda x, s: jnp.sum(fused_rmsnorm(x, s) ** 2), argnums=(0, 1))(x, scale)
-        gr = jax.grad(
-            lambda x, s: jnp.sum((x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5) * s) ** 2),
-            argnums=(0, 1),
-        )(x, scale)
-        for a, b in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-4)
 
 
 class TestQuantizer:

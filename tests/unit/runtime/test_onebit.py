@@ -6,10 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec
 
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
 from deepspeed_tpu.runtime.comm.compressed import (

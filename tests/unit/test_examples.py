@@ -30,6 +30,13 @@ def test_example_runs(script, tmp_path, monkeypatch):
     from deepspeed_tpu import comm
 
     comm.destroy()
+    # the examples place the persistent compile cache themselves
+    # (utils/compile_cache.py); in-process they must keep THIS session's
+    # own directory and threshold (conftest.py), not open <repo>/.jax_cache
+    import jax
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", jax.config.jax_compilation_cache_dir)
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
     monkeypatch.setenv("EXAMPLE_SMOKE", "1")
     monkeypatch.setenv("EXAMPLE_CKPT", str(tmp_path / "ck"))
     path = os.path.join(EXAMPLES, script)
@@ -39,3 +46,4 @@ def test_example_runs(script, tmp_path, monkeypatch):
         runpy.run_path(path, run_name="__main__")
     finally:
         sys.argv = argv
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)

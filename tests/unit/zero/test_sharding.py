@@ -79,3 +79,21 @@ def test_tp_plus_fsdp_composition():
     logical = {"wi": ("embed", "mlp")}
     s3 = ShardingPolicy(mesh, stage=3, logical_specs=logical)
     assert s3.param_pspecs(params)["wi"] == PartitionSpec("fsdp", "tensor")
+
+
+def test_indivisible_dim_degrades_to_replicated():
+    """GPT-2's vocab (50257, odd) cannot split over any tensor width: the
+    vocab dim replicates instead of jax refusing the placement — and the
+    engine with it — while a dim that divides keeps its axis."""
+    comm.destroy()
+    mesh = comm.init_distributed(mesh_shape={"fsdp": 2, "tensor": 4}, verbose=False)
+    params = {"tok": _abstract((50257, 64)), "even": _abstract((50264, 64))}
+    logical = {"tok": ("vocab", "embed"), "even": ("vocab", "embed")}
+    s0 = ShardingPolicy(mesh, stage=0, logical_specs=logical)
+    assert s0.param_pspecs(params)["tok"] == PartitionSpec(None, None)
+    assert s0.param_pspecs(params)["even"] == PartitionSpec("tensor", None)
+    # ZeRO still finds the free dim it can shard
+    s3 = ShardingPolicy(mesh, stage=3, logical_specs=logical)
+    assert s3.param_pspecs(params)["tok"] == PartitionSpec(None, "fsdp")
+    placed = jax.device_put(jnp.zeros((50257, 64)), s3.param_shardings(params)["tok"])
+    assert placed.addressable_shards[0].data.shape == (50257, 32)

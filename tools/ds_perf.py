@@ -29,7 +29,7 @@ Usage:
                                                    #   ds_trace_report --perf
     python tools/ds_perf.py --diff perf.json       # jax-free re-diff
     python tools/ds_perf.py --write-baseline       # accept current programs
-    python tools/ds_perf.py --device v5e           # predict at v5e peaks
+    python tools/ds_perf.py --device v5p           # predict at v5p peaks
 
 Exit codes match ds-lint: 0 clean, 1 findings, 2 usage error.
 """
@@ -127,9 +127,11 @@ def build_parser():
              "baseline file) against the baseline WITHOUT lowering "
              "anything — runs jax-free")
     parser.add_argument(
-        "--device", default=None, metavar="KIND",
-        help="device kind for the roofline predictions (e.g. 'v5e', "
-             "'v5p'; default: the kind the programs compiled on)")
+        "--device", default="v5e", metavar="KIND",
+        help="the TARGET part the roofline predictions are priced for, a "
+             "row of costmodel.DEVICE_PEAKS (default: v5e). The programs "
+             "lower on the virtual-CPU mesh; where they lowered is not "
+             "where they will run")
     parser.add_argument(
         "--layers", type=int, default=1,
         help="tiny-model depth (the layer scan keeps the inventory "
@@ -201,7 +203,7 @@ def _print_text(report):
     s = report["summary"]
     verdict = "clean" if not report["findings"] else "FAIL"
     print(f"ds-perf: {s['programs']} program(s) at "
-          f"{report['device_kind'] or 'unknown'} peaks, {s['new']} "
+          f"{report['device_kind']} peaks, {s['new']} "
           f"finding(s) — {verdict}")
 
 
@@ -229,10 +231,21 @@ def _load_programs(path):
     with open(path, "r", encoding="utf-8") as fh:
         data = json.load(fh)
     if isinstance(data, dict) and "programs" in data:
-        return dict(data["programs"] or {}), data.get("device_kind", "")
+        return dict(data["programs"] or {})
     if isinstance(data, dict):
-        return dict(data), ""
+        return dict(data)
     raise ValueError(f"{path}: not an inventory document")
+
+
+def _check_device(device_kind, prog_pkg) -> bool:
+    """False (after a usage message) when ``--device`` names a part the
+    peaks table does not hold."""
+    try:
+        prog_pkg.peaks_for(device_kind)
+    except ValueError as exc:
+        print(f"ds-perf: --device: {exc}", file=sys.stderr)
+        return False
+    return True
 
 
 def _attach_predictions(programs, device_kind, prog_pkg):
@@ -271,9 +284,11 @@ def main(argv=None) -> int:
     if args.diff:
         # jax-free read side: both documents are pure data
         prog_pkg = _program_pkg()
+        if not _check_device(args.device, prog_pkg):
+            return 2
         inventory = importlib.import_module(_ALIAS + ".program.inventory")
         try:
-            current, cur_kind = _load_programs(args.diff)
+            current = _load_programs(args.diff)
         except (OSError, ValueError, json.JSONDecodeError) as exc:
             print(f"ds-perf: cannot read {args.diff}: {exc}",
                   file=sys.stderr)
@@ -288,9 +303,8 @@ def main(argv=None) -> int:
                       f"{exc}", file=sys.stderr)
                 return 2
         findings = inventory.diff_inventories(current, baseline)
-        device_kind = args.device or cur_kind
-        programs = _attach_predictions(current, device_kind, prog_pkg)
-        report = _build_report(findings, programs, device_kind,
+        programs = _attach_predictions(current, args.device, prog_pkg)
+        report = _build_report(findings, programs, args.device,
                                len(set(current) & set(baseline)))
         if args.json_out:
             with open(args.json_out, "w", encoding="utf-8") as fh:
@@ -315,6 +329,9 @@ def main(argv=None) -> int:
     from deepspeed_tpu.analysis.program.families import (
         build_family_artifacts,
     )
+
+    if not _check_device(args.device, prog_pkg):
+        return 2
 
     # quiet the stack's stdout INFO logger for machine formats (see
     # ds_audit.py — must run AFTER the package import set the level)
@@ -350,9 +367,8 @@ def main(argv=None) -> int:
     findings = sorted(
         live + inventory_mod.diff_inventories(inventories, baseline),
         key=lambda f: (f.path, f.rule_id, f.code))
-    pred_kind = args.device or device_kind
-    programs = _attach_predictions(inventories, pred_kind, prog_pkg)
-    report = _build_report(findings, programs, pred_kind,
+    programs = _attach_predictions(inventories, args.device, prog_pkg)
+    report = _build_report(findings, programs, args.device,
                            len(set(inventories) & set(baseline)))
     if args.json_out:
         with open(args.json_out, "w", encoding="utf-8") as fh:
