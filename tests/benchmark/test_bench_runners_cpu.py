@@ -1,0 +1,239 @@
+"""Each runner end to end on the CPU through the harness's Python entry
+point, on the toy cell files kept beside this file: traced and untraced, the
+ZeRO-3 cell on four virtual devices. Control flow and correctness only: a CPU
+run gives no device number, and its result never says ``tpu``.
+
+Also the comparisons' power: a wrong trainer and wrong token streams FAIL."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import compare, harness
+from benchmark.reference import gpt2
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+TOY = os.path.join(ROOT, "tests", "benchmark", "toy", "MANIFEST.json")
+RUNS = [("toy-train", 0), ("toy-train", 1), ("toy-train-zero3", 0), ("toy-train-zero3", 1),
+        ("toy-chat", 0), ("toy-chat", 1), ("toy-batch", 0), ("toy-batch", 1)]
+
+
+@pytest.fixture(scope="module")
+def results(tmp_path_factory):
+    # the harness places the compile cache unless JAX_COMPILATION_CACHE_DIR is set: keep the
+    # session's own directory (conftest.py), never the checkout's .jax_cache
+    saved = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = jax.config.jax_compilation_cache_dir
+    os.environ["TMPDIR"] = str(tmp_path_factory.mktemp("traces"))
+    # launcher tests that ran earlier on this xdist worker can leave a rendezvous in the
+    # environment (PERF.md section 7), and `initialize` would then try to join eight processes
+    leaked = {k: os.environ.pop(k) for k in ("DSTPU_COORDINATOR", "DSTPU_NUM_PROCESSES",
+                                             "DSTPU_PROCESS_ID") if k in os.environ}
+    cache = {}
+
+    def get(workload, trace, overrides=()):
+        key = (workload, trace, tuple(overrides))
+        if key not in cache:
+            cache[key] = harness.run_cell(TOY, workload, 2 ** 31 + 7, 1.5, bool(trace),
+                                          require_tpu=False, overrides=list(overrides))
+        return cache[key]
+
+    yield get
+    os.environ.update(leaked)
+    os.environ.pop("TMPDIR", None)
+    if saved is None:
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
+    else:
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = saved
+
+
+@pytest.mark.parametrize("workload,trace", RUNS)
+def test_runner_gives_the_contracts_object(results, workload, trace):
+    line = results(workload, trace)
+    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"} | (
+        {"breakdown"} if trace else set())
+    assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 1
+    assert "tpu" not in json.dumps(line).lower()
+    device = line["device"]
+    assert device["platform"] == "cpu" and device["count"] == (4 if "zero3" in workload else 1)
+    manifest = harness.load_json(TOY)
+    group = manifest["per_layer"] if trace else manifest["end_to_end"]
+    mine = {m["name"]: m for m in group if workload in m.get("workloads", [workload])}
+    assert set(line["metrics"]) <= set(mine)
+    for name, metric in line["metrics"].items():
+        assert metric["unit"] == mine[name]["unit"] and np.isfinite(metric["value"])
+    if trace:
+        assert device["busy_s"] > 0 and device["window_s"] > 0
+        assert len(line["breakdown"]["device_ops"]) <= 10
+        assert len(line["breakdown"]["idle_gaps"]) <= 10
+        assert "compile_s" in line["metrics"]
+        # no peak is known for a CPU: a roofline or an MFU is left out, never made up
+        assert not any("roofline" in n or "mfu" in n for n in line["metrics"])
+    else:
+        assert set(line["metrics"]) == set(mine) and line["metrics"]["setup_s"]["value"] > 0
+
+
+def test_zero3_cell_reports_its_collectives(results):
+    metrics = results("toy-train-zero3", 1)["metrics"]
+    assert metrics["collective_ms.train"]["value"] > 0
+    assert 0 <= metrics["collective_exposed.train"]["value"] <= 100
+
+
+def test_a_wrong_trainer_fails_the_training_comparison(results, capsys):
+    line = results("toy-train", 0, ["cell.train.controls=true"])
+    assert line["correct"] is True  # the engine passes AND every control failed
+    compare_line = next(json.loads(l) for l in capsys.readouterr().out.splitlines()
+                        if l.startswith('{"phase": "compare"'))
+    assert compare_line["controls_passed_the_check"] == {
+        "grads_scaled": False, "shard_left_out": False, "double_update": False}
+
+
+def test_the_reference_trains_once_the_engine_is_gone(results, capsys):
+    results("toy-train", 0, ["cell.note=a run of its own, so that its lines are printed here"])
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines() if l.startswith('{"phase"')]
+    compare_line = next(l for l in lines if l["phase"] == "compare")
+    # state, moments and accumulators are freed before the float32 reference takes the chip: the
+    # peak the harness read after the window is the engine's, and setup_s holds no reference
+    assert compare_line["live_array_bytes_after_engine_release"] < 1024
+    assert compare_line["reference_s"] > 0
+
+
+def test_the_command_line_has_no_way_to_run_another_cell_under_a_cells_name(capsys):
+    with pytest.raises(SystemExit) as e:
+        harness.main(["--workload", "toy-train", "--seed", "1", "--seconds", "1",
+                      "--set", "cell.train.controls=true"], 0.0)
+    assert e.value.code == 2 and "unrecognized arguments" in capsys.readouterr().err
+
+
+def test_correct_does_not_read_trace_or_seconds():
+    import inspect
+
+    from benchmark.runners import serve, train
+
+    for fn in (compare.train_verdict, compare.serve_verdict, compare.train_reference):
+        assert not {"trace", "seconds"} & set(inspect.signature(fn).parameters)
+    for module in (train, serve):
+        assert "trace" not in inspect.signature(module.Runner.finish).parameters
+        assert "trace" not in inspect.signature(module.Runner.setup).parameters
+
+
+def test_refuses_the_cpu_and_too_few_chips_and_unknown_cells(results):
+    with pytest.raises(harness.BenchmarkError, match="not a TPU"):
+        harness.run_cell(TOY, "toy-train", 1, 1.0, False)
+    with pytest.raises(harness.BenchmarkError, match="no workload"):
+        harness.run_cell(TOY, "no-such-cell", 1, 1.0, False, require_tpu=False)
+
+
+def test_overrides_reach_the_files():
+    target = {"traffic": {"arrivals": {"rate_per_s": 1.0}}, "cell": {}}
+    harness.apply_overrides(target, ["traffic.arrivals.rate_per_s=2.5", "cell.train.controls=true",
+                                     "cell.note=plain"])
+    assert target == {"traffic": {"arrivals": {"rate_per_s": 2.5}},
+                      "cell": {"train": {"controls": True}, "note": "plain"}}
+
+
+# -- the reference against itself and against wrong outputs -------------------
+
+@pytest.fixture(scope="module")
+def toy_model():
+    from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+
+    return TransformerModel(TransformerConfig(vocab_size=211, hidden_size=64, num_layers=2,
+                                              num_heads=4, max_seq_len=64))
+
+
+def test_reference_forward_matches_the_model_in_float32(toy_model):
+    params = toy_model.init(jax.random.PRNGKey(0))
+    tokens = np.random.RandomState(0).randint(0, 211, (2, 32)).astype(np.int32)
+    at = np.tile(np.arange(32, dtype=np.int32), (2, 1))
+    ours = gpt2.logits_at(params, tokens, at, 4)
+    theirs = toy_model.apply(params, tokens)
+    assert np.allclose(np.asarray(ours), np.asarray(theirs, np.float32), atol=2e-4)
+
+
+def test_reference_loss_and_grads_match_the_models(toy_model):
+    params = toy_model.init(jax.random.PRNGKey(1))
+    tokens = jnp.asarray(np.random.RandomState(1).randint(0, 211, (4, 32)), jnp.int32)
+    loss, grads = gpt2.loss_and_grads(params, tokens, 4, rows_per_pass=2)
+    want, want_g = jax.value_and_grad(lambda p: toy_model.loss(p, {"input_ids": tokens}))(params)
+    assert float(loss) == pytest.approx(float(want), abs=1e-5)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_g)):
+        assert np.allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+@pytest.mark.parametrize("fault", gpt2.FAULTS)
+def test_each_training_fault_leaves_the_tolerances(toy_model, fault):
+    tokens = np.random.RandomState(2).randint(0, 211, (4, 32)).astype(np.int32)
+    opt = dict(lr=1e-2, betas=(0.9, 0.999), eps=1e-8, weight_decay=0.01)
+    key = jax.random.PRNGKey(2)
+    run = lambda f: compare.train_reference(toy_model.init, key, tokens, 4, 3, opt,
+                                            jax.devices()[:1], rows_per_pass=2, fault=f)
+    good, bad = run(None), run(fault)
+    tol = dict(loss_abs=0.005, grad_norm_rel=0.01, min_fall=0.01)
+    assert compare.train_verdict(good["losses"], good["grad_norms"][0], good, tol)[0]
+    ok, fields = compare.train_verdict(good["losses"], good["grad_norms"][0], bad, tol)
+    assert not ok, fields
+    assert good["checksum"] == bad["checksum"]  # same start: only the trainer differs
+
+
+def test_adamw_matches_the_programs_optimizer():
+    from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
+
+    rs = np.random.RandomState(3)
+    p = {"a": jnp.asarray(rs.randn(5, 3), jnp.float32), "b": jnp.asarray(rs.randn(7), jnp.float32)}
+    g = jax.tree.map(lambda x: 0.1 * x + 0.01, p)
+    opt = FusedAdam(lr=1e-3, weight_decay=0.01, adam_w_mode=True)
+    state = opt.init(p)
+    ours_p, m, v = p, state.exp_avg, state.exp_avg_sq
+    for step in (1, 2):
+        updates, state = opt.update(g, state, p)
+        p = jax.tree.map(jnp.add, p, updates)
+        ours_p, m, v = gpt2.adamw(ours_p, g, m, v, float(step), 1e-3, 0.9, 0.999, 1e-8, 0.01)
+    for a, b in zip(jax.tree.leaves(ours_p), jax.tree.leaves(p)):
+        assert np.allclose(np.asarray(a), np.asarray(b), atol=1e-7)
+
+
+def test_serving_comparison_passes_greedy_streams_and_fails_wrong_ones(toy_model):
+    params = compare.seed_params(toy_model, 5, 3.0)
+    rs = np.random.RandomState(5)
+    prompts = [rs.randint(0, 211, n).astype(np.int32) for n in (9, 14, 20, 27)]
+    streams = []
+    for p in prompts:  # greedy decoding by the reference itself: the right answer
+        seq = list(p)
+        for _ in range(6):
+            toks = np.zeros((1, 64), np.int32)
+            toks[0, :len(seq)] = seq
+            logits = gpt2.logits_at(params, toks, np.array([[len(seq) - 1]], np.int32), 4)
+            seq.append(int(np.argmax(np.asarray(logits)[0, 0])))
+        streams.append(np.array(seq[len(p):], np.int32))
+    tol = dict(margin=0.25, share_within=0.99, control_share=0.2, distinct_per_request=1)  # 24 toy tokens
+    ok, fields = compare.serve_verdict(params, prompts, streams, 4, 5, tol, width=64, new_max=6)
+    assert ok and fields["share_within_margin"] == 1.0 and fields["worst_gap"] == 0.0, fields
+    wrong = [rs.randint(0, 211, 6).astype(np.int32) for _ in prompts]
+    ok, fields = compare.serve_verdict(params, prompts, wrong, 4, 5, tol, width=64, new_max=6)
+    assert not ok and fields["share_within_margin"] < 0.5
+    shifted = [np.roll(s, 1) for s in streams]  # the right tokens, one position off
+    assert not compare.serve_verdict(params, prompts, shifted, 4, 5, tol, width=64, new_max=6)[0]
+
+
+def test_warm_plan_reaches_every_tick_program_the_lengths_can():
+    from deepspeed_tpu.inference.decoding import read_bucket
+
+    from benchmark.runners.serve import warm_plan
+
+    for prompt_range, new_max in (((16, 512), 192), ((128, 768), 256)):
+        plan = list(warm_plan(prompt_range, 1024, 128))
+        reached = set()
+        for anchor, new, shorts in plan:
+            assert anchor + new <= 1024 and new >= 1
+            for extent in range(anchor + 1, anchor + new + 1):
+                reached.add(read_bucket(extent, 1024, 128))
+        wanted = {read_bucket(e, 1024, 128)
+                  for e in range(prompt_range[0] + 1, min(1024, prompt_range[1] + new_max) + 1)}
+        assert wanted <= reached
+        widths = {read_bucket(n, 128, 16) for _, _, shorts in plan for n in shorts}
+        assert widths == {16, 32, 64, 128}
