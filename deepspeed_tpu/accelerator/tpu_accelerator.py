@@ -5,7 +5,6 @@ by ``jax.devices()`` / XLA memory stats / ``jax.profiler`` ranges instead of
 torch.cuda streams and events.
 """
 
-import contextlib
 
 import jax
 
@@ -93,10 +92,15 @@ class TpuAccelerator(Accelerator):
         return jax.random.PRNGKey(seed)
 
     # --- profiler ranges (nvtx push/pop semantics: LIFO stack) ----------
+    # one annotation helper for the tree: telemetry.spans.host_span, so a
+    # user's ranges land in the xplane beside the program's own as
+    # ``dstpu:<msg>`` and cost a flag test when no capture is on
     def range_push(self, msg: str):
+        from deepspeed_tpu.telemetry.spans import host_span
+
         if not hasattr(self, "_range_stack"):
             self._range_stack = []
-        annotation = jax.profiler.TraceAnnotation(msg)
+        annotation = host_span(msg)
         annotation.__enter__()
         self._range_stack.append(annotation)
 
@@ -105,10 +109,10 @@ class TpuAccelerator(Accelerator):
         if stack:
             stack.pop().__exit__(None, None, None)
 
-    @contextlib.contextmanager
     def range(self, msg: str):
-        with jax.profiler.TraceAnnotation(msg):
-            yield
+        from deepspeed_tpu.telemetry.spans import host_span
+
+        return host_span(msg)
 
     # --- op builder dispatch -------------------------------------------
     def create_op_builder(self, op_name: str):

@@ -94,6 +94,7 @@ from deepspeed_tpu.inference.decoding import (
     compile_spec_row_update_fn,
     read_bucket,
 )
+from deepspeed_tpu.telemetry.spans import host_span
 
 # admission/bucket sizing shares the ONE bucketing rule with the tight-read
 # geometry (decoding.read_bucket); the old local name stays importable
@@ -131,6 +132,12 @@ class _Request:
     # KV-cache bytes this request's row streamed across its decode ticks
     # (host accounting at the read length each retired tick dispatched)
     kv_bytes_read: int = 0
+    # called once, with no arguments, when this request's FIRST prefill work
+    # is dispatched (its first fused chunk, or the admission-time separate /
+    # speculative prefill), then dropped: the submitter's lifecycle mark
+    # (the serving layer stamps ``ServeRequest.prefill_start_t`` on its own
+    # clock). None = nobody asked.
+    on_prefill_start: Optional[Callable[[], None]] = None
     # speculative accounting (spec ticks only): drafts proposed for this
     # request vs drafts its verify rounds accepted
     spec_drafted: int = 0
@@ -440,7 +447,15 @@ class ContinuousBatchingEngine:
                             "block_ms": 0.0, "tokens": 0, "wasted_tokens": 0,
                             "capacity_tokens": 0, "fused_prefill_ticks": 0,
                             "max_inflight": 0, "spec_drafted": 0,
-                            "spec_accepted": 0}
+                            "spec_accepted": 0,
+                            # the two kinds of tick told apart (counted at
+                            # dispatch like ``ticks``; blocked ms charged
+                            # where each tick retires, by its own kind)
+                            "plain_ticks": 0, "block_ms_plain": 0.0,
+                            "block_ms_fused": 0.0,
+                            # how long the prefill queues stood when
+                            # each step looked (÷ steps = mean depth)
+                            "prefill_q_depth_sum": 0}
         # cancelled rids, remembered so status()/result() answer precisely
         # instead of "unknown" — BOUNDED (oldest evicted past 4096): a
         # long-running server cancels routinely and must not leak an int
@@ -669,8 +684,11 @@ class ContinuousBatchingEngine:
         return prompt
 
     def submit(self, prompt_ids, max_new_tokens: int = 32, *,
-               rid: Optional[int] = None, gen_base: int = 0) -> int:
-        """Queue a request. ``rid``/``gen_base`` are the RESUME surface
+               rid: Optional[int] = None, gen_base: int = 0,
+               on_prefill_start: Optional[Callable[[], None]] = None) -> int:
+        """Queue a request. ``on_prefill_start`` is called once, with no
+        arguments, when the request's first prefill work is dispatched (the
+        submitter's lifecycle mark). ``rid``/``gen_base`` are the RESUME surface
         (serving-layer recovery): an explicit ``rid`` preserves a lost
         request's RNG identity on a rebuilt engine, and ``gen_base``
         offsets the device generation counter so the per-token keys
@@ -691,7 +709,8 @@ class ContinuousBatchingEngine:
                 raise ValueError(f"explicit rid {rid} is already in use")
             self._next_rid = max(self._next_rid, rid + 1)
         self._pending.append(_Request(rid, prompt, max_new_tokens,
-                                      gen_base=gen_base))
+                                      gen_base=gen_base,
+                                      on_prefill_start=on_prefill_start))
         return rid
 
     def register_prefix(self, prefix_ids) -> int:
@@ -740,9 +759,11 @@ class ContinuousBatchingEngine:
         self._require_prefix(prefix_id)
         self._prefixes.pop(prefix_id)
 
-    def submit_with_prefix(self, prefix_id: int, suffix_ids, max_new_tokens: int = 32) -> int:
+    def submit_with_prefix(self, prefix_id: int, suffix_ids, max_new_tokens: int = 32, *,
+                           on_prefill_start: Optional[Callable[[], None]] = None) -> int:
         """Queue a request whose prompt is (registered prefix + suffix);
-        the prefix KV is reused, only the suffix is prefilled."""
+        the prefix KV is reused, only the suffix is prefilled.
+        ``on_prefill_start`` as in :meth:`submit`."""
         suffix = np.asarray(suffix_ids, np.int32).reshape(-1)
         if suffix.size == 0:
             raise ValueError("empty suffix (use submit for prefix-only prompts)")
@@ -758,7 +779,8 @@ class ContinuousBatchingEngine:
             )
         rid = self._next_rid
         self._next_rid += 1
-        req = _Request(rid, np.concatenate([pre["tokens"], suffix]), max_new_tokens)
+        req = _Request(rid, np.concatenate([pre["tokens"], suffix]), max_new_tokens,
+                       on_prefill_start=on_prefill_start)
         req.prefix = pre  # snapshot: queued requests survive unregister_prefix
         self._pending.append(req)
         return rid
@@ -863,7 +885,13 @@ class ContinuousBatchingEngine:
         depth actually reached. ``overlap_frac`` is the fraction of
         host-side tick-loop time NOT spent blocked on device results
         (1.0 = the device never made the host wait); ``block_ms_per_token``
-        is the loadgen A/B headline — host-blocked ms per decoded token."""
+        is the loadgen A/B headline — host-blocked ms per decoded token.
+        Tick kinds: ``plain_ticks + fused_prefill_ticks == ticks`` and
+        ``block_ms_plain + block_ms_fused == block_ms`` (each retired
+        tick's blocked time charged to its own kind);
+        ``prefill_q_depth_sum`` (÷ ``steps`` = mean) is the
+        admitted-but-not-yet-prefilled requests over all pools, read once
+        per ``step()``."""
         s = dict(self._tick_stats)
         s["pipeline_depth"] = self.pipeline_depth
         # NOT the tokens_per_tick knob (the burst width): the observed mean
@@ -928,18 +956,23 @@ class ContinuousBatchingEngine:
         # FIFO with skip: a request that only fits the (full) long pool
         # must not block shorter requests behind it
         still_pending = []
-        for req in self._pending:
-            placed = self._place(req)
-            if placed is None:
-                still_pending.append(req)
-                continue
-            self._admit(req, *placed)
+        with host_span("tick.admit"):
+            for req in self._pending:
+                placed = self._place(req)
+                if placed is None:
+                    still_pending.append(req)
+                    continue
+                self._admit(req, *placed)
         self._pending = still_pending
+        stats = self._tick_stats
+        stats["prefill_q_depth_sum"] += sum(len(p.prefill_q) for p in self._pools)
 
         recs: Dict[int, _TickRecord] = {}
         for pi, pool in enumerate(self._pools):
-            rec = (self._dispatch_spec_tick(pool) if self.spec_gamma
-                   else self._dispatch_tick(pool))
+            with host_span("tick.dispatch.fused" if self.fused_prefill and pool.prefill_q
+                           else "tick.dispatch.plain"):
+                rec = (self._dispatch_spec_tick(pool) if self.spec_gamma
+                       else self._dispatch_tick(pool))
             if rec is not None:
                 recs[pi] = rec
         # the dispatch span is INTENTIONALLY unsynced: it measures host
@@ -954,7 +987,6 @@ class ContinuousBatchingEngine:
                 for r in recs.values():
                     r.t0 = t_disp
             self._inflight.append(recs)
-        stats = self._tick_stats
         stats["steps"] += 1
         stats["ticks"] += len(recs)
         # emission capacity this step actually dispatched: every slot of a
@@ -962,7 +994,9 @@ class ContinuousBatchingEngine:
         # not assume one tick covers ALL pools)
         stats["capacity_tokens"] += sum(
             self._pools[pi].n_slots * r.k for pi, r in recs.items())
-        stats["fused_prefill_ticks"] += sum(1 for r in recs.values() if r.fused)
+        n_fused = sum(1 for r in recs.values() if r.fused)
+        stats["fused_prefill_ticks"] += n_fused
+        stats["plain_ticks"] += len(recs) - n_fused
         stats["dispatch_ms"] += dispatch_ms
         stats["max_inflight"] = max(stats["max_inflight"], len(self._inflight))
 
@@ -1109,6 +1143,7 @@ class ContinuousBatchingEngine:
         if admit is not None:
             ctoks, cpos0, nreal, emits = admit.chunks[0]
             aslot = admit.slot
+            self._mark_prefill_start(admit)
             W = _bucket(nreal, pool.chunk_cap, _CHUNK_FLOOR)
             extent = max(extent, cpos0 + nreal)
             read_len = self._read_len(pool, extent)
@@ -1226,6 +1261,7 @@ class ContinuousBatchingEngine:
         if self.fused_prefill and pool.prefill_q:
             admit = pool.prefill_q[0]
             ctoks, cpos0, nreal, _ = admit.chunks.pop(0)
+            self._mark_prefill_start(admit)
             W = _bucket(nreal, pool.chunk_cap, _CHUNK_FLOOR)
             seg_toks = np.zeros((n, W), np.int32)
             seg_toks[admit.slot, :nreal] = ctoks
@@ -1306,7 +1342,8 @@ class ContinuousBatchingEngine:
                 self.fault_hook("retire", {"tick": self._tick_index,
                                            "pool": pi})
             t0 = time.perf_counter()
-            arr = np.asarray(rec.packed)  # the single device get per tick
+            with host_span("tick.retire"):
+                arr = np.asarray(rec.packed)  # the single device get per tick
             dt = time.perf_counter() - t0
             if self.fetch_timeout_s is not None and dt > self.fetch_timeout_s:
                 # post-hoc watchdog: the fetch DID return, but far past
@@ -1319,6 +1356,7 @@ class ContinuousBatchingEngine:
                     f"(> fetch_timeout_s={self.fetch_timeout_s}) — device "
                     f"unhealthy, tick pipeline abandoned")
             block_ms += dt * 1000.0
+            stats["block_ms_fused" if rec.fused else "block_ms_plain"] += dt * 1000.0
             k = rec.k
             g = rec.spec
             hook = self.span_hook
@@ -1400,6 +1438,14 @@ class ContinuousBatchingEngine:
         req.win_drafted = req.win_accepted = 0
 
     # -- internals ------------------------------------------------------
+    @staticmethod
+    def _mark_prefill_start(req: _Request):
+        """Prefill work for ``req`` is being dispatched: tell its submitter,
+        if this is the first (the callback is dropped once called)."""
+        notify, req.on_prefill_start = req.on_prefill_start, None
+        if notify is not None:
+            notify()
+
     def _prefill_for_bucket(self, bucket: int):
         """B=1 ragged prefill into a bucket-length cache (pool-agnostic)."""
         def build():
@@ -1520,6 +1566,7 @@ class ContinuousBatchingEngine:
         from deepspeed_tpu.models import transformer as tf
 
         m = int(toks.size)
+        self._mark_prefill_start(req)
         if m <= 1:
             return
         if req.prefix is not None:

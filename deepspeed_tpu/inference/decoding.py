@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
+from deepspeed_tpu.telemetry.hlo_scopes import Scope
+
 
 def _mark_first_token(timings: Optional[dict], token):
     """TTFT hook: when the caller passes a ``timings`` dict (telemetry
@@ -539,6 +541,7 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
     assert k >= 1, k
     donate_argnums = (1, 2, 3) if donate else ()
 
+    @jax.named_scope(Scope.ACCEPT)
     def accept(tok, last_tok, done, gen, quota, emit_mask):
         """Shared acceptance: which rows emit this step, updated state."""
         live = (done == 0) & (emit_mask == 1)
@@ -550,6 +553,7 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
         last2 = jnp.where(live, tok, last_tok)
         return last2, done2, gen2, live.astype(jnp.int32)
 
+    @jax.named_scope(Scope.SAMPLE)
     def sample(logits, rids, gen, base_key):
         if temperature <= 0.0:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)

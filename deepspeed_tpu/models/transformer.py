@@ -31,6 +31,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.telemetry.hlo_scopes import Scope
+
 
 @dataclass(frozen=True)
 class TransformerConfig:
@@ -425,6 +427,7 @@ def logical_specs(params, cfg: TransformerConfig):
 # forward
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(Scope.NORM)
 def _norm(x, scale, bias, cfg: TransformerConfig):
     x32 = x.astype(jnp.float32)
     if cfg.norm_type == "rmsnorm":
@@ -490,6 +493,7 @@ def _sparse_layout(sparse_attention: tuple, num_heads: int, seq_len: int):
     return config.make_layout(seq_len), config.block
 
 
+@jax.named_scope(Scope.ATTN_CORE)
 def _attention(q, k, v, cfg: TransformerConfig, segment_positions, window=None):
     """Causal multi-head / grouped-query attention.
 
@@ -654,6 +658,7 @@ def _dense_act(cfg: TransformerConfig):
     return {"relu": jax.nn.relu, "quick_gelu": _quick_gelu}.get(cfg.activation, jax.nn.gelu)
 
 
+@jax.named_scope(Scope.MLP)
 def _mlp_block(h, mlp_p, cfg: TransformerConfig, dropout_rng=None, decode=False):
     """Shared MLP/MoE block: h (B,S,D) -> (out (B,S,D), moe aux loss)."""
     if cfg.moe_num_experts > 0:
@@ -741,6 +746,7 @@ def _linear(x, w):
     return x @ w
 
 
+@jax.named_scope(Scope.ATTN_QKV)
 def _qkv(h, attn_p, cfg: TransformerConfig, positions):
     """Project h -> (q, k, v) heads with positional transform applied."""
     B, S, _ = h.shape
@@ -757,6 +763,14 @@ def _qkv(h, attn_p, cfg: TransformerConfig, positions):
         q = _rope(q, positions, cfg.rope_theta, cfg.rope_dim, cfg.rope_interleaved)
         k = _rope(k, positions, cfg.rope_theta, cfg.rope_dim, cfg.rope_interleaved)
     return q, k, v
+
+
+@jax.named_scope(Scope.ATTN_OUT)
+def _attn_out_proj(attn_out, attn_p, cfg: TransformerConfig):
+    attn_out = _linear(attn_out, attn_p["wo"])
+    if cfg.use_bias:
+        attn_out = attn_out + attn_p["bo"]
+    return attn_out
 
 
 def _layer_body(x, layer_params, cfg: TransformerConfig, positions, dropout_rng,
@@ -783,9 +797,7 @@ def _layer_body(x, layer_params, cfg: TransformerConfig, positions, dropout_rng,
     h = maybe_quant(h)
     q, k, v = _qkv(h, attn_p, cfg, positions)
     attn_out = _attention(q, k, v, cfg, positions, window=window).reshape(B, S, nh * hd)
-    attn_out = _linear(attn_out, attn_p["wo"])
-    if cfg.use_bias:
-        attn_out = attn_out + attn_p["bo"]
+    attn_out = _attn_out_proj(attn_out, attn_p, cfg)
     if cfg.dropout > 0.0 and dropout_rng is not None:
         keep = jax.random.bernoulli(dropout_rng, 1.0 - cfg.dropout, attn_out.shape)
         attn_out = jnp.where(keep, attn_out / (1.0 - cfg.dropout), 0.0).astype(attn_out.dtype)
@@ -883,25 +895,26 @@ def forward(params, cfg: TransformerConfig, tokens, dropout_rng=None,
     """
     dtype = cfg.jnp_dtype
     B, S = tokens.shape
-    x = jnp.take(_constrain_tp(params["embed"]["tok"], ("vocab", "embed")),
-                 tokens, axis=0).astype(dtype)
-    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
-    if cfg.pos_embedding == "learned":
-        pos_t = _constrain_tp(params["embed"]["pos"], ("seq", "embed"))
-        # explicit broadcast: the implicit (1, S, D) rank-promotion leaves a
-        # keepdims reduce in the transpose whose unit dim drags the batch
-        # sharding along, and GSPMD can only reshard that to the fsdp grad
-        # spec by replicating ("[SPMD] Involuntary full rematerialization")
-        x = x + jnp.broadcast_to(pos_t[:S].astype(dtype), x.shape)
-    if cfg.type_vocab_size > 0:
-        tt = token_types if token_types is not None else jnp.zeros_like(tokens)
-        # same scatter-grad constraint as tok/pos (logical (None, "embed"),
-        # matching logical_specs for the type table)
-        type_t = _constrain_tp(params["embed"]["type"], (None, "embed"))
-        x = x + jnp.take(type_t, tt, axis=0).astype(dtype)
-    if cfg.embed_norm:
-        en = params["embed_norm"]
-        x = _norm(x, en["scale"], en.get("bias"), cfg)
+    with jax.named_scope(Scope.EMBED):
+        x = jnp.take(_constrain_tp(params["embed"]["tok"], ("vocab", "embed")),
+                     tokens, axis=0).astype(dtype)
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
+        if cfg.pos_embedding == "learned":
+            pos_t = _constrain_tp(params["embed"]["pos"], ("seq", "embed"))
+            # explicit broadcast: the implicit (1, S, D) rank-promotion leaves a
+            # keepdims reduce in the transpose whose unit dim drags the batch
+            # sharding along, and GSPMD can only reshard that to the fsdp grad
+            # spec by replicating ("[SPMD] Involuntary full rematerialization")
+            x = x + jnp.broadcast_to(pos_t[:S].astype(dtype), x.shape)
+        if cfg.type_vocab_size > 0:
+            tt = token_types if token_types is not None else jnp.zeros_like(tokens)
+            # same scatter-grad constraint as tok/pos (logical (None, "embed"),
+            # matching logical_specs for the type table)
+            type_t = _constrain_tp(params["embed"]["type"], (None, "embed"))
+            x = x + jnp.take(type_t, tt, axis=0).astype(dtype)
+        if cfg.embed_norm:
+            en = params["embed_norm"]
+            x = _norm(x, en["scale"], en.get("bias"), cfg)
     x = _constrain_batch_sharding(x)
 
     ltd_on = (
@@ -1023,6 +1036,7 @@ def forward(params, cfg: TransformerConfig, tokens, dropout_rng=None,
     return _vocab_head(x, params, cfg, dtype), aux_total
 
 
+@jax.named_scope(Scope.LM_HEAD)
 def _vocab_head(x, params, cfg: TransformerConfig, dtype):
     """Hidden states -> vocab logits.
 
@@ -1066,6 +1080,7 @@ def encode(params, cfg: TransformerConfig, tokens, token_types=None):
 # partitioned_param_swapper.py.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(Scope.EMBED)
 def embed_fwd(params, cfg: TransformerConfig, tokens):
     """tokens (..., S) -> embedded activations (..., S, D) in model dtype
     (leading dims beyond batch — e.g. a microbatch dim — broadcast through)."""
@@ -1116,6 +1131,7 @@ def layer_slice_fwd(layers_slice, cfg: TransformerConfig, x, windows=None):
     return x, jnp.sum(auxs)
 
 
+@jax.named_scope(Scope.LOSS)
 def _ce_from_logits(logits, batch, tokens, denom=None):
     """Shift + masked token cross-entropy shared by loss_fn / head_loss_fwd.
 
@@ -1247,16 +1263,16 @@ def _layer_body_cached(x, layer_params, k_cache, v_cache, cfg: TransformerConfig
     )
     ring = cfg.rolling_kv_cache
 
-    k_cache, v_cache = update_kv_cache(k_cache, v_cache, k, v, pos, positions,
-                                       ring=ring)
+    with jax.named_scope(Scope.ATTN_KV_WRITE):
+        k_cache, v_cache = update_kv_cache(k_cache, v_cache, k, v, pos, positions,
+                                           ring=ring)
 
     if use_flash_prefill:
         w = window if isinstance(window, int) and window > 0 and window < S else None
-        attn_out = _flash_sharded(q, k, v, cfg, causal=True,
-                                  window=w).reshape(B, S, nh * hd)
-        attn_out = _linear(attn_out, attn_p["wo"])
-        if cfg.use_bias:
-            attn_out = attn_out + attn_p["bo"]
+        with jax.named_scope(Scope.ATTN_CORE):
+            attn_out = _flash_sharded(q, k, v, cfg, causal=True,
+                                      window=w).reshape(B, S, nh * hd)
+        attn_out = _attn_out_proj(attn_out, attn_p, cfg)
         return _finish_layer_cached(x, h, attn_out, layer_params, cfg, k_cache, v_cache)
 
     cache_T = (k_cache["q8"] if isinstance(k_cache, dict) else k_cache).shape[1]
@@ -1271,9 +1287,7 @@ def _layer_body_cached(x, layer_params, k_cache, v_cache, cfg: TransformerConfig
         alibi_slopes=slopes, local_window=window, ring=ring,
         read_len=read_len if not ring else None,
     ).reshape(B, S, nh * hd)
-    attn_out = _linear(attn_out, attn_p["wo"])
-    if cfg.use_bias:
-        attn_out = attn_out + attn_p["bo"]
+    attn_out = _attn_out_proj(attn_out, attn_p, cfg)
     return _finish_layer_cached(x, h, attn_out, layer_params, cfg, k_cache, v_cache)
 
 
@@ -1315,24 +1329,25 @@ def forward_with_cache(params, cfg: TransformerConfig, tokens, cache, pos, posit
     B, S = tokens.shape
     if read_len is not None and read_len >= cache_alloc_len(cache):
         read_len = None  # degenerate slice: the allocation is already tight
-    x = jnp.take(params["embed"]["tok"], tokens, axis=0).astype(dtype)
-    if positions is not None:
-        assert jnp.ndim(pos) == 1, "explicit positions require vector pos"
-    elif jnp.ndim(pos) == 1:
-        positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # (B, S)
-    else:
-        positions = pos + jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
-    if cfg.pos_embedding == "learned":
-        pos_table = params["embed"]["pos"].astype(dtype)
-        clamped = jnp.minimum(positions, pos_table.shape[0] - 1)
-        x = x + (jnp.take(pos_table, clamped, axis=0) if jnp.ndim(pos) == 1
-                 else jnp.take(pos_table, clamped[0], axis=0))
-    if cfg.type_vocab_size > 0:
-        # decode has no token-type stream; type 0 matches forward()'s default
-        x = x + params["embed"]["type"][0].astype(dtype)
-    if cfg.embed_norm:
-        en = params["embed_norm"]
-        x = _norm(x, en["scale"], en.get("bias"), cfg)
+    with jax.named_scope(Scope.EMBED):
+        x = jnp.take(params["embed"]["tok"], tokens, axis=0).astype(dtype)
+        if positions is not None:
+            assert jnp.ndim(pos) == 1, "explicit positions require vector pos"
+        elif jnp.ndim(pos) == 1:
+            positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # (B, S)
+        else:
+            positions = pos + jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
+        if cfg.pos_embedding == "learned":
+            pos_table = params["embed"]["pos"].astype(dtype)
+            clamped = jnp.minimum(positions, pos_table.shape[0] - 1)
+            x = x + (jnp.take(pos_table, clamped, axis=0) if jnp.ndim(pos) == 1
+                     else jnp.take(pos_table, clamped[0], axis=0))
+        if cfg.type_vocab_size > 0:
+            # decode has no token-type stream; type 0 matches forward()'s default
+            x = x + params["embed"]["type"][0].astype(dtype)
+        if cfg.embed_norm:
+            en = params["embed_norm"]
+            x = _norm(x, en["scale"], en.get("bias"), cfg)
 
     layers = _cast_layers(params["layers"], dtype)
 

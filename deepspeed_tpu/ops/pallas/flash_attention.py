@@ -180,6 +180,7 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, vma=None, windo
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
@@ -353,6 +354,7 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, vma, window, res, do):
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal, bq=bq, bk=bk,
                           nk=nk_eff, window=window),
+        name="flash_bwd_dq",
         grid=(B, H, nq, nk_eff),
         in_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, ki: (b, h, qi, 0)),
@@ -374,6 +376,7 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, vma, window, res, do):
     dk_full, dv_full = pl.pallas_call(
         functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal, bq=bq, bk=bk,
                           nq=nq_eff, window=window, nq_total=nq),
+        name="flash_bwd_dkv",
         grid=(B, H, nk, nq_eff),
         in_specs=[
             pl.BlockSpec((1, 1, bq, hd), dkv_q_index),

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.ops.transformer.fused_ops import fused_softmax
+from deepspeed_tpu.telemetry.hlo_scopes import Scope
 
 
 def apply_rotary_pos_emb(x, positions, theta: float = 10000.0,
@@ -161,18 +162,29 @@ def softmax_context(q, k_cache, v_cache, pos, scale: Optional[float] = None,
     (the ring is already O(window)).
     """
     B, S, nh, hd = q.shape
-    if read_len is not None:
-        assert not ring, "tight reads do not apply to the rolling (ring) cache"
-        k_cache = slice_kv_time(k_cache, read_len)
-        v_cache = slice_kv_time(v_cache, read_len)
-    if isinstance(k_cache, dict):  # int8 KV cache: dequant at the read
-        k_cache = dequantize_kv(k_cache, q.dtype)
-        v_cache = dequantize_kv(v_cache, q.dtype)
-    nkv = k_cache.shape[2]
-    kk, vv = k_cache, v_cache
-    if nkv != nh:
-        kk = jnp.repeat(kk, nh // nkv, axis=2)
-        vv = jnp.repeat(vv, nh // nkv, axis=2)
+    with jax.named_scope(Scope.ATTN_KV_READ):
+        if read_len is not None:
+            assert not ring, "tight reads do not apply to the rolling (ring) cache"
+            k_cache = slice_kv_time(k_cache, read_len)
+            v_cache = slice_kv_time(v_cache, read_len)
+        if isinstance(k_cache, dict):  # int8 KV cache: dequant at the read
+            k_cache = dequantize_kv(k_cache, q.dtype)
+            v_cache = dequantize_kv(v_cache, q.dtype)
+        nkv = k_cache.shape[2]
+        kk, vv = k_cache, v_cache
+        if nkv != nh:
+            kk = jnp.repeat(kk, nh // nkv, axis=2)
+            vv = jnp.repeat(vv, nh // nkv, axis=2)
+    with jax.named_scope(Scope.ATTN_CORE):
+        return _masked_attention(q, kk, vv, pos, scale, positions, alibi_slopes,
+                                 local_window, ring)
+
+
+def _masked_attention(q, kk, vv, pos, scale, positions, alibi_slopes,
+                      local_window, ring):
+    """The contraction half of :func:`softmax_context`, over the cache
+    window its read half selected."""
+    B, S, nh, hd = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, kk).astype(jnp.float32) * scale  # (B,nh,S,T)
     T = kk.shape[1]

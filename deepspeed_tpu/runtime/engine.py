@@ -43,6 +43,8 @@ from deepspeed_tpu.runtime.config import TpuConfig
 from deepspeed_tpu.runtime.fp16.loss_scaler import LossScaleState, create_loss_scaler
 from deepspeed_tpu.runtime.lr_schedules import create_lr_scheduler
 from deepspeed_tpu.runtime.zero.sharding import ShardingPolicy
+from deepspeed_tpu.telemetry.hlo_scopes import Scope
+from deepspeed_tpu.telemetry.spans import host_span
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import EngineTimers, ThroughputTimer
 
@@ -796,7 +798,8 @@ class TpuEngine:
                         return model.loss(p, batch, rng, **kwargs).astype(jnp.float32) * scale
 
                     loss, grads = jax.value_and_grad(scaled_loss)(params)
-                new_acc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32) / predivide, grad_acc, grads)
+                with jax.named_scope(Scope.GRAD_ACCUMULATE):
+                    new_acc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32) / predivide, grad_acc, grads)
                 return loss / scale, new_acc
 
             fn = jax.jit(
@@ -856,8 +859,9 @@ class TpuEngine:
                 grads = jax.tree.map(lambda g: g * factor, grads)
 
             base = master if mixed else params
-            updates, new_opt = optimizer.update(grads, opt_state, base, lr)
-            new_base = jax.tree.map(lambda p, u: (p.astype(jnp.float32) + u).astype(p.dtype), base, updates)
+            with jax.named_scope(Scope.OPTIMIZER_APPLY):
+                updates, new_opt = optimizer.update(grads, opt_state, base, lr)
+                new_base = jax.tree.map(lambda p, u: (p.astype(jnp.float32) + u).astype(p.dtype), base, updates)
 
             if fp16:
                 # skip the step wholesale on overflow (loss_scaler semantics)
@@ -1076,18 +1080,12 @@ class TpuEngine:
     # -- trace capture (reference aux: NVTX ranges + torch profiler hooks;
     # here the XLA-native equivalent is an xplane trace, SURVEY §5a) -------
     def start_profile(self, logdir: str):
-        """Begin a jax.profiler trace (view in TensorBoard / xprof)."""
-        import jax.profiler
-
-        jax.profiler.start_trace(logdir)
-        self._profiling = True
+        """Begin a jax.profiler trace (view in TensorBoard / xprof) through
+        the hub's one capture entry point (``Telemetry.start_capture``)."""
+        self.telemetry.start_capture(logdir)
 
     def stop_profile(self):
-        import jax.profiler
-
-        if getattr(self, "_profiling", False):
-            jax.profiler.stop_trace()
-            self._profiling = False
+        self.telemetry.stop_capture()
 
     def forward(self, batch, rng=None):
         if not self.telemetry.enabled:
@@ -1226,9 +1224,10 @@ class TpuEngine:
         if micro is None:
             micro = self._micro_jits[keep_len] = self._micro_builder(keep_len)
         theta = jnp.float32(self.pld.get_theta() if self.pld is not None else 1.0)
-        loss, self.grad_acc = micro(
-            self.params, self.grad_acc, batch, rng, self.scale_state.scale, theta
-        )
+        with host_span("train.micro_dispatch"):
+            loss, self.grad_acc = micro(
+                self.params, self.grad_acc, batch, rng, self.scale_state.scale, theta
+            )
         self._pending_loss = loss
         self.timers(EngineTimers.FORWARD).stop()
         return loss
@@ -1329,25 +1328,31 @@ class TpuEngine:
             metrics = self._host_offload_step(self.get_lr_value())
         else:
             lr = jnp.asarray(self.get_lr_value(), jnp.float32)
-            (
-                self.params,
-                self.master_params,
-                self.opt_state,
-                self.grad_acc,
-                self.scale_state,
-                metrics,
-            ) = self._apply_fn(
-                self.params, self.master_params, self.opt_state, self.grad_acc, self.scale_state, lr
-            )
+            with host_span("train.apply_dispatch"):
+                (
+                    self.params,
+                    self.master_params,
+                    self.opt_state,
+                    self.grad_acc,
+                    self.scale_state,
+                    metrics,
+                ) = self._apply_fn(
+                    self.params, self.master_params, self.opt_state, self.grad_acc, self.scale_state, lr
+                )
         self._last_metrics = metrics
-        self._guarded_fetch(metrics)
+        # where the step itself would block on the device: the watchdog's
+        # fetch of the step metrics (and fp16's overflow read, below)
+        with host_span("train.loss_fetch"):
+            self._guarded_fetch(metrics)
         self.global_steps += 1
         if self.pld is not None:
             self.pld.update_state(self.global_steps)
         if self.fp16_enabled:
             # dynamic scaling requires reading the overflow flag (host sync,
             # same as the reference's has_overflow allreduce + item())
-            if bool(metrics.overflow):
+            with host_span("train.loss_fetch"):
+                overflowed = bool(metrics.overflow)
+            if overflowed:
                 self.skipped_steps += 1
                 log_dist(
                     f"step {self.global_steps} overflow: skipping, loss scale -> {float(self.scale_state.scale)}",
@@ -1454,7 +1459,8 @@ class TpuEngine:
         it = data_iter if data_iter is not None else iter(self.training_dataloader)
         losses = []
         for _ in range(self.gradient_accumulation_steps):
-            batch = next(it)
+            with host_span("train.next_batch"):
+                batch = next(it)
             loss = self.forward(batch)
             self.backward(loss)
             self.step()

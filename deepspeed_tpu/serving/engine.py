@@ -67,6 +67,7 @@ tests exact.
 
 import threading
 import time
+from functools import partial
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
@@ -92,7 +93,7 @@ from deepspeed_tpu.serving.request import (
     Admission,
     ServeRequest,
 )
-from deepspeed_tpu.telemetry.spans import SpanEmitter
+from deepspeed_tpu.telemetry.spans import SpanEmitter, host_span
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -349,8 +350,9 @@ class ServingEngine:
         aging barrier), then one engine tick. Returns {rid: [tokens]}
         emitted this tick, keyed by SERVING rid."""
         now = self._clock()
-        self._expire(now)
-        self._schedule(now)
+        with host_span("serve.schedule"):
+            self._expire(now)
+            self._schedule(now)
         out: Dict[int, List[int]] = {}
         if self._cb.has_work():
             emitted, ticked = self._guarded_tick()
@@ -644,7 +646,8 @@ class ServingEngine:
             t0_replay = self._clock()
             try:
                 erid = new.submit(full, remaining, rid=entry["engine_rid"],
-                                  gen_base=len(emitted))
+                                  gen_base=len(emitted),
+                                  on_prefill_start=partial(self._on_prefill_start, req))
             except ValueError as e:
                 # the degraded engine cannot hold it — shed honestly
                 self._mark_lost(req, f"readmit_failed: {e}")
@@ -1352,6 +1355,10 @@ class ServingEngine:
                    for p in self._effective_pool_state())
 
     def _handover(self, req: ServeRequest, now: float):
+        # request lifecycle (always on): the batcher calls this once, at the
+        # request's first prefill dispatch, and we read OUR clock — submit_t /
+        # admit_t / prefill_start_t / first_token_t are all on this one clock
+        mark = partial(self._on_prefill_start, req)
         if req.engine_rid is not None or req.tokens:
             # migrated resume (readmit): re-prefill prompt + everything
             # already emitted and continue at gen_base, pinning the
@@ -1365,18 +1372,20 @@ class ServingEngine:
                     if req.tokens else req.prompt)
             req.engine_rid = self._cb.submit(
                 full, req.max_new_tokens - len(req.tokens),
-                rid=req.engine_rid, gen_base=len(req.tokens))
+                rid=req.engine_rid, gen_base=len(req.tokens), on_prefill_start=mark)
         elif req.prefix_id is not None and req.prefix_id in self._prefixes:
             # splice the registered prefix KV; only the suffix prefills
             suffix = req.prompt[self._prefixes[req.prefix_id].size:]
             req.engine_rid = self._cb.submit_with_prefix(
-                self._prefix_pids[req.prefix_id], suffix, req.max_new_tokens)
+                self._prefix_pids[req.prefix_id], suffix, req.max_new_tokens,
+                on_prefill_start=mark)
         else:
             # no prefix — or it was unregistered while this request sat
             # in the queue: req.prompt already holds the FULL token
             # sequence, so pay the full prefill instead of stranding the
             # request (stream bitwise identical either way)
-            req.engine_rid = self._cb.submit(req.prompt, req.max_new_tokens)
+            req.engine_rid = self._cb.submit(req.prompt, req.max_new_tokens,
+                                             on_prefill_start=mark)
         req.state = RUNNING
         req.admit_t = now
         self._rid_watermark = max(self._rid_watermark, req.engine_rid + 1)
@@ -1483,6 +1492,22 @@ class ServingEngine:
                    "prefix": req.prefix_id is not None})
         req.span_parent = sid
 
+    def _on_prefill_start(self, req: ServeRequest):
+        """Handed to the batching engine with each submit (``on_prefill_start``)
+        and called once, as the request's first prefill work is dispatched:
+        one clock read per request, no lookup. A request that already
+        streamed its first token (an in-process recovery replaying ``prompt +
+        emitted``) keeps the marks its client felt; one that had not gets
+        the rebuilt engine's instant. With the hub live the ``prefill_wait``
+        span (admit -> here) closes now."""
+        if req.first_token_t is not None:
+            return
+        req.prefill_start_t = t = self._clock()
+        if req.trace_id is not None and self._spans.enabled:
+            self._spans.emit("prefill_wait", req.trace_id, req.admit_t, t,
+                             parent_id=req.span_parent,
+                             attrs={"engine_rid": int(req.engine_rid)})
+
     def _span_hook(self, engine_rid: int, kind: str, t0: float, t1: float,
                    attrs: Optional[dict] = None):
         """Installed as the batching engine's ``span_hook`` (only when the
@@ -1520,6 +1545,11 @@ class ServingEngine:
         ttft = req.ttft_ms()
         event["ttft_ms"] = round(
             ttft if ttft is not None else (now - req.submit_t) * 1000.0, 3)
+        wait, prefill = req.prefill_wait_ms(), req.prefill_ms(now)
+        if wait is not None:
+            event["prefill_wait_ms"] = round(wait, 3)
+        if prefill is not None:
+            event["prefill_ms"] = round(prefill, 3)
         event["priority"] = req.priority
         event["tenant"] = req.tenant
         if req.trace_id is not None:

@@ -302,6 +302,12 @@ def run_load(serving, workload: List[dict], arrivals: List[float],
         t = req.ttft_ms()
         if t is not None:
             rec["ttft_ms"] = t
+        # TTFT's parts inside the program: queue_ms + prefill_wait_ms +
+        # prefill_ms = ttft_ms (admit -> first prefill dispatch -> token 1)
+        for field in ("prefill_wait_ms", "prefill_ms"):
+            v = getattr(req, field)()
+            if v is not None:
+                rec[field] = v
         if (req.first_token_t is not None and req.finish_t is not None
                 and len(req.tokens) > 1):
             rec["tbt_ms"] = ((req.finish_t - req.first_token_t) * 1000.0
@@ -334,6 +340,18 @@ def host_overhead(tick_stats: dict) -> dict:
         "block_ms_per_token": tick_stats.get("block_ms_per_token"),
         "wasted_tokens": tick_stats.get("wasted_tokens", 0),
     }
+    # the two kinds of tick apart (a fused tick carries a prefill chunk),
+    # and the mean depth of the batcher's prefill queue per step
+    fused, plain = tick_stats.get("fused_prefill_ticks"), tick_stats.get("plain_ticks")
+    if plain is not None and fused is not None:
+        out["fused_tick_share"] = (round(fused / (fused + plain), 4)
+                                   if fused + plain else None)
+        out["tick_block_ms_fused"] = (round(tick_stats["block_ms_fused"] / fused, 4)
+                                      if fused else None)
+        out["tick_block_ms_plain"] = (round(tick_stats["block_ms_plain"] / plain, 4)
+                                      if plain else None)
+        out["prefill_q_depth_mean"] = (round(tick_stats["prefill_q_depth_sum"] / steps, 4)
+                                       if steps else None)
     if "utilization" in tick_stats:
         out["tick_utilization"] = tick_stats["utilization"]
     if tick_stats.get("spec_gamma"):
@@ -525,7 +543,7 @@ def summarize(records: List[dict], wall_s: float,
             }
             for reason, d in sorted(by_reason.items())
         }
-    for field in ("ttft_ms", "tbt_ms", "queue_ms"):
+    for field in ("ttft_ms", "tbt_ms", "queue_ms", "prefill_wait_ms", "prefill_ms"):
         vals = [r[field] for r in finished if field in r]
         if vals:
             out[field] = {k: round(v, 3) for k, v in _pcts(vals).items()}
@@ -554,7 +572,9 @@ def format_summary(summary: dict) -> str:
     else:
         lines.append(f"wall time      {summary['wall_s']} s")
     for field, label in (("ttft_ms", "TTFT"), ("tbt_ms", "TBT"),
-                         ("queue_ms", "queue wait")):
+                         ("queue_ms", "queue wait"),
+                         ("prefill_wait_ms", "prefill wait"),
+                         ("prefill_ms", "prefill")):
         if field in summary:
             p = summary[field]
             lines.append(f"{label:<14} p50 {p['p50']:.1f} ms   p99 {p['p99']:.1f} ms")
@@ -584,6 +604,12 @@ def format_summary(summary: dict) -> str:
         lines.append(f"blocked/token  {_ms(host['block_ms_per_token'])}  "
                      f"(pipeline depth {host['pipeline_depth']}, "
                      f"wasted {host['wasted_tokens']} tok)")
+        if host.get("fused_tick_share") is not None:
+            lines.append(
+                f"tick kinds     fused {host['fused_tick_share']:.1%} of ticks   "
+                f"blocked {_ms(host['tick_block_ms_fused'])} fused / "
+                f"{_ms(host['tick_block_ms_plain'])} plain   "
+                f"prefill queue {host['prefill_q_depth_mean']} deep")
         if host.get("spec_gamma"):
             acc = host.get("spec_acceptance")
             per = (f"{acc * host['spec_gamma']:.2f}/{host['spec_gamma']}"

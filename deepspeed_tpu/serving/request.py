@@ -72,6 +72,10 @@ class ServeRequest:
     state: str = QUEUED
     submit_t: float = 0.0
     admit_t: Optional[float] = None
+    # the instant the batcher dispatched this request's FIRST prefill work
+    # (its first fused chunk, or the separate / speculative admission
+    # prefill): admit_t -> here is the wait in the batcher's prefill queue
+    prefill_start_t: Optional[float] = None
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
     # the ONE SLO verdict every reporting surface shares (trace event,
@@ -119,6 +123,20 @@ class ServeRequest:
         if self.admit_t is None:
             return None
         return (self.admit_t - self.submit_t) * 1000.0
+
+    def prefill_wait_ms(self) -> Optional[float]:
+        if self.admit_t is None or self.prefill_start_t is None:
+            return None
+        return (self.prefill_start_t - self.admit_t) * 1000.0
+
+    def prefill_ms(self, now: Optional[float] = None) -> Optional[float]:
+        """First prefill dispatch -> first token (``now`` stands in for a
+        first token the finishing tick emitted but ``step()`` has not
+        stamped yet)."""
+        end = self.first_token_t if self.first_token_t is not None else now
+        if self.prefill_start_t is None or end is None:
+            return None
+        return (end - self.prefill_start_t) * 1000.0
 
     def ttft_ms(self) -> Optional[float]:
         if self.first_token_t is None:

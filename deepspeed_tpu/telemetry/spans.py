@@ -20,13 +20,37 @@ module validates against — that module stays loadable by file path, so
 imports only ever point from here to there.
 """
 
+import contextlib
 import itertools
+import sys
 import time
 from typing import Callable, Optional
 
-from deepspeed_tpu.telemetry.timeline import SPAN_KINDS
+from deepspeed_tpu.telemetry.timeline import HOST_SPAN_PREFIX, SPAN_KINDS
 
 _SCOPES = itertools.count()
+
+_trace_annotation = None  # jax.profiler.TraceAnnotation, bound on first use
+_NO_SPAN = contextlib.nullcontext()
+
+
+def host_span(name: str):
+    """A host span in the PROFILER's own trace, ``dstpu:<name>``: the hot
+    loops' program spans (docs/telemetry.md "Span, timestamp and counter
+    catalogue"), on the same clock as the device ops of the xplane they
+    land in. A context manager; with no profiler session on, entering it
+    is a flag test and nothing is recorded, so the sites are unconditional.
+    jax is never imported from here: a process that has not loaded it (the
+    jax-free tools and CI stage) can have no profiler session, and gets a
+    null context."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return _NO_SPAN
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(HOST_SPAN_PREFIX + name)
 
 
 class SpanEmitter:

@@ -17,6 +17,7 @@ from typing import Optional
 
 from deepspeed_tpu.telemetry.config import TelemetryConfig
 from deepspeed_tpu.telemetry.registry import MetricsRegistry
+from deepspeed_tpu.telemetry.timeline import CLOCK_SYNC_PREFIX
 from deepspeed_tpu.telemetry.trace import SCHEMA_VERSION, TraceWriter
 from deepspeed_tpu.utils.logging import logger
 
@@ -146,6 +147,44 @@ class Telemetry:
         return self._peak_flops_per_device
 
     # ------------------------------------------------------------------
+    def start_capture(self, logdir: str):
+        """Start a ``jax.profiler`` capture into ``logdir``: the ONE place
+        a capture starts (``maybe_capture``'s window, ``TpuEngine.
+        start_profile``, the serving loop's tick-indexed window). Host
+        tracing is on and Python-frame tracing off, or the program's
+        ``dstpu:`` spans (``spans.host_span``) would not be recorded and
+        the host loop under the profiler would slow down. Works on a
+        disabled hub (then only the JSONL event is skipped).
+
+        Clock sync: as the capture starts, one zero-length annotation
+        ``dstpu:clock_sync monotonic_ns=<n>`` goes into the xplane and a
+        ``profile_window`` JSONL event carries the same reading, so a reader
+        holding both files can place every JSONL span (``time.monotonic``
+        seconds) on the xplane's axis (``ds_trace_timeline --xplane``)."""
+        import jax.profiler
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(logdir, profiler_options=options)
+        self._profiling = True
+        now_ns = time.monotonic_ns()
+        with jax.profiler.TraceAnnotation(f"{CLOCK_SYNC_PREFIX}{now_ns}"):
+            pass
+        self.emit("profile_window", {"event": "start", "monotonic_ns": now_ns,
+                                     "logdir": os.path.abspath(logdir)})
+
+    def stop_capture(self):
+        """Stop the capture ``start_capture`` began (no-op without one)."""
+        if not self._profiling:
+            return
+        import jax.profiler
+
+        self._profiling = False
+        jax.profiler.stop_trace()
+        self.emit("profile_window", {"event": "stop",
+                                     "monotonic_ns": time.monotonic_ns()})
+
     def maybe_capture(self, step: int):
         """Drive the configured jax.profiler window: start when ``step``
         reaches ``profile_start_step``, stop ``profile_num_steps`` later.
@@ -154,20 +193,13 @@ class Telemetry:
         if not self.enabled or cfg.profile_start_step <= 0:
             return
         try:
-            import jax.profiler
-        except Exception:
-            return
-        try:
             if not self._profiling and step == cfg.profile_start_step:
-                logdir = cfg.profile_dir or os.path.join(
+                self.start_capture(cfg.profile_dir or os.path.join(
                     os.path.dirname(os.path.abspath(cfg.trace_file or ".")),
                     "xla_trace",
-                )
-                jax.profiler.start_trace(logdir)
-                self._profiling = True
+                ))
             elif self._profiling and step >= cfg.profile_start_step + cfg.profile_num_steps:
-                jax.profiler.stop_trace()
-                self._profiling = False
+                self.stop_capture()
         except Exception as e:
             logger.warning(f"telemetry profiler capture failed: {e}")
             self._profiling = False
@@ -190,13 +222,9 @@ class Telemetry:
         return s
 
     def close(self):
-        if self._profiling:
-            try:
-                import jax.profiler
-
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        try:
+            self.stop_capture()
+        except Exception:
             self._profiling = False
         if self._writer is not None:
             self._writer.close()

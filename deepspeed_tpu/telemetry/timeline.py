@@ -25,6 +25,8 @@ from typing import Dict, Iterable, List, Optional
 
 # Every span kind the stack emits. Serving request lifecycle: queue
 # (submit -> handover), admission (the handover/engine-submit work),
+# prefill_wait (handover -> the request's first prefill dispatch: the
+# wait in the batcher's prefill queue, one chunk a tick for the pool),
 # then per-tick windows (prefill_chunk / decode_window /
 # spec_verify_round) from the continuous engine's retire path.
 # Cross-replica: migration (router-emitted, bridges the dead replica's
@@ -35,6 +37,7 @@ from typing import Dict, Iterable, List, Optional
 SPAN_KINDS = (
     "queue",
     "admission",
+    "prefill_wait",
     "prefill_chunk",
     "decode_window",
     "spec_verify_round",
@@ -50,6 +53,7 @@ SPAN_KINDS = (
 SPAN_CATEGORY = {
     "queue": "queue",
     "drain_wait": "queue",
+    "prefill_wait": "queue",
     "admission": "compute",
     "prefill_chunk": "compute",
     "decode_window": "compute",
@@ -60,6 +64,15 @@ SPAN_CATEGORY = {
     "train_retry": "recovery",
     "train_rebuild": "recovery",
 }
+
+# Host spans the program writes into the PROFILER's trace carry this prefix
+# (``spans.host_span``); the capture entry point marks the instant it starts
+# with a zero-length ``dstpu:clock_sync monotonic_ns=<n>`` annotation and a
+# ``profile_window`` JSONL event holding the same ``n`` (``Telemetry.
+# start_capture``), which is what lets the JSONL spans below (monotonic
+# seconds) be placed on the xplane's axis.
+HOST_SPAN_PREFIX = "dstpu:"
+CLOCK_SYNC_PREFIX = "dstpu:clock_sync monotonic_ns="
 
 
 class Span:
@@ -322,3 +335,58 @@ def validate_chrome_trace(doc: dict) -> List[str]:
             if not isinstance(ev.get("dur"), (int, float)) or ev["dur"] < 0:
                 problems.append(f"event {i}: bad dur {ev.get('dur')!r}")
     return problems
+
+
+# -- the profiler's axis -------------------------------------------------
+
+def clock_offset_ns(host_events) -> Optional[int]:
+    """``xplane_ns - monotonic_ns`` from the capture's ``clock_sync``
+    annotation among ``host_events`` ((name, start_ns, dur_ns) rows of the
+    xplane's host plane); None where the capture wrote none (a trace not
+    started through ``Telemetry.start_capture``)."""
+    for name, start_ns, _ in host_events:
+        if name.startswith(CLOCK_SYNC_PREFIX):
+            try:
+                return int(start_ns) - int(name[len(CLOCK_SYNC_PREFIX):])
+            except ValueError:
+                return None
+    return None
+
+
+def place_on_xplane(spans: Iterable["Span"], offset_ns: int) -> List[tuple]:
+    """JSONL spans (monotonic seconds) as xplane rows ``(name, start_ns,
+    dur_ns)``, named ``<kind> <trace_id>``."""
+    return [(f"{s.kind} {s.trace_id}", int(round(s.t0 * 1e9)) + offset_ns,
+             int(round((s.t1 - s.t0) * 1e9))) for s in spans]
+
+
+def blame_idle_gaps(busy: List[tuple], host_events: List[tuple],
+                    min_gap_ns: int = 1_000_000) -> List[dict]:
+    """Every device-idle gap longer than ``min_gap_ns`` between the first
+    and the last of the ``busy`` intervals ((start_ns, end_ns), any order,
+    may overlap), each with the host event ((name, start_ns, dur_ns): the
+    program's ``dstpu:`` spans, and JSONL spans through
+    ``place_on_xplane``) to blame — ``(no span)`` where none overlaps —
+    and the share of the gap that event covers. The blame goes to the
+    NARROWEST event among those covering at least nine tenths of what the
+    best one covers: a request-long ``decode_window`` covers every gap of
+    its lifetime whole and says nothing, the 120 ms ``dstpu:`` span inside
+    it that covers 98 % is what the host was doing."""
+    merged: List[list] = []
+    for s, e in sorted(b for b in busy if b[1] > b[0]):
+        if merged and s <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], e)
+        else:
+            merged.append([s, e])
+    rows = []
+    for (_, g0), (g1, _) in zip(merged, merged[1:]):
+        if g1 - g0 <= min_gap_ns:
+            continue
+        over = [(min(g1, s + d) - max(g0, s), d, name) for name, s, d in host_events]
+        over = [o for o in over if o[0] > 0]
+        most = max((o[0] for o in over), default=0)
+        covered, _, blamed = min((o for o in over if o[0] >= 0.9 * most),
+                                 key=lambda o: o[1], default=(0, 0, "(no span)"))
+        rows.append({"start_ns": g0, "gap_ms": (g1 - g0) / 1e6, "span": blamed,
+                     "covered": covered / (g1 - g0)})
+    return rows

@@ -68,6 +68,17 @@ def _reset_comm_state():
         pass
 
 
+@pytest.fixture(autouse=True)
+def _clear_launcher_env(monkeypatch):
+    """``launcher/mpi_shim.py`` writes the rendezvous variables into
+    ``os.environ`` for the script it execs; a test that drives it in-process
+    leaves them in the xdist worker, and every later ``initialize`` on that
+    worker then tries to join a coordinator that is not there. Cleared
+    before each test, so the suite does not depend on the order it runs in."""
+    for name in ("DSTPU_COORDINATOR", "DSTPU_NUM_PROCESSES", "DSTPU_PROCESS_ID"):
+        monkeypatch.delenv(name, raising=False)
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _clear_jax_caches_per_module():
     """Free compiled executables between modules. A full-suite run holds

@@ -46,6 +46,11 @@ class FakeEngine:
         # ServingEngine runs on, so span times share its domain.
         self.span_hook = None
         self.clock = clock
+        # request lifecycle, mirroring the real engine: ``submit``'s
+        # ``on_prefill_start`` is called once, when the request's prefill
+        # starts; ``prefill_wait_ticks`` > 0 holds an admitted request that
+        # many ticks first (the real engine's one-chunk-a-tick prefill queue)
+        self.prefill_wait_ticks = 0
         # spec accounting knob: gamma > 0 emulates speculative ticks —
         # the TOKEN STREAM is unchanged (still one token/request/tick, so
         # bitwise-resume invariants hold); only drafted/accepted
@@ -81,7 +86,7 @@ class FakeEngine:
         return prompt
 
     def submit(self, prompt_ids, max_new_tokens: int = 32, *,
-               rid=None, gen_base: int = 0) -> int:
+               rid=None, gen_base: int = 0, on_prefill_start=None) -> int:
         prompt = self.validate_request(prompt_ids, max_new_tokens)
         if rid is None:
             rid = self._next_rid
@@ -93,7 +98,8 @@ class FakeEngine:
         self._next_rid = max(self._next_rid, rid + 1)
         self._pending.append({"rid": rid, "prompt": prompt,
                               "max_new": int(max_new_tokens),
-                              "gen_base": int(gen_base), "emitted": []})
+                              "gen_base": int(gen_base), "emitted": [],
+                              "on_prefill_start": on_prefill_start})
         return rid
 
     def register_prefix(self, prefix_ids) -> int:
@@ -105,10 +111,11 @@ class FakeEngine:
     def unregister_prefix(self, pid: int):
         self._prefixes.pop(pid, None)
 
-    def submit_with_prefix(self, pid: int, suffix, max_new_tokens: int) -> int:
+    def submit_with_prefix(self, pid: int, suffix, max_new_tokens: int, *,
+                           on_prefill_start=None) -> int:
         full = np.concatenate([self._prefixes[pid],
                                np.asarray(suffix, np.int32).reshape(-1)])
-        return self.submit(full, max_new_tokens)
+        return self.submit(full, max_new_tokens, on_prefill_start=on_prefill_start)
 
     # -- the tick -------------------------------------------------------
     def pool_state(self):
@@ -134,6 +141,7 @@ class FakeEngine:
         for req in self._pending:
             if len(self._active) < self.slots:
                 req["fresh"] = True  # first tick prefills
+                req["wait"] = self.prefill_wait_ticks
                 self._active[req["rid"]] = req
             else:
                 still.append(req)
@@ -143,6 +151,12 @@ class FakeEngine:
         span_t0 = self.clock() if self.span_hook is not None else 0.0
         g = self.spec_gamma
         for rid, req in self._active.items():
+            if req["wait"] > 0:
+                req["wait"] -= 1
+                continue
+            notify = req.pop("on_prefill_start", None)
+            if notify is not None:
+                notify()
             idx = req["gen_base"] + len(req["emitted"])
             tok = fake_token(rid, idx, self.cfg.vocab_size)
             req["emitted"].append(tok)
@@ -155,8 +169,9 @@ class FakeEngine:
                 self._stats["spec_accepted"] += accepted
                 req["spec_drafted"] = req.get("spec_drafted", 0) + g
                 req["spec_accepted"] = req.get("spec_accepted", 0) + accepted
+            fresh, req["fresh"] = req["fresh"], False
             if self.span_hook is not None:
-                if req.pop("fresh", False):
+                if fresh:
                     kind, attrs = "prefill_chunk", {
                         "ticks": 1, "tokens": int(req["prompt"].size)}
                 elif g:

@@ -221,7 +221,7 @@ def test_profiler_window_is_tick_indexed(setup, tmp_path, monkeypatch):
 
     calls = []
     monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda logdir: calls.append(("start", logdir)))
+                        lambda logdir, **_: calls.append(("start", logdir)))
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: calls.append(("stop", None)))
     cb = _build_cb(setup, tmp_path, name="prof.jsonl",

@@ -47,6 +47,7 @@ JAXFREE_TESTS = [
     "tests/unit/checkpoint/test_checkpoint_integrity.py",
     "tests/unit/serving/test_spans.py",
     "tests/unit/telemetry/test_timeline.py",
+    "tests/unit/telemetry/test_prefill_wait_span.py",
     # ds-perf's text parsers / cost model / inventory diff are stdlib-only
     # by contract (the --diff path must run on hosts without jax)
     "tests/unit/analysis/test_perf_inventory.py",

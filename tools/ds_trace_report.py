@@ -219,7 +219,7 @@ def serve_table(events):
     out = {"finished": len(finished), "shed": shed, "expired": expired,
            "cancelled": cancelled, "requests": total}
     out["shed_rate"] = round((shed + expired) / total, 4) if total else 0.0
-    for fld in ("queue_ms", "ttft_ms"):
+    for fld in ("queue_ms", "prefill_wait_ms", "prefill_ms", "ttft_ms"):
         vals = sorted(float(e[fld]) for e in finished
                       if isinstance(e.get(fld), (int, float))
                       and not isinstance(e.get(fld), bool))
@@ -438,7 +438,9 @@ def format_serve_table(table):
                       if table.get(k))
     lines.append(f"requests          {table['requests']}"
                  + (f"  ({counts})" if counts else ""))
-    for fld, label in (("queue_ms", "queue wait"), ("ttft_ms", "ttft")):
+    for fld, label in (("queue_ms", "queue wait"),
+                       ("prefill_wait_ms", "prefill wait"),
+                       ("prefill_ms", "prefill"), ("ttft_ms", "ttft")):
         if f"{fld}_p50" in table:
             lines.append(f"{label:<17} p50 {_fmt(table[f'{fld}_p50'])} ms"
                          f"   p95 {_fmt(table[f'{fld}_p95'])} ms")

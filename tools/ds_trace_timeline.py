@@ -17,6 +17,18 @@ Usage:
     python tools/ds_trace_timeline.py runs/trace.jsonl --perfetto out.json
     python tools/ds_trace_timeline.py runs/trace.jsonl --trace r0/5 --json
     python tools/ds_trace_timeline.py runs/trace.jsonl --strict  # orphans -> exit 1
+    python tools/ds_trace_timeline.py runs/trace.jsonl --xplane runs/xla_trace
+
+``--xplane`` takes the ``jax.profiler`` capture (its ``.xplane.pb`` or the
+directory it was written under) that ``Telemetry.start_capture`` made
+beside this JSONL: the capture's ``dstpu:clock_sync monotonic_ns=<n>``
+annotation and the JSONL's ``profile_window`` event hold the same clock
+reading, so every JSONL span is placed on the profiler's axis, and each
+device-idle gap over 1 ms is printed with the span (the program's
+``dstpu:`` host spans or a placed JSONL span) to blame: the narrowest of
+those that cover nearly as much of the gap as any does.
+Reading an xplane needs jax (``jax.profiler.ProfileData``); nothing else
+here does.
 
 Deliberately stdlib-only (``telemetry/timeline.py`` is loaded by file
 path, no package import): runs anywhere, including laptops holding
@@ -117,6 +129,83 @@ def format_one(tl):
     return "\n".join(lines) + "\n"
 
 
+def read_xplane(path):
+    """(host events, device busy intervals) of a ``jax.profiler`` capture:
+    the host plane's ``dstpu:`` annotations as (name, start_ns, dur_ns),
+    and every device op's (start_ns, end_ns) — a TPU's ``XLA Ops`` lines,
+    or XLA:CPU's worker threads in a rehearsal."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    if os.path.isdir(path):
+        found = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True))
+        if not found:
+            raise FileNotFoundError(f"no .xplane.pb under {path}")
+        path = found[-1]
+    import warnings
+
+    host, busy = [], []
+    with warnings.catch_warnings():  # an event's stats warn once per read on this jaxlib
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            on_tpu = plane.name.startswith("/device:TPU:")
+            if not on_tpu and plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                if on_tpu:
+                    if line.name == "XLA Ops":
+                        busy += [(int(e.start_ns), int(e.start_ns + e.duration_ns))
+                                 for e in line.events]
+                elif line.name.startswith("tf_"):
+                    busy += [(int(e.start_ns), int(e.start_ns + e.duration_ns))
+                             for e in line.events if "hlo_module" in dict(e.stats)]
+                else:
+                    host += [(e.name, int(e.start_ns), int(e.duration_ns))
+                             for e in line.events if e.name.startswith("dstpu:")]
+    host.sort(key=lambda e: e[1])
+    return host, busy
+
+
+def xplane_report(tm, events, xplane_path):
+    """The ``--xplane`` view as a dict: the clock offset, how it was
+    checked against the JSONL, the placed spans, the blamed idle gaps."""
+    host, busy = read_xplane(xplane_path)
+    offset = tm.clock_offset_ns(host)
+    if offset is None:
+        raise ValueError("the capture holds no dstpu:clock_sync annotation "
+                         "(not started through Telemetry.start_capture?)")
+    sync = next(int(n[len(tm.CLOCK_SYNC_PREFIX):]) for n, _, _ in host
+                if n.startswith(tm.CLOCK_SYNC_PREFIX))
+    windows = [e for e in events if e.get("kind") == "profile_window"
+               and e.get("event") == "start"]
+    placed = tm.place_on_xplane(tm.spans_of(events), offset)
+    program = [e for e in host if not e[0].startswith(tm.CLOCK_SYNC_PREFIX)]
+    gaps = tm.blame_idle_gaps(busy, program + placed)
+    return {
+        "clock_offset_ns": offset,
+        "clock_sync_monotonic_ns": sync,
+        "profile_window_matches": any(w.get("monotonic_ns") == sync for w in windows),
+        "host_spans": len(program),
+        "placed_spans": len(placed),
+        "placed": placed,
+        "idle_gaps": gaps,
+    }
+
+
+def format_xplane(rep):
+    lines = [f"== on the profiler's axis: offset {rep['clock_offset_ns']} ns "
+             f"(clock_sync monotonic_ns={rep['clock_sync_monotonic_ns']}; "
+             f"profile_window event {'matches' if rep['profile_window_matches'] else 'NOT FOUND'}), "
+             f"{rep['host_spans']} dstpu: host spans, {rep['placed_spans']} JSONL spans placed =="]
+    if not rep["idle_gaps"]:
+        lines.append("no device-idle gap over 1 ms")
+    for g in rep["idle_gaps"]:
+        lines.append(f"  idle {_fmt_ms(g['gap_ms']):>10} ms  at {g['start_ns']} ns  "
+                     f"{g['span']} (covers {g['covered']:.0%})")
+    return "\n".join(lines) + "\n"
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="per-request span timelines + Perfetto export from a "
@@ -131,6 +220,10 @@ def main(argv=None):
                          "https://ui.perfetto.dev or chrome://tracing)")
     ap.add_argument("--json", action="store_true", dest="as_json",
                     help="emit summary rows as JSON instead of tables")
+    ap.add_argument("--xplane", metavar="PB_OR_DIR", default=None,
+                    help="the jax.profiler capture made beside this trace: "
+                         "place the JSONL spans on its axis and blame each "
+                         "device-idle gap over 1 ms (needs jax)")
     ap.add_argument("--strict", action="store_true",
                     help="exit 1 if any timeline has orphan spans (CI "
                          "round-trip gate)")
@@ -167,6 +260,17 @@ def main(argv=None):
             n_span_events = sum(1 for e in events if e.get("kind") == "span")
             sys.stdout.write(format_summary(
                 timelines, len(events) - n_span_events))
+
+    if args.xplane is not None:
+        try:
+            rep = xplane_report(tm, events, args.xplane)
+        except (OSError, ValueError) as e:
+            print(f"error: --xplane: {e}", file=sys.stderr)
+            return 2
+        if args.as_json:
+            print(json.dumps({"xplane": rep}, indent=2, sort_keys=True))
+        else:
+            sys.stdout.write(format_xplane(rep))
 
     if args.perfetto is not None:
         doc = tm.to_chrome_trace(timelines)
