@@ -42,8 +42,11 @@ scheduling OVERLAP instead of alternating:
   prompt token is re-fed by the first decode tick, whose logits yield the
   first generated token, keeping every admission dispatch-only.
 - **Donation**: the pool KV cache and the threaded tick state are
-  ``donate_argnums`` operands of every tick program, so per-tick cache
-  copies disappear from HBM traffic.
+  ``donate_argnums`` operands of every tick program, so a tick returns
+  the pool it was given instead of allocating a second one. That saves
+  the allocation; the TRAFFIC is the model's (``forward_with_cache``
+  carries the pool through its layer scan and rewrites in place only the
+  window a tick reads — no layer-sized copy under the loop).
 
 Bucketed KV (VERDICT r4 #9): ``cache_buckets=[(slots, len), ...]``
 partitions the slots into pools with different cache lengths; admission

@@ -526,8 +526,14 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
           -> (packed, cache, last_tok, done)
 
     The cache AND the threaded last_tok/done buffers are donated
-    (``donate_argnums``), so per-tick copies of the KV pool disappear from
-    HBM traffic. ``donate=False`` opts out: the jax CPU backend implements
+    (``donate_argnums``). Donation lets the returned pool BE the donated
+    buffer (no second pool is allocated); what a tick then moves is
+    ``forward_with_cache``'s doing: the pool rides its layer scan's carry
+    and each layer rewrites, in place, the ``read_len`` window it reads —
+    nothing of a layer's or the pool's size is copied (while the pool was
+    the scan's xs / ys, a donated tick still copied every layer's K and V
+    out of the pool and back: PERF.md, PR 24 / PR 25).
+    ``donate=False`` opts out: the jax CPU backend implements
     donation by BLOCKING at dispatch until the donated buffer is free,
     which serializes the tick chain and defeats dispatch-ahead pipelining
     — the virtual-mesh loadgen A/B runs donation-off to measure the

@@ -1175,10 +1175,12 @@ def head_loss_fwd(params, cfg: TransformerConfig, x, batch, denom=None):
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg: TransformerConfig, batch_size: int, max_len: Optional[int] = None):
-    """Per-layer KV cache: (L, B, T, kv_heads, head_dim) in model dtype —
-    or, with ``kv_cache_dtype="int8"``, {"q8": int8, "s": f32 per-token-
-    per-head scales} per component (half the decode-read bytes; the
-    quantized write / dequantized read live in inference_ops)."""
+    """The KV pool, all layers stacked: (L, B, T, kv_heads, head_dim) in
+    model dtype — or, with ``kv_cache_dtype="int8"``, {"q8": int8, "s": f32
+    per-token-per-head scales} per component (half the decode-read bytes;
+    the quantized write / dequantized read live in inference_ops). The
+    layer axis stays unsharded and leading: ``forward_with_cache`` carries
+    the whole pool through its layer scan and indexes it by layer."""
     T = max_len or cfg.max_seq_len
     shape = (cfg.num_layers, batch_size, T, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_cache_dtype == "int8":
@@ -1221,17 +1223,20 @@ def kv_read_bytes_per_row(cfg: TransformerConfig, read_len: int,
     return 2 * cfg.num_layers * read_len * per_slot // tp
 
 
-def _layer_body_cached(x, layer_params, k_cache, v_cache, cfg: TransformerConfig, positions, pos,
-                       window=None, read_len=None):
+def _layer_body_cached(x, layer_params, pool_k, pool_v, layer, cfg: TransformerConfig, positions,
+                       pos, window=None, read_len=None):
     """One decoder layer over a segment of S new tokens with KV cache.
 
-    x: (B, S, D); k_cache/v_cache: (B, T, nkv, hd) for THIS layer; pos: the
+    x: (B, S, D); pool_k/pool_v: the WHOLE stacked (L, B, T, nkv, hd) pool
+    (or its int8 {"q8","s"} form), of which this layer touches ``[layer]``
+    only: it writes the S new tokens in place and reads its own window
+    back, so no layer-sized copy of the pool is ever made. pos: the
     count of tokens already cached — a scalar (all rows aligned: plain
     prefill/decode) or an (B,) vector (rows at different depths: the
     speculative-decode verify/draft path writes each row's segment at its
     own offset). ``read_len`` (static int) tight-reads the cache: attention
     streams only slots [0, read_len) — the caller guarantees it covers
-    every attended position. Returns (x, new_k_cache, new_v_cache).
+    every attended position. Returns (x, pool_k, pool_v).
     """
     attn_p, mlp_p = layer_params["attn"], layer_params["mlp"]
     ln1, ln2 = layer_params["ln1"], layer_params["ln2"]
@@ -1262,10 +1267,12 @@ def _layer_body_cached(x, layer_params, k_cache, v_cache, cfg: TransformerConfig
         and supports_seq_len(S)
     )
     ring = cfg.rolling_kv_cache
+    if ring:
+        read_len = None  # the ring is already O(window): no tight reads
 
     with jax.named_scope(Scope.ATTN_KV_WRITE):
-        k_cache, v_cache = update_kv_cache(k_cache, v_cache, k, v, pos, positions,
-                                           ring=ring)
+        pool_k, pool_v = update_kv_cache(pool_k, pool_v, k, v, pos, positions, ring=ring,
+                                         layer=layer, write_len=read_len)
 
     if use_flash_prefill:
         w = window if isinstance(window, int) and window > 0 and window < S else None
@@ -1273,9 +1280,9 @@ def _layer_body_cached(x, layer_params, k_cache, v_cache, cfg: TransformerConfig
             attn_out = _flash_sharded(q, k, v, cfg, causal=True,
                                       window=w).reshape(B, S, nh * hd)
         attn_out = _attn_out_proj(attn_out, attn_p, cfg)
-        return _finish_layer_cached(x, h, attn_out, layer_params, cfg, k_cache, v_cache)
+        return _finish_layer_cached(x, h, attn_out, layer_params, cfg), pool_k, pool_v
 
-    cache_T = (k_cache["q8"] if isinstance(k_cache, dict) else k_cache).shape[1]
+    cache_T = cache_alloc_len(pool_k)
     assert not (ring and S > 1 and cache_T < S), (
         "rolling KV cache: a multi-token segment longer than the ring must "
         f"take the flash prefill path (S={S}, cache={cache_T}) — a segment "
@@ -1283,15 +1290,14 @@ def _layer_body_cached(x, layer_params, k_cache, v_cache, cfg: TransformerConfig
         "gates cache sizing on this")
     slopes = _alibi_slopes(nh) if cfg.pos_embedding == "alibi" else None
     attn_out = softmax_context(
-        q, k_cache, v_cache, pos, scale=cfg.attn_scale, positions=positions,
-        alibi_slopes=slopes, local_window=window, ring=ring,
-        read_len=read_len if not ring else None,
+        q, pool_k, pool_v, pos, scale=cfg.attn_scale, positions=positions,
+        alibi_slopes=slopes, local_window=window, ring=ring, read_len=read_len, layer=layer,
     ).reshape(B, S, nh * hd)
     attn_out = _attn_out_proj(attn_out, attn_p, cfg)
-    return _finish_layer_cached(x, h, attn_out, layer_params, cfg, k_cache, v_cache)
+    return _finish_layer_cached(x, h, attn_out, layer_params, cfg), pool_k, pool_v
 
 
-def _finish_layer_cached(x, h, attn_out, layer_params, cfg: TransformerConfig, k_cache, v_cache):
+def _finish_layer_cached(x, h, attn_out, layer_params, cfg: TransformerConfig):
     """Residual topology + MLP tail of a cached layer (shared by the einsum
     and flash-prefill attention paths)."""
     mlp_p = layer_params["mlp"]
@@ -1300,17 +1306,17 @@ def _finish_layer_cached(x, h, attn_out, layer_params, cfg: TransformerConfig, k
     if cfg.parallel_residual:
         h2 = h if cfg.shared_ln else _norm(x, ln2["scale"], ln2.get("bias"), cfg)
         mlp_out, _ = _mlp_block(h2, mlp_p, cfg, decode=True)
-        return x + attn_out + mlp_out, k_cache, v_cache
+        return x + attn_out + mlp_out
 
     if cfg.norm_position == "pre":
         x = x + attn_out
         h = _norm(x, ln2["scale"], ln2.get("bias"), cfg)
         mlp_out, _ = _mlp_block(h, mlp_p, cfg, decode=True)
-        return x + mlp_out, k_cache, v_cache
+        return x + mlp_out
 
     x = _norm(x + attn_out, ln1["scale"], ln1.get("bias"), cfg)
     mlp_out, _ = _mlp_block(x, mlp_p, cfg, decode=True)
-    return _norm(x + mlp_out, ln2["scale"], ln2.get("bias"), cfg), k_cache, v_cache
+    return _norm(x + mlp_out, ln2["scale"], ln2.get("bias"), cfg)
 
 
 def forward_with_cache(params, cfg: TransformerConfig, tokens, cache, pos, positions=None,
@@ -1323,7 +1329,13 @@ def forward_with_cache(params, cfg: TransformerConfig, tokens, cache, pos, posit
     length, so their KV writes drop out of bounds and real tokens pack
     densely per row (requires vector ``pos``). ``read_len`` (static int)
     tight-reads the cache time axis — attention streams slots
-    [0, read_len) only; the caller guarantees the active extent fits.
+    [0, read_len) only, and the write touches no slot beyond them; the
+    caller guarantees the active extent (the new tokens included) fits.
+
+    The pool travels in the layer scan's CARRY, never as its xs / ys: each
+    layer updates ``[layer]`` in place (inference_ops.update_kv_cache) and
+    reads its window straight back (softmax_context), so with the cache
+    donated a call moves the windows it reads and no layer-sized copy.
     Returns (logits (B,S,V), updated cache)."""
     dtype = cfg.jnp_dtype
     B, S = tokens.shape
@@ -1361,18 +1373,23 @@ def forward_with_cache(params, cfg: TransformerConfig, tokens, cache, pos, posit
         if varying else jnp.zeros((cfg.num_layers,), jnp.int32)
     )
 
+    # the pool rides the CARRY: a carried buffer is updated in place, layer
+    # after layer. A scanned input (xs) is read-only and a stacked output
+    # (ys) a fresh buffer, so as xs / ys XLA has to copy each layer's whole
+    # (B, T, H, hd) K and V out of the pool and back, donated or not
     def body(carry, inp):
-        h = carry
-        layer_p, k_c, v_c, win = inp
+        h, pool_k, pool_v = carry
+        layer_p, layer, win = inp
         win = win if varying else uniform_w
-        h, k_c, v_c = _layer_body_cached(h, layer_p, k_c, v_c, cfg, positions, pos,
-                                         window=win, read_len=read_len)
-        return h, (k_c, v_c)
+        return _layer_body_cached(h, layer_p, pool_k, pool_v, layer, cfg, positions, pos,
+                                  window=win, read_len=read_len), None
 
-    x, (new_k, new_v) = jax.lax.scan(body, x, (layers, cache["k"], cache["v"], windows))
+    layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    (x, pool_k, pool_v), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]), (layers, layer_ids, windows))
     if cfg.norm_position == "pre":
         x = _norm(x, params["final_norm"]["scale"], params["final_norm"].get("bias"), cfg)
-    return _vocab_head(x, params, cfg, dtype), {"k": new_k, "v": new_v}
+    return _vocab_head(x, params, cfg, dtype), {"k": pool_k, "v": pool_v}
 
 
 def loss_fn(params, cfg: TransformerConfig, batch, rng=None, ltd_keep_len=None, pld_theta=None):

@@ -75,66 +75,113 @@ def dequantize_kv(cache_component, dtype):
     return (cache_component["q8"].astype(jnp.float32) * cache_component["s"]).astype(dtype)
 
 
-def slice_kv_time(cache_component, read_len: Optional[int]):
-    """First ``read_len`` time slots of a cache component (dense
-    (B, T, H, hd) array or int8 {"q8","s"} pair). ``read_len`` is a static
-    python int, so the slice is a static-shape view — the attention
-    contraction downstream only ever touches those bytes in HBM (the
-    tight-read geometry: decode reads the bucketed active length, not the
-    full allocation)."""
-    if read_len is None:
-        return cache_component
-    if isinstance(cache_component, dict):
-        return {"q8": cache_component["q8"][:, :read_len],
-                "s": cache_component["s"][:, :read_len]}
-    return cache_component[:, :read_len]
+def kv_window(cache_component, read_len: Optional[int] = None, layer=None):
+    """First ``read_len`` (default: all) time slots of a cache component, as
+    a per-layer (B, read_len, H, x) component (dense array or int8
+    {"q8","s"} pair). ``layer`` None: the component is one layer's
+    (B, T, H, x); else it is the stacked (L, B, T, H, x) pool and
+    ``[layer]``'s window comes straight out of it in one ``dynamic_slice``.
+    ``read_len`` is a static python int, so the window is static-shape and
+    no longer than what attention reads (the tight-read geometry: decode
+    reads the bucketed active length, not the full allocation)."""
+    def window(c):
+        if layer is None:
+            return c if read_len is None else c[:, :read_len]
+        _, B, T, H, x = c.shape
+        return jax.lax.dynamic_slice(
+            c, (layer, 0, 0, 0, 0), (1, B, read_len or T, H, x))[0]
+
+    return jax.tree.map(window, cache_component)
 
 
-def _write_component(cache, new, pos, positions, ring=False):
-    if ring:
-        # ring-buffer write: slot = absolute position mod cache length.
-        # Stale tokens of an over-long segment (more new tokens than
-        # slots) drop instead of colliding: only the last T positions of
-        # the segment land, later tokens must win.
-        T = cache.shape[1]
-        assert jnp.ndim(pos) == 0, "ring cache writes need the aligned (scalar-pos) path"
-        total = pos + new.shape[1]
-        rows = jnp.arange(new.shape[0], dtype=jnp.int32)[:, None]
-        cols = jnp.where(positions >= total - T, positions % T, T)
-        return cache.at[rows, cols].set(new.astype(cache.dtype), mode="drop")
-    if jnp.ndim(pos) == 0:
-        return jax.lax.dynamic_update_slice(cache, new.astype(cache.dtype), (0, pos, 0, 0))
-    rows = jnp.arange(new.shape[0], dtype=jnp.int32)[:, None]
-    cols = positions  # (B, S) absolute positions of the new tokens
-    return cache.at[rows, cols].set(new.astype(cache.dtype), mode="drop")
+def _write_columns(T, new_shape, pos, positions, ring):
+    """Cache column (B, S) each new token lands in; a column outside the
+    cache drops its token (the ONE drop rule of every write)."""
+    B, S = new_shape[:2]
+    if positions is None:
+        positions = jnp.reshape(pos, (-1, 1)) + jnp.arange(S, dtype=jnp.int32)[None, :]
+    positions = jnp.broadcast_to(positions, (B, S))
+    if not ring:
+        return positions
+    # ring-buffer write: slot = absolute position mod cache length.
+    # Stale tokens of an over-long segment (more new tokens than
+    # slots) drop instead of colliding: only the last T positions of
+    # the segment land, later tokens must win.
+    assert jnp.ndim(pos) == 0, "ring cache writes need the aligned (scalar-pos) path"
+    return jnp.where(positions >= pos + S - T, positions % T, T)
 
 
-def update_kv_cache(k_cache, v_cache, k_new, v_new, pos,
-                    positions=None, ring=False) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _place(window, new, cols):
+    """``window`` (B, R, H, x) with ``new`` (B, S, H, x) at columns ``cols``
+    (B, S): a one-hot contraction lays the tokens out along R and a select
+    merges them in, so no index is dynamic along the time axis — the TPU
+    keeps the KV pool time-minor, where a token-sized scatter or
+    ``dynamic_update_slice`` makes the compiler re-lay out the whole pool.
+    Exact (one term a slot); a row's in-window columns are distinct. A
+    single token needs no contraction, and without one the TPU compiler
+    fuses slice, select and update into one in-place pass."""
+    hit = cols[:, None, :] == jnp.arange(window.shape[1], dtype=cols.dtype)[None, :, None]
+    placed = new.astype(window.dtype)  # S == 1: the one token, wherever its column hits
+    if new.shape[1] > 1:
+        acc = jnp.int32 if jnp.issubdtype(placed.dtype, jnp.integer) else jnp.float32
+        placed = jnp.einsum("brs,bshx->brhx", hit.astype(placed.dtype), placed,
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=acc).astype(window.dtype)
+    return jnp.where(hit.any(-1)[:, :, None, None], placed, window)
+
+
+def _write_component(cache, new, pos, positions, ring=False, layer=None, write_len=None):
+    """S new tokens into one cache array: a layer's (B, T, H, x) cache
+    (``layer`` None), or — in place, touching ``[layer]``'s first
+    ``write_len`` slots only — the stacked (L, B, T, H, x) pool."""
+    if isinstance(pos, int) and not ring:
+        # static offset (the prefill program): the S tokens and nothing else
+        new = new.astype(cache.dtype)
+        if layer is None:
+            return jax.lax.dynamic_update_slice(cache, new, (0, pos, 0, 0))
+        return jax.lax.dynamic_update_slice(cache, new[None], (layer, 0, pos, 0, 0))
+    cols = _write_columns(cache.shape[-3], new.shape, pos, positions, ring)
+    if layer is None:
+        return _place(cache, new, cols)
+    window = _place(kv_window(cache, write_len, layer), new, cols)
+    return jax.lax.dynamic_update_slice(cache, window[None], (layer, 0, 0, 0, 0))
+
+
+def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, positions=None, ring=False,
+                    layer=None, write_len=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Write S new keys/values into (B, T, H, hd) caches (or int8
     {"q8","s"} cache components — the write quantizes per token/head).
 
     ``pos`` scalar: contiguous write at offset pos (plain prefill/decode).
-    ``pos`` (B,) vector with ``positions`` (B, S): per-row scatter — the
-    speculative-decode verify/draft path writes each row's segment at its
-    own depth; out-of-bounds columns (>= T) are dropped, matching the
-    clamped read mask in :func:`softmax_context`.
+    ``pos`` (B,) vector with ``positions`` (B, S): per-row write — the
+    speculative-decode verify/draft path and the serving tick write each
+    row's segment at its own depth; out-of-bounds columns (>= T) are
+    dropped, matching the clamped read mask in :func:`softmax_context`.
     ``ring``: rolling-cache mode (sliding-window models) — positions wrap
     modulo the cache length; requires scalar ``pos`` + ``positions``.
+    ``layer`` (i32 scalar): the caches are the stacked (L, B, T, H, hd)
+    pool, updated in place at ``[layer]`` — the form the model's layer scan
+    carries, so a step never copies a layer out of the pool. ``write_len``
+    (static int, the step's ``read_len``) then bounds the slots the write
+    touches: columns at or beyond it drop too, which loses nothing because
+    ``read_len`` covers every live position, the new tokens' included.
     """
+    def component(cache, new):
+        return _write_component(cache, new, pos, positions, ring, layer, write_len)
+
     def write(cache, new):
         if isinstance(cache, dict):
             q, s = quantize_kv(new)
-            return {"q8": _write_component(cache["q8"], q, pos, positions, ring),
-                    "s": _write_component(cache["s"], s, pos, positions, ring)}
-        return _write_component(cache, new, pos, positions, ring)
+            return {"q8": component(cache["q8"], q), "s": component(cache["s"], s)}
+        return component(cache, new)
 
     return write(k_cache, k_new), write(v_cache, v_new)
 
 
 def softmax_context(q, k_cache, v_cache, pos, scale: Optional[float] = None,
                     positions=None, alibi_slopes=None, local_window=None,
-                    ring=False, read_len: Optional[int] = None) -> jnp.ndarray:
+                    ring=False, read_len: Optional[int] = None,
+                    layer=None) -> jnp.ndarray:
     """Cached masked attention (softmax_context binding): q (B, S, nh, hd)
     against (B, T, nkv, hd) caches (GQA repeat applied here).
 
@@ -160,13 +207,14 @@ def softmax_context(q, k_cache, v_cache, pos, scale: Optional[float] = None,
     below it; the masked tail beyond the active length contributes exact
     zeros, so logits match the full-length read. Incompatible with ring
     (the ring is already O(window)).
+    ``layer`` (i32 scalar): the caches are the stacked (L, B, T, nkv, hd)
+    pool; ``[layer]``'s window is read straight from it (:func:`kv_window`).
     """
     B, S, nh, hd = q.shape
     with jax.named_scope(Scope.ATTN_KV_READ):
-        if read_len is not None:
-            assert not ring, "tight reads do not apply to the rolling (ring) cache"
-            k_cache = slice_kv_time(k_cache, read_len)
-            v_cache = slice_kv_time(v_cache, read_len)
+        assert read_len is None or not ring, "tight reads do not apply to the rolling (ring) cache"
+        k_cache = kv_window(k_cache, read_len, layer)
+        v_cache = kv_window(v_cache, read_len, layer)
         if isinstance(k_cache, dict):  # int8 KV cache: dequant at the read
             k_cache = dequantize_kv(k_cache, q.dtype)
             v_cache = dequantize_kv(v_cache, q.dtype)

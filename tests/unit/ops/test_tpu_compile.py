@@ -11,6 +11,7 @@ times. Skipped where libtpu cannot describe the topology.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -144,3 +145,50 @@ def test_compiles_for_v5e(topo, case):
     resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert resident < HBM_BYTES, (case, resident)
+
+
+@pytest.mark.parametrize("read_len,chunk", [(256, None), (None, None), (512, 128)],
+                         ids=["plain-read256", "plain-read1024", "fused128-read512"])
+def test_serving_tick_updates_the_kv_pool_in_place(topo, read_len, chunk):
+    """The gpt2-xl serving tick (16 slots x 1024, the benchmark's batch
+    cell) for the chip: the chip lays the pool bf16[48,16,1024,25,64] out
+    TIME-minor (heads-minor would pad (25, 64) 2.6x), and a token-sized
+    scatter or ``dynamic_update_slice`` on the carried pool makes the
+    compiler turn the whole pool heads-minor and back — pool-sized copies
+    and 12 GB of temporaries. The tick must compile to an in-place update:
+    no ``copy`` of the pool's or a layer's shape, the pool aliased to the
+    output, temporaries far under the pool's 5 GB."""
+    from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
+    from deepspeed_tpu.models import transformer as tf
+
+    slots, length = 16, 1024
+    mesh = comm.build_mesh({"data": 1, "tensor": 1}, devices=topo.devices[:1])
+    one = NamedSharding(mesh, PartitionSpec())
+    model = tf.TransformerModel.from_preset("gpt2-1.5b", dtype="bfloat16", max_seq_len=length,
+                                            attn_impl="pallas")
+    cfg = model.cfg
+    abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    p_sh = jax.tree.map(lambda a: one, abstract)
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one),
+                          abstract)
+    with force_interpret(False):
+        fn, cache_sh, _ = compile_pool_tick_fn(mesh, cfg, p_sh, slots, length, 1, 0.0, 0, 1.0,
+                                               read_len=read_len, chunk=chunk)
+        cache = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            jax.eval_shape(lambda: tf.init_cache(cfg, slots, length)), cache_sh)
+        row = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+        args = [params, cache, row, row, row, row, row, row,
+                jax.ShapeDtypeStruct((2,), jnp.uint32)]
+        if chunk is not None:
+            wide = jax.ShapeDtypeStruct((chunk,), jnp.int32)
+            args += [wide, wide, jax.ShapeDtypeStruct((), jnp.int32), row, row]
+        compiled = fn.lower(*args).compile()
+    comm.destroy()
+    pool_bytes = 2 * cfg.num_layers * slots * length * cfg.kv_heads * cfg.head_dim * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes / 8, mem.temp_size_in_bytes
+    kv = rf"{slots},{length},{cfg.kv_heads},{cfg.head_dim}\]"
+    copies = re.findall(rf"= bf16\[(?:\d+,)?{kv}\S* copy\(", compiled.as_text())
+    assert not copies, copies
