@@ -239,6 +239,10 @@ def run_cell(manifest_path, workload, seed, seconds, trace, *, require_tpu=True,
     if trace:
         line["breakdown"] = dict(device_ops=R.top_ops(tr, 10, window),
                                  idle_gaps=R.idle_gaps(tr, 10, window))
+    # each number compared beside its limit, as the last line of standard error too: where a
+    # run is not correct, the end of that stream is what the driver's record keeps
+    print(json.dumps(dict(phase="compare", correct=line["correct"], **verdict["fields"]),
+                     default=float), file=sys.stderr, flush=True)
     return line
 
 

@@ -1,4 +1,24 @@
-"""Configuration file -> the program's model object."""
+"""Configuration file -> the program's model object: the builder of GPT-2's
+family, and the default for a configuration file that names no ``builder``.
+What a builder module gives is written down in ``benchmark/README.md``."""
+
+import math
+
+REQUIRED_SIZES = ("n_embd", "n_layer", "n_head", "n_positions", "vocab_size")
+
+
+def sharpen_attention(params, n_layers, query_scale):
+    """Seed weights of the program's ``TransformerModel`` rescaled so that
+    the context decides the next token: queries x ``query_scale``, and init's
+    1/sqrt(2L) on the attention output undone (PERF.md section 6)."""
+    attn = params["layers"]["attn"]
+    attn["wq"] = attn["wq"] * query_scale
+    attn["wo"] = attn["wo"] * math.sqrt(2 * n_layers)
+    return params
+
+
+def sharpen(params, config, query_scale):
+    return sharpen_attention(params, config["model"]["n_layer"], query_scale)
 
 
 def build_model(config, *, max_seq_len, remat, attn_impl):

@@ -34,37 +34,49 @@ import jax.numpy as jnp
 F32 = jnp.float32
 
 
+def arch(config):
+    """What the functions below take as ``n_heads``: all they need of the
+    configuration file that the parameter tree does not say."""
+    return int(config["model"]["n_head"])
+
+
 def _norm(x, w):
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
     return (x - mu) / jnp.sqrt(var + 1e-5) * w["scale"].astype(F32) + w["bias"].astype(F32)
 
 
-def _block(n_heads):
+def _as_is(x):
+    return x
+
+
+def _block(n_heads, operand=_as_is):
+    r = operand  # what every matmul's two operands go through: nothing, but for a control
+
     def block(x, w):
         w = jax.tree.map(lambda a: a.astype(F32), w)
         B, S, D = x.shape
         hd = D // n_heads
         a, m = w["attn"], w["mlp"]
-        h = _norm(x, w["ln1"])
-        q, k, v = ((h @ a["w" + n] + a["b" + n]).reshape(B, S, n_heads, hd) for n in "qkv")
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
+        h = r(_norm(x, w["ln1"]))
+        q, k, v = ((h @ r(a["w" + n]) + a["b" + n]).reshape(B, S, n_heads, hd) for n in "qkv")
+        s = jnp.einsum("bqhd,bkhd->bhqk", r(q), r(k)) / math.sqrt(hd)
         causal = jnp.tril(jnp.ones((S, S), bool))
         s = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-        x = x + jnp.einsum("bhqk,bkhd->bqhd", s, v).reshape(B, S, D) @ a["wo"] + a["bo"]
-        u = _norm(x, w["ln2"]) @ m["wi"] + m["bi"]
+        x = x + r(jnp.einsum("bhqk,bkhd->bqhd", r(s), r(v)).reshape(B, S, D)) @ r(a["wo"]) + a["bo"]
+        u = r(_norm(x, w["ln2"])) @ r(m["wi"]) + m["bi"]
         u = 0.5 * u * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi) * (u + 0.044715 * u ** 3)))
-        return x + u @ m["wo"] + m["bo"], None
+        return x + r(u) @ r(m["wo"]) + m["bo"], None
 
     return block
 
 
-def hidden(params, tokens, n_heads, remat=False):
+def hidden(params, tokens, n_heads, remat=False, operand=_as_is):
     """tokens (B, S) -> final-normed hidden states (B, S, D), float32."""
     S = tokens.shape[1]
     tok, pos = params["embed"]["tok"], params["embed"]["pos"]
     x = tok[tokens].astype(F32) + pos[:S].astype(F32)
-    block = _block(n_heads)
+    block = _block(n_heads, operand)
     x, _ = jax.lax.scan(jax.checkpoint(block) if remat else block, x, params["layers"])
     return _norm(x, params["final_norm"])
 
@@ -75,12 +87,14 @@ def logits_at(params, tokens, at, n_heads):
     return x @ params["embed"]["tok"].astype(F32).T
 
 
-def loss_sum(params, tokens, n_heads, weights=None):
+def loss_sum(params, tokens, n_heads, weights=None, operand=_as_is):
     """Summed next-token cross-entropy of ``tokens`` (B, S) over its
     B * (S - 1) predicted positions; with ``weights`` (B,), also the sum in
-    which each row counts by its weight: (weighted, plain)."""
-    x = hidden(params, tokens, n_heads, remat=True)[:, :-1]
-    logits = x @ params["embed"]["tok"].astype(F32).T
+    which each row counts by its weight: (weighted, plain). ``operand`` is
+    applied to both operands of every matmul: the lower-precision control
+    hands in its rounding (``compare.fp8``), and nobody else anything."""
+    x = hidden(params, tokens, n_heads, remat=True, operand=operand)[:, :-1]
+    logits = operand(x) @ operand(params["embed"]["tok"].astype(F32).T)
     picked = jnp.take_along_axis(logits, tokens[:, 1:, None], axis=2)[..., 0]
     nll = (jax.nn.logsumexp(logits, axis=-1) - picked).sum(-1)
     if weights is None:
@@ -88,9 +102,12 @@ def loss_sum(params, tokens, n_heads, weights=None):
     return (nll * weights).sum(), nll.sum()
 
 
-def loss_and_grads(params, tokens, n_heads, rows_per_pass, skip_rows=0, row_sharding=None):
+def loss_and_grads(params, tokens, n_heads, rows_per_pass, skip_rows=0, row_sharding=None,
+                   loss_sum=loss_sum):
     """Mean loss of the batch and its gradient, ``rows_per_pass`` rows at a
-    time (``row_sharding`` spreads a pass's rows over the chips).
+    time (``row_sharding`` spreads a pass's rows over the chips). The passes
+    know nothing of the model: another family's reference hands in its own
+    ``loss_sum`` and shares them.
     ``skip_rows`` > 0 is a fault for the negative controls: the last
     ``skip_rows`` rows' gradients are left out of the sum, while the loss and
     the count they are divided by stay whole, as after a reduce-scatter that
@@ -138,10 +155,15 @@ FAULTS = ("grads_scaled", "shard_left_out", "double_update")
 
 
 def train(params, tokens, n_heads, steps, optimizer, rows_per_pass, fault=None,
-          out_shardings=None, row_sharding=None):
+          out_shardings=None, row_sharding=None, loss_and_grads=loss_and_grads,
+          norm=global_norm):
     """``steps`` optimizer steps on the one batch ``tokens``. Returns
     (losses[steps], grad_norms[steps]): the loss BEFORE each update and the
-    global norm of its gradient. ``params`` is not donated or changed.
+    global norm of its gradient (or whatever ``norm`` makes of the gradient:
+    the comparison leaf by leaf hands in its own). ``params`` is not donated
+    or changed.
+    Another family's reference hands in its own ``loss_and_grads`` and
+    shares the optimizer loop and the faults.
 
     ``fault`` makes a wrong trainer for the negative controls:
     ``grads_scaled`` multiplies every gradient by 4 (a sum over four chips
@@ -161,9 +183,9 @@ def train(params, tokens, n_heads, steps, optimizer, rows_per_pass, fault=None,
                                  row_sharding=row_sharding)
         if fault == "grads_scaled":
             g = jax.tree.map(lambda x: 4.0 * x, g)
-        norm = global_norm(g)
+        recorded = norm(g)
         p, m, v = adamw(p, g, m, v, step, **hyper)
-        return p, m, v, loss, norm
+        return p, m, v, loss, recorded
 
     sh = out_shardings
     step_jit = jax.jit(step_fn, donate_argnums=(1, 2),
@@ -173,7 +195,7 @@ def train(params, tokens, n_heads, steps, optimizer, rows_per_pass, fault=None,
     p, m, v = params, zeros(params), zeros(params)
     losses, norms = [], []
     for i in range(steps):
-        p, m, v, loss, norm = step_jit(p, m, v, jnp.asarray(i + 1, F32), tokens)
+        p, m, v, loss, recorded = step_jit(p, m, v, jnp.asarray(i + 1, F32), tokens)
         losses.append(loss)
-        norms.append(norm)
-    return [float(x) for x in losses], [float(x) for x in norms]
+        norms.append(recorded)
+    return [float(x) for x in losses], [jax.tree.map(float, x) for x in norms]

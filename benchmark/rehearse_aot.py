@@ -17,6 +17,7 @@ sharding policy, because ``initialize`` places weights and cannot run on
 described devices.
 """
 
+import functools
 import json
 import os
 import sys
@@ -31,9 +32,8 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from benchmark import compare, models  # noqa: E402
+from benchmark import compare  # noqa: E402
 from benchmark.harness import load_json  # noqa: E402
-from benchmark.reference import gpt2  # noqa: E402
 
 GB = 1e9
 LIMIT = 15.75 * 2 ** 30  # bytes_limit of one v5e chip as memory_stats() reports it: 16.91 GB
@@ -80,8 +80,10 @@ def train_cell(topo, workload, micro_batches=None):
     devices = topo.devices[:chips]
     mesh = comm.build_mesh({"fsdp": chips}, devices=devices)
     comm.set_mesh(mesh)
-    model = models.build_model(config, max_seq_len=t["seq"], remat=t["remat"],
-                               attn_impl=t["attn_impl"])
+    model = compare.builder_of(config).build_model(config, max_seq_len=t["seq"], remat=t["remat"],
+                                                   attn_impl=t["attn_impl"])
+    reference = compare.reference_of(config)
+    arch = reference.arch(config)
     abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     policy = ShardingPolicy(mesh, stage=t["zero_stage"], logical_specs=model.logical_specs(abstract))
     p_sh, g_sh = policy.param_shardings(abstract), policy.grad_shardings(abstract)
@@ -110,21 +112,36 @@ def train_cell(topo, workload, micro_batches=None):
 
     # the reference trains once the engine is released: it has the chip to itself
     r_sh, batch_sh = compare.reference_shardings(abstract, devices)
-    rows = t["micro_batch_per_chip"] * chips
+    tol = dict(config["compare"]["train"], **t.get("compare", {}))
+    distinct = tol.get("micro_batches") == "distinct"
+    rows = t["micro_batch_per_chip"] * chips * (t["gradient_accumulation_steps"] if distinct else 1)
     toks = jax.ShapeDtypeStruct((rows, t["seq"]), jnp.int32, sharding=batch_sh)
     opt = dict(lr=1e-4, beta1=0.9, beta2=0.999, eps=1e-8, weight_decay=0.01)
+    norm = reference.global_norm
+    if compare.by_leaf(tol):  # the first gradient leaf by leaf: the engine's first moment, then
+        # the reference's gradient inside its step
+        compiled = jax.jit(compare.leaf_readings).lower(sds(abstract, jnp.float32, o_sh)).compile()
+        report(f"{workload}: leaf readings of the engine's first moment", compiled, beside=state)
+        norm = lambda g: (reference.global_norm(g), compare.leaf_readings(g))
 
-    def ref_fn(p, m, v, step, tokens):
-        loss, g = gpt2.loss_and_grads(p, tokens, model.cfg.num_heads, t["reference_rows_per_pass"],
-                                      row_sharding=batch_sh if chips > 1 else None)
-        return gpt2.adamw(p, g, m, v, step, **opt) + (loss, gpt2.global_norm(g))
+    def ref_fn(operand):
+        def step(p, m, v, step, tokens):
+            hook = {} if operand is None else dict(
+                loss_sum=functools.partial(reference.loss_sum, operand=operand))
+            loss, g = reference.loss_and_grads(p, tokens, arch, t["reference_rows_per_pass"],
+                                               row_sharding=batch_sh if chips > 1 else None, **hook)
+            return reference.adamw(p, g, m, v, step, **opt) + (loss, norm(g))
+
+        return step
 
     f32 = sds(abstract, jnp.float32, r_sh)
-    with jax.default_matmul_precision("highest"):
-        compiled = jax.jit(ref_fn, donate_argnums=(1, 2), out_shardings=(r_sh, r_sh, r_sh, None, None)).lower(
-            f32, f32, f32, jax.ShapeDtypeStruct((), jnp.float32), toks).compile()
-    report(f"{workload}: float32 reference train step, {t['reference_rows_per_pass']} rows a pass",
-           compiled, note="runs after the window, with the engine released")
+    for operand, name in ((None, "float32 reference"), (compare.fp8, "its fp8 control")):
+        with jax.default_matmul_precision("highest"):
+            compiled = jax.jit(ref_fn(operand), donate_argnums=(1, 2),
+                               out_shardings=(r_sh, r_sh, r_sh, None, None)).lower(
+                f32, f32, f32, jax.ShapeDtypeStruct((), jnp.float32), toks).compile()
+        report(f"{workload}: {name} train step, {rows} rows, {t['reference_rows_per_pass']} a pass",
+               compiled, note="runs after the window, with the engine released")
 
 
 def serve_cell(topo, workload, slot_counts=None):
@@ -139,7 +156,9 @@ def serve_cell(topo, workload, slot_counts=None):
 
     mesh = comm.build_mesh({"data": 1, "tensor": 1}, devices=topo.devices[:1])
     one = NamedSharding(mesh, P())
-    model = models.build_model(config, max_seq_len=s["cache_len"], remat=False, attn_impl=s["attn_impl"])
+    model = compare.builder_of(config).build_model(config, max_seq_len=s["cache_len"], remat=False,
+                                                   attn_impl=s["attn_impl"])
+    reference = compare.reference_of(config)
     cfg = model.cfg
     abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     p_sh = jax.tree.map(lambda a: one, abstract)
@@ -170,9 +189,9 @@ def serve_cell(topo, workload, slot_counts=None):
     sample = int(config["compare"]["serve"]["sample"])
     new_max = int(traffic["output_tokens"]["max"])
     with jax.default_matmul_precision("highest"):
-        compiled = jax.jit(gpt2.logits_at, static_argnums=3).lower(
+        compiled = jax.jit(reference.logits_at, static_argnums=3).lower(
             params, jax.ShapeDtypeStruct((sample, s["cache_len"]), jnp.int32),
-            jax.ShapeDtypeStruct((sample, new_max), jnp.int32), cfg.num_heads).compile()
+            jax.ShapeDtypeStruct((sample, new_max), jnp.int32), reference.arch(config)).compile()
     report(f"{workload}: float32 reference forward, {sample} requests x {s['cache_len']}", compiled,
            note="runs after the window with the KV pool released; the bf16 weights are its argument")
 

@@ -20,18 +20,20 @@ import time
 
 import numpy as np
 
-from benchmark import compare, models, trafficgen
+from benchmark import compare, trafficgen
 from benchmark.harness import BenchmarkError, span
 
 clock = time.monotonic  # the serving engine's own clock: its admit times compare with ours
 
 
 class Record:
-    __slots__ = ("request", "due", "submitted", "rid", "times", "state", "admit", "tokens")
+    __slots__ = ("request", "due", "submitted", "rid", "times", "state", "admit", "tokens",
+                 "prefill_start", "first_token")
 
     def __init__(self, request, due):
         self.request, self.due = request, due
         self.submitted = self.rid = self.admit = self.state = self.tokens = None
+        self.prefill_start = self.first_token = None
         self.times = []
 
 
@@ -78,10 +80,13 @@ class Runner:
         comm.destroy()
         seed = ctx["seed"]
         self.tol = dict(ctx["config"]["compare"]["serve"], **s.get("compare", {}))
-        model = models.build_model(ctx["config"], max_seq_len=s["cache_len"], remat=False,
-                                   attn_impl=s["attn_impl"])
-        self.model, self.vocab = model, model.cfg.vocab_size
-        params = compare.seed_params(model, seed % (2 ** 31 - 1), self.tol["query_scale"])
+        builder = compare.builder_of(ctx["config"])
+        model = builder.build_model(ctx["config"], max_seq_len=s["cache_len"], remat=False,
+                                    attn_impl=s["attn_impl"])
+        self.vocab = int(ctx["config"]["model"]["vocab_size"])
+        params = compare.seed_params(
+            model, seed % (2 ** 31 - 1),
+            lambda p: builder.sharpen(p, ctx["config"], self.tol["query_scale"]))
         config = {"dtype": ctx["config"]["dtype"],
                   "mesh": {"shape": {"data": 1, "tensor": ctx["chips"]}}}
         engine = deepspeed_tpu.init_inference(model, config=config, params=params)
@@ -148,6 +153,8 @@ class Runner:
             if rec is None:
                 continue
             rec.state, rec.admit, rec.tokens = req.state, req.admit_t, list(req.tokens)
+            rec.prefill_start = getattr(req, "prefill_start_t", None)
+            rec.first_token = getattr(req, "first_token_t", None)
             finished.append(rec)
         return finished
 
@@ -242,6 +249,18 @@ class Runner:
             mean_live_rows=float(np.mean(self.live_rows)) if self.live_rows else None,
             mean_live_kv_tokens=float(np.mean(self.live_kv)) if self.live_kv else None,
             drain_s=t_end - t_close)
+        # the two kinds of tick and the prefill queue (the program's counters since PR 24); a
+        # program without a counter, or a window without that kind of tick, gives no reading
+        delta = lambda key: (stats1[key] - stats0[key] if key in stats0 and key in stats1 else None)
+        per = lambda total, count: total / count if total is not None and count else None
+        fused, plain = delta("fused_prefill_ticks"), delta("plain_ticks")
+        obs.update(
+            fused_tick_block_ms=per(delta("block_ms_fused"), fused),
+            plain_tick_block_ms=per(delta("block_ms_plain"), plain),
+            fused_tick_share_pct=(100.0 * fused / (fused + plain)
+                                  if fused is not None and plain is not None and fused + plain
+                                  else None),
+            prefill_q_depth_mean=per(delta("prefill_q_depth_sum"), delta("steps")))
         e2e = {"setup_s": setup_s, "serve_tokens_per_s": obs["serve_tokens_per_s"]}
         if not closed:
             worst = t_end  # a request that never answered waited at least until now
@@ -252,6 +271,12 @@ class Runner:
             late = np.array([(rec.submitted - rec.due) * 1e3 for rec in measured])
             waits = np.array([(rec.admit - rec.due) * 1e3 for rec in measured
                               if rec.admit is not None])
+            p95 = lambda ms: float(np.percentile(ms, 95)) if ms else None
+            obs.update(  # the request's own timestamps (the program's, on this clock, since PR 24)
+                prefill_wait_p95_ms=p95([(r.prefill_start - r.admit) * 1e3 for r in measured
+                                         if r.prefill_start is not None and r.admit is not None]),
+                prefill_p95_ms=p95([(r.first_token - r.prefill_start) * 1e3 for r in measured
+                                    if r.first_token is not None and r.prefill_start is not None]))
             e2e.update(ttft_p95_ms=float(np.percentile(ttft, 95)),
                        gap_p95_ms=float(np.percentile(gaps, 95)))
             obs.update(ttft_p50_ms=float(np.median(ttft)), gap_p50_ms=float(np.median(gaps)),
@@ -275,14 +300,15 @@ class Runner:
         longest = max(range(len(done)), key=lambda i: done[i].request.prompt.size + len(done[i].tokens))
         rest = [i for i in rs.permutation(len(done)) if i != longest]
         sample = [done[i] for i in [longest] + rest[:int(tol["sample"]) - 1]]
-        params, n_heads = self.params, self.model.cfg.num_heads
+        params = self.params
+        reference = compare.reference_of(ctx["config"])
         self.serving.close()
         self.serving = self.batcher = self.params = None   # the KV pool's memory is the reference's now
         gc.collect()
         out_spec = ctx["traffic"]["output_tokens"]
         ok, fields = compare.serve_verdict(
-            params, [r.request.prompt for r in sample], [np.asarray(r.tokens, np.int32) for r in sample],
-            n_heads, ctx["seed"], tol, width=self.s["cache_len"],
-            new_max=int(out_spec["max"]))
+            reference, params, [r.request.prompt for r in sample],
+            [np.asarray(r.tokens, np.int32) for r in sample], reference.arch(ctx["config"]),
+            ctx["seed"], tol, width=self.s["cache_len"], new_max=int(out_spec["max"]))
         fields.update(requests_finished=len(done), finished_with_wrong_token_count=len(wrong_count))
         return dict(ok=ok and not wrong_count, fields=fields)

@@ -1,8 +1,13 @@
 """Training cell: ``deepspeed_tpu.initialize`` -> ``engine.train_batch``.
 
-Set-up: the engine does K optimizer steps on one fixed micro-batch from the
-seed, fed at every micro-step; these steps are the warm-up too, and their
-losses and first gradient norm are kept.
+Set-up: the engine does K optimizer steps on fixed rows from the seed; these
+steps are the warm-up too, and their losses and first gradient norm are kept.
+The rows are one micro-batch fed at every micro-step or, where the cell's
+``compare`` group says ``"micro_batches": "distinct"``, as many micro-batches
+as a step accumulates, every row different (a step that drops or counts twice
+one of them then differs from the reference). With a leaf limit there
+(``compare.LEAF_LIMITS``), the first gradient is also compared leaf by leaf,
+as the optimizer got it: read back from its first moment after one step.
 
 Window: fresh token batches from the seed, one optimizer step dispatched
 ahead of the one being waited for, until the time is up; the window ends in
@@ -23,7 +28,7 @@ import time
 
 import numpy as np
 
-from benchmark import compare, models, trafficgen
+from benchmark import compare, trafficgen
 from benchmark.harness import BenchmarkError, span
 
 
@@ -54,17 +59,21 @@ class Runner:
         ctx, t = self.ctx, self.t
         n, devices, seed = ctx["chips"], ctx["devices"], ctx["seed"]
         comm.destroy()
-        model = models.build_model(ctx["config"], max_seq_len=t["seq"], remat=t["remat"],
-                                   attn_impl=t["attn_impl"])
-        self.vocab = model.cfg.vocab_size
+        builder = compare.builder_of(ctx["config"])
+        reference = compare.reference_of(ctx["config"])
+        model = builder.build_model(ctx["config"], max_seq_len=t["seq"], remat=t["remat"],
+                                    attn_impl=t["attn_impl"])
+        self.vocab = int(ctx["config"]["model"]["vocab_size"])
         self.rows = t["micro_batch_per_chip"] * n
+        tol = dict(ctx["config"]["compare"]["train"], **t.get("compare", {}))
+        distinct = tol.get("micro_batches") == "distinct"
+        gas = t["gradient_accumulation_steps"]
         fixed = np.random.RandomState(seed % (2 ** 32)).randint(
-            0, self.vocab, (self.rows, t["seq"])).astype(np.int32)
+            0, self.vocab, (self.rows * (gas if distinct else 1), t["seq"])).astype(np.int32)
         engine_seed = seed % (2 ** 31 - 1)
         # the engine makes its weights as model.init(split(PRNGKey(seed))[1]); the
         # reference starts from the same call, and the checksums below prove it
         init_key = jax.random.split(jax.random.PRNGKey(engine_seed))[1]
-        tol = dict(ctx["config"]["compare"]["train"], **t.get("compare", {}))
         K = int(tol["steps"])
         opt = dict(t["optimizer"])
         opt.setdefault("betas", (0.9, 0.999))
@@ -78,20 +87,27 @@ class Runner:
         engine = deepspeed_tpu.initialize(model=model, config=config, mesh=mesh)[0]
         self.start = compare.tree_checksum(
             engine.master_params if engine.master_params is not None else engine.params)
-        batch = {"input_ids": fixed}
-        self.losses, self.grad_norm = [], None
+        feed = itertools.cycle([{"input_ids": fixed[i:i + self.rows]}
+                                for i in range(0, len(fixed), self.rows)])
+        self.losses, self.grad_norm, self.leaves = [], None, None
         for k in range(K):
-            loss = engine.train_batch(itertools.repeat(batch))
+            loss = engine.train_batch(feed)
             self.losses.append(float(loss))
             if k == 0:
                 self.grad_norm = float(engine.get_global_grad_norm())
+                if compare.by_leaf(tol):  # after one step Adam's first moment is (1 - beta1) g
+                    moment = jax.device_get(
+                        jax.jit(compare.leaf_readings)(engine.opt_state.exp_avg))
+                    self.leaves = {leaf: tuple(float(x) / (1.0 - opt["betas"][0]) for x in pair)
+                                   for leaf, pair in moment.items()}
         self.engine_s = time.perf_counter() - t0
         self.engine = engine
         # what finish() needs for the reference, which runs once the engine is gone
-        self.reference = lambda fault=None: compare.train_reference(
-            model.init, init_key, fixed, model.cfg.num_heads, K, opt, devices,
-            rows_per_pass=t["reference_rows_per_pass"], fault=fault)
-        self.tol = tol
+        arch = reference.arch(ctx["config"])
+        self.reference = lambda steps=K, **wrong: compare.train_reference(
+            reference, model.init, init_key, fixed, arch, steps, opt, devices,
+            rows_per_pass=t["reference_rows_per_pass"], leaves=compare.by_leaf(tol), **wrong)
+        self.tol, self.faults = tol, reference.FAULTS
 
     def window(self, seconds, t_start):
         import jax
@@ -156,16 +172,27 @@ class Runner:
                    for a, b in zip(self.start, ref["checksum"])):
             raise BenchmarkError(f"engine and reference start from different weights: "
                                  f"checksums {self.start} vs {ref['checksum']}")
-        ok, fields = compare.train_verdict(self.losses, self.grad_norm, ref, self.tol)
+        ok, fields = compare.train_verdict(self.losses, self.grad_norm, ref, self.tol,
+                                           self.leaves)
         fields.update(reference_s=ref_s, engine_init_and_compared_steps_s=self.engine_s,
                       bytes_in_use_after_engine_release=in_use,
                       live_array_bytes_after_engine_release=live)
-        if self.t.get("controls"):  # on request, three wrong trainers that must FAIL the comparison
-            wrong = {f: self.reference(f) for f in compare.gpt2.FAULTS}
-            passed = {f: compare.train_verdict(self.losses, self.grad_norm, r, self.tol)[0]
+        controls = self.t.get("controls")
+        if controls:  # on request (true, or a list of names): wrong trainers, and the reference in
+            # the precision below the configuration's (its first step: the gradient is what it
+            # moves), each in the program's place. Each must FAIL the comparison.
+            names = list(self.faults) + ["lower_precision"] if controls is True else controls
+            t0 = time.perf_counter()
+            wrong = {f: self.reference(fault=f) for f in names if f in self.faults}
+            if "lower_precision" in names:
+                wrong["lower_precision"] = self.reference(steps=1, operand=compare.fp8)
+            passed = {f: compare.train_verdict(r["losses"], r["grad_norms"][0], ref, self.tol,
+                                               r.get("leaf_readings"))
                       for f, r in wrong.items()}
-            fields["controls_passed_the_check"] = passed
-            fields["controls_grad_norm"] = {f: r["grad_norms"][0] for f, r in wrong.items()}
-            fields["controls_last_loss"] = {f: r["losses"][-1] for f, r in wrong.items()}
-            ok = ok and not any(passed.values())
+            fields["controls_passed_the_check"] = {f: p[0] for f, p in passed.items()}
+            fields["controls"] = {
+                f: {k: v for k, v in p[1].items() if k.endswith("_diff") or k == "grad_leaf_gaps"}
+                for f, p in passed.items()}
+            fields["controls_s"] = time.perf_counter() - t0
+            ok = ok and not any(p[0] for p in passed.values())
         return dict(ok=ok, fields=fields)

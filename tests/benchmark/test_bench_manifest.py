@@ -1,10 +1,15 @@
 """BENCHMARK.json and every file it names, held to the contract's limits."""
 
+import functools
+import glob
+import importlib
 import json
 import os
 import re
 
 import pytest
+
+import bench_toy
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -26,6 +31,19 @@ PER_LAYER = MANIFEST["per_layer"]
 
 def cells_of(metric):
     return metric.get("workloads", CELLS)
+
+
+def cell_body(name):
+    return load("benchmark", "cells", name + ".json")
+
+
+@functools.cache
+def runner_modules():
+    """The runners there are: every module of benchmark/runners that has a ``Runner``."""
+    names = [os.path.splitext(os.path.basename(p))[0]
+             for p in glob.glob(os.path.join(ROOT, "benchmark", "runners", "*.py"))]
+    return {n for n in names if n != "__init__"
+            and hasattr(importlib.import_module("benchmark.runners." + n), "Runner")}
 
 
 def one_line(text, limit=200):
@@ -61,9 +79,17 @@ def test_configuration_entry_and_file(config):
     assert any(w["config"] == config["name"] for w in MANIFEST["workloads"])
     body = load(config["file"])
     assert body["source"] == config["source"] and body["reduced"] == config["reduced"]
-    for key in ("n_embd", "n_layer", "n_head", "n_positions", "vocab_size"):
-        assert isinstance(body["model"][key], int)
-    for kind in ("train", "serve"):
+    # the sizes its builder module declares, the reference it names, and the comparison of
+    # each kind of runner that its cells use: nothing a family does not have is asked of it
+    builder = importlib.import_module(body.get("builder", "benchmark.models"))
+    assert callable(builder.build_model) and "vocab_size" in builder.REQUIRED_SIZES
+    for key in builder.REQUIRED_SIZES:
+        assert isinstance(body["model"][key], int), key
+    reference = importlib.import_module("benchmark.reference." + body.get("reference", "gpt2"))
+    assert callable(reference.arch) and callable(reference.train) and reference.FAULTS
+    kinds = {cell_body(w["name"])["runner"] for w in MANIFEST["workloads"]
+             if w["config"] == config["name"]}
+    for kind in kinds:
         assert one_line(body["compare"][kind]["why"], 2000)
     files = [c["file"] for c in MANIFEST["configs"]]
     assert files.count(config["file"]) == 1
@@ -75,9 +101,8 @@ def test_cell_entry_and_files(cell):
     assert all(NAME.match(cell[k]) for k in ("name", "config", "traffic"))
     assert cell["chips"] in (1, 4) and one_line(cell["why"])
     assert cell["config"] in {c["name"] for c in MANIFEST["configs"]}
-    body = load("benchmark", "cells", cell["name"] + ".json")
-    assert body["runner"] in ("train", "serve") and body[body["runner"]]
-    assert os.path.exists(os.path.join(ROOT, "benchmark", "runners", body["runner"] + ".py"))
+    body = cell_body(cell["name"])
+    assert body["runner"] in runner_modules() and body[body["runner"]]
     load("benchmark", "traffic", cell["traffic"] + ".json")
     # every cell reports setup_s, one more end-to-end metric and a per-layer metric
     mine = [m["name"] for m in MANIFEST["end_to_end"] if cell["name"] in cells_of(m)]
@@ -143,10 +168,20 @@ def test_every_file_under_paths_has_a_permitted_name():
                 assert PATH.match(rel), rel
 
 
-def test_toy_manifest_mirrors_the_real_metrics():
-    toy = load("tests", "benchmark", "toy", "MANIFEST.json")
-    # ... and two more: the toy ZeRO-3 cell on four virtual devices keeps the collective readers
-    # exercised until a four-chip cell enters BENCHMARK.json (PERF.md section 7)
-    assert [m["name"] for m in toy["per_layer"] if not m["name"].startswith("collective_")] == [
-        m["name"] for m in PER_LAYER]
+def test_toy_manifest_is_derived_from_the_real_metrics_and_the_toy_files():
+    toy = bench_toy.manifest()
+    assert [m["name"] for m in toy["per_layer"]] == [m["name"] for m in PER_LAYER]
     assert [m["name"] for m in toy["end_to_end"]] == list(E2E)
+    assert {w["name"] for w in toy["workloads"]} == set(bench_toy.cells())
+    assert {w["config"] for w in toy["workloads"]} == {c["name"] for c in toy["configs"]}
+
+
+@pytest.mark.parametrize("name", sorted(bench_toy.cells()))
+def test_toy_cell_stands_for_a_committed_cell_of_its_runner(name):
+    toy = bench_toy.cells()[name]
+    assert toy["stands_for"] in CELLS and toy["chips"] in (1, 4)
+    body = load("tests", "benchmark", "toy", "cells", name + ".json")
+    assert body["runner"] == cell_body(toy["stands_for"])["runner"]
+    load("tests", "benchmark", "toy", "traffic", toy["traffic"] + ".json")
+    config = load("tests", "benchmark", "toy", "configs", toy["config"] + ".json")
+    assert one_line(config["compare"][body["runner"]]["why"], 2000)

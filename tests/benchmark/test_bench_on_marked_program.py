@@ -10,12 +10,13 @@ import os
 import jax
 import pytest
 
+import bench_toy
 from benchmark import harness
 from benchmark.reduce import reductions as R
 from benchmark.reduce import xplane
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-TOY = os.path.join(ROOT, "tests", "benchmark", "toy", "MANIFEST.json")
+TOY = bench_toy.manifest_path()
 RECORDED = os.path.join(ROOT, "benchmark", "reduce", "recorded_1chip_toy_train.json.gz")
 
 PARENT_LINE = [  # (cell, a per-layer metric the parent's traced line held there)

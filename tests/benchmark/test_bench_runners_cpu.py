@@ -13,13 +13,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import compare, harness
+import bench_toy
+from benchmark import compare, harness, models
 from benchmark.reference import gpt2
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-TOY = os.path.join(ROOT, "tests", "benchmark", "toy", "MANIFEST.json")
-RUNS = [("toy-train", 0), ("toy-train", 1), ("toy-train-zero3", 0), ("toy-train-zero3", 1),
-        ("toy-chat", 0), ("toy-chat", 1), ("toy-batch", 0), ("toy-batch", 1)]
+TOY = bench_toy.manifest_path()
+RUNS = [(cell, trace) for cell in bench_toy.cells() for trace in (0, 1)]  # every toy cell file
 
 
 @pytest.fixture(scope="module")
@@ -59,7 +59,7 @@ def test_runner_gives_the_contracts_object(results, workload, trace):
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 1
     assert "tpu" not in json.dumps(line).lower()
     device = line["device"]
-    assert device["platform"] == "cpu" and device["count"] == (4 if "zero3" in workload else 1)
+    assert device["platform"] == "cpu" and device["count"] == bench_toy.cells()[workload]["chips"]
     manifest = harness.load_json(TOY)
     group = manifest["per_layer"] if trace else manifest["end_to_end"]
     mine = {m["name"]: m for m in group if workload in m.get("workloads", [workload])}
@@ -83,13 +83,94 @@ def test_zero3_cell_reports_its_collectives(results):
     assert 0 <= metrics["collective_exposed.train"]["value"] <= 100
 
 
+def _compare_line(capsys):
+    return next(json.loads(l) for l in capsys.readouterr().out.splitlines()
+                if l.startswith('{"phase": "compare"'))
+
+
 def test_a_wrong_trainer_fails_the_training_comparison(results, capsys):
-    line = results("toy-train", 0, ["cell.train.controls=true"])
+    line = results("toy-train", 0, ['cell.train.controls=["grads_scaled", "shard_left_out", '
+                                    '"double_update"]'])
     assert line["correct"] is True  # the engine passes AND every control failed
-    compare_line = next(json.loads(l) for l in capsys.readouterr().out.splitlines()
-                        if l.startswith('{"phase": "compare"'))
-    assert compare_line["controls_passed_the_check"] == {
+    assert _compare_line(capsys)["controls_passed_the_check"] == {
         "grads_scaled": False, "shard_left_out": False, "double_update": False}
+
+
+def test_the_reference_in_the_precision_below_fails_the_leaf_comparison(results, capsys):
+    """The control that a later PR would be tempted by: the float32 reference with fp8 matmul
+    operands, in the program's place, at the toy cell that stands for the four-chip one. The
+    program's bfloat16 passes the same limits; every wrong trainer fails them too."""
+    line = results("toy-train-zero3", 0, ["cell.train.controls=true"])
+    assert line["correct"] is True
+    said = _compare_line(capsys)
+    assert said["controls_passed_the_check"] == {
+        "grads_scaled": False, "shard_left_out": False, "double_update": False,
+        "lower_precision": False}
+    limit = said["grad_leaf_proj_rel_tolerance"]
+    assert said["grad_leaf_proj_rel_diff"] < limit / 2
+    assert said["controls"]["lower_precision"]["grad_leaf_proj_rel_diff"] > 1.5 * limit
+    assert said["grad_norm_rel_tolerance"] is None  # the norms do not tell the two apart: not held
+
+
+# -- the timed path broken underneath: the rest of a run must say so ------------
+
+def _run_broken(workload):
+    return harness.run_cell(TOY, workload, 2 ** 31 + 9, 1.0, False, require_tpu=False)
+
+
+def test_a_step_that_leaves_the_parameters_unchanged_is_not_correct(results, monkeypatch, capsys):
+    from deepspeed_tpu.ops.adam.fused_adam import FusedAdam
+
+    real = FusedAdam.update
+
+    def no_update(self, grads, state, params, lr=None):
+        updates, state = real(self, grads, state, params, lr)
+        return jax.tree.map(jnp.zeros_like, updates), state
+
+    results("toy-train", 0)  # the fixture's environment (compile cache, TMPDIR) is in place
+    monkeypatch.setattr(FusedAdam, "update", no_update)
+    line = _run_broken("toy-train")
+    assert line["correct"] is False and line["attempted"] >= 1
+    said = json.loads(capsys.readouterr().err.strip().splitlines()[-1])  # standard error's last line
+    assert said["phase"] == "compare" and said["correct"] is False
+    assert said["max_loss_diff"] > said["loss_abs_tolerance"]
+
+
+@pytest.mark.parametrize("feed,correct", [("distinct", False), ("same", True)])
+def test_a_step_that_counts_one_micro_batch_twice_is_seen_on_distinct_rows(results, monkeypatch,
+                                                                            feed, correct):
+    """An accumulation that takes its first micro-batch for every micro-step (one counted
+    twice, one dropped): not correct where the compared steps feed rows that all differ, and
+    invisible where one micro-batch is fed at every micro-step."""
+    import itertools
+
+    from deepspeed_tpu.runtime.engine import TpuEngine
+
+    real = TpuEngine.train_batch
+    results("toy-train-zero3", 0)
+    monkeypatch.setattr(TpuEngine, "train_batch",
+                        lambda self, data_iter=None: real(self, itertools.repeat(next(data_iter))))
+    line = harness.run_cell(TOY, "toy-train-zero3", 2 ** 31 + 9, 1.0, False, require_tpu=False,
+                            overrides=[f"cell.train.compare.micro_batches={feed}"])
+    assert line["correct"] is correct
+
+
+def test_a_token_altered_where_it_is_produced_is_not_correct(results, monkeypatch):
+    from deepspeed_tpu.serving import ServingEngine
+
+    real = ServingEngine.reap
+
+    def reap(self):
+        done = real(self)
+        for request in done.values():
+            if len(request.tokens):  # the last token: no later position is scored on it
+                request.tokens[-1] = (int(request.tokens[-1]) + 1) % 503
+        return done
+
+    results("toy-chat", 0)
+    monkeypatch.setattr(ServingEngine, "reap", reap)
+    line = _run_broken("toy-chat")
+    assert line["correct"] is False and line["failed"] == 0
 
 
 def test_the_reference_trains_once_the_engine_is_gone(results, capsys):
@@ -170,7 +251,7 @@ def test_each_training_fault_leaves_the_tolerances(toy_model, fault):
     tokens = np.random.RandomState(2).randint(0, 211, (4, 32)).astype(np.int32)
     opt = dict(lr=1e-2, betas=(0.9, 0.999), eps=1e-8, weight_decay=0.01)
     key = jax.random.PRNGKey(2)
-    run = lambda f: compare.train_reference(toy_model.init, key, tokens, 4, 3, opt,
+    run = lambda f: compare.train_reference(gpt2, toy_model.init, key, tokens, 4, 3, opt,
                                             jax.devices()[:1], rows_per_pass=2, fault=f)
     good, bad = run(None), run(fault)
     tol = dict(loss_abs=0.005, grad_norm_rel=0.01, min_fall=0.01)
@@ -178,6 +259,35 @@ def test_each_training_fault_leaves_the_tolerances(toy_model, fault):
     ok, fields = compare.train_verdict(good["losses"], good["grad_norms"][0], bad, tol)
     assert not ok, fields
     assert good["checksum"] == bad["checksum"]  # same start: only the trainer differs
+
+
+def test_fp8_rounds_the_value_and_passes_the_gradient_through():
+    x = jnp.asarray(np.random.RandomState(4).randn(64, 32), jnp.float32)
+    y = compare.fp8(x)
+    err = np.abs(np.asarray(y - x)) / np.abs(np.asarray(x))
+    assert float(jnp.max(jnp.abs(y))) == pytest.approx(float(jnp.max(jnp.abs(x))), rel=1e-6)
+    assert 0.01 < np.median(err) < 2 ** -4 + 1e-6  # three bits of mantissa, no finer and no coarser
+    assert len(np.unique(np.asarray(y))) < 2 * 2 ** 7 + 1
+    grad = jax.grad(lambda a: jnp.sum(compare.fp8(a) * x))(x)
+    assert np.array_equal(np.asarray(grad), np.asarray(x))
+
+
+def test_leaf_gaps_are_measured_against_the_leaf_or_the_median_leaf():
+    tree = {"a": jnp.full((4, 8), 2.0), "b": jnp.full((8,), 1e-6), "c": jnp.full((3,), 1.0)}
+    read = jax.device_get(jax.jit(compare.leaf_readings)(tree))
+    assert set(read) == {"['a']", "['b']", "['c']"} and float(read["['a']"][0]) == pytest.approx(
+        2.0 * np.sqrt(32))
+    assert abs(float(read["['a']"][1])) <= 2.0 * 32 and float(read["['a']"][1]) % 4.0 == 0.0
+    theirs = {k: (float(n), float(p)) for k, (n, p) in read.items()}
+    b = theirs["['b']"]
+    mine = dict(theirs, **{"['b']": (3 * b[0], b[1])})  # an all but zero leaf, tripled
+    norm_gap, proj_gap, gaps = compare.worst_leaf_gaps(mine, theirs)
+    assert norm_gap == pytest.approx(2 * b[0] / np.sqrt(3.0)) and proj_gap == 0.0  # against the median leaf
+    half = {k: (n / 2, p / 2) for k, (n, p) in theirs.items()}
+    assert compare.worst_leaf_gaps(theirs, theirs)[:2] == (0.0, 0.0)
+    assert compare.worst_leaf_gaps(half, theirs)[0] == pytest.approx(0.5)
+    with pytest.raises(ValueError):
+        compare.worst_leaf_gaps({"['a']": (1.0, 1.0)}, theirs)
 
 
 def test_adamw_matches_the_programs_optimizer():
@@ -198,7 +308,9 @@ def test_adamw_matches_the_programs_optimizer():
 
 
 def test_serving_comparison_passes_greedy_streams_and_fails_wrong_ones(toy_model):
-    params = compare.seed_params(toy_model, 5, 3.0)
+    config = {"model": {"n_layer": 2, "n_head": 4}}
+    assert gpt2.arch(config) == 4
+    params = compare.seed_params(toy_model, 5, lambda p: models.sharpen(p, config, 3.0))
     rs = np.random.RandomState(5)
     prompts = [rs.randint(0, 211, n).astype(np.int32) for n in (9, 14, 20, 27)]
     streams = []
@@ -211,13 +323,13 @@ def test_serving_comparison_passes_greedy_streams_and_fails_wrong_ones(toy_model
             seq.append(int(np.argmax(np.asarray(logits)[0, 0])))
         streams.append(np.array(seq[len(p):], np.int32))
     tol = dict(margin=0.25, share_within=0.99, control_share=0.2, distinct_per_request=1)  # 24 toy tokens
-    ok, fields = compare.serve_verdict(params, prompts, streams, 4, 5, tol, width=64, new_max=6)
+    ok, fields = compare.serve_verdict(gpt2, params, prompts, streams, 4, 5, tol, width=64, new_max=6)
     assert ok and fields["share_within_margin"] == 1.0 and fields["worst_gap"] == 0.0, fields
     wrong = [rs.randint(0, 211, 6).astype(np.int32) for _ in prompts]
-    ok, fields = compare.serve_verdict(params, prompts, wrong, 4, 5, tol, width=64, new_max=6)
+    ok, fields = compare.serve_verdict(gpt2, params, prompts, wrong, 4, 5, tol, width=64, new_max=6)
     assert not ok and fields["share_within_margin"] < 0.5
     shifted = [np.roll(s, 1) for s in streams]  # the right tokens, one position off
-    assert not compare.serve_verdict(params, prompts, shifted, 4, 5, tol, width=64, new_max=6)[0]
+    assert not compare.serve_verdict(gpt2, params, prompts, shifted, 4, 5, tol, width=64, new_max=6)[0]
 
 
 def test_warm_plan_reaches_every_tick_program_the_lengths_can():
