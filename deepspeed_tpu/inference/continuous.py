@@ -96,6 +96,7 @@ from deepspeed_tpu.inference.decoding import (
     compile_spec_pool_tick_fn,
     compile_spec_row_update_fn,
     read_bucket,
+    TICK_STATS,
 )
 from deepspeed_tpu.telemetry.spans import host_span
 
@@ -104,8 +105,11 @@ from deepspeed_tpu.telemetry.spans import host_span
 _bucket = read_bucket
 
 # smallest fused-prefill chunk program width (power-of-2 buckets up to the
-# pool's chunk cap bound the static-shape program family)
+# pool's chunk cap bound the static-shape program family); a layer plan's
+# chunks ride the flash chunk kernel (key tiles of 128), and starting at 256
+# keeps its family of tick programs (widths x read buckets) small
 _CHUNK_FLOOR = 16
+_PLAN_CHUNK_FLOOR = 256
 
 
 @dataclass
@@ -339,6 +343,11 @@ class ContinuousBatchingEngine:
         # the footprint (see PERF.md bucketed-KV table)
         self.cfg = self._eng._ring_off_cfg
         self.mesh = self._eng.mesh
+        self._chunk_floor = (_CHUNK_FLOOR if self.cfg.layer_kinds is None
+                             else _PLAN_CHUNK_FLOOR)
+        # a layer plan with expert layers: its ticks return routing counters
+        self._moe_stats = (self.cfg.layer_kinds is not None
+                           and self.cfg.moe_num_experts > 0)
         self.eos_token_id = eos_token_id
         self.temperature, self.top_k, self.top_p = temperature, top_k, top_p
         assert tokens_per_tick >= 1, tokens_per_tick
@@ -369,6 +378,13 @@ class ContinuousBatchingEngine:
         # every tick proposes spec_gamma tokens per active row and ONE
         # target forward verifies them (decoding.compile_spec_pool_tick_fn)
         spec = self._eng.config.speculative
+        if self.cfg.layer_kinds is not None and (
+                (spec.enabled and spec.pool) or not fused_prefill
+                or tokens_per_tick != 1):
+            raise NotImplementedError(
+                "a layer-plan model is served by single-token ticks with "
+                "fused prefill chunks (no speculative pool ticks, bursts or "
+                "separate prefill)")
         self.spec_gamma = 0
         self.spec_mode = None
         self._draft_eng = None
@@ -459,6 +475,22 @@ class ContinuousBatchingEngine:
                             # how long the prefill queues stood when
                             # each step looked (÷ steps = mean depth)
                             "prefill_q_depth_sum": 0}
+        if self.cfg.layer_kinds is not None:
+            # what the prefill chunks' attention had to do, counted where a
+            # chunk is dispatched: real tokens, (query, key) pairs attended
+            # in a full and in a window layer, keys a full layer read
+            self._tick_stats.update(prefill_chunk_tokens=0, prefill_pairs_full=0,
+                                    prefill_pairs_window=0, prefill_keys_full=0)
+            self._window = max(k.window for k in self.cfg.layer_kinds)
+        if self._moe_stats:
+            # expert routing as the ticks report it (decoding.TICK_STATS):
+            # assignments made / to the experts held here, and per tick
+            # the most one held expert got in a layer against the mean
+            # (sum of the ratios ÷ moe_ticks = mean imbalance)
+            self._tick_stats.update(
+                moe_ticks=0, moe_assignments=0, moe_held_assignments=0, moe_experts_hit=0,
+                moe_expert_tokens_most_sum=0, moe_expert_tokens_mean_sum=0.0,
+                moe_imbalance_sum=0.0)
         # cancelled rids, remembered so status()/result() answer precisely
         # instead of "unknown" — BOUNDED (oldest evicted past 4096): a
         # long-running server cancels routinely and must not leak an int
@@ -539,6 +571,17 @@ class ContinuousBatchingEngine:
         """Total device bytes held by the slot-pool KV caches (the number
         the PERF.md bucketed-vs-fixed footprint table reports)."""
         return sum(p.kv_bytes() for p in self._pools)
+
+    def kv_pool_bytes(self) -> Dict[str, int]:
+        """``kv_cache_bytes()`` by kind of pool: a layer plan keeps a
+        full-length pool and a ring of ``window`` positions
+        ({"full": ..., "window": ...}); a model of one kind has {"full"}."""
+        out: Dict[str, int] = {}
+        for p in self._pools:
+            tree = p.cache if "full" in p.cache else {"full": p.cache}
+            for name, sub in tree.items():
+                out[name] = out.get(name, 0) + sum(l.nbytes for l in jax.tree.leaves(sub))
+        return out
 
     def hbm_components(self) -> Dict[str, int]:
         """PER-CHIP HBM attribution of everything this engine keeps
@@ -722,6 +765,10 @@ class ContinuousBatchingEngine:
         system-prompt pattern, where admission then only pays prefill for
         the per-request suffix. Returns a prefix id for submit_with_prefix.
         """
+        if self.cfg.layer_kinds is not None:
+            raise NotImplementedError(
+                "prefix registration splices one pool of one length; a "
+                "layer plan's pools have no splice yet")
         prefix = np.asarray(prefix_ids, np.int32).reshape(-1)
         if prefix.size == 0:
             raise ValueError("empty prefix")
@@ -909,6 +956,9 @@ class ContinuousBatchingEngine:
         s["spec_mode"] = self.spec_mode
         s["spec_acceptance"] = (round(s["spec_accepted"] / s["spec_drafted"], 4)
                                 if s["spec_drafted"] else None)
+        if self.cfg.layer_kinds is not None:
+            for name, nbytes in self.kv_pool_bytes().items():
+                s["kv_pool_bytes_" + name] = nbytes
         return s
 
     def _place(self, req: _Request) -> Optional[tuple]:
@@ -1147,10 +1197,17 @@ class ContinuousBatchingEngine:
             ctoks, cpos0, nreal, emits = admit.chunks[0]
             aslot = admit.slot
             self._mark_prefill_start(admit)
-            W = _bucket(nreal, pool.chunk_cap, _CHUNK_FLOOR)
+            W = _bucket(nreal, pool.chunk_cap, self._chunk_floor)
             extent = max(extent, cpos0 + nreal)
             read_len = self._read_len(pool, extent)
             fn = self._tick_fn(pool, read_len, chunk=W)
+            if self.cfg.layer_kinds is not None:
+                st = self._tick_stats
+                st["prefill_chunk_tokens"] += nreal
+                st["prefill_pairs_full"] += nreal * cpos0 + nreal * (nreal + 1) // 2
+                st["prefill_pairs_window"] += int(np.minimum(
+                    np.arange(cpos0 + 1, cpos0 + nreal + 1), self._window).sum())
+                st["prefill_keys_full"] += cpos0 + nreal
             chunk_toks = np.zeros(W, np.int32)
             chunk_toks[:nreal] = ctoks
             chunk_pos = np.full(W, pool.length, np.int32)
@@ -1265,7 +1322,7 @@ class ContinuousBatchingEngine:
             admit = pool.prefill_q[0]
             ctoks, cpos0, nreal, _ = admit.chunks.pop(0)
             self._mark_prefill_start(admit)
-            W = _bucket(nreal, pool.chunk_cap, _CHUNK_FLOOR)
+            W = _bucket(nreal, pool.chunk_cap, self._chunk_floor)
             seg_toks = np.zeros((n, W), np.int32)
             seg_toks[admit.slot, :nreal] = ctoks
             seg_pos = np.full(n, pool.length, np.int32)
@@ -1362,6 +1419,18 @@ class ContinuousBatchingEngine:
             stats["block_ms_fused" if rec.fused else "block_ms_plain"] += dt * 1000.0
             k = rec.k
             g = rec.spec
+            if self._moe_stats:
+                made, held, most, layers, hit = (
+                    int(v) for v in arr[0, k + 2:k + 2 + TICK_STATS])
+                stats["moe_experts_hit"] += hit
+                mean = held / max(1, layers * self.cfg.held_experts[1])
+                stats["moe_ticks"] += 1
+                stats["moe_assignments"] += made
+                stats["moe_held_assignments"] += held
+                stats["moe_expert_tokens_most_sum"] += most
+                stats["moe_expert_tokens_mean_sum"] += mean
+                if held:
+                    stats["moe_imbalance_sum"] += most / mean
             hook = self.span_hook
             if hook is not None:
                 t_ret = time.monotonic()
@@ -1662,7 +1731,7 @@ class ContinuousBatchingEngine:
                 continue
             chunks: List[Optional[int]] = [None]
             if self.fused_prefill:
-                chunks += sorted({_bucket(m, pool.chunk_cap, _CHUNK_FLOOR)
+                chunks += sorted({_bucket(m, pool.chunk_cap, self._chunk_floor)
                                   for m in range(1, pool.chunk_cap + 1)})
             for rl in read_lens:
                 for ch in chunks:
@@ -1731,7 +1800,7 @@ class ContinuousBatchingEngine:
         if self.fused_prefill:
             # fused spec admission dispatches prompt chunks through the
             # shared segment program — retraces per chunk width
-            for W in sorted({_bucket(m, pool.chunk_cap, _CHUNK_FLOOR)
+            for W in sorted({_bucket(m, pool.chunk_cap, self._chunk_floor)
                              for m in range(1, pool.chunk_cap + 1)}):
                 t0 = time.time()
                 cache = jax.device_put(

@@ -81,6 +81,12 @@ def decode_kv_bytes(cfg, prompt_len: int, new_tokens: int, cache_len: int,
     return total
 
 
+# routing counters a tick of a model with expert layers returns beside its
+# tokens: assignments made, to held experts, the most one held expert got in
+# a layer, expert layers run, held experts hit (layer_plan.forward_plan_cached)
+TICK_STATS = 5
+
+
 def _decode_shardings(mesh, cfg, batch_size: int):
     """(batch_sharding, cache_sharding) — the ONE sharding-selection policy
     for every cached-decode program (plain and speculative paths must place
@@ -89,7 +95,9 @@ def _decode_shardings(mesh, cfg, batch_size: int):
 
     dp = mesh.shape["data"] * mesh.shape["fsdp"]
     batch_axes = ("data", "fsdp") if batch_size % dp == 0 else None
-    kv_tensor = "tensor" if cfg.kv_heads % mesh.shape["tensor"] == 0 else None
+    # a layer plan's pools differ in heads: they stay whole on every chip
+    kv_tensor = ("tensor" if cfg.layer_kinds is None
+                 and cfg.kv_heads % mesh.shape["tensor"] == 0 else None)
     batch_sh = NamedSharding(mesh, PartitionSpec(batch_axes))
     cache_sh = jax.tree.map(
         lambda _: NamedSharding(mesh, PartitionSpec(None, batch_axes, None, kv_tensor, None)),
@@ -566,28 +574,49 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
         keys = request_keys(base_key, rids, gen)
         return select_token_rows(logits, temperature, top_k, keys, top_p)
 
+    # a layer-plan model (models/layer_plan.py) runs its own tick body; one
+    # with expert layers appends its routing counters to the packed buffer
+    # as TICK_STATS more columns (row 0 carries them), so they come back
+    # in the tick's one fetch
+    plan = cfg.layer_kinds is not None
+    if plan:
+        from deepspeed_tpu.models.layer_plan import Chunk, forward_plan_cached
+
+    def with_stats(packed, stats):
+        if stats is None or not cfg.moe_num_experts:
+            return packed
+        extra = jnp.zeros((batch_size, TICK_STATS), jnp.int32).at[0].set(stats)
+        return jnp.concatenate([packed, extra], axis=1)
+
     if chunk is None:
         ones = jnp.ones((batch_size,), jnp.int32)
 
         def run(params, cache, last_tok, done, pos, gen, quota, rids, base_key):
             def body(carry, _):
-                cache, last_tok, done, pos, gen = carry
-                logits, cache = tf.forward_with_cache(
-                    params, cfg, last_tok[:, None], cache, pos,
-                    read_len=read_len)
-                tok = sample(logits[:, 0], rids, gen, base_key)
+                cache, last_tok, done, pos, gen, stats = carry
+                if plan:
+                    logits, cache, stats = forward_plan_cached(
+                        params, cfg, last_tok, pos, cache, read_len=read_len)
+                else:
+                    logits, cache = tf.forward_with_cache(
+                        params, cfg, last_tok[:, None], cache, pos,
+                        read_len=read_len)
+                    logits = logits[:, 0]
+                tok = sample(logits, rids, gen, base_key)
                 last2, done2, gen2, emitted = accept(
                     tok, last_tok, done, gen, quota, ones)
                 pos2 = jnp.where(done == 0, pos + 1, pos)
-                return (cache, last2, done2, pos2, gen2), (tok, emitted)
+                return (cache, last2, done2, pos2, gen2, stats), (tok, emitted)
 
-            (cache, last_tok, done, _, _), (toks, emitted) = jax.lax.scan(
-                body, (cache, last_tok, done, pos, gen), None, length=k)
+            (cache, last_tok, done, _, _, stats), (toks, emitted) = jax.lax.scan(
+                body, (cache, last_tok, done, pos, gen,
+                       jnp.zeros((TICK_STATS,), jnp.int32) if plan else None),
+                None, length=k)
             packed = jnp.concatenate(
                 [jnp.moveaxis(toks, 0, 1),
                  emitted.sum(axis=0, dtype=jnp.int32)[:, None],
                  done[:, None]], axis=1)
-            return packed, cache, last_tok, done
+            return with_stats(packed, stats), cache, last_tok, done
 
         fn = jax.jit(
             run,
@@ -604,20 +633,29 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
 
     def run(params, cache, last_tok, done, pos, gen, quota, rids, base_key,
             chunk_toks, chunk_pos, admit_slot, emit_col, emit_mask):
-        toks = jnp.zeros((batch_size, W), jnp.int32).at[:, 0].set(last_tok)
-        toks = toks.at[admit_slot].set(chunk_toks)
-        positions = jnp.full((batch_size, W), cache_len, jnp.int32)
-        positions = positions.at[:, 0].set(pos).at[admit_slot].set(chunk_pos)
-        logits, cache = tf.forward_with_cache(
-            params, cfg, toks, cache, pos, positions=positions,
-            read_len=read_len)
-        sel = jnp.take_along_axis(logits, emit_col[:, None, None], axis=1)[:, 0]
+        stats = None
+        if plan:
+            # the chunk rides beside the rows as W more tokens, not as a
+            # W-wide row of padding under every slot
+            sel, cache, stats = forward_plan_cached(
+                params, cfg, last_tok, pos, cache, read_len=read_len,
+                chunk=Chunk(chunk_toks, chunk_pos, jnp.asarray(admit_slot, jnp.int32),
+                            emit_col[admit_slot]))
+        else:
+            toks = jnp.zeros((batch_size, W), jnp.int32).at[:, 0].set(last_tok)
+            toks = toks.at[admit_slot].set(chunk_toks)
+            positions = jnp.full((batch_size, W), cache_len, jnp.int32)
+            positions = positions.at[:, 0].set(pos).at[admit_slot].set(chunk_pos)
+            logits, cache = tf.forward_with_cache(
+                params, cfg, toks, cache, pos, positions=positions,
+                read_len=read_len)
+            sel = jnp.take_along_axis(logits, emit_col[:, None, None], axis=1)[:, 0]
         tok = sample(sel, rids, gen, base_key)
         last2, done2, gen2, emitted = accept(
             tok, last_tok, done, gen, quota, emit_mask)
         packed = jnp.concatenate(
             [tok[:, None], emitted[:, None], done2[:, None]], axis=1)
-        return packed, cache, last2, done2
+        return with_stats(packed, stats), cache, last2, done2
 
     fn = jax.jit(
         run,

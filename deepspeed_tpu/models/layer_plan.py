@@ -1,0 +1,519 @@
+"""Layers of several kinds in one stack: the layer plan.
+
+``TransformerConfig.layer_kinds`` lists the kinds of layer a model has
+(:class:`~deepspeed_tpu.models.transformer.LayerKind`: the reach of its
+attention, its key-value heads, its rotary base, whether a sink joins its
+softmax, a dense or an expert FFN) and ``layer_plan`` says which kind each
+layer is. Kinds differ in parameter SHAPES, so the parameters are stacked
+per kind (``params["layers"][kind.name]``, leading axis = that kind's
+layers in model order) and the stack is walked as **one scan per run of
+equal layers**: a plan ``D W W W W W F`` is a call, a scan of five and a
+call. A scan over periods with the period unrolled inside would compile
+one body for the whole depth of a periodic model, but needs the plan to BE
+periodic (a leading dense layer, a cut in depth and a last period of
+another length all break it); the run walk takes any plan, and a model cut
+to one period costs the same either way. A run that is not the whole of
+its kind's stack reads a static slice of it.
+
+The KV cache is one pool per attention reach, in one tree the slot manager
+carries and donates whole:
+
+    cache["full"]   {"k": (L_full, B, kv, T, dk),      "v": (..., dv)}
+    cache["window"] {"k": (L_win,  B, kv, window, dk), "v": (..., dv)}
+
+heads before time: a row's keys of one head are a (T, dk) matrix as the
+score and value contractions and the chunk kernel want it (time before
+heads, the compiler re-laid out the whole value pool on the way into every
+tick and back). ``full`` rows are written and read like the one-kind pool (in place, tight
+reads). ``window`` is a ring: position p of a row lives in slot p mod
+window, which is all a window layer can ever attend, so a window layer
+costs ``window`` positions of memory and of read however long the row is.
+A prefill chunk never reads its own keys through the ring: it attends the
+ring's tail (the ``window`` positions before the chunk, put in order) joined
+to the chunk's own keys, then writes its last ``window`` tokens.
+
+Three entry points: :func:`forward_plan` (no cache: training, the reference
+comparison), :func:`forward_plan_cached` (the serving tick: every slot's
+one decode token at its own depth and, with ``chunk``, ONE admitting
+row's prefill chunk beside them, as one flat list of tokens through the
+projections and FFNs) and the bookkeeping the engine asks for
+(:func:`init_pools`, :func:`kv_read_bytes_by_pool`).
+"""
+
+import math
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.telemetry.hlo_scopes import Scope
+
+
+class Chunk(NamedTuple):
+    """One admitting row's prefill chunk riding a tick."""
+    toks: jnp.ndarray   # (W,) int32
+    pos: jnp.ndarray    # (W,) int32 absolute positions; pads carry the pool's length
+    slot: jnp.ndarray   # () int32 the row it belongs to
+    emit: jnp.ndarray   # () int32 the column whose logits the tick samples
+
+
+class Run(NamedTuple):
+    kind: object
+    kind_start: int   # first layer of the run within its kind's stack
+    n: int
+    pool_start: int   # ... and within its pool
+
+
+def check_plan(cfg):
+    kinds, plan = cfg.layer_kinds, cfg.layer_plan
+    if plan is None or len(plan) != cfg.num_layers:
+        raise ValueError("layer_plan must name a kind for each of num_layers layers")
+    if any(not 0 <= i < len(kinds) for i in plan):
+        raise ValueError(f"layer_plan {plan} names a kind outside layer_kinds")
+    if len({k.name for k in kinds}) != len(kinds):
+        raise ValueError("layer kinds need distinct names")
+    for pool in ("full", "window"):
+        shapes = {(k.kv_heads, k.window) for k in kinds if k.pool == pool}
+        if len(shapes) > 1:
+            raise ValueError(f"kinds of the {pool} pool differ in key-value heads or window: "
+                             f"{sorted(shapes)}")
+    if not any(k.window == 0 for k in cfg.plan):
+        raise ValueError("a layer plan needs a full-attention layer: the slot manager reads "
+                         "a row's length off the full pool")
+    for k in kinds:
+        if cfg.num_heads % k.kv_heads:
+            raise ValueError(f"kind {k.name}: {cfg.num_heads} heads over {k.kv_heads} kv heads")
+        if k.ffn not in ("dense", "moe"):
+            raise ValueError(f"kind {k.name}: ffn {k.ffn!r}")
+        if k.ffn == "moe" and cfg.moe_num_experts < 1:
+            raise ValueError(f"kind {k.name} routes but moe_num_experts is 0")
+    if (cfg.pos_embedding != "rope" or cfg.norm_position != "pre" or cfg.use_bias
+            or cfg.activation != "silu_glu" or not cfg.causal or cfg.kv_cache_dtype != "model"):
+        raise ValueError("a layer plan takes rotary positions, pre-norm blocks, no biases, "
+                         "SwiGLU, causal attention and a KV cache in the model's dtype")
+
+
+def runs(cfg):
+    """The plan as runs of equal layers."""
+    out, seen_kind, seen_pool = [], {}, {}
+    for kind in cfg.plan:
+        ks, ps = seen_kind.get(kind.name, 0), seen_pool.get(kind.pool, 0)
+        if out and out[-1].kind is kind:
+            out[-1] = out[-1]._replace(n=out[-1].n + 1)
+        else:
+            out.append(Run(kind, ks, 1, ps))
+        seen_kind[kind.name], seen_pool[kind.pool] = ks + 1, ps + 1
+    return out
+
+
+def layers_of(cfg, kind) -> int:
+    return sum(k is kind for k in cfg.plan)
+
+
+def pool_shapes(cfg):
+    """{pool: (layers, kv_heads, window or 0)} for the pools the plan needs."""
+    out = {}
+    for kind in cfg.plan:
+        n = out.get(kind.pool, (0,))[0]
+        out[kind.pool] = (n + 1, kind.kv_heads, kind.window)
+    return out
+
+
+def _ffn_size(cfg, kind):
+    return kind.ffn_size or cfg.ffn_size
+
+
+def _layer_shapes(cfg, kind):
+    """{(group, leaf): (shape, scale of its normal init; None = ones)} of one layer."""
+    D, nh, dk, dv, F = cfg.hidden_size, cfg.num_heads, cfg.head_dim, cfg.v_head_dim, _ffn_size(cfg, kind)
+    out_scale = 1.0 / math.sqrt(2 * cfg.num_layers)
+    shapes = {
+        ("attn", "wq"): ((D, nh * dk), 1 / math.sqrt(D)),
+        ("attn", "wk"): ((D, kind.kv_heads * dk), 1 / math.sqrt(D)),
+        ("attn", "wv"): ((D, kind.kv_heads * dv), 1 / math.sqrt(D)),
+        ("attn", "wo"): ((nh * dv, D), out_scale / math.sqrt(nh * dv)),
+        ("ln1", "scale"): ((D,), None),
+        ("ln2", "scale"): ((D,), None),
+    }
+    if kind.sink:
+        shapes[("attn", "sink")] = ((nh,), 1.0)
+    if kind.ffn == "moe":
+        E, held = cfg.moe_num_experts, cfg.held_experts[1]
+        shapes.update({
+            ("mlp", "gate"): ((D, E), 0.02),
+            # the selection bias balancing leaves behind: small beside the scores' spread,
+            # large enough to decide some choices
+            ("mlp", "gate_bias"): ((E,), 0.01),
+            ("mlp", "wg"): ((held, D, F), 1 / math.sqrt(D)),
+            ("mlp", "wi"): ((held, D, F), 1 / math.sqrt(D)),
+            ("mlp", "wo"): ((held, F, D), out_scale / math.sqrt(F)),
+        })
+    else:
+        shapes.update({
+            ("mlp", "wg"): ((D, F), 1 / math.sqrt(D)),
+            ("mlp", "wi"): ((D, F), 1 / math.sqrt(D)),
+            ("mlp", "wo"): ((F, D), out_scale / math.sqrt(F)),
+        })
+    return shapes
+
+
+def num_params(cfg) -> int:
+    D, V = cfg.hidden_size, cfg.vocab_size
+    total = V * D + D + (0 if cfg.tie_embeddings else V * D)
+    for kind in cfg.plan:
+        total += sum(math.prod(shape) for shape, _ in _layer_shapes(cfg, kind).values())
+    return total
+
+
+def init_layers(rng, cfg):
+    """{kind.name: that kind's layers stacked}; float32 leaves, or the
+    model's dtype straight away under ``cfg.init_in_model_dtype`` (each leaf
+    is drawn and cast in one fusion, so the float32 tree never exists)."""
+    dtype = cfg.jnp_dtype if cfg.init_in_model_dtype else jnp.float32
+    out = {}
+    for ki, kind in enumerate(cfg.layer_kinds):
+        n = layers_of(cfg, kind)
+        if not n:
+            continue
+        tree = {}
+        for li, ((group, name), (shape, scale)) in enumerate(sorted(_layer_shapes(cfg, kind).items())):
+            key = jax.random.fold_in(jax.random.fold_in(rng, ki), li)
+            if scale is None:
+                leaf = jnp.ones((n,) + shape, dtype)
+            else:
+                leaf = (jax.random.normal(key, (n,) + shape, jnp.float32) * scale).astype(dtype)
+            tree.setdefault(group, {})[name] = leaf
+        out[kind.name] = tree
+    return out
+
+
+def init_pools(cfg, batch_size: int, length: int):
+    dt = cfg.jnp_dtype
+    out = {}
+    for pool, (n, kv, window) in pool_shapes(cfg).items():
+        T = window if window else length
+        out[pool] = {"k": jnp.zeros((n, batch_size, kv, T, cfg.head_dim), dt),
+                     "v": jnp.zeros((n, batch_size, kv, T, cfg.v_head_dim), dt)}
+    return out
+
+
+def kv_read_bytes_by_pool(cfg, read_len: int) -> dict:
+    """{pool: bytes ONE row's attention streams from it in a decode step
+    that attends ``read_len`` slots}: a window layer reads its ring."""
+    item = jnp.dtype(cfg.jnp_dtype).itemsize
+    return {pool: n * (min(window, read_len) if window else read_len) * kv
+            * (cfg.head_dim + cfg.v_head_dim) * item
+            for pool, (n, kv, window) in pool_shapes(cfg).items()}
+
+
+# ---------------------------------------------------------------------------
+# pieces of a layer
+# ---------------------------------------------------------------------------
+
+def _tf():
+    from deepspeed_tpu.models import transformer as tf
+
+    return tf
+
+
+def _project(h, attn_p, kind, cfg, positions):
+    """h (N, D), positions (N,) -> q (N, nh, dk), k (N, kv, dk), v (N, kv, dv)."""
+    tf = _tf()
+    with jax.named_scope(Scope.ATTN_QKV):
+        N = h.shape[0]
+        q = tf._linear(h, attn_p["wq"]).reshape(1, N, cfg.num_heads, cfg.head_dim)
+        k = tf._linear(h, attn_p["wk"]).reshape(1, N, kind.kv_heads, cfg.head_dim)
+        v = tf._linear(h, attn_p["wv"]).reshape(N, kind.kv_heads, cfg.v_head_dim)
+        q = tf._rope(q, positions[None], kind.rope_theta, cfg.rope_dim, cfg.rope_interleaved)[0]
+        k = tf._rope(k, positions[None], kind.rope_theta, cfg.rope_dim, cfg.rope_interleaved)[0]
+        if cfg.attn_value_scale is not None:
+            v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
+    return q, k, v
+
+
+def _scale(cfg):
+    return cfg.attn_scale if cfg.attn_scale is not None else 1.0 / math.sqrt(cfg.head_dim)
+
+
+def _grouped_attention(q, k, v, ok, sink, scale):
+    """q (B, S, nh, dk) against k (B, kv, T, dk), v (B, kv, T, dv) where
+    ``ok`` (B|1, S, T) says which keys a query attends; query head h reads
+    key-value head h // (nh / kv) WITHOUT the keys being repeated. ``sink``
+    (nh,): a per-head logit in the softmax's denominator, with no value."""
+    B, S, nh, dk = q.shape
+    kv = k.shape[1]
+    g = nh // kv
+    logits = jnp.einsum("bsngd,bntd->bngst", q.reshape(B, S, kv, g, dk), k,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(ok[:, None, None], logits, jnp.float32(-1e30))
+    m = logits.max(axis=-1, keepdims=True)
+    if sink is not None:
+        s = sink.astype(jnp.float32).reshape(1, kv, g, 1, 1)
+        m = jnp.maximum(m, s)
+    p = jnp.where(ok[:, None, None], jnp.exp(logits - m), 0.0)
+    denom = p.sum(axis=-1, keepdims=True)
+    if sink is not None:
+        denom = denom + jnp.exp(s - m)
+    p = (p / jnp.maximum(denom, 1e-20)).astype(v.dtype)
+    out = jnp.einsum("bngst,bntd->bsngd", p, v)
+    return out.reshape(B, S, nh, v.shape[-1])
+
+
+def _ffn(h, mlp_p, kind, cfg, valid, grad):
+    """h (N, D) -> (out (N, D), stats (5,) int32: assignments made, to held
+    experts, the most one held expert got, expert layers, held experts hit).
+    ``grad``: the caller differentiates this (the training forward)."""
+    tf = _tf()
+    if kind.ffn == "dense":
+        with jax.named_scope(Scope.MLP):
+            act = jax.nn.silu(tf._linear(h, mlp_p["wg"])) * tf._linear(h, mlp_p["wi"])
+            return tf._linear(act, mlp_p["wo"]), jnp.zeros((5,), jnp.int32)
+    from deepspeed_tpu.moe import held_experts as he
+
+    first, count = cfg.held_experts
+    chosen, weights = he.route(h, mlp_p["gate"], mlp_p["gate_bias"], cfg.moe_top_k)
+    out, counts = he.held_experts_ffn(
+        h, chosen, weights, {n: mlp_p[n] for n in _EXPERT_LEAVES}, first, count,
+        grad=grad, valid=valid, layer=mlp_p.get("layer"))
+    made = (h.shape[0] if valid is None else valid.sum(dtype=jnp.int32)) * cfg.moe_top_k
+    return out, jnp.stack([jnp.asarray(made, jnp.int32), counts.sum(dtype=jnp.int32),
+                           counts.max().astype(jnp.int32), jnp.int32(1),
+                           (counts > 0).sum(dtype=jnp.int32)])
+
+
+def _merge_stats(a, b):
+    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2]), a[3] + b[3],
+                      a[4] + b[4]])
+
+
+_EXPERT_LEAVES = ("wg", "wi", "wo")
+
+
+def _walk(cfg, layers, carry, layer_fn):
+    """Walk the plan: ``layer_fn(carry, layer_params, kind, pool_index) ->
+    carry`` over every layer, one ``lax.scan`` per run of equal layers (a
+    run of one is a plain call). An expert kind's expert weights do not ride
+    the scan as sliced inputs (a slice handed to the grouped-matmul kernel
+    is a copy of the layer's experts, every tick): ``layer_params["mlp"]``
+    carries the kind's whole stacks and ``"layer"``, the index into them."""
+    for run in runs(cfg):
+        stack = layers[run.kind.name]
+        experts = {}
+        if run.kind.ffn == "moe":
+            experts = {n: stack["mlp"][n] for n in _EXPERT_LEAVES}
+            stack = dict(stack, mlp={n: p for n, p in stack["mlp"].items()
+                                     if n not in _EXPERT_LEAVES})
+
+        def one(c, layer_p, kind_index, pool_index, kind=run.kind, experts=experts):
+            if experts:
+                layer_p = dict(layer_p, mlp=dict(layer_p["mlp"], layer=kind_index, **experts))
+            return layer_fn(c, layer_p, kind, pool_index)
+
+        if run.n == 1:
+            carry = one(carry, jax.tree.map(lambda p: p[run.kind_start], stack),
+                        jnp.int32(run.kind_start), jnp.int32(run.pool_start))
+            continue
+        if run.n != layers_of(cfg, run.kind):
+            stack = jax.tree.map(lambda p: p[run.kind_start:run.kind_start + run.n], stack)
+        steps = jnp.arange(run.n, dtype=jnp.int32)
+        carry, _ = jax.lax.scan(lambda c, inp: (one(c, *inp), None), carry,
+                                (stack, run.kind_start + steps, run.pool_start + steps))
+    return carry
+
+
+def _head(x, params, cfg):
+    tf = _tf()
+    x = tf._norm(x, params["final_norm"]["scale"], None, cfg)
+    return tf._vocab_head(x, params, cfg, cfg.jnp_dtype)
+
+
+# ---------------------------------------------------------------------------
+# no cache: training and the reference comparison
+# ---------------------------------------------------------------------------
+
+def forward_plan(params, cfg, tokens, return_hidden=False):
+    """tokens (B, S) -> (logits (B, S, V), 0.0): whole sequences, attention
+    by masked einsum, the expert layers' grouped matmul by ``ragged_dot``,
+    which has a gradient."""
+    tf = _tf()
+    dtype = cfg.jnp_dtype
+    B, S = tokens.shape
+    with jax.named_scope(Scope.EMBED):
+        x = jnp.take(params["embed"]["tok"], tokens, axis=0).astype(dtype)
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S)).reshape(-1)
+    qpos = jnp.arange(S, dtype=jnp.int32)[:, None]
+    kpos = jnp.arange(S, dtype=jnp.int32)[None, :]
+
+    def layer(x, layer_p, kind, _):
+        h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg).reshape(B * S, -1)
+        q, k, v = _project(h, layer_p["attn"], kind, cfg, positions)
+        ok = kpos <= qpos
+        if kind.window:
+            ok = ok & (qpos - kpos < kind.window)
+        with jax.named_scope(Scope.ATTN_WINDOW if kind.window else Scope.ATTN_FULL):
+            att = _grouped_attention(
+                q.reshape(B, S, *q.shape[1:]),
+                k.reshape(B, S, *k.shape[1:]).transpose(0, 2, 1, 3),
+                v.reshape(B, S, *v.shape[1:]).transpose(0, 2, 1, 3),
+                ok[None], layer_p["attn"].get("sink"), _scale(cfg))
+        x = x + tf._attn_out_proj(att.reshape(B, S, -1), layer_p["attn"], cfg)
+        h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg).reshape(B * S, -1)
+        out, _ = _ffn(h, layer_p["mlp"], kind, cfg, None, grad=True)
+        return x + out.reshape(B, S, -1)
+
+    if cfg.remat:
+        layer = jax.checkpoint(layer, policy=tf._resolve_remat_policy(cfg.remat_policy),
+                               static_argnums=(2,))
+    x = _walk(cfg, tf._cast_layers(params["layers"], dtype), x, layer)
+    x = tf._norm(x, params["final_norm"]["scale"], None, cfg)
+    if return_hidden:
+        return x, jnp.float32(0.0)
+    return tf._vocab_head(x, params, cfg, dtype), jnp.float32(0.0)
+
+
+# ---------------------------------------------------------------------------
+# the serving tick
+# ---------------------------------------------------------------------------
+
+def _layer_window(pool, layer, size):
+    """(B, H, size, x): the first ``size`` slots of every row of one layer."""
+    _, B, H, _, x = pool.shape
+    return jax.lax.dynamic_slice(pool, (layer, 0, 0, 0, 0), (1, B, H, size, x))[0]
+
+
+def _write_rows(pool, layer, new, cols, size):
+    """One token a row, ``new`` (B, H, x), into slot ``cols`` (B,) of its
+    row, in place: slice, select and update fuse into one pass over the
+    layer's first ``size`` slots. A column at or past ``size`` drops."""
+    window = _layer_window(pool, layer, size)
+    hit = cols[:, None] == jnp.arange(size, dtype=cols.dtype)[None, :]
+    window = jnp.where(hit[:, None, :, None], new.astype(pool.dtype)[:, :, None, :], window)
+    return jax.lax.dynamic_update_slice(pool, window[None], (layer, 0, 0, 0, 0))
+
+
+def _row_window(pool, layer, slot, start, size):
+    """(H, size, x): ``size`` slots of row ``slot`` of layer ``layer``."""
+    _, _, H, _, x = pool.shape
+    return jax.lax.dynamic_slice(pool, (layer, slot, 0, start, 0), (1, 1, H, size, x))[0, 0]
+
+
+def _write_row(pool, layer, slot, start, size, new, cols):
+    """``new`` (W, H, x) into slots ``start + cols`` (W,) of one row, through
+    a ``size``-slot window of it; a column outside the window drops. A
+    one-hot contraction lays the tokens out along the window (exact: one
+    term a slot), so nothing is scattered token by token."""
+    window = _row_window(pool, layer, slot, start, size)
+    hit = jnp.arange(size, dtype=cols.dtype)[:, None] == cols[None, :]          # (size, W)
+    placed = jnp.einsum("rs,shx->hrx", hit.astype(pool.dtype), new.astype(pool.dtype),
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32).astype(pool.dtype)
+    window = jnp.where(hit.any(axis=1)[None, :, None], placed, window)
+    return jax.lax.dynamic_update_slice(pool, window[None, None], (layer, slot, 0, start, 0))
+
+
+def _attend_cached(q, k, v, attn_p, kind, cfg, pk, pv, layer, pos, chunk, read_len, length):
+    """Attention of one layer of the tick: the B rows' single tokens against
+    their pool rows and, with ``chunk``, the chunk's W tokens against its
+    row. q/k/v hold the rows first, then the chunk. Returns ((N, nh * dv),
+    pool_k, pool_v)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_chunk
+
+    B = pos.shape[0]
+    sink, scale, R = attn_p.get("sink"), _scale(cfg), kind.window
+    size = R or read_len or length
+    with jax.named_scope(Scope.ATTN_KV_WRITE):
+        # a parked row (pos = the pool's length) writes nothing in either pool
+        cols = jnp.where(pos >= length, size, pos % R) if R else pos
+        pk = _write_rows(pk, layer, k[:B], cols, size)
+        pv = _write_rows(pv, layer, v[:B], cols, size)
+    with jax.named_scope(Scope.ATTN_KV_READ):
+        kw, vw = _layer_window(pk, layer, size), _layer_window(pv, layer, size)
+        slot = jnp.arange(size, dtype=jnp.int32)[None, :]
+        # the position a ring slot holds once this token is written: the largest one not
+        # past the row's depth that falls in the slot; below zero, nothing was written
+        kpos = pos[:, None] - ((pos[:, None] - slot) % R) if R else slot
+    with jax.named_scope(Scope.ATTN_WINDOW if R else Scope.ATTN_FULL):
+        ok = (kpos <= pos[:, None]) & (kpos >= 0)
+        rows = _grouped_attention(q[:B, None], kw, vw, ok[:, None, :], sink, scale)[:, 0]
+    if chunk is None:
+        return rows.reshape(B, -1), pk, pv
+
+    qc, kc, vc = q[B:], k[B:], v[B:]
+    W = qc.shape[0]
+    real = chunk.pos < length
+    first = chunk.pos[0]
+    if R:
+        with jax.named_scope(Scope.ATTN_KV_READ):
+            # the window before the chunk, in order of position: position first - R + j
+            # lives in slot (first + j) mod R
+            tail_k = jnp.roll(_row_window(pk, layer, chunk.slot, 0, R), -(first % R), axis=1)
+            tail_v = jnp.roll(_row_window(pv, layer, chunk.slot, 0, R), -(first % R), axis=1)
+        with jax.named_scope(Scope.ATTN_WINDOW):
+            out = flash_attention_chunk(
+                qc, jnp.concatenate([tail_k, kc.transpose(1, 0, 2)], axis=1),
+                jnp.concatenate([tail_v, vc.transpose(1, 0, 2)], axis=1), q_off=R,
+                k_min=jnp.maximum(R - first, 0), sink=sink, window=R, sm_scale=scale)
+        with jax.named_scope(Scope.ATTN_KV_WRITE):
+            last = first + real.sum(dtype=jnp.int32)
+            cols = jnp.where(real & (chunk.pos >= last - R), chunk.pos % R, R)
+            pk = _write_row(pk, layer, chunk.slot, 0, R, kc, cols)
+            pv = _write_row(pv, layer, chunk.slot, 0, R, vc, cols)
+    else:
+        width = min(W, size)
+        with jax.named_scope(Scope.ATTN_KV_WRITE):
+            start = jnp.clip(first, 0, size - width)
+            cols = jnp.where(real, chunk.pos - start, width)
+            pk = _write_row(pk, layer, chunk.slot, start, width, kc, cols)
+            pv = _write_row(pv, layer, chunk.slot, start, width, vc, cols)
+        with jax.named_scope(Scope.ATTN_KV_READ):
+            row_k = _row_window(pk, layer, chunk.slot, 0, size)
+            row_v = _row_window(pv, layer, chunk.slot, 0, size)
+        with jax.named_scope(Scope.ATTN_FULL):
+            out = flash_attention_chunk(qc, row_k, row_v, q_off=first, sink=sink, sm_scale=scale)
+    return jnp.concatenate([rows.reshape(B, -1), out.reshape(W, -1)]), pk, pv
+
+
+def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int] = None,
+                        chunk: Optional[Chunk] = None):
+    """One tick of a layer-plan model. tokens (B,), pos (B,): every slot's
+    next token and the count of tokens its row has cached (a parked row
+    carries the pool's length: it writes nothing and its output means
+    nothing). ``chunk``: ONE row's next prefill chunk, run beside the rows as
+    part of the same flat list of tokens; the row it belongs to is parked
+    among the rows, and its logits (at column ``chunk.emit``) take that
+    row's place in the output. ``read_len`` (static) tight-reads the full
+    pool. Returns (logits (B, V), cache, stats (5,) int32: expert assignments
+    made / to held experts / the most one held expert got in a layer /
+    expert layers / held experts that got a token, summed over the layers)."""
+    tf = _tf()
+    dtype = cfg.jnp_dtype
+    B = tokens.shape[0]
+    length = tf.cache_alloc_len(cache)
+    if read_len is not None and read_len >= length:
+        read_len = None
+    all_toks, all_pos = tokens, pos
+    if chunk is not None:
+        all_toks = jnp.concatenate([tokens, chunk.toks])
+        all_pos = jnp.concatenate([pos, chunk.pos])
+    with jax.named_scope(Scope.EMBED):
+        x = jnp.take(params["embed"]["tok"], all_toks, axis=0).astype(dtype)
+    valid = all_pos < length
+
+    def layer(carry, layer_p, kind, pool_index):
+        x, pools, stats = carry
+        pool = pools[kind.pool]
+        h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg)
+        q, k, v = _project(h, layer_p["attn"], kind, cfg, all_pos)
+        att, pk, pv = _attend_cached(q, k, v, layer_p["attn"], kind, cfg, pool["k"], pool["v"],
+                                     pool_index, pos, chunk, read_len, length)
+        x = x + tf._attn_out_proj(att, layer_p["attn"], cfg)
+        h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg)
+        out, st = _ffn(h, layer_p["mlp"], kind, cfg, valid, grad=False)
+        return x + out, dict(pools, **{kind.pool: {"k": pk, "v": pv}}), _merge_stats(stats, st)
+
+    x, cache, stats = _walk(cfg, tf._cast_layers(params["layers"], dtype),
+                            (x, cache, jnp.zeros((5,), jnp.int32)), layer)
+    rows = x[:B]
+    if chunk is not None:  # the admitting row's place is taken by the chunk's sampled column
+        rows = jax.lax.dynamic_update_slice(rows, x[B + chunk.emit][None], (chunk.slot, 0))
+    return _head(rows, params, cfg), cache, stats

@@ -35,6 +35,26 @@ from deepspeed_tpu.telemetry.hlo_scopes import Scope
 
 
 @dataclass(frozen=True)
+class LayerKind:
+    """One kind of decoder layer in a layer plan: the shape of its attention
+    and the kind of its FFN. Layers of one kind share parameter shapes and
+    are stacked together (``params["layers"][name]``); layers whose
+    attention has the same reach (``window`` 0 or not) share a KV pool."""
+    name: str
+    kv_heads: int
+    window: int = 0  # 0 = full causal attention; W = the last W positions
+    rope_theta: float = 10000.0
+    sink: bool = False  # a learned per-head logit joins the softmax's denominator
+    ffn: str = "dense"  # dense | moe (sigmoid top-k over the experts held)
+    ffn_size: Optional[int] = None  # None => cfg.ffn_size
+
+    @property
+    def pool(self) -> str:
+        """The KV pool this kind's layers live in."""
+        return "window" if self.window > 0 else "full"
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 50257
     hidden_size: int = 768
@@ -118,6 +138,25 @@ class TransformerConfig:
     # p_l = 1 - (l/L) * (1 - theta); theta is a dynamic scalar from the
     # engine's PLD schedule (runtime/progressive_layer_drop.py)
     pld_enabled: bool = False
+    # --- head widths free of hidden // heads (None => the derived width) ---
+    head_size: Optional[int] = None  # query/key head width
+    v_head_size: Optional[int] = None  # value head width (None => head_dim)
+    attn_value_scale: Optional[float] = None  # v = scale * (h Wv)
+    # --- layer plan (models/layer_plan.py): layers of several kinds in one
+    # stack. ``layer_kinds`` lists the kinds, ``layer_plan`` gives each
+    # layer's index into it; parameters are stacked per kind and the KV
+    # cache is one pool per attention reach (full length / a ring of
+    # ``window`` positions). None = one kind, the single scan above.
+    layer_kinds: Optional[tuple] = None
+    layer_plan: Optional[tuple] = None
+    # expert layers of a plan: sigmoid scores over ``moe_num_experts``,
+    # top ``moe_top_k`` by score + selection bias, weights normalised, no
+    # capacity and no dropped token; this chip holds the contiguous experts
+    # [first, first + count) and computes their part of the result
+    moe_experts_held: Optional[tuple] = None  # (first, count); None => all
+    # leaves made in the model dtype at init (a model whose float32 leaves
+    # would not fit beside their cast copy)
+    init_in_model_dtype: bool = False
 
     def __post_init__(self):
         # accept a dict for sparse_attention (user-facing) but store a
@@ -126,6 +165,10 @@ class TransformerConfig:
             object.__setattr__(
                 self, "sparse_attention", tuple(sorted(self.sparse_attention.items()))
             )
+        if self.layer_kinds is not None:
+            from deepspeed_tpu.models.layer_plan import check_plan
+
+            check_plan(self)
 
     @property
     def uniform_window(self) -> Optional[int]:
@@ -147,7 +190,23 @@ class TransformerConfig:
 
     @property
     def head_dim(self):
-        return self.hidden_size // self.num_heads
+        return self.head_size or self.hidden_size // self.num_heads
+
+    @property
+    def v_head_dim(self):
+        return self.v_head_size or self.head_dim
+
+    @property
+    def plan(self) -> Optional[tuple]:
+        """The layer plan as LayerKind objects, layer by layer; None for a
+        model of one kind."""
+        if self.layer_kinds is None:
+            return None
+        return tuple(self.layer_kinds[i] for i in self.layer_plan)
+
+    @property
+    def held_experts(self) -> tuple:
+        return self.moe_experts_held or (0, self.moe_num_experts)
 
     @property
     def kv_heads(self):
@@ -164,6 +223,10 @@ class TransformerConfig:
         return {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}[self.dtype]
 
     def num_params(self) -> int:
+        if self.layer_kinds is not None:
+            from deepspeed_tpu.models.layer_plan import num_params
+
+            return num_params(self)
         D, V, L, F = self.hidden_size, self.vocab_size, self.num_layers, self.ffn_size
         kvd = self.kv_heads * self.head_dim
         attn = D * D + 2 * D * kvd + D * D  # q,k,v,o
@@ -369,6 +432,13 @@ def init(rng, cfg: TransformerConfig):
     """Build the parameter pytree (all leaves fp32; engine casts as needed)."""
     r_outer, r_layers = jax.random.split(rng)
     params = init_outer(r_outer, cfg)
+    if cfg.layer_kinds is not None:
+        from deepspeed_tpu.models.layer_plan import init_layers
+
+        params["layers"] = init_layers(r_layers, cfg)
+        if cfg.init_in_model_dtype:
+            params = jax.tree.map(lambda p: p.astype(cfg.jnp_dtype), params)
+        return params
     params["layers"] = init_layer_slice(r_layers, cfg, 0, cfg.num_layers)
     return params
 
@@ -389,15 +459,19 @@ def logical_specs(params, cfg: TransformerConfig):
             table = {
                 "wq": ("embed", "heads"), "wk": ("embed", "kv"), "wv": ("embed", "kv"),
                 "wo": ("heads", "embed"), "bq": ("heads",), "bk": ("kv",), "bv": ("kv",), "bo": ("embed",),
+                "sink": ("heads",),
             }
             return pre + table[last]
         if "mlp" in names:
-            if cfg.moe_num_experts > 0 and last in ("wi", "wg", "wo", "bi", "bo"):
+            # a layer plan has dense layers beside its expert layers: the rank tells them apart
+            if (cfg.moe_num_experts > 0 and last in ("wi", "wg", "wo", "bi", "bo")
+                    and leaf.ndim == len(pre) + (3 if last[0] == "w" else 2)):
                 table = {"wi": ("expert", "embed", "mlp"), "wg": ("expert", "embed", "mlp"),
                          "wo": ("expert", "mlp", "embed"), "bi": ("expert", "mlp"), "bo": ("expert", "embed")}
                 return pre + table[last]
             table = {"wi": ("embed", "mlp"), "wg": ("embed", "mlp"), "wo": ("mlp", "embed"),
                      "bi": ("mlp",), "bo": ("embed",), "gate": ("embed", None),
+                     "gate_bias": (None,),
                      # PR-MoE residual MLP + mixing coefficient (dense)
                      "res_wi": ("embed", "mlp"), "res_wg": ("embed", "mlp"),
                      "res_wo": ("mlp", "embed"), "res_bi": ("mlp",), "res_bo": ("embed",),
@@ -893,6 +967,10 @@ def forward(params, cfg: TransformerConfig, tokens, dropout_rng=None,
     stochastic depth with keep prob 1 - (l/L)(1-theta) (reference
     progressive_layer_drop.py, consumed at engine.py:1512).
     """
+    if cfg.layer_kinds is not None:
+        from deepspeed_tpu.models.layer_plan import forward_plan
+
+        return forward_plan(params, cfg, tokens, return_hidden=return_hidden)
     dtype = cfg.jnp_dtype
     B, S = tokens.shape
     with jax.named_scope(Scope.EMBED):
@@ -1182,6 +1260,10 @@ def init_cache(cfg: TransformerConfig, batch_size: int, max_len: Optional[int] =
     layer axis stays unsharded and leading: ``forward_with_cache`` carries
     the whole pool through its layer scan and indexes it by layer."""
     T = max_len or cfg.max_seq_len
+    if cfg.layer_kinds is not None:
+        from deepspeed_tpu.models.layer_plan import init_pools
+
+        return init_pools(cfg, batch_size, T)
     shape = (cfg.num_layers, batch_size, T, cfg.kv_heads, cfg.head_dim)
     if cfg.kv_cache_dtype == "int8":
         def q_component():
@@ -1196,7 +1278,10 @@ def init_cache(cfg: TransformerConfig, batch_size: int, max_len: Optional[int] =
 
 
 def cache_alloc_len(cache) -> int:
-    """Allocated time-axis length of a cache pytree (dense or int8)."""
+    """Allocated time-axis length of a cache pytree (dense or int8; of a
+    layer plan's pools, the full-length pool's)."""
+    if "full" in cache:  # heads before time there (models/layer_plan.py)
+        return cache["full"]["k"].shape[3]
     return jax.tree.leaves(cache)[0].shape[2]
 
 
@@ -1215,6 +1300,10 @@ def kv_read_bytes_per_row(cfg: TransformerConfig, read_len: int,
     head shard, so the PER-CHIP bytes — the quantity that bounds a
     bandwidth-limited decode step — divide by it. Must divide kv_heads
     (the caller resolves the replicated fallback to tp=1)."""
+    if cfg.layer_kinds is not None:
+        from deepspeed_tpu.models.layer_plan import kv_read_bytes_by_pool
+
+        return sum(kv_read_bytes_by_pool(cfg, read_len).values()) // tp
     assert cfg.kv_heads % tp == 0, (cfg.kv_heads, tp)
     if cfg.kv_cache_dtype == "int8":
         per_slot = cfg.kv_heads * (cfg.head_dim * 1 + 4)  # q8 payload + s
@@ -1337,6 +1426,17 @@ def forward_with_cache(params, cfg: TransformerConfig, tokens, cache, pos, posit
     reads its window straight back (softmax_context), so with the cache
     donated a call moves the windows it reads and no layer-sized copy.
     Returns (logits (B,S,V), updated cache)."""
+    if cfg.layer_kinds is not None:
+        from deepspeed_tpu.models.layer_plan import forward_plan_cached
+
+        if tokens.shape[1] != 1 or jnp.ndim(pos) != 1 or positions is not None:
+            raise NotImplementedError(
+                "a layer-plan model takes single-token rows at per-row depths here; its "
+                "prompts are prefilled chunk by chunk in the serving tick "
+                "(layer_plan.forward_tick)")
+        logits, cache, _ = forward_plan_cached(params, cfg, tokens[:, 0], pos, cache,
+                                               read_len=read_len)
+        return logits[:, None], cache
     dtype = cfg.jnp_dtype
     B, S = tokens.shape
     if read_len is not None and read_len >= cache_alloc_len(cache):

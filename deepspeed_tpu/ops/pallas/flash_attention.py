@@ -484,6 +484,115 @@ def flash_attention(
     return jnp.transpose(o, (0, 2, 1, 3))
 
 
+# ---------------------------------------------------------------------------
+# one prefill chunk against a cached row (forward only)
+# ---------------------------------------------------------------------------
+
+def _chunk_kernel(scal_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                  sm_scale, bq, bk, nk, window, has_sink):
+    h, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    q_off, k_min = scal_ref[0], scal_ref[1]
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    q_lo, k_lo = q_off + qi * bq, ki * bk
+    should_compute = (k_lo <= q_lo + bq - 1) & (k_lo + bk - 1 >= k_min)
+    if window is not None:
+        should_compute = should_compute & (k_lo + bk - 1 > q_lo - window)
+
+    @pl.when(should_compute)
+    def _compute():
+        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * sm_scale  # (bq, bk) f32
+        qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        ok = (kpos <= qpos) & (kpos >= k_min)
+        if window is not None:
+            ok = ok & (qpos - kpos < window)
+        s = jnp.where(ok, s, NEG_INF)
+        m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)  # a row all masked so far keeps l = 0
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(ki == nk - 1)
+    def _finalize():
+        m, l, acc = m_scr[:, :1], l_scr[:, :1], acc_scr[...]
+        if has_sink:  # the sink joins the denominator and brings no value
+            sink = sink_ref[h]
+            m_all = jnp.maximum(m, sink)
+            corr = jnp.exp(m - m_all)
+            l, acc = l * corr + jnp.exp(sink - m_all), acc * corr
+        o_ref[...] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+
+
+def flash_attention_chunk(q, k, v, q_off, k_min=0, sink=None, window: Optional[int] = None,
+                          sm_scale: Optional[float] = None, interpret: Optional[bool] = None):
+    """One row's prefill chunk against that row's cached keys: q (W, H, dk)
+    at key-index ``q_off + i`` for query i; k (Hkv, T, dk) and v (Hkv, T, dv)
+    head-major, as a layer plan's pools keep a row (dv may differ from dk);
+    returns (W, H, dv). Query i attends key j where
+    ``k_min <= j <= q_off + i`` and, with ``window``, ``q_off + i - j <
+    window``. ``q_off`` and ``k_min`` are traced scalars (the chunk's depth
+    in its row): key tiles outside that range are neither fetched nor
+    computed. ``sink`` (H,) float32: a per-head logit that joins the
+    softmax's denominator and contributes no value. Forward only."""
+    W, H, dk = q.shape
+    Hkv, T, dv = v.shape
+    group = H // Hkv
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(dk)
+    bq, bk = _auto_block(W, None), _auto_block(T, None)
+    nq, nk = W // bq, T // bk
+    has_sink = sink is not None
+    scal = jnp.stack([jnp.asarray(q_off, jnp.int32), jnp.asarray(k_min, jnp.int32)])
+    sink = (jnp.zeros((H,), jnp.float32) if sink is None else sink.astype(jnp.float32))
+
+    def k_index(h, qi, ki, scal_ref, sink_ref):
+        q_lo = scal_ref[0] + qi * bq
+        lo = scal_ref[1]
+        if window is not None:
+            lo = jnp.maximum(lo, q_lo - window + 1)
+        hi = jnp.minimum((q_lo + bq - 1) // bk, nk - 1)
+        return h // group, jnp.clip(ki, jnp.minimum(jnp.maximum(lo, 0) // bk, hi), hi), 0
+
+    def q_index(h, qi, ki, scal_ref, sink_ref):
+        return h, qi, 0
+
+    out = pl.pallas_call(
+        functools.partial(_chunk_kernel, sm_scale=sm_scale, bq=bq, bk=bk, nk=nk, window=window,
+                          has_sink=has_sink),
+        name="flash_chunk_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(H, nq, nk),
+            in_specs=[pl.BlockSpec((None, bq, dk), q_index),
+                      pl.BlockSpec((None, bk, dk), k_index),
+                      pl.BlockSpec((None, bk, dv), k_index)],
+            out_specs=pl.BlockSpec((None, bq, dv), q_index),
+            scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
+                            pltpu.VMEM((bq, 128), jnp.float32),
+                            pltpu.VMEM((bq, dv), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((H, W, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
+    )(scal, sink, jnp.transpose(q, (1, 0, 2)), k, v)
+    return jnp.transpose(out, (1, 0, 2))
+
+
 def mha_reference(q, k, v, causal: bool = True, sm_scale: Optional[float] = None,
                   window: Optional[int] = None):
     """jnp reference for parity tests."""

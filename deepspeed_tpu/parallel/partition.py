@@ -282,6 +282,6 @@ def kv_shard_width(mesh: Optional[Mesh], cfg) -> int:
     shards over ``tensor`` only when kv_heads divide evenly; otherwise
     the cache replicates and every chip reads full rows."""
     t = mesh_tensor_width(mesh)
-    if t <= 1 or cfg.kv_heads % t != 0:
+    if t <= 1 or cfg.kv_heads % t != 0 or getattr(cfg, "layer_kinds", None) is not None:
         return 1
     return t

@@ -38,6 +38,10 @@ class Scope:
     ATTN_CORE = "attn.core"
     ATTN_OUT = "attn.out"
     MLP = "mlp"
+    ATTN_FULL = "attn.full"      # a layer plan's full-attention core
+    ATTN_WINDOW = "attn.window"  # ... and its sliding-window core
+    MOE_ROUTE = "moe.route"      # router scores, top-k, the sort by expert
+    MOE_EXPERTS = "moe.experts"  # gather, grouped matmuls over the held experts, combine
     NORM = "norm"
     LM_HEAD = "lm_head"
     LOSS = "loss"

@@ -1,0 +1,153 @@
+"""The expert layer that is told which experts it holds: sigmoid top-k
+routing with a selection bias, no capacity and no dropped token, the shares
+of an expert-parallel deployment adding up to the whole layer, and the
+grouped matmul (Pallas, in interpret mode here) against plain matmuls."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.moe import held_experts as he
+from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+N, D, F, E, K = 37, 32, 48, 16, 4
+
+
+@pytest.fixture(scope="module")
+def layer():
+    rs = np.random.RandomState(0)
+    f = lambda *shape, scale=1.0: jnp.asarray(rs.randn(*shape) * scale, jnp.float32)
+    return dict(h=f(N, D), gate=f(D, E, scale=0.3), bias=f(E, scale=0.01),
+                experts={"wg": f(E, D, F, scale=0.2), "wi": f(E, D, F, scale=0.2),
+                         "wo": f(E, F, D, scale=0.2)})
+
+
+def dense(layer, chosen, weights, first, count):
+    """Every held expert on every token, weighed by its routing weight or zero."""
+    h, ex = layer["h"], layer["experts"]
+    out = jnp.zeros((N, D))
+    for e in range(first, first + count):
+        y = (jax.nn.silu(h @ ex["wg"][e]) * (h @ ex["wi"][e])) @ ex["wo"][e]
+        out += y * jnp.where(chosen == e, weights, 0).sum(1)[:, None]
+    return out
+
+
+def share(layer, first, count):
+    return {n: w[first:first + count] for n, w in layer["experts"].items()}
+
+
+def test_route_scores_by_sigmoid_chooses_by_score_plus_bias_and_normalises(layer):
+    chosen, weights = he.route(layer["h"], layer["gate"], layer["bias"], K)
+    scores = 1 / (1 + np.exp(-np.asarray(layer["h"] @ layer["gate"], np.float64)))
+    want = np.argsort(-(scores + np.asarray(layer["bias"])), axis=1)[:, :K]
+    assert (np.sort(np.asarray(chosen), 1) == np.sort(want, 1)).all()
+    picked = np.take_along_axis(scores, np.asarray(chosen), axis=1)
+    assert np.allclose(weights, picked / picked.sum(1, keepdims=True), atol=1e-6)
+    assert np.allclose(np.asarray(weights).sum(1), 1.0, atol=1e-6)  # the bias enters no weight
+
+
+def test_the_bias_decides_a_choice_and_no_weight(layer):
+    tilted = layer["bias"].at[3].add(10.0)
+    chosen, weights = he.route(layer["h"], layer["gate"], tilted, K)
+    assert (np.asarray(chosen) == 3).any(1).all()  # every token takes expert 3 now
+    w3 = np.asarray(jnp.where(chosen == 3, weights, 0).sum(1))
+    assert (w3 < 0.9).all()  # ... at its unbiased score's weight
+
+
+def test_router_runs_in_float32_whatever_the_weights_are_stored_in(layer):
+    chosen, weights = he.route(layer["h"].astype(jnp.bfloat16), layer["gate"].astype(jnp.bfloat16),
+                               layer["bias"].astype(jnp.bfloat16), K)
+    assert weights.dtype == jnp.float32 and chosen.dtype == jnp.int32
+
+
+@pytest.mark.parametrize("grad", [False, True])
+@pytest.mark.parametrize("first,count", [(0, 16), (4, 4), (12, 4), (7, 1)])
+def test_held_share_is_the_held_experts_part_of_the_layer(layer, grad, first, count):
+    chosen, weights = he.route(layer["h"], layer["gate"], layer["bias"], K)
+    out, counts = he.held_experts_ffn(layer["h"], chosen, weights, share(layer, first, count),
+                                      first, count, grad=grad, tm=8)
+    assert np.allclose(out, dense(layer, chosen, weights, first, count), atol=2e-5)
+    want = [(np.asarray(chosen) == e).sum() for e in range(first, first + count)]
+    assert list(np.asarray(counts)) == want
+
+
+@pytest.mark.parametrize("shares", [16, 4, 2])
+def test_the_shares_add_up_to_the_whole_layer(layer, shares):
+    chosen, weights = he.route(layer["h"], layer["gate"], layer["bias"], K)
+    count = E // shares
+    total = sum(he.held_experts_ffn(layer["h"], chosen, weights, share(layer, s * count, count),
+                                    s * count, count, tm=8)[0]
+                for s in range(shares))
+    whole = he.held_experts_ffn(layer["h"], chosen, weights, layer["experts"], 0, E, tm=8)[0]
+    assert np.allclose(total, whole, atol=5e-5)
+    assert np.allclose(whole, dense(layer, chosen, weights, 0, E), atol=5e-5)
+
+
+@pytest.mark.parametrize("grad", [False, True])
+def test_no_token_is_dropped_when_every_token_takes_one_expert(layer, grad):
+    skewed = layer["bias"].at[jnp.array([5, 6, 7, 9])].add(10.0)  # all N tokens to four experts
+    chosen, weights = he.route(layer["h"], layer["gate"], skewed, K)
+    out, counts = he.held_experts_ffn(layer["h"], chosen, weights, share(layer, 4, 4), 4, 4,
+                                      grad=grad, tm=8)
+    assert list(np.asarray(counts)) == [0, N, N, N]  # experts 4..7: three of the four chosen
+    assert np.allclose(out, dense(layer, chosen, weights, 4, 4), atol=5e-5)
+
+
+def test_a_token_that_is_not_valid_is_neither_computed_nor_counted(layer):
+    chosen, weights = he.route(layer["h"], layer["gate"], layer["bias"], K)
+    valid = jnp.arange(N) % 3 != 0
+    out, counts = he.held_experts_ffn(layer["h"], chosen, weights, layer["experts"], 0, E,
+                                      valid=valid, tm=8)
+    want = dense(layer, chosen, weights, 0, E)
+    assert np.allclose(out[valid], want[valid], atol=5e-5) and not np.asarray(out[~valid]).any()
+    assert int(counts.sum()) == int(valid.sum()) * K
+
+
+@pytest.mark.parametrize("tm", [8, 16, 128])
+def test_layout_pads_each_expert_to_whole_tiles_and_names_each_tiles_expert(layer, tm):
+    chosen, _ = he.route(layer["h"], layer["gate"], layer["bias"], K)
+    lay = he.layout(chosen, 4, 8, tm)
+    M = he.buffer_rows(N, K, 8, tm)
+    assert lay.src.shape == (M,) and M % tm == 0
+    counts = np.asarray(lay.counts)
+    assert int(lay.num_tiles[0]) == sum(-(-c // tm) for c in counts)
+    src, dest = np.asarray(lay.src), np.asarray(lay.dest)
+    for n in range(N):
+        for j in range(K):
+            e = int(chosen[n, j]) - 4
+            if 0 <= e < 8:
+                assert src[dest[n, j]] == n and lay.tile_group[dest[n, j] // tm] == e
+            else:
+                assert dest[n, j] == M
+    assert (src < N).sum() == counts.sum()  # every other row is the zero row
+
+
+def test_buffer_holds_the_worst_routing():
+    assert he.buffer_rows(32, 8, 16, 16) == 32 * 8 + 16 * 15 + 0  # already whole tiles
+    assert he.buffer_rows(10, 8, 2, 8) == 40  # a token takes at most the 2 held experts
+
+
+@pytest.mark.parametrize("layer_index", [None, 1])
+def test_grouped_matmul_reads_the_groups_named_and_skips_unused_tiles(layer_index):
+    rs = np.random.RandomState(1)
+    G, tm, Kd, Nd = 3, 8, 16, 24
+    w = jnp.asarray(rs.randn(2, G, Kd, Nd), jnp.float32)
+    x = jnp.asarray(rs.randn(6 * tm, Kd), jnp.float32)
+    groups = jnp.asarray([0, 0, 2, 2, 2, 1], jnp.int32)   # the last two tiles are not in use
+    out = grouped_matmul(x, w if layer_index is not None else w[0], groups,
+                         jnp.asarray([4], jnp.int32), tm=tm, layer=layer_index)
+    wl = w[layer_index or 0]
+    for t in range(4):
+        assert np.allclose(out[t * tm:(t + 1) * tm], x[t * tm:(t + 1) * tm] @ wl[int(groups[t])],
+                           atol=1e-5)
+
+
+def test_the_xla_form_has_a_gradient(layer):
+    chosen, weights = he.route(layer["h"], layer["gate"], layer["bias"], K)
+
+    def loss(ex):
+        return he.held_experts_ffn(layer["h"], chosen, weights, ex, 0, E, grad=True, tm=1)[0].sum()
+
+    g = jax.grad(loss)(layer["experts"])
+    assert all(np.isfinite(np.asarray(v)).all() and float(jnp.abs(v).sum()) > 0 for v in g.values())

@@ -1,0 +1,121 @@
+"""Ahead-of-time compiles, for a *described* ``v5e:2x2``, of what a layer
+plan adds: the flash chunk kernel at the published head widths (192 for
+queries and keys, not a multiple of the 128 lanes; 128 for values), the
+grouped matmul over the held experts, and the MiMo-V2.5 serving tick at the
+benchmark's cut (``benchmark/configs/mimo-v2.5.json``, 32 slots of 16,896
+positions): both pools updated in place, no copy of a pool, temporaries
+far under the pools. Nothing runs. Skipped where libtpu cannot describe
+the topology."""
+
+import json
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from deepspeed_tpu import comm
+from deepspeed_tpu.ops.pallas.interpret import force_interpret
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to compile against
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _compile(topo, fn, *shapes):
+    sh = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in shapes]
+    with force_interpret(False):
+        lowered = jax.jit(fn).lower(*args)
+    assert "tpu_custom_call" in lowered.as_text()
+    return lowered.compile()
+
+
+@pytest.mark.parametrize("W,T,kv,window", [(1024, 16896, 4, None), (1024, 1152, 8, 128),
+                                           (256, 384, 8, 128), (512, 2048, 4, None)])
+def test_flash_chunk_kernel_compiles_at_the_published_widths(topo, W, T, kv, window):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_chunk
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+    _compile(topo, lambda q, k, v, a, b, s: flash_attention_chunk(q, k, v, a, b, s, window),
+             ((W, 64, 192), bf), ((kv, T, 192), bf), ((kv, T, 128), bf), ((), i32), ((), i32),
+             ((64,), jnp.float32))
+
+
+@pytest.mark.parametrize("tokens", [32, 1056], ids=["decode-rows", "rows-and-a-chunk"])
+def test_held_experts_layer_compiles_at_the_published_widths(topo, tokens):
+    from deepspeed_tpu.moe import held_experts as he
+
+    def layer(h, gate, bias, wg, wi, wo):
+        chosen, weights = he.route(h, gate, bias, 8)
+        return he.held_experts_ffn(h, chosen, weights, {"wg": wg, "wi": wi, "wo": wo}, 48, 16,
+                                   layer=jnp.int32(2))
+
+    bf = jnp.bfloat16
+    compiled = _compile(topo, layer, ((tokens, 4096), bf), ((4096, 256), bf), ((256,), bf),
+                        ((5, 16, 4096, 2048), bf), ((5, 16, 4096, 2048), bf),
+                        ((5, 16, 2048, 4096), bf))
+    # the kernel reads the layer out of the stack: no copy of a layer's experts (268 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
+
+
+@pytest.mark.parametrize("read_len,chunk", [(None, None), (1024, None), (None, 1024), (2048, 256)],
+                         ids=["plain", "plain-read1024", "fused1024", "fused256-read2048"])
+def test_mimo_tick_updates_both_pools_in_place(topo, read_len, chunk):
+    from benchmark import models_mimo_v2
+    from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
+    from deepspeed_tpu.models import transformer as tf
+
+    with open(os.path.join(ROOT, "benchmark", "configs", "mimo-v2.5.json")) as fh:
+        config = json.load(fh)
+    slots, length = 32, 16896
+    model = models_mimo_v2.build_model(config, max_seq_len=length, remat=False, attn_impl="pallas")
+    cfg = model.cfg
+    mesh = comm.build_mesh({"data": 1, "tensor": 1}, devices=topo.devices[:1])
+    one = NamedSharding(mesh, PartitionSpec())
+    abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    p_sh = jax.tree.map(lambda a: one, abstract)
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one),
+                          abstract)
+    with force_interpret(False):
+        fn, cache_sh, _ = compile_pool_tick_fn(mesh, cfg, p_sh, slots, length, 1, 0.0, 0, 1.0,
+                                               read_len=read_len, chunk=chunk)
+        cache = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            jax.eval_shape(lambda: tf.init_cache(cfg, slots, length)), cache_sh)
+        row = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+        args = [params, cache, row, row, row, row, row, row, jax.ShapeDtypeStruct((2,), jnp.uint32)]
+        if chunk is not None:
+            wide = jax.ShapeDtypeStruct((chunk,), jnp.int32)
+            args += [wide, wide, jax.ShapeDtypeStruct((), jnp.int32), row, row]
+        compiled = fn.lower(*args).compile()
+    comm.destroy()
+    pool_bytes = sum(a.size * 2 for a in jax.tree.leaves(cache))
+    assert pool_bytes == 32 * (2 * 4 * 16896 + 5 * 8 * 128) * 320 * 2   # 2.87 GB, not 19.4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes / 8, mem.temp_size_in_bytes
+    resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert resident < 15.75e9
+    # the full-length pool is never copied (the ring, 0.1 GB, may be re-laid out for a chunk)
+    copies = re.findall(r"= bf16\[(?:\d+,)?32,4,16896,\d+\]\S* copy\(", compiled.as_text())
+    assert not copies, copies
