@@ -104,12 +104,15 @@ from deepspeed_tpu.telemetry.spans import host_span
 # geometry (decoding.read_bucket); the old local name stays importable
 _bucket = read_bucket
 
-# smallest fused-prefill chunk program width (power-of-2 buckets up to the
-# pool's chunk cap bound the static-shape program family); a layer plan's
-# chunks ride the flash chunk kernel (key tiles of 128), and starting at 256
-# keeps its family of tick programs (widths x read buckets) small
-_CHUNK_FLOOR = 16
+# fused-prefill chunk program widths. A one-kind model's tick runs the chunk
+# beside the rows (B + W tokens), where a full-width chunk costs under a
+# hundredth of a tick more than a narrow one: ONE width, the pool's chunk
+# cap, so one fused program a read bucket (floor None). A layer plan's chunks
+# ride the flash chunk kernel (key tiles of 128): power-of-2 widths from 256
+# up to the cap. The speculative pool's chunk still runs the (B, W) segment
+# program under every slot, where a narrow last chunk pays: widths from 16 up.
 _PLAN_CHUNK_FLOOR = 256
+_SPEC_CHUNK_FLOOR = 16
 
 
 @dataclass
@@ -343,7 +346,7 @@ class ContinuousBatchingEngine:
         # the footprint (see PERF.md bucketed-KV table)
         self.cfg = self._eng._ring_off_cfg
         self.mesh = self._eng.mesh
-        self._chunk_floor = (_CHUNK_FLOOR if self.cfg.layer_kinds is None
+        self._chunk_floor = (None if self.cfg.layer_kinds is None
                              else _PLAN_CHUNK_FLOOR)
         # a layer plan with expert layers: its ticks return routing counters
         self._moe_stats = (self.cfg.layer_kinds is not None
@@ -474,12 +477,17 @@ class ContinuousBatchingEngine:
                             "block_ms_fused": 0.0,
                             # how long the prefill queues stood when
                             # each step looked (÷ steps = mean depth)
-                            "prefill_q_depth_sum": 0}
+                            "prefill_q_depth_sum": 0,
+                            # what the fused ticks' chunks carried, counted
+                            # where a chunk is dispatched: real prompt
+                            # tokens, and the pads that filled the
+                            # program's width (pad share = pad / (real + pad))
+                            "prefill_chunk_tokens": 0,
+                            "prefill_pad_tokens": 0}
         if self.cfg.layer_kinds is not None:
-            # what the prefill chunks' attention had to do, counted where a
-            # chunk is dispatched: real tokens, (query, key) pairs attended
-            # in a full and in a window layer, keys a full layer read
-            self._tick_stats.update(prefill_chunk_tokens=0, prefill_pairs_full=0,
+            # what those chunks' attention had to do: (query, key) pairs
+            # attended in a full and in a window layer, keys a full layer read
+            self._tick_stats.update(prefill_pairs_full=0,
                                     prefill_pairs_window=0, prefill_keys_full=0)
             self._window = max(k.window for k in self.cfg.layer_kinds)
         if self._moe_stats:
@@ -1124,6 +1132,14 @@ class ContinuousBatchingEngine:
             self.cfg, read_len if read_len is not None else pool.length,
             tp=kv_shard_width(self.mesh, self.cfg))
 
+    def _chunk_width(self, pool: _Pool, nreal: int) -> int:
+        """Width of the fused tick program that carries ``nreal`` prompt
+        tokens: the pool's chunk cap (a one-kind model: one fused program a
+        read bucket), or the power-of-2 bucket from the plan's floor up."""
+        if self._chunk_floor is None:
+            return pool.chunk_cap
+        return _bucket(nreal, pool.chunk_cap, self._chunk_floor)
+
     def _tick_fn(self, pool: _Pool, read_len: Optional[int],
                  chunk: Optional[int] = None):
         """The pool's compiled tick program at (chunk width, tight-read
@@ -1197,13 +1213,14 @@ class ContinuousBatchingEngine:
             ctoks, cpos0, nreal, emits = admit.chunks[0]
             aslot = admit.slot
             self._mark_prefill_start(admit)
-            W = _bucket(nreal, pool.chunk_cap, self._chunk_floor)
+            W = self._chunk_width(pool, nreal)
             extent = max(extent, cpos0 + nreal)
             read_len = self._read_len(pool, extent)
             fn = self._tick_fn(pool, read_len, chunk=W)
+            st = self._tick_stats
+            st["prefill_chunk_tokens"] += nreal
+            st["prefill_pad_tokens"] += W - nreal
             if self.cfg.layer_kinds is not None:
-                st = self._tick_stats
-                st["prefill_chunk_tokens"] += nreal
                 st["prefill_pairs_full"] += nreal * cpos0 + nreal * (nreal + 1) // 2
                 st["prefill_pairs_window"] += int(np.minimum(
                     np.arange(cpos0 + 1, cpos0 + nreal + 1), self._window).sum())
@@ -1322,7 +1339,7 @@ class ContinuousBatchingEngine:
             admit = pool.prefill_q[0]
             ctoks, cpos0, nreal, _ = admit.chunks.pop(0)
             self._mark_prefill_start(admit)
-            W = _bucket(nreal, pool.chunk_cap, self._chunk_floor)
+            W = _bucket(nreal, pool.chunk_cap, _SPEC_CHUNK_FLOOR)
             seg_toks = np.zeros((n, W), np.int32)
             seg_toks[admit.slot, :nreal] = ctoks
             seg_pos = np.full(n, pool.length, np.int32)
@@ -1731,7 +1748,7 @@ class ContinuousBatchingEngine:
                 continue
             chunks: List[Optional[int]] = [None]
             if self.fused_prefill:
-                chunks += sorted({_bucket(m, pool.chunk_cap, self._chunk_floor)
+                chunks += sorted({self._chunk_width(pool, m)
                                   for m in range(1, pool.chunk_cap + 1)})
             for rl in read_lens:
                 for ch in chunks:
@@ -1800,7 +1817,7 @@ class ContinuousBatchingEngine:
         if self.fused_prefill:
             # fused spec admission dispatches prompt chunks through the
             # shared segment program — retraces per chunk width
-            for W in sorted({_bucket(m, pool.chunk_cap, self._chunk_floor)
+            for W in sorted({_bucket(m, pool.chunk_cap, _SPEC_CHUNK_FLOOR)
                              for m in range(1, pool.chunk_cap + 1)}):
                 t0 = time.time()
                 cache = jax.device_put(

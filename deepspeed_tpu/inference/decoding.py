@@ -524,10 +524,12 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
 
     Fused prefill (``chunk=W``, requires ``n_tokens == 1``): the same tick
     additionally prefills ONE admitting row's next W-wide prompt chunk
-    inside the same dispatch (Dynamic-SplitFuse-style) — decode rows ride
-    column 0, the admitting row carries ``chunk_toks``/``chunk_pos`` (pads
-    parked at ``cache_len``), and ``emit_col``/``emit_mask`` route sampling
-    to the admitting row's last real prompt column on its final chunk::
+    inside the same dispatch (Dynamic-SplitFuse-style) — the program runs
+    B + W tokens, the rows' ``last_tok`` (the admitting row parked among
+    them) followed by ``chunk_toks`` at ``chunk_pos`` (pads parked at
+    ``cache_len``), takes logits at B + 1 of them, and ``emit_col`` /
+    ``emit_mask`` route sampling to the admitting row's last real prompt
+    column on its final chunk::
 
         tick_fn(params, cache, last_tok, done, pos, gen, quota, rids, key,
                 chunk_toks, chunk_pos, admit_slot, emit_col, emit_mask)
@@ -579,8 +581,7 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
     # as TICK_STATS more columns (row 0 carries them), so they come back
     # in the tick's one fetch
     plan = cfg.layer_kinds is not None
-    if plan:
-        from deepspeed_tpu.models.layer_plan import Chunk, forward_plan_cached
+    from deepspeed_tpu.models.layer_plan import Chunk, forward_plan_cached
 
     def with_stats(packed, stats):
         if stats is None or not cfg.moe_num_experts:
@@ -629,27 +630,24 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
 
     assert k == 1, "fused-prefill ticks are single-token (burst admits " \
                    "between bursts via the separate-prefill path)"
-    W = chunk
 
     def run(params, cache, last_tok, done, pos, gen, quota, rids, base_key,
             chunk_toks, chunk_pos, admit_slot, emit_col, emit_mask):
+        # the chunk rides beside the rows as W more tokens (B + W in all),
+        # never as a W-wide row under every slot; the admitting row is
+        # parked among the rows and the chunk's sampled column takes its
+        # place in the logits
         stats = None
+        slot = jnp.asarray(admit_slot, jnp.int32)
+        ch = Chunk(chunk_toks, chunk_pos, slot, emit_col[slot])
         if plan:
-            # the chunk rides beside the rows as W more tokens, not as a
-            # W-wide row of padding under every slot
             sel, cache, stats = forward_plan_cached(
-                params, cfg, last_tok, pos, cache, read_len=read_len,
-                chunk=Chunk(chunk_toks, chunk_pos, jnp.asarray(admit_slot, jnp.int32),
-                            emit_col[admit_slot]))
+                params, cfg, last_tok, pos, cache, read_len=read_len, chunk=ch)
         else:
-            toks = jnp.zeros((batch_size, W), jnp.int32).at[:, 0].set(last_tok)
-            toks = toks.at[admit_slot].set(chunk_toks)
-            positions = jnp.full((batch_size, W), cache_len, jnp.int32)
-            positions = positions.at[:, 0].set(pos).at[admit_slot].set(chunk_pos)
-            logits, cache = tf.forward_with_cache(
-                params, cfg, toks, cache, pos, positions=positions,
-                read_len=read_len)
-            sel = jnp.take_along_axis(logits, emit_col[:, None, None], axis=1)[:, 0]
+            logits, cache = tf.forward_tick_cached(
+                params, cfg, last_tok, pos, cache, ch, read_len=read_len)
+            sel = jax.lax.dynamic_update_slice(
+                logits[:batch_size], logits[batch_size:], (slot, 0))
         tok = sample(sel, rids, gen, base_key)
         last2, done2, gen2, emitted = accept(
             tok, last_tok, done, gen, quota, emit_mask)

@@ -1437,31 +1437,50 @@ def forward_with_cache(params, cfg: TransformerConfig, tokens, cache, pos, posit
         logits, cache, _ = forward_plan_cached(params, cfg, tokens[:, 0], pos, cache,
                                                read_len=read_len)
         return logits[:, None], cache
-    dtype = cfg.jnp_dtype
     B, S = tokens.shape
     if read_len is not None and read_len >= cache_alloc_len(cache):
         read_len = None  # degenerate slice: the allocation is already tight
+    if positions is not None:
+        assert jnp.ndim(pos) == 1, "explicit positions require vector pos"
+    elif jnp.ndim(pos) == 1:
+        positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # (B, S)
+    else:
+        positions = pos + jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
+    x = _embed_cached(params, cfg, tokens, positions, aligned=jnp.ndim(pos) == 0)
+
+    def layer_fn(h, layer_p, pool_k, pool_v, layer, win):
+        return _layer_body_cached(h, layer_p, pool_k, pool_v, layer, cfg, positions, pos,
+                                  window=win, read_len=read_len)
+
+    x, cache = _scan_layers_cached(params, cfg, x, cache, layer_fn)
+    return _head_cached(x, params, cfg), cache
+
+
+def _embed_cached(params, cfg: TransformerConfig, tokens, positions, aligned: bool):
+    """Token (+ learned position, type) embedding of a cached segment:
+    tokens / positions (B, S) -> (B, S, D). ``aligned``: every row sits at
+    the same positions (scalar ``pos``), so one row's are looked up."""
+    dtype = cfg.jnp_dtype
     with jax.named_scope(Scope.EMBED):
         x = jnp.take(params["embed"]["tok"], tokens, axis=0).astype(dtype)
-        if positions is not None:
-            assert jnp.ndim(pos) == 1, "explicit positions require vector pos"
-        elif jnp.ndim(pos) == 1:
-            positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # (B, S)
-        else:
-            positions = pos + jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
         if cfg.pos_embedding == "learned":
             pos_table = params["embed"]["pos"].astype(dtype)
             clamped = jnp.minimum(positions, pos_table.shape[0] - 1)
-            x = x + (jnp.take(pos_table, clamped, axis=0) if jnp.ndim(pos) == 1
-                     else jnp.take(pos_table, clamped[0], axis=0))
+            x = x + jnp.take(pos_table, clamped[0] if aligned else clamped, axis=0)
         if cfg.type_vocab_size > 0:
             # decode has no token-type stream; type 0 matches forward()'s default
             x = x + params["embed"]["type"][0].astype(dtype)
         if cfg.embed_norm:
             en = params["embed_norm"]
             x = _norm(x, en["scale"], en.get("bias"), cfg)
+    return x
 
-    layers = _cast_layers(params["layers"], dtype)
+
+def _scan_layers_cached(params, cfg: TransformerConfig, x, cache, layer_fn):
+    """The layer scan of a cached forward: ``layer_fn(x, layer_params,
+    pool_k, pool_v, layer, window) -> (x, pool_k, pool_v)`` over every
+    layer. Returns (x, cache)."""
+    layers = _cast_layers(params["layers"], cfg.jnp_dtype)
 
     # mirror forward(): a uniform window stays a STATIC int through the
     # scan (flash band prefill + the rolling cache depend on it); only
@@ -1480,16 +1499,70 @@ def forward_with_cache(params, cfg: TransformerConfig, tokens, cache, pos, posit
     def body(carry, inp):
         h, pool_k, pool_v = carry
         layer_p, layer, win = inp
-        win = win if varying else uniform_w
-        return _layer_body_cached(h, layer_p, pool_k, pool_v, layer, cfg, positions, pos,
-                                  window=win, read_len=read_len), None
+        return layer_fn(h, layer_p, pool_k, pool_v, layer, win if varying else uniform_w), None
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     (x, pool_k, pool_v), _ = jax.lax.scan(
         body, (x, cache["k"], cache["v"]), (layers, layer_ids, windows))
+    return x, {"k": pool_k, "v": pool_v}
+
+
+def _head_cached(x, params, cfg: TransformerConfig):
     if cfg.norm_position == "pre":
         x = _norm(x, params["final_norm"]["scale"], params["final_norm"].get("bias"), cfg)
-    return _vocab_head(x, params, cfg, dtype), {"k": pool_k, "v": pool_v}
+    return _vocab_head(x, params, cfg, cfg.jnp_dtype)
+
+
+def forward_tick_cached(params, cfg: TransformerConfig, tokens, pos, cache, chunk,
+                        read_len=None):
+    """One serving tick that carries a prefill chunk, as a FLAT list of
+    B + W tokens: every slot's next token (``tokens`` / ``pos`` (B,): a
+    parked row carries the pool's length, writes nothing and its output
+    means nothing) followed by ONE row's next W prompt tokens (``chunk``, a
+    ``layer_plan.Chunk``; the row it belongs to is parked among the rows,
+    pads carry the pool's length). Embedding, norms, projections and MLP run
+    over the B + W tokens once; rows write one token at their own depth and
+    attend their windows as a plain tick does, the chunk writes its tokens
+    into row ``chunk.slot`` alone and attends that row's ``[0, read_len)``
+    after the write, causal by ``chunk.pos``. The pool rides the layer
+    scan's carry as in :func:`forward_with_cache`. Returns (logits
+    (B + 1, V): the rows', then the chunk's column ``chunk.emit``; cache)."""
+    assert not cfg.rolling_kv_cache, "slot pools run plain caches (rows sit at their own depths)"
+    B = tokens.shape[0]
+    if read_len is not None and read_len >= cache_alloc_len(cache):
+        read_len = None
+    positions = jnp.concatenate([pos, chunk.pos])[None]                  # (1, B + W)
+    x = _embed_cached(params, cfg, jnp.concatenate([tokens, chunk.toks])[None], positions,
+                      aligned=False)
+    slopes = _alibi_slopes(cfg.num_heads) if cfg.pos_embedding == "alibi" else None
+    row_pos, chunk_pos = pos[:, None], chunk.pos[None]                   # (B, 1), (1, W)
+    chunk_depth = chunk.pos[:1]  # a (1,) vector ``pos``: the per-row form of write and read
+
+    def layer_fn(x, layer_p, pool_k, pool_v, layer, window):
+        attn_p, ln1 = layer_p["attn"], layer_p["ln1"]
+        h = _norm(x, ln1["scale"], ln1.get("bias"), cfg) if cfg.norm_position == "pre" else x
+        q, k, v = _qkv(h, attn_p, cfg, positions)                        # (1, B + W, heads, hd)
+        rows = lambda a: a[0, :B, None]                                  # (B, 1, heads, hd)
+        with jax.named_scope(Scope.ATTN_KV_WRITE):
+            pool_k, pool_v = update_kv_cache(pool_k, pool_v, rows(k), rows(v), pos, row_pos,
+                                             layer=layer, write_len=read_len)
+            pool_k, pool_v = update_kv_cache(pool_k, pool_v, k[:, B:], v[:, B:], chunk_depth,
+                                             chunk_pos, layer=layer, write_len=read_len,
+                                             slot=chunk.slot)
+        attend = partial(softmax_context, scale=cfg.attn_scale, alibi_slopes=slopes,
+                         local_window=window, read_len=read_len, layer=layer)
+        att_rows = attend(rows(q), pool_k, pool_v, pos, positions=row_pos)
+        att_chunk = attend(q[:, B:], pool_k, pool_v, chunk_depth, positions=chunk_pos,
+                           slot=chunk.slot)
+        attn_out = jnp.concatenate([att_rows.reshape(B, -1),
+                                    att_chunk.reshape(chunk.toks.shape[0], -1)])[None]
+        attn_out = _attn_out_proj(attn_out, attn_p, cfg)
+        return _finish_layer_cached(x, h, attn_out, layer_p, cfg), pool_k, pool_v
+
+    x, cache = _scan_layers_cached(params, cfg, x, cache, layer_fn)
+    # the head over B + 1 positions: the rows, and the chunk's sampled column
+    x = jnp.concatenate([x[0, :B], jax.lax.dynamic_slice_in_dim(x[0], B + chunk.emit, 1)])
+    return _head_cached(x, params, cfg), cache
 
 
 def loss_fn(params, cfg: TransformerConfig, batch, rng=None, ltd_keep_len=None, pld_theta=None):

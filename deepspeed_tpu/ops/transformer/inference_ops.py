@@ -75,12 +75,14 @@ def dequantize_kv(cache_component, dtype):
     return (cache_component["q8"].astype(jnp.float32) * cache_component["s"]).astype(dtype)
 
 
-def kv_window(cache_component, read_len: Optional[int] = None, layer=None):
+def kv_window(cache_component, read_len: Optional[int] = None, layer=None, slot=None):
     """First ``read_len`` (default: all) time slots of a cache component, as
     a per-layer (B, read_len, H, x) component (dense array or int8
     {"q8","s"} pair). ``layer`` None: the component is one layer's
     (B, T, H, x); else it is the stacked (L, B, T, H, x) pool and
     ``[layer]``'s window comes straight out of it in one ``dynamic_slice``.
+    ``slot`` (i32 scalar, with ``layer``): ONE row's window, (1, read_len,
+    H, x) — what a prefill chunk riding a tick reads and writes.
     ``read_len`` is a static python int, so the window is static-shape and
     no longer than what attention reads (the tight-read geometry: decode
     reads the bucketed active length, not the full allocation)."""
@@ -88,8 +90,9 @@ def kv_window(cache_component, read_len: Optional[int] = None, layer=None):
         if layer is None:
             return c if read_len is None else c[:, :read_len]
         _, B, T, H, x = c.shape
+        first, rows = (0, B) if slot is None else (slot, 1)
         return jax.lax.dynamic_slice(
-            c, (layer, 0, 0, 0, 0), (1, B, read_len or T, H, x))[0]
+            c, (layer, first, 0, 0, 0), (1, rows, read_len or T, H, x))[0]
 
     return jax.tree.map(window, cache_component)
 
@@ -130,10 +133,12 @@ def _place(window, new, cols):
     return jnp.where(hit.any(-1)[:, :, None, None], placed, window)
 
 
-def _write_component(cache, new, pos, positions, ring=False, layer=None, write_len=None):
+def _write_component(cache, new, pos, positions, ring=False, layer=None, write_len=None,
+                     slot=None):
     """S new tokens into one cache array: a layer's (B, T, H, x) cache
     (``layer`` None), or — in place, touching ``[layer]``'s first
-    ``write_len`` slots only — the stacked (L, B, T, H, x) pool."""
+    ``write_len`` slots only — the stacked (L, B, T, H, x) pool; with
+    ``slot``, ``new`` is (1, S, H, x) and only that row of it is touched."""
     if isinstance(pos, int) and not ring:
         # static offset (the prefill program): the S tokens and nothing else
         new = new.astype(cache.dtype)
@@ -143,12 +148,13 @@ def _write_component(cache, new, pos, positions, ring=False, layer=None, write_l
     cols = _write_columns(cache.shape[-3], new.shape, pos, positions, ring)
     if layer is None:
         return _place(cache, new, cols)
-    window = _place(kv_window(cache, write_len, layer), new, cols)
-    return jax.lax.dynamic_update_slice(cache, window[None], (layer, 0, 0, 0, 0))
+    window = _place(kv_window(cache, write_len, layer, slot), new, cols)
+    return jax.lax.dynamic_update_slice(
+        cache, window[None], (layer, 0 if slot is None else slot, 0, 0, 0))
 
 
 def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, positions=None, ring=False,
-                    layer=None, write_len=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                    layer=None, write_len=None, slot=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Write S new keys/values into (B, T, H, hd) caches (or int8
     {"q8","s"} cache components — the write quantizes per token/head).
 
@@ -165,9 +171,12 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, positions=None, ring=Fa
     (static int, the step's ``read_len``) then bounds the slots the write
     touches: columns at or beyond it drop too, which loses nothing because
     ``read_len`` covers every live position, the new tokens' included.
+    ``slot`` (i32 scalar, with ``layer``): the new tokens are ONE row's,
+    (1, S, H, hd) at ``positions`` (1, S), and the write touches that row
+    of the pool alone (a tick's prefill chunk beside its decode rows).
     """
     def component(cache, new):
-        return _write_component(cache, new, pos, positions, ring, layer, write_len)
+        return _write_component(cache, new, pos, positions, ring, layer, write_len, slot)
 
     def write(cache, new):
         if isinstance(cache, dict):
@@ -181,7 +190,7 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, positions=None, ring=Fa
 def softmax_context(q, k_cache, v_cache, pos, scale: Optional[float] = None,
                     positions=None, alibi_slopes=None, local_window=None,
                     ring=False, read_len: Optional[int] = None,
-                    layer=None) -> jnp.ndarray:
+                    layer=None, slot=None) -> jnp.ndarray:
     """Cached masked attention (softmax_context binding): q (B, S, nh, hd)
     against (B, T, nkv, hd) caches (GQA repeat applied here).
 
@@ -209,12 +218,14 @@ def softmax_context(q, k_cache, v_cache, pos, scale: Optional[float] = None,
     (the ring is already O(window)).
     ``layer`` (i32 scalar): the caches are the stacked (L, B, T, nkv, hd)
     pool; ``[layer]``'s window is read straight from it (:func:`kv_window`).
+    ``slot`` (i32 scalar, with ``layer``): q is (1, S, nh, hd), ONE row's
+    segment, and only that row's window is read.
     """
     B, S, nh, hd = q.shape
     with jax.named_scope(Scope.ATTN_KV_READ):
         assert read_len is None or not ring, "tight reads do not apply to the rolling (ring) cache"
-        k_cache = kv_window(k_cache, read_len, layer)
-        v_cache = kv_window(v_cache, read_len, layer)
+        k_cache = kv_window(k_cache, read_len, layer, slot)
+        v_cache = kv_window(v_cache, read_len, layer, slot)
         if isinstance(k_cache, dict):  # int8 KV cache: dequant at the read
             k_cache = dequantize_kv(k_cache, q.dtype)
             v_cache = dequantize_kv(v_cache, q.dtype)

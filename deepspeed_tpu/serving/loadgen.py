@@ -352,6 +352,10 @@ def host_overhead(tick_stats: dict) -> dict:
                                       if plain else None)
         out["prefill_q_depth_mean"] = (round(tick_stats["prefill_q_depth_sum"] / steps, 4)
                                        if steps else None)
+    # what one chunk width costs: pads ÷ columns the fused ticks' chunks ran
+    real, pad = tick_stats.get("prefill_chunk_tokens"), tick_stats.get("prefill_pad_tokens")
+    if real is not None and pad is not None:
+        out["prefill_pad_share"] = round(pad / (real + pad), 4) if real + pad else None
     if "utilization" in tick_stats:
         out["tick_utilization"] = tick_stats["utilization"]
     if tick_stats.get("spec_gamma"):
@@ -609,7 +613,9 @@ def format_summary(summary: dict) -> str:
                 f"tick kinds     fused {host['fused_tick_share']:.1%} of ticks   "
                 f"blocked {_ms(host['tick_block_ms_fused'])} fused / "
                 f"{_ms(host['tick_block_ms_plain'])} plain   "
-                f"prefill queue {host['prefill_q_depth_mean']} deep")
+                f"prefill queue {host['prefill_q_depth_mean']} deep"
+                + (f"   chunk pads {host['prefill_pad_share']:.1%}"
+                   if host.get("prefill_pad_share") is not None else ""))
         if host.get("spec_gamma"):
             acc = host.get("spec_acceptance")
             per = (f"{acc * host['spec_gamma']:.2f}/{host['spec_gamma']}"

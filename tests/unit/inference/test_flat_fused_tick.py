@@ -1,0 +1,252 @@
+"""The one-kind fused-prefill tick runs B + W tokens — the rows' next
+tokens, then ONE row's prompt chunk beside them — and one chunk width a read
+bucket (inference/decoding.py, models/transformer.forward_tick_cached,
+inference/continuous.py). Token streams against ``generate`` and against
+separate prefill at toy size, and what the lowered program may not hold."""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu import comm
+from deepspeed_tpu.inference.continuous import ContinuousBatchingEngine
+from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
+from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+
+FLOOR = 16   # small tight-read floor so toy pools cross read buckets
+LENGTH = 384
+BASE = TransformerConfig(vocab_size=160, hidden_size=64, num_layers=2, num_heads=4,
+                         max_seq_len=LENGTH, dtype="float32")
+
+VARIANTS = {
+    "plain": {},
+    "int8_kv": {"config": {"kv_cache_dtype": "int8"}},
+    "alibi": {"cfg": {"pos_embedding": "alibi"}},
+    "layer_windows": {"cfg": {"local_attn_windows": (24, 0)}},
+    "gqa_rope": {"cfg": {"pos_embedding": "rope", "num_kv_heads": 2}},
+    "tensor2": {"tensor": 2},
+}
+
+
+@pytest.fixture(scope="module")
+def models():
+    comm.destroy()
+    built = {}
+
+    def get(variant="plain"):
+        if variant not in built:
+            spec = VARIANTS[variant]
+            model = TransformerModel(dataclasses.replace(BASE, **spec.get("cfg", {})))
+            built[variant] = (model, model.init(jax.random.PRNGKey(0)))
+        return built[variant]
+
+    return get
+
+
+def _engine(models, variant="plain", **kw):
+    """Continuous engine of a variant. Donation off: the CPU backend blocks
+    a donated dispatch, and these tests compare schedules."""
+    model, params = models(variant)
+    spec = VARIANTS[variant]
+    config = {"dtype": "float32", "kv_read_floor": FLOOR, **spec.get("config", {})}
+    if "tensor" in spec:
+        config["mesh"] = {"shape": {"data": 1, "tensor": spec["tensor"]}}
+    kw.setdefault("max_slots", 3)
+    kw.setdefault("cache_len", LENGTH)
+    kw.setdefault("prefill_chunk", 128)
+    kw.setdefault("donate_cache", False)
+    return ContinuousBatchingEngine(model, params=params, config=config, **kw)
+
+
+_PLAIN = {}
+
+
+def _generate(models, variant, prompt, new):
+    if variant not in _PLAIN:  # one plain engine a variant: its programs compile once
+        model, params = models(variant)
+        config = {"dtype": "float32", **VARIANTS[variant].get("config", {})}
+        _PLAIN[variant] = deepspeed_tpu.init_inference(model, params=params, config=config)
+    return np.asarray(_PLAIN[variant].generate(prompt[None, :], max_new_tokens=new))[0]
+
+
+def _prompt(n, seed=0):
+    return np.random.RandomState(seed).randint(0, BASE.vocab_size, (n,)).astype(np.int32)
+
+
+def _drain(cb, rids):
+    while cb.has_work():
+        cb.step()
+    done = cb.finished()
+    return [np.asarray(done[r]) for r in rids]
+
+
+def _serve_one(cb, prompt, new, live_rows):
+    """``prompt`` admitted while ``live_rows`` other requests decode (their
+    rows ride the same ticks as its chunks) or into an idle pool."""
+    others = [cb.submit(_prompt(5 + 3 * i, seed=10 + i), max_new_tokens=40)
+              for i in range(live_rows)]
+    for _ in range(3 if live_rows else 0):
+        cb.step()
+    rid = cb.submit(prompt, max_new_tokens=new)
+    out = _drain(cb, others + [rid])
+    return out[-1], out[:-1]
+
+
+# -- token streams ---------------------------------------------------------
+
+@pytest.mark.parametrize("live_rows", [0, 2], ids=["none_live", "rows_live"])
+@pytest.mark.parametrize("n", [1, 15, 16, 17, 127, 128, 300])
+def test_greedy_stream_equals_generate_for_every_chunk_size(models, n, live_rows):
+    """Prompts of 1 … 300 tokens (300 = three chunks of the 128 cap, the
+    last one padded): the fused tick's greedy stream is ``generate``'s."""
+    prompt = _prompt(n, seed=n)
+    cb = _engine(models)
+    got, _ = _serve_one(cb, prompt, 10, live_rows)
+    np.testing.assert_array_equal(got, _generate(models, "plain", prompt, 10))
+    st = cb.tick_stats()
+    chunks = -(-n // 128)
+    assert st["prefill_chunk_tokens"] >= n
+    assert (st["prefill_chunk_tokens"] + st["prefill_pad_tokens"]) % 128 == 0
+    assert st["fused_prefill_ticks"] >= chunks
+
+
+@pytest.mark.parametrize("n", [17, 300])
+def test_sampled_stream_equals_separate_prefill(models, n):
+    """Sampling draws from fold_in(fold_in(key, rid), token index) on the
+    device: fused and separate admission give the same sampled stream."""
+    prompt = _prompt(n, seed=n)
+    outs = []
+    for fused in (True, False):
+        cb = _engine(models, temperature=0.8, top_k=20, seed=7, fused_prefill=fused)
+        outs.append(_serve_one(cb, prompt, 12, 2))
+    np.testing.assert_array_equal(outs[0][0], outs[1][0])
+    for a, b in zip(outs[0][1], outs[1][1]):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("slot", [0, 1, 2], ids=["first", "middle", "last"])
+def test_admitting_slot_first_middle_last(models, slot):
+    """The chunk's row can be any slot; the rows on either side keep
+    decoding their own streams."""
+    cb = _engine(models)
+    holders = [_prompt(6 + i, seed=20 + i) for i in range(3)]
+    rids = [cb.submit(p, max_new_tokens=60) for p in holders]
+    for _ in range(4):
+        cb.step()
+    assert cb.cancel(rids[slot])
+    prompt = _prompt(150, seed=33)
+    rid = cb.submit(prompt, max_new_tokens=8)
+    cb.step()
+    assert cb._pools[0].active[slot].rid == rid
+    keep = [r for i, r in enumerate(rids) if i != slot]
+    out = _drain(cb, keep + [rid])
+    np.testing.assert_array_equal(out[-1], _generate(models, "plain", prompt, 8))
+    for got, p in zip(out, [h for i, h in enumerate(holders) if i != slot]):
+        np.testing.assert_array_equal(got, _generate(models, "plain", p, 60))
+
+
+@pytest.mark.parametrize("variant", ["int8_kv", "alibi", "layer_windows", "gqa_rope", "tensor2"])
+def test_variants_equal_separate_prefill_and_generate(models, variant):
+    """Every cache and attention variant the (B, W) layout served: an int8
+    pool, ALiBi, a per-layer window, grouped heads with rotary positions, a
+    pool whose heads are split over ``tensor``."""
+    prompt = _prompt(200, seed=5)
+    fused, fused_others = _serve_one(_engine(models, variant), prompt, 10, 2)
+    sep, sep_others = _serve_one(_engine(models, variant, fused_prefill=False), prompt, 10, 2)
+    np.testing.assert_array_equal(fused, sep)
+    for a, b in zip(fused_others, sep_others):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(fused, _generate(models, variant, prompt, 10))
+
+
+def test_chunk_crossing_a_read_bucket(models):
+    """A chunk cap that is no power of two: chunks sit astride the read
+    buckets (48 … 95 crosses 64), and the tick reads the bucket that covers
+    the chunk's END."""
+    prompt = _prompt(110, seed=9)
+    cb = _engine(models, prefill_chunk=48)
+    got, _ = _serve_one(cb, prompt, 10, 1)
+    np.testing.assert_array_equal(got, _generate(models, "plain", prompt, 10))
+    widths = {k[0] for k in cb._pools[0].tick_fns if k[0] is not None}
+    assert widths == {48}
+
+
+def test_eos_on_the_first_token(models):
+    """The chunk's sampled column is the request's first AND last token."""
+    prompt = _prompt(140, seed=11)
+    want = _generate(models, "plain", prompt, 4)
+    cb = _engine(models, eos_token_id=int(want[prompt.size]))
+    got, others = _serve_one(cb, prompt, 10, 2)
+    np.testing.assert_array_equal(got, want[:prompt.size + 1])
+    assert all(o.size for o in others)
+
+
+# -- the program -----------------------------------------------------------
+
+def _lowered_tick(slots, width, read_len=None):
+    comm.destroy()
+    cfg = dataclasses.replace(BASE, max_seq_len=256)
+    model = TransformerModel(cfg)
+    mesh = comm.build_mesh({"data": 1, "tensor": 1}, devices=jax.devices()[:1])
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    p_sh = jax.tree.map(lambda a: NamedSharding(mesh, PartitionSpec()), params)
+    fn, _, _ = compile_pool_tick_fn(mesh, cfg, p_sh, slots, 256, 1, 0.0, 0, 1.0,
+                                    read_len=read_len, chunk=width, donate=False)
+    from deepspeed_tpu.models import transformer as tf
+
+    cache = jax.eval_shape(lambda: tf.init_cache(cfg, slots, 256))
+    row = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    wide = jax.ShapeDtypeStruct((width,), jnp.int32)
+    text = fn.lower(params, cache, row, row, row, row, row, row,
+                    jax.ShapeDtypeStruct((2,), jnp.uint32), wide, wide,
+                    jax.ShapeDtypeStruct((), jnp.int32), row, row).as_text()
+    comm.destroy()
+    return text, cfg
+
+
+@pytest.mark.parametrize("read_len", [None, 128], ids=["read_all", "read128"])
+def test_lowered_tick_is_one_loop_over_b_plus_w_tokens(read_len):
+    """ONE ``stablehlo.while`` (the layer scan with the pool in its carry);
+    nothing of shape (B, W, hidden) or (B, W, vocab); the logits are
+    (B + 1, vocab)."""
+    B, W = 3, 32
+    text, cfg = _lowered_tick(B, W, read_len)
+    assert text.count("stablehlo.while") == 1
+    shapes = set(re.findall(r"tensor<([0-9x]+)x[a-z]+[0-9]+>", text))
+    assert f"{B}x{W}x{cfg.vocab_size}" not in shapes
+    assert f"{B}x{W}x{cfg.hidden_size}" not in shapes
+    assert not any(s.startswith(f"{B}x{W}x") for s in shapes), sorted(shapes)
+    assert f"{B + 1}x{cfg.vocab_size}" in shapes
+    assert f"1x{B + W}x{cfg.hidden_size}" in shapes
+    rows_of_logits = [np.prod([int(d) for d in s.split("x")[:-1]]) for s in shapes
+                      if s.endswith(f"x{cfg.vocab_size}")]
+    assert max(rows_of_logits) == B + 1, sorted(shapes)
+
+
+def test_precompile_counts_two_programs_a_read_bucket(models):
+    """A one-kind pool: a plain and ONE fused program a read bucket."""
+    cb = _engine(models, max_slots=2, cache_len=128)
+    buckets = {cb._read_len(cb._pools[0], e) for e in range(1, 129)}
+    assert len(buckets) == 4                                  # 16, 32, 64, all
+    assert cb.precompile_tick_programs() == 2 * len(buckets)
+    assert len(cb._pools[0].tick_fns) == 2 * len(buckets)
+    assert {k[0] for k in cb._pools[0].tick_fns} == {None, 128}
+
+
+def test_tick_stats_count_real_and_pad_tokens(models):
+    """``prefill_chunk_tokens`` + ``prefill_pad_tokens`` = width x fused
+    ticks; the pad share is read, not reckoned."""
+    cb = _engine(models, prefill_chunk=64)
+    rids = [cb.submit(_prompt(n, seed=n), max_new_tokens=3) for n in (64, 100, 7)]
+    _drain(cb, rids)
+    st = cb.tick_stats()
+    assert st["prefill_chunk_tokens"] == 64 + 100 + 7
+    assert st["fused_prefill_ticks"] == 1 + 2 + 1
+    assert st["prefill_pad_tokens"] == 4 * 64 - (64 + 100 + 7)
