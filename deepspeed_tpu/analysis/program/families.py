@@ -91,6 +91,7 @@ def build_serving_artifacts(tp: int = 1, *, donate: bool = True,
     )
     from deepspeed_tpu.inference.engine import InferenceEngine
     from deepspeed_tpu.models import transformer as tf
+    from deepspeed_tpu.ops.transformer import kv_cache
 
     from .capture import extract_artifact, shape_structs
 
@@ -115,7 +116,7 @@ def build_serving_artifacts(tp: int = 1, *, donate: bool = True,
         return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
     params_s = jax.tree.map(sds, eng.params)
-    cache_s = jax.eval_shape(lambda: tf.init_cache(cfg, slots, cache_len))
+    cache_s = jax.eval_shape(lambda: kv_cache.init(cfg, slots, cache_len))
     row = jax.ShapeDtypeStruct((slots,), jnp.int32)
     key_s = sds(jax.random.PRNGKey(0))
     scalar = jax.ShapeDtypeStruct((), jnp.int32)
@@ -179,7 +180,7 @@ def build_serving_artifacts(tp: int = 1, *, donate: bool = True,
         dmodel = tf.TransformerModel(dcfg_t)
         deng = InferenceEngine(dmodel, config=config, mesh=mesh)
         dcfg = deng._ring_off_cfg
-        dcache_s = jax.eval_shape(lambda: tf.init_cache(dcfg, slots,
+        dcache_s = jax.eval_shape(lambda: kv_cache.init(dcfg, slots,
                                                         cache_len))
         dparams_s = jax.tree.map(sds, deng.params)
         dmeta = dict(meta, param_shapes=(meta["param_shapes"]
@@ -206,7 +207,7 @@ def build_serving_artifacts(tp: int = 1, *, donate: bool = True,
         prefill_fn, decode_fn, _, _ = compile_decode_fns(
             mesh, cfg, shardings, batch, cache_len)
         d_cache = shape_structs(
-            jax.eval_shape(lambda: tf.init_cache(cfg, batch, cache_len)))
+            jax.eval_shape(lambda: kv_cache.init(cfg, batch, cache_len)))
         if "decode_prefill" in wanted:
             toks = jax.ShapeDtypeStruct((batch, 8), jnp.int32)
             out.append(extract_artifact(

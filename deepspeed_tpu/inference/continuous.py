@@ -98,6 +98,7 @@ from deepspeed_tpu.inference.decoding import (
     read_bucket,
     TICK_STATS,
 )
+from deepspeed_tpu.ops.transformer import kv_cache
 from deepspeed_tpu.telemetry.spans import host_span
 
 # admission/bucket sizing shares the ONE bucketing rule with the tight-read
@@ -189,8 +190,6 @@ class _Pool:
     """One static-shape slot pool: ``n_slots`` rows of ``length`` KV."""
 
     def __init__(self, engine, n_slots: int, length: int):
-        from deepspeed_tpu.models import transformer as tf
-
         self.n_slots = n_slots
         self.length = length
         # the hub is resolved at FIRST DISPATCH, not here: a serving
@@ -208,7 +207,7 @@ class _Pool:
         self.segment_fn = wrap_deferred(get_tele, self.segment_fn,
                                         "pool_segment", (n_slots, length))
         self.cache = jax.device_put(
-            tf.init_cache(engine.cfg, n_slots, length), self.cache_sh
+            kv_cache.init(engine.cfg, n_slots, length), self.cache_sh
         )
         self.active: Dict[int, _Request] = {}       # slot -> request
         # device-THREADED tick state: the tick programs return these as
@@ -253,7 +252,7 @@ class _Pool:
                     get_tele, self.draft_segment_fn, "pool_segment",
                     (n_slots, length, "draft"))
                 self.draft_cache = jax.device_put(
-                    tf.init_cache(engine.draft_cfg, n_slots, length),
+                    kv_cache.init(engine.draft_cfg, n_slots, length),
                     self.draft_cache_sh)
         # ds-audit capture of the pool's companion programs (the tick
         # variants notify from _tick_fn as they are built)
@@ -317,9 +316,6 @@ class _Pool:
 
     def free_slots(self) -> List[int]:
         return [s for s in range(self.n_slots) if s not in self.active]
-
-    def kv_bytes(self) -> int:
-        return sum(l.nbytes for l in jax.tree.leaves(self.cache))
 
 
 class ContinuousBatchingEngine:
@@ -578,17 +574,17 @@ class ContinuousBatchingEngine:
     def kv_cache_bytes(self) -> int:
         """Total device bytes held by the slot-pool KV caches (the number
         the PERF.md bucketed-vs-fixed footprint table reports)."""
-        return sum(p.kv_bytes() for p in self._pools)
+        return sum(self.kv_pool_bytes().values())
 
     def kv_pool_bytes(self) -> Dict[str, int]:
-        """``kv_cache_bytes()`` by kind of pool: a layer plan keeps a
-        full-length pool and a ring of ``window`` positions
-        ({"full": ..., "window": ...}); a model of one kind has {"full"}."""
+        """``kv_cache_bytes()`` by kind of pool (``kv_cache.specs``): a
+        layer plan keeps a full-length pool and a ring of ``window``
+        positions ({"full": ..., "window": ...}); a model of one kind has
+        {"kv"}."""
         out: Dict[str, int] = {}
         for p in self._pools:
-            tree = p.cache if "full" in p.cache else {"full": p.cache}
-            for name, sub in tree.items():
-                out[name] = out.get(name, 0) + sum(l.nbytes for l in jax.tree.leaves(sub))
+            for name, nbytes in kv_cache.pool_bytes(self.cfg, p.cache).items():
+                out[name] = out.get(name, 0) + nbytes
         return out
 
     def hbm_components(self) -> Dict[str, int]:
@@ -782,8 +778,6 @@ class ContinuousBatchingEngine:
             raise ValueError("empty prefix")
         if prefix.size >= self.cache_len:
             raise ValueError("prefix does not fit the cache")
-        from deepspeed_tpu.models import transformer as tf
-
         n = prefix.size
         bucket = _bucket(n, self.cache_len)
         prefill_fn = self._prefill_for_bucket(bucket)
@@ -791,7 +785,7 @@ class ContinuousBatchingEngine:
         toks[0, :n] = prefix
         positions = np.full((1, bucket), bucket, np.int32)
         positions[0, :n] = np.arange(n, dtype=np.int32)
-        small = tf.init_cache(self.cfg, 1, bucket)
+        small = kv_cache.init(self.cfg, 1, bucket)
         logits, small = prefill_fn(
             self._eng.params, jnp.asarray(toks), jnp.asarray(positions), small
         )
@@ -1122,15 +1116,13 @@ class ContinuousBatchingEngine:
                         self._eng.config.kv_read_floor)
         return None if r >= pool.length else r
 
-    def _row_read_bytes(self, pool: _Pool, read_len: Optional[int]) -> int:
-        from deepspeed_tpu.models.transformer import kv_read_bytes_per_row
-        from deepspeed_tpu.parallel.partition import kv_shard_width
-
+    def _row_read_bytes(self, pool: _Pool, read_len: Optional[int], cfg=None) -> int:
         # per-chip: the pool cache shards its heads axis over the mesh's
         # tensor width, so each chip streams 1/tp of the row's window
-        return kv_read_bytes_per_row(
-            self.cfg, read_len if read_len is not None else pool.length,
-            tp=kv_shard_width(self.mesh, self.cfg))
+        cfg = cfg or self.cfg
+        return kv_cache.read_bytes_per_row(
+            cfg, read_len if read_len is not None else pool.length,
+            tp=kv_cache.shard_width(self.mesh, cfg))
 
     def _chunk_width(self, pool: _Pool, nreal: int) -> int:
         """Width of the fused tick program that carries ``nreal`` prompt
@@ -1283,13 +1275,7 @@ class ContinuousBatchingEngine:
         draft-cache window (0 extra for ngram — drafting is host-side)."""
         total = self._row_read_bytes(pool, read_len)
         if self.spec_mode == "draft":
-            from deepspeed_tpu.models.transformer import kv_read_bytes_per_row
-            from deepspeed_tpu.parallel.partition import kv_shard_width
-
-            total += (self.spec_gamma + 1) * kv_read_bytes_per_row(
-                self.draft_cfg,
-                read_len if read_len is not None else pool.length,
-                tp=kv_shard_width(self.mesh, self.draft_cfg))
+            total += (self.spec_gamma + 1) * self._row_read_bytes(pool, read_len, self.draft_cfg)
         return total
 
     def _spec_tick_fn(self, pool: _Pool, read_len: Optional[int]):
@@ -1554,15 +1540,7 @@ class ContinuousBatchingEngine:
             _, small_sh = _decode_shardings(self.mesh, self.cfg, 1)
 
             def insert(big, small, slot):
-                # positions [0..bucket) overwritten, staler junk beyond is
-                # causally masked until real writes reach it (tree.map:
-                # also covers the int8 {"q8","s"} representation)
-                return jax.tree.map(
-                    lambda b, sm: jax.lax.dynamic_update_slice(
-                        b, sm.astype(b.dtype), (0, slot, 0, 0, 0)
-                    ),
-                    big, small,
-                )
+                return kv_cache.splice_row(big, small, slot)
 
             return jax.jit(
                 insert,
@@ -1607,8 +1585,6 @@ class ContinuousBatchingEngine:
         through the B=1 bucket program + splice and re-feeds the last
         prompt token on the first decode tick (whose logits produce the
         first generated token — same stream, no admission-time sample)."""
-        from deepspeed_tpu.models import transformer as tf
-
         pool = self._pools[pi]
         req.slot, req.pool = slot, pi
         # placement guarantees prompt + max_new_tokens fits the pool row;
@@ -1652,8 +1628,6 @@ class ContinuousBatchingEngine:
         B=1 bucket program + splice, or the shared segment program for
         prefix suffixes. Shared by the plain separate path and every
         speculative non-fused admission."""
-        from deepspeed_tpu.models import transformer as tf
-
         m = int(toks.size)
         self._mark_prefill_start(req)
         if m <= 1:
@@ -1681,7 +1655,7 @@ class ContinuousBatchingEngine:
             # pads park at bucket (dropped writes), real tokens 0..m-2
             positions = np.full((1, b), b, np.int32)
             positions[0, :m - 1] = np.arange(m - 1, dtype=np.int32)
-            small = tf.init_cache(self.cfg, 1, b)
+            small = kv_cache.init(self.cfg, 1, b)
             _, small = prefill_fn(
                 self._eng.params, jnp.asarray(ptoks),
                 jnp.asarray(positions), small)
@@ -1732,8 +1706,6 @@ class ContinuousBatchingEngine:
         serve could dispatch — so first serve-time requests don't pay a
         compile per variant (dstpu_prewarm --continuous).
         Runs each program once on throwaway state. Returns the count."""
-        from deepspeed_tpu.models import transformer as tf
-
         count = 0
         for pool in self._pools:
             # enumerate the families through the SAME functions the serve
@@ -1755,7 +1727,7 @@ class ContinuousBatchingEngine:
                     t0 = time.time()
                     fn = self._tick_fn(pool, rl, chunk=ch)
                     cache = jax.device_put(
-                        tf.init_cache(self.cfg, pool.n_slots, pool.length),
+                        kv_cache.init(self.cfg, pool.n_slots, pool.length),
                         pool.cache_sh)
 
                     def zeros():
@@ -1782,14 +1754,12 @@ class ContinuousBatchingEngine:
         """Speculative arm of :meth:`precompile_tick_programs`: the spec
         tick per read bucket (chunks never enter it — fused admission
         rides the segment program, warmed per chunk width below)."""
-        from deepspeed_tpu.models import transformer as tf
-
         count, g, n = 0, self.spec_gamma, pool.n_slots
         for rl in read_lens:
             t0 = time.time()
             fn = self._spec_tick_fn(pool, rl)
             cache = jax.device_put(
-                tf.init_cache(self.cfg, n, pool.length), pool.cache_sh)
+                kv_cache.init(self.cfg, n, pool.length), pool.cache_sh)
 
             def zeros():
                 # donated operands must not alias — fresh buffers each
@@ -1798,7 +1768,7 @@ class ContinuousBatchingEngine:
             parked = jnp.full(n, pool.length, jnp.int32)
             if self.spec_mode == "draft":
                 dcache = jax.device_put(
-                    tf.init_cache(self.draft_cfg, n, pool.length),
+                    kv_cache.init(self.draft_cfg, n, pool.length),
                     pool.draft_cache_sh)
                 args = (self._eng.params, self._draft_eng.params, cache,
                         dcache, zeros(), jnp.ones(n, jnp.int32), parked,
@@ -1821,7 +1791,7 @@ class ContinuousBatchingEngine:
                              for m in range(1, pool.chunk_cap + 1)}):
                 t0 = time.time()
                 cache = jax.device_put(
-                    tf.init_cache(self.cfg, n, pool.length), pool.cache_sh)
+                    kv_cache.init(self.cfg, n, pool.length), pool.cache_sh)
                 _, c2 = pool.segment_fn(
                     self._eng.params, jnp.zeros((n, W), jnp.int32), cache,
                     jnp.full(n, pool.length, jnp.int32))

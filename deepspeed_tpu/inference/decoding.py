@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
+from deepspeed_tpu.ops.transformer import kv_cache
 from deepspeed_tpu.telemetry.hlo_scopes import Scope
 
 
@@ -70,14 +71,12 @@ def decode_kv_bytes(cfg, prompt_len: int, new_tokens: int, cache_len: int,
     read geometry the compiled programs actually execute (read_stages), so
     telemetry's ``kv_bytes_read`` is assertable in tests and comparable
     across tight/full configurations. ``tp`` (the cache's heads-axis shard
-    width, parallel.partition.kv_shard_width) makes the number PER-CHIP:
+    width, kv_cache.shard_width) makes the number PER-CHIP:
     each chip of a tensor-parallel mesh streams only its head shard."""
-    from deepspeed_tpu.models.transformer import kv_read_bytes_per_row
-
     total = 0
     for r, n in read_stages(prompt_len, new_tokens - 1, cache_len, floor):
-        total += n * kv_read_bytes_per_row(cfg, r if r is not None else cache_len,
-                                           tp=tp)
+        total += n * kv_cache.read_bytes_per_row(cfg, r if r is not None else cache_len,
+                                                 tp=tp)
     return total
 
 
@@ -91,18 +90,11 @@ def _decode_shardings(mesh, cfg, batch_size: int):
     """(batch_sharding, cache_sharding) — the ONE sharding-selection policy
     for every cached-decode program (plain and speculative paths must place
     batch/KV identically or each call pays a reshard)."""
-    from deepspeed_tpu.models import transformer as tf
-
     dp = mesh.shape["data"] * mesh.shape["fsdp"]
     batch_axes = ("data", "fsdp") if batch_size % dp == 0 else None
-    # a layer plan's pools differ in heads: they stay whole on every chip
-    kv_tensor = ("tensor" if cfg.layer_kinds is None
-                 and cfg.kv_heads % mesh.shape["tensor"] == 0 else None)
     batch_sh = NamedSharding(mesh, PartitionSpec(batch_axes))
-    cache_sh = jax.tree.map(
-        lambda _: NamedSharding(mesh, PartitionSpec(None, batch_axes, None, kv_tensor, None)),
-        tf.init_cache(cfg, 1, 8),
-    )
+    cache_sh = jax.tree.map(lambda spec: NamedSharding(mesh, spec),
+                            kv_cache.partition_spec(cfg, mesh, batch_axes))
     return batch_sh, cache_sh
 
 
@@ -1209,8 +1201,6 @@ def speculative_generate(cfg, params, draft, tokens, max_new_tokens: int,
     InferenceEngine and the RLHF hybrid engine. ``get_fns(B, cache_len) ->
     (t_prefill, t_segment, cache_sh)`` supplies the target programs;
     ``draft`` is an InferenceEngine providing its own via _spec_fns."""
-    from deepspeed_tpu.models import transformer as tf
-
     if draft.cfg.vocab_size != cfg.vocab_size:
         raise ValueError(
             f"draft must share the vocabulary: draft vocab "
@@ -1222,8 +1212,8 @@ def speculative_generate(cfg, params, draft, tokens, max_new_tokens: int,
     cache_len = bounded_cache_len(total, max(cfg.max_seq_len, total), max_out_tokens)
     t_prefill, t_segment, cache_sh = get_fns(B, cache_len)
     d_prefill, d_decode, d_cache_sh = draft._spec_fns(B, cache_len)
-    cache_t = jax.device_put(tf.init_cache(cfg, B, cache_len), cache_sh)
-    cache_d = jax.device_put(tf.init_cache(draft.cfg, B, cache_len), d_cache_sh)
+    cache_t = jax.device_put(kv_cache.init(cfg, B, cache_len), cache_sh)
+    cache_d = jax.device_put(kv_cache.init(draft.cfg, B, cache_len), d_cache_sh)
     return speculative_decode_loop(
         t_prefill, t_segment, d_prefill, d_decode,
         params, draft.params, tokens, cache_t, cache_d,

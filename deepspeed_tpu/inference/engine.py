@@ -34,6 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from deepspeed_tpu import comm
 from deepspeed_tpu.inference.config import InferenceConfig
 from deepspeed_tpu.models import transformer as tf
+from deepspeed_tpu.ops.transformer import kv_cache
 from deepspeed_tpu.runtime.zero.sharding import ShardingPolicy
 from deepspeed_tpu.utils.logging import log_dist, logger
 
@@ -302,7 +303,7 @@ class InferenceEngine:
 
             params_s = jax.tree.map(sds, self.params)
             cache_s = jax.tree.map(sds, jax.eval_shape(
-                lambda: tf.init_cache(self.cfg, batch_size, max_len)))
+                lambda: kv_cache.init(self.cfg, batch_size, max_len)))
             capture.notify_program(
                 "decode_prefill", "", self._prefill_fn,
                 lambda: (params_s,
@@ -387,15 +388,14 @@ class InferenceEngine:
         so tests assert it exactly and the byte counts are the same on
         the CPU mesh as on a chip. On a tensor-parallel mesh
         the bytes are PER-CHIP — each chip streams only its head shard, so
-        kv_shard_width divides them out (that per-chip rate is what bounds
+        kv_cache.shard_width divides them out (that per-chip rate is what bounds
         a bandwidth-limited decode step)."""
         if not self.telemetry.enabled:
             return None
         from deepspeed_tpu.inference.decoding import decode_kv_bytes
-        from deepspeed_tpu.parallel.partition import kv_shard_width
 
         per_row = decode_kv_bytes(self.cfg, prompt_len, new_tokens, cache_len,
-                                  floor, tp=kv_shard_width(self.mesh, self.cfg))
+                                  floor, tp=kv_cache.shard_width(self.mesh, self.cfg))
         decoded = max(new_tokens - 1, 0)
         alloc = alloc if alloc is not None else cache_len
         fields = {
@@ -517,7 +517,7 @@ class InferenceEngine:
             max_len = bounded_cache_len(total, self.cfg.max_seq_len,
                                         self.config.max_out_tokens)
             prefill_fn, segment_fn, cache_sh = self._ragged_fns_for(B, max_len)
-            cache = jax.device_put(tf.init_cache(self.cfg, B, max_len), cache_sh)
+            cache = jax.device_put(kv_cache.init(self.cfg, B, max_len), cache_sh)
             t0 = time.time()
             result = chunked_generate(
                 prefill_fn, segment_fn, self.params, tokens, cache, max_len,
@@ -542,7 +542,7 @@ class InferenceEngine:
 
             max_len = bounded_cache_len(total, self.cfg.max_seq_len, self.config.max_out_tokens)
             prefill_fn, segment_fn, cache_sh = self._ragged_fns_for(B, max_len)
-            cache = jax.device_put(tf.init_cache(self.cfg, B, max_len), cache_sh)
+            cache = jax.device_put(kv_cache.init(self.cfg, B, max_len), cache_sh)
             t0 = time.time()
             result = ragged_decode_loop(
                 prefill_fn, segment_fn, self.params, tokens, attention_mask,
@@ -592,7 +592,7 @@ class InferenceEngine:
             fused_fn, cache_sh = self._fused_generate_fn(
                 B, max_len, max_new_tokens, temperature, top_k, top_p,
                 read_floor=floor)
-            cache = jax.device_put(tf.init_cache(self.cfg, B, max_len), cache_sh)
+            cache = jax.device_put(kv_cache.init(self.cfg, B, max_len), cache_sh)
             t0 = time.time()
             result = fused_fn(self.params, tokens, cache, rng)
             result = self._finish_request(
@@ -640,7 +640,7 @@ class InferenceEngine:
         decode_fn = (self._decode_fn if floor is None
                      else self._migrating_decode_fn(max_len, floor,
                                                     fresh_allocs))
-        cache = jax.device_put(tf.init_cache(self.cfg, B, alloc), self._cache_sharding)
+        cache = jax.device_put(kv_cache.init(self.cfg, B, alloc), self._cache_sharding)
         t0 = time.time()
         result = decode_loop(
             self._prefill_fn, decode_fn, self.params, tokens, cache,
@@ -670,14 +670,13 @@ class InferenceEngine:
         active length — the tight-read geometry — without any per-step
         slicing in the compiled program."""
         from deepspeed_tpu.inference.decoding import read_bucket
-        from deepspeed_tpu.models.transformer import cache_alloc_len
 
         fresh = set() if fresh_allocs is None else fresh_allocs
         first = True
 
         def dispatch(params, tok, cache, pos):
             nonlocal first
-            if pos + 1 > cache_alloc_len(cache):
+            if pos + 1 > kv_cache.alloc_len(self.cfg, cache):
                 new_len = min(read_bucket(pos + 1, max_len, floor), max_len)
                 cache = self._grow_cache(cache, new_len)
                 if self.telemetry.enabled:
@@ -696,7 +695,7 @@ class InferenceEngine:
                 # the decode fn's own first-call timer is still armed (the
                 # genuine first compile, which records itself)
                 first = False
-                start_alloc = cache_alloc_len(cache)
+                start_alloc = kv_cache.alloc_len(self.cfg, cache)
                 if (start_alloc in fresh and self.telemetry.enabled
                         and getattr(self._decode_fn, "_done", True)):
                     fresh.discard(start_alloc)
@@ -756,10 +755,7 @@ class InferenceEngine:
 
         def build():
             def grow(c):
-                return jax.tree.map(
-                    lambda leaf: jnp.pad(
-                        leaf, [(0, 0), (0, 0), (0, new_len - leaf.shape[2]),
-                               (0, 0), (0, 0)]), c)
+                return kv_cache.grow(self.cfg, c, new_len)
 
             return jax.jit(grow, in_shardings=(sharding,),
                            out_shardings=sharding)

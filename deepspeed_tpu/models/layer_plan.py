@@ -15,20 +15,16 @@ another length all break it); the run walk takes any plan, and a model cut
 to one period costs the same either way. A run that is not the whole of
 its kind's stack reads a static slice of it.
 
-The KV cache is one pool per attention reach, in one tree the slot manager
-carries and donates whole:
-
-    cache["full"]   {"k": (L_full, B, kv, T, dk),      "v": (..., dv)}
-    cache["window"] {"k": (L_win,  B, kv, window, dk), "v": (..., dv)}
-
-heads before time: a row's keys of one head are a (T, dk) matrix as the
-score and value contractions and the chunk kernel want it (time before
-heads, the compiler re-laid out the whole value pool on the way into every
-tick and back). ``full`` rows are written and read like the one-kind pool (in place, tight
-reads). ``window`` is a ring: position p of a row lives in slot p mod
-window, which is all a window layer can ever attend, so a window layer
-costs ``window`` positions of memory and of read however long the row is.
-A prefill chunk never reads its own keys through the ring: it attends the
+The KV cache is one pool per attention reach, ``cache["full"]`` and
+``cache["window"]``, in one tree the slot manager carries and donates
+whole; its format (axis order, shapes, bytes, the in-place write and the
+window read) is ``ops/transformer/kv_cache.py``'s, and the attention here
+contracts the windows it hands out heads before time. ``full`` rows are
+written and read like the one-kind pool (in place, tight reads).
+``window`` is a ring: position p of a row lives in slot p mod window,
+which is all a window layer can ever attend, so a window layer costs
+``window`` positions of memory and of read however long the row is. A
+prefill chunk never reads its own keys through the ring: it attends the
 ring's tail (the ``window`` positions before the chunk, put in order) joined
 to the chunk's own keys, then writes its last ``window`` tokens.
 
@@ -36,16 +32,17 @@ Three entry points: :func:`forward_plan` (no cache: training, the reference
 comparison), :func:`forward_plan_cached` (the serving tick: every slot's
 one decode token at its own depth and, with ``chunk``, ONE admitting
 row's prefill chunk beside them, as one flat list of tokens through the
-projections and FFNs) and the bookkeeping the engine asks for
-(:func:`init_pools`, :func:`kv_read_bytes_by_pool`).
+projections and FFNs).
 """
 
 import math
+from functools import partial
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops.transformer import kv_cache
 from deepspeed_tpu.telemetry.hlo_scopes import Scope
 
 
@@ -110,13 +107,11 @@ def layers_of(cfg, kind) -> int:
     return sum(k is kind for k in cfg.plan)
 
 
-def pool_shapes(cfg):
-    """{pool: (layers, kv_heads, window or 0)} for the pools the plan needs."""
-    out = {}
-    for kind in cfg.plan:
-        n = out.get(kind.pool, (0,))[0]
-        out[kind.pool] = (n + 1, kind.kv_heads, kind.window)
-    return out
+def pool_shapes(cfg):  # {pool: (layers, kv_heads, window or 0)}; kept for test_bench_mimo_v2.py
+    return {s.name: (s.layers, s.kv_heads, s.ring or 0) for s in kv_cache.specs(cfg)}
+
+
+kv_read_bytes_by_pool = kv_cache.read_bytes_by_pool  # kept for tests/benchmark/test_bench_mimo_v2.py
 
 
 def _ffn_size(cfg, kind):
@@ -185,25 +180,6 @@ def init_layers(rng, cfg):
             tree.setdefault(group, {})[name] = leaf
         out[kind.name] = tree
     return out
-
-
-def init_pools(cfg, batch_size: int, length: int):
-    dt = cfg.jnp_dtype
-    out = {}
-    for pool, (n, kv, window) in pool_shapes(cfg).items():
-        T = window if window else length
-        out[pool] = {"k": jnp.zeros((n, batch_size, kv, T, cfg.head_dim), dt),
-                     "v": jnp.zeros((n, batch_size, kv, T, cfg.v_head_dim), dt)}
-    return out
-
-
-def kv_read_bytes_by_pool(cfg, read_len: int) -> dict:
-    """{pool: bytes ONE row's attention streams from it in a decode step
-    that attends ``read_len`` slots}: a window layer reads its ring."""
-    item = jnp.dtype(cfg.jnp_dtype).itemsize
-    return {pool: n * (min(window, read_len) if window else read_len) * kv
-            * (cfg.head_dim + cfg.v_head_dim) * item
-            for pool, (n, kv, window) in pool_shapes(cfg).items()}
 
 
 # ---------------------------------------------------------------------------
@@ -375,40 +351,12 @@ def forward_plan(params, cfg, tokens, return_hidden=False):
 # the serving tick
 # ---------------------------------------------------------------------------
 
-def _layer_window(pool, layer, size):
-    """(B, H, size, x): the first ``size`` slots of every row of one layer."""
-    _, B, H, _, x = pool.shape
-    return jax.lax.dynamic_slice(pool, (layer, 0, 0, 0, 0), (1, B, H, size, x))[0]
-
-
-def _write_rows(pool, layer, new, cols, size):
-    """One token a row, ``new`` (B, H, x), into slot ``cols`` (B,) of its
-    row, in place: slice, select and update fuse into one pass over the
-    layer's first ``size`` slots. A column at or past ``size`` drops."""
-    window = _layer_window(pool, layer, size)
-    hit = cols[:, None] == jnp.arange(size, dtype=cols.dtype)[None, :]
-    window = jnp.where(hit[:, None, :, None], new.astype(pool.dtype)[:, :, None, :], window)
-    return jax.lax.dynamic_update_slice(pool, window[None], (layer, 0, 0, 0, 0))
-
-
-def _row_window(pool, layer, slot, start, size):
-    """(H, size, x): ``size`` slots of row ``slot`` of layer ``layer``."""
-    _, _, H, _, x = pool.shape
-    return jax.lax.dynamic_slice(pool, (layer, slot, 0, start, 0), (1, 1, H, size, x))[0, 0]
-
-
-def _write_row(pool, layer, slot, start, size, new, cols):
-    """``new`` (W, H, x) into slots ``start + cols`` (W,) of one row, through
-    a ``size``-slot window of it; a column outside the window drops. A
-    one-hot contraction lays the tokens out along the window (exact: one
-    term a slot), so nothing is scattered token by token."""
-    window = _row_window(pool, layer, slot, start, size)
-    hit = jnp.arange(size, dtype=cols.dtype)[:, None] == cols[None, :]          # (size, W)
-    placed = jnp.einsum("rs,shx->hrx", hit.astype(pool.dtype), new.astype(pool.dtype),
-                        precision=jax.lax.Precision.HIGHEST,
-                        preferred_element_type=jnp.float32).astype(pool.dtype)
-    window = jnp.where(hit.any(axis=1)[None, :, None], placed, window)
-    return jax.lax.dynamic_update_slice(pool, window[None, None], (layer, slot, 0, start, 0))
+# the pools' window and write, in the order this attention contracts them
+_window = partial(kv_cache.window, heads_first=True)
+_write = partial(kv_cache.write, heads_first=True)
+# kept, and looked up when a tick is traced: tools/mimo_cell_variant.py and
+# tests/benchmark/test_mimo_cell_variant.py replace it by assignment
+_write_rows = _write
 
 
 def _attend_cached(q, k, v, attn_p, kind, cfg, pk, pv, layer, pos, chunk, read_len, length):
@@ -427,7 +375,7 @@ def _attend_cached(q, k, v, attn_p, kind, cfg, pk, pv, layer, pos, chunk, read_l
         pk = _write_rows(pk, layer, k[:B], cols, size)
         pv = _write_rows(pv, layer, v[:B], cols, size)
     with jax.named_scope(Scope.ATTN_KV_READ):
-        kw, vw = _layer_window(pk, layer, size), _layer_window(pv, layer, size)
+        kw, vw = _window(pk, layer, size), _window(pv, layer, size)
         slot = jnp.arange(size, dtype=jnp.int32)[None, :]
         # the position a ring slot holds once this token is written: the largest one not
         # past the row's depth that falls in the slot; below zero, nothing was written
@@ -446,8 +394,8 @@ def _attend_cached(q, k, v, attn_p, kind, cfg, pk, pv, layer, pos, chunk, read_l
         with jax.named_scope(Scope.ATTN_KV_READ):
             # the window before the chunk, in order of position: position first - R + j
             # lives in slot (first + j) mod R
-            tail_k = jnp.roll(_row_window(pk, layer, chunk.slot, 0, R), -(first % R), axis=1)
-            tail_v = jnp.roll(_row_window(pv, layer, chunk.slot, 0, R), -(first % R), axis=1)
+            tail_k = jnp.roll(_window(pk, layer, R, slot=chunk.slot), -(first % R), axis=1)
+            tail_v = jnp.roll(_window(pv, layer, R, slot=chunk.slot), -(first % R), axis=1)
         with jax.named_scope(Scope.ATTN_WINDOW):
             out = flash_attention_chunk(
                 qc, jnp.concatenate([tail_k, kc.transpose(1, 0, 2)], axis=1),
@@ -456,18 +404,18 @@ def _attend_cached(q, k, v, attn_p, kind, cfg, pk, pv, layer, pos, chunk, read_l
         with jax.named_scope(Scope.ATTN_KV_WRITE):
             last = first + real.sum(dtype=jnp.int32)
             cols = jnp.where(real & (chunk.pos >= last - R), chunk.pos % R, R)
-            pk = _write_row(pk, layer, chunk.slot, 0, R, kc, cols)
-            pv = _write_row(pv, layer, chunk.slot, 0, R, vc, cols)
+            pk = _write(pk, layer, kc, cols, R, slot=chunk.slot)
+            pv = _write(pv, layer, vc, cols, R, slot=chunk.slot)
     else:
         width = min(W, size)
         with jax.named_scope(Scope.ATTN_KV_WRITE):
             start = jnp.clip(first, 0, size - width)
             cols = jnp.where(real, chunk.pos - start, width)
-            pk = _write_row(pk, layer, chunk.slot, start, width, kc, cols)
-            pv = _write_row(pv, layer, chunk.slot, start, width, vc, cols)
+            pk = _write(pk, layer, kc, cols, width, slot=chunk.slot, start=start)
+            pv = _write(pv, layer, vc, cols, width, slot=chunk.slot, start=start)
         with jax.named_scope(Scope.ATTN_KV_READ):
-            row_k = _row_window(pk, layer, chunk.slot, 0, size)
-            row_v = _row_window(pv, layer, chunk.slot, 0, size)
+            row_k = _window(pk, layer, size, slot=chunk.slot)
+            row_v = _window(pv, layer, size, slot=chunk.slot)
         with jax.named_scope(Scope.ATTN_FULL):
             out = flash_attention_chunk(qc, row_k, row_v, q_off=first, sink=sink, sm_scale=scale)
     return jnp.concatenate([rows.reshape(B, -1), out.reshape(W, -1)]), pk, pv
@@ -488,7 +436,7 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
     tf = _tf()
     dtype = cfg.jnp_dtype
     B = tokens.shape[0]
-    length = tf.cache_alloc_len(cache)
+    length = kv_cache.alloc_len(cfg, cache)
     if read_len is not None and read_len >= length:
         read_len = None
     all_toks, all_pos = tokens, pos

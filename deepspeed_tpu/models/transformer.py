@@ -520,11 +520,12 @@ def _norm(x, scale, bias, cfg: TransformerConfig):
 # (ops/transformer/inference_ops.apply_rotary_pos_emb; reference analogue
 # csrc/transformer/inference apply_rotary_pos_emb.cu)
 from deepspeed_tpu.ops.transformer.fused_ops import fused_softmax  # noqa: E402
+from deepspeed_tpu.ops.transformer import kv_cache  # noqa: E402
 from deepspeed_tpu.ops.transformer.inference_ops import (  # noqa: E402
     apply_rotary_pos_emb as _rope,
     softmax_context,
-    update_kv_cache,
 )
+from deepspeed_tpu.ops.transformer.kv_cache import update_kv_cache  # noqa: E402
 
 
 def _alibi_slopes(n_heads: int) -> jnp.ndarray:
@@ -1253,63 +1254,8 @@ def head_loss_fwd(params, cfg: TransformerConfig, x, batch, denom=None):
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg: TransformerConfig, batch_size: int, max_len: Optional[int] = None):
-    """The KV pool, all layers stacked: (L, B, T, kv_heads, head_dim) in
-    model dtype — or, with ``kv_cache_dtype="int8"``, {"q8": int8, "s": f32
-    per-token-per-head scales} per component (half the decode-read bytes;
-    the quantized write / dequantized read live in inference_ops). The
-    layer axis stays unsharded and leading: ``forward_with_cache`` carries
-    the whole pool through its layer scan and indexes it by layer."""
-    T = max_len or cfg.max_seq_len
-    if cfg.layer_kinds is not None:
-        from deepspeed_tpu.models.layer_plan import init_pools
-
-        return init_pools(cfg, batch_size, T)
-    shape = (cfg.num_layers, batch_size, T, cfg.kv_heads, cfg.head_dim)
-    if cfg.kv_cache_dtype == "int8":
-        def q_component():
-            return {"q8": jnp.zeros(shape, jnp.int8),
-                    "s": jnp.zeros(shape[:-1] + (1,), jnp.float32)}
-
-        return {"k": q_component(), "v": q_component()}
-    return {
-        "k": jnp.zeros(shape, cfg.jnp_dtype),
-        "v": jnp.zeros(shape, cfg.jnp_dtype),
-    }
-
-
-def cache_alloc_len(cache) -> int:
-    """Allocated time-axis length of a cache pytree (dense or int8; of a
-    layer plan's pools, the full-length pool's)."""
-    if "full" in cache:  # heads before time there (models/layer_plan.py)
-        return cache["full"]["k"].shape[3]
-    return jax.tree.leaves(cache)[0].shape[2]
-
-
-def kv_read_bytes_per_row(cfg: TransformerConfig, read_len: int,
-                          tp: int = 1) -> int:
-    """HBM bytes ONE sequence row's attention streams from the KV cache
-    when a decode step attends ``read_len`` slots: K and V across all
-    layers, int8 payload + fp32 per-token-per-head scales when
-    ``kv_cache_dtype == "int8"``. This is the deterministic host-side
-    accounting behind the ``kv_bytes_read`` telemetry field and the
-    bench's roofline math — it counts exactly what the compiled read
-    touches, so tests can assert it.
-
-    ``tp`` is the tensor width the cache's heads axis is ACTUALLY split
-    over (parallel.partition.kv_shard_width): each chip streams only its
-    head shard, so the PER-CHIP bytes — the quantity that bounds a
-    bandwidth-limited decode step — divide by it. Must divide kv_heads
-    (the caller resolves the replicated fallback to tp=1)."""
-    if cfg.layer_kinds is not None:
-        from deepspeed_tpu.models.layer_plan import kv_read_bytes_by_pool
-
-        return sum(kv_read_bytes_by_pool(cfg, read_len).values()) // tp
-    assert cfg.kv_heads % tp == 0, (cfg.kv_heads, tp)
-    if cfg.kv_cache_dtype == "int8":
-        per_slot = cfg.kv_heads * (cfg.head_dim * 1 + 4)  # q8 payload + s
-    else:
-        per_slot = cfg.kv_heads * cfg.head_dim * jnp.dtype(cfg.jnp_dtype).itemsize
-    return 2 * cfg.num_layers * read_len * per_slot // tp
+    # kept for benchmark/rehearse_aot.py and runtime/hybrid_engine.py: everyone else calls kv_cache.init
+    return kv_cache.init(cfg, batch_size, max_len or cfg.max_seq_len)
 
 
 def _layer_body_cached(x, layer_params, pool_k, pool_v, layer, cfg: TransformerConfig, positions,
@@ -1371,7 +1317,7 @@ def _layer_body_cached(x, layer_params, pool_k, pool_v, layer, cfg: TransformerC
         attn_out = _attn_out_proj(attn_out, attn_p, cfg)
         return _finish_layer_cached(x, h, attn_out, layer_params, cfg), pool_k, pool_v
 
-    cache_T = cache_alloc_len(pool_k)
+    cache_T = kv_cache.alloc_len(cfg, pool_k)
     assert not (ring and S > 1 and cache_T < S), (
         "rolling KV cache: a multi-token segment longer than the ring must "
         f"take the flash prefill path (S={S}, cache={cache_T}) — a segment "
@@ -1422,7 +1368,7 @@ def forward_with_cache(params, cfg: TransformerConfig, tokens, cache, pos, posit
     caller guarantees the active extent (the new tokens included) fits.
 
     The pool travels in the layer scan's CARRY, never as its xs / ys: each
-    layer updates ``[layer]`` in place (inference_ops.update_kv_cache) and
+    layer updates ``[layer]`` in place (kv_cache.update_kv_cache) and
     reads its window straight back (softmax_context), so with the cache
     donated a call moves the windows it reads and no layer-sized copy.
     Returns (logits (B,S,V), updated cache)."""
@@ -1438,7 +1384,7 @@ def forward_with_cache(params, cfg: TransformerConfig, tokens, cache, pos, posit
                                                read_len=read_len)
         return logits[:, None], cache
     B, S = tokens.shape
-    if read_len is not None and read_len >= cache_alloc_len(cache):
+    if read_len is not None and read_len >= kv_cache.alloc_len(cfg, cache):
         read_len = None  # degenerate slice: the allocation is already tight
     if positions is not None:
         assert jnp.ndim(pos) == 1, "explicit positions require vector pos"
@@ -1529,7 +1475,7 @@ def forward_tick_cached(params, cfg: TransformerConfig, tokens, pos, cache, chun
     (B + 1, V): the rows', then the chunk's column ``chunk.emit``; cache)."""
     assert not cfg.rolling_kv_cache, "slot pools run plain caches (rows sit at their own depths)"
     B = tokens.shape[0]
-    if read_len is not None and read_len >= cache_alloc_len(cache):
+    if read_len is not None and read_len >= kv_cache.alloc_len(cfg, cache):
         read_len = None
     positions = jnp.concatenate([pos, chunk.pos])[None]                  # (1, B + W)
     x = _embed_cached(params, cfg, jnp.concatenate([tokens, chunk.toks])[None], positions,

@@ -1,0 +1,331 @@
+"""The KV cache's data format: the ONE module that knows the order of a
+pool's axes, its dtype forms, its bytes and how its heads are sharded.
+
+A cache is a tree of pools the slot manager carries and donates whole:
+``{"k", "v"}`` (one kind of layer: pool "kv") or ``{"full": {"k", "v"},
+"window": {"k", "v"}}`` (a layer plan). A leaf is one stacked array,
+``(L, B, T, kv_heads, width)`` (time before heads) or ``(L, B, kv_heads, T,
+width)`` (heads before time), or int8 (``kv_cache_dtype="int8"``) the pair
+``{"q8": payload, "s": float32 per-token-per-head scales}`` of such arrays.
+
+Three parts: the spec (:func:`specs` chooses the order), what the host
+knows (init, length, bytes, sharding, growth, splice) and the device-side
+window and write. Attention is not format: ``softmax_context`` and
+``layer_plan._attend_cached`` contract the windows handed out here, each in
+its pool's order. The configuration is duck-typed (``ops`` is below ``models``).
+"""
+
+from typing import NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec
+
+
+class PoolSpec(NamedTuple):
+    name: str              # "kv" (one kind of layer) / "full" / "window"
+    layers: int
+    kv_heads: int
+    k_width: int
+    v_width: int
+    ring: Optional[int]    # slots of a ring (position p in slot p mod ring); None: the allocation
+    heads_first: bool      # the order of a leaf's (time, heads) axes
+    int8: bool             # {"q8", "s"} leaves
+
+    @property
+    def time_axis(self) -> int:
+        return 3 if self.heads_first else 2
+
+    @property
+    def heads_axis(self) -> int:
+        return 2 if self.heads_first else 3
+
+    def shape(self, batch: int, length: int, width: int) -> tuple:
+        T = self.ring or length
+        mid = (self.kv_heads, T) if self.heads_first else (T, self.kv_heads)
+        return (self.layers, batch) + mid + (width,)
+
+
+def _is_plan(cfg) -> bool:
+    return getattr(cfg, "layer_kinds", None) is not None
+
+
+def specs(cfg) -> Tuple[PoolSpec, ...]:
+    """The pools of ``cfg``'s cache. The ONE place that chooses a layout,
+    and it chooses as each model body was written. One kind of layer, time
+    before heads: GPT-2 XL's 25 heads of 64 stay time-minor on the chip, and
+    heads first a token-sized update re-laid out the whole pool, two pool
+    copies a tick (PR 25). A layer plan, heads before time: at MiMo's 4-8
+    heads of 192 / 128 a row's keys of one head are the (T, width) matrix
+    the contractions and the chunk kernel want, and time first the compiler
+    copied the whole value pool into and out of every tick (PR 27). A choice
+    from the shapes alone, one order for both bodies, is ROADMAP Queue 1
+    item 1's to make, here. A rolling one-kind cache is a ring as long as
+    its allocation: ``ring`` stays None, the write takes ``ring=True``."""
+    if not _is_plan(cfg):
+        return (PoolSpec("kv", cfg.num_layers, cfg.kv_heads, cfg.head_dim, cfg.head_dim, None,
+                         heads_first=False, int8=cfg.kv_cache_dtype == "int8"),)
+    pools = {}
+    for kind in cfg.plan:
+        n = pools[kind.pool].layers if kind.pool in pools else 0
+        pools[kind.pool] = PoolSpec(kind.pool, n + 1, kind.kv_heads, cfg.head_dim,
+                                    cfg.v_head_dim, kind.window or None,
+                                    heads_first=True, int8=False)
+    return tuple(pools.values())
+
+
+# -- what the host knows ----------------------------------------------------
+
+def _build(cfg, leaf):
+    """``cfg``'s cache tree with ``leaf(spec, width, dtype)`` in every array's place."""
+    def component(spec, width):
+        if spec.int8:
+            return {"q8": leaf(spec, width, jnp.int8), "s": leaf(spec, 1, jnp.float32)}
+        return leaf(spec, width, cfg.jnp_dtype)
+
+    pools = {s.name: {"k": component(s, s.k_width), "v": component(s, s.v_width)}
+             for s in specs(cfg)}
+    return pools if _is_plan(cfg) else pools["kv"]
+
+
+def _pools(cfg, cache):
+    """[(spec, its subtree)]; of a one-kind cache any part will do (one spec)."""
+    if not _is_plan(cfg):
+        return [(specs(cfg)[0], cache)]
+    return [(s, cache[s.name]) for s in specs(cfg)]
+
+
+def init(cfg, batch_size: int, length: int):
+    """The zeroed cache; a ring pool is ``ring`` long whatever ``length`` is."""
+    return _build(cfg, lambda spec, width, dtype: jnp.zeros(
+        spec.shape(batch_size, length, width), dtype))
+
+
+def alloc_len(cfg, cache) -> int:
+    """Allocated length of the time axis (of a plan's pools, the full pool's:
+    the slot manager reads a row's room off it)."""
+    return next(jax.tree.leaves(sub)[0].shape[spec.time_axis]
+                for spec, sub in _pools(cfg, cache) if spec.ring is None)
+
+
+def grow(cfg, cache, new_len: int):
+    """``cache`` zero-padded along time to ``new_len`` (a ring keeps its
+    length). Traced: the caller jits it."""
+    grown, more = {}, new_len - alloc_len(cfg, cache)
+    for spec, sub in _pools(cfg, cache):
+        widths = [(0, 0)] * 5
+        widths[spec.time_axis] = (0, more)
+        grown[spec.name] = sub if spec.ring else jax.tree.map(lambda a: jnp.pad(a, widths), sub)
+    return grown if _is_plan(cfg) else grown["kv"]
+
+
+def splice_row(big, small, slot):
+    """The one-row cache ``small`` over the first slots of row ``slot`` of
+    ``big`` (the prefix splice; staler entries beyond stay, causally masked
+    until real writes reach them). Either order, dense or int8. Traced."""
+    return jax.tree.map(
+        lambda b, sm: jax.lax.dynamic_update_slice(b, sm.astype(b.dtype), (0, slot, 0, 0, 0)),
+        big, small)
+
+
+def pool_bytes(cfg, cache) -> dict:
+    return {spec.name: sum(leaf.nbytes for leaf in jax.tree.leaves(sub))
+            for spec, sub in _pools(cfg, cache)}
+
+
+def read_bytes_by_pool(cfg, read_len: int) -> dict:
+    """{pool: HBM bytes ONE row's attention streams from it in a decode step
+    that attends ``read_len`` slots}: K and V across its layers (a ring is
+    read whole and no further), int8 as payload + a float32 scale a token
+    and head. What the compiled read touches, so tests assert it."""
+    item = jnp.dtype(cfg.jnp_dtype).itemsize
+    out = {}
+    for s in specs(cfg):
+        per_head = (s.k_width + s.v_width) * (1 if s.int8 else item) + (2 * 4 if s.int8 else 0)
+        out[s.name] = s.layers * min(s.ring or read_len, read_len) * s.kv_heads * per_head
+    return out
+
+
+def read_bytes_per_row(cfg, read_len: int, tp: int = 1) -> int:
+    """:func:`read_bytes_by_pool` summed (``kv_bytes_read``, the roofline
+    math); ``tp`` (:func:`shard_width`) makes it PER-CHIP: a chip streams its
+    head shard only, which is what bounds a bandwidth-limited decode step."""
+    assert all(s.kv_heads % tp == 0 for s in specs(cfg)), (specs(cfg), tp)
+    return sum(read_bytes_by_pool(cfg, read_len).values()) // tp
+
+
+def _tensor_split(cfg, mesh) -> int:
+    """The ONE rule of where ``tensor`` goes: on the heads axis when the
+    heads divide its width evenly (returned), else nowhere (0: every chip
+    reads full rows). A layer plan's pools differ in heads: they stay whole."""
+    t = 1 if mesh is None else int(mesh.shape.get("tensor", 1))
+    return t if not _is_plan(cfg) and cfg.kv_heads % t == 0 else 0
+
+
+def shard_width(mesh, cfg) -> int:
+    """How many ways the heads axis is ACTUALLY split on this mesh."""
+    return _tensor_split(cfg, mesh) or 1
+
+
+def partition_spec(cfg, mesh, batch_axes):
+    """The cache tree of ``PartitionSpec``s: ``batch_axes`` on the batch
+    axis, ``tensor`` on the HEADS axis of each leaf's own order or nowhere."""
+    def leaf(spec, width, dtype):
+        axes = [None, batch_axes, None, None, None]
+        if _tensor_split(cfg, mesh):
+            axes[spec.heads_axis] = "tensor"
+        return PartitionSpec(*axes)
+
+    return _build(cfg, leaf)
+
+
+# -- on the device: window and write of one stacked array, both orders -------
+#                   time before heads                 heads before time
+#   rows' window    (B, size, H, x)                   (B, H, size, x)
+#   one row's       (1, size, H, x), from slot 0      (H, size, x), from ``start``
+#   rows write      new (B, S, H, x), cols (B, S)     new (B, H, x), cols (B,)
+#   chunk write     new (1, S, H, x), cols (1, S)     new (W, H, x), cols (W,)
+#
+# Tokens come as the model body of that order produces them, windows go out
+# as its attention contracts them. A column outside [0, size) drops its
+# token: the ONE drop rule of every write.
+
+def window(pool, layer, size, *, heads_first: bool, slot=None, start=0):
+    """``size`` slots (static) of ``[layer]`` straight out of the stacked
+    pool, one ``dynamic_slice``: every row's first ``size``, or with
+    ``slot`` (i32 scalar) that ONE row's from ``start`` on."""
+    if not heads_first:
+        assert isinstance(start, int) and start == 0
+        _, B, T, H, x = pool.shape
+        first, rows = (0, B) if slot is None else (slot, 1)
+        return jax.lax.dynamic_slice(
+            pool, (layer, first, 0, 0, 0), (1, rows, size or T, H, x))[0]
+    _, B, H, _, x = pool.shape
+    if slot is None:
+        return jax.lax.dynamic_slice(pool, (layer, 0, 0, 0, 0), (1, B, H, size, x))[0]
+    return jax.lax.dynamic_slice(pool, (layer, slot, 0, start, 0), (1, 1, H, size, x))[0, 0]
+
+
+def write(pool, layer, new, cols, size, *, heads_first: bool, slot=None, start=0):
+    """``new`` into slots ``cols`` of ``[layer]``, in place, through the
+    window :func:`window` reads: one token a row, or with ``slot`` W tokens
+    into that ONE row at ``start + cols``. Slice, select and update fuse
+    into one pass over the window; several tokens a row are laid out along
+    it by a one-hot contraction (exact: one term a slot), never scattered
+    token by token, so no index is dynamic along the time axis."""
+    if not heads_first:
+        merged = _place(window(pool, layer, size, heads_first=False, slot=slot), new, cols)
+        return jax.lax.dynamic_update_slice(
+            pool, merged[None], (layer, 0 if slot is None else slot, 0, 0, 0))
+    merged = window(pool, layer, size, heads_first=True, slot=slot, start=start)
+    if slot is None:
+        hit = cols[:, None] == jnp.arange(size, dtype=cols.dtype)[None, :]
+        merged = jnp.where(hit[:, None, :, None], new.astype(pool.dtype)[:, :, None, :], merged)
+        return jax.lax.dynamic_update_slice(pool, merged[None], (layer, 0, 0, 0, 0))
+    hit = jnp.arange(size, dtype=cols.dtype)[:, None] == cols[None, :]          # (size, W)
+    placed = jnp.einsum("rs,shx->hrx", hit.astype(pool.dtype), new.astype(pool.dtype),
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32).astype(pool.dtype)
+    merged = jnp.where(hit.any(axis=1)[None, :, None], placed, merged)
+    return jax.lax.dynamic_update_slice(pool, merged[None, None], (layer, slot, 0, start, 0))
+
+
+def _place(win, new, cols):
+    """Time before heads: ``win`` (B, R, H, x) with ``new`` (B, S, H, x) at
+    columns ``cols`` (B, S), distinct within a row. The TPU keeps this pool
+    time-minor, where a token-sized scatter or ``dynamic_update_slice``
+    makes the compiler re-lay out the whole pool. A single token needs no
+    contraction, and without one slice, select and update fuse in place."""
+    hit = cols[:, None, :] == jnp.arange(win.shape[1], dtype=cols.dtype)[None, :, None]
+    placed = new.astype(win.dtype)  # S == 1: the one token, wherever its column hits
+    if new.shape[1] > 1:
+        acc = jnp.int32 if jnp.issubdtype(placed.dtype, jnp.integer) else jnp.float32
+        placed = jnp.einsum("brs,bshx->brhx", hit.astype(placed.dtype), placed,
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=acc).astype(win.dtype)
+    return jnp.where(hit.any(-1)[:, :, None, None], placed, win)
+
+
+# -- on the device: time before heads, S tokens a row, dense or int8 --------
+
+def quantize_kv(x):
+    """Per-token-per-head symmetric int8 quantization of (B, S, H, hd)
+    keys/values (the int8 write; scales keep the trailing dim)."""
+    a = x.astype(jnp.float32)
+    s = jnp.max(jnp.abs(a), axis=-1, keepdims=True) / 127.0
+    s = jnp.maximum(s, 1e-8)
+    q = jnp.clip(jnp.round(a / s), -127, 127).astype(jnp.int8)
+    return q, s
+
+
+def dequantize_kv(cache_component, dtype):
+    """{"q8","s"} component -> dense in ``dtype``. Under jit convert and
+    multiply fuse into the attention read: HBM traffic is payload + scales."""
+    return (cache_component["q8"].astype(jnp.float32) * cache_component["s"]).astype(dtype)
+
+
+def kv_window(cache_component, read_len: Optional[int] = None, layer=None, slot=None):
+    """First ``read_len`` (static; default all) slots of a component (dense
+    or int8 pair) as one layer's (B, read_len, H, x): of one layer's
+    (B, T, H, x) cache (``layer`` None), or straight out of ``[layer]`` of
+    the stacked pool (:func:`window`); with ``slot`` ONE row's, (1,
+    read_len, H, x). The window is no longer than what attention reads (the
+    tight-read geometry: the bucketed active length, not the allocation)."""
+    def one(c):
+        if layer is None:
+            return c if read_len is None else c[:, :read_len]
+        return window(c, layer, read_len, heads_first=False, slot=slot)
+
+    return jax.tree.map(one, cache_component)
+
+
+def _write_columns(T, new_shape, pos, positions, ring):
+    """Cache column (B, S) each new token lands in (outside the cache: dropped)."""
+    B, S = new_shape[:2]
+    if positions is None:
+        positions = jnp.reshape(pos, (-1, 1)) + jnp.arange(S, dtype=jnp.int32)[None, :]
+    positions = jnp.broadcast_to(positions, (B, S))
+    if not ring:
+        return positions
+    # ring: slot = absolute position mod cache length. Stale tokens of an
+    # over-long segment (more new tokens than slots) drop instead of
+    # colliding: only its last T positions land, later tokens must win.
+    assert jnp.ndim(pos) == 0, "ring cache writes need the aligned (scalar-pos) path"
+    return jnp.where(positions >= pos + S - T, positions % T, T)
+
+
+def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, positions=None, ring=False,
+                    layer=None, write_len=None, slot=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Write S new keys/values (B, S, H, hd) into (B, T, H, hd) caches (or
+    int8 {"q8","s"} components: the write quantizes per token and head).
+
+    ``pos`` python int: static offset (the prefill program). ``pos`` scalar:
+    contiguous write at ``pos``. ``pos`` (B,) with ``positions`` (B, S):
+    each row's segment at its own depth (speculative verify / draft, the
+    serving tick); columns >= T drop, matching ``softmax_context``'s mask.
+    ``ring``: positions wrap modulo the cache length (scalar ``pos`` +
+    ``positions``). ``layer`` (i32 scalar): the caches are the stacked
+    (L, B, T, H, hd) pool the layer scan carries, updated in place at
+    ``[layer]``; ``write_len`` (static, the step's ``read_len``) then bounds
+    the slots touched: columns at or beyond it drop too, which loses nothing
+    because ``read_len`` covers every live position. ``slot`` (with
+    ``layer``): the tokens are ONE row's, (1, S, H, hd) at ``positions``
+    (1, S), and only that row of the pool is touched (a tick's chunk)."""
+    def component(cache, new):
+        if isinstance(pos, int) and not ring:
+            new = new.astype(cache.dtype)  # the S tokens and nothing else
+            if layer is None:
+                return jax.lax.dynamic_update_slice(cache, new, (0, pos, 0, 0))
+            return jax.lax.dynamic_update_slice(cache, new[None], (layer, 0, pos, 0, 0))
+        cols = _write_columns(cache.shape[-3], new.shape, pos, positions, ring)
+        if layer is None:
+            return _place(cache, new, cols)
+        return write(cache, layer, new, cols, write_len, heads_first=False, slot=slot)
+
+    def write_one(cache, new):
+        if isinstance(cache, dict):
+            q, s = quantize_kv(new)
+            return {"q8": component(cache["q8"], q), "s": component(cache["s"], s)}
+        return component(cache, new)
+
+    return write_one(k_cache, k_new), write_one(v_cache, v_new)

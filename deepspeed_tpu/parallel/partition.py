@@ -277,11 +277,9 @@ def mesh_tensor_width(mesh: Optional[Mesh]) -> int:
 
 def kv_shard_width(mesh: Optional[Mesh], cfg) -> int:
     """How many ways the KV cache's heads axis is ACTUALLY split on this
-    mesh — the ONE divisor behind per-chip ``kv_bytes_read`` accounting,
-    mirroring _decode_shardings' kv_tensor choice exactly: the heads dim
-    shards over ``tensor`` only when kv_heads divide evenly; otherwise
-    the cache replicates and every chip reads full rows."""
-    t = mesh_tensor_width(mesh)
-    if t <= 1 or cfg.kv_heads % t != 0 or getattr(cfg, "layer_kinds", None) is not None:
-        return 1
-    return t
+    mesh — the ONE divisor behind per-chip ``kv_bytes_read`` accounting.
+    The rule is the cache format's (the sharding of every decode program
+    is built from the same one)."""
+    from deepspeed_tpu.ops.transformer import kv_cache
+
+    return kv_cache.shard_width(mesh, cfg)

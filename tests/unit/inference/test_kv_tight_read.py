@@ -21,8 +21,8 @@ from deepspeed_tpu.inference.decoding import (
 from deepspeed_tpu.models.transformer import (
     TransformerConfig,
     TransformerModel,
-    kv_read_bytes_per_row,
 )
+from deepspeed_tpu.ops.transformer.kv_cache import read_bytes_per_row as kv_read_bytes_per_row
 
 FLOOR = 16  # small bucket floor so tiny test models cross several buckets
 

@@ -22,8 +22,8 @@ from deepspeed_tpu.inference.decoding import decode_kv_bytes, read_bucket
 from deepspeed_tpu.models.transformer import (
     TransformerConfig,
     TransformerModel,
-    kv_read_bytes_per_row,
 )
+from deepspeed_tpu.ops.transformer.kv_cache import read_bytes_per_row as kv_read_bytes_per_row
 from deepspeed_tpu.parallel.partition import (
     DEFAULT_RULES,
     kv_shard_width,

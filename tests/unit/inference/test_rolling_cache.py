@@ -47,10 +47,8 @@ def _engines(window=W, **cfg_overrides):
 
 class TestRingOps:
     def test_ring_degenerates_to_plain_before_wrap(self):
-        from deepspeed_tpu.ops.transformer.inference_ops import (
-            softmax_context,
-            update_kv_cache,
-        )
+        from deepspeed_tpu.ops.transformer.inference_ops import softmax_context
+        from deepspeed_tpu.ops.transformer.kv_cache import update_kv_cache
 
         B, T, H, hd = 2, 8, 2, 4
         rng = jax.random.PRNGKey(0)
@@ -71,7 +69,7 @@ class TestRingOps:
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
 
     def test_ring_write_wraps_and_drops_stale(self):
-        from deepspeed_tpu.ops.transformer.inference_ops import update_kv_cache
+        from deepspeed_tpu.ops.transformer.kv_cache import update_kv_cache
 
         B, T, H, hd = 1, 4, 1, 2
         kc = vc = jnp.zeros((B, T, H, hd), jnp.float32)

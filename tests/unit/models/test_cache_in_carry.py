@@ -20,6 +20,7 @@ import pytest
 
 from deepspeed_tpu.models import transformer as tf
 from deepspeed_tpu.ops.transformer import inference_ops as ops
+from deepspeed_tpu.ops.transformer import kv_cache
 from deepspeed_tpu.telemetry.hlo_scopes import scope_table
 
 B, T, L = 4, 32, 3
@@ -113,7 +114,7 @@ def _ref_update(pool_k, pool_v, k, v, pos, positions=None, ring=False, layer=Non
                 write_len=None):
     def write(pool, new):
         if isinstance(pool, dict):
-            q8, s = ops.quantize_kv(new)
+            q8, s = kv_cache.quantize_kv(new)
             return {"q8": write(pool["q8"], q8), "s": write(pool["s"], s)}
         return pool.at[layer].set(_ref_write_layer(pool[layer], new, pos, positions, ring))
 
@@ -267,7 +268,7 @@ def test_write_past_the_read_window_drops():
     pool = jnp.asarray(np.random.RandomState(0).normal(size=(L, B, T, 2, 8)), jnp.float32)
     new = jnp.ones((B, 1, 2, 8), jnp.float32)
     pos = jnp.asarray([3, 15, 16, PARKED], jnp.int32)
-    got, _ = ops.update_kv_cache(pool, pool, new, new, pos, pos[:, None], layer=1, write_len=16)
+    got, _ = kv_cache.update_kv_cache(pool, pool, new, new, pos, pos[:, None], layer=1, write_len=16)
     want = np.asarray(pool).copy()
     want[1, 0, 3] = 1.0
     want[1, 1, 15] = 1.0

@@ -14,6 +14,7 @@ from deepspeed_tpu.inference import ContinuousBatchingEngine
 from deepspeed_tpu.models import layer_plan
 from deepspeed_tpu.models import transformer as tf
 from deepspeed_tpu.models.transformer import LayerKind, TransformerConfig, TransformerModel
+from deepspeed_tpu.ops.transformer import kv_cache
 
 KINDS = (LayerKind("dense_full", kv_heads=1, rope_theta=1e7, ffn="dense", ffn_size=96),
          LayerKind("moe_window", kv_heads=2, window=8, rope_theta=1e4, sink=True, ffn="moe",
@@ -97,13 +98,13 @@ def test_cache_is_one_pool_a_reach_heads_before_time():
     assert {k: v["k"].shape for k, v in cache.items()} == {
         "full": (2, 3, 1, 128, 24), "window": (3, 3, 2, 8, 24)}   # a ring of `window` positions
     assert cache["full"]["v"].shape[-1] == 16 and cache["window"]["v"].shape[-1] == 16
-    assert tf.cache_alloc_len(cache) == 128
+    assert kv_cache.alloc_len(config(), cache) == 128
 
 
 def test_kv_read_bytes_count_the_row_in_full_layers_and_the_window_in_window_layers():
     by_pool = layer_plan.kv_read_bytes_by_pool(config(), 100)
     assert by_pool == {"full": 2 * 100 * 1 * (24 + 16) * 4, "window": 3 * 8 * 2 * (24 + 16) * 4}
-    assert tf.kv_read_bytes_per_row(config(), 100) == sum(by_pool.values())
+    assert kv_cache.read_bytes_per_row(config(), 100) == sum(by_pool.values())
     assert layer_plan.kv_read_bytes_by_pool(config(), 4)["window"] == 3 * 4 * 2 * 40 * 4
 
 
@@ -212,7 +213,7 @@ def test_a_model_of_one_kind_is_left_as_it_was():
     assert params["layers"]["attn"]["wq"].shape == (2, 32, 32)
     cache = tf.init_cache(cfg, 2, 16)
     assert set(cache) == {"k", "v"} and cache["k"].shape == (2, 2, 16, 4, 8)
-    assert tf.cache_alloc_len(cache) == 16
+    assert kv_cache.alloc_len(cfg, cache) == 16
     text = jax.jit(lambda p, t: tf.forward(p, cfg, t)[0]).lower(
         params, jnp.zeros((1, 8), jnp.int32)).as_text()
     assert text.count("stablehlo.while") == 1

@@ -1,0 +1,285 @@
+"""The KV cache's format behind one module (``ops/transformer/kv_cache.py``):
+what the host knows of every layout that exists (shapes, dtypes, bytes,
+sharding, growth) and the device-side window and write, the same cases run
+against both orders of (time, heads)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding
+
+from deepspeed_tpu import comm
+from deepspeed_tpu.inference.decoding import _decode_shardings
+from deepspeed_tpu.models.transformer import LayerKind, TransformerConfig
+from deepspeed_tpu.ops.transformer import kv_cache
+from deepspeed_tpu.parallel.partition import kv_shard_width
+
+PLAN_KINDS = (LayerKind("dense_full", kv_heads=1, rope_theta=1e7, ffn="dense", ffn_size=96),
+              LayerKind("moe_window", kv_heads=2, window=8, rope_theta=1e4, sink=True, ffn="moe",
+                        ffn_size=32),
+              LayerKind("moe_full", kv_heads=1, rope_theta=1e7, ffn="moe", ffn_size=32))
+
+
+def plan_config(**over):
+    base = dict(vocab_size=97, hidden_size=64, num_layers=5, num_heads=4, head_size=24,
+                v_head_size=16, rope_dim=8, pos_embedding="rope", norm_type="rmsnorm",
+                activation="silu_glu", tie_embeddings=False, use_bias=False, dtype="float32",
+                max_seq_len=128, layer_kinds=PLAN_KINDS, layer_plan=(0, 1, 1, 1, 2),
+                moe_num_experts=16, moe_top_k=4, moe_experts_held=(4, 8))
+    return TransformerConfig(**dict(base, **over))
+
+
+def one_kind(**over):
+    base = dict(vocab_size=64, hidden_size=64, num_layers=2, num_heads=4, max_seq_len=64,
+                dtype="bfloat16")
+    return TransformerConfig(**dict(base, **over))
+
+
+LAYOUTS = {
+    "dense": one_kind,
+    "int8": lambda: one_kind(kv_cache_dtype="int8"),
+    "grouped": lambda: one_kind(num_kv_heads=2),
+    "plan": plan_config,
+}
+
+
+@pytest.fixture(params=sorted(LAYOUTS))
+def cfg(request):
+    return LAYOUTS[request.param]()
+
+
+def pools_of(cfg, cache):
+    """{pool name: (spec, its {"k", "v"} subtree)} of a whole cache."""
+    specs = kv_cache.specs(cfg)
+    if len(specs) == 1 and specs[0].name == "kv":
+        return {"kv": (specs[0], cache)}
+    return {s.name: (s, cache[s.name]) for s in specs}
+
+
+# -- the spec and what the host knows ------------------------------------
+
+def test_specs_name_the_pools_and_choose_the_order():
+    (kv,) = kv_cache.specs(one_kind(num_kv_heads=2, kv_cache_dtype="int8"))
+    assert kv == kv_cache.PoolSpec("kv", 2, 2, 16, 16, None, heads_first=False, int8=True)
+    assert (kv.time_axis, kv.heads_axis) == (2, 3)
+    full, window = kv_cache.specs(plan_config())
+    assert full == kv_cache.PoolSpec("full", 2, 1, 24, 16, None, heads_first=True, int8=False)
+    assert window == kv_cache.PoolSpec("window", 3, 2, 24, 16, 8, heads_first=True, int8=False)
+    assert (full.time_axis, full.heads_axis) == (3, 2)
+
+
+def test_init_gives_the_specs_shapes_and_dtypes(cfg):
+    B, T = 3, 32
+    cache = kv_cache.init(cfg, B, T)
+    for name, (spec, sub) in pools_of(cfg, cache).items():
+        assert set(sub) == {"k", "v"}
+        for part, width in (("k", spec.k_width), ("v", spec.v_width)):
+            shape = spec.shape(B, T, width)
+            assert shape[0] == spec.layers and shape[1] == B and shape[-1] == width
+            assert shape[spec.time_axis] == (spec.ring or T)
+            assert shape[spec.heads_axis] == spec.kv_heads
+            leaf = sub[part]
+            if spec.int8:
+                assert set(leaf) == {"q8", "s"}
+                assert leaf["q8"].shape == shape and leaf["q8"].dtype == jnp.int8
+                assert leaf["s"].shape == shape[:-1] + (1,) and leaf["s"].dtype == jnp.float32
+            else:
+                assert leaf.shape == shape and leaf.dtype == cfg.jnp_dtype
+    assert kv_cache.alloc_len(cfg, cache) == T
+    assert not any(np.asarray(leaf, np.float32).any() for leaf in jax.tree.leaves(cache))
+
+
+def test_pool_bytes_are_the_arrays_bytes(cfg):
+    cache = kv_cache.init(cfg, 3, 32)
+    by_pool = kv_cache.pool_bytes(cfg, cache)
+    assert set(by_pool) == {s.name for s in kv_cache.specs(cfg)}
+    assert sum(by_pool.values()) == sum(leaf.nbytes for leaf in jax.tree.leaves(cache))
+    for name, (_, sub) in pools_of(cfg, cache).items():
+        assert by_pool[name] == sum(leaf.nbytes for leaf in jax.tree.leaves(sub))
+
+
+def test_read_bytes_are_the_values_the_older_tests_pin():
+    # tests/unit/inference/test_kv_tight_read.py: K+V, layers, slots, heads, head_dim, bf16
+    assert kv_cache.read_bytes_per_row(one_kind(), 64) == 2 * 2 * 64 * 4 * 16 * 2
+    # ... and the int8 payload + a 4-byte scale per (token, head)
+    assert kv_cache.read_bytes_per_row(one_kind(kv_cache_dtype="int8"), 64) == 2 * 2 * 64 * 4 * (16 + 4)
+    assert kv_cache.read_bytes_per_row(one_kind(), 64, tp=2) == 2 * 2 * 64 * 2 * 16 * 2
+    assert kv_cache.read_bytes_by_pool(one_kind(num_kv_heads=2), 64) == {"kv": 2 * 2 * 64 * 2 * 16 * 2}
+    # tests/benchmark/test_bench_mimo_v2.py: MiMo-V2.5 at the benchmark's cut, 16,896 slots read
+    mimo = plan_config(
+        num_layers=7, num_heads=64, head_size=192, v_head_size=128, rope_dim=64, dtype="bfloat16",
+        layer_kinds=(LayerKind("dense_full", kv_heads=4, ffn="dense"),
+                     LayerKind("moe_window", kv_heads=8, window=128, sink=True, ffn="moe"),
+                     LayerKind("moe_full", kv_heads=4, ffn="moe")),
+        layer_plan=(0, 1, 1, 1, 1, 1, 2))
+    assert kv_cache.read_bytes_by_pool(mimo, 16896) == {"full": 5120 * 16896,
+                                                       "window": 5 * 8 * 320 * 2 * 128}
+    assert kv_cache.read_bytes_per_row(mimo, 16896) == 5120 * 16896 + 5 * 8 * 320 * 2 * 128
+    # a ring is read whole and no further; a read shorter than the ring reads that much
+    assert kv_cache.read_bytes_by_pool(plan_config(), 4)["window"] == 3 * 4 * 2 * 40 * 4
+
+
+@pytest.mark.parametrize("name, tensor, width", [
+    ("dense", 2, 2), ("int8", 2, 2), ("grouped", 2, 2), ("dense", 4, 4),
+    ("grouped", 4, 1),       # 2 heads do not split 4 ways: the cache replicates
+    ("three_heads", 2, 1),   # nor 3 heads 2 ways
+    ("plan", 2, 1),          # a plan's pools differ in heads: whole on every chip
+    ("dense", 1, 1),
+])
+def test_tensor_sits_on_the_heads_axis_and_shard_width_counts_its_shards(name, tensor, width):
+    comm.destroy()
+    cfg = (one_kind(hidden_size=48, num_heads=3) if name == "three_heads" else LAYOUTS[name]())
+    mesh = comm.build_mesh({"data": 1, "tensor": tensor}, devices=jax.devices()[:tensor])
+    try:
+        cache = kv_cache.init(cfg, 2, 16)
+        pspecs = kv_cache.partition_spec(cfg, mesh, ("data", "fsdp"))
+        assert jax.tree.structure(pspecs) == jax.tree.structure(cache)
+        _, cache_sh = _decode_shardings(mesh, cfg, 2)   # what every decode program is built with
+        assert jax.tree.leaves(cache_sh) == [NamedSharding(mesh, p) for p in jax.tree.leaves(pspecs)]
+        assert kv_cache.shard_width(mesh, cfg) == kv_shard_width(mesh, cfg) == width
+        for _, (spec, sub) in pools_of(cfg, cache).items():
+            sharded = pools_of(cfg, cache_sh)[spec.name][1]
+            for leaf, sh in zip(jax.tree.leaves(sub), jax.tree.leaves(sharded)):
+                on_tensor = [axis for axis, names in enumerate(sh.spec) if names == "tensor"]
+                assert on_tensor in ([], [spec.heads_axis])
+                pieces = [n // m for n, m in zip(leaf.shape, sh.shard_shape(leaf.shape))]
+                assert pieces[spec.heads_axis] == width          # the shards it really has
+                assert all(p == 1 for a, p in enumerate(pieces) if a != spec.heads_axis)
+    finally:
+        comm.destroy()
+
+
+def test_growth_pads_the_time_axis_and_nothing_else(cfg):
+    cache = jax.tree.map(lambda leaf: jnp.ones_like(leaf), kv_cache.init(cfg, 2, 8))
+    grown = kv_cache.grow(cfg, cache, 16)
+    assert jax.tree.structure(grown) == jax.tree.structure(cache)
+    assert kv_cache.alloc_len(cfg, grown) == 16
+    for name, (spec, sub) in pools_of(cfg, grown).items():
+        for new, old in zip(jax.tree.leaves(sub), jax.tree.leaves(pools_of(cfg, cache)[name][1])):
+            if spec.ring:   # a ring keeps its length
+                assert new.shape == old.shape
+                continue
+            want = list(old.shape)
+            want[spec.time_axis] = 16
+            assert list(new.shape) == want and new.dtype == old.dtype
+            head, tail = np.split(np.asarray(new, np.float32), [8], axis=spec.time_axis)
+            assert (head == 1).all() and (tail == 0).all()
+
+
+@pytest.mark.parametrize("name", ["dense", "int8", "grouped"])   # a plan's pools have no splice yet
+def test_splice_lays_a_one_row_cache_over_the_first_slots_of_its_row(name):
+    cfg = LAYOUTS[name]()
+    big = kv_cache.init(cfg, 3, 16)
+    small = jax.tree.map(lambda leaf: jnp.ones_like(leaf), kv_cache.init(cfg, 1, 4))
+    out = jax.jit(kv_cache.splice_row)(big, small, jnp.int32(1))
+    for leaf in jax.tree.leaves(out):
+        a = np.asarray(leaf, np.float32)
+        assert (a[:, 1, :4] == 1).all() and a.sum() == a[:, 1, :4].size
+
+
+# -- window and write: the same cases against both orders -----------------
+
+L, B, H, T, X = 3, 4, 2, 16, 8
+
+
+def pool_of(heads_first, seed=0):
+    spec = kv_cache.PoolSpec("p", L, H, X, X, None, heads_first, False)
+    values = np.random.RandomState(seed).normal(size=spec.shape(B, T, X)).astype(np.float32)
+    return jnp.asarray(values), spec
+
+
+def by_time(pool, spec):
+    """A pool, or a window of it, as numpy (..., T, H, x)."""
+    a = np.asarray(pool)
+    return np.swapaxes(a, -3, -2) if spec.heads_first else a
+
+
+def rows_tokens(new, cols, heads_first):
+    """(B, H, x) tokens at (B,) columns, as that order's body hands them over."""
+    return (new, cols) if heads_first else (new[:, None], cols[:, None])
+
+
+def chunk_tokens(new, cols, heads_first):
+    """(W, H, x) tokens at (W,) columns of one row."""
+    return (new, cols) if heads_first else (new[None], cols[None])
+
+
+@pytest.mark.parametrize("heads_first", [False, True], ids=["time_first", "heads_first"])
+class TestBothOrders:
+    def test_windows_are_the_first_slots_of_every_row_or_of_one(self, heads_first):
+        pool, spec = pool_of(heads_first)
+        whole = by_time(pool, spec)                                    # (L, B, T, H, x)
+        rows = kv_cache.window(pool, jnp.int32(1), 8, heads_first=heads_first)
+        np.testing.assert_array_equal(by_time(rows, spec), whole[1, :, :8])
+        row = kv_cache.window(pool, jnp.int32(2), 8, heads_first=heads_first, slot=jnp.int32(3))
+        np.testing.assert_array_equal(by_time(row, spec).reshape(8, H, X), whole[2, 3, :8])
+
+    @pytest.mark.parametrize("size", [8, T])
+    def test_rows_write_round_trips_and_drops_outside_the_window(self, heads_first, size):
+        pool, spec = pool_of(heads_first)
+        new = np.random.RandomState(1).normal(size=(B, H, X)).astype(np.float32)
+        cols = np.asarray([0, 7, 8, T], np.int32)    # in, the window's last slot, past 8, parked
+        toks, at = rows_tokens(jnp.asarray(new), jnp.asarray(cols), heads_first)
+        out = jax.jit(lambda p: kv_cache.write(p, jnp.int32(1), toks, at, size,
+                                               heads_first=heads_first))(pool)
+        want = by_time(pool, spec).copy()
+        for b, c in enumerate(cols):
+            if c < size:
+                want[1, b, c] = new[b]
+        np.testing.assert_array_equal(by_time(out, spec), want)
+        back = kv_cache.window(out, jnp.int32(1), size, heads_first=heads_first)
+        np.testing.assert_array_equal(by_time(back, spec), want[1, :, :size])
+
+    def test_chunk_write_lays_w_tokens_into_one_row(self, heads_first):
+        pool, spec = pool_of(heads_first)
+        W = 6
+        new = np.random.RandomState(2).normal(size=(W, H, X)).astype(np.float32)
+        cols = np.asarray([2, 3, 4, 5, 8, 9], np.int32)     # the last two fall outside 8 slots
+        toks, at = chunk_tokens(jnp.asarray(new), jnp.asarray(cols), heads_first)
+        out = jax.jit(lambda p: kv_cache.write(p, jnp.int32(2), toks, at, 8,
+                                               heads_first=heads_first, slot=jnp.int32(1)))(pool)
+        want = by_time(pool, spec).copy()
+        want[2, 1, 2:6] = new[:4]
+        np.testing.assert_allclose(by_time(out, spec), want, rtol=0, atol=0)
+        row = kv_cache.window(out, jnp.int32(2), 8, heads_first=heads_first, slot=jnp.int32(1))
+        np.testing.assert_array_equal(by_time(row, spec).reshape(8, H, X), want[2, 1, :8])
+
+    def test_a_write_touches_no_other_layer_and_no_other_row(self, heads_first):
+        pool, spec = pool_of(heads_first)
+        toks, at = chunk_tokens(jnp.ones((2, H, X)), jnp.asarray([0, 1], jnp.int32), heads_first)
+        out = kv_cache.write(pool, jnp.int32(0), toks, at, 4, heads_first=heads_first,
+                             slot=jnp.int32(2))
+        changed = np.argwhere((by_time(out, spec) != by_time(pool, spec)).any(axis=(-1, -2)))
+        assert sorted(map(tuple, changed)) == [(0, 2, 0), (0, 2, 1)]
+
+
+def test_heads_first_row_window_starts_where_it_is_told():
+    pool, spec = pool_of(True)   # time before heads reads a row from slot 0
+    row = kv_cache.window(pool, jnp.int32(0), 4, heads_first=True, slot=jnp.int32(1),
+                          start=jnp.int32(6))
+    np.testing.assert_array_equal(by_time(row, spec), by_time(pool, spec)[0, 1, 6:10])
+
+
+def test_heads_first_chunk_write_through_a_window_that_starts_mid_row():
+    pool, spec = pool_of(True)
+    new = np.random.RandomState(3).normal(size=(3, H, X)).astype(np.float32)
+    out = kv_cache.write(pool, jnp.int32(1), jnp.asarray(new), jnp.asarray([0, 1, 4], jnp.int32), 4,
+                         heads_first=True, slot=jnp.int32(0), start=jnp.int32(5))
+    want = by_time(pool, spec).copy()
+    want[1, 0, 5:7] = new[:2]                       # column 4 is outside the 4-slot window
+    np.testing.assert_array_equal(by_time(out, spec), want)
+
+
+def test_int8_write_quantises_and_the_read_dequantises():
+    cfg = one_kind(kv_cache_dtype="int8", dtype="float32")
+    cache = kv_cache.init(cfg, 2, 8)
+    new = jax.random.normal(jax.random.PRNGKey(0), (2, 1, 4, 16), jnp.float32)
+    pos = jnp.asarray([3, 8], jnp.int32)            # the second row is parked: nothing lands
+    k, v = kv_cache.update_kv_cache(cache["k"], cache["v"], new, new * 2, pos, pos[:, None],
+                                    layer=jnp.int32(1), write_len=8)
+    back = np.array(kv_cache.dequantize_kv(kv_cache.kv_window(k, 8, jnp.int32(1)), jnp.float32))
+    scales = np.asarray(k["s"])[1, 0, 3]
+    assert np.all(np.abs(back[0, 3] - np.asarray(new)[0, 0]) <= scales / 2 + 1e-6)
+    back[0, 3] = 0
+    assert not back.any() and not np.asarray(k["q8"])[0].any() and np.asarray(v["q8"])[1, 0, 3].any()

@@ -115,7 +115,7 @@ class TestInferenceOps:
         np.testing.assert_allclose(out_half, want_half, rtol=1e-5)
 
     def test_kv_cache_update(self):
-        from deepspeed_tpu.ops.transformer.inference_ops import update_kv_cache
+        from deepspeed_tpu.ops.transformer.kv_cache import update_kv_cache
 
         kc = jnp.zeros((1, 8, 2, 4))
         vc = jnp.zeros((1, 8, 2, 4))
@@ -266,7 +266,7 @@ class TestInt8KVCache:
     bytes and doubles servable context. Beyond the v0.9.1 reference."""
 
     def test_quantized_write_roundtrip_bound(self):
-        from deepspeed_tpu.ops.transformer.inference_ops import (
+        from deepspeed_tpu.ops.transformer.kv_cache import (
             dequantize_kv,
             update_kv_cache,
         )
@@ -287,10 +287,8 @@ class TestInt8KVCache:
         assert np.all(np.asarray(k8["q8"])[:, :3] == 0)
 
     def test_softmax_context_close_to_fp_cache(self):
-        from deepspeed_tpu.ops.transformer.inference_ops import (
-            quantize_kv,
-            softmax_context,
-        )
+        from deepspeed_tpu.ops.transformer.inference_ops import softmax_context
+        from deepspeed_tpu.ops.transformer.kv_cache import quantize_kv
 
         B, T, H, hd = 2, 12, 4, 8
         rng = jax.random.PRNGKey(1)
