@@ -479,7 +479,11 @@ class ContinuousBatchingEngine:
                             # tokens, and the pads that filled the
                             # program's width (pad share = pad / (real + pad))
                             "prefill_chunk_tokens": 0,
-                            "prefill_pad_tokens": 0}
+                            "prefill_pad_tokens": 0,
+                            # ticks whose rows' one-token KV write took
+                            # the block path (kv_cache.takes_block_write,
+                            # told here from the tick's read bucket)
+                            "block_write_ticks": 0}
         if self.cfg.layer_kinds is not None:
             # what those chunks' attention had to do: (query, key) pairs
             # attended in a full and in a window layer, keys a full layer read
@@ -943,7 +947,9 @@ class ContinuousBatchingEngine:
         tick's blocked time charged to its own kind);
         ``prefill_q_depth_sum`` (÷ ``steps`` = mean) is the
         admitted-but-not-yet-prefilled requests over all pools, read once
-        per ``step()``."""
+        per ``step()``. ``block_write_ticks``: ticks dispatched on a program
+        whose rows' one-token KV write took the block path (the host tells
+        it from the tick's read bucket by ``kv_cache``'s own rule)."""
         s = dict(self._tick_stats)
         s["pipeline_depth"] = self.pipeline_depth
         # NOT the tokens_per_tick knob (the burst width): the observed mean
@@ -1257,6 +1263,8 @@ class ContinuousBatchingEngine:
             rec = _TickRecord(packed, live, k,
                               self._row_read_bytes(pool, read_len), False)
             advance = k
+        self._tick_stats["block_write_ticks"] += kv_cache.rows_write_by_blocks(
+            self.cfg, pool.cache, read_len, self.mesh)
         # advance the dispatch mirrors for the decode rows (the admitting
         # row's were set above); quota-clamped so a burst tail never
         # over-advances a row the host can predict finishing

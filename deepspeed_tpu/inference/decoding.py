@@ -112,6 +112,16 @@ def _tick_shardings(mesh, cfg, batch_size: int):
     return row_sh, cache_sh, batch_sh
 
 
+def _jit_cached(mesh, fn, **jit_kwargs):
+    """``jax.jit`` of a program that writes into a pool placed by
+    :func:`_decode_shardings`. The cache's writer sees shapes, not
+    shardings: a program whose pools span several chips is traced knowing
+    so (``kv_cache.split_over_chips``) and keeps the window write."""
+    if kv_cache.spans_chips(mesh):
+        fn = kv_cache.split_over_chips(fn)
+    return jax.jit(fn, **jit_kwargs)
+
+
 def compile_decode_fns(mesh, cfg, param_shardings, batch_size: int, cache_len: int):
     """Build (prefill_fn, decode_fn, cache_sharding, batch_sharding) for a
     TransformerConfig ``cfg`` with params placed per ``param_shardings``."""
@@ -126,14 +136,14 @@ def compile_decode_fns(mesh, cfg, param_shardings, batch_size: int, cache_len: i
         logits, cache = tf.forward_with_cache(params, cfg, tok, cache, pos)
         return logits[:, -1], cache
 
-    prefill_fn = jax.jit(
-        prefill,
+    prefill_fn = _jit_cached(
+        mesh, prefill,
         in_shardings=(param_shardings, batch_sh, cache_sh),
         out_shardings=(batch_sh, cache_sh),
         donate_argnums=(2,),
     )
-    decode_fn = jax.jit(
-        decode,
+    decode_fn = _jit_cached(
+        mesh, decode,
         in_shardings=(param_shardings, batch_sh, cache_sh, None),
         out_shardings=(batch_sh, cache_sh),
         donate_argnums=(2,),
@@ -192,8 +202,8 @@ def compile_generate_fn(mesh, cfg, param_shardings, batch_size: int, cache_len: 
         # donated input cache aliases an output instead of warning
         return seq, cache
 
-    jitted = jax.jit(
-        run,
+    jitted = _jit_cached(
+        mesh, run,
         in_shardings=(param_shardings, batch_sh, cache_sh, None),
         out_shardings=(batch_sh, cache_sh),
         donate_argnums=(2,),
@@ -219,8 +229,8 @@ def compile_ragged_prefill_fn(mesh, cfg, param_shardings, batch_size: int, cache
         zero = jnp.zeros((tokens.shape[0],), jnp.int32)
         return tf.forward_with_cache(params, cfg, tokens, cache, zero, positions=positions)
 
-    fn = jax.jit(
-        prefill,
+    fn = _jit_cached(
+        mesh, prefill,
         in_shardings=(param_shardings, batch_sh, batch_sh, cache_sh),
         out_shardings=(batch_sh, cache_sh),
         donate_argnums=(3,),
@@ -424,8 +434,8 @@ def compile_segment_fn(mesh, cfg, param_shardings, batch_size: int, cache_len: i
         return tf.forward_with_cache(params, cfg, toks, cache, pos,
                                      read_len=read_len)
 
-    segment_fn = jax.jit(
-        segment,
+    segment_fn = _jit_cached(
+        mesh, segment,
         in_shardings=(param_shardings, batch_sh, cache_sh, batch_sh),
         out_shardings=(batch_sh, cache_sh),
         donate_argnums=(2,),
@@ -611,8 +621,8 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
                  done[:, None]], axis=1)
             return with_stats(packed, stats), cache, last_tok, done
 
-        fn = jax.jit(
-            run,
+        fn = _jit_cached(
+            mesh, run,
             in_shardings=(param_shardings, cache_sh, row_sh, row_sh,
                           row_sh, row_sh, row_sh, row_sh, None),
             out_shardings=(row_sh, cache_sh, row_sh, row_sh),
@@ -647,8 +657,8 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
             [tok[:, None], emitted[:, None], done2[:, None]], axis=1)
         return with_stats(packed, stats), cache, last2, done2
 
-    fn = jax.jit(
-        run,
+    fn = _jit_cached(
+        mesh, run,
         in_shardings=(param_shardings, cache_sh, row_sh, row_sh,
                       row_sh, row_sh, row_sh, row_sh, None,
                       None, None, None, row_sh, row_sh),
@@ -862,8 +872,8 @@ def compile_spec_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
                 last_tok, done, rids, base_key)
             return packed, cache, last2, done2, pos2, gen2
 
-        fn = jax.jit(
-            run,
+        fn = _jit_cached(
+            mesh, run,
             in_shardings=(param_shardings, cache_sh, row_sh, row_sh, row_sh,
                           row_sh, row_sh, row_sh, row_sh, row_sh, None),
             out_shardings=(row_sh, cache_sh, row_sh, row_sh, row_sh, row_sh),
@@ -912,8 +922,8 @@ def compile_spec_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
             last_tok, done, rids, base_key)
         return packed, cache, draft_cache, last2, done2, pos2, gen2
 
-    fn = jax.jit(
-        run,
+    fn = _jit_cached(
+        mesh, run,
         in_shardings=(param_shardings, draft_param_shardings, cache_sh,
                       draft_cache_sh, row_sh, row_sh, row_sh, row_sh,
                       row_sh, row_sh, row_sh, None),

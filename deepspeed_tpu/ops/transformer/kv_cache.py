@@ -15,11 +15,16 @@ window and write. Attention is not format: ``softmax_context`` and
 its pool's order. The configuration is duck-typed (``ops`` is below ``models``).
 """
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
+
+from deepspeed_tpu.ops.pallas.interpret import resolve_interpret
 
 
 class PoolSpec(NamedTuple):
@@ -60,7 +65,7 @@ def specs(cfg) -> Tuple[PoolSpec, ...]:
     the contractions and the chunk kernel want, and time first the compiler
     copied the whole value pool into and out of every tick (PR 27). A choice
     from the shapes alone, one order for both bodies, is ROADMAP Queue 1
-    item 1's to make, here. A rolling one-kind cache is a ring as long as
+    item 3's to make, here. A rolling one-kind cache is a ring as long as
     its allocation: ``ring`` stays None, the write takes ``ring=True``."""
     if not _is_plan(cfg):
         return (PoolSpec("kv", cfg.num_layers, cfg.kv_heads, cfg.head_dim, cfg.head_dim, None,
@@ -179,6 +184,25 @@ def partition_spec(cfg, mesh, batch_axes):
     return _build(cfg, leaf)
 
 
+def spans_chips(mesh) -> bool:
+    """Whether a program built on ``mesh`` has its pools on more than one
+    chip (split by rows, by heads, or held whole by each)."""
+    return mesh is not None and mesh.size > 1
+
+
+def rows_write_by_blocks(cfg, cache, read_len: Optional[int], mesh=None) -> bool:
+    """The host's side of :func:`takes_block_write`: whether a program built
+    on ``mesh`` that writes one token a row into ``cache`` at read bucket
+    ``read_len`` (None: the allocation) takes the block path in any leaf
+    (``tick_stats()``'s ``block_write_ticks``)."""
+    def one(spec, leaf):
+        size = spec.ring or read_len or leaf.shape[spec.time_axis]
+        return takes_block_write(size, _row_bytes(leaf, size, spec.heads_first))
+
+    return not spans_chips(mesh) and any(one(spec, leaf) for spec, sub in _pools(cfg, cache)
+                                         for leaf in jax.tree.leaves(sub))
+
+
 # -- on the device: window and write of one stacked array, both orders -------
 #                   time before heads                 heads before time
 #   rows' window    (B, size, H, x)                   (B, H, size, x)
@@ -207,12 +231,19 @@ def window(pool, layer, size, *, heads_first: bool, slot=None, start=0):
 
 
 def write(pool, layer, new, cols, size, *, heads_first: bool, slot=None, start=0):
-    """``new`` into slots ``cols`` of ``[layer]``, in place, through the
-    window :func:`window` reads: one token a row, or with ``slot`` W tokens
-    into that ONE row at ``start + cols``. Slice, select and update fuse
-    into one pass over the window; several tokens a row are laid out along
-    it by a one-hot contraction (exact: one term a slot), never scattered
-    token by token, so no index is dynamic along the time axis."""
+    """``new`` into slots ``cols`` of ``[layer]``, in place: one token a row,
+    or with ``slot`` W tokens into that ONE row at ``start + cols``. One
+    token a row of a long window goes into the row's own time block
+    (:func:`_write_blocks`, by :func:`takes_block_write`). Every other
+    write goes through the window :func:`window` reads: slice, select and
+    update fuse into one pass over it; several tokens a row are laid out
+    along it by a one-hot contraction (exact: one term a slot), never
+    scattered token by token, so no index is dynamic along the time axis."""
+    size = size or pool.shape[3 if heads_first else 2]
+    one_token = slot is None and (heads_first or new.shape[1] == 1) and not _split_over_chips
+    if one_token and takes_block_write(size, _row_bytes(pool, size, heads_first)):
+        return _write_blocks(pool, layer, new[:, :, None] if heads_first else new,
+                             cols.reshape(-1), size, heads_first)
     if not heads_first:
         merged = _place(window(pool, layer, size, heads_first=False, slot=slot), new, cols)
         return jax.lax.dynamic_update_slice(
@@ -228,6 +259,118 @@ def write(pool, layer, new, cols, size, *, heads_first: bool, slot=None, start=0
                         preferred_element_type=jnp.float32).astype(pool.dtype)
     merged = jnp.where(hit.any(axis=1)[None, :, None], placed, merged)
     return jax.lax.dynamic_update_slice(pool, merged[None, None], (layer, slot, 0, start, 0))
+
+
+# The rows' one-token write, by blocks. BLOCK slots are one whole tile of the
+# time-minor layout the chip keeps a time-before-heads pool in: the smallest
+# extent along time that can be updated in place (PR 25).
+BLOCK = 128
+# The ONE rule of which path a rows' write takes, over static shapes: by
+# blocks iff one row's window of one leaf holds this many bytes. Measured on
+# a v5e (plain ticks, PERF.md section 6, PR 32): the window path moves a
+# row's window twice at the chip's bandwidth (GPT-2 XL, 25 x 64 bf16: 1.25 us
+# a row, layer and leaf for every 128 slots of the window; gpt2-medium 0.8);
+# the block path is one kernel call a layer and leaf that costs 1.33 us a row
+# at XL's 410 KB block (0.92 at gpt2-medium's 262 KB) plus the tokens'
+# broadcast, whatever the window's length: 2.66 ms a tick at XL against
+# 3.95 / 7.81 / 15.36 ms for windows of 256 / 512 / 1,024 slots, 2.25 ms at
+# gpt2-medium's 40 rows against 3.05 / 6.21 / 12.23. The smallest row
+# measured, gpt2-medium at 256 slots (512 KiB), still gains 0.8 ms a tick;
+# below it nothing was measured and the window path stays.
+BLOCK_WRITE_MIN_ROW_BYTES = 1 << 19
+
+
+_split_over_chips = False   # True while a program whose pools span several chips is traced
+
+
+def split_over_chips(fn):
+    """``fn``, traced knowing that its pools are split over a mesh of more
+    than one chip. The block write is a Mosaic kernel, which the partitioner
+    cannot split (and a row's block is another chip's as often as not when
+    the BATCH axis is split): such a program keeps the window path, which
+    splits by rows and by heads. ``write`` sees shapes, not shardings: the
+    programs' builder (``decoding.py``) says so here."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        global _split_over_chips
+        before, _split_over_chips = _split_over_chips, True
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _split_over_chips = before
+    return traced
+
+
+def takes_block_write(size: int, row_bytes: int) -> bool:
+    """Whether one token a row goes into its row's block (True) or through
+    the whole window (False), for a window of ``size`` slots that holds
+    ``row_bytes`` a row of one leaf. A window no longer than a block IS its
+    block; one that is not whole blocks has no aligned last block."""
+    return size > BLOCK and size % BLOCK == 0 and row_bytes >= BLOCK_WRITE_MIN_ROW_BYTES
+
+
+def _row_bytes(pool, size: int, heads_first: bool) -> int:
+    """Bytes of ONE row's ``size`` slots of one layer of a leaf."""
+    heads, width = pool.shape[2 if heads_first else 3], pool.shape[4]
+    return size * heads * width * pool.dtype.itemsize
+
+
+def _write_blocks(pool, layer, token, cols, size, heads_first):
+    """Each row's one token (``token`` holds one slot along time, ``cols``
+    (B,)) into the BLOCK slots that hold its column, in place: ONE kernel
+    call, a grid step a row, that fetches the row's block, selects the token
+    in where the slot is the column's, and stores the block back
+    (``kv_block_write``; the pool is aliased to the result and nothing else
+    of it is touched). A column outside ``[0, size)`` clips to the first or
+    last block and hits no slot of it: stored back unchanged.
+
+    The kernel has to see the pool in the order the chip keeps it, or the
+    compiler copies the pool in and out. A leaf whose width is whole 128-lane
+    tiles is kept as it is written (MiMo's values, ``(L, B, H, T, 128)``).
+    Any other is kept TIME-minor (GPT-2's ``(L, B, T, H, 64)``, MiMo's keys
+    ``(L, B, H, T, 192)``): it goes in as its ``(L, B, H, x, T)`` transpose,
+    which IS that memory order and costs nothing (no copy in the compiled
+    ticks: ``tests/unit/ops/test_tpu_compile.py``, ``test_tpu_compile_plan.py``).
+    Unrolled XLA ops (a ``dynamic_slice``, select and ``dynamic_update_slice``
+    a row) compile in place too, but the chip runs each update as a copy of
+    the block's separate 2 KB tiles, 7.7 us a row and leaf at XL: no faster
+    than the window (PERF.md section 6, PR 32)."""
+    order = list(range(5))
+    if pool.shape[4] % 128:                              # time-minor on the chip: time goes last
+        order.append(order.pop(3 if heads_first else 2))   # (L, B, H, x, T)
+    lead = pool.transpose(order)
+    last = order.index(3 if heads_first else 2)          # where time is in the kernel's array
+    rows = pool.shape[1]
+    block = (None, None) + tuple(BLOCK if a == last else n for a, n in enumerate(lead.shape) if a > 1)
+    first = jnp.clip(cols // BLOCK, 0, size // BLOCK - 1).astype(jnp.int32)
+    offset = (cols - first * BLOCK).astype(jnp.int32)
+    token = token.astype(pool.dtype)[None].transpose(order)[0]      # the pool's axes, one slot of time
+    token = jnp.broadcast_to(token, (rows,) + block[2:])
+
+    def index(row, layer_ref, first_ref, offset_ref):
+        at = [layer_ref[0], row, 0, 0, 0]
+        at[last] = first_ref[row]
+        return tuple(at)
+
+    def kernel(layer_ref, first_ref, offset_ref, pool_ref, token_ref, out_ref):
+        slot = jax.lax.broadcasted_iota(jnp.int32, pool_ref.shape, last - 2)
+        hit = slot == offset_ref[pl.program_id(0)]
+        out_ref[...] = jnp.where(hit, token_ref[...], pool_ref[...])
+
+    out = pl.pallas_call(
+        kernel, name="kv_block_write",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(rows,),
+            in_specs=[pl.BlockSpec(block, index),
+                      pl.BlockSpec((None,) + block[2:], lambda row, *_: (row, 0, 0, 0))],
+            out_specs=pl.BlockSpec(block, index)),
+        out_shape=jax.ShapeDtypeStruct(lead.shape, lead.dtype),
+        input_output_aliases={3: 0},
+        interpret=resolve_interpret(),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), first, offset, lead, token)
+    # one value for every later reader: a chunk's write that read the kernel's result and updated
+    # its transpose was given a copy of the pool (the fused tick, compiled for a described v5e)
+    return jax.lax.optimization_barrier(out.transpose([order.index(a) for a in range(5)]))
 
 
 def _place(win, new, cols):

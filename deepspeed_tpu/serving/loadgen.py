@@ -356,6 +356,10 @@ def host_overhead(tick_stats: dict) -> dict:
     real, pad = tick_stats.get("prefill_chunk_tokens"), tick_stats.get("prefill_pad_tokens")
     if real is not None and pad is not None:
         out["prefill_pad_share"] = round(pad / (real + pad), 4) if real + pad else None
+    # ticks whose rows' KV write went into each row's block, not the window
+    ticks, blocks = tick_stats.get("ticks"), tick_stats.get("block_write_ticks")
+    if blocks is not None:
+        out["block_write_share"] = round(blocks / ticks, 4) if ticks else None
     if "utilization" in tick_stats:
         out["tick_utilization"] = tick_stats["utilization"]
     if tick_stats.get("spec_gamma"):
@@ -615,7 +619,9 @@ def format_summary(summary: dict) -> str:
                 f"{_ms(host['tick_block_ms_plain'])} plain   "
                 f"prefill queue {host['prefill_q_depth_mean']} deep"
                 + (f"   chunk pads {host['prefill_pad_share']:.1%}"
-                   if host.get("prefill_pad_share") is not None else ""))
+                   if host.get("prefill_pad_share") is not None else "")
+                + (f"   block writes {host['block_write_share']:.1%}"
+                   if host.get("block_write_share") is not None else ""))
         if host.get("spec_gamma"):
             acc = host.get("spec_acceptance")
             per = (f"{acc * host['spec_gamma']:.2f}/{host['spec_gamma']}"

@@ -17,6 +17,7 @@ from deepspeed_tpu import comm
 from deepspeed_tpu.inference.continuous import ContinuousBatchingEngine
 from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
 from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+from deepspeed_tpu.ops.transformer import kv_cache
 
 FLOOR = 16   # small tight-read floor so toy pools cross read buckets
 LENGTH = 384
@@ -30,6 +31,10 @@ VARIANTS = {
     "layer_windows": {"cfg": {"local_attn_windows": (24, 0)}},
     "gqa_rope": {"cfg": {"pos_embedding": "rope", "num_kv_heads": 2}},
     "tensor2": {"tensor": 2},
+    # a mesh of ONE device, as a one-chip deployment has it (the default mesh here spans the
+    # eight virtual devices, and a pool on several chips keeps the window write)
+    "one_chip": {"tensor": 1},
+    "int8_one_chip": {"tensor": 1, "config": {"kv_cache_dtype": "int8"}},
 }
 
 
@@ -186,6 +191,31 @@ def test_eos_on_the_first_token(models):
     assert all(o.size for o in others)
 
 
+@pytest.mark.parametrize("variant", ["one_chip", "int8_one_chip", "tensor2"])
+def test_streams_through_the_block_write_above_one_block(models, monkeypatch, variant):
+    """Read buckets 256 and the whole 384-slot pool with the rule's constant
+    at zero: the rows' tokens go into each row's 128-slot block (plain and
+    fused ticks), the streams are ``generate``'s, and ``tick_stats()`` counts
+    the ticks dispatched on such programs and no other. A pool split over
+    two chips keeps the window write (the kernel cannot be partitioned)."""
+    prompt = _prompt(300, seed=300)
+    want = _generate(models, variant, prompt, 10)        # window path: before the constant moves
+    before = _engine(models, variant)
+    _serve_one(before, _prompt(20, seed=2), 4, 1)
+    assert before.tick_stats()["block_write_ticks"] == 0   # a toy row is far under the constant
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 0)
+    cb = _engine(models, variant)
+    got, others = _serve_one(cb, prompt, 10, 2)
+    np.testing.assert_array_equal(got, want)
+    assert all(o.size for o in others)
+    st = cb.tick_stats()
+    by_blocks = sum(1 for (_, read_len) in cb._pools[0].tick_fns if read_len in (256, None))
+    assert by_blocks >= 2 and st["block_write_ticks"] < st["ticks"]
+    assert (st["block_write_ticks"] > 0) == (variant != "tensor2")
+    # the ticks below 256 slots (the other requests' first steps) went through the window
+    assert any(read_len is not None and read_len <= 128 for (_, read_len) in cb._pools[0].tick_fns)
+
+
 # -- the program -----------------------------------------------------------
 
 def _lowered_tick(slots, width, read_len=None):
@@ -250,3 +280,27 @@ def test_tick_stats_count_real_and_pad_tokens(models):
     assert st["prefill_chunk_tokens"] == 64 + 100 + 7
     assert st["fused_prefill_ticks"] == 1 + 2 + 1
     assert st["prefill_pad_tokens"] == 4 * 64 - (64 + 100 + 7)
+
+
+def test_tick_stats_count_the_ticks_whose_rows_wrote_by_blocks(models, monkeypatch):
+    """``block_write_ticks`` is the host's reading of ``kv_cache``'s own rule
+    at each tick's read bucket: with the constant at the bytes of a 256-slot
+    row of this pool, the ticks that read 256 slots or the whole pool count
+    and the shorter buckets do not; ``ds_loadgen`` prints the share."""
+    from deepspeed_tpu.serving import loadgen
+
+    row_256 = 256 * BASE.num_heads * (BASE.hidden_size // BASE.num_heads) * 4
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", row_256)
+    cb = _engine(models, "one_chip")
+    seen = []
+    dispatch = cb._tick_fn
+    monkeypatch.setattr(cb, "_tick_fn", lambda pool, read_len, chunk=None: (
+        seen.append(read_len), dispatch(pool, read_len, chunk=chunk))[1])
+    rids = [cb.submit(_prompt(n, seed=n), max_new_tokens=6) for n in (20, 140, 300)]
+    _drain(cb, rids)
+    st = cb.tick_stats()
+    assert st["ticks"] == len(seen)
+    assert st["block_write_ticks"] == sum(1 for r in seen if r is None or r >= 256) > 0
+    assert st["block_write_ticks"] < st["ticks"]
+    host = loadgen.host_overhead(st)
+    assert host["block_write_share"] == pytest.approx(st["block_write_ticks"] / st["ticks"], abs=1e-4)

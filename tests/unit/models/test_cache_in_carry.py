@@ -74,7 +74,23 @@ CASES = {
     "tight_read": ({}, T, 1, jnp.asarray([3, 9, PARKED, 0], jnp.int32), None, 16, (2,)),
     "tight_read_fused": ({}, T, 8, jnp.asarray([3, 9, PARKED, 4], jnp.int32), _fused_positions(),
                          16, (2,)),
+    # read buckets above one 128-slot block, with the rule's constant at zero (``block_path``):
+    # the rows' write goes into each row's own block, rows in different blocks in one call
+    "tick_blocks": (dict(max_seq_len=512), 512, 1, jnp.asarray([3, 130, 512, 255], jnp.int32), None,
+                    256, (2,)),
+    "int8_blocks": (dict(max_seq_len=512, kv_cache_dtype="int8"), 512, 1,
+                    jnp.asarray([127, 128, 512, 511], jnp.int32), None, None, (2,)),
+    "scalar_decode_blocks": (dict(max_seq_len=256), 256, 1, jnp.int32(200), None, None, ()),
 }
+
+
+@pytest.fixture(autouse=True)
+def block_path(request, monkeypatch):
+    """The ``*_blocks`` cases run the rows' write by blocks (a toy row holds
+    far fewer bytes than the rule asks for)."""
+    params = getattr(getattr(request.node, "callspec", None), "params", {})
+    if str(params.get("name", "")).endswith("_blocks"):
+        monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 0)
 
 
 def _case(name):
@@ -259,6 +275,17 @@ def test_matches_the_per_layer_reference(name, reference):
         got, before = np.asarray(new_cache["k"]), np.asarray(cache["k"])
         np.testing.assert_array_equal(got[:, ~written], before[:, ~written])
         assert not np.array_equal(got[:, written], before[:, written])
+
+
+@pytest.mark.parametrize("name", ["tick_blocks", "tick"])
+def test_the_blocks_cases_write_one_block_a_row(name):
+    """What ``*_blocks`` puts under test: the block-write kernel, once for K
+    and once for V in the layer scan's body, and no window-sized select."""
+    cfg, params, tokens, cache, pos, positions, read_len, _ = _case(name)
+    jaxpr = str(jax.make_jaxpr(
+        lambda p, t, c, at: tf.forward_with_cache(p, cfg, t, c, at, positions, read_len)
+    )(params, tokens, cache, pos))
+    assert jaxpr.count("name=kv_block_write") == (2 if name == "tick_blocks" else 0)
 
 
 def test_write_past_the_read_window_drops():

@@ -254,6 +254,140 @@ class TestBothOrders:
         assert sorted(map(tuple, changed)) == [(0, 2, 0), (0, 2, 1)]
 
 
+# -- the rows' one-token write by blocks: the window path's bits, every case --
+
+BIG_T = 512
+
+
+def big_pool(heads_first, dtype, batch, seed=0):
+    spec = kv_cache.PoolSpec("p", L, 5, X, X, None, heads_first, False)
+    values = np.random.RandomState(seed).normal(size=spec.shape(batch, BIG_T, X)) * 20
+    return jnp.asarray(values, dtype), spec
+
+
+def both_paths(monkeypatch, build):
+    """``build()`` -> (jitted fn, its args); run and lowered on the window
+    path (the constant out of reach) and on the block path (at zero)."""
+    out = {}
+    for path, least in (("window", 1 << 60), ("block", 0)):
+        monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", least)
+        fn, args = build()
+        out[path] = (jax.tree.map(np.asarray, fn(*args)), fn.lower(*args).as_text())
+    return out["window"], out["block"]
+
+
+@pytest.mark.parametrize("heads_first", [False, True], ids=["time_first", "heads_first"])
+@pytest.mark.parametrize("size", [256, 512, None], ids=["size256", "size512", "full"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8], ids=str)
+def test_block_write_leaves_the_window_writes_bits(monkeypatch, heads_first, size, dtype):
+    """Columns 0, 127, 128, size - 1, size (dropped), the pool's length (a
+    parked row), a negative one, and rows in different blocks in one call."""
+    reach = size or BIG_T
+    cols = np.asarray([0, 127, 128, reach - 1, reach, BIG_T, -1, 300 % reach], np.int32)
+    pool, spec = big_pool(heads_first, dtype, len(cols))
+    new = jnp.asarray(np.random.RandomState(1).normal(size=(len(cols), 5, X)) * 20, dtype)
+    toks, at = rows_tokens(new, jnp.asarray(cols), heads_first)
+
+    def build():
+        return jax.jit(lambda p: kv_cache.write(p, jnp.int32(1), toks, at, size,
+                                                heads_first=heads_first)), (pool,)
+
+    (window, window_text), (block, block_text) = both_paths(monkeypatch, build)
+    assert window_text != block_text                    # two programs ...
+    np.testing.assert_array_equal(block.view(np.uint8), window.view(np.uint8))   # ... one pool
+    want = by_time(pool, spec).copy()
+    for b, c in enumerate(cols):
+        if 0 <= c < reach:
+            want[1, b, c] = np.asarray(new)[b]
+    np.testing.assert_array_equal(by_time(block, spec), want)
+
+
+@pytest.mark.parametrize("name", ["dense", "int8", "grouped"])
+@pytest.mark.parametrize("ring", [False, True], ids=["rows", "ring"])
+def test_block_write_through_update_kv_cache_dense_int8_and_a_ring(monkeypatch, name, ring):
+    """The one-kind body's entry: rows at their own depths (the tick), and a
+    rolling cache's scalar-depth step whose columns wrap; an int8 pool is
+    written component by component (``q8`` and ``s``)."""
+    cfg = LAYOUTS[name]()
+    cache = jax.tree.map(
+        lambda a: jnp.asarray(np.random.RandomState(2).randint(-90, 90, a.shape), a.dtype),
+        kv_cache.init(cfg, 4, 256))
+    new = jax.random.normal(jax.random.PRNGKey(0), (4, 1, cfg.kv_heads, cfg.head_dim), jnp.float32)
+    pos = jnp.int32(300) if ring else jnp.asarray([0, 127, 128, 256], jnp.int32)
+
+    def build():
+        def step(k, v):
+            return kv_cache.update_kv_cache(k, v, new, new * 2, pos,
+                                            None if ring else pos[:, None], ring=ring,
+                                            layer=jnp.int32(1), write_len=None)
+        return jax.jit(step), (cache["k"], cache["v"])
+
+    (window, window_text), (block, block_text) = both_paths(monkeypatch, build)
+    assert window_text != block_text
+    for got, want in zip(jax.tree.leaves(block), jax.tree.leaves(window)):
+        np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+    assert any((a != np.asarray(b)).any() for a, b in zip(jax.tree.leaves(block),
+                                                         jax.tree.leaves(cache)))
+
+
+@pytest.mark.parametrize("case", ["size128", "size64", "not_whole_blocks", "three_tokens_a_row",
+                                  "one_rows_chunk", "pools_span_chips"])
+def test_writes_the_rule_leaves_alone_lower_to_the_window_paths_text(monkeypatch, case):
+    """A window no longer than a block, one that is not whole blocks,
+    several tokens a row, the chunk's one-row write, and a program whose
+    pools are split over several chips (the partitioner cannot split the
+    kernel): the text the parent's ``write`` lowers to, with the constant at
+    zero."""
+    pool, _ = big_pool(False, jnp.float32, 4)
+    size = {"size128": 128, "size64": 64, "not_whole_blocks": 200}.get(case, 256)
+    S = 3 if case in ("three_tokens_a_row", "one_rows_chunk") else 1
+    rows = 1 if case == "one_rows_chunk" else 4
+    new = jnp.ones((rows, S, 5, X))
+    cols = jnp.arange(rows * S, dtype=jnp.int32).reshape(rows, S)
+    slot = jnp.int32(2) if case == "one_rows_chunk" else None
+
+    def build():
+        fn = lambda p: kv_cache.write(p, jnp.int32(1), new, cols, size, heads_first=False, slot=slot)
+        return jax.jit(kv_cache.split_over_chips(fn) if case == "pools_span_chips" else fn), (pool,)
+
+    (window, window_text), (block, block_text) = both_paths(monkeypatch, build)
+    assert window_text == block_text
+    np.testing.assert_array_equal(block, window)
+
+
+def test_the_rule_reads_static_shapes_and_the_host_reads_the_same_rule(monkeypatch):
+    """One rule for every pool: a window longer than a block, of whole
+    blocks, whose row holds the constant's bytes or more. The benchmark's
+    cells: every read bucket above 128 slots of GPT-2 XL's 16 rows (0.82 MB a
+    row at 256 slots) and of gpt2-medium's 40 (0.52 MB) goes by blocks, and
+    MiMo's full pool from a 512-slot read on; a toy model's rows never do."""
+    least = kv_cache.BLOCK_WRITE_MIN_ROW_BYTES
+    xl, medium, mimo_k = 25 * 64 * 2, 16 * 64 * 2, 4 * 192 * 2
+    assert kv_cache.takes_block_write(1024, 1024 * xl) and kv_cache.takes_block_write(256, 256 * xl)
+    assert kv_cache.takes_block_write(256, 256 * medium)
+    assert kv_cache.takes_block_write(512, 512 * mimo_k) and not kv_cache.takes_block_write(256, 256 * mimo_k)
+    assert not kv_cache.takes_block_write(128, 1 << 40) and not kv_cache.takes_block_write(1000, 1 << 40)
+    assert kv_cache.takes_block_write(256, least) and not kv_cache.takes_block_write(256, least - 1)
+    assert not kv_cache.takes_block_write(512, 512 * 4 * 16 * 4)     # this file's toy pool
+    # the host's side, from a cache's own leaves: any leaf by blocks; a ring never; several chips never
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 256 * 4 * 16 * 2)
+    cfg = one_kind(max_seq_len=512)
+    cache = kv_cache.init(cfg, 2, 512)
+    assert kv_cache.rows_write_by_blocks(cfg, cache, None)
+    assert kv_cache.rows_write_by_blocks(cfg, cache, 256)
+    assert not kv_cache.rows_write_by_blocks(cfg, cache, 128)
+    plan = plan_config(max_seq_len=512)
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 256 * 1 * 16 * 4)
+    assert kv_cache.rows_write_by_blocks(plan, kv_cache.init(plan, 2, 512), 256)   # the full pool
+    assert not kv_cache.rows_write_by_blocks(plan, kv_cache.init(plan, 2, 512), 128)
+    two = comm.build_mesh({"data": 1, "tensor": 2}, devices=jax.devices()[:2])
+    one = comm.build_mesh({"data": 1, "tensor": 1}, devices=jax.devices()[:1])
+    assert kv_cache.spans_chips(two) and not kv_cache.spans_chips(one) and not kv_cache.spans_chips(None)
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 0)
+    assert kv_cache.rows_write_by_blocks(cfg, cache, None, one)
+    assert not kv_cache.rows_write_by_blocks(cfg, cache, None, two)
+
+
 def test_heads_first_row_window_starts_where_it_is_told():
     pool, spec = pool_of(True)   # time before heads reads a row from slot 0
     row = kv_cache.window(pool, jnp.int32(0), 4, heads_first=True, slot=jnp.int32(1),

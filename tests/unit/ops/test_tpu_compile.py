@@ -24,6 +24,7 @@ from deepspeed_tpu import comm
 from deepspeed_tpu.ops.pallas.block_sparse_attention import block_sparse_attention
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.interpret import force_interpret
+from deepspeed_tpu.telemetry.hlo_scopes import scope_table
 
 HBM_BYTES = 15.75e9  # what one v5e chip reports usable
 
@@ -147,24 +148,35 @@ def test_compiles_for_v5e(topo, case):
     assert resident < HBM_BYTES, (case, resident)
 
 
-@pytest.mark.parametrize("read_len,chunk", [(256, None), (None, None), (512, 128)],
-                         ids=["plain-read256", "plain-read1024", "fused128-read512"])
-def test_serving_tick_updates_the_kv_pool_in_place(topo, read_len, chunk):
+@pytest.mark.parametrize("preset,slots,read_len,chunk,by_blocks", [
+    ("gpt2-1.5b", 16, 256, None, True), ("gpt2-1.5b", 16, None, None, True),
+    ("gpt2-1.5b", 16, 512, 128, True), ("gpt2-1.5b", 16, None, 128, True),
+    ("gpt2-350m", 40, None, None, True), ("gpt2-1.5b", 16, 128, None, False),
+], ids=["plain-read256", "plain-read1024", "fused128-read512", "fused128-read1024",
+        "chat-plain-read1024", "plain-read128"])
+def test_serving_tick_updates_the_kv_pool_in_place(topo, preset, slots, read_len, chunk, by_blocks):
     """The gpt2-xl serving tick (16 slots x 1024, the benchmark's batch
-    cell) for the chip: the chip lays the pool bf16[48,16,1024,25,64] out
-    TIME-minor (heads-minor would pad (25, 64) 2.6x), and a token-sized
-    scatter or ``dynamic_update_slice`` on the carried pool makes the
-    compiler turn the whole pool heads-minor and back — pool-sized copies
-    and 12 GB of temporaries. The tick must compile to an in-place update:
-    no ``copy`` of the pool's or a layer's shape, the pool aliased to the
-    output, temporaries far under the pool's 5 GB."""
+    cell) and gpt2-medium's (40 x 1024, the chat cell) for the chip: the
+    chip lays the pool bf16[48,16,1024,25,64] out TIME-minor (heads-minor
+    would pad (25, 64) 2.6x), and a token-sized scatter or
+    ``dynamic_update_slice`` on the carried pool makes the compiler turn the
+    whole pool heads-minor and back — pool-sized copies and 12 GB of
+    temporaries. The tick must compile to an in-place update: no ``copy`` of
+    the pool's or a layer's shape, the pool aliased to the output,
+    temporaries far under the pool's 5 GB. ``by_blocks`` is what
+    ``kv_cache.takes_block_write`` decides at these shapes: above one
+    128-slot block the rows' tokens go in through ``kv_block_write`` (one
+    call for K, one for V, in the layer loop; the pool enters it as its
+    (L, B, H, x, T) transpose, which must be a bitcast here, not a copy) and
+    no op of ``attn.kv_write`` yields a value of the window's shape; a
+    128-slot read keeps the window's in-place rewrite."""
     from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
     from deepspeed_tpu.models import transformer as tf
 
-    slots, length = 16, 1024
+    length = 1024
     mesh = comm.build_mesh({"data": 1, "tensor": 1}, devices=topo.devices[:1])
     one = NamedSharding(mesh, PartitionSpec())
-    model = tf.TransformerModel.from_preset("gpt2-1.5b", dtype="bfloat16", max_seq_len=length,
+    model = tf.TransformerModel.from_preset(preset, dtype="bfloat16", max_seq_len=length,
                                             attn_impl="pallas")
     cfg = model.cfg
     abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -189,6 +201,66 @@ def test_serving_tick_updates_the_kv_pool_in_place(topo, read_len, chunk):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes / 8, mem.temp_size_in_bytes
+    text = compiled.as_text()
     kv = rf"{slots},{length},{cfg.kv_heads},{cfg.head_dim}\]"
-    copies = re.findall(rf"= bf16\[(?:\d+,)?{kv}\S* copy\(", compiled.as_text())
+    copies = re.findall(rf"= bf16\[(?:\d+,)?{kv}\S* copy\(", text)
     assert not copies, copies
+    # values of the window's shape among the ops of the rows' / the chunk's write
+    scopes = scope_table(text)
+    window = re.compile(rf"\s*(?:ROOT\s+)?%?([\w.\-]+) = bf16\[(?:1,)?{slots},{read_len or length},"
+                        rf"{cfg.kv_heads},{cfg.head_dim}\]")
+    rewritten = [m.group(1) for m in map(window.match, text.splitlines())
+                 if m and "attn.kv_write" in scopes.get(m.group(1), "")]
+    assert bool(rewritten) != by_blocks, rewritten
+    assert len(re.findall(r" custom-call\(.*kv_block_write", text)) == (2 if by_blocks else 0)
+
+
+def test_plan_tick_writes_its_full_pool_by_blocks_in_place(topo, monkeypatch):
+    """A toy layer plan (heads BEFORE time: the same kernel with time on the
+    second-minor axis, or on the minor one where a leaf's width is not whole
+    lanes) with the rule's constant at zero: the full pool's rows go by
+    blocks in both of its layers' scans, the 8-slot window ring keeps the
+    window path, both pools are aliased and nothing of the full window's
+    shape comes out of ``attn.kv_write``."""
+    from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
+    from deepspeed_tpu.models import transformer as tf
+    from deepspeed_tpu.ops.transformer import kv_cache
+
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 0)
+    kinds = (tf.LayerKind("dense_full", kv_heads=1, rope_theta=1e7, ffn="dense", ffn_size=256),
+             tf.LayerKind("moe_window", kv_heads=2, window=8, rope_theta=1e4, sink=True, ffn="moe",
+                          ffn_size=128),
+             tf.LayerKind("moe_full", kv_heads=1, rope_theta=1e7, ffn="moe", ffn_size=128))
+    cfg = tf.TransformerConfig(
+        vocab_size=512, hidden_size=256, num_layers=5, num_heads=4, head_size=192, v_head_size=128,
+        rope_dim=64, pos_embedding="rope", norm_type="rmsnorm", activation="silu_glu",
+        tie_embeddings=False, use_bias=False, dtype="bfloat16", attn_impl="pallas", max_seq_len=512,
+        layer_kinds=kinds, layer_plan=(0, 1, 1, 1, 2), moe_num_experts=16, moe_top_k=4,
+        moe_experts_held=(4, 8))
+    slots, length = 8, 512
+    mesh = comm.build_mesh({"data": 1, "tensor": 1}, devices=topo.devices[:1])
+    one = NamedSharding(mesh, PartitionSpec())
+    abstract = jax.eval_shape(tf.TransformerModel(cfg).init, jax.random.PRNGKey(0))
+    p_sh = jax.tree.map(lambda a: one, abstract)
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one),
+                          abstract)
+    with force_interpret(False):
+        fn, cache_sh, _ = compile_pool_tick_fn(mesh, cfg, p_sh, slots, length, 1, 0.0, 0, 1.0,
+                                               read_len=256)
+        cache = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            jax.eval_shape(lambda: tf.init_cache(cfg, slots, length)), cache_sh)
+        row = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+        compiled = fn.lower(params, cache, row, row, row, row, row, row,
+                            jax.ShapeDtypeStruct((2,), jnp.uint32)).compile()
+    comm.destroy()
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        a.size * 2 for a in jax.tree.leaves(cache))
+    text, scopes = compiled.as_text(), scope_table(compiled)
+    shaped = lambda dims: [m.group(1) for m in map(re.compile(
+        rf"\s*(?:ROOT\s+)?%?([\w.\-]+) = bf16\[(?:1,)?{dims}\]").match, text.splitlines())
+        if m and "attn.kv_write" in scopes.get(m.group(1), "")]
+    assert not shaped(f"{slots},1,256,192") and not shaped(f"{slots},1,256,128")   # the full windows
+    assert len(re.findall(r" custom-call\(.*kv_block_write", text)) == 4      # K, V x two full runs
+    assert shaped(f"{slots},2,8,192")                # the ring, through its window
+    assert not re.findall(r"= bf16\[\d+,8,1,512,\d+\]\S* copy\(", text)   # no copy of the full pool

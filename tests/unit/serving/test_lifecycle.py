@@ -243,6 +243,7 @@ def test_loadgen_reports_ttfts_parts_and_the_tick_kinds(setup):
         stats["prefill_q_depth_sum"] / stats["steps"], abs=1e-4)
     text = loadgen.format_summary(summary)
     assert "prefill wait" in text and "tick kinds     fused" in text
+    assert host["block_write_share"] == 0.0 and "block writes 0.0%" in text   # toy rows: the window
 
 
 def test_loadgen_host_columns_without_the_kind_counters():
