@@ -259,9 +259,11 @@ class _Pool:
         from deepspeed_tpu.analysis.program import capture
 
         if capture.active():
-            def row_args(n=n_slots):
+            def row_args(n=n_slots, pool=self):
                 row = jax.ShapeDtypeStruct((n,), jnp.int32)
-                return (row, row, 0, 0, 0)
+                state = pool.cache.get(kv_cache.StateSpec.name)   # a state pool rides the flip
+                return (row, row, 0, 0, 0) + (() if state is None else (jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), state),))
 
             def seg_args(n=n_slots, pool=self, eng=engine):
                 def sds(a):
@@ -347,6 +349,9 @@ class ContinuousBatchingEngine:
         # a layer plan with expert layers: its ticks return routing counters
         self._moe_stats = (self.cfg.layer_kinds is not None
                            and self.cfg.moe_num_experts > 0)
+        # ... with delta-rule layers: a state pool, reset when a row is
+        # admitted, and two counters more among the routing counters
+        self._state_pool = kv_cache.state_spec(self.cfg) is not None
         self.eos_token_id = eos_token_id
         self.temperature, self.top_k, self.top_p = temperature, top_k, top_p
         assert tokens_per_tick >= 1, tokens_per_tick
@@ -383,7 +388,8 @@ class ContinuousBatchingEngine:
             raise NotImplementedError(
                 "a layer-plan model is served by single-token ticks with "
                 "fused prefill chunks (no speculative pool ticks, bursts or "
-                "separate prefill)")
+                "separate prefill: a burst or a verify round would have to "
+                "roll a state pool's recurrent state back)")
         self.spec_gamma = 0
         self.spec_mode = None
         self._draft_eng = None
@@ -499,6 +505,10 @@ class ContinuousBatchingEngine:
                 moe_ticks=0, moe_assignments=0, moe_held_assignments=0, moe_experts_hit=0,
                 moe_expert_tokens_most_sum=0, moe_expert_tokens_mean_sum=0.0,
                 moe_imbalance_sum=0.0)
+        if self._state_pool:
+            # as the ticks report them (layer_plan.GDN_STATS): real tokens
+            # the chunks' scans took, rows whose state a tick stepped
+            self._tick_stats.update(gdn_chunk_tokens=0, gdn_step_rows=0)
         # cancelled rids, remembered so status()/result() answer precisely
         # instead of "unknown" — BOUNDED (oldest evicted past 4096): a
         # long-running server cancels routinely and must not leak an int
@@ -583,8 +593,8 @@ class ContinuousBatchingEngine:
     def kv_pool_bytes(self) -> Dict[str, int]:
         """``kv_cache_bytes()`` by kind of pool (``kv_cache.specs``): a
         layer plan keeps a full-length pool and a ring of ``window``
-        positions ({"full": ..., "window": ...}); a model of one kind has
-        {"kv"}."""
+        positions ({"full": ..., "window": ...}) and, with delta-rule
+        layers, the state pool ("state"); a model of one kind has {"kv"}."""
         out: Dict[str, int] = {}
         for p in self._pools:
             for name, nbytes in kv_cache.pool_bytes(self.cfg, p.cache).items():
@@ -776,7 +786,8 @@ class ContinuousBatchingEngine:
         if self.cfg.layer_kinds is not None:
             raise NotImplementedError(
                 "prefix registration splices one pool of one length; a "
-                "layer plan's pools have no splice yet")
+                "layer plan's pools have no splice yet, and a state pool's "
+                "recurrent state would need a snapshot at the prefix's end")
         prefix = np.asarray(prefix_ids, np.int32).reshape(-1)
         if prefix.size == 0:
             raise ValueError("empty prefix")
@@ -967,6 +978,8 @@ class ContinuousBatchingEngine:
         if self.cfg.layer_kinds is not None:
             for name, nbytes in self.kv_pool_bytes().items():
                 s["kv_pool_bytes_" + name] = nbytes
+        if self._state_pool:
+            s["state_pool_bytes"] = s["kv_pool_bytes_state"]
         return s
 
     def _place(self, req: _Request) -> Optional[tuple]:
@@ -1442,6 +1455,10 @@ class ContinuousBatchingEngine:
                 stats["moe_expert_tokens_mean_sum"] += mean
                 if held:
                     stats["moe_imbalance_sum"] += most / mean
+                if self._state_pool:
+                    at = k + 2 + TICK_STATS
+                    stats["gdn_chunk_tokens"] += int(arr[0, at])
+                    stats["gdn_step_rows"] += int(arr[0, at + 1])
             hook = self.span_hook
             if hook is not None:
                 t_ret = time.monotonic()
@@ -1583,6 +1600,13 @@ class ContinuousBatchingEngine:
         if self.fault_hook is not None:
             self.fault_hook("set_row", {"tick": self._tick_index,
                                         "slot": slot})
+        if self._state_pool:
+            # nothing masks a stale recurrent state: the row starts from zero
+            name = kv_cache.StateSpec.name
+            pool.last_tok_dev, pool.done_dev, state = pool.set_row_fn(
+                pool.last_tok_dev, pool.done_dev, slot, tok, flag, pool.cache[name])
+            pool.cache = dict(pool.cache, **{name: state})
+            return
         pool.last_tok_dev, pool.done_dev = pool.set_row_fn(
             pool.last_tok_dev, pool.done_dev, slot, tok, flag)
 

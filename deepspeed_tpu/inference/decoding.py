@@ -82,7 +82,9 @@ def decode_kv_bytes(cfg, prompt_len: int, new_tokens: int, cache_len: int,
 
 # routing counters a tick of a model with expert layers returns beside its
 # tokens: assignments made, to held experts, the most one held expert got in
-# a layer, expert layers run, held experts hit (layer_plan.forward_plan_cached)
+# a layer, expert layers run, held experts hit (layer_plan.forward_plan_cached);
+# a plan with delta-rule layers appends layer_plan.GDN_STATS more
+# (layer_plan.stats_len is the count a configuration's ticks return)
 TICK_STATS = 5
 
 
@@ -583,12 +585,14 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
     # as TICK_STATS more columns (row 0 carries them), so they come back
     # in the tick's one fetch
     plan = cfg.layer_kinds is not None
-    from deepspeed_tpu.models.layer_plan import Chunk, forward_plan_cached
+    from deepspeed_tpu.models.layer_plan import Chunk, forward_plan_cached, stats_len
+
+    n_stats = stats_len(cfg) if plan else 0
 
     def with_stats(packed, stats):
         if stats is None or not cfg.moe_num_experts:
             return packed
-        extra = jnp.zeros((batch_size, TICK_STATS), jnp.int32).at[0].set(stats)
+        extra = jnp.zeros((batch_size, n_stats), jnp.int32).at[0].set(stats)
         return jnp.concatenate([packed, extra], axis=1)
 
     if chunk is None:
@@ -613,7 +617,7 @@ def compile_pool_tick_fn(mesh, cfg, param_shardings, batch_size: int,
 
             (cache, last_tok, done, _, _, stats), (toks, emitted) = jax.lax.scan(
                 body, (cache, last_tok, done, pos, gen,
-                       jnp.zeros((TICK_STATS,), jnp.int32) if plan else None),
+                       jnp.zeros((n_stats,), jnp.int32) if plan else None),
                 None, length=k)
             packed = jnp.concatenate(
                 [jnp.moveaxis(toks, 0, 1),
@@ -677,11 +681,29 @@ def compile_row_update_fn(mesh, cfg, batch_size: int, donate: bool = True):
     engine's ``donate_cache`` knob — the CPU backend blocks donated
     dispatches, and admission must stay enqueue-only in overlap
     measurements. Returns ``set_row(last_tok, done, slot, tok, flag) ->
-    (last_tok, done)``."""
-    row_sh, _, _ = _tick_shardings(mesh, cfg, batch_size)
+    (last_tok, done)``.
+
+    A model whose cache has a state pool (``kv_cache.state_spec``: recurrent
+    state, which no position masks) takes and returns that pool as well, the
+    admitted row of it zeroed in place: ``set_row(last_tok, done, slot, tok,
+    flag, state) -> (last_tok, done, state)``."""
+    row_sh, cache_sh, _ = _tick_shardings(mesh, cfg, batch_size)
 
     def set_row(last_tok, done, slot, tok, flag):
         return last_tok.at[slot].set(tok), done.at[slot].set(flag)
+
+    if kv_cache.state_spec(cfg) is not None:
+        state_sh = cache_sh[kv_cache.StateSpec.name]
+
+        def set_row_and_reset(last_tok, done, slot, tok, flag, state):
+            return set_row(last_tok, done, slot, tok, flag) + (kv_cache.reset_row(state, slot),)
+
+        return jax.jit(
+            set_row_and_reset,
+            in_shardings=(row_sh, row_sh, None, None, None, state_sh),
+            out_shardings=(row_sh, row_sh, state_sh),
+            donate_argnums=(0, 1, 5) if donate else (),
+        )
 
     return jax.jit(
         set_row,

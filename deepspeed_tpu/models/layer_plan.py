@@ -1,10 +1,10 @@
 """Layers of several kinds in one stack: the layer plan.
 
 ``TransformerConfig.layer_kinds`` lists the kinds of layer a model has
-(:class:`~deepspeed_tpu.models.transformer.LayerKind`: the reach of its
-attention, its key-value heads, its rotary base, whether a sink joins its
-softmax, a dense or an expert FFN) and ``layer_plan`` says which kind each
-layer is. Kinds differ in parameter SHAPES, so the parameters are stacked
+(:class:`~deepspeed_tpu.models.transformer.LayerKind`: its mixer, softmax
+attention with its reach, its key-value heads, its rotary base and whether a
+sink joins its softmax, or the gated delta rule; a dense or an expert FFN)
+and ``layer_plan`` says which kind each layer is. Kinds differ in parameter SHAPES, so the parameters are stacked
 per kind (``params["layers"][kind.name]``, leading axis = that kind's
 layers in model order) and the stack is walked as **one scan per run of
 equal layers**: a plan ``D W W W W W F`` is a call, a scan of five and a
@@ -13,7 +13,7 @@ one body for the whole depth of a periodic model, but needs the plan to BE
 periodic (a leading dense layer, a cut in depth and a last period of
 another length all break it); the run walk takes any plan, and a model cut
 to one period costs the same either way. A run that is not the whole of
-its kind's stack reads a static slice of it.
+its kind's stack reads each of its layers out of that stack as it goes.
 
 The KV cache is one pool per attention reach, ``cache["full"]`` and
 ``cache["window"]``, in one tree the slot manager carries and donates
@@ -26,7 +26,10 @@ which is all a window layer can ever attend, so a window layer costs
 ``window`` positions of memory and of read however long the row is. A
 prefill chunk never reads its own keys through the ring: it attends the
 ring's tail (the ``window`` positions before the chunk, put in order) joined
-to the chunk's own keys, then writes its last ``window`` tokens.
+to the chunk's own keys, then writes its last ``window`` tokens. A
+delta-rule layer keeps no keys: its rows live in ``cache["state"]``, a
+recurrent state and the tail of its convolution a row, which the rows' one
+token steps in place and a chunk scans from (``ops/pallas/gated_delta.py``).
 
 Three entry points: :func:`forward_plan` (no cache: training, the reference
 comparison), :func:`forward_plan_cached` (the serving tick: every slot's
@@ -74,11 +77,22 @@ def check_plan(cfg):
         if len(shapes) > 1:
             raise ValueError(f"kinds of the {pool} pool differ in key-value heads or window: "
                              f"{sorted(shapes)}")
-    if not any(k.window == 0 for k in cfg.plan):
+    if not any(k.pool == "full" for k in cfg.plan):
         raise ValueError("a layer plan needs a full-attention layer: the slot manager reads "
                          "a row's length off the full pool")
+    if any(k.pool == "state" for k in kinds):
+        # the state pool's shape is the configuration's, so its kinds agree in it
+        sizes = (cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim)
+        if min(sizes) < 1 or cfg.gdn_value_heads % cfg.gdn_key_heads or cfg.gdn_conv < 2:
+            raise ValueError(f"kinds of the state pool need gdn key/value heads and widths, value "
+                             f"heads a multiple of key heads, and a convolution: {sizes}, "
+                             f"{cfg.gdn_conv} taps")
+    if cfg.moe_score not in ("sigmoid", "softmax"):
+        raise ValueError(f"moe_score {cfg.moe_score!r}")
     for k in kinds:
-        if cfg.num_heads % k.kv_heads:
+        if k.mixer not in ("attention", "gdn"):
+            raise ValueError(f"kind {k.name}: mixer {k.mixer!r}")
+        if k.mixer == "attention" and cfg.num_heads % k.kv_heads:
             raise ValueError(f"kind {k.name}: {cfg.num_heads} heads over {k.kv_heads} kv heads")
         if k.ffn not in ("dense", "moe"):
             raise ValueError(f"kind {k.name}: ffn {k.ffn!r}")
@@ -122,27 +136,53 @@ def _layer_shapes(cfg, kind):
     """{(group, leaf): (shape, scale of its normal init; None = ones)} of one layer."""
     D, nh, dk, dv, F = cfg.hidden_size, cfg.num_heads, cfg.head_dim, cfg.v_head_dim, _ffn_size(cfg, kind)
     out_scale = 1.0 / math.sqrt(2 * cfg.num_layers)
-    shapes = {
-        ("attn", "wq"): ((D, nh * dk), 1 / math.sqrt(D)),
-        ("attn", "wk"): ((D, kind.kv_heads * dk), 1 / math.sqrt(D)),
-        ("attn", "wv"): ((D, kind.kv_heads * dv), 1 / math.sqrt(D)),
-        ("attn", "wo"): ((nh * dv, D), out_scale / math.sqrt(nh * dv)),
-        ("ln1", "scale"): ((D,), None),
-        ("ln2", "scale"): ((D,), None),
-    }
-    if kind.sink:
-        shapes[("attn", "sink")] = ((nh,), 1.0)
+    norm = 0.1 if cfg.norm_one_plus else None   # (1 + w): w zero-centred
+    shapes = {("ln1", "scale"): ((D,), norm), ("ln2", "scale"): ((D,), norm)}
+    if kind.mixer == "gdn":
+        Hk, Hv, gk, gv = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
+        C = 2 * Hk * gk + Hv * gv
+        shapes.update({
+            ("gdn", "wqkvz"): ((D, C + Hv * gv), 1 / math.sqrt(D)),    # q, k, v (convolved), z
+            ("gdn", "wba"): ((D, 2 * Hv), 1 / math.sqrt(D)),           # beta's and the decay's inputs
+            ("gdn", "conv"): ((C, cfg.gdn_conv), 1 / math.sqrt(cfg.gdn_conv)),
+            ("gdn", "a_log"): ((Hv,), 1.0),
+            ("gdn", "dt_bias"): ((Hv,), 1.0),
+            ("gdn", "norm"): ((gv,), None),
+            ("gdn", "wo"): ((Hv * gv, D), out_scale / math.sqrt(Hv * gv)),
+        })
+    else:
+        shapes.update({
+            ("attn", "wq"): ((D, nh * dk), 1 / math.sqrt(D)),
+            ("attn", "wk"): ((D, kind.kv_heads * dk), 1 / math.sqrt(D)),
+            ("attn", "wv"): ((D, kind.kv_heads * dv), 1 / math.sqrt(D)),
+            ("attn", "wo"): ((nh * dv, D), out_scale / math.sqrt(nh * dv)),
+        })
+        if kind.sink:
+            shapes[("attn", "sink")] = ((nh,), 1.0)
+        if cfg.attn_out_gate:
+            shapes[("attn", "wq_gate")] = ((D, nh * dv), 1 / math.sqrt(D))
+        if cfg.qk_norm:
+            shapes[("attn", "q_norm")] = shapes[("attn", "k_norm")] = ((dk,), norm)
     if kind.ffn == "moe":
         E, held = cfg.moe_num_experts, cfg.held_experts[1]
         shapes.update({
             ("mlp", "gate"): ((D, E), 0.02),
-            # the selection bias balancing leaves behind: small beside the scores' spread,
-            # large enough to decide some choices
-            ("mlp", "gate_bias"): ((E,), 0.01),
             ("mlp", "wg"): ((held, D, F), 1 / math.sqrt(D)),
             ("mlp", "wi"): ((held, D, F), 1 / math.sqrt(D)),
             ("mlp", "wo"): ((held, F, D), out_scale / math.sqrt(F)),
         })
+        if cfg.moe_score == "sigmoid":
+            # the selection bias balancing leaves behind: small beside the scores' spread,
+            # large enough to decide some choices
+            shapes[("mlp", "gate_bias")] = ((E,), 0.01)
+        if cfg.moe_shared_size:
+            Fs = cfg.moe_shared_size
+            shapes.update({
+                ("mlp", "shared_wg"): ((D, Fs), 1 / math.sqrt(D)),
+                ("mlp", "shared_wi"): ((D, Fs), 1 / math.sqrt(D)),
+                ("mlp", "shared_wo"): ((Fs, D), out_scale / math.sqrt(Fs)),
+                ("mlp", "shared_gate"): ((D, 1), 1 / math.sqrt(D)),
+            })
     else:
         shapes.update({
             ("mlp", "wg"): ((D, F), 1 / math.sqrt(D)),
@@ -200,11 +240,25 @@ def _project(h, attn_p, kind, cfg, positions):
         q = tf._linear(h, attn_p["wq"]).reshape(1, N, cfg.num_heads, cfg.head_dim)
         k = tf._linear(h, attn_p["wk"]).reshape(1, N, kind.kv_heads, cfg.head_dim)
         v = tf._linear(h, attn_p["wv"]).reshape(N, kind.kv_heads, cfg.v_head_dim)
+        if cfg.qk_norm:  # over each head's width, before it turns
+            q = tf._norm(q, attn_p["q_norm"], None, cfg)
+            k = tf._norm(k, attn_p["k_norm"], None, cfg)
         q = tf._rope(q, positions[None], kind.rope_theta, cfg.rope_dim, cfg.rope_interleaved)[0]
         k = tf._rope(k, positions[None], kind.rope_theta, cfg.rope_dim, cfg.rope_interleaved)[0]
         if cfg.attn_value_scale is not None:
             v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
     return q, k, v
+
+
+def _attn_out(att, h, attn_p, cfg):
+    """att (..., nh * dv) -> (..., D): through the output gate where the
+    model has one (sigmoid of a projection of the layer's input), then Wo."""
+    tf = _tf()
+    if cfg.attn_out_gate:
+        with jax.named_scope(Scope.ATTN_GATE):
+            gate = tf._linear(h, attn_p["wq_gate"]).reshape(att.shape)
+            att = (att.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(att.dtype)
+    return tf._attn_out_proj(att, attn_p, cfg)
 
 
 def _scale(cfg):
@@ -247,14 +301,140 @@ def _ffn(h, mlp_p, kind, cfg, valid, grad):
     from deepspeed_tpu.moe import held_experts as he
 
     first, count = cfg.held_experts
-    chosen, weights = he.route(h, mlp_p["gate"], mlp_p["gate_bias"], cfg.moe_top_k)
+    chosen, weights = he.route(h, mlp_p["gate"], mlp_p.get("gate_bias"), cfg.moe_top_k,
+                               cfg.moe_score)
     out, counts = he.held_experts_ffn(
         h, chosen, weights, {n: mlp_p[n] for n in _EXPERT_LEAVES}, first, count,
         grad=grad, valid=valid, layer=mlp_p.get("layer"))
+    if cfg.moe_shared_size:  # every chip computes it alike, whatever was routed where
+        with jax.named_scope(Scope.MOE_SHARED):
+            act = (jax.nn.silu(tf._linear(h, mlp_p["shared_wg"]))
+                   * tf._linear(h, mlp_p["shared_wi"]))
+            gate = jax.nn.sigmoid(tf._linear(h, mlp_p["shared_gate"]).astype(jnp.float32))
+            out = out + (tf._linear(act, mlp_p["shared_wo"]) * gate).astype(out.dtype)
     made = (h.shape[0] if valid is None else valid.sum(dtype=jnp.int32)) * cfg.moe_top_k
     return out, jnp.stack([jnp.asarray(made, jnp.int32), counts.sum(dtype=jnp.int32),
                            counts.max().astype(jnp.int32), jnp.int32(1),
                            (counts > 0).sum(dtype=jnp.int32)])
+
+
+# -- the gated delta rule mixer (ops/pallas/gated_delta.py has the rule itself) --
+
+def _gdn_project(h, p, cfg):
+    """h (N, D) -> (u (N, C) the convolution's inputs [q | k | v], z (N, Hv,
+    dv) the output gate's, g (N, Hv) the log decay, beta (N, Hv)), the last
+    two in float32."""
+    tf = _tf()
+    Hv = cfg.gdn_value_heads
+    C = p["conv"].shape[0]
+    qkvz, ba = tf._linear(h, p["wqkvz"]), tf._linear(h, p["wba"]).astype(jnp.float32)
+    beta = jax.nn.sigmoid(ba[:, :Hv])
+    g = -jnp.exp(p["a_log"].astype(jnp.float32)) * jax.nn.softplus(
+        ba[:, Hv:] + p["dt_bias"].astype(jnp.float32))
+    return qkvz[:, :C], qkvz[:, C:].reshape(-1, Hv, cfg.gdn_value_dim), g, beta
+
+
+def _gdn_conv(seq, w):
+    """Causal depthwise convolution then SiLU: seq (..., T + K - 1, C), its
+    first K - 1 steps the inputs before the first output; w (C, K).
+    Returns (..., T, C)."""
+    with jax.named_scope(Scope.GDN_CONV):
+        K = w.shape[1]
+        T = seq.shape[-2] - (K - 1)
+        acc = sum(seq[..., j:j + T, :].astype(jnp.float32) * w[:, j].astype(jnp.float32)
+                  for j in range(K))
+        return jax.nn.silu(acc).astype(seq.dtype)
+
+
+def _gdn_heads(u, cfg):
+    """Convolved u (N, C) -> q, k (N, Hv, dk) L2-normalised (q scaled), v
+    (N, Hv, dv), float32; value head j reads key head j // (Hv / Hk)."""
+    Hk, Hv, dk, dv = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
+    u = u.astype(jnp.float32)
+    unit = lambda a: a * jax.lax.rsqrt((a * a).sum(-1, keepdims=True) + 1e-6)
+    q = unit(u[:, :Hk * dk].reshape(-1, Hk, dk)) * dk ** -0.5
+    k = unit(u[:, Hk * dk:2 * Hk * dk].reshape(-1, Hk, dk))
+    rep = lambda a: jnp.repeat(a, Hv // Hk, axis=1)
+    return rep(q), rep(k), u[:, 2 * Hk * dk:].reshape(-1, Hv, dv)
+
+
+def _gdn_out(o, z, p, cfg):
+    """o, z (N, Hv, dv) -> (N, D): RMSNorm of each head's output (a plain
+    weight), gated by silu(z), through Wo."""
+    tf = _tf()
+    o = o.astype(jnp.float32)
+    o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.norm_eps) * p["norm"].astype(jnp.float32)
+    y = (o * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+    return tf._linear(y.reshape(y.shape[0], -1), p["wo"])
+
+
+def _hold(g, beta, valid):
+    """A token that is not ``valid`` (a chunk's pad, a parked row, an empty
+    slot) takes ``g = 0`` and ``beta = 0``: the step that leaves the state
+    exactly as it was. Looked up when a tick is traced (a test plants a
+    fault here)."""
+    return jnp.where(valid[:, None], g, 0.0), jnp.where(valid[:, None], beta, 0.0)
+
+
+def _gdn_plain(h, p, cfg, B, S):
+    """The mixer over whole sequences from a zero state, token by token:
+    h (B * S, D) -> (B * S, D)."""
+    from deepspeed_tpu.ops.pallas.gated_delta import gdn_recurrence
+
+    with jax.named_scope(Scope.MIX_GDN):
+        u, z, g, beta = _gdn_project(h, p, cfg)
+        u = u.reshape(B, S, -1)
+        u = _gdn_conv(jnp.pad(u, ((0, 0), (cfg.gdn_conv - 1, 0), (0, 0))), p["conv"])
+        q, k, v = (a.reshape((B, S) + a.shape[1:]) for a in _gdn_heads(u.reshape(B * S, -1), cfg))
+        zero = jnp.zeros((cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim), jnp.float32)
+        with jax.named_scope(Scope.GDN_SCAN):
+            o = jax.vmap(lambda *a: gdn_recurrence(*a, zero)[0])(
+                q, k, v, g.reshape(B, S, -1), beta.reshape(B, S, -1))
+        return _gdn_out(o.reshape((B * S,) + o.shape[2:]), z, p, cfg)
+
+
+def _gdn_cached(h, p, cfg, pool, layer, B, chunk, valid):
+    """The mixer of one layer of the tick: h (N, D) holds the B rows' single
+    tokens, then the chunk's W. Each valid row's state takes one step and
+    its convolution tail shifts by one; the chunk scans from its own row's
+    state and tail (that row is parked among the rows) and leaves the state
+    after its last real token and that token's last inputs. Returns ((N, D),
+    the state pool)."""
+    from deepspeed_tpu.ops.pallas.gated_delta import gdn_chunk, gdn_step_pool
+
+    with jax.named_scope(Scope.MIX_GDN):
+        u, z, g, beta = _gdn_project(h, p, cfg)
+        g, beta = _hold(g, beta, valid)
+        tails = jax.lax.dynamic_index_in_dim(pool["conv"], layer, 0, keepdims=False)   # (B, K-1, C)
+        seq = jnp.concatenate([tails, u[:B, None]], axis=1)
+        q, k, v = _gdn_heads(_gdn_conv(seq, p["conv"])[:, 0], cfg)
+        with jax.named_scope(Scope.GDN_STEP):   # in place (as XLA ops on the layer's slab: PERF.md section 6, PR 34)
+            o, states = gdn_step_pool(pool["s"], layer, q, k, v, g[:B], beta[:B])
+        tails = jnp.where(valid[:B, None, None], seq[:, 1:], tails)
+        if chunk is not None:
+            at = (layer, chunk.slot, 0, 0, 0)
+            seq = jnp.concatenate([jax.lax.dynamic_index_in_dim(tails, chunk.slot, 0, keepdims=False),
+                                   u[B:]])
+            q, k, v = _gdn_heads(_gdn_conv(seq, p["conv"]), cfg)
+            with jax.named_scope(Scope.GDN_SCAN):
+                oc, state = gdn_chunk(q, k, v, g[B:], beta[B:], jax.lax.dynamic_slice(
+                    states, at, (1, 1) + states.shape[2:])[0, 0])
+            states = jax.lax.dynamic_update_slice(states, state[None, None], at)
+            real = valid[B:].sum(dtype=jnp.int32)
+            tail = jax.lax.dynamic_slice_in_dim(seq, real, cfg.gdn_conv - 1, axis=0)   # the last real token's inputs
+            tails = jax.lax.dynamic_update_index_in_dim(tails, tail, chunk.slot, 0)
+            o = jnp.concatenate([o, oc])
+        pool = {"s": states, "conv": jax.lax.dynamic_update_index_in_dim(pool["conv"], tails, layer, 0)}
+        return _gdn_out(o, z, p, cfg), pool
+
+
+GDN_STATS = 2   # beside the routing counters: real tokens the chunk's scan took, rows stepped
+
+
+def stats_len(cfg) -> int:
+    """Counters a tick of ``cfg`` returns: the five routing counters, and
+    where it has delta-rule layers :data:`GDN_STATS` more."""
+    return 5 + (GDN_STATS if kv_cache.state_spec(cfg) is not None else 0)
 
 
 def _merge_stats(a, b):
@@ -289,9 +469,17 @@ def _walk(cfg, layers, carry, layer_fn):
             carry = one(carry, jax.tree.map(lambda p: p[run.kind_start], stack),
                         jnp.int32(run.kind_start), jnp.int32(run.pool_start))
             continue
-        if run.n != layers_of(cfg, run.kind):
-            stack = jax.tree.map(lambda p: p[run.kind_start:run.kind_start + run.n], stack)
         steps = jnp.arange(run.n, dtype=jnp.int32)
+        if run.n != layers_of(cfg, run.kind):
+            # one run of several of its kind (a period that repeats): each step reads its
+            # layer out of the kind's whole stack, where a slice of the run would be a copy of
+            # the run's weights, every tick
+            def step(c, inp, stack=stack):
+                at = lambda p: jax.lax.dynamic_index_in_dim(p, inp[0], 0, keepdims=False)
+                return one(c, jax.tree.map(at, stack), *inp), None
+
+            carry, _ = jax.lax.scan(step, carry, (run.kind_start + steps, run.pool_start + steps))
+            continue
         carry, _ = jax.lax.scan(lambda c, inp: (one(c, *inp), None), carry,
                                 (stack, run.kind_start + steps, run.pool_start + steps))
     return carry
@@ -320,8 +508,9 @@ def forward_plan(params, cfg, tokens, return_hidden=False):
     qpos = jnp.arange(S, dtype=jnp.int32)[:, None]
     kpos = jnp.arange(S, dtype=jnp.int32)[None, :]
 
-    def layer(x, layer_p, kind, _):
-        h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg).reshape(B * S, -1)
+    def mix(h, layer_p, kind):
+        if kind.mixer == "gdn":
+            return _gdn_plain(h, layer_p["gdn"], cfg, B, S)
         q, k, v = _project(h, layer_p["attn"], kind, cfg, positions)
         ok = kpos <= qpos
         if kind.window:
@@ -332,7 +521,11 @@ def forward_plan(params, cfg, tokens, return_hidden=False):
                 k.reshape(B, S, *k.shape[1:]).transpose(0, 2, 1, 3),
                 v.reshape(B, S, *v.shape[1:]).transpose(0, 2, 1, 3),
                 ok[None], layer_p["attn"].get("sink"), _scale(cfg))
-        x = x + tf._attn_out_proj(att.reshape(B, S, -1), layer_p["attn"], cfg)
+        return _attn_out(att.reshape(B * S, -1), h, layer_p["attn"], cfg)
+
+    def layer(x, layer_p, kind, _):
+        h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg).reshape(B * S, -1)
+        x = x + mix(h, layer_p, kind).reshape(B, S, -1)
         h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg).reshape(B * S, -1)
         out, _ = _ffn(h, layer_p["mlp"], kind, cfg, None, grad=True)
         return x + out.reshape(B, S, -1)
@@ -430,9 +623,11 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
     part of the same flat list of tokens; the row it belongs to is parked
     among the rows, and its logits (at column ``chunk.emit``) take that
     row's place in the output. ``read_len`` (static) tight-reads the full
-    pool. Returns (logits (B, V), cache, stats (5,) int32: expert assignments
-    made / to held experts / the most one held expert got in a layer /
-    expert layers / held experts that got a token, summed over the layers)."""
+    pool. Returns (logits (B, V), cache, stats (:func:`stats_len`,) int32:
+    expert assignments made / to held experts / the most one held expert got
+    in a layer / expert layers / held experts that got a token, summed over
+    the layers; with delta-rule layers also the real tokens the chunk's scan
+    took and the rows whose state this tick stepped)."""
     tf = _tf()
     dtype = cfg.jnp_dtype
     B = tokens.shape[0]
@@ -451,16 +646,23 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
         x, pools, stats = carry
         pool = pools[kind.pool]
         h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg)
-        q, k, v = _project(h, layer_p["attn"], kind, cfg, all_pos)
-        att, pk, pv = _attend_cached(q, k, v, layer_p["attn"], kind, cfg, pool["k"], pool["v"],
-                                     pool_index, pos, chunk, read_len, length)
-        x = x + tf._attn_out_proj(att, layer_p["attn"], cfg)
+        if kind.mixer == "gdn":
+            out, pool = _gdn_cached(h, layer_p["gdn"], cfg, pool, pool_index, B, chunk, valid)
+        else:
+            q, k, v = _project(h, layer_p["attn"], kind, cfg, all_pos)
+            att, pk, pv = _attend_cached(q, k, v, layer_p["attn"], kind, cfg, pool["k"], pool["v"],
+                                         pool_index, pos, chunk, read_len, length)
+            out, pool = _attn_out(att, h, layer_p["attn"], cfg), {"k": pk, "v": pv}
+        x = x + out
         h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg)
         out, st = _ffn(h, layer_p["mlp"], kind, cfg, valid, grad=False)
-        return x + out, dict(pools, **{kind.pool: {"k": pk, "v": pv}}), _merge_stats(stats, st)
+        return x + out, dict(pools, **{kind.pool: pool}), _merge_stats(stats, st)
 
     x, cache, stats = _walk(cfg, tf._cast_layers(params["layers"], dtype),
                             (x, cache, jnp.zeros((5,), jnp.int32)), layer)
+    if kv_cache.state_spec(cfg) is not None:
+        stats = jnp.concatenate([stats, jnp.stack([valid[B:].sum(dtype=jnp.int32),
+                                                   valid[:B].sum(dtype=jnp.int32)])])
     rows = x[:B]
     if chunk is not None:  # the admitting row's place is taken by the chunk's sampled column
         rows = jax.lax.dynamic_update_slice(rows, x[B + chunk.emit][None], (chunk.slot, 0))

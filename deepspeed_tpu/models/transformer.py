@@ -36,21 +36,26 @@ from deepspeed_tpu.telemetry.hlo_scopes import Scope
 
 @dataclass(frozen=True)
 class LayerKind:
-    """One kind of decoder layer in a layer plan: the shape of its attention
-    and the kind of its FFN. Layers of one kind share parameter shapes and
-    are stacked together (``params["layers"][name]``); layers whose
-    attention has the same reach (``window`` 0 or not) share a KV pool."""
+    """One kind of decoder layer in a layer plan: its mixer (softmax
+    attention of some shape, or the gated delta rule, whose shape is the
+    configuration's ``gdn_*``) and the kind of its FFN. Layers of one kind
+    share parameter shapes and are stacked together
+    (``params["layers"][name]``); attention layers of the same reach
+    (``window`` 0 or not) share a KV pool, delta-rule layers the state pool."""
     name: str
-    kv_heads: int
+    kv_heads: int = 1  # of an attention mixer
     window: int = 0  # 0 = full causal attention; W = the last W positions
     rope_theta: float = 10000.0
     sink: bool = False  # a learned per-head logit joins the softmax's denominator
     ffn: str = "dense"  # dense | moe (sigmoid top-k over the experts held)
     ffn_size: Optional[int] = None  # None => cfg.ffn_size
+    mixer: str = "attention"  # attention | gdn (Gated DeltaNet: recurrent state, no keys kept)
 
     @property
     def pool(self) -> str:
-        """The KV pool this kind's layers live in."""
+        """The cache pool this kind's layers live in."""
+        if self.mixer == "gdn":
+            return "state"
         return "window" if self.window > 0 else "full"
 
 
@@ -154,6 +159,21 @@ class TransformerConfig:
     # capacity and no dropped token; this chip holds the contiguous experts
     # [first, first + count) and computes their part of the result
     moe_experts_held: Optional[tuple] = None  # (first, count); None => all
+    moe_score: str = "sigmoid"  # sigmoid (+ selection bias) | softmax over all experts, no bias
+    moe_shared_size: int = 0  # width of a shared SwiGLU expert behind a sigmoid gate; 0 = none
+    # a plan's attention layers: sigmoid(gate) on the attention output (the gate rides wq's
+    # projection as a leaf of its own), RMSNorm over each query and key head
+    attn_out_gate: bool = False
+    qk_norm: bool = False
+    norm_one_plus: bool = False  # RMSNorm scales by (1 + w), w stored zero-centred
+    # Gated DeltaNet mixer (LayerKind.mixer == "gdn"): key heads x key width,
+    # value heads x value width (value head j reads key head j // (value / key
+    # heads)), the taps of its causal depthwise convolution
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 4
     # leaves made in the model dtype at init (a model whose float32 leaves
     # would not fit beside their cast copy)
     init_in_model_dtype: bool = False
@@ -436,6 +456,9 @@ def init(rng, cfg: TransformerConfig):
         from deepspeed_tpu.models.layer_plan import init_layers
 
         params["layers"] = init_layers(r_layers, cfg)
+        if cfg.norm_one_plus:  # (1 + w): w is stored zero-centred
+            params["final_norm"]["scale"] = 0.1 * jax.random.normal(
+                jax.random.fold_in(r_outer, 7), (cfg.hidden_size,), jnp.float32)
         if cfg.init_in_model_dtype:
             params = jax.tree.map(lambda p: p.astype(cfg.jnp_dtype), params)
         return params
@@ -459,7 +482,8 @@ def logical_specs(params, cfg: TransformerConfig):
             table = {
                 "wq": ("embed", "heads"), "wk": ("embed", "kv"), "wv": ("embed", "kv"),
                 "wo": ("heads", "embed"), "bq": ("heads",), "bk": ("kv",), "bv": ("kv",), "bo": ("embed",),
-                "sink": ("heads",),
+                "sink": ("heads",), "wq_gate": ("embed", "heads"), "q_norm": (None,),
+                "k_norm": (None,),
             }
             return pre + table[last]
         if "mlp" in names:
@@ -472,6 +496,8 @@ def logical_specs(params, cfg: TransformerConfig):
             table = {"wi": ("embed", "mlp"), "wg": ("embed", "mlp"), "wo": ("mlp", "embed"),
                      "bi": ("mlp",), "bo": ("embed",), "gate": ("embed", None),
                      "gate_bias": (None,),
+                     "shared_wi": ("embed", "mlp"), "shared_wg": ("embed", "mlp"),
+                     "shared_wo": ("mlp", "embed"), "shared_gate": ("embed", None),
                      # PR-MoE residual MLP + mixing coefficient (dense)
                      "res_wi": ("embed", "mlp"), "res_wg": ("embed", "mlp"),
                      "res_wo": ("mlp", "embed"), "res_bi": ("mlp",), "res_bo": ("embed",),
@@ -510,6 +536,8 @@ def _norm(x, scale, bias, cfg: TransformerConfig):
         mu = jnp.mean(x32, axis=-1, keepdims=True)
         var = jnp.var(x32, axis=-1, keepdims=True)
         x32 = (x32 - mu) * jax.lax.rsqrt(var + cfg.norm_eps)
+    if cfg.norm_one_plus:
+        scale = 1.0 + scale.astype(jnp.float32)
     out = x32 * scale
     if bias is not None:
         out = out + bias

@@ -1,10 +1,11 @@
 """An expert layer that is told which experts it holds.
 
-The router scores every expert of the model (``E`` of them) with a sigmoid,
-in float32; a token takes the ``k`` experts whose score plus selection bias
-is largest (the bias decides the choice and nothing else: "noaux_tc",
-DeepSeek-V3's auxiliary-loss-free balancing) and weighs them by their
-scores normalised over the chosen ``k``. This chip holds the contiguous
+The router scores every expert of the model (``E`` of them) in float32,
+with a sigmoid, a token taking the ``k`` experts whose score plus selection
+bias is largest (the bias decides the choice and nothing else: "noaux_tc",
+DeepSeek-V3's auxiliary-loss-free balancing), or with a softmax over all
+``E``, a token taking the ``k`` most probable; either way it weighs them by
+their scores normalised over the chosen ``k``. This chip holds the contiguous
 experts ``[first, first + count)`` and computes their part of the result:
 
     y = sum over chosen experts e that are HELD of w_e * Expert_e(h)
@@ -40,12 +41,20 @@ class Layout(NamedTuple):
 
 
 @jax.named_scope(Scope.MOE_ROUTE)
-def route(h, gate_w, gate_bias, k: int):
+def route(h, gate_w, gate_bias, k: int, score: str = "sigmoid"):
     """h (N, D) -> (chosen experts (N, k) int32, their weights (N, k)
-    float32). Scores in float32 whatever the dtype the weights are stored in."""
-    scores = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32), gate_w.astype(jnp.float32),
-                                    precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(scores + gate_bias.astype(jnp.float32), k)
+    float32). Scores in float32 whatever the dtype the weights are stored
+    in: ``score`` "sigmoid", chosen by score + ``gate_bias``, or "softmax"
+    over ALL the experts (``gate_bias`` None: the k largest probabilities);
+    either way the chosen scores are normalised over the chosen k."""
+    logits = jnp.dot(h.astype(jnp.float32), gate_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, chosen = jax.lax.top_k(scores, k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(scores + gate_bias.astype(jnp.float32), k)
     picked = jnp.take_along_axis(scores, chosen, axis=1)
     return chosen.astype(jnp.int32), picked / picked.sum(axis=1, keepdims=True)
 

@@ -7,6 +7,12 @@ A cache is a tree of pools the slot manager carries and donates whole:
 ``(L, B, T, kv_heads, width)`` (time before heads) or ``(L, B, kv_heads, T,
 width)`` (heads before time), or int8 (``kv_cache_dtype="int8"``) the pair
 ``{"q8": payload, "s": float32 per-token-per-head scales}`` of such arrays.
+A plan with delta-rule layers has one pool more, ``"state": {"s", "conv"}``
+(:class:`StateSpec`), which has NO time axis: a row's recurrent state ``(L,
+B, heads, key width, value width)`` float32 and the last taps - 1 inputs of
+its convolution ``(L, B, taps - 1, channels)`` are read whole and rewritten
+whole every token, never grow, have no read bucket and, since nothing masks
+a stale state, are zeroed when a request takes the row (:func:`reset_row`).
 
 Three parts: the spec (:func:`specs` chooses the order), what the host
 knows (init, length, bytes, sharding, growth, splice) and the device-side
@@ -16,6 +22,7 @@ its pool's order. The configuration is duck-typed (``ops`` is below ``models``).
 """
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -51,8 +58,35 @@ class PoolSpec(NamedTuple):
         return (self.layers, batch) + mid + (width,)
 
 
+class StateSpec(NamedTuple):
+    """The state pool of a plan's delta-rule layers."""
+    layers: int
+    heads: int             # value heads: one (k_width, v_width) state each
+    k_width: int
+    v_width: int
+    tail: int              # convolution taps - 1: the inputs kept
+    channels: int          # ... of this many channels
+
+    name = "state"
+
+    def shapes(self, batch: int, dtype) -> dict:
+        """{leaf: (shape, dtype)}: the state in float32, the tail in the model's dtype."""
+        return {"s": ((self.layers, batch, self.heads, self.k_width, self.v_width), jnp.float32),
+                "conv": ((self.layers, batch, self.tail, self.channels), dtype)}
+
+
 def _is_plan(cfg) -> bool:
     return getattr(cfg, "layer_kinds", None) is not None
+
+
+def state_spec(cfg) -> Optional[StateSpec]:
+    """The state pool of ``cfg``'s cache; None where no layer keeps one."""
+    n = sum(k.pool == "state" for k in cfg.plan) if _is_plan(cfg) else 0
+    if not n:
+        return None
+    channels = 2 * cfg.gdn_key_heads * cfg.gdn_key_dim + cfg.gdn_value_heads * cfg.gdn_value_dim
+    return StateSpec(n, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim,
+                     cfg.gdn_conv - 1, channels)
 
 
 def specs(cfg) -> Tuple[PoolSpec, ...]:
@@ -72,6 +106,8 @@ def specs(cfg) -> Tuple[PoolSpec, ...]:
                          heads_first=False, int8=cfg.kv_cache_dtype == "int8"),)
     pools = {}
     for kind in cfg.plan:
+        if kind.pool == "state":   # no keys, no time axis: state_spec()
+            continue
         n = pools[kind.pool].layers if kind.pool in pools else 0
         pools[kind.pool] = PoolSpec(kind.pool, n + 1, kind.kv_heads, cfg.head_dim,
                                     cfg.v_head_dim, kind.window or None,
@@ -81,8 +117,9 @@ def specs(cfg) -> Tuple[PoolSpec, ...]:
 
 # -- what the host knows ----------------------------------------------------
 
-def _build(cfg, leaf):
-    """``cfg``'s cache tree with ``leaf(spec, width, dtype)`` in every array's place."""
+def _build(cfg, leaf, state_leaf):
+    """``cfg``'s cache tree with ``leaf(spec, width, dtype)`` in every KV
+    array's place and ``state_leaf(name)`` in the state pool's."""
     def component(spec, width):
         if spec.int8:
             return {"q8": leaf(spec, width, jnp.int8), "s": leaf(spec, 1, jnp.float32)}
@@ -90,6 +127,8 @@ def _build(cfg, leaf):
 
     pools = {s.name: {"k": component(s, s.k_width), "v": component(s, s.v_width)}
              for s in specs(cfg)}
+    if state_spec(cfg) is not None:
+        pools[StateSpec.name] = {name: state_leaf(name) for name in ("s", "conv")}
     return pools if _is_plan(cfg) else pools["kv"]
 
 
@@ -101,9 +140,12 @@ def _pools(cfg, cache):
 
 
 def init(cfg, batch_size: int, length: int):
-    """The zeroed cache; a ring pool is ``ring`` long whatever ``length`` is."""
+    """The zeroed cache; a ring pool is ``ring`` long whatever ``length``
+    is, and the state pool has no length."""
+    state = state_spec(cfg)
     return _build(cfg, lambda spec, width, dtype: jnp.zeros(
-        spec.shape(batch_size, length, width), dtype))
+        spec.shape(batch_size, length, width), dtype),
+        lambda name: jnp.zeros(*state.shapes(batch_size, cfg.jnp_dtype)[name]))
 
 
 def alloc_len(cfg, cache) -> int:
@@ -115,8 +157,9 @@ def alloc_len(cfg, cache) -> int:
 
 def grow(cfg, cache, new_len: int):
     """``cache`` zero-padded along time to ``new_len`` (a ring keeps its
-    length). Traced: the caller jits it."""
-    grown, more = {}, new_len - alloc_len(cfg, cache)
+    length, the state pool has none). Traced: the caller jits it."""
+    more = new_len - alloc_len(cfg, cache)
+    grown = {StateSpec.name: cache[StateSpec.name]} if state_spec(cfg) is not None else {}
     for spec, sub in _pools(cfg, cache):
         widths = [(0, 0)] * 5
         widths[spec.time_axis] = (0, more)
@@ -133,18 +176,44 @@ def splice_row(big, small, slot):
         big, small)
 
 
+def reset_row(state, slot):
+    """The state pool ``state`` (``cache["state"]``) with row ``slot``
+    zeroed in every layer, in place: what a request admitted to the row
+    starts from. Traced: ``decoding.compile_row_update_fn`` jits it beside
+    the row's flip."""
+    def zero(leaf):
+        blank = jnp.zeros((leaf.shape[0], 1) + leaf.shape[2:], leaf.dtype)
+        return jax.lax.dynamic_update_slice(leaf, blank, (0, slot) + (0,) * (leaf.ndim - 2))
+
+    return jax.tree.map(zero, state)
+
+
 def pool_bytes(cfg, cache) -> dict:
-    return {spec.name: sum(leaf.nbytes for leaf in jax.tree.leaves(sub))
-            for spec, sub in _pools(cfg, cache)}
+    out = {spec.name: sum(leaf.nbytes for leaf in jax.tree.leaves(sub))
+           for spec, sub in _pools(cfg, cache)}
+    if state_spec(cfg) is not None:
+        out[StateSpec.name] = sum(leaf.nbytes for leaf in jax.tree.leaves(cache[StateSpec.name]))
+    return out
+
+
+def state_bytes_per_row(cfg) -> int:
+    """Bytes of ONE row of the state pool over its layers (0 without one):
+    what a row's step reads, and writes back."""
+    state = state_spec(cfg)
+    if state is None:
+        return 0
+    return sum(math.prod(shape[2:]) * shape[0] * jnp.dtype(dtype).itemsize
+               for shape, dtype in state.shapes(1, cfg.jnp_dtype).values())
 
 
 def read_bytes_by_pool(cfg, read_len: int) -> dict:
     """{pool: HBM bytes ONE row's attention streams from it in a decode step
     that attends ``read_len`` slots}: K and V across its layers (a ring is
     read whole and no further), int8 as payload + a float32 scale a token
-    and head. What the compiled read touches, so tests assert it."""
+    and head; the state pool is read whole whatever ``read_len`` is. What
+    the compiled read touches, so tests assert it."""
     item = jnp.dtype(cfg.jnp_dtype).itemsize
-    out = {}
+    out = {StateSpec.name: state_bytes_per_row(cfg)} if state_spec(cfg) is not None else {}
     for s in specs(cfg):
         per_head = (s.k_width + s.v_width) * (1 if s.int8 else item) + (2 * 4 if s.int8 else 0)
         out[s.name] = s.layers * min(s.ring or read_len, read_len) * s.kv_heads * per_head
@@ -181,7 +250,9 @@ def partition_spec(cfg, mesh, batch_axes):
             axes[spec.heads_axis] = "tensor"
         return PartitionSpec(*axes)
 
-    return _build(cfg, leaf)
+    state = state_spec(cfg)
+    return _build(cfg, leaf, lambda name: PartitionSpec(
+        None, batch_axes, *[None] * (len(state.shapes(1, cfg.jnp_dtype)[name][0]) - 2)))
 
 
 def spans_chips(mesh) -> bool:

@@ -42,6 +42,12 @@ class Scope:
     ATTN_WINDOW = "attn.window"  # ... and its sliding-window core
     MOE_ROUTE = "moe.route"      # router scores, top-k, the sort by expert
     MOE_EXPERTS = "moe.experts"  # gather, grouped matmuls over the held experts, combine
+    MOE_SHARED = "moe.shared"    # the shared expert and its sigmoid gate
+    ATTN_GATE = "attn.gate"      # the sigmoid gate on a plan's attention output
+    MIX_GDN = "mix.gdn"          # a gated-delta-rule mixer: projections, gates, output norm
+    GDN_CONV = "gdn.conv"        # ... its causal depthwise convolution
+    GDN_SCAN = "gdn.scan"        # ... one prefill chunk's scan (the gdn_chunk_fwd kernel)
+    GDN_STEP = "gdn.step"        # ... the rows' one-token state step
     NORM = "norm"
     LM_HEAD = "lm_head"
     LOSS = "loss"

@@ -4,8 +4,12 @@ queries and keys, not a multiple of the 128 lanes; 128 for values), the
 grouped matmul over the held experts, and the MiMo-V2.5 serving tick at the
 benchmark's cut (``benchmark/configs/mimo-v2.5.json``, 32 slots of 16,896
 positions): both pools updated in place, no copy of a pool, temporaries
-far under the pools. Nothing runs. Skipped where libtpu cannot describe
-the topology."""
+far under the pools; and what a state pool adds (PR 34): the chunked
+delta-rule scan and the rows' in-place state step at the published widths,
+flash at head width 256, and the Qwen3-Next serving tick at the benchmark's
+cut (``benchmark/configs/qwen3-next-80b-a3b.json``) with the key-value pool
+and the state pool both in place. Nothing runs. Skipped where libtpu cannot
+describe the topology."""
 
 import json
 import os
@@ -119,3 +123,78 @@ def test_mimo_tick_updates_both_pools_in_place(topo, read_len, chunk):
     # the full-length pool is never copied (the ring, 0.1 GB, may be re-laid out for a chunk)
     copies = re.findall(r"= bf16\[(?:\d+,)?32,4,16896,\d+\]\S* copy\(", compiled.as_text())
     assert not copies, copies
+
+
+def test_flash_chunk_kernel_compiles_at_head_width_256(topo):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_chunk
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+    _compile(topo, lambda q, k, v, a: flash_attention_chunk(q, k, v, a),
+             ((1024, 16, 256), bf), ((2, 16896, 256), bf), ((2, 16896, 256), bf), ((), i32))
+
+
+@pytest.mark.parametrize("W", [1024, 256])
+def test_delta_rule_chunk_scan_compiles_at_the_published_widths(topo, W):
+    from deepspeed_tpu.ops.pallas.gated_delta import gdn_chunk
+
+    f32 = jnp.float32
+    _compile(topo, gdn_chunk, ((W, 32, 128), f32), ((W, 32, 128), f32), ((W, 32, 128), f32),
+             ((W, 32), f32), ((W, 32), f32), ((32, 128, 128), f32))
+
+
+def test_delta_rule_rows_step_updates_the_state_pool_in_place(topo):
+    from deepspeed_tpu.ops.pallas.gated_delta import gdn_step_pool
+
+    f32 = jnp.float32
+    compiled = _compile(topo, lambda pool, layer, *a: gdn_step_pool(pool, layer, *a),
+                        ((9, 32, 32, 128, 128), f32), ((), jnp.int32), ((32, 32, 128), f32),
+                        ((32, 32, 128), f32), ((32, 32, 128), f32), ((32, 32), f32), ((32, 32), f32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 20e6      # no copy of the pool (604 MB)
+
+
+@pytest.mark.parametrize("read_len,chunk", [(None, None), (None, 1024), (2048, 256)],
+                         ids=["plain", "fused1024", "fused256-read2048"])
+def test_qwen3_next_tick_updates_both_kinds_of_pool_in_place(topo, read_len, chunk):
+    from benchmark import models_qwen3_next
+    from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
+    from deepspeed_tpu.models import transformer as tf
+
+    with open(os.path.join(ROOT, "benchmark", "configs", "qwen3-next-80b-a3b.json")) as fh:
+        config = json.load(fh)
+    slots, length = 32, 16896
+    model = models_qwen3_next.build_model(config, max_seq_len=length, remat=False,
+                                          attn_impl="pallas")
+    cfg = model.cfg
+    mesh = comm.build_mesh({"data": 1, "tensor": 1}, devices=topo.devices[:1])
+    one = NamedSharding(mesh, PartitionSpec())
+    abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    p_sh = jax.tree.map(lambda a: one, abstract)
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one),
+                          abstract)
+    with force_interpret(False):
+        fn, cache_sh, _ = compile_pool_tick_fn(mesh, cfg, p_sh, slots, length, 1, 0.0, 0, 1.0,
+                                               read_len=read_len, chunk=chunk)
+        cache = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            jax.eval_shape(lambda: tf.init_cache(cfg, slots, length)), cache_sh)
+        row = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+        args = [params, cache, row, row, row, row, row, row, jax.ShapeDtypeStruct((2,), jnp.uint32)]
+        if chunk is not None:
+            wide = jax.ShapeDtypeStruct((chunk,), jnp.int32)
+            args += [wide, wide, jax.ShapeDtypeStruct((), jnp.int32), row, row]
+        compiled = fn.lower(*args).compile()
+    comm.destroy()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert pool_bytes == 3 * 32 * 2 * 16896 * 512 * 2 + 9 * 32 * (32 * 128 * 128 * 4 + 3 * 8192 * 2)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes                     # 3.32 + 0.62 GB, in place
+    assert mem.temp_size_in_bytes < 0.4e9, mem.temp_size_in_bytes    # 0.07 plain, 0.23 with a chunk
+    resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert resident < 15.75e9
+    text = compiled.as_text()
+    # neither pool is copied, and no run's weights are sliced out of their kind's stack
+    assert not re.findall(r"= bf16\[(?:\d+,)?32,2,16896,\d+\]\S* copy\(", text)
+    assert not re.findall(r"= f32\[(?:\d+,)?32,32,128,128\]\S* (?:copy|dynamic-slice)\(", text)
+    assert not re.findall(r"= bf16\[3,2048,12288\]\S* slice\(", text)
+    assert "gdn_step" in text and ("gdn_chunk_fwd" in text) == (chunk is not None)
