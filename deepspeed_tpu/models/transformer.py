@@ -77,7 +77,13 @@ class TransformerConfig:
     norm_eps: float = 1e-5
     dropout: float = 0.0
     remat: bool = False
-    remat_policy: str = "nothing_saveable"  # nothing_saveable | dots_saveable | dots_with_no_batch_dims
+    # a name of checkpointing.POLICIES (or "offload"): flash_saveable |
+    # nothing_saveable | dots_saveable | dots_with_no_batch_dims | full.
+    # The default keeps the flash kernel's output and log-sum-exp a layer
+    # (one (B, S, D) array in the model's dtype + 3 %) so the backward pass
+    # does not rerun the kernel; without the kernel (attn_impl="xla", ...) it
+    # is nothing_saveable, which a job at its memory limit can also ask for
+    remat_policy: str = "flash_saveable"
     attn_impl: str = "xla"  # xla | pallas (flash) | block_sparse (layout kernel)
     # block-sparse attention pattern (attn_impl="block_sparse"): mode is one
     # of dense|fixed|bigbird|bslongformer|variable plus that mode's kwargs

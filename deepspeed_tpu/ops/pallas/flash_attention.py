@@ -419,16 +419,16 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, vma, window, res, do):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_bhsd(q, k, v, causal, sm_scale, block_q, block_k, interpret, vma, window):
     o, _ = _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, vma, window)
-    return o
+    return jnp.transpose(o, (0, 2, 1, 3))
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, interpret, vma, window):
     o, lse = _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, vma, window)
-    return o, (q, k, v, o, lse)
+    return _out_and_residuals(q, k, v, o, lse)
 
 
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, vma, window, res, do):
-    return _bwd(causal, sm_scale, block_q, block_k, interpret, vma, window, res, do)
+    return _bwd(causal, sm_scale, block_q, block_k, interpret, vma, window, *_kernel_forms(res, do))
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -479,9 +479,9 @@ def flash_attention(
     qt = jnp.transpose(q, (0, 2, 1, 3))
     kt = jnp.transpose(k, (0, 2, 1, 3))
     vt = jnp.transpose(v, (0, 2, 1, 3))
-    o = _flash_bhsd(qt, kt, vt, causal, sm_scale, block_q, block_k, interpret, vma,
-                    window)
-    return jnp.transpose(o, (0, 2, 1, 3))
+    # kernels run (B, H, S, hd); the output comes back (B, S, H, hd) already
+    return _flash_bhsd(qt, kt, vt, causal, sm_scale, block_q, block_k, interpret, vma,
+                       window)
 
 
 # ---------------------------------------------------------------------------
@@ -616,3 +616,45 @@ def mha_reference(q, k, v, causal: bool = True, sm_scale: Optional[float] = None
         s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# what a layer checkpoint keeps of the forward kernel
+# (the section sits last so that no kernel above moves a line: a Mosaic
+# call's payload carries its source lines, and with them its cache key)
+# ---------------------------------------------------------------------------
+
+from jax.ad_checkpoint import checkpoint_name  # noqa: E402
+
+# The two residuals only the kernel can produce, by the names a remat policy
+# saves them under (``save_only_these_names(*RESIDUAL_NAMES)`` is the policy
+# "flash_saveable" of runtime/activation_checkpointing). q, k and v carry no
+# name: the backward pass rebuilds them from the layer's input.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+
+def _out_and_residuals(q, k, v, o, lse):
+    """``_flash_bhsd``'s output and its VJP residuals from the kernel's
+    (B, H, S, hd) output and (B, H, S, 1) log-sum-exp. What is named, and so
+    what a layer scan stacks when a policy saves it, is dense: the output as
+    (B, S, H*hd) and the log-sum-exp as (B, H, S) -- the chip pads a minor
+    axis of 64 to 128 lanes and one of 1 to a whole (8, 128) tile. The
+    output handed on is a view of the NAMED array, so that a backward pass
+    which holds the name needs the kernel for nothing."""
+    B, H, S, hd = o.shape
+    out = checkpoint_name(jnp.transpose(o, (0, 2, 1, 3)).reshape(B, S, H * hd),
+                          RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
+    return out.reshape(B, S, H, hd), (q, k, v, out, lse)
+
+
+def _kernel_forms(res, do):
+    """The residuals and the output's cotangent back in the backward
+    kernels' shapes: a transpose and a trailing axis, in XLA."""
+    q, k, v, out, lse = res
+    B, H, S, hd = q.shape
+    # the barrier makes the chip transpose the saved array in its own dtype;
+    # without it the compiler first widens it to float32 for ``_bwd``'s
+    # ``delta``, transposes twice the bytes, and runs the sum as a third op
+    o = jax.lax.optimization_barrier(jnp.transpose(out.reshape(B, S, H, hd), (0, 2, 1, 3)))
+    return (q, k, v, o, lse[..., None]), jnp.transpose(do, (0, 2, 1, 3))

@@ -40,12 +40,19 @@ from typing import Any, Callable, Dict, Optional
 import jax
 from jax.ad_checkpoint import checkpoint_policies as _cp
 
+from deepspeed_tpu.ops.pallas.flash_attention import RESIDUAL_NAMES
 from deepspeed_tpu.utils.logging import log_dist, logger
 
 # Named remat policies. "offload_dots" saves matmul outputs to host memory —
 # the cpu_checkpointing tier; "nothing" is full recompute (max memory saving).
+# "flash_saveable" keeps what the flash forward kernel alone can produce (its
+# output and log-sum-exp, one (B, S, D) array a layer plus 3 %) so that the
+# backward pass does not run the kernel a second time; where a layer's
+# attention ran no such kernel nothing carries the names and it is
+# "nothing_saveable".
 POLICIES: Dict[str, Any] = {
     "nothing_saveable": _cp.nothing_saveable,
+    "flash_saveable": _cp.save_only_these_names(*RESIDUAL_NAMES),
     "dots_saveable": _cp.dots_saveable,
     "dots_with_no_batch_dims": _cp.dots_with_no_batch_dims_saveable,
     "full": _cp.everything_saveable,

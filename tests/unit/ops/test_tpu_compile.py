@@ -10,6 +10,7 @@ is not). Nothing runs: a compile that passes says nothing about results or
 times. Skipped where libtpu cannot describe the topology.
 """
 
+import functools
 import os
 import re
 
@@ -102,22 +103,23 @@ def _flash_on_mesh(mesh_shape, spec):
     return build
 
 
-def _gpt2_350m_step(topo):
+def _gpt2_350m_step(n_kernels, **remat_policy):
     """TransformerModel.loss + grad at gpt2-350m, micro-batch 8, seq 1024,
     bf16, remat, attn_impl="pallas" — chip_smoke.py's training model."""
-    from deepspeed_tpu.models.transformer import TransformerModel
+    def build(topo):
+        from deepspeed_tpu.models.transformer import TransformerModel
 
-    one = SingleDeviceSharding(topo.devices[0])
-    model = TransformerModel.from_preset("gpt2-350m", dtype="bfloat16", remat=True,
-                                         attn_impl="pallas")
-    params = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one),
-        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    batch = {"input_ids": jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one)}
-    step = lambda p, b: jax.value_and_grad(  # noqa: E731
-        lambda p: model.loss(p, b).astype(jnp.float32))(p)
-    # fwd + remat'd fwd + dq + dkv inside the layer scan
-    return _lower(step, params, batch), 4
+        one = SingleDeviceSharding(topo.devices[0])
+        model = TransformerModel.from_preset("gpt2-350m", dtype="bfloat16", remat=True,
+                                             attn_impl="pallas", **remat_policy)
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one),
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+        batch = {"input_ids": jax.ShapeDtypeStruct((8, 1024), jnp.int32, sharding=one)}
+        step = lambda p, b: jax.value_and_grad(  # noqa: E731
+            lambda p: model.loss(p, b).astype(jnp.float32))(p)
+        return _lower(step, params, batch), n_kernels
+    return build
 
 
 CASES = {
@@ -133,19 +135,53 @@ CASES = {
         {"tensor": 4}, PartitionSpec(None, None, "tensor", None)),
     "flash-bwd-mesh-fsdp4": _flash_on_mesh(
         {"fsdp": 4}, PartitionSpec("fsdp", None, None, None)),
-    "gpt2-350m-loss-grad-mb8": _gpt2_350m_step,
+    # fwd + dq + dkv inside the layer scan: the default policy keeps the
+    # forward kernel's output and log-sum-exp, so remat does not rerun it
+    "gpt2-350m-loss-grad-mb8": _gpt2_350m_step(3),
+    # fwd + remat'd fwd + dq + dkv
+    "gpt2-350m-loss-grad-mb8-nothing-saveable": _gpt2_350m_step(
+        4, remat_policy="nothing_saveable"),
 }
 
 
+@pytest.fixture(scope="module")
+def compiled(topo):
+    """case -> (lowered, compiled, kernels expected), each compiled once."""
+    @functools.cache
+    def of(case):
+        lowered, n_kernels = CASES[case](topo)
+        return lowered, lowered.compile(), n_kernels  # raises what the chip's compiler would
+    return of
+
+
 @pytest.mark.parametrize("case", list(CASES))
-def test_compiles_for_v5e(topo, case):
-    lowered, n_kernels = CASES[case](topo)
+def test_compiles_for_v5e(compiled, case):
+    lowered, executable, n_kernels = compiled(case)
     # the Mosaic kernel itself was lowered, not the interpreter's loops
     assert lowered.as_text().count("tpu_custom_call") >= n_kernels
-    mem = lowered.compile().memory_analysis()  # raises what the chip's compiler would
+    mem = executable.memory_analysis()
     resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert resident < HBM_BYTES, (case, resident)
+
+
+def test_layer_checkpoint_keeps_the_flash_residuals_dense(compiled):
+    """What the default remat policy saves of the flash forward, stacked by
+    the layer scan: the output as the model's (B, S, D) and the log-sum-exp
+    as (B, H, S). The kernel's own (B, H, S, 64) and (B, H, S, 1) would be
+    padded to 128 lanes (twice the bytes) and to whole (8, 128) tiles (128
+    times). One Mosaic call fewer than ``nothing_saveable``, for one
+    (24, 8, 1024, 1024) bfloat16 array more — which the compile holds twice."""
+    _, kept, _ = compiled("gpt2-350m-loss-grad-mb8")
+    _, recomputed, _ = compiled("gpt2-350m-loss-grad-mb8-nothing-saveable")
+    text = kept.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert recomputed.as_text().count('custom_call_target="tpu_custom_call"') == 4
+    assert "bf16[24,8,1024,1024]" in text and "f32[24,8,16,1024]" in text
+    assert "[24,8,16,1024,64]" not in text and "[24,8,16,1024,1]" not in text
+    grown = (kept.memory_analysis().temp_size_in_bytes
+             - recomputed.memory_analysis().temp_size_in_bytes)
+    assert 0 < grown <= 0.9e9, grown
 
 
 @pytest.mark.parametrize("preset,slots,read_len,chunk,by_blocks", [

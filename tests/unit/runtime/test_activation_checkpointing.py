@@ -85,8 +85,17 @@ class TestConfigure:
         assert ac._CONFIG.partition_activations
 
     def test_policy_resolution(self):
-        for name in ("nothing_saveable", "dots_saveable", "dots_with_no_batch_dims", "full"):
+        for name in ("nothing_saveable", "flash_saveable", "dots_saveable",
+                     "dots_with_no_batch_dims", "full"):
             assert ac.resolve_policy(name) is not None
+
+    def test_the_model_default_is_a_known_policy(self):
+        from deepspeed_tpu.models.transformer import TransformerConfig
+
+        assert TransformerConfig().remat_policy == "flash_saveable"
+        assert TransformerConfig().remat_policy in ac.POLICIES
+        # the default of checkpoint_wrapper for a user's own function stays
+        assert ac.CheckpointConfig().policy == "nothing_saveable"
 
     def test_offload_policy(self):
         pol = ac.resolve_policy("offload")
@@ -154,6 +163,87 @@ class TestModelIntegration:
         g_remat = jax.grad(lambda p: m_remat.loss(p, batch, None))(params)
         for a, b in zip(jax.tree.leaves(g_plain), jax.tree.leaves(g_remat)):
             np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-6)
+
+
+def _pallas_calls(jaxpr, name):
+    """How many ``pallas_call`` equations of that kernel name a jaxpr holds,
+    bodies of scans, checkpoints and shard_maps included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += eqn.params["name"] == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _pallas_calls(sub, name)
+    return n
+
+
+class TestFlashResidualsSaved:
+    """The default policy keeps the flash forward kernel's output and
+    log-sum-exp (``flash_attention.RESIDUAL_NAMES``), so the backward pass of
+    a checkpointed layer does not run the kernel a second time; the bits are
+    the ones the recomputation would produce."""
+
+    BASE = dict(vocab_size=64, hidden_size=32, num_layers=3, num_heads=2, max_seq_len=64)
+
+    def _grad_fn(self, batch, **kw):
+        from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+
+        model = TransformerModel(TransformerConfig(**{**self.BASE, "remat": True, **kw}))
+        return model, jax.grad(lambda p: model.loss(p, batch, None))
+
+    def _batch(self, rows=2):
+        toks = np.random.RandomState(0).randint(0, 64, (rows, 64)).astype(np.int32)
+        return {"input_ids": jnp.asarray(toks)}
+
+    @pytest.mark.parametrize("scan_layers", [True, False])
+    def test_one_flash_forward_a_layer_body(self, scan_layers):
+        """ONE ``flash_fwd`` a layer body in the gradient's jaxpr (the scan
+        has one body, the unrolled stack one a layer) where
+        ``nothing_saveable`` holds two, and the same gradients to the bit."""
+        batch = self._batch()
+        bodies = 1 if scan_layers else self.BASE["num_layers"]
+        grads = {}
+        for policy, fwd_calls in (("flash_saveable", 1), ("nothing_saveable", 2)):
+            kw = {} if policy == "flash_saveable" else {"remat_policy": policy}  # the default
+            model, grad = self._grad_fn(batch, attn_impl="pallas", scan_layers=scan_layers, **kw)
+            params = model.init(jax.random.PRNGKey(0))
+            jaxpr = jax.make_jaxpr(grad)(params).jaxpr
+            assert _pallas_calls(jaxpr, "flash_fwd") == fwd_calls * bodies, policy
+            assert _pallas_calls(jaxpr, "flash_bwd_dq") == bodies
+            assert _pallas_calls(jaxpr, "flash_bwd_dkv") == bodies
+            grads[policy] = jax.jit(grad)(params)
+        for a, b in zip(*map(jax.tree.leaves, grads.values())):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_one_flash_forward_under_shard_map(self):
+        """On a four-device ``fsdp`` mesh the kernel runs under ``shard_map``
+        (``transformer._flash_sharded``): the policy reaches its body."""
+        from deepspeed_tpu import comm
+
+        comm.destroy()
+        comm.set_mesh(comm.build_mesh({"fsdp": 4}, devices=jax.devices()[:4]))
+        batch = self._batch(rows=4)
+        grads = []
+        for policy, fwd_calls in (("flash_saveable", 1), ("nothing_saveable", 2)):
+            model, grad = self._grad_fn(batch, attn_impl="pallas", remat_policy=policy)
+            params = model.init(jax.random.PRNGKey(0))
+            jaxpr = jax.make_jaxpr(grad)(params)
+            assert "shard_map" in str(jaxpr)
+            assert _pallas_calls(jaxpr.jaxpr, "flash_fwd") == fwd_calls, policy
+            grads.append(jax.jit(grad)(params))
+        for a, b in zip(*map(jax.tree.leaves, grads)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_without_the_kernel_it_is_nothing_saveable(self):
+        """``attn_impl="xla"`` carries no name: the default lowers to the
+        text ``nothing_saveable`` lowers to."""
+        batch = self._batch()
+        texts = []
+        for policy in ("flash_saveable", "nothing_saveable"):
+            model, grad = self._grad_fn(batch, attn_impl="xla", remat_policy=policy)
+            params = model.init(jax.random.PRNGKey(0))
+            texts.append(jax.jit(grad).lower(params).as_text())
+        assert texts[0] == texts[1]
 
 
 class TestPartitionActivations:
