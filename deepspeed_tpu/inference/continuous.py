@@ -98,6 +98,7 @@ from deepspeed_tpu.inference.decoding import (
     read_bucket,
     TICK_STATS,
 )
+from deepspeed_tpu.ops.pallas.mla_attention import expanded_entries
 from deepspeed_tpu.ops.transformer import kv_cache
 from deepspeed_tpu.telemetry.spans import host_span
 
@@ -352,6 +353,9 @@ class ContinuousBatchingEngine:
         # ... with delta-rule layers: a state pool, reset when a row is
         # admitted, and two counters more among the routing counters
         self._state_pool = kv_cache.state_spec(self.cfg) is not None
+        # ... with latent-attention layers: a latent pool, whose rows' read
+        # and chunks' expansion the host counts from each tick's own lengths
+        self._latent_pool = any(s.name == kv_cache.LATENT for s in kv_cache.specs(self.cfg))
         self.eos_token_id = eos_token_id
         self.temperature, self.top_k, self.top_p = temperature, top_k, top_p
         assert tokens_per_tick >= 1, tokens_per_tick
@@ -388,8 +392,11 @@ class ContinuousBatchingEngine:
             raise NotImplementedError(
                 "a layer-plan model is served by single-token ticks with "
                 "fused prefill chunks (no speculative pool ticks, bursts or "
-                "separate prefill: a burst or a verify round would have to "
-                "roll a state pool's recurrent state back)")
+                "separate prefill: " + (
+                    "a burst or a verify round would have to roll a state "
+                    "pool's recurrent state back" if self._state_pool else
+                    "the plan's tick is written for one token a row and one "
+                    "chunk") + ")")
         self.spec_gamma = 0
         self.spec_mode = None
         self._draft_eng = None
@@ -509,6 +516,13 @@ class ContinuousBatchingEngine:
             # as the ticks report them (layer_plan.GDN_STATS): real tokens
             # the chunks' scans took, rows whose state a tick stepped
             self._tick_stats.update(gdn_chunk_tokens=0, gdn_step_rows=0)
+        if self._latent_pool:
+            # cached entries the rows' kernel read (each live row to its own
+            # length, a latent layer; summed over rows and ticks), and
+            # entries a chunk expanded into heads again (its row up to the
+            # end of the key tile that holds the chunk's last key, a latent
+            # layer: ``mla_attention.expanded_entries``)
+            self._tick_stats.update(mla_row_keys=0, mla_expand_tokens=0)
         # cancelled rids, remembered so status()/result() answer precisely
         # instead of "unknown" — BOUNDED (oldest evicted past 4096): a
         # long-running server cancels routinely and must not leak an int
@@ -593,8 +607,9 @@ class ContinuousBatchingEngine:
     def kv_pool_bytes(self) -> Dict[str, int]:
         """``kv_cache_bytes()`` by kind of pool (``kv_cache.specs``): a
         layer plan keeps a full-length pool and a ring of ``window``
-        positions ({"full": ..., "window": ...}) and, with delta-rule
-        layers, the state pool ("state"); a model of one kind has {"kv"}."""
+        positions ({"full": ..., "window": ...}), with delta-rule layers
+        the state pool ("state") and with latent-attention layers the
+        latent pool ("latent"); a model of one kind has {"kv"}."""
         out: Dict[str, int] = {}
         for p in self._pools:
             for name, nbytes in kv_cache.pool_bytes(self.cfg, p.cache).items():
@@ -786,8 +801,9 @@ class ContinuousBatchingEngine:
         if self.cfg.layer_kinds is not None:
             raise NotImplementedError(
                 "prefix registration splices one pool of one length; a "
-                "layer plan's pools have no splice yet, and a state pool's "
-                "recurrent state would need a snapshot at the prefix's end")
+                "layer plan's pools have no splice yet" + (
+                    ", and a state pool's recurrent state would need a "
+                    "snapshot at the prefix's end" if self._state_pool else ""))
         prefix = np.asarray(prefix_ids, np.int32).reshape(-1)
         if prefix.size == 0:
             raise ValueError("empty prefix")
@@ -980,6 +996,8 @@ class ContinuousBatchingEngine:
                 s["kv_pool_bytes_" + name] = nbytes
         if self._state_pool:
             s["state_pool_bytes"] = s["kv_pool_bytes_state"]
+        if self._latent_pool:
+            s["latent_pool_bytes"] = s["kv_pool_bytes_" + kv_cache.LATENT]
         return s
 
     def _place(self, req: _Request) -> Optional[tuple]:
@@ -1236,6 +1254,8 @@ class ContinuousBatchingEngine:
                 st["prefill_pairs_window"] += int(np.minimum(
                     np.arange(cpos0 + 1, cpos0 + nreal + 1), self._window).sum())
                 st["prefill_keys_full"] += cpos0 + nreal
+                if self._latent_pool:
+                    st["mla_expand_tokens"] += expanded_entries(cpos0 + W, read_len or pool.length)
             chunk_toks = np.zeros(W, np.int32)
             chunk_toks[:nreal] = ctoks
             chunk_pos = np.full(W, pool.length, np.int32)
@@ -1278,6 +1298,8 @@ class ContinuousBatchingEngine:
             advance = k
         self._tick_stats["block_write_ticks"] += kv_cache.rows_write_by_blocks(
             self.cfg, pool.cache, read_len, self.mesh)
+        if self._latent_pool:  # a row that is not parked attends its cached entries and the one it writes
+            self._tick_stats["mla_row_keys"] += int((pos[pos < pool.length] + 1).sum())
         # advance the dispatch mirrors for the decode rows (the admitting
         # row's were set above); quota-clamped so a burst tail never
         # over-advances a row the host can predict finishing

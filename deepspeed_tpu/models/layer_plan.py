@@ -30,6 +30,13 @@ to the chunk's own keys, then writes its last ``window`` tokens. A
 delta-rule layer keeps no keys: its rows live in ``cache["state"]``, a
 recurrent state and the tail of its convolution a row, which the rows' one
 token steps in place and a chunk scans from (``ops/pallas/gated_delta.py``).
+A latent-attention layer (MLA) keeps ONE vector a token in ``cache["latent"]``,
+its normed latent and the rotated key all heads share, which is the keys and
+the values of every head: the rows' one token attends it in the ABSORBED form
+(``W_UK`` folded into the query, ``W_UV`` taken out of the output, the pool
+read in place by ``ops/pallas/mla_attention.py``), a chunk in the EXPANDED
+form (its row's latents up to the chunk's end through ``W_UKV`` into heads,
+then the flash chunk kernel) - two forms of one function.
 
 Three entry points: :func:`forward_plan` (no cache: training, the reference
 comparison), :func:`forward_plan_cached` (the serving tick: every slot's
@@ -77,9 +84,16 @@ def check_plan(cfg):
         if len(shapes) > 1:
             raise ValueError(f"kinds of the {pool} pool differ in key-value heads or window: "
                              f"{sorted(shapes)}")
-    if not any(k.pool == "full" for k in cfg.plan):
-        raise ValueError("a layer plan needs a full-attention layer: the slot manager reads "
-                         "a row's length off the full pool")
+    if not any(k.pool in ("full", kv_cache.LATENT) for k in cfg.plan):
+        raise ValueError("a layer plan needs a full-attention layer or a latent-attention layer: "
+                         "the slot manager reads a row's length off the full or the latent pool")
+    if any(k.mixer == "mla" for k in kinds):
+        sizes = (cfg.mla_q_rank, cfg.mla_kv_rank, cfg.mla_nope_dim, cfg.mla_rope_dim, cfg.mla_v_dim)
+        if (min(sizes) < 1 or cfg.mla_rope_dim % 2 or cfg.head_dim != cfg.mla_nope_dim + cfg.mla_rope_dim
+                or cfg.v_head_dim != cfg.mla_v_dim):
+            raise ValueError(f"latent-attention kinds need the five mla sizes {sizes}, an even rotary "
+                             f"width, head_dim = unrotated + rotated width ({cfg.head_dim}) and "
+                             f"v_head_dim = the value width ({cfg.v_head_dim})")
     if any(k.pool == "state" for k in kinds):
         # the state pool's shape is the configuration's, so its kinds agree in it
         sizes = (cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim)
@@ -90,7 +104,7 @@ def check_plan(cfg):
     if cfg.moe_score not in ("sigmoid", "softmax"):
         raise ValueError(f"moe_score {cfg.moe_score!r}")
     for k in kinds:
-        if k.mixer not in ("attention", "gdn"):
+        if k.mixer not in ("attention", "gdn", "mla"):
             raise ValueError(f"kind {k.name}: mixer {k.mixer!r}")
         if k.mixer == "attention" and cfg.num_heads % k.kv_heads:
             raise ValueError(f"kind {k.name}: {cfg.num_heads} heads over {k.kv_heads} kv heads")
@@ -150,6 +164,17 @@ def _layer_shapes(cfg, kind):
             ("gdn", "norm"): ((gv,), None),
             ("gdn", "wo"): ((Hv * gv, D), out_scale / math.sqrt(Hv * gv)),
         })
+    elif kind.mixer == "mla":
+        qr, kr, dn, dr = cfg.mla_q_rank, cfg.mla_kv_rank, cfg.mla_nope_dim, cfg.mla_rope_dim
+        shapes.update({
+            ("mla", "wdq"): ((D, qr), 1 / math.sqrt(D)),
+            ("mla", "q_norm"): ((qr,), norm),
+            ("mla", "wuq"): ((qr, nh * (dn + dr)), 1 / math.sqrt(qr)),      # a head: [unrotated | rotated]
+            ("mla", "wdkv"): ((D, kr + dr), 1 / math.sqrt(D)),              # [latent | the shared key]
+            ("mla", "kv_norm"): ((kr,), norm),
+            ("mla", "wukv"): ((kr, nh * (dn + dv)), 1 / math.sqrt(kr)),     # a head: [key | value]
+            ("mla", "wo"): ((nh * dv, D), out_scale / math.sqrt(nh * dv)),
+        })
     else:
         shapes.update({
             ("attn", "wq"): ((D, nh * dk), 1 / math.sqrt(D)),
@@ -181,8 +206,9 @@ def _layer_shapes(cfg, kind):
                 ("mlp", "shared_wg"): ((D, Fs), 1 / math.sqrt(D)),
                 ("mlp", "shared_wi"): ((D, Fs), 1 / math.sqrt(D)),
                 ("mlp", "shared_wo"): ((Fs, D), out_scale / math.sqrt(Fs)),
-                ("mlp", "shared_gate"): ((D, 1), 1 / math.sqrt(D)),
             })
+            if cfg.moe_shared_gated:
+                shapes[("mlp", "shared_gate")] = ((D, 1), 1 / math.sqrt(D))
     else:
         shapes.update({
             ("mlp", "wg"): ((D, F), 1 / math.sqrt(D)),
@@ -302,7 +328,7 @@ def _ffn(h, mlp_p, kind, cfg, valid, grad):
 
     first, count = cfg.held_experts
     chosen, weights = he.route(h, mlp_p["gate"], mlp_p.get("gate_bias"), cfg.moe_top_k,
-                               cfg.moe_score)
+                               cfg.moe_score, scale=cfg.moe_routed_scale)
     out, counts = he.held_experts_ffn(
         h, chosen, weights, {n: mlp_p[n] for n in _EXPERT_LEAVES}, first, count,
         grad=grad, valid=valid, layer=mlp_p.get("layer"))
@@ -310,8 +336,11 @@ def _ffn(h, mlp_p, kind, cfg, valid, grad):
         with jax.named_scope(Scope.MOE_SHARED):
             act = (jax.nn.silu(tf._linear(h, mlp_p["shared_wg"]))
                    * tf._linear(h, mlp_p["shared_wi"]))
-            gate = jax.nn.sigmoid(tf._linear(h, mlp_p["shared_gate"]).astype(jnp.float32))
-            out = out + (tf._linear(act, mlp_p["shared_wo"]) * gate).astype(out.dtype)
+            if cfg.moe_shared_gated:
+                gate = jax.nn.sigmoid(tf._linear(h, mlp_p["shared_gate"]).astype(jnp.float32))
+                out = out + (tf._linear(act, mlp_p["shared_wo"]) * gate).astype(out.dtype)
+            else:
+                out = out + tf._linear(act, mlp_p["shared_wo"]).astype(out.dtype)
     made = (h.shape[0] if valid is None else valid.sum(dtype=jnp.int32)) * cfg.moe_top_k
     return out, jnp.stack([jnp.asarray(made, jnp.int32), counts.sum(dtype=jnp.int32),
                            counts.max().astype(jnp.int32), jnp.int32(1),
@@ -428,6 +457,127 @@ def _gdn_cached(h, p, cfg, pool, layer, B, chunk, valid):
         return _gdn_out(o, z, p, cfg), pool
 
 
+# -- the latent-attention mixer (MLA; ops/pallas/mla_attention.py reads the pool for the rows) --
+
+def _stored(x, cfg):
+    """x (..., latent + rotated width) zero-padded along its last axis to
+    the columns the pool stores a token in (``kv_cache.latent_width``)."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, kv_cache.latent_width(cfg) - x.shape[-1])])
+
+
+def _mla_project(h, p, kind, cfg, positions):
+    """h (N, D), positions (N,) -> (q (N, nh, dn + dr) with its rotary part
+    turned, tok (N, stored width): each token's cache entry [normed latent |
+    rotated shared key | zeros], as ``kv_cache.latent_width`` lays it out)."""
+    tf = _tf()
+    N = h.shape[0]
+    kr, dn, dr = cfg.mla_kv_rank, cfg.mla_nope_dim, cfg.mla_rope_dim
+    turn = lambda a, at=positions: tf._rope(a, at[None], kind.rope_theta, None,
+                                            cfg.rope_interleaved)[0]
+    with jax.named_scope(Scope.MLA_Q):
+        cq = tf._norm(tf._linear(h, p["wdq"]), p["q_norm"], None, cfg)
+        q = tf._linear(cq, p["wuq"]).reshape(N, cfg.num_heads, dn + dr)
+        q = jnp.concatenate([q[..., :dn], turn(q[None, ..., dn:])], axis=-1)
+    with jax.named_scope(Scope.MLA_LATENT):
+        ckv = tf._linear(h, p["wdkv"])
+        c = tf._norm(ckv[:, :kr], p["kv_norm"], None, cfg)
+        r = turn(ckv[None, :, None, kr:])[:, 0]
+        tok = _stored(jnp.concatenate([c, r], axis=-1), cfg)
+    return q, tok
+
+
+def _mla_up(p, cfg):
+    """W_UKV by head: (W_UK (kr, nh, dn), W_UV (kr, nh, dv))."""
+    w = p["wukv"].reshape(cfg.mla_kv_rank, cfg.num_heads, cfg.mla_nope_dim + cfg.mla_v_dim)
+    return w[..., :cfg.mla_nope_dim], w[..., cfg.mla_nope_dim:]
+
+
+def _mla_expand(toks, p, cfg):
+    """Cached entries toks (T, stored width) -> every head's keys (nh, T, dn
+    + dr) and values (nh, T, dv): the latent through W_UKV, the one rotated
+    key repeated to each head."""
+    kr, dr = cfg.mla_kv_rank, cfg.mla_rope_dim
+    wuk, wuv = _mla_up(p, cfg)
+    with jax.named_scope(Scope.MLA_EXPAND):
+        c, r = toks[:, :kr], toks[:, kr:kr + dr]
+        k = jnp.einsum("tk,khd->htd", c, wuk)
+        k = jnp.concatenate([k, jnp.broadcast_to(r[None], (cfg.num_heads,) + r.shape)], axis=-1)
+        return k, jnp.einsum("tk,khd->htd", c, wuv)
+
+
+def _mla_absorb(q, p, cfg):
+    """q (N, nh, dn + dr) -> (N, nh, stored width): W_UK folded into the
+    unrotated part, the rotated part beside it, zeros where the pool's
+    entries have zeros: a head's score against a cached entry is their dot
+    product."""
+    dn = cfg.mla_nope_dim
+    qa = jnp.einsum("nhd,khd->nhk", q[..., :dn], _mla_up(p, cfg)[0])
+    return _stored(jnp.concatenate([qa.astype(q.dtype), q[..., dn:]], axis=-1), cfg)
+
+
+def _mla_unabsorb(u, p, cfg):
+    """u (N, nh, >= kr): a head's average of cached entries -> its output (N, nh, dv)."""
+    return jnp.einsum("nhk,khd->nhd", u[..., :cfg.mla_kv_rank], _mla_up(p, cfg)[1])
+
+
+def _mla_plain(h, p, kind, cfg, B, S, positions):
+    """The mixer over whole sequences, expanded form: h (B * S, D) -> (B * S, D)."""
+    tf = _tf()
+    with jax.named_scope(Scope.MIX_MLA):
+        q, tok = _mla_project(h, p, kind, cfg, positions)
+        k, v = jax.vmap(lambda t: _mla_expand(t, p, cfg))(tok.reshape(B, S, -1))
+        pos = jnp.arange(S, dtype=jnp.int32)
+        with jax.named_scope(Scope.ATTN_LATENT):
+            att = _grouped_attention(q.reshape(B, S, *q.shape[1:]), k, v,
+                                     (pos[None, :] <= pos[:, None])[None], None, _scale(cfg))
+        return tf._attn_out_proj(att.reshape(B * S, -1), p, cfg)
+
+
+def _mla_cached(h, p, kind, cfg, pool, layer, pos, all_pos, chunk, read_len, length):
+    """The mixer of one layer of the tick: h (N, D) holds the B rows' single
+    tokens, then the chunk's W. Every token's entry is written; the rows
+    attend their pool rows in the absorbed form, each to its own length (a
+    parked row and an empty slot read nothing); the chunk attends its row in
+    the expanded form: the row's entries up to the chunk's end through
+    ``W_UKV`` into heads, then the flash chunk kernel. Returns ((N, D), the
+    latent pool's leaf)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_chunk
+    from deepspeed_tpu.ops.pallas import mla_attention
+
+    tf = _tf()
+    B = pos.shape[0]
+    size, scale = read_len or length, _scale(cfg)
+    with jax.named_scope(Scope.MIX_MLA):
+        q, tok = _mla_project(h, p, kind, cfg, all_pos)
+        with jax.named_scope(Scope.ATTN_KV_WRITE):
+            pool = _write_rows(pool, layer, tok[:B, None], pos, size)
+        with jax.named_scope(Scope.MLA_ABSORB):
+            qa = _mla_absorb(q[:B], p, cfg)
+        with jax.named_scope(Scope.ATTN_LATENT):
+            u = mla_attention.mla_decode(qa, pool, layer, jnp.where(pos < length, pos + 1, 0),
+                                         size=size, sm_scale=scale)
+        with jax.named_scope(Scope.MLA_ABSORB):
+            att = _mla_unabsorb(u, p, cfg).reshape(B, -1)
+        if chunk is not None:
+            W = q.shape[0] - B
+            real, first = chunk.pos < length, chunk.pos[0]
+            width = min(W, size)
+            with jax.named_scope(Scope.ATTN_KV_WRITE):
+                start = jnp.clip(first, 0, size - width)
+                cols = jnp.where(real, chunk.pos - start, width)
+                pool = _write(pool, layer, tok[B:, None], cols, width, slot=chunk.slot, start=start)
+            with jax.named_scope(Scope.ATTN_KV_READ):
+                row = _window(pool, layer, size, slot=chunk.slot)[0]               # (size, stored width)
+            with jax.named_scope(Scope.MLA_EXPAND):
+                wuk, wuv = (jnp.transpose(w, (1, 0, 2)) for w in _mla_up(p, cfg))
+                k, v = mla_attention.mla_expand(row, wuk, wuv, first + W, rank=cfg.mla_kv_rank,
+                                                rope=cfg.mla_rope_dim)
+            with jax.named_scope(Scope.ATTN_LATENT):
+                out = flash_attention_chunk(q[B:], k, v, q_off=first, sm_scale=scale)
+            att = jnp.concatenate([att, out.reshape(W, -1)])
+        return tf._attn_out_proj(att, p, cfg), pool
+
+
 GDN_STATS = 2   # beside the routing counters: real tokens the chunk's scan took, rows stepped
 
 
@@ -511,6 +661,8 @@ def forward_plan(params, cfg, tokens, return_hidden=False):
     def mix(h, layer_p, kind):
         if kind.mixer == "gdn":
             return _gdn_plain(h, layer_p["gdn"], cfg, B, S)
+        if kind.mixer == "mla":
+            return _mla_plain(h, layer_p["mla"], kind, cfg, B, S, positions)
         q, k, v = _project(h, layer_p["attn"], kind, cfg, positions)
         ok = kpos <= qpos
         if kind.window:
@@ -648,6 +800,10 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
         h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg)
         if kind.mixer == "gdn":
             out, pool = _gdn_cached(h, layer_p["gdn"], cfg, pool, pool_index, B, chunk, valid)
+        elif kind.mixer == "mla":
+            out, leaf = _mla_cached(h, layer_p["mla"], kind, cfg, pool["c"], pool_index, pos,
+                                    all_pos, chunk, read_len, length)
+            pool = {"c": leaf}
         else:
             q, k, v = _project(h, layer_p["attn"], kind, cfg, all_pos)
             att, pk, pv = _attend_cached(q, k, v, layer_p["attn"], kind, cfg, pool["k"], pool["v"],

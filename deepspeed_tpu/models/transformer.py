@@ -37,11 +37,12 @@ from deepspeed_tpu.telemetry.hlo_scopes import Scope
 @dataclass(frozen=True)
 class LayerKind:
     """One kind of decoder layer in a layer plan: its mixer (softmax
-    attention of some shape, or the gated delta rule, whose shape is the
-    configuration's ``gdn_*``) and the kind of its FFN. Layers of one kind
-    share parameter shapes and are stacked together
-    (``params["layers"][name]``); attention layers of the same reach
-    (``window`` 0 or not) share a KV pool, delta-rule layers the state pool."""
+    attention of some shape, the gated delta rule, whose shape is the
+    configuration's ``gdn_*``, or latent attention, whose shape is its
+    ``mla_*``) and the kind of its FFN. Layers of one kind share parameter
+    shapes and are stacked together (``params["layers"][name]``); attention
+    layers of the same reach (``window`` 0 or not) share a KV pool,
+    delta-rule layers the state pool, latent layers the latent pool."""
     name: str
     kv_heads: int = 1  # of an attention mixer
     window: int = 0  # 0 = full causal attention; W = the last W positions
@@ -49,13 +50,17 @@ class LayerKind:
     sink: bool = False  # a learned per-head logit joins the softmax's denominator
     ffn: str = "dense"  # dense | moe (sigmoid top-k over the experts held)
     ffn_size: Optional[int] = None  # None => cfg.ffn_size
-    mixer: str = "attention"  # attention | gdn (Gated DeltaNet: recurrent state, no keys kept)
+    # attention | gdn (Gated DeltaNet: recurrent state, no keys kept) | mla (latent
+    # attention: one latent and one rotated key a token, shared by every head)
+    mixer: str = "attention"
 
     @property
     def pool(self) -> str:
         """The cache pool this kind's layers live in."""
         if self.mixer == "gdn":
             return "state"
+        if self.mixer == "mla":
+            return "latent"
         return "window" if self.window > 0 else "full"
 
 
@@ -166,7 +171,9 @@ class TransformerConfig:
     # [first, first + count) and computes their part of the result
     moe_experts_held: Optional[tuple] = None  # (first, count); None => all
     moe_score: str = "sigmoid"  # sigmoid (+ selection bias) | softmax over all experts, no bias
-    moe_shared_size: int = 0  # width of a shared SwiGLU expert behind a sigmoid gate; 0 = none
+    moe_shared_size: int = 0  # width of a shared SwiGLU expert; 0 = none
+    moe_shared_gated: bool = True  # ... behind a sigmoid gate (False: added as it is)
+    moe_routed_scale: float = 1.0  # the normalised top-k weights times this (routed_scaling_factor)
     # a plan's attention layers: sigmoid(gate) on the attention output (the gate rides wq's
     # projection as a leaf of its own), RMSNorm over each query and key head
     attn_out_gate: bool = False
@@ -180,6 +187,15 @@ class TransformerConfig:
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
     gdn_conv: int = 4
+    # Latent attention mixer (LayerKind.mixer == "mla", DeepSeek-V2's MLA): the
+    # queries' low rank, the latent a token keeps (its keys' and values' low
+    # rank), each head's unrotated and rotated query/key widths and its value
+    # width; the cache is the latent and ONE rotated key a token
+    mla_q_rank: int = 0
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_rope_dim: int = 0
+    mla_v_dim: int = 0
     # leaves made in the model dtype at init (a model whose float32 leaves
     # would not fit beside their cast copy)
     init_in_model_dtype: bool = False

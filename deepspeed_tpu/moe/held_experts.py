@@ -5,7 +5,8 @@ with a sigmoid, a token taking the ``k`` experts whose score plus selection
 bias is largest (the bias decides the choice and nothing else: "noaux_tc",
 DeepSeek-V3's auxiliary-loss-free balancing), or with a softmax over all
 ``E``, a token taking the ``k`` most probable; either way it weighs them by
-their scores normalised over the chosen ``k``. This chip holds the contiguous
+their scores normalised over the chosen ``k`` (times a routed scaling
+factor, where the model has one). This chip holds the contiguous
 experts ``[first, first + count)`` and computes their part of the result:
 
     y = sum over chosen experts e that are HELD of w_e * Expert_e(h)
@@ -41,12 +42,13 @@ class Layout(NamedTuple):
 
 
 @jax.named_scope(Scope.MOE_ROUTE)
-def route(h, gate_w, gate_bias, k: int, score: str = "sigmoid"):
+def route(h, gate_w, gate_bias, k: int, score: str = "sigmoid", scale: float = 1.0):
     """h (N, D) -> (chosen experts (N, k) int32, their weights (N, k)
     float32). Scores in float32 whatever the dtype the weights are stored
     in: ``score`` "sigmoid", chosen by score + ``gate_bias``, or "softmax"
     over ALL the experts (``gate_bias`` None: the k largest probabilities);
-    either way the chosen scores are normalised over the chosen k."""
+    either way the chosen scores are normalised over the chosen k, and then
+    multiplied by ``scale`` (a model's routed scaling factor)."""
     logits = jnp.dot(h.astype(jnp.float32), gate_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if score == "softmax":
@@ -56,7 +58,8 @@ def route(h, gate_w, gate_bias, k: int, score: str = "sigmoid"):
         scores = jax.nn.sigmoid(logits)
         _, chosen = jax.lax.top_k(scores + gate_bias.astype(jnp.float32), k)
     picked = jnp.take_along_axis(scores, chosen, axis=1)
-    return chosen.astype(jnp.int32), picked / picked.sum(axis=1, keepdims=True)
+    weights = picked / picked.sum(axis=1, keepdims=True)
+    return chosen.astype(jnp.int32), weights if scale == 1.0 else weights * scale
 
 
 def buffer_rows(n_tokens: int, k: int, count: int, tm: int) -> int:

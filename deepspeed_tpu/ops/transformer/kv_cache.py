@@ -13,6 +13,11 @@ B, heads, key width, value width)`` float32 and the last taps - 1 inputs of
 its convolution ``(L, B, taps - 1, channels)`` are read whole and rewritten
 whole every token, never grow, have no read bucket and, since nothing masks
 a stale state, are zeroed when a request takes the row (:func:`reset_row`).
+A plan's latent-attention layers keep the pool ``"latent": {"c"}``: ONE leaf
+``(L, B, 1, T, stored width)``, a token's latent and its one rotated key,
+which are the keys AND the values of every head (:func:`latent_width`); it
+has a time axis like any keyed pool and is written, read, grown and masked
+like one, as a pool of one head.
 
 Three parts: the spec (:func:`specs` chooses the order), what the host
 knows (init, length, bytes, sharding, growth, splice) and the device-side
@@ -35,11 +40,11 @@ from deepspeed_tpu.ops.pallas.interpret import resolve_interpret
 
 
 class PoolSpec(NamedTuple):
-    name: str              # "kv" (one kind of layer) / "full" / "window"
+    name: str              # "kv" (one kind of layer) / "full" / "window" / "latent"
     layers: int
     kv_heads: int
     k_width: int
-    v_width: int
+    v_width: int           # 0: a latent pool, whose one leaf "c" (k_width wide) is keys and values
     ring: Optional[int]    # slots of a ring (position p in slot p mod ring); None: the allocation
     heads_first: bool      # the order of a leaf's (time, heads) axes
     int8: bool             # {"q8", "s"} leaves
@@ -89,6 +94,25 @@ def state_spec(cfg) -> Optional[StateSpec]:
                      cfg.gdn_conv - 1, channels)
 
 
+LATENT = "latent"
+LANES = 128
+
+
+def latent_width(cfg) -> int:
+    """Stored columns of a latent pool's token: the latent (``mla_kv_rank``)
+    and the shared rotated key (``mla_rope_dim``) side by side, zero-padded
+    to whole 128-lane tiles. GLM-4.7-Flash: 512 + 64 = 576 -> 640, 1,280
+    bytes a token and layer in bfloat16 where the numbers themselves are
+    1,152. The chip holds no less either way it is cut: a leaf 576 wide is
+    padded to 640 lanes or kept time-minor, and of two leaves the 64-wide
+    one pads to 128; time-minor is an order the rows' kernel (``mla_decode``,
+    which wants a block of tokens as a (tokens, width) matrix) could only
+    read through a copy of the pool, and the block write chooses its path
+    by ``width % 128``. One leaf of whole tiles is one DMA a block, one
+    product for all the scores, and the write path of any other pool."""
+    return -(-(cfg.mla_kv_rank + cfg.mla_rope_dim) // LANES) * LANES
+
+
 def specs(cfg) -> Tuple[PoolSpec, ...]:
     """The pools of ``cfg``'s cache. The ONE place that chooses a layout,
     and it chooses as each model body was written. One kind of layer, time
@@ -109,6 +133,10 @@ def specs(cfg) -> Tuple[PoolSpec, ...]:
         if kind.pool == "state":   # no keys, no time axis: state_spec()
             continue
         n = pools[kind.pool].layers if kind.pool in pools else 0
+        if kind.pool == LATENT:   # one head, one leaf: latent_width()
+            pools[LATENT] = PoolSpec(LATENT, n + 1, 1, latent_width(cfg), 0, None,
+                                     heads_first=True, int8=False)
+            continue
         pools[kind.pool] = PoolSpec(kind.pool, n + 1, kind.kv_heads, cfg.head_dim,
                                     cfg.v_head_dim, kind.window or None,
                                     heads_first=True, int8=False)
@@ -125,7 +153,8 @@ def _build(cfg, leaf, state_leaf):
             return {"q8": leaf(spec, width, jnp.int8), "s": leaf(spec, 1, jnp.float32)}
         return leaf(spec, width, cfg.jnp_dtype)
 
-    pools = {s.name: {"k": component(s, s.k_width), "v": component(s, s.v_width)}
+    pools = {s.name: ({"k": component(s, s.k_width), "v": component(s, s.v_width)}
+                      if s.v_width else {"c": component(s, s.k_width)})
              for s in specs(cfg)}
     if state_spec(cfg) is not None:
         pools[StateSpec.name] = {name: state_leaf(name) for name in ("s", "conv")}
@@ -149,8 +178,9 @@ def init(cfg, batch_size: int, length: int):
 
 
 def alloc_len(cfg, cache) -> int:
-    """Allocated length of the time axis (of a plan's pools, the full pool's:
-    the slot manager reads a row's room off it)."""
+    """Allocated length of the time axis (of a plan's pools, the full pool's
+    or the latent pool's, which are as long as each other: the slot manager
+    reads a row's room off it)."""
     return next(jax.tree.leaves(sub)[0].shape[spec.time_axis]
                 for spec, sub in _pools(cfg, cache) if spec.ring is None)
 
@@ -210,7 +240,8 @@ def read_bytes_by_pool(cfg, read_len: int) -> dict:
     """{pool: HBM bytes ONE row's attention streams from it in a decode step
     that attends ``read_len`` slots}: K and V across its layers (a ring is
     read whole and no further), int8 as payload + a float32 scale a token
-    and head; the state pool is read whole whatever ``read_len`` is. What
+    and head; the state pool is read whole whatever ``read_len`` is; a
+    latent pool's one leaf at its stored width (:func:`latent_width`). What
     the compiled read touches, so tests assert it."""
     item = jnp.dtype(cfg.jnp_dtype).itemsize
     out = {StateSpec.name: state_bytes_per_row(cfg)} if state_spec(cfg) is not None else {}

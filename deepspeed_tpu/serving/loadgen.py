@@ -360,6 +360,15 @@ def host_overhead(tick_stats: dict) -> dict:
     ticks, blocks = tick_stats.get("ticks"), tick_stats.get("block_write_ticks")
     if blocks is not None:
         out["block_write_share"] = round(blocks / ticks, 4) if ticks else None
+    # a latent pool: its bytes, the cached entries a tick's rows read, the
+    # entries a fused tick's chunk expanded into heads again
+    if "latent_pool_bytes" in tick_stats:
+        fused = tick_stats.get("fused_prefill_ticks")
+        out["latent_pool_bytes"] = tick_stats["latent_pool_bytes"]
+        out["mla_row_keys_per_tick"] = (round(tick_stats["mla_row_keys"] / ticks, 1)
+                                        if ticks else None)
+        out["mla_expand_tokens_per_chunk"] = (round(tick_stats["mla_expand_tokens"] / fused, 1)
+                                              if fused else None)
     if "utilization" in tick_stats:
         out["tick_utilization"] = tick_stats["utilization"]
     if tick_stats.get("spec_gamma"):
@@ -622,6 +631,12 @@ def format_summary(summary: dict) -> str:
                    if host.get("prefill_pad_share") is not None else "")
                 + (f"   block writes {host['block_write_share']:.1%}"
                    if host.get("block_write_share") is not None else ""))
+        if host.get("latent_pool_bytes") is not None:
+            expanded = host.get("mla_expand_tokens_per_chunk")
+            lines.append(
+                f"latent pool    {host['latent_pool_bytes'] / 1e9:.3f} GB   rows read "
+                f"{host['mla_row_keys_per_tick']} entries/tick a layer"
+                + (f"   a chunk expands {expanded}" if expanded is not None else ""))
         if host.get("spec_gamma"):
             acc = host.get("spec_acceptance")
             per = (f"{acc * host['spec_gamma']:.2f}/{host['spec_gamma']}"

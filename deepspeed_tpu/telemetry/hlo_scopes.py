@@ -42,12 +42,18 @@ class Scope:
     ATTN_WINDOW = "attn.window"  # ... and its sliding-window core
     MOE_ROUTE = "moe.route"      # router scores, top-k, the sort by expert
     MOE_EXPERTS = "moe.experts"  # gather, grouped matmuls over the held experts, combine
-    MOE_SHARED = "moe.shared"    # the shared expert and its sigmoid gate
+    MOE_SHARED = "moe.shared"    # the shared expert (and its sigmoid gate, where it has one)
     ATTN_GATE = "attn.gate"      # the sigmoid gate on a plan's attention output
     MIX_GDN = "mix.gdn"          # a gated-delta-rule mixer: projections, gates, output norm
     GDN_CONV = "gdn.conv"        # ... its causal depthwise convolution
     GDN_SCAN = "gdn.scan"        # ... one prefill chunk's scan (the gdn_chunk_fwd kernel)
     GDN_STEP = "gdn.step"        # ... the rows' one-token state step
+    MIX_MLA = "mix.mla"          # a latent-attention mixer: everything between its two norms
+    MLA_Q = "mla.q"              # ... the queries: down, norm, up, the rotary part turned
+    MLA_LATENT = "mla.latent"    # ... the latent and the shared rotated key of each token
+    MLA_ABSORB = "mla.absorb"    # ... W_UK folded into the rows' queries, W_UV out of their output
+    MLA_EXPAND = "mla.expand"    # ... a row's latents through W_UKV into heads, for a chunk (mla_expand)
+    ATTN_LATENT = "attn.latent"  # ... attention over the latent pool (mla_decode, or a chunk's)
     NORM = "norm"
     LM_HEAD = "lm_head"
     LOSS = "loss"

@@ -74,9 +74,17 @@ def _clear_launcher_env(monkeypatch):
     ``os.environ`` for the script it execs; a test that drives it in-process
     leaves them in the xdist worker, and every later ``initialize`` on that
     worker then tries to join a coordinator that is not there. Cleared
-    before each test, so the suite does not depend on the order it runs in."""
-    for name in ("DSTPU_COORDINATOR", "DSTPU_NUM_PROCESSES", "DSTPU_PROCESS_ID"):
+    before each test, so the suite does not depend on the order it runs in,
+    and after it too: a module-scoped fixture of the NEXT file (``toy_tick``
+    of ``test_cache_in_carry.py``) is set up before that file's first
+    function-scoped clearing, and met the variables where the two files
+    shared a worker (two errors in one whole run of PR 38)."""
+    names = ("DSTPU_COORDINATOR", "DSTPU_NUM_PROCESSES", "DSTPU_PROCESS_ID")
+    for name in names:
         monkeypatch.delenv(name, raising=False)
+    yield
+    for name in names:
+        os.environ.pop(name, None)
 
 
 @pytest.fixture(autouse=True, scope="module")

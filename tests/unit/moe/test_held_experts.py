@@ -151,3 +151,16 @@ def test_the_xla_form_has_a_gradient(layer):
 
     g = jax.grad(loss)(layer["experts"])
     assert all(np.isfinite(np.asarray(v)).all() and float(jnp.abs(v).sum()) > 0 for v in g.values())
+
+
+@pytest.mark.parametrize("score", ["sigmoid", "softmax"])
+def test_a_routed_scaling_factor_multiplies_the_normalised_weights_and_nothing_else(layer, score):
+    bias = layer["bias"] if score == "sigmoid" else None
+    chosen, weights = he.route(layer["h"], layer["gate"], bias, K, score)
+    scaled_chosen, scaled = he.route(layer["h"], layer["gate"], bias, K, score, scale=1.8)
+    assert np.array_equal(chosen, scaled_chosen)
+    assert np.allclose(scaled, 1.8 * weights, rtol=1e-6) and np.allclose(scaled.sum(1), 1.8, rtol=1e-5)
+    # at 1.0 the function is the one it was: the same program, to the text
+    text = lambda **kw: jax.jit(lambda h, g: he.route(h, g, bias, K, score, **kw)).lower(
+        layer["h"], layer["gate"]).as_text()
+    assert text() == text(scale=1.0) != text(scale=1.8)

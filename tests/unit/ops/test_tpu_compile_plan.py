@@ -8,8 +8,12 @@ far under the pools; and what a state pool adds (PR 34): the chunked
 delta-rule scan and the rows' in-place state step at the published widths,
 flash at head width 256, and the Qwen3-Next serving tick at the benchmark's
 cut (``benchmark/configs/qwen3-next-80b-a3b.json``) with the key-value pool
-and the state pool both in place. Nothing runs. Skipped where libtpu cannot
-describe the topology."""
+and the state pool both in place; and what a latent pool adds (PR 38): the
+rows' absorbed attention over the pool in place (``mla_decode``) at 20 heads
+over 640 stored columns, and the GLM-4.7-Flash serving tick at the
+benchmark's cut (``benchmark/configs/glm-4.7-flash.json``) with the latent
+pool in place and the block write at width 640. Nothing runs. Skipped where
+libtpu cannot describe the topology."""
 
 import json
 import os
@@ -198,3 +202,72 @@ def test_qwen3_next_tick_updates_both_kinds_of_pool_in_place(topo, read_len, chu
     assert not re.findall(r"= f32\[(?:\d+,)?32,32,128,128\]\S* (?:copy|dynamic-slice)\(", text)
     assert not re.findall(r"= bf16\[3,2048,12288\]\S* slice\(", text)
     assert "gdn_step" in text and ("gdn_chunk_fwd" in text) == (chunk is not None)
+
+
+@pytest.mark.parametrize("size", [16896, 2048])
+def test_the_rows_latent_attention_reads_the_pool_in_place(topo, size):
+    from deepspeed_tpu.ops.pallas.mla_attention import mla_decode
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+    compiled = _compile(topo, lambda q, pool, layer, lengths: mla_decode(
+        q, pool, layer, lengths, size=size, sm_scale=1 / 16.0),
+        ((32, 20, 640), bf), ((6, 32, 1, 16896, 640), bf), ((), i32), ((32,), i32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 20e6      # no copy of the pool (4.15 GB)
+
+
+@pytest.mark.parametrize("size", [16896, 2048])
+def test_a_chunks_expansion_compiles_at_the_published_widths(topo, size):
+    from deepspeed_tpu.ops.pallas.mla_attention import mla_expand
+
+    bf = jnp.bfloat16
+    compiled = _compile(topo, lambda row, wuk, wuv, end: mla_expand(
+        row, wuk, wuv, end, rank=512, rope=64),
+        ((size, 640), bf), ((20, 512, 192), bf), ((20, 512, 256), bf), ((), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6       # the kernel alone, no relayout
+
+
+@pytest.mark.parametrize("read_len,chunk", [(None, None), (None, 1024), (2048, 256)],
+                         ids=["plain", "fused1024", "fused256-read2048"])
+def test_glm_tick_updates_the_latent_pool_in_place(topo, read_len, chunk):
+    from benchmark import models_glm4_moe_lite
+    from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
+    from deepspeed_tpu.models import transformer as tf
+
+    with open(os.path.join(ROOT, "benchmark", "configs", "glm-4.7-flash.json")) as fh:
+        config = json.load(fh)
+    slots, length = 32, 16896
+    model = models_glm4_moe_lite.build_model(config, max_seq_len=length, remat=False,
+                                             attn_impl="pallas")
+    cfg = model.cfg
+    mesh = comm.build_mesh({"data": 1, "tensor": 1}, devices=topo.devices[:1])
+    one = NamedSharding(mesh, PartitionSpec())
+    abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    p_sh = jax.tree.map(lambda a: one, abstract)
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one),
+                          abstract)
+    with force_interpret(False):
+        fn, cache_sh, _ = compile_pool_tick_fn(mesh, cfg, p_sh, slots, length, 1, 0.0, 0, 1.0,
+                                               read_len=read_len, chunk=chunk)
+        cache = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            jax.eval_shape(lambda: tf.init_cache(cfg, slots, length)), cache_sh)
+        row = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+        args = [params, cache, row, row, row, row, row, row, jax.ShapeDtypeStruct((2,), jnp.uint32)]
+        if chunk is not None:
+            wide = jax.ShapeDtypeStruct((chunk,), jnp.int32)
+            args += [wide, wide, jax.ShapeDtypeStruct((), jnp.int32), row, row]
+        compiled = fn.lower(*args).compile()
+    comm.destroy()
+    pool_bytes = sum(a.size * 2 for a in jax.tree.leaves(cache))
+    assert pool_bytes == 6 * 32 * 16896 * 640 * 2                    # 4.15 GB, not 66.4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 0.6e9, mem.temp_size_in_bytes    # 0.002 plain, 0.45 with a chunk
+    resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert resident < 15.75e9
+    text = compiled.as_text()
+    assert not re.findall(r"= bf16\[(?:\d+,)?32,1,16896,\d+\]\S* copy\(", text)
+    assert "mla_decode" in text and ("flash_chunk_fwd" in text) == (chunk is not None)
+    assert ("mla_expand" in text) == (chunk is not None)
+    assert "kv_block_write" in text                                  # 2,048 slots x 1,280 B a row: over the rule

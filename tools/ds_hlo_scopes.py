@@ -51,12 +51,12 @@ def _files(manifest_path, workload):
 def serve_programs(cell, config, chips, read_lens=None, chunks=None):
     """{variant: compiled} for the cell's tick family."""
     import deepspeed_tpu
-    from benchmark import models
+    from benchmark import compare
     from deepspeed_tpu.inference import ContinuousBatchingEngine
 
-    s = cell["serve"]
-    model = models.build_model(config, max_seq_len=s["cache_len"], remat=False,
-                               attn_impl=s["attn_impl"])
+    s = cell[cell["runner"]]   # a serving runner's group is named after it (serve, serve_routed, ...)
+    model = compare.builder_of(config).build_model(config, max_seq_len=s["cache_len"], remat=False,
+                                                   attn_impl=s["attn_impl"])
     ds_config = {"dtype": config["dtype"], "mesh": {"shape": {"data": 1, "tensor": chips}}}
     params = deepspeed_tpu.init_inference(model, config=ds_config).params
     eng = ContinuousBatchingEngine(model, config=ds_config, params=params,

@@ -18,15 +18,12 @@ planted fault is expected to give. ``--set path=value`` overrides a value of
 the cell's files (``config.compare.serve_routed.sample=2``).
 """
 
-import argparse
 import contextlib
-import json
 import os
 import sys
-import time
 
-T_PROCESS_START = time.perf_counter()
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import cell_variant  # noqa: E402  (its clock starts at import, as the harness wants)
 
 
 @contextlib.contextmanager
@@ -79,26 +76,8 @@ VARIANTS = dict(FAULTS, ragged_dot=ragged_dot)
 
 
 def main(argv=None, manifest=None, require_tpu=True):
-    from benchmark import harness
-
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--variant", required=True, choices=sorted(VARIANTS))
-    ap.add_argument("--workload", default="serve-mimo-v2.5-longdoc-batch")
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, default=40.0)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ap.add_argument("--set", action="append", default=[], metavar="PATH=VALUE")
-    args = ap.parse_args(argv)
-    manifest = manifest or os.path.join(harness.ROOT, "BENCHMARK.json")
-    listed = harness.load_json(manifest)
-    entry = next(w for w in listed["workloads"] if w["name"] == args.workload)
-    config_entry = next(c for c in listed["configs"] if c["name"] == entry["config"])
-    with VARIANTS[args.variant](harness.load_json(os.path.join(harness.ROOT, config_entry["file"]))):
-        line = harness.run_cell(manifest, args.workload, args.seed, args.seconds,
-                                bool(args.trace), require_tpu=require_tpu, overrides=args.set,
-                                t_process_start=T_PROCESS_START)
-    print(json.dumps(dict(line, variant=args.variant)), flush=True)
-    return line
+    return cell_variant.main(argv, manifest, require_tpu, variants=VARIANTS,
+                             workload="serve-mimo-v2.5-longdoc-batch", doc=__doc__)
 
 
 if __name__ == "__main__":
