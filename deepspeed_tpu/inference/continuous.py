@@ -496,7 +496,14 @@ class ContinuousBatchingEngine:
                             # ticks whose rows' one-token KV write took
                             # the block path (kv_cache.takes_block_write,
                             # told here from the tick's read bucket)
-                            "block_write_ticks": 0}
+                            "block_write_ticks": 0,
+                            # ticks whose rows' attention read each row to
+                            # its own length (kv_cache.takes_length_read),
+                            # the slots that kernel fetched (whole blocks a
+                            # live row, a layer and leaf) and the slots
+                            # those rows hold (read / live = the over-read)
+                            "length_read_ticks": 0, "row_keys_read": 0,
+                            "row_keys_live": 0}
         if self.cfg.layer_kinds is not None:
             # what those chunks' attention had to do: (query, key) pairs
             # attended in a full and in a window layer, keys a full layer read
@@ -976,7 +983,14 @@ class ContinuousBatchingEngine:
         admitted-but-not-yet-prefilled requests over all pools, read once
         per ``step()``. ``block_write_ticks``: ticks dispatched on a program
         whose rows' one-token KV write took the block path (the host tells
-        it from the tick's read bucket by ``kv_cache``'s own rule)."""
+        it from the tick's read bucket by ``kv_cache``'s own rule).
+        ``length_read_ticks``: ticks dispatched on a program whose rows'
+        attention reads each row to its own length (the same way, by
+        ``kv_cache.takes_length_read``); of those ticks ``row_keys_read``
+        is the cached slots the kernel fetched, whole 128-slot blocks a
+        live row (summed over rows and a burst's steps; a layer and leaf),
+        and ``row_keys_live`` the slots those rows attend: their ratio is
+        what reading by blocks costs over reading by tokens."""
         s = dict(self._tick_stats)
         s["pipeline_depth"] = self.pipeline_depth
         # NOT the tokens_per_tick knob (the burst width): the observed mean
@@ -1300,6 +1314,16 @@ class ContinuousBatchingEngine:
             self.cfg, pool.cache, read_len, self.mesh)
         if self._latent_pool:  # a row that is not parked attends its cached entries and the one it writes
             self._tick_stats["mla_row_keys"] += int((pos[pos < pool.length] + 1).sum())
+        if kv_cache.rows_read_to_length(self.cfg, pool.cache, read_len, self.mesh):
+            # each of the tick's steps, a live row attends what it holds and
+            # the token it writes (a row that finishes inside a burst stays
+            # where it is: counted as if it went on)
+            held = np.minimum(pos[pos < pool.length, None] + 1 + np.arange(advance),
+                              read_len or pool.length)
+            st = self._tick_stats
+            st["length_read_ticks"] += 1
+            st["row_keys_live"] += int(held.sum())
+            st["row_keys_read"] += int((-(-held // kv_cache.BLOCK) * kv_cache.BLOCK).sum())
         # advance the dispatch mirrors for the decode rows (the admitting
         # row's were set above); quota-clamped so a burst tail never
         # over-advances a row the host can predict finishing

@@ -22,7 +22,9 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.ops.transformer.fused_ops import fused_softmax
-from deepspeed_tpu.ops.transformer.kv_cache import dequantize_kv, kv_window
+from deepspeed_tpu.ops.pallas.decode_attention import decode_rows
+from deepspeed_tpu.ops.transformer.kv_cache import (BLOCK, dequantize_kv, kv_window,
+                                                    takes_length_read, time_minor)
 from deepspeed_tpu.telemetry.hlo_scopes import Scope
 
 
@@ -96,6 +98,18 @@ def softmax_context(q, k_cache, v_cache, pos, scale: Optional[float] = None,
     segment, and only that row's window is read.
     """
     B, S, nh, hd = q.shape
+    per_row = layer is not None and slot is None and positions is not None and jnp.ndim(pos) == 1
+    if per_row and takes_length_read(
+            k_cache, read_len, tokens=S, heads=nh,
+            masked_only=alibi_slopes is None and local_window is None and not ring):
+        # one token a row of a long window: each row to its own length, straight from the pool
+        with jax.named_scope(Scope.ATTN_CORE):
+            T = k_cache.shape[2]
+            depth = positions[:, 0]
+            out = decode_rows(q[:, 0], time_minor(k_cache), time_minor(v_cache), layer,
+                              jnp.where(depth < T, depth + 1, 0), size=read_len or T, block=BLOCK,
+                              sm_scale=scale if scale is not None else 1.0 / math.sqrt(hd))
+        return out[:, None]
     with jax.named_scope(Scope.ATTN_KV_READ):
         assert read_len is None or not ring, "tight reads do not apply to the rolling (ring) cache"
         k_cache = kv_window(k_cache, read_len, layer, slot)

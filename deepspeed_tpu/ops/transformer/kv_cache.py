@@ -305,6 +305,22 @@ def rows_write_by_blocks(cfg, cache, read_len: Optional[int], mesh=None) -> bool
                                          for leaf in jax.tree.leaves(sub))
 
 
+def rows_read_to_length(cfg, cache, read_len: Optional[int], mesh=None) -> bool:
+    """The host's side of :func:`takes_length_read`: whether a program built
+    on ``mesh`` whose rows attend one token each at read bucket ``read_len``
+    (None: the allocation) reads each row of ``cache`` to its own length
+    (``tick_stats()``'s ``length_read_ticks``). What ``softmax_context`` sees
+    in its arguments the host reads off the configuration: one kind of
+    layer, no ALiBi, no local window, no ring, as many key heads as query
+    heads, a dense pool."""
+    if _is_plan(cfg) or spans_chips(mesh) or specs(cfg)[0].int8:
+        return False
+    masked_only = (cfg.pos_embedding != "alibi" and cfg.uniform_window is None
+                   and not cfg.varying_windows and not cfg.rolling_kv_cache)
+    return takes_length_read(cache["k"], read_len, tokens=1, heads=cfg.num_heads,
+                             masked_only=masked_only)
+
+
 # -- on the device: window and write of one stacked array, both orders -------
 #                   time before heads                 heads before time
 #   rows' window    (B, size, H, x)                   (B, H, size, x)
@@ -379,6 +395,16 @@ BLOCK = 128
 # gpt2-medium's 40 rows against 3.05 / 6.21 / 12.23. The smallest row
 # measured, gpt2-medium at 256 slots (512 KiB), still gains 0.8 ms a tick;
 # below it nothing was measured and the window path stays.
+# The rows' READ by length (``takes_length_read``, PR 39) stands on the same
+# constant, and its measurement did not move it (a v5e, the kernel against
+# the two dots and their softmax over the window, ms over all layers,
+# PERF.md section 6, PR 39): at the smallest row measured, gpt2-medium's 40
+# rows at 256 slots, 0.23 against 1.78 with 2 rows live, 0.38 with 8, and
+# 1.88 against 1.78 only when all 40 rows fill the window; XL's 16 rows at
+# 256 slots 1.72-2.13 against 2.17-2.30 even full. Where EVERY row is as
+# long as the read bucket the kernel loses 5-20 % (XL 1,024: 7.77 against
+# 6.98; it moves 590-650 GB/s of what it fetches where the dots move 760),
+# which no static shape can tell; a call costs ~10 us before its first block.
 BLOCK_WRITE_MIN_ROW_BYTES = 1 << 19
 
 
@@ -415,6 +441,39 @@ def _row_bytes(pool, size: int, heads_first: bool) -> int:
     """Bytes of ONE row's ``size`` slots of one layer of a leaf."""
     heads, width = pool.shape[2 if heads_first else 3], pool.shape[4]
     return size * heads * width * pool.dtype.itemsize
+
+
+def takes_length_read(pool, size: Optional[int], *, tokens: int, heads: int,
+                      masked_only: bool) -> bool:
+    """Whether the rows' attention over the first ``size`` slots (None: all)
+    of a time-before-heads leaf ``pool`` goes through the kernel that reads
+    each row to ITS length in whole BLOCKs (``ops/pallas/decode_attention.py``;
+    True) or contracts every row's whole window (``_masked_attention``,
+    False). The ONE rule, over what a trace can see: ``tokens`` a row with
+    per-row depths is 1; ``masked_only``, the causal mask is all there is (no
+    ALiBi, no local window, no ring); the pool is dense (the int8 pair is
+    dequantized where it is read) with a key head a query head (``heads``)
+    and is kept time-minor on the chip (a width of whole 128-lane tiles is
+    not: its transpose would be a copy); the window is whole blocks and more
+    than one (a 128-slot read IS its block, and the XLA form reads it at the
+    chip's bandwidth), and a row's window of one leaf holds
+    ``BLOCK_WRITE_MIN_ROW_BYTES`` (the block write's threshold: below it
+    nothing was measured, and toy models keep the XLA program); its pools are
+    on one chip (a Mosaic kernel is not partitioned)."""
+    if _split_over_chips or isinstance(pool, dict) or not masked_only or tokens != 1:
+        return False
+    size = size or pool.shape[2]
+    return (pool.shape[3] == heads and pool.shape[4] % LANES != 0
+            and takes_block_write(size, _row_bytes(pool, size, heads_first=False)))
+
+
+def time_minor(pool):
+    """A time-before-heads leaf ``(L, B, T, H, x)`` as ``(L, B, H * x, T)``:
+    the order the chip keeps it in where ``x`` is not whole lanes, so the
+    view is a bitcast in the compiled tick (``test_tpu_compile.py``). A
+    128-token block of it is every head's keys as one 2-D array."""
+    L, B, T, H, x = pool.shape
+    return pool.transpose(0, 1, 3, 4, 2).reshape(L, B, H * x, T)
 
 
 def _write_blocks(pool, layer, token, cols, size, heads_first):

@@ -360,6 +360,14 @@ def host_overhead(tick_stats: dict) -> dict:
     ticks, blocks = tick_stats.get("ticks"), tick_stats.get("block_write_ticks")
     if blocks is not None:
         out["block_write_share"] = round(blocks / ticks, 4) if ticks else None
+    # ticks whose rows' attention read each row to its own length, and what
+    # reading by whole blocks fetched over what those rows hold
+    by_length = tick_stats.get("length_read_ticks")
+    if by_length is not None:
+        held = tick_stats["row_keys_live"]
+        out["length_read_share"] = round(by_length / ticks, 4) if ticks else None
+        out["row_keys_read_per_live"] = (round(tick_stats["row_keys_read"] / held, 4)
+                                         if held else None)
     # a latent pool: its bytes, the cached entries a tick's rows read, the
     # entries a fused tick's chunk expanded into heads again
     if "latent_pool_bytes" in tick_stats:
@@ -630,7 +638,11 @@ def format_summary(summary: dict) -> str:
                 + (f"   chunk pads {host['prefill_pad_share']:.1%}"
                    if host.get("prefill_pad_share") is not None else "")
                 + (f"   block writes {host['block_write_share']:.1%}"
-                   if host.get("block_write_share") is not None else ""))
+                   if host.get("block_write_share") is not None else "")
+                + (f"   length reads {host['length_read_share']:.1%}"
+                   if host.get("length_read_share") is not None else "")
+                + (f"   keys read ÷ live {host['row_keys_read_per_live']:.3f}"
+                   if host.get("row_keys_read_per_live") is not None else ""))
         if host.get("latent_pool_bytes") is not None:
             expanded = host.get("mla_expand_tokens_per_chunk")
             lines.append(

@@ -72,6 +72,25 @@ def _flash(mode, B, S, H, D, kv_heads=None):
     return build
 
 
+def _decode_rows(B, H, x, layers, length):
+    """The rows' one-token attention over the stacked pools as the serving
+    tick keeps them, (L, B, T, H, x), read in place through their time-minor view."""
+    def build(topo):
+        from deepspeed_tpu.ops.pallas.decode_attention import decode_rows
+        from deepspeed_tpu.ops.transformer import kv_cache
+
+        one = SingleDeviceSharding(topo.devices[0])
+        q = jax.ShapeDtypeStruct((B, H, x), jnp.bfloat16, sharding=one)
+        pool = jax.ShapeDtypeStruct((layers, B, length, H, x), jnp.bfloat16, sharding=one)
+        scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+        lengths = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one)
+        attn = lambda q, k, v, layer, lengths: decode_rows(  # noqa: E731
+            q, kv_cache.time_minor(k), kv_cache.time_minor(v), layer, lengths, size=length,
+            block=kv_cache.BLOCK, sm_scale=x ** -0.5)
+        return _lower(attn, q, pool, pool, scalar, lengths), 1
+    return build
+
+
 def _block_sparse(topo):
     from deepspeed_tpu.ops.sparse_attention.sparsity_config import FixedSparsityConfig
 
@@ -135,6 +154,9 @@ CASES = {
         {"tensor": 4}, PartitionSpec(None, None, "tensor", None)),
     "flash-bwd-mesh-fsdp4": _flash_on_mesh(
         {"fsdp": 4}, PartitionSpec("fsdp", None, None, None)),
+    # the serving rows' read at the benchmark's two GPT-2 cells: 16 slots of gpt2-xl, 40 of gpt2-medium
+    "decode-rows-xl-16x1024x25x64": _decode_rows(16, 25, 64, 48, 1024),
+    "decode-rows-medium-40x1024x16x64": _decode_rows(40, 16, 64, 24, 1024),
     # fwd + dq + dkv inside the layer scan: the default policy keeps the
     # forward kernel's output and log-sum-exp, so remat does not rerun it
     "gpt2-350m-loss-grad-mb8": _gpt2_350m_step(3),
@@ -188,8 +210,9 @@ def test_layer_checkpoint_keeps_the_flash_residuals_dense(compiled):
     ("gpt2-1.5b", 16, 256, None, True), ("gpt2-1.5b", 16, None, None, True),
     ("gpt2-1.5b", 16, 512, 128, True), ("gpt2-1.5b", 16, None, 128, True),
     ("gpt2-350m", 40, None, None, True), ("gpt2-1.5b", 16, 128, None, False),
+    ("gpt2-350m", 40, None, 128, True), ("gpt2-350m", 40, 256, None, True),
 ], ids=["plain-read256", "plain-read1024", "fused128-read512", "fused128-read1024",
-        "chat-plain-read1024", "plain-read128"])
+        "chat-plain-read1024", "plain-read128", "chat-fused128-read1024", "chat-plain-read256"])
 def test_serving_tick_updates_the_kv_pool_in_place(topo, preset, slots, read_len, chunk, by_blocks):
     """The gpt2-xl serving tick (16 slots x 1024, the benchmark's batch
     cell) and gpt2-medium's (40 x 1024, the chat cell) for the chip: the
@@ -205,7 +228,13 @@ def test_serving_tick_updates_the_kv_pool_in_place(topo, preset, slots, read_len
     call for K, one for V, in the layer loop; the pool enters it as its
     (L, B, H, x, T) transpose, which must be a bitcast here, not a copy) and
     no op of ``attn.kv_write`` yields a value of the window's shape; a
-    128-slot read keeps the window's in-place rewrite."""
+    128-slot read keeps the window's in-place rewrite. The same rule's
+    shapes send the rows' READ through ``decode_rows`` (PR 39,
+    ``kv_cache.takes_length_read``): once in the layer loop, K and V in one
+    call, the pools entering as their (L, B, H * x, T) view, which again
+    must be a bitcast (the copy check above); the rows' float32 logits
+    (B, heads, 1, T), which the compiler keeps as (B, heads, T), are then
+    nowhere in the program, and at a 128-slot read they are."""
     from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
     from deepspeed_tpu.models import transformer as tf
 
@@ -249,6 +278,9 @@ def test_serving_tick_updates_the_kv_pool_in_place(topo, preset, slots, read_len
                  if m and "attn.kv_write" in scopes.get(m.group(1), "")]
     assert bool(rewritten) != by_blocks, rewritten
     assert len(re.findall(r" custom-call\(.*kv_block_write", text)) == (2 if by_blocks else 0)
+    assert len(re.findall(r" custom-call\(.*decode_rows", text)) == (1 if by_blocks else 0)
+    logits = re.findall(rf"= f32\[{slots},{cfg.num_heads},(?:1,)?{read_len or length}\]", text)
+    assert bool(logits) != by_blocks, logits[:3]
 
 
 def test_plan_tick_writes_its_full_pool_by_blocks_in_place(topo, monkeypatch):
