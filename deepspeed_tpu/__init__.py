@@ -12,9 +12,11 @@ from deepspeed_tpu.comm import init_distributed
 from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.runtime.config import TpuConfig, DeepSpeedConfig
 from deepspeed_tpu.runtime.engine import TpuEngine, DeepSpeedEngine
+from deepspeed_tpu.telemetry import compile_log
 from deepspeed_tpu.utils.logging import logger, log_dist
 
 
+@compile_log.phase("engine_init")
 def initialize(
     args=None,
     model=None,

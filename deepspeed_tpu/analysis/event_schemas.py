@@ -295,7 +295,20 @@ EVENT_SCHEMAS = {
             "compile_ms": "number",
             "recompile": "bool",
         },
-        "optional": {"replica": "str"},
+        # the build journal's split of compile_ms (= the first dispatch's
+        # wall time; telemetry/compile_log.py), its phase and tick
+        "optional": {
+            "trace_ms": "number",
+            "lower_ms": "number",
+            "backend_ms": "number",
+            "load_ms": "number",
+            "other_ms": "number",
+            "cache_hit": "bool",
+            "phase": "str",
+            "tick": "int",
+            "cache_alloc": "int",
+            "replica": "str",
+        },
     },
 }
 

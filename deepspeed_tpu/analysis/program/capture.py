@@ -88,8 +88,8 @@ def extract_artifact(family: str, variant: str, fn, args, meta=None,
     artifact with ``error`` set, which the audit reports as a finding
     (``audit-extraction-error``) rather than crashing the build site.
 
-    ``fn`` may be a telemetry wrapper (_FirstCallTimer et al) — those
-    forward ``.lower`` via ``__getattr__``."""
+    ``fn`` may be the build journal's wrapper (``compile_log.record_build``)
+    — it forwards ``.lower`` via ``__getattr__``."""
     meta = dict(meta or {})
     art = ProgramArtifact(family=family, variant=variant, meta=meta)
     try:

@@ -77,6 +77,7 @@ overlap win is measurable from traces alone — ``tick_stats()`` exposes
 the same accounting in-process.
 """
 
+import functools
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
@@ -100,6 +101,7 @@ from deepspeed_tpu.inference.decoding import (
 )
 from deepspeed_tpu.ops.pallas.mla_attention import expanded_entries
 from deepspeed_tpu.ops.transformer import kv_cache
+from deepspeed_tpu.telemetry import compile_log
 from deepspeed_tpu.telemetry.spans import host_span
 
 # admission/bucket sizing shares the ONE bucketing rule with the tight-read
@@ -193,20 +195,15 @@ class _Pool:
     def __init__(self, engine, n_slots: int, length: int):
         self.n_slots = n_slots
         self.length = length
-        # the hub is resolved at FIRST DISPATCH, not here: a serving
-        # recovery factory builds replacement engines with telemetry off
-        # and injects the shared hub afterwards — jit compiles lazily, so
-        # the deferred wrap still journals the rebuild's compiles
-        from deepspeed_tpu.telemetry.compile_log import wrap_deferred
-
-        def get_tele(_engine=engine):
-            return _engine._eng.telemetry
-
+        # the pool's companion programs journal their first dispatch
+        # (telemetry/compile_log.py); as attributes they keep the wrapper
+        # and its one flag test
+        record_build = engine._record_build
         self.segment_fn, self.cache_sh, _ = compile_segment_fn(
             engine.mesh, engine.cfg, engine._eng.param_shardings, n_slots, length
         )
-        self.segment_fn = wrap_deferred(get_tele, self.segment_fn,
-                                        "pool_segment", (n_slots, length))
+        self.segment_fn = record_build(self.segment_fn, "pool_segment",
+                                       (n_slots, length))
         self.cache = jax.device_put(
             kv_cache.init(engine.cfg, n_slots, length), self.cache_sh
         )
@@ -225,8 +222,8 @@ class _Pool:
         self.set_row_fn = compile_row_update_fn(engine.mesh, engine.cfg,
                                                 n_slots,
                                                 donate=engine.donate_cache)
-        self.set_row_fn = wrap_deferred(get_tele, self.set_row_fn,
-                                        "row_update", (n_slots,))
+        self.set_row_fn = record_build(self.set_row_fn, "row_update",
+                                       (n_slots,))
         # speculative tick state (engine.spec_gamma > 0): pos/gen join the
         # device-THREADED arrays — a spec row advances by its own accepted
         # count, which only the device knows at dispatch time — and
@@ -241,16 +238,15 @@ class _Pool:
             self.spec_set_row_fn = compile_spec_row_update_fn(
                 engine.mesh, engine.cfg, n_slots,
                 donate=engine.donate_cache)
-            self.spec_set_row_fn = wrap_deferred(
-                get_tele, self.spec_set_row_fn, "spec_row_update",
-                (n_slots,))
+            self.spec_set_row_fn = record_build(
+                self.spec_set_row_fn, "spec_row_update", (n_slots,))
             if engine.spec_mode == "draft":
                 deng = engine._draft_eng
                 self.draft_segment_fn, self.draft_cache_sh, _ = \
                     compile_segment_fn(engine.mesh, engine.draft_cfg,
                                        deng.param_shardings, n_slots, length)
-                self.draft_segment_fn = wrap_deferred(
-                    get_tele, self.draft_segment_fn, "pool_segment",
+                self.draft_segment_fn = record_build(
+                    self.draft_segment_fn, "pool_segment",
                     (n_slots, length, "draft"))
                 self.draft_cache = jax.device_put(
                     kv_cache.init(engine.draft_cfg, n_slots, length),
@@ -324,6 +320,7 @@ class _Pool:
 class ContinuousBatchingEngine:
     """Slot-pool serving loop over the compiled pool-tick programs."""
 
+    @compile_log.phase("pools")
     def __init__(self, model, config=None, params=None, mesh=None,
                  max_slots: Optional[int] = None, cache_len: Optional[int] = None,
                  cache_buckets: Optional[List] = None,
@@ -503,7 +500,11 @@ class ContinuousBatchingEngine:
                             # live row, a layer and leaf) and the slots
                             # those rows hold (read / live = the over-read)
                             "length_read_ticks": 0, "row_keys_read": 0,
-                            "row_keys_live": 0}
+                            "row_keys_live": 0,
+                            # programs this engine built (first dispatches,
+                            # telemetry/compile_log.py) and what they cost:
+                            # a window's delta is what it built
+                            "programs_built": 0, "program_build_ms": 0.0}
         if self.cfg.layer_kinds is not None:
             # what those chunks' attention had to do: (query, key) pairs
             # attended in a full and in a window layer, keys a full layer read
@@ -576,6 +577,14 @@ class ContinuousBatchingEngine:
         # enabled guard keeps telemetry-off builds from walking the trees
         if self._eng.telemetry.enabled:
             self.memory_snapshot("build")
+
+    def _record_build(self, fn, family: str, key, **kw):
+        """``compile_log.record_build`` with this engine's hub, tick index
+        and ``tick_stats()`` sums, each read at the program's first
+        dispatch."""
+        return compile_log.record_build(
+            fn, family, key, hub=lambda: self._eng.telemetry,
+            tick=lambda: self._tick_index, sums=lambda: self._tick_stats, **kw)
 
     @property
     def telemetry(self):
@@ -1196,19 +1205,17 @@ class ContinuousBatchingEngine:
                 self.temperature, self.top_k, self.top_p,
                 eos_token_id=self.eos_token_id, read_len=read_len,
                 chunk=chunk, donate=self.donate_cache)[0]
-            tele = self._eng.telemetry
-            if tele.enabled:
-                # compile flight recorder: the program's first dispatch
-                # journals a compile_event keyed by the full shapes key —
-                # a rebuilt engine re-compiling the family through the
-                # shared hub is flagged recompile (the runtime view of
-                # ds-lint's static recompile-hazard rule)
-                fn = tele.compile_recorder().wrap(
-                    fn, "pool_tick",
-                    (pool.length, pool.n_slots,
-                     1 if chunk is not None else self.tokens_per_tick,
-                     chunk, read_len))
-            pool.tick_fns[key] = fn
+            # build journal: the program's first dispatch leaves an entry
+            # keyed by the full shapes key — a rebuilt engine re-compiling
+            # the family is flagged recompile (the runtime view of
+            # ds-lint's static recompile-hazard rule) — and then hands the
+            # bare program back to the table: a steady tick runs no wrapper
+            fn = pool.tick_fns[key] = self._record_build(
+                fn, "pool_tick",
+                (pool.length, pool.n_slots,
+                 1 if chunk is not None else self.tokens_per_tick,
+                 chunk, read_len),
+                settle=functools.partial(pool.tick_fns.__setitem__, key))
             # ds-audit capture (zero cost without a hook): the contract
             # auditor sees every tick variant a serve actually compiles
             from deepspeed_tpu.analysis.program import capture
@@ -1362,13 +1369,11 @@ class ContinuousBatchingEngine:
                 pool.length, self.spec_gamma, self.temperature, self.top_k,
                 self.top_p, eos_token_id=self.eos_token_id,
                 read_len=read_len, donate=self.donate_cache, **kw)[0]
-            tele = self._eng.telemetry
-            if tele.enabled:
-                fn = tele.compile_recorder().wrap(
-                    fn, "pool_spec_tick",
-                    (pool.length, pool.n_slots, self.spec_gamma,
-                     self.spec_mode, read_len))
-            pool.tick_fns[key] = fn
+            fn = pool.tick_fns[key] = self._record_build(
+                fn, "pool_spec_tick",
+                (pool.length, pool.n_slots, self.spec_gamma,
+                 self.spec_mode, read_len),
+                settle=functools.partial(pool.tick_fns.__setitem__, key))
             from deepspeed_tpu.analysis.program import capture
 
             if capture.active():
@@ -1778,6 +1783,7 @@ class ContinuousBatchingEngine:
         pool.disp_pos[slot] = first_pos
         pool.disp_gen[slot] = req.gen_base
 
+    @compile_log.phase("precompile")
     def precompile_tick_programs(self, progress: Optional[Callable] = None) -> int:
         """Compile (and block on) the FULL tick-program family — every
         (pool, read bucket, {plain/burst, fused chunk widths}) variant a

@@ -6,6 +6,7 @@ Single home for the compile-and-sample logic used by BOTH the standalone
 jits, same sampling loop, so fixes propagate to both surfaces.
 """
 
+import functools
 import time
 from typing import Optional, Tuple
 
@@ -1187,6 +1188,34 @@ def fused_generate_fn(holder, mesh, cfg, param_shardings, batch_size: int,
     )
 
 
+def _journalled(holder, family: dict, kind: str, key, value):
+    """Arm the build journal (telemetry/compile_log.py) on a fresh
+    ``cached_fn`` entry: a bare callable, or a tuple led by one (the
+    convention every builder follows — ``(fn, cache_sharding, ...)``);
+    anything else passes through. After its first dispatch the entry is
+    the bare program again, so a hit runs no wrapper."""
+    led = isinstance(value, tuple) and bool(value)
+    fn = value[0] if led else value
+    if not callable(fn):
+        return value
+    record = getattr(holder, "_record_build", None)
+    if record is None:
+        from deepspeed_tpu.telemetry.compile_log import record_build
+
+        record = functools.partial(
+            record_build, hub=lambda: getattr(holder, "telemetry", None))
+
+    def entry(program):
+        return (program,) + value[1:] if led else program
+
+    def settle(bare):
+        if family.get(key) is armed:  # not evicted or rebuilt meanwhile
+            family[key] = entry(bare)
+
+    armed = entry(record(fn, kind, key, settle=settle))
+    return armed
+
+
 def cached_fn(holder, kind: str, key, builder, slots: int = 4):
     """Bounded per-family memoization of compiled functions on ``holder``
     (InferenceEngine and TpuHybridEngine share this; a long-running server
@@ -1196,9 +1225,10 @@ def cached_fn(holder, kind: str, key, builder, slots: int = 4):
     ``_compile_hits``/``_compile_misses`` ints (request events diff the
     miss count to tag compile-triggering requests), and a holder carrying
     an enabled ``telemetry`` hub gets per-family labeled counters. A miss
-    additionally arms the compile flight recorder (telemetry/
-    compile_log.py) on the fresh entry: its first dispatch — the one that
-    pays tracing + XLA compile — emits a ``compile_event`` keyed
+    additionally arms the build journal (telemetry/compile_log.py) on the
+    fresh entry: its first dispatch — the one that pays tracing, lowering
+    and the XLA compile or cache load — leaves a journal entry whatever the
+    hub's state, and on a live hub a ``compile_event`` keyed
     (family=``kind``, shapes key), flagged ``recompile`` when this hub
     compiled the same key before (LRU eviction churn made visible)."""
     cache = getattr(holder, "_fn_cache", None)
@@ -1210,9 +1240,7 @@ def cached_fn(holder, kind: str, key, builder, slots: int = 4):
     if miss:
         if len(family) >= slots:
             family.pop(next(iter(family)))  # evict least-recently-used
-        from deepspeed_tpu.telemetry.compile_log import wrap_compiled
-
-        family[key] = wrap_compiled(tele, kind, key, builder())
+        family[key] = _journalled(holder, family, kind, key, builder())
     else:
         family[key] = family.pop(key)  # refresh recency (LRU, not FIFO)
     attr = "_compile_misses" if miss else "_compile_hits"

@@ -36,6 +36,7 @@ from deepspeed_tpu.inference.config import InferenceConfig
 from deepspeed_tpu.models import transformer as tf
 from deepspeed_tpu.ops.transformer import kv_cache
 from deepspeed_tpu.runtime.zero.sharding import ShardingPolicy
+from deepspeed_tpu.telemetry import compile_log
 from deepspeed_tpu.utils.logging import log_dist, logger
 
 
@@ -280,6 +281,12 @@ class InferenceEngine:
         return new_params, new_shardings
 
     # ------------------------------------------------------------------
+    def _record_build(self, fn, family: str, key, **kw):
+        """``compile_log.record_build`` with this engine's hub, read at the
+        program's first dispatch."""
+        return compile_log.record_build(fn, family, key,
+                                        hub=lambda: self.telemetry, **kw)
+
     def _compile(self, batch_size: int, max_len: int):
         from deepspeed_tpu.inference.decoding import compile_decode_fns
 
@@ -287,12 +294,10 @@ class InferenceEngine:
             compile_decode_fns(self.mesh, self.cfg, self.param_shardings, batch_size, max_len)
         )
         self._compiled_shape = (batch_size, max_len)
-        if self.telemetry.enabled:
-            rec = self.telemetry.compile_recorder()
-            self._prefill_fn = rec.wrap(self._prefill_fn, "decode_prefill",
-                                        self._compiled_shape)
-            self._decode_fn = rec.wrap(self._decode_fn, "decode_step",
-                                       self._compiled_shape)
+        self._prefill_fn = self._record_build(
+            self._prefill_fn, "decode_prefill", self._compiled_shape)
+        self._decode_fn = self._record_build(
+            self._decode_fn, "decode_step", self._compiled_shape)
         # ds-audit capture (zero cost without a hook): the decode pair is
         # the engine's hot program family — contract-checked as built
         from deepspeed_tpu.analysis.program import capture
@@ -633,8 +638,8 @@ class InferenceEngine:
                 self._compile_misses += 1
                 self._traced_geoms |= fresh
                 # allocation buckets whose migration dispatch will pay a
-                # real re-trace this request — the flight recorder only
-                # journals those (an already-traced bucket re-migrated by
+                # real re-trace this request — the build journal only
+                # records those (an already-traced bucket re-migrated by
                 # a later request dispatches from the jit cache)
                 fresh_allocs = {g[2] for g in fresh}
         decode_fn = (self._decode_fn if floor is None
@@ -679,24 +684,23 @@ class InferenceEngine:
             if pos + 1 > kv_cache.alloc_len(self.cfg, cache):
                 new_len = min(read_bucket(pos + 1, max_len, floor), max_len)
                 cache = self._grow_cache(cache, new_len)
-                if self.telemetry.enabled:
-                    # every migration snapshots the grown allocation; the
-                    # decode jit RE-TRACES only at an untraced bucket —
-                    # that runtime recompile is what the flight recorder
-                    # journals (each fresh bucket compiles exactly once)
-                    retrace = new_len in fresh
-                    fresh.discard(new_len)
-                    return self._migrated_decode(params, tok, cache, pos,
-                                                 new_len, retrace)
+                # every migration snapshots the grown allocation (hub on);
+                # the decode jit RE-TRACES only at an untraced bucket —
+                # that runtime recompile is what the build journal records,
+                # hub or no hub (each fresh bucket compiles exactly once)
+                retrace = new_len in fresh
+                fresh.discard(new_len)
+                return self._migrated_decode(params, tok, cache, pos,
+                                             new_len, retrace)
             if first:
                 # a request can also pay a re-trace at its STARTING bucket
                 # (a longer prompt opening an untraced allocation, no
                 # migration involved) — journal that compile too, unless
-                # the decode fn's own first-call timer is still armed (the
+                # the decode fn's own journal wrapper is still armed (the
                 # genuine first compile, which records itself)
                 first = False
                 start_alloc = kv_cache.alloc_len(self.cfg, cache)
-                if (start_alloc in fresh and self.telemetry.enabled
+                if (start_alloc in fresh
                         and getattr(self._decode_fn, "_done", True)):
                     fresh.discard(start_alloc)
                     return self._timed_decode_retrace(params, tok, cache,
@@ -715,12 +719,13 @@ class InferenceEngine:
         as the original ``decode_step`` compile, so the event is
         recompile-flagged (the visible counter behind runtime recompile
         storms)."""
-        from deepspeed_tpu.telemetry import memory as hbm
+        if self.telemetry.enabled:
+            from deepspeed_tpu.telemetry import memory as hbm
 
-        hbm.emit_snapshot(self.telemetry, {
-            "params": hbm.tree_device_bytes(self.params),
-            "kv_cache": hbm.tree_device_bytes(cache),
-        }, "migration")
+            hbm.emit_snapshot(self.telemetry, {
+                "params": hbm.tree_device_bytes(self.params),
+                "kv_cache": hbm.tree_device_bytes(cache),
+            }, "migration")
         if not retrace:
             return self._decode_fn(params, tok, cache, pos)
         return self._timed_decode_retrace(params, tok, cache, pos, new_len)
@@ -731,15 +736,8 @@ class InferenceEngine:
         compile_event under the same family+key as the original
         ``decode_step`` compile — recompile-flagged, ``cache_alloc``
         attached (the visible counter behind runtime recompile storms)."""
-        rec = self.telemetry.compile_recorder()
-        t0 = time.perf_counter()
-        out = self._decode_fn(params, tok, cache, pos)
-        # dispatch blocks through the re-trace + XLA compile and returns
-        # futures — the span is compile cost, not execution, by design
-        rec.record("decode_step", self._compiled_shape,
-                   # ds-lint: disable=unsynced-timing
-                   (time.perf_counter() - t0) * 1000.0, cache_alloc=alloc)
-        return out
+        return self._record_build(self._decode_fn, "decode_step", self._compiled_shape,
+                                  cache_alloc=alloc)(params, tok, cache, pos)
 
     def _grow_cache(self, cache, new_len: int):
         """Migrate a KV cache to a longer time axis (zero-padded tail; the
@@ -928,6 +926,7 @@ class InferenceEngine:
         return jnp.asarray(arr)
 
 
+@compile_log.phase("params_place")
 def init_inference(model, config=None, params=None, mesh=None, draft_model=None,
                    draft_params=None, seed: int = 0, **kwargs) -> InferenceEngine:
     """Reference: deepspeed.init_inference (deepspeed/__init__.py:251).

@@ -43,6 +43,7 @@ from deepspeed_tpu.runtime.config import TpuConfig
 from deepspeed_tpu.runtime.fp16.loss_scaler import LossScaleState, create_loss_scaler
 from deepspeed_tpu.runtime.lr_schedules import create_lr_scheduler
 from deepspeed_tpu.runtime.zero.sharding import ShardingPolicy
+from deepspeed_tpu.telemetry import compile_log
 from deepspeed_tpu.telemetry.hlo_scopes import Scope
 from deepspeed_tpu.telemetry.spans import host_span
 from deepspeed_tpu.utils.logging import log_dist, logger
@@ -742,6 +743,13 @@ class TpuEngine:
     # ------------------------------------------------------------------
     # compiled programs
     # ------------------------------------------------------------------
+    def _record_build(self, fn, family: str, key, **kw):
+        """``compile_log.record_build`` with this engine's hub and global
+        step, each read at the program's first dispatch."""
+        return compile_log.record_build(
+            fn, family, key, hub=lambda: self.telemetry,
+            tick=lambda: self.global_steps, **kw)
+
     def _compile_step_fns(self):
         if self.param_offload:
             # the coordinator owns the compiled programs (streamed per-group)
@@ -810,17 +818,23 @@ class TpuEngine:
                 ),
                 out_shardings=(self.replicated, self.grad_shardings),
             )
-            if self.telemetry.enabled:
-                # compile flight recorder: the first dispatch of each
-                # (ltd grid point) micro program journals a compile_event
-                # (LTD shape churn shows up as train_micro recompiles)
-                fn = self.telemetry.compile_recorder().wrap(
-                    fn, "train_micro",
-                    (self.train_micro_batch_size_per_gpu, gas, ltd_keep_len))
-            return fn
+            # build journal: the first dispatch of each (ltd grid point)
+            # micro program leaves an entry (LTD shape churn shows up as
+            # train_micro recompiles), then hands the bare program back to
+            # ``_micro_jits``: a steady micro-step runs no wrapper
+            def settle(bare):
+                self._micro_jits[ltd_keep_len] = bare
+                if ltd_keep_len is None:
+                    self._micro_fn = bare
+
+            return self._record_build(
+                fn, "train_micro",
+                (self.train_micro_batch_size_per_gpu, gas, ltd_keep_len),
+                settle=settle)
 
         self._micro_builder = build_micro
-        self._micro_jits = {None: build_micro(None)}
+        self._micro_jits = {}
+        self._micro_jits[None] = build_micro(None)
         self._micro_fn = self._micro_jits[None]
 
         def loss_only_fn(params, batch, rng):
@@ -899,10 +913,10 @@ class TpuEngine:
                 self.replicated,
             ),
         )
-        if self.telemetry.enabled:
-            self._apply_fn = self.telemetry.compile_recorder().wrap(
-                self._apply_fn, "train_apply",
-                (self.train_micro_batch_size_per_gpu, gas))
+        self._apply_fn = self._record_build(
+            self._apply_fn, "train_apply",
+            (self.train_micro_batch_size_per_gpu, gas),
+            settle=partial(setattr, self, "_apply_fn"))
         # ds-audit capture (zero cost without a hook): the optimizer
         # apply program's args are all engine state, so it can be
         # contract-checked right at build (the micro program needs a

@@ -38,6 +38,7 @@ import numpy as np
 
 from deepspeed_tpu.serving.faults import FaultInjector, FaultPlan
 from deepspeed_tpu.serving.recovery import RecoveryConfig
+from deepspeed_tpu.telemetry import compile_log
 from deepspeed_tpu.telemetry.registry import percentile
 
 _PROCESSES = ("poisson", "uniform", "burst")
@@ -339,6 +340,8 @@ def host_overhead(tick_stats: dict) -> dict:
         "overlap_frac": tick_stats.get("overlap_frac"),
         "block_ms_per_token": tick_stats.get("block_ms_per_token"),
         "wasted_tokens": tick_stats.get("wasted_tokens", 0),
+        "programs_built": tick_stats.get("programs_built", 0),
+        "program_build_ms": round(tick_stats.get("program_build_ms", 0.0), 3),
     }
     # the two kinds of tick apart (a fused tick carries a prefill chunk),
     # and the mean depth of the batcher's prefill queue per step
@@ -587,6 +590,46 @@ def summarize(records: List[dict], wall_s: float,
     return out
 
 
+def format_setup(s: dict) -> str:
+    """``compile_log.summary()`` as the ``set-up`` block of the summary:
+    where the seconds before the window and the chip's memory went, and
+    what was built after the window opened."""
+    cols = ("seconds", "programs", "trace_s", "lower_s", "backend_s", "load_s", "other_s",
+            "unwrapped_s", "rest_s", "peak_raised_bytes")
+    heads = ("seconds", "programs", "trace", "lower", "backend", "(load)", "other",
+             "unwrapped", "rest", "peak +GB")
+
+    def cells(row):
+        out = []
+        for c in cols:
+            v = row.get(c)
+            out.append("-" if v is None else str(v) if c == "programs"
+                       else f"{v / 1e9:.3f}" if c.endswith("bytes") else f"{v:.2f}")
+        return "".join(x.rjust(10) for x in out)
+
+    lines = ["== set-up (build journal) ==",
+             "phase".ljust(16) + "".join(h.rjust(10) for h in heads)]
+    for name, row in s["phases"].items():
+        lines.append(name.ljust(16) + cells(row))
+    lines.append(f"span {s['span_s']:.2f} s to since_t   remainder {s['remainder_s']:.3f} s")
+    if s["families"]:
+        lines.append("family".ljust(16) + "".join(h.rjust(10) for h in heads))
+        for name in sorted(s["families"], key=lambda f: -s["families"][f]["wall_s"]):
+            row = dict(s["families"][name])
+            row["seconds"] = row["wall_s"]
+            lines.append(name.ljust(16) + cells(row))
+    for r in s["peak_raisers"]:
+        if r["bytes"] >= 1e6:  # a tick program's kilobytes are in the data, not the table
+            lines.append(f"peak raised    +{r['bytes'] / 1e9:.3f} GB by {r['what']}")
+    if "hbm" in s:
+        lines.append(f"hbm            in use {s['hbm']['bytes_in_use'] / 1e9:.3f} GB (steady floor)"
+                     f"   lifetime peak {s['hbm']['peak_bytes_in_use'] / 1e9:.3f} GB")
+    for b in s["built_after"]:
+        lines.append(f"built in window {b['family']} {b['key']} tick {b.get('tick', '-')} "
+                     f"at +{b['at_s']:.3f} s   {b['wall_ms']:.1f} ms")
+    return "\n".join(lines) + "\n"
+
+
 def format_summary(summary: dict) -> str:
     lines = ["== ds_loadgen summary =="]
     oc = " ".join(f"{k}={v}" for k, v in summary["outcomes"].items())
@@ -629,6 +672,9 @@ def format_summary(summary: dict) -> str:
         lines.append(f"blocked/token  {_ms(host['block_ms_per_token'])}  "
                      f"(pipeline depth {host['pipeline_depth']}, "
                      f"wasted {host['wasted_tokens']} tok)")
+        if host.get("programs_built"):
+            lines.append(f"programs built {host['programs_built']} in "
+                         f"{_ms(host['program_build_ms'])} (first dispatches)")
         if host.get("fused_tick_share") is not None:
             lines.append(
                 f"tick kinds     fused {host['fused_tick_share']:.1%} of ticks   "
@@ -702,6 +748,8 @@ def format_summary(summary: dict) -> str:
             f"migrated {fleet['migrated']}   lost {fleet['lost']}   "
             f"spillovers {fleet['spillovers']}   conservation "
             + ("ok" if fleet["conservation_ok"] else "VIOLATED"))
+    if summary.get("setup"):
+        lines.append(format_setup(summary["setup"]).rstrip())
     return "\n".join(lines) + "\n"
 
 
@@ -1229,8 +1277,10 @@ def main(argv=None) -> int:
             ops = serving.start_ops_server(port=args.ops_port)
             print(f"ops server live at {ops.url} "
                   f"(/metrics /healthz /statusz)")
+        t_window = time.monotonic()  # the build journal's clock
         records, wall_s = run_load(serving, workload, arrivals, seed=args.seed)
         summary = summarize(records, wall_s, tick_stats=serving.tick_stats())
+        summary["setup"] = compile_log.summary(since_t=t_window)
         if chaos_plan is not None:
             injector = serving._cb.fault_hook
             summary["chaos"] = chaos_scorecard(

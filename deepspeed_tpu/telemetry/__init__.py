@@ -8,6 +8,8 @@ Entry points:
   - :class:`MetricsRegistry` — standalone counters/gauges/histograms/spans.
   - :class:`TraceWriter` / :func:`read_trace` — the JSONL format
     (``"schema": 1``) consumed by ``tools/ds_trace_report.py``.
+  - :mod:`compile_log` — the always-on build journal (``journal()``,
+    ``summary()``), hub or no hub.
 """
 
 from deepspeed_tpu.telemetry.compile_log import CompileRecorder

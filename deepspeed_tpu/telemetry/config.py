@@ -26,11 +26,14 @@ class TelemetryConfig:
     # mirror numeric event fields into MonitorMaster writers
     # (tensorboard/csv/wandb) when any are configured
     emit_to_monitor: bool = True
-    # block on device work at micro-step/step boundaries so fwd/step wall
-    # times measure compute, not dispatch. Costs the dispatch overlap —
-    # that is the price of honest per-phase numbers; turn off to keep the
-    # async pipeline and accept dispatch-time phase attribution.
-    sync_timers: bool = True
+    # true: block on device work at micro-step/step boundaries so the
+    # forward / backward / step timers hold the device's time, not the
+    # enqueue's. That buys device-inclusive phase timers and costs the
+    # host's dispatch-ahead: 8.0 % of training throughput on the chip with
+    # the hub on, against 0.7 % with it false (PERF.md §6, PR 24). False
+    # (the default since PR 40) keeps the async pipeline; iter_ms,
+    # samples/sec and MFU span whole steps and still hold.
+    sync_timers: bool = False
     # per-device peak FLOP/s (in TFLOP/s) for the MFU denominator.
     # 0 = auto-detect from jax device_kind (v4/v5e/v5p/v6e table),
     # falling back to the v5e peak (197) on unknown hardware — override

@@ -116,7 +116,7 @@ class Telemetry:
 
     # ------------------------------------------------------------------
     def compile_recorder(self):
-        """The hub's compile flight recorder (telemetry/compile_log.py),
+        """The hub's side of the build journal (telemetry/compile_log.py),
         created lazily and shared across engine generations — a serving
         rebuild re-injects this hub, so the replacement engine's compiles
         are correctly flagged as recompiles."""

@@ -196,27 +196,29 @@ def test_start_bucket_retrace_journaled(setup, tmp_path):
 
 
 # -- recorder unit behavior --------------------------------------------
-def test_wrap_deferred_resolves_hub_at_first_call():
+def test_record_build_resolves_hub_at_first_call():
     """The serving-rebuild flow: programs are built while the factory's
     telemetry is off, the shared hub is injected afterwards, and jit
-    compiles lazily — so the deferred wrap must consult the hub at FIRST
+    compiles lazily — so the wrapper must consult the hub at FIRST
     DISPATCH, not wrap time."""
-    from deepspeed_tpu.telemetry.compile_log import wrap_deferred
+    from deepspeed_tpu.telemetry.compile_log import record_build
 
     hub = {"tele": Telemetry(TelemetryConfig(enabled=False))}
     fn = lambda x: x * 2  # noqa: E731 — the wrapped "program"
-    w = wrap_deferred(lambda: hub["tele"], fn, "fam", (1,))
-    assert w(2) == 4  # hub disabled at first call: plain passthrough
+    w = record_build(fn, "fam", (1,), hub=lambda: hub["tele"])
+    assert w(2) == 4  # hub disabled at first call: journalled, no event
     hub["tele"] = Telemetry(TelemetryConfig(enabled=True, trace_file=""))
     assert w(3) == 6  # first call already burned: stays a passthrough
     assert "compile_event_total{family=fam}" \
         not in hub["tele"].registry.dump()["counters"]
     # program built before injection, dispatched after: journaled
-    w2 = wrap_deferred(lambda: hub["tele"], fn, "fam", (1,))
+    w2 = record_build(fn, "fam", (1,), hub=lambda: hub["tele"])
     assert w2(4) == 8 and w2(5) == 10
     dump = hub["tele"].registry.dump()
     assert dump["counters"]["compile_event_total{family=fam}"] == 1.0
     assert dump["histograms"]["compile_ms{family=fam}"]["count"] == 1
+
+
 def test_cached_fn_eviction_flags_recompile():
     from deepspeed_tpu.inference.decoding import cached_fn
 
@@ -244,9 +246,10 @@ def test_cached_fn_eviction_flags_recompile():
     assert dump["recompile_total{family=fam}"] == 1.0
 
 
-def test_recorder_wrap_is_transparent():
+def test_record_build_is_transparent():
+    from deepspeed_tpu.telemetry.compile_log import record_build
+
     tele = Telemetry(TelemetryConfig(enabled=True, trace_file=""))
-    rec = tele.compile_recorder()
 
     class FnWithLower:
         def __call__(self, x):
@@ -255,12 +258,13 @@ def test_recorder_wrap_is_transparent():
         def lower(self, x):  # the AOT surface engines rely on
             return "lowered"
 
-    wrapped = rec.wrap(FnWithLower(), "f", (1,))
+    home = {}
+    fn = FnWithLower()
+    wrapped = record_build(fn, "f", (1,), hub=lambda: tele,
+                           settle=lambda bare: home.update(fn=bare))
+    assert wrapped.lower(0) == "lowered" and not home
     assert wrapped(1) == 2 and wrapped(2) == 3
-    assert wrapped.lower(0) == "lowered"
     hist = tele.registry.dump()["histograms"]["compile_ms{family=f}"]
     assert hist["count"] == 1  # only the first call was timed
-    # disabled hub: wrap is the identity (zero hot-path cost)
-    off = Telemetry(TelemetryConfig(enabled=False))
-    fn = FnWithLower()
-    assert off.compile_recorder().wrap(fn, "f", ()) is fn
+    # the cache that owns the program has the bare callable back
+    assert home["fn"] is fn

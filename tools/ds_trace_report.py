@@ -759,34 +759,42 @@ def format_memory_table(table):
     return "\n".join(lines) + "\n"
 
 
+_BUILD_SPLIT = ("trace_ms", "lower_ms", "backend_ms", "load_ms", "other_ms")
+
+
 def compile_table(events):
     """Compile flight-recorder totals over ``compile_event`` events:
-    count, total compile_ms, and recompiles — overall and per program
-    family. A non-zero recompile count at serve time is the runtime
-    recompile storm ds-lint can only guess at statically. Empty dict
-    when the trace carries no compile events."""
+    count, total compile_ms (the first dispatches' wall time), and
+    recompiles — overall and per program family; where the events carry
+    the build journal's split (traces written before it do not), also
+    trace / lower / backend (of which load) / other ms and the
+    persistent-cache hits. A non-zero recompile count at serve time is the
+    runtime recompile storm ds-lint can only guess at statically. Empty
+    dict when the trace carries no compile events."""
     evs = [e for e in events if e.get("kind") == "compile_event"]
     if not evs:
         return {}
+    split = _BUILD_SPLIT if any("trace_ms" in e for e in evs) else ()
+    blank = dict({"count": 0, "compile_ms": 0.0, "recompiles": 0},
+                 **dict.fromkeys(split, 0.0), **({"cache_hits": 0} if split else {}))
     families = {}
     for e in evs:
-        fam = families.setdefault(e.get("family", "?"),
-                                  {"count": 0, "compile_ms": 0.0,
-                                   "recompiles": 0})
+        fam = families.setdefault(e.get("family", "?"), dict(blank))
         fam["count"] += 1
-        ms = e.get("compile_ms")
-        if isinstance(ms, (int, float)) and not isinstance(ms, bool):
-            fam["compile_ms"] += float(ms)
-        if e.get("recompile") is True:
-            fam["recompiles"] += 1
+        for field in ("compile_ms",) + split:
+            ms = e.get(field)
+            if isinstance(ms, (int, float)) and not isinstance(ms, bool):
+                fam[field] += float(ms)
+        fam["recompiles"] += e.get("recompile") is True
+        if split:
+            fam["cache_hits"] += e.get("cache_hit") is True
     return {
         "count": len(evs),
         "compile_ms_total": round(sum(f["compile_ms"]
                                       for f in families.values()), 3),
         "recompiles": sum(f["recompiles"] for f in families.values()),
-        "families": {k: {"count": v["count"],
-                         "compile_ms": round(v["compile_ms"], 3),
-                         "recompiles": v["recompiles"]}
+        "families": {k: {f: (round(x, 3) if isinstance(x, float) else x)
+                         for f, x in v.items()}
                      for k, v in families.items()},
     }
 
@@ -799,16 +807,17 @@ def format_compile_table(table):
              f"{_fmt(table['compile_ms_total'])} ms   recompiles "
              f"{table['recompiles']}"]
     name_w = max(len("family"), max(len(n) for n in table["families"]))
-    col_w = 14
-    header = ("family".ljust(name_w) + "count".rjust(col_w)
-              + "compile_ms".rjust(col_w) + "recompiles".rjust(col_w))
+    cols = list(next(iter(table["families"].values())))
+    cols.append(cols.pop(cols.index("recompiles")))  # last, as it was
+    col_w = 14 if len(cols) == 3 else 12
+    header = "family".ljust(name_w) + "".join(c.rjust(col_w) for c in cols)
     lines.append(header)
     lines.append("-" * len(header))
     for name in sorted(table["families"]):
         f = table["families"][name]
-        lines.append(name.ljust(name_w) + str(f["count"]).rjust(col_w)
-                     + _fmt(f["compile_ms"]).rjust(col_w)
-                     + str(f["recompiles"]).rjust(col_w))
+        lines.append(name.ljust(name_w) + "".join(
+            (_fmt(f[c]) if isinstance(f[c], float) else str(f[c])).rjust(col_w)
+            for c in cols))
     return "\n".join(lines) + "\n"
 
 
