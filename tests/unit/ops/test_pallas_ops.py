@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.ops.pallas.flash_attention import flash_attention, mha_reference
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention, mha_reference, tile_walk
 from deepspeed_tpu.ops.quantizer import (
     dequantize,
     fake_quantize,
@@ -16,13 +16,13 @@ from deepspeed_tpu.ops.quantizer import (
 )
 
 
-def _qkv(B=2, S=128, H=4, hd=64, nkv=None, seed=0, dtype=np.float32):
+def _qkv(B=2, S=128, H=4, hd=64, nkv=None, seed=0, dtype=np.float32, Sk=None):
     rs = np.random.RandomState(seed)
-    nkv = nkv or H
+    nkv, Sk = nkv or H, Sk or S
     return (
         jnp.asarray(rs.randn(B, S, H, hd).astype(dtype)),
-        jnp.asarray(rs.randn(B, S, nkv, hd).astype(dtype)),
-        jnp.asarray(rs.randn(B, S, nkv, hd).astype(dtype)),
+        jnp.asarray(rs.randn(B, Sk, nkv, hd).astype(dtype)),
+        jnp.asarray(rs.randn(B, Sk, nkv, hd).astype(dtype)),
     )
 
 
@@ -72,6 +72,98 @@ class TestFlashAttention:
         tokens = jnp.asarray(np.random.RandomState(0).randint(0, 64, (2, 32)).astype(np.int32))
         l0, l1 = m0.loss(params, {"input_ids": tokens}), m1.loss(params, {"input_ids": tokens})
         np.testing.assert_allclose(float(l1), float(l0), rtol=1e-4)
+
+
+# (Sq, Sk, kwargs of flash_attention, grouped kv heads, gradients too): by the
+# classes of tile the walk meets at the shape (``flash_attention._Walk``)
+TILE_CLASS_CASES = {
+    # skipped + full + crossed tiles of 128, every loop bound static
+    "causal-384": (384, 384, {}, None, True),
+    "causal-512": (512, 512, {}, None, True),
+    "causal-640": (640, 640, {}, None, True),
+    # every tile full: no mask is built
+    "non-causal-256": (256, 256, dict(causal=False), None, True),
+    # a band narrower than a tile (two crossed tiles a row of tiles, none full)
+    "window-48-of-384": (384, 384, dict(window=48), None, True),
+    # ... wider than one, misaligned and aligned (crossed, full, crossed)
+    "window-200-of-512": (512, 512, dict(window=200), None, True),
+    "window-256-of-512": (512, 512, dict(window=256), None, True),
+    "grouped-heads-256": (256, 256, {}, 2, True),
+    # the grid cut on both axes: traced bounds, clamped index maps, steps past the diagonal
+    "causal-512-blocks-256": (512, 512, dict(block_q=256, block_k=256), None, True),
+    "causal-512-blocks-256x128": (512, 512, dict(block_q=256, block_k=128), None, True),
+    "window-200-of-512-blocks-256": (512, 512, dict(window=200, block_q=256, block_k=256), None, True),
+    "non-causal-512-blocks-256": (512, 512, dict(causal=False, block_q=256, block_k=256), None, True),
+    # Sq != Sk, forward only
+    "causal-128-keys-384": (128, 384, {}, None, False),
+    "causal-384-keys-128": (384, 128, {}, None, False),
+    "non-causal-256-keys-128": (256, 128, dict(causal=False), None, False),
+    # one tile: S <= 128, and lengths no tile of 128 divides
+    "causal-64": (64, 64, {}, None, True),
+    "causal-128": (128, 128, {}, None, True),
+    "causal-192": (192, 192, {}, None, True),
+    "causal-320": (320, 320, {}, None, True),
+}
+
+
+@pytest.mark.parametrize("case", list(TILE_CLASS_CASES))
+def test_flash_parity_by_tile_class(case):
+    Sq, Sk, kw, nkv, grads = TILE_CLASS_CASES[case]
+    q, k, v = _qkv(B=1, S=Sq, Sk=Sk, H=4 if nkv else 2, nkv=nkv)
+    ref_kw = {a: b for a, b in kw.items() if a in ("causal", "window")}
+    out = flash_attention(q, k, v, **kw)
+    ref = mha_reference(q, k, v, **ref_kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-5)
+    if not grads:
+        return
+    gf = jax.grad(lambda *a: jnp.sum(flash_attention(*a, **kw) ** 2), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(mha_reference(*a, **ref_kw) ** 2), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-4)
+
+
+def _unmasked(Sq, Sk, causal, window):
+    d = np.arange(Sq)[:, None] - np.arange(Sk)[None, :]
+    ok = np.ones((Sq, Sk), bool)
+    if causal:
+        ok &= d >= 0
+    if window is not None:
+        ok &= d < window
+    return ok
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("Sq,Sk,bq,bk,causal,window", [
+    (1024, 1024, None, None, True, None),   # the training cells' shape: one grid step a head
+    (2048, 2048, None, None, True, None),   # still one step a head
+    (1024, 1024, 512, 512, True, None), (4096, 4096, None, None, True, None),
+    (4096, 4096, None, None, True, 256), (1024, 1024, None, None, True, 200),
+    (1024, 1024, 256, 512, True, 700), (512, 512, None, None, False, None),
+    (384, 128, None, None, True, None), (128, 384, None, None, True, None),
+    (192, 192, None, None, True, None), (256, 256, 64, 64, True, 17), (576, 576, None, None, True, None),
+])
+def test_tile_walk_covers_every_unmasked_pair_once(Sq, Sk, bq, bk, causal, window, kernel):
+    """The walk, asked on the host with the kernels' own object: every
+    unmasked (q, k) lies in exactly one visited tile, no visited tile is
+    wholly masked, and a tile that builds no mask holds no masked pair."""
+    ok = _unmasked(Sq, Sk, causal, window)
+    seen = np.zeros((Sq, Sk), np.int32)
+    for q0, k0, tq, tk, crossed in tile_walk(Sq, Sk, bq, bk, causal, window, kernel=kernel):
+        part = ok[q0:q0 + tq, k0:k0 + tk]
+        assert part.shape == (tq, tk) and part.any(), (q0, k0)
+        assert crossed == (not part.all()), (q0, k0, crossed)
+        seen[q0:q0 + tq, k0:k0 + tk] += 1
+    assert seen.max() == 1 and (seen[ok] == 1).all()
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_tile_walk_at_the_training_cells_shape(kernel):
+    """S 1,024 causal in one grid step a head: 36 of 64 tiles' worth of 128
+    computed (0.5625 S^2; blocks of 512 computed whole were 0.75), the 8 on
+    the diagonal masked and no other."""
+    tiles = tile_walk(1024, 1024, causal=True, kernel=kernel)
+    assert sum(t[4] for t in tiles) == 8 and all(t[2:4] == (128, 128) for t in tiles if t[4])
+    assert sum(t[2] * t[3] for t in tiles) == 36 * 128 * 128 <= 0.57 * 1024 * 1024
 
 
 class TestFlashResidualsUnderRemat:

@@ -145,6 +145,11 @@ CASES = {
     "flash-fwd-8x1024x16x64": _flash("fwd", 8, 1024, 16, 64),
     "flash-bwd-8x1024x16x64": _flash("bwd", 8, 1024, 16, 64),
     "flash-window-8x1024x16x64": _flash("window", 8, 1024, 16, 64),
+    # a chip's share of the four-chip training cell (gpt2-xl: 25 heads)
+    "flash-fwd-8x1024x25x64": _flash("fwd", 8, 1024, 25, 64),
+    "flash-bwd-8x1024x25x64": _flash("bwd", 8, 1024, 25, 64),
+    # the longest sequence a head walks in ONE grid step (`flash_attention._WHOLE`)
+    "flash-bwd-4x2048x16x128": _flash("bwd", 4, 2048, 16, 128),
     "flash-fwd-2x4096x32x128": _flash("fwd", 2, 4096, 32, 128),
     "flash-bwd-2x4096x32x128": _flash("bwd", 2, 4096, 32, 128),
     "flash-window-2x4096x32x128": _flash("window", 2, 4096, 32, 128),

@@ -132,7 +132,7 @@ def long_ctx_window_budget(S=4096, B=2, window=1024, block=512):
     the causal window, so attention flops AND k/v HBM reads scale by the
     band fraction. Backend-independent shape math — the auditable proxy
     for the bench's window arm until it runs on silicon."""
-    from deepspeed_tpu.ops.pallas.flash_attention import _band_width
+    from deepspeed_tpu.ops.pallas.flash_attention import _grid_blocks, tile_walk
 
     L, D, H, hd, V = 12, 768, 12, 64, 50257
     causal_area = S * S / 2
@@ -143,18 +143,11 @@ def long_ctx_window_budget(S=4096, B=2, window=1024, block=512):
     # flash — the band arm additionally prunes to the window fraction)
     attn_causal = 3 * L * 2 * B * H * S * S * hd
     matmul_flops = 3 * 2 * B * S * (L * 12 * D * D + D * V)
-    nq = S // block
-    # grid steps = DMA proxy (clamped/masked steps still prefetch their
-    # block); computed blocks = compute proxy (pl.when-skipped steps don't)
-    grid_full, grid_band = nq * nq, nq * _band_width(window, block, block, nq)
-    computed_full = nq * (nq + 1) // 2
-
-    def _band_ki_min(qi):
-        # smallest ki with ki*block + block - 1 >= qi*block - window + 1
-        # (the kernel's should_compute band edge)
-        return max(0, -(-(qi * block - window + 2 - block) // block))
-
-    computed_band = sum(qi - _band_ki_min(qi) + 1 for qi in range(nq))
+    # the kernels' own walk at this shape: tiles computed = compute AND fetch
+    # proxy (a tile that holds no unmasked pair is neither), tiles that build
+    # a mask = the ones a mask's edge crosses
+    blocks = _grid_blocks(S, S, hd * 2, block, block)
+    full, band = (tile_walk(S, S, *blocks, causal=True, window=w) for w in (None, window))
     step_full = (attn_causal + matmul_flops) / V5E_PEAK_FLOPS * 1e3
     step_band = (attn_causal * frac + matmul_flops) / V5E_PEAK_FLOPS * 1e3
     return {
@@ -163,8 +156,8 @@ def long_ctx_window_budget(S=4096, B=2, window=1024, block=512):
         "attn_causal_flops_G": round(attn_causal / 1e9, 1),
         "attn_band_flops_G": round(attn_causal * frac / 1e9, 1),
         "matmul_flops_G": round(matmul_flops / 1e9, 1),
-        "kv_grid_steps_full_vs_band": [grid_full, grid_band],
-        "kv_blocks_computed_full_vs_band": [computed_full, computed_band],
+        "kv_tiles_computed_full_vs_band": [len(full), len(band)],
+        "kv_tiles_masked_full_vs_band": [sum(t[4] for t in full), sum(t[4] for t in band)],
         "roofline_step_ms_full": round(step_full, 1),
         "roofline_step_ms_band": round(step_band, 1),
         "roofline_speedup": round(step_full / step_band, 3),
