@@ -522,8 +522,12 @@ class ContinuousBatchingEngine:
                 moe_imbalance_sum=0.0)
         if self._state_pool:
             # as the ticks report them (layer_plan.GDN_STATS): real tokens
-            # the chunks' scans took, rows whose state a tick stepped
-            self._tick_stats.update(gdn_chunk_tokens=0, gdn_step_rows=0)
+            # the chunks' scans took, rows whose state a tick stepped, named
+            # after the pool's mixer (gdn_* / ssm_*)
+            from deepspeed_tpu.models.layer_plan import state_counters
+
+            self._state_counters = state_counters(self.cfg)
+            self._tick_stats.update(dict.fromkeys(self._state_counters, 0))
         if self._latent_pool:
             # cached entries the rows' kernel read (each live row to its own
             # length, a latent layer; summed over rows and ticks), and
@@ -1508,8 +1512,8 @@ class ContinuousBatchingEngine:
                     stats["moe_imbalance_sum"] += most / mean
                 if self._state_pool:
                     at = k + 2 + TICK_STATS
-                    stats["gdn_chunk_tokens"] += int(arr[0, at])
-                    stats["gdn_step_rows"] += int(arr[0, at + 1])
+                    for i, name in enumerate(self._state_counters):
+                        stats[name] += int(arr[0, at + i])
             hook = self.span_hook
             if hook is not None:
                 t_ret = time.monotonic()

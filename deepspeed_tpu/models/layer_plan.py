@@ -3,7 +3,8 @@
 ``TransformerConfig.layer_kinds`` lists the kinds of layer a model has
 (:class:`~deepspeed_tpu.models.transformer.LayerKind`: its mixer, softmax
 attention with its reach, its key-value heads, its rotary base and whether a
-sink joins its softmax, or the gated delta rule; a dense or an expert FFN)
+sink joins its softmax, the gated delta rule or a state-space scan; a dense or
+an expert FFN)
 and ``layer_plan`` says which kind each layer is. Kinds differ in parameter SHAPES, so the parameters are stacked
 per kind (``params["layers"][kind.name]``, leading axis = that kind's
 layers in model order) and the stack is walked as **one scan per run of
@@ -30,6 +31,9 @@ to the chunk's own keys, then writes its last ``window`` tokens. A
 delta-rule layer keeps no keys: its rows live in ``cache["state"]``, a
 recurrent state and the tail of its convolution a row, which the rows' one
 token steps in place and a chunk scans from (``ops/pallas/gated_delta.py``).
+A state-space layer (Mamba-2) lives in the same pool with a state of its own
+shape and another rule (``ops/pallas/ssd.py``): a scalar decay a head and no
+overwrite, so a plan has delta-rule layers or state-space layers, not both.
 A latent-attention layer (MLA) keeps ONE vector a token in ``cache["latent"]``,
 its normed latent and the rotated key all heads share, which is the keys and
 the values of every head: the rows' one token attends it in the ABSORBED form
@@ -94,17 +98,26 @@ def check_plan(cfg):
             raise ValueError(f"latent-attention kinds need the five mla sizes {sizes}, an even rotary "
                              f"width, head_dim = unrotated + rotated width ({cfg.head_dim}) and "
                              f"v_head_dim = the value width ({cfg.v_head_dim})")
-    if any(k.pool == "state" for k in kinds):
+    if len({k.mixer for k in kinds if k.pool == "state"}) > 1:
+        raise ValueError("kinds of the state pool share one mixer (the pool's shape is the "
+                         "configuration's): delta-rule layers or state-space layers, not both")
+    if any(k.mixer == "gdn" for k in kinds):
         # the state pool's shape is the configuration's, so its kinds agree in it
         sizes = (cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim)
         if min(sizes) < 1 or cfg.gdn_value_heads % cfg.gdn_key_heads or cfg.gdn_conv < 2:
             raise ValueError(f"kinds of the state pool need gdn key/value heads and widths, value "
                              f"heads a multiple of key heads, and a convolution: {sizes}, "
                              f"{cfg.gdn_conv} taps")
+    if any(k.mixer == "ssm" for k in kinds):
+        sizes = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+        if min(sizes) < 1 or cfg.ssm_groups != 1 or cfg.ssm_conv < 2:
+            raise ValueError(f"state-space kinds of the state pool need ssm heads, head width and "
+                             f"state width, one group and a convolution: {sizes}, "
+                             f"{cfg.ssm_groups} groups, {cfg.ssm_conv} taps")
     if cfg.moe_score not in ("sigmoid", "softmax"):
         raise ValueError(f"moe_score {cfg.moe_score!r}")
     for k in kinds:
-        if k.mixer not in ("attention", "gdn", "mla"):
+        if k.mixer not in ("attention", "gdn", "ssm", "mla"):
             raise ValueError(f"kind {k.name}: mixer {k.mixer!r}")
         if k.mixer == "attention" and cfg.num_heads % k.kv_heads:
             raise ValueError(f"kind {k.name}: {cfg.num_heads} heads over {k.kv_heads} kv heads")
@@ -112,10 +125,13 @@ def check_plan(cfg):
             raise ValueError(f"kind {k.name}: ffn {k.ffn!r}")
         if k.ffn == "moe" and cfg.moe_num_experts < 1:
             raise ValueError(f"kind {k.name} routes but moe_num_experts is 0")
-    if (cfg.pos_embedding != "rope" or cfg.norm_position != "pre" or cfg.use_bias
+    if (cfg.pos_embedding not in ("rope", "none") or cfg.norm_position != "pre" or cfg.use_bias
             or cfg.activation != "silu_glu" or not cfg.causal or cfg.kv_cache_dtype != "model"):
-        raise ValueError("a layer plan takes rotary positions, pre-norm blocks, no biases, "
-                         "SwiGLU, causal attention and a KV cache in the model's dtype")
+        raise ValueError("a layer plan takes rotary positions or none at all (pos_embedding "
+                         "'rope' | 'none'), pre-norm blocks, no biases, SwiGLU, causal attention "
+                         "and a KV cache in the model's dtype")
+    if cfg.pos_embedding == "none" and any(k.mixer == "mla" for k in kinds):
+        raise ValueError("latent attention keeps a rotated key: it needs rotary positions")
 
 
 def runs(cfg):
@@ -163,6 +179,20 @@ def _layer_shapes(cfg, kind):
             ("gdn", "dt_bias"): ((Hv,), 1.0),
             ("gdn", "norm"): ((gv,), None),
             ("gdn", "wo"): ((Hv * gv, D), out_scale / math.sqrt(Hv * gv)),
+        })
+    elif kind.mixer == "ssm":
+        Hs, N = cfg.ssm_heads, cfg.ssm_state
+        inner = Hs * cfg.ssm_head_dim
+        C = inner + 2 * cfg.ssm_groups * N
+        shapes.update({
+            ("ssm", "win"): ((D, inner + C + Hs), 1 / math.sqrt(D)),      # z, [x | B | C] (convolved), dt
+            ("ssm", "conv"): ((C, cfg.ssm_conv), 1 / math.sqrt(cfg.ssm_conv)),
+            ("ssm", "conv_bias"): ((C,), 0.1),
+            ("ssm", "a_log"): ((Hs,), 1.0),
+            ("ssm", "dt_bias"): ((Hs,), 1.0),
+            ("ssm", "d"): ((Hs,), 1.0),
+            ("ssm", "norm"): ((inner,), None),
+            ("ssm", "wo"): ((inner, D), out_scale / math.sqrt(inner)),
         })
     elif kind.mixer == "mla":
         qr, kr, dn, dr = cfg.mla_q_rank, cfg.mla_kv_rank, cfg.mla_nope_dim, cfg.mla_rope_dim
@@ -269,8 +299,11 @@ def _project(h, attn_p, kind, cfg, positions):
         if cfg.qk_norm:  # over each head's width, before it turns
             q = tf._norm(q, attn_p["q_norm"], None, cfg)
             k = tf._norm(k, attn_p["k_norm"], None, cfg)
-        q = tf._rope(q, positions[None], kind.rope_theta, cfg.rope_dim, cfg.rope_interleaved)[0]
-        k = tf._rope(k, positions[None], kind.rope_theta, cfg.rope_dim, cfg.rope_interleaved)[0]
+        turn = lambda a: a    # pos_embedding "none": no position signal anywhere
+        if cfg.pos_embedding == "rope":
+            turn = lambda a: tf._rope(a, positions[None], kind.rope_theta, cfg.rope_dim,
+                                      cfg.rope_interleaved)
+        q, k = turn(q)[0], turn(k)[0]
         if cfg.attn_value_scale is not None:
             v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
     return q, k, v
@@ -363,16 +396,22 @@ def _gdn_project(h, p, cfg):
     return qkvz[:, :C], qkvz[:, C:].reshape(-1, Hv, cfg.gdn_value_dim), g, beta
 
 
+def _causal_conv_silu(seq, w, bias=None):
+    """Causal depthwise convolution (plus ``bias`` (C,)) then SiLU: seq (...,
+    T + K - 1, C), its first K - 1 steps the inputs before the first output;
+    w (C, K). Returns (..., T, C)."""
+    K = w.shape[1]
+    T = seq.shape[-2] - (K - 1)
+    acc = sum(seq[..., j:j + T, :].astype(jnp.float32) * w[:, j].astype(jnp.float32)
+              for j in range(K))
+    if bias is not None:
+        acc = acc + bias.astype(jnp.float32)
+    return jax.nn.silu(acc).astype(seq.dtype)
+
+
 def _gdn_conv(seq, w):
-    """Causal depthwise convolution then SiLU: seq (..., T + K - 1, C), its
-    first K - 1 steps the inputs before the first output; w (C, K).
-    Returns (..., T, C)."""
     with jax.named_scope(Scope.GDN_CONV):
-        K = w.shape[1]
-        T = seq.shape[-2] - (K - 1)
-        acc = sum(seq[..., j:j + T, :].astype(jnp.float32) * w[:, j].astype(jnp.float32)
-                  for j in range(K))
-        return jax.nn.silu(acc).astype(seq.dtype)
+        return _causal_conv_silu(seq, w)
 
 
 def _gdn_heads(u, cfg):
@@ -455,6 +494,109 @@ def _gdn_cached(h, p, cfg, pool, layer, B, chunk, valid):
             o = jnp.concatenate([o, oc])
         pool = {"s": states, "conv": jax.lax.dynamic_update_index_in_dim(pool["conv"], tails, layer, 0)}
         return _gdn_out(o, z, p, cfg), pool
+
+
+# -- the state-space mixer (Mamba-2; ops/pallas/ssd.py has the scan itself) --
+
+def _ssm_project(h, p, cfg):
+    """h (N, D) -> (z (N, inner) the gate's inputs, u (N, C) the convolution's
+    inputs [x | B | C], dt (N, H) = softplus(. + dt_bias), float32)."""
+    tf = _tf()
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    zxbcdt = tf._linear(h, p["win"])
+    dt = jax.nn.softplus(zxbcdt[:, -cfg.ssm_heads:].astype(jnp.float32)
+                         + p["dt_bias"].astype(jnp.float32))
+    return zxbcdt[:, :inner], zxbcdt[:, inner:-cfg.ssm_heads], dt
+
+
+def _ssm_conv(seq, p):
+    with jax.named_scope(Scope.SSM_CONV):
+        return _causal_conv_silu(seq, p["conv"], p["conv_bias"])
+
+
+def _ssm_lanes(per_head, cfg):
+    """(N, H) -> (N, inner): a head's scalar on each of its channels."""
+    return jnp.repeat(per_head, cfg.ssm_head_dim, axis=-1)
+
+
+def _ssm_parts(u, dt, p, cfg):
+    """Convolved u (N, C), dt (N, H) -> (x (N, inner), dt x, a = dt A (N, H),
+    B, C (N, state width)), float32. The heads stay side by side along the
+    channels (a (N, H, 64) array would leave half of every lane tile empty
+    and cost a re-layout each way: PERF.md section 6, PR 42)."""
+    N = cfg.ssm_state
+    u = u.astype(jnp.float32)
+    x = u[:, :-2 * N]
+    a = -jnp.exp(p["a_log"].astype(jnp.float32)) * dt
+    return x, _ssm_lanes(dt, cfg) * x, a, u[:, -2 * N:-N], u[:, -N:]
+
+
+def _ssm_out(y, x, z, p, cfg):
+    """y, x, z (N, inner) -> (N, D): the skip ``D x``, the gate silu(z), THEN
+    one RMSNorm over the whole inner width, through Wo."""
+    tf = _tf()
+    y = y + _ssm_lanes(p["d"].astype(jnp.float32)[None], cfg) * x
+    y = y * jax.nn.silu(z.astype(jnp.float32))
+    y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + cfg.norm_eps) * p["norm"].astype(jnp.float32)
+    return tf._linear(y.astype(z.dtype), p["wo"])
+
+
+def _hold_dt(dt, valid):
+    """A token that is not ``valid`` (a chunk's pad, a parked row, an empty
+    slot) takes ``dt = 0``: decay exp(0) = 1, nothing added, the step that
+    leaves the state exactly as it was (:func:`_hold`'s idea). Looked up when
+    a tick is traced (a test plants a fault here)."""
+    return jnp.where(valid[:, None], dt, 0.0)
+
+
+def _ssm_plain(h, p, cfg, B, S):
+    """The mixer over whole sequences from a zero state, token by token:
+    h (B * S, D) -> (B * S, D)."""
+    from deepspeed_tpu.ops.pallas.ssd import ssd_recurrence
+
+    with jax.named_scope(Scope.MIX_SSM):
+        z, u, dt = _ssm_project(h, p, cfg)
+        u = u.reshape(B, S, -1)
+        u = _ssm_conv(jnp.pad(u, ((0, 0), (cfg.ssm_conv - 1, 0), (0, 0))), p)
+        x, _, a, Bm, Cm = _ssm_parts(u.reshape(B * S, -1), dt, p, cfg)
+        rows = lambda v: v.reshape((B, S) + v.shape[1:])
+        heads = (cfg.ssm_heads, cfg.ssm_head_dim)
+        zero = jnp.zeros(heads + (cfg.ssm_state,), jnp.float32)
+        with jax.named_scope(Scope.SSM_SCAN):
+            y = jax.vmap(lambda *v: ssd_recurrence(*v, zero)[0])(
+                x.reshape((B, S) + heads), rows(dt), rows(a), rows(Bm), rows(Cm))
+        return _ssm_out(y.reshape(x.shape), x, z, p, cfg)
+
+
+def _ssm_cached(h, p, cfg, pool, layer, B, chunk, valid):
+    """The mixer of one layer of the tick, as :func:`_gdn_cached`: each valid
+    row's state takes one step in place and its convolution tail shifts by
+    one; the chunk scans from its own row's state and tail (that row is
+    parked among the rows) and leaves the state after its last real token and
+    that token's last inputs. Returns ((N, D), the state pool)."""
+    from deepspeed_tpu.ops.pallas.ssd import ssd_chunk_pool, ssd_step_pool
+
+    with jax.named_scope(Scope.MIX_SSM):
+        z, u, dt = _ssm_project(h, p, cfg)
+        dt = _hold_dt(dt, valid)
+        tails = jax.lax.dynamic_index_in_dim(pool["conv"], layer, 0, keepdims=False)   # (B, K-1, C)
+        seq = jnp.concatenate([tails, u[:B, None]], axis=1)
+        x, xd, a, Bm, Cm = _ssm_parts(_ssm_conv(seq, p)[:, 0], dt[:B], p, cfg)
+        with jax.named_scope(Scope.SSM_STEP):
+            y, states = ssd_step_pool(pool["s"], layer, xd, a, Bm, Cm)
+        tails = jnp.where(valid[:B, None, None], seq[:, 1:], tails)
+        if chunk is not None:
+            seq = jnp.concatenate([jax.lax.dynamic_index_in_dim(tails, chunk.slot, 0, keepdims=False),
+                                   u[B:]])
+            xc, xd, a, Bm, Cm = _ssm_parts(_ssm_conv(seq, p), dt[B:], p, cfg)
+            with jax.named_scope(Scope.SSM_SCAN):
+                yc, states = ssd_chunk_pool(states, layer, chunk.slot, xd, a, Bm, Cm)
+            real = valid[B:].sum(dtype=jnp.int32)
+            tail = jax.lax.dynamic_slice_in_dim(seq, real, cfg.ssm_conv - 1, axis=0)   # the last real token's inputs
+            tails = jax.lax.dynamic_update_index_in_dim(tails, tail, chunk.slot, 0)
+            y, x = jnp.concatenate([y, yc]), jnp.concatenate([x, xc])
+        pool = {"s": states, "conv": jax.lax.dynamic_update_index_in_dim(pool["conv"], tails, layer, 0)}
+        return _ssm_out(y, x, z, p, cfg), pool
 
 
 # -- the latent-attention mixer (MLA; ops/pallas/mla_attention.py reads the pool for the rows) --
@@ -583,8 +725,15 @@ GDN_STATS = 2   # beside the routing counters: real tokens the chunk's scan took
 
 def stats_len(cfg) -> int:
     """Counters a tick of ``cfg`` returns: the five routing counters, and
-    where it has delta-rule layers :data:`GDN_STATS` more."""
+    where it has a state pool :data:`GDN_STATS` more."""
     return 5 + (GDN_STATS if kv_cache.state_spec(cfg) is not None else 0)
+
+
+def state_counters(cfg) -> tuple:
+    """``tick_stats()``'s names for the :data:`GDN_STATS` counters, after the
+    state pool's mixer."""
+    mixer = next(k.mixer for k in cfg.layer_kinds if k.pool == "state")
+    return (mixer + "_chunk_tokens", mixer + "_step_rows")
 
 
 def _merge_stats(a, b):
@@ -635,10 +784,23 @@ def _walk(cfg, layers, carry, layer_fn):
     return carry
 
 
+def _embed(params, cfg, tokens, dtype):
+    x = jnp.take(params["embed"]["tok"], tokens, axis=0).astype(dtype)
+    return x if cfg.embed_scale == 1.0 else x * jnp.asarray(cfg.embed_scale, dtype)
+
+
+def _add(x, out, cfg):
+    """The residual stream plus what a mixer or an FFN gives, times the model's multiplier."""
+    return x + (out if cfg.residual_scale == 1.0 else out * jnp.asarray(cfg.residual_scale, out.dtype))
+
+
+def _logits(x, params, cfg):
+    logits = _tf()._vocab_head(x, params, cfg, cfg.jnp_dtype)
+    return logits if cfg.logit_scale == 1.0 else logits * jnp.asarray(cfg.logit_scale, logits.dtype)
+
+
 def _head(x, params, cfg):
-    tf = _tf()
-    x = tf._norm(x, params["final_norm"]["scale"], None, cfg)
-    return tf._vocab_head(x, params, cfg, cfg.jnp_dtype)
+    return _logits(_tf()._norm(x, params["final_norm"]["scale"], None, cfg), params, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -653,7 +815,7 @@ def forward_plan(params, cfg, tokens, return_hidden=False):
     dtype = cfg.jnp_dtype
     B, S = tokens.shape
     with jax.named_scope(Scope.EMBED):
-        x = jnp.take(params["embed"]["tok"], tokens, axis=0).astype(dtype)
+        x = _embed(params, cfg, tokens, dtype)
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S)).reshape(-1)
     qpos = jnp.arange(S, dtype=jnp.int32)[:, None]
     kpos = jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -661,6 +823,8 @@ def forward_plan(params, cfg, tokens, return_hidden=False):
     def mix(h, layer_p, kind):
         if kind.mixer == "gdn":
             return _gdn_plain(h, layer_p["gdn"], cfg, B, S)
+        if kind.mixer == "ssm":
+            return _ssm_plain(h, layer_p["ssm"], cfg, B, S)
         if kind.mixer == "mla":
             return _mla_plain(h, layer_p["mla"], kind, cfg, B, S, positions)
         q, k, v = _project(h, layer_p["attn"], kind, cfg, positions)
@@ -677,10 +841,10 @@ def forward_plan(params, cfg, tokens, return_hidden=False):
 
     def layer(x, layer_p, kind, _):
         h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg).reshape(B * S, -1)
-        x = x + mix(h, layer_p, kind).reshape(B, S, -1)
+        x = _add(x, mix(h, layer_p, kind).reshape(B, S, -1), cfg)
         h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg).reshape(B * S, -1)
         out, _ = _ffn(h, layer_p["mlp"], kind, cfg, None, grad=True)
-        return x + out.reshape(B, S, -1)
+        return _add(x, out.reshape(B, S, -1), cfg)
 
     if cfg.remat:
         layer = jax.checkpoint(layer, policy=tf._resolve_remat_policy(cfg.remat_policy),
@@ -689,7 +853,7 @@ def forward_plan(params, cfg, tokens, return_hidden=False):
     x = tf._norm(x, params["final_norm"]["scale"], None, cfg)
     if return_hidden:
         return x, jnp.float32(0.0)
-    return tf._vocab_head(x, params, cfg, dtype), jnp.float32(0.0)
+    return _logits(x, params, cfg), jnp.float32(0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -778,7 +942,7 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
     pool. Returns (logits (B, V), cache, stats (:func:`stats_len`,) int32:
     expert assignments made / to held experts / the most one held expert got
     in a layer / expert layers / held experts that got a token, summed over
-    the layers; with delta-rule layers also the real tokens the chunk's scan
+    the layers; with a state pool also the real tokens the chunk's scan
     took and the rows whose state this tick stepped)."""
     tf = _tf()
     dtype = cfg.jnp_dtype
@@ -791,7 +955,7 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
         all_toks = jnp.concatenate([tokens, chunk.toks])
         all_pos = jnp.concatenate([pos, chunk.pos])
     with jax.named_scope(Scope.EMBED):
-        x = jnp.take(params["embed"]["tok"], all_toks, axis=0).astype(dtype)
+        x = _embed(params, cfg, all_toks, dtype)
     valid = all_pos < length
 
     def layer(carry, layer_p, kind, pool_index):
@@ -800,6 +964,8 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
         h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg)
         if kind.mixer == "gdn":
             out, pool = _gdn_cached(h, layer_p["gdn"], cfg, pool, pool_index, B, chunk, valid)
+        elif kind.mixer == "ssm":
+            out, pool = _ssm_cached(h, layer_p["ssm"], cfg, pool, pool_index, B, chunk, valid)
         elif kind.mixer == "mla":
             out, leaf = _mla_cached(h, layer_p["mla"], kind, cfg, pool["c"], pool_index, pos,
                                     all_pos, chunk, read_len, length)
@@ -809,10 +975,10 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
             att, pk, pv = _attend_cached(q, k, v, layer_p["attn"], kind, cfg, pool["k"], pool["v"],
                                          pool_index, pos, chunk, read_len, length)
             out, pool = _attn_out(att, h, layer_p["attn"], cfg), {"k": pk, "v": pv}
-        x = x + out
+        x = _add(x, out, cfg)
         h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg)
         out, st = _ffn(h, layer_p["mlp"], kind, cfg, valid, grad=False)
-        return x + out, dict(pools, **{kind.pool: pool}), _merge_stats(stats, st)
+        return _add(x, out, cfg), dict(pools, **{kind.pool: pool}), _merge_stats(stats, st)
 
     x, cache, stats = _walk(cfg, tf._cast_layers(params["layers"], dtype),
                             (x, cache, jnp.zeros((5,), jnp.int32)), layer)

@@ -38,11 +38,12 @@ from deepspeed_tpu.telemetry.hlo_scopes import Scope
 class LayerKind:
     """One kind of decoder layer in a layer plan: its mixer (softmax
     attention of some shape, the gated delta rule, whose shape is the
-    configuration's ``gdn_*``, or latent attention, whose shape is its
-    ``mla_*``) and the kind of its FFN. Layers of one kind share parameter
-    shapes and are stacked together (``params["layers"][name]``); attention
-    layers of the same reach (``window`` 0 or not) share a KV pool,
-    delta-rule layers the state pool, latent layers the latent pool."""
+    configuration's ``gdn_*``, a state-space scan (Mamba-2), whose shape is
+    its ``ssm_*``, or latent attention, whose shape is its ``mla_*``) and the
+    kind of its FFN. Layers of one kind share parameter shapes and are
+    stacked together (``params["layers"][name]``); attention layers of the
+    same reach (``window`` 0 or not) share a KV pool, delta-rule or
+    state-space layers the state pool, latent layers the latent pool."""
     name: str
     kv_heads: int = 1  # of an attention mixer
     window: int = 0  # 0 = full causal attention; W = the last W positions
@@ -50,14 +51,15 @@ class LayerKind:
     sink: bool = False  # a learned per-head logit joins the softmax's denominator
     ffn: str = "dense"  # dense | moe (sigmoid top-k over the experts held)
     ffn_size: Optional[int] = None  # None => cfg.ffn_size
-    # attention | gdn (Gated DeltaNet: recurrent state, no keys kept) | mla (latent
+    # attention | gdn (Gated DeltaNet: recurrent state, no keys kept) | ssm (Mamba-2: a
+    # state-space scan with a scalar decay a head, no keys kept) | mla (latent
     # attention: one latent and one rotated key a token, shared by every head)
     mixer: str = "attention"
 
     @property
     def pool(self) -> str:
         """The cache pool this kind's layers live in."""
-        if self.mixer == "gdn":
+        if self.mixer in ("gdn", "ssm"):
             return "state"
         if self.mixer == "mla":
             return "latent"
@@ -187,6 +189,20 @@ class TransformerConfig:
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
     gdn_conv: int = 4
+    # Mamba-2 mixer (LayerKind.mixer == "ssm"): heads x head width is its inner
+    # width, every head keeps a (head width, state width) float32 state, ``B``
+    # and ``C`` are shared by the heads of a group, and the causal depthwise
+    # convolution (with a bias) runs over inner width + 2 x groups x state width
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    # a plan's scalar multipliers: the embedding's output, what a mixer or an
+    # FFN adds to the residual stream, the logits (attn_scale is above)
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
     # Latent attention mixer (LayerKind.mixer == "mla", DeepSeek-V2's MLA): the
     # queries' low rank, the latent a token keeps (its keys' and values' low
     # rank), each head's unrotated and rotated query/key widths and its value

@@ -7,7 +7,7 @@ A cache is a tree of pools the slot manager carries and donates whole:
 ``(L, B, T, kv_heads, width)`` (time before heads) or ``(L, B, kv_heads, T,
 width)`` (heads before time), or int8 (``kv_cache_dtype="int8"``) the pair
 ``{"q8": payload, "s": float32 per-token-per-head scales}`` of such arrays.
-A plan with delta-rule layers has one pool more, ``"state": {"s", "conv"}``
+A plan with delta-rule or state-space layers has one pool more, ``"state": {"s", "conv"}``
 (:class:`StateSpec`), which has NO time axis: a row's recurrent state ``(L,
 B, heads, key width, value width)`` float32 and the last taps - 1 inputs of
 its convolution ``(L, B, taps - 1, channels)`` are read whole and rewritten
@@ -64,9 +64,9 @@ class PoolSpec(NamedTuple):
 
 
 class StateSpec(NamedTuple):
-    """The state pool of a plan's delta-rule layers."""
+    """The state pool of a plan's delta-rule or state-space layers."""
     layers: int
-    heads: int             # value heads: one (k_width, v_width) state each
+    heads: int             # value heads (state-space: lane tiles of heads): a (k_width, v_width) state each
     k_width: int
     v_width: int
     tail: int              # convolution taps - 1: the inputs kept
@@ -86,9 +86,19 @@ def _is_plan(cfg) -> bool:
 
 def state_spec(cfg) -> Optional[StateSpec]:
     """The state pool of ``cfg``'s cache; None where no layer keeps one."""
-    n = sum(k.pool == "state" for k in cfg.plan) if _is_plan(cfg) else 0
+    kinds = [k for k in cfg.plan if k.pool == "state"] if _is_plan(cfg) else []
+    n = len(kinds)
     if not n:
         return None
+    if kinds[0].mixer == "ssm":
+        # a state-space head's state, stored transposed (state width down, head width along
+        # the lanes) with as many heads side by side as fill the 128 lanes: ops/pallas/ssd.py
+        from deepspeed_tpu.ops.pallas.ssd import heads_per_tile
+
+        g = heads_per_tile(cfg.ssm_head_dim, cfg.ssm_heads)
+        inner = cfg.ssm_heads * cfg.ssm_head_dim
+        return StateSpec(n, cfg.ssm_heads // g, cfg.ssm_state, g * cfg.ssm_head_dim,
+                         cfg.ssm_conv - 1, inner + 2 * cfg.ssm_groups * cfg.ssm_state)
     channels = 2 * cfg.gdn_key_heads * cfg.gdn_key_dim + cfg.gdn_value_heads * cfg.gdn_value_dim
     return StateSpec(n, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim,
                      cfg.gdn_conv - 1, channels)

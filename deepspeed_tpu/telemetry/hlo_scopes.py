@@ -48,6 +48,10 @@ class Scope:
     GDN_CONV = "gdn.conv"        # ... its causal depthwise convolution
     GDN_SCAN = "gdn.scan"        # ... one prefill chunk's scan (the gdn_chunk_fwd kernel)
     GDN_STEP = "gdn.step"        # ... the rows' one-token state step
+    MIX_SSM = "mix.ssm"          # a state-space (Mamba-2) mixer: projections, the step size, the gated norm
+    SSM_CONV = "ssm.conv"        # ... its causal depthwise convolution and bias
+    SSM_SCAN = "ssm.scan"        # ... one prefill chunk's scan (the ssd_chunk_fwd kernel)
+    SSM_STEP = "ssm.step"        # ... the rows' one-token state step (the ssd_step kernel)
     MIX_MLA = "mix.mla"          # a latent-attention mixer: everything between its two norms
     MLA_Q = "mla.q"              # ... the queries: down, norm, up, the rotary part turned
     MLA_LATENT = "mla.latent"    # ... the latent and the shared rotated key of each token

@@ -271,3 +271,82 @@ def test_glm_tick_updates_the_latent_pool_in_place(topo, read_len, chunk):
     assert "mla_decode" in text and ("flash_chunk_fwd" in text) == (chunk is not None)
     assert ("mla_expand" in text) == (chunk is not None)
     assert "kv_block_write" in text                                  # 2,048 slots x 1,280 B a row: over the rule
+
+
+SSD_POOL = ((9, 32, 64, 128, 128), jnp.float32)           # Granite 4.0-H Small's state pool: 1.21 GB
+
+
+def _in_place(compiled):
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9         # no copy of the pool (1.21 GB)
+
+
+def test_state_space_rows_step_updates_the_state_pool_in_place(topo):
+    from deepspeed_tpu.ops.pallas.ssd import ssd_step_pool
+
+    f32 = jnp.float32
+    _in_place(_compile(topo, ssd_step_pool, SSD_POOL, ((), jnp.int32), ((32, 8192), f32),
+                       ((32, 128), f32), ((32, 128), f32), ((32, 128), f32)))
+
+
+@pytest.mark.parametrize("W", [1024, 128])
+def test_state_space_chunk_scan_compiles_with_the_state_pool_in_place(topo, W):
+    from deepspeed_tpu.ops.pallas.ssd import ssd_chunk_pool
+
+    f32, i32 = jnp.float32, jnp.int32
+    _in_place(_compile(topo, ssd_chunk_pool, SSD_POOL, ((), i32), ((), i32), ((W, 8192), f32),
+                       ((W, 128), f32), ((W, 128), f32), ((W, 128), f32)))
+
+
+@pytest.mark.parametrize("read_len,chunk", [(None, None), (None, 1024), (2048, 256)],
+                         ids=["plain", "fused1024", "fused256-read2048"])
+def test_granite_tick_updates_both_kinds_of_pool_in_place(topo, read_len, chunk):
+    """What a state-space mixer adds (PR 42): the Granite-4.0-H-Small tick at
+    the benchmark's cut with the key-value pool of its one attention layer
+    and the float32 state pool of its nine Mamba-2 layers both in place
+    (the two kernels alone: the tests above)."""
+    from benchmark import models_granitemoehybrid
+    from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
+    from deepspeed_tpu.models import transformer as tf
+
+    with open(os.path.join(ROOT, "benchmark", "configs", "granite-4.0-h-small.json")) as fh:
+        config = json.load(fh)
+    slots, length = 32, 16896
+    model = models_granitemoehybrid.build_model(config, max_seq_len=length, remat=False,
+                                                attn_impl="pallas")
+    cfg = model.cfg
+    mesh = comm.build_mesh({"data": 1, "tensor": 1}, devices=topo.devices[:1])
+    one = NamedSharding(mesh, PartitionSpec())
+    abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    p_sh = jax.tree.map(lambda a: one, abstract)
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one),
+                          abstract)
+    with force_interpret(False):
+        fn, cache_sh, _ = compile_pool_tick_fn(mesh, cfg, p_sh, slots, length, 1, 0.0, 0, 1.0,
+                                               read_len=read_len, chunk=chunk)
+        cache = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            jax.eval_shape(lambda: tf.init_cache(cfg, slots, length)), cache_sh)
+        row = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+        args = [params, cache, row, row, row, row, row, row, jax.ShapeDtypeStruct((2,), jnp.uint32)]
+        if chunk is not None:
+            wide = jax.ShapeDtypeStruct((chunk,), jnp.int32)
+            args += [wide, wide, jax.ShapeDtypeStruct((), jnp.int32), row, row]
+        compiled = fn.lower(*args).compile()
+    comm.destroy()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert pool_bytes == 32 * 8 * 16896 * 256 * 2 + 9 * 32 * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes                     # 2.21 + 1.22 GB, in place
+    assert mem.temp_size_in_bytes < 0.7e9, mem.temp_size_in_bytes
+    resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert resident < 0.85 * 16.91e9, resident
+    print("granite tick", read_len, chunk, "temp", mem.temp_size_in_bytes / 1e9, "arguments",
+          mem.argument_size_in_bytes / 1e9, "resident", resident / 1e9)
+    text = compiled.as_text()
+    # neither pool is copied, no slab of the state pool is sliced out, no run's weights either
+    assert not re.findall(r"= bf16\[(?:\d+,)?32,8,16896,\d+\]\S* copy\(", text)
+    assert not re.findall(r"= f32\[(?:\d+,)?32,64,128,128\]\S* (?:copy|dynamic-slice)\(", text)
+    assert not re.findall(r"= bf16\[[45],4096,16768\]\S* slice\(", text)
+    assert "ssd_step" in text and ("ssd_chunk_fwd" in text) == (chunk is not None)
+    assert ("flash_chunk_fwd" in text) == (chunk is not None)

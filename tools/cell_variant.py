@@ -1,5 +1,6 @@
 """What the per-configuration variant tools share (``tools/mimo_cell_variant.py``,
-``tools/qwen3_next_cell_variant.py``, ``tools/glm_cell_variant.py``): one
+``tools/qwen3_next_cell_variant.py``, ``tools/glm_cell_variant.py``,
+``tools/granite_cell_variant.py``): one
 command line that runs a cell through the harness, as the driver runs it, with
 ONE thing swapped for the length of the run, and the swaps that are the same
 for every configuration. A variant is a function of the cell's configuration
@@ -44,6 +45,14 @@ def reference_without(piece):
 
     variant.__name__ = "no_" + piece
     return variant
+
+
+def no_reset(config):
+    """The program admits a request to a slot without zeroing the slot's row
+    of the state pool (the last request's state leaks into the next one's)."""
+    from deepspeed_tpu.ops.transformer import kv_cache
+
+    return swapped(kv_cache, "reset_row", lambda state, slot: state)
 
 
 def fp8(config):
