@@ -510,6 +510,15 @@ class ContinuousBatchingEngine:
             # attended in a full and in a window layer, keys a full layer read
             self._tick_stats.update(prefill_pairs_full=0,
                                     prefill_pairs_window=0, prefill_keys_full=0)
+            # ... and what the flash chunk kernel did for it, summed over the
+            # plan's layers: score tiles computed (a tile a query head), of
+            # them masked, K/V tiles fetched (a tile a key-value head) —
+            # ``layer_plan.chunk_attention_tiles``, the kernel's own walk
+            from deepspeed_tpu.models.layer_plan import chunk_attention_tiles
+
+            self._chunk_tiles = chunk_attention_tiles
+            self._tick_stats.update(prefill_tiles_visited=0, prefill_tiles_masked=0,
+                                    prefill_kv_tile_fetches=0)
             self._window = max(k.window for k in self.cfg.layer_kinds)
         if self._moe_stats:
             # expert routing as the ticks report it (decoding.TICK_STATS):
@@ -1279,6 +1288,10 @@ class ContinuousBatchingEngine:
                 st["prefill_pairs_window"] += int(np.minimum(
                     np.arange(cpos0 + 1, cpos0 + nreal + 1), self._window).sum())
                 st["prefill_keys_full"] += cpos0 + nreal
+                visited, masked, fetched = self._chunk_tiles(self.cfg, W, read_len or pool.length, cpos0)
+                st["prefill_tiles_visited"] += visited
+                st["prefill_tiles_masked"] += masked
+                st["prefill_kv_tile_fetches"] += fetched
                 if self._latent_pool:
                     st["mla_expand_tokens"] += expanded_entries(cpos0 + W, read_len or pool.length)
             chunk_toks = np.zeros(W, np.int32)

@@ -720,6 +720,29 @@ def _mla_cached(h, p, kind, cfg, pool, layer, pos, all_pos, chunk, read_len, len
         return tf._attn_out_proj(att, p, cfg), pool
 
 
+def chunk_attention_tiles(cfg, W: int, size: int, first: int):
+    """What the flash chunk kernel does for ONE prefill chunk of ``W`` columns
+    whose first token sits at position ``first``, the full pool read to
+    ``size``, summed over the plan's attention layers: (score tiles computed,
+    of them masked, K/V tiles fetched) — the calls :func:`_attend_cached` and
+    :func:`_mla_cached` make, asked of the kernel's own walk on the host."""
+    from deepspeed_tpu.ops.pallas.flash_attention import chunk_tiles
+
+    nh, dk, dv, itemsize = cfg.num_heads, cfg.head_dim, cfg.v_head_dim, jnp.dtype(cfg.jnp_dtype).itemsize
+    total = [0, 0, 0]
+    for kind in cfg.layer_kinds:
+        if kind.pool == "state":
+            continue
+        if kind.window:  # the ring's tail joined to the chunk's own keys, the offset a Python int
+            R = kind.window
+            call = chunk_tiles(W, nh, kind.kv_heads, R + W, dk, dv, R, max(R - first, 0), R, True, itemsize)
+        else:            # the row as cached; a latent layer expands a key-value head a query head
+            kv = nh if kind.mixer == "mla" else kind.kv_heads
+            call = chunk_tiles(W, nh, kv, size, dk, dv, first, itemsize=itemsize)
+        total = [t + layers_of(cfg, kind) * c for t, c in zip(total, call)]
+    return tuple(total)
+
+
 GDN_STATS = 2   # beside the routing counters: real tokens the chunk's scan took, rows stepped
 
 

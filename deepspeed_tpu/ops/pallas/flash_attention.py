@@ -94,6 +94,20 @@ def _in_tiles(size):
     return size <= _WHOLE[0] and size % _TILE == 0
 
 
+def _edges(d0, rows, cols, lo, hi):
+    """(some, every) of a ``rows`` x ``cols`` tile whose first pair has ``outer
+    - inner = d0``: does it hold an unmasked pair, and no masked one? A pair is
+    unmasked iff ``lo <= outer - inner <= hi`` (None: unbounded). ``d0`` is a
+    Python int (a static walk's) or a traced scalar (a grid step's)."""
+    dmin, dmax = d0 - cols + 1, d0 + rows - 1
+    some = every = True
+    if lo is not None:
+        some, every = some & (dmax >= lo), every & (dmin >= lo)
+    if hi is not None:
+        some, every = some & (dmin <= hi), every & (dmax <= hi)
+    return some, every
+
+
 def _visited(o0, to, ti, n, lo, hi):
     """The inner blocks (``ti`` wide, ``n`` of them) that hold an unmasked
     pair with outer positions ``[o0, o0 + to)``, as ``(first, end)``; a pair
@@ -181,11 +195,8 @@ class _Walk:
         out = []
         for r0 in range(0, self.bo, self.to):
             def kind(c0, width):  # 0 all masked, 1 crossed, 2 none masked
-                dmin, dmax = off + r0 - c0 - width + 1, off + r0 + self.to - 1 - c0
-                if (self.lo is not None and dmax < self.lo) or (self.hi is not None and dmin > self.hi):
-                    return 0
-                return 2 if ((self.lo is None or dmin >= self.lo)
-                             and (self.hi is None or dmax <= self.hi)) else 1
+                some, every = _edges(off + r0 - c0, self.to, width, self.lo, self.hi)
+                return 2 if every else int(some)
             inner, c0 = [], 0
             while c0 < self.bi:
                 if c0 % self.tw == 0 and kind(c0, self.tw) == 2:
@@ -488,10 +499,191 @@ def _kernel_forms(res, do):
 # one prefill chunk against a cached row (forward only)
 # ---------------------------------------------------------------------------
 
-def _chunk_kernel(scal_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                  sm_scale, bq, bk, nk, window, has_sink):
-    h, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    q_off, k_min = scal_ref[0], scal_ref[1]
+_CHUNK_VMEM = 12 << 20  # bytes a grid step's query-side blocks and state may take (they set the query block)
+# query rows that go through ONE product against a key block, as whole heads of the group stacked: a key block
+# is the MXU's stationary operand, and a head's 256 rows alone do not pay for loading it (ms a call on v5e,
+# 256 / 1,024 / 2,048 / 4,096 rows: MiMo's full layer 3.66 / 3.01 / 3.03 / 3.38, Qwen3-Next's 1.15 / 0.98 /
+# 0.94 / 0.94, Granite's at 512 / 1,024 / 2,048 1.31 / 1.37 / 1.35: PERF.md section 6, PR 43)
+_CHUNK_ROWS = 2048
+
+
+@dataclasses.dataclass(frozen=True)
+class _ChunkWalk:
+    """One ``flash_attention_chunk`` call's walk of its (W, T) score matrix,
+    query i at key index ``q_off + i``. A grid step belongs to a KEY-VALUE
+    head: a block of ``bq`` queries of each of the ``group`` query heads that
+    share it, against ``bk`` of its keys, fetched once for all of them. The
+    kernel's index maps, its choice of a masked or an unmasked tile and the
+    host's count of both (:func:`chunk_tiles`) all ask this object.
+
+    ``q_off`` a Python int, and both lengths short and in tiles (``straight``):
+    every edge but ``k_min`` is static, so the key head's keys and values are
+    ONE block and a query head is straight-line code over 128-row tiles
+    against the key tiles its band holds (``_Walk.tiles``: no tile outside the
+    band or above the diagonal, a mask only where an edge crosses), the state
+    in registers. Else ``q_off`` is a traced scalar: the grid's last axis
+    steps through the key blocks, a pair of blocks is one tile, visited and
+    masked by a scalar choice a step, the state in VMEM scratch."""
+    W: int
+    T: int
+    group: int
+    bq: int
+    bk: int
+    window: Optional[int]
+    q_off: Optional[int]  # where the walk is straight
+
+    @classmethod
+    def of(cls, W, T, group, dk, dv, itemsize, q_off, window):
+        straight = (isinstance(q_off, int) and _in_tiles(W) and _in_tiles(T)
+                    and T * (dk + dv) * itemsize <= 2 * _WHOLE[1])
+        bq, bk = (W, T) if straight else (_auto_block(W, None), _auto_block(T, None))
+        # a query row of the step: q and out of every head of the group, twice (the pipeline's two
+        # buffers), and where the state crosses steps its float32 acc, m and l (lane-broadcast)
+        row = group * (2 * (dk + dv) * itemsize + (0 if straight else (dv + 2 * 128) * 4))
+        while bq * row > _CHUNK_VMEM and bq % (2 * _TILE) == 0:
+            bq //= 2
+        return cls(W, T, group, bq, bk, window, q_off if straight else None)
+
+    @property
+    def straight(self):
+        return self.q_off is not None
+
+    @property
+    def grid(self):
+        return self.W // self.bq, self.T // self.bk
+
+    @property
+    def hi(self):  # a pair is unmasked iff 0 <= qpos - kpos <= hi, and kpos >= k_min
+        return None if self.window is None else self.window - 1
+
+    @property
+    def stacked(self):
+        """Query heads of the group that a grid step stacks into one product (``_CHUNK_ROWS``)."""
+        return max(n for n in range(1, self.group + 1)
+                   if self.group % n == 0 and n * self.bq <= max(_CHUNK_ROWS, self.bq))
+
+    @staticmethod
+    def begun(k_lo, cols, k_min):
+        """``k_min``'s part of :meth:`kind`: (some, every) key of the tile is a key of the row."""
+        return k_min < k_lo + cols, k_min <= k_lo
+
+    def kind(self, q_lo, rows, k_lo, cols, k_min):
+        """(some, every) of the tile of ``rows`` queries from position ``q_lo``
+        and ``cols`` keys from ``k_lo``: does it hold an unmasked pair, and no
+        masked one? Python ints (the host's count) or traced scalars (a step's)."""
+        (some, every), (begun, whole) = _edges(q_lo - k_lo, rows, cols, 0, self.hi), self.begun(k_lo, cols, k_min)
+        return some & begun, every & whole
+
+    def blocks(self, q_lo, k_min):
+        """(first, end): the key blocks that hold an unmasked pair with the
+        query block at position ``q_lo``."""
+        first, end = _visited(q_lo, self.bq, self.bk, self.grid[1], 0, self.hi)
+        if isinstance(q_lo, int):
+            return max(first, min(k_min // self.bk, end)), end
+        return jnp.maximum(first, jnp.minimum(k_min // self.bk, end)), end
+
+    def tiles(self, qi):
+        """A straight walk's query block ``qi``: for each 128-row query tile
+        (its row in the block, key tiles), a key tile (its first key, its
+        width, ``qpos - kpos`` of its first pair where the diagonal or the
+        band's edge crosses it, else None). ``k_min`` is not in it."""
+        walk = _Walk(self.W, self.T, self.bq, self.T, _TILE, _TILE, _TILE, 0, self.hi, True)
+        return walk.tiles(self.q_off + qi * self.bq)
+
+    def visits(self, q_off, k_min):
+        """What the kernel does for ONE query head at these offsets (Python
+        ints): the tiles it computes, (query row in the chunk, first key, rows,
+        keys, whether it builds a mask) each, and the K/V tiles its key-value
+        head's steps fetch."""
+        tiles, fetched = [], 0
+        for qi in range(self.grid[0]):
+            q0 = qi * self.bq
+            if self.straight:
+                fetched = self.T // _TILE  # the head's keys and values once, whole
+                for r0, inner in self.tiles(qi):
+                    for c0, width, d0 in inner:
+                        some, every = self.begun(c0, width, k_min)
+                        if some:
+                            tiles.append((q0 + r0, c0, _TILE, width, d0 is not None or not every))
+                continue
+            first, end = self.blocks(q_off + q0, k_min)
+            fetched += max(end - first, 1)
+            for ki in range(first, end):
+                some, every = self.kind(q_off + q0, self.bq, ki * self.bk, self.bk, k_min)
+                if some:
+                    tiles.append((q0, ki * self.bk, self.bq, self.bk, not every))
+        return tiles, fetched
+
+
+def _chunk_kernel(scal_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, *scratch, sm_scale, walk, has_sink):
+    """Online softmax of a key-value head's query heads (``q_ref``, ``o_ref``:
+    (group, bq, width)) over the key block in VMEM: float32 scores, statistics
+    and accumulator, the input dtype into the MXU; the sink joins the
+    denominator at a row's last tile and brings no value."""
+    hk, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    k_min, (nq, nk), bq, bk = scal_ref[1], walk.grid, walk.bq, walk.bk
+
+    def cut(ok, shape, k_lo):  # keys before the row's first
+        late = k_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 1) >= k_min
+        return late if ok is None else ok & late
+
+    def tile(q, k, v, ok, m, l, acc, lanes=False):
+        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * sm_scale
+        if ok is not None:
+            s = jnp.where(ok, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        if ok is not None:  # a row all masked so far keeps l = 0
+            p = jnp.where(ok, p, 0.0)
+        corr = jnp.exp(m - m_new)
+        if lanes:
+            psum = functools.reduce(jnp.add, [p[:, c:c + 128] for c in range(0, p.shape[1], 128)])
+        else:
+            psum = jnp.sum(p, axis=-1, keepdims=True)
+        return (m_new, l * corr + psum,
+                acc * corr + jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32))
+
+    def finish(g, m, l, acc):
+        if has_sink:
+            sink = sink_ref[hk * walk.group + g]
+            m_all = jnp.maximum(m, sink)
+            corr = jnp.exp(m - m_all)
+            l, acc = l * corr + jnp.exp(sink - m_all), acc * corr
+        return (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+
+    if walk.straight:  # every edge but k_min is static: a query head is straight-line code
+        def head(g, tiles):
+            edges = {}  # the masks of the diagonal and the band's edge: one a value of d0
+            for r0, inner in tiles:
+                rows = slice(r0, r0 + _TILE)
+                q = q_ref[g, rows]
+                state = (jnp.full((_TILE, 1), NEG_INF, jnp.float32), jnp.zeros((_TILE, 1), jnp.float32),
+                         jnp.zeros((_TILE, v_ref.shape[-1]), jnp.float32))
+                for c0, width, d0 in inner:
+                    if d0 is not None and (d0, width) not in edges:
+                        edges[d0, width] = _mask(d0, (_TILE, width), 0, 0, walk.hi)
+                    ok, cols = edges.get((d0, width)), slice(c0, c0 + width)
+
+                    def step(state, early, ok=ok, cols=cols, c0=c0, width=width):
+                        return tile(q, k_ref[cols], v_ref[cols], cut(ok, (_TILE, width), c0) if early else ok, *state)
+                    if c0 >= walk.q_off:  # the chunk's own keys: k_min is not past them
+                        state = step(state, False)
+                        continue
+                    some, every = walk.begun(c0, width, k_min)  # a scalar choice
+                    paths = [lambda s: s, functools.partial(step, early=True)]
+                    if d0 is None:
+                        paths.append(functools.partial(step, early=False))
+                    which = some.astype(jnp.int32) + ((some & every).astype(jnp.int32) if d0 is None else 0)
+                    state = jax.lax.switch(which, paths, state)
+                o_ref[g, rows] = finish(g, *state)
+
+        for n in range(nq):
+            @pl.when(qi == n)
+            def _block(n=n):
+                jax.lax.fori_loop(0, walk.group, lambda g, c: (head(g, walk.tiles(n)), c)[1], 0)
+        return
+
+    m_scr, l_scr, acc_scr = scratch  # the state crosses the key blocks' steps
 
     @pl.when(ki == 0)
     def _init():
@@ -499,42 +691,37 @@ def _chunk_kernel(scal_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, 
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q_lo, k_lo = q_off + qi * bq, ki * bk
-    should_compute = (k_lo <= q_lo + bq - 1) & (k_lo + bk - 1 >= k_min)
-    if window is not None:
-        should_compute = should_compute & (k_lo + bk - 1 > q_lo - window)
+    q_lo, k_lo = scal_ref[0] + qi * bq, ki * bk
+    some, every = walk.kind(q_lo, bq, k_lo, bk, k_min)
 
-    @pl.when(should_compute)
-    def _compute():
-        q, k, v = q_ref[...], k_ref[...], v_ref[...]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # (bq, bk) f32
-        qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        ok = (kpos <= qpos) & (kpos >= k_min)
-        if window is not None:
-            ok = ok & (qpos - kpos < window)
-        s = jnp.where(ok, s, NEG_INF)
-        m_prev, l_prev = m_scr[:, :1], l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)  # a row all masked so far keeps l = 0
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    # l as a sum a LANE where the key block is whole lanes: whole vectors added a step, and ONE reduction
+    # along lanes at the row's end (a reduction along lanes a step cost a fifth to two fifths of a call)
+    lanes, sub = bk % 128 == 0, walk.stacked
+    rows = sub * bq
+
+    def block(masked):
+        k, v = k_ref[...], v_ref[...]
+        ok = cut(_mask(q_lo - k_lo, (bq, bk), 0, 0, walk.hi), (bq, bk), k_lo) if masked else None
+        if masked and sub > 1:  # one mask for the group
+            ok = jnp.broadcast_to(ok[None], (sub, bq, bk)).reshape(rows, bk)
+        for part in (slice(g, g + sub) for g in range(0, walk.group, sub)):
+            l = l_scr[part].reshape(rows, 128)
+            m, l, acc = tile(q_ref[part].reshape(rows, -1), k, v, ok, m_scr[part].reshape(rows, 128)[:, :1],
+                             l if lanes else l[:, :1], acc_scr[part].reshape(rows, -1), lanes)
+            m_scr[part] = jnp.broadcast_to(m, (rows, 128)).reshape(sub, bq, 128)
+            l_scr[part] = jnp.broadcast_to(l, (rows, 128)).reshape(sub, bq, 128)
+            acc_scr[part] = acc.reshape(sub, bq, -1)
+
+    pl.when(some & jnp.logical_not(every))(functools.partial(block, True))
+    pl.when(some & every)(functools.partial(block, False))
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        m, l, acc = m_scr[:, :1], l_scr[:, :1], acc_scr[...]
-        if has_sink:  # the sink joins the denominator and brings no value
-            sink = sink_ref[h]
-            m_all = jnp.maximum(m, sink)
-            corr = jnp.exp(m - m_all)
-            l, acc = l * corr + jnp.exp(sink - m_all), acc * corr
-        o_ref[...] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+        def head(g, c):
+            l = jnp.sum(l_scr[g], axis=-1, keepdims=True) if lanes else l_scr[g, :, :1]
+            o_ref[g] = finish(g, m_scr[g, :, :1], l, acc_scr[g])
+            return c
+        jax.lax.fori_loop(0, walk.group, head, 0)
 
 
 def flash_attention_chunk(q, k, v, q_off, k_min=0, sink=None, window: Optional[int] = None,
@@ -544,50 +731,52 @@ def flash_attention_chunk(q, k, v, q_off, k_min=0, sink=None, window: Optional[i
     head-major, as a layer plan's pools keep a row (dv may differ from dk);
     returns (W, H, dv). Query i attends key j where
     ``k_min <= j <= q_off + i`` and, with ``window``, ``q_off + i - j <
-    window``. ``q_off`` and ``k_min`` are traced scalars (the chunk's depth
-    in its row): key tiles outside that range are neither fetched nor
-    computed. ``sink`` (H,) float32: a per-head logit that joins the
-    softmax's denominator and contributes no value. Forward only."""
+    window``; ``k_min <= q_off`` (the chunk attends its own keys). ``q_off``
+    and ``k_min`` are traced scalars (the chunk's depth in its row) or, where
+    the caller knows them, Python ints, which make the walk static
+    (``_ChunkWalk``): either way key tiles outside that range are neither
+    fetched nor computed, each is fetched once for all the query heads of its
+    key-value head, and a mask is built only where an edge crosses a tile.
+    ``sink`` (H,) float32: a per-head logit that joins the softmax's
+    denominator and contributes no value. Forward only."""
     W, H, dk = q.shape
     Hkv, T, dv = v.shape
     group = H // Hkv
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(dk)
-    bq, bk = _auto_block(W, None), _auto_block(T, None)
-    nq, nk = W // bq, T // bk
+    walk = _ChunkWalk.of(W, T, group, dk, dv, q.dtype.itemsize, q_off, window)
+    bq, bk, (nq, nk) = walk.bq, walk.bk, walk.grid
     has_sink = sink is not None
     scal = jnp.stack([jnp.asarray(q_off, jnp.int32), jnp.asarray(k_min, jnp.int32)])
     sink = (jnp.zeros((H,), jnp.float32) if sink is None else sink.astype(jnp.float32))
 
-    def k_index(h, qi, ki, scal_ref, sink_ref):
-        q_lo = scal_ref[0] + qi * bq
-        lo = scal_ref[1]
-        if window is not None:
-            lo = jnp.maximum(lo, q_lo - window + 1)
-        hi = jnp.minimum((q_lo + bq - 1) // bk, nk - 1)
-        return h // group, jnp.clip(ki, jnp.minimum(jnp.maximum(lo, 0) // bk, hi), hi), 0
+    def k_index(hk, qi, ki, scal_ref, sink_ref):
+        if walk.straight:
+            return hk, 0, 0
+        first, end = walk.blocks(scal_ref[0] + qi * bq, scal_ref[1])
+        return hk, jnp.clip(ki, first, jnp.maximum(end, first + 1) - 1), 0  # held past the end: no new fetch
 
-    def q_index(h, qi, ki, scal_ref, sink_ref):
-        return h, qi, 0
+    def q_index(hk, qi, ki, scal_ref, sink_ref):
+        return hk, qi, 0
 
     out = pl.pallas_call(
-        functools.partial(_chunk_kernel, sm_scale=sm_scale, bq=bq, bk=bk, nk=nk, window=window,
-                          has_sink=has_sink),
+        functools.partial(_chunk_kernel, sm_scale=sm_scale, walk=walk, has_sink=has_sink),
         name="flash_chunk_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(H, nq, nk),
-            in_specs=[pl.BlockSpec((None, bq, dk), q_index),
+            grid=(Hkv, nq, nk),
+            in_specs=[pl.BlockSpec((group, bq, dk), q_index),
                       pl.BlockSpec((None, bk, dk), k_index),
                       pl.BlockSpec((None, bk, dv), k_index)],
-            out_specs=pl.BlockSpec((None, bq, dv), q_index),
-            scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
-                            pltpu.VMEM((bq, 128), jnp.float32),
-                            pltpu.VMEM((bq, dv), jnp.float32)],
+            out_specs=pl.BlockSpec((group, bq, dv), q_index),
+            scratch_shapes=[] if walk.straight else [pltpu.VMEM((group, bq, 128), jnp.float32),
+                                                     pltpu.VMEM((group, bq, 128), jnp.float32),
+                                                     pltpu.VMEM((group, bq, dv), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((H, W, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_CHUNK_VMEM + (20 << 20)),
         interpret=resolve_interpret(interpret),
     )(scal, sink, jnp.transpose(q, (1, 0, 2)), k, v)
     return jnp.transpose(out, (1, 0, 2))
@@ -619,10 +808,11 @@ def mha_reference(q, k, v, causal: bool = True, sm_scale: Optional[float] = None
 
 
 # ---------------------------------------------------------------------------
-# backward, and the host's view of the walk
-# (after the prefill-chunk kernel, not beside the forward: a Mosaic call's
-# payload carries its source lines, and with them its cache key, so no line
-# of ``_chunk_kernel`` / ``flash_attention_chunk`` may move)
+# backward, and the host's view of the walks
+# (a Mosaic call's payload carries its source lines, and with them its cache
+# key: an edit above this line moves the backward kernels, and every training
+# program compiles anew once; an edit above the chunk kernel does the same
+# to every layer plan's fused tick)
 # ---------------------------------------------------------------------------
 
 def _stat_spec(block, index, rows):
@@ -817,3 +1007,22 @@ def tile_walk(sq, sk, block_q=None, block_k=None, causal=True, window=None, kern
                 tile = (i0 + c0, o0 + r0, width, w.to) if kernel == "dkv" else (o0 + r0, i0 + c0, w.to, width)
                 out.append(tile + (d0 is not None,))
     return out
+
+
+def chunk_walk(W, T, group, dk, dv, q_off, k_min=0, window=None, static=False, itemsize=2):
+    """The host's view of one ``flash_attention_chunk`` call, from the object
+    its kernel unrolls and its index maps ask (``_ChunkWalk``; ``static``: the
+    call gave ``q_off`` as a Python int): (tiles, fetched) — the score tiles a
+    QUERY head computes, (query row in the chunk, first key, rows, keys,
+    masked) each, a tile not listed being neither fetched for nor computed,
+    and the K/V tiles a KEY-VALUE head's grid steps fetch."""
+    q_off, k_min = int(q_off), int(k_min)
+    return _ChunkWalk.of(W, T, group, dk, dv, itemsize, q_off if static else None, window).visits(q_off, k_min)
+
+
+@functools.lru_cache(maxsize=4096)  # a serving loop asks a tick: chunks start at few depths
+def chunk_tiles(W, H, Hkv, T, dk, dv, q_off, k_min=0, window=None, static=False, itemsize=2):
+    """(score tiles computed, of them masked, K/V tiles fetched) of one call
+    over all its heads: :func:`chunk_walk` summed."""
+    tiles, fetched = chunk_walk(W, T, H // Hkv, dk, dv, q_off, k_min, window, static, itemsize)
+    return H * len(tiles), H * sum(t[-1] for t in tiles), Hkv * fetched

@@ -359,6 +359,16 @@ def host_overhead(tick_stats: dict) -> dict:
     real, pad = tick_stats.get("prefill_chunk_tokens"), tick_stats.get("prefill_pad_tokens")
     if real is not None and pad is not None:
         out["prefill_pad_share"] = round(pad / (real + pad), 4) if real + pad else None
+    # a layer-plan model: what the flash chunk kernel did for those chunks, over the
+    # plan's layers — score tiles computed (of them masked), K/V tiles fetched
+    tiles = tick_stats.get("prefill_tiles_visited")
+    if tiles is not None:
+        fused = tick_stats.get("fused_prefill_ticks")
+        out["prefill_tiles_per_chunk"] = round(tiles / fused, 1) if fused else None
+        out["prefill_tiles_masked_share"] = (round(tick_stats["prefill_tiles_masked"] / tiles, 4)
+                                             if tiles else None)
+        out["prefill_kv_tile_fetches_per_chunk"] = (
+            round(tick_stats["prefill_kv_tile_fetches"] / fused, 1) if fused else None)
     # ticks whose rows' KV write went into each row's block, not the window
     ticks, blocks = tick_stats.get("ticks"), tick_stats.get("block_write_ticks")
     if blocks is not None:
@@ -689,6 +699,11 @@ def format_summary(summary: dict) -> str:
                    if host.get("length_read_share") is not None else "")
                 + (f"   keys read ÷ live {host['row_keys_read_per_live']:.3f}"
                    if host.get("row_keys_read_per_live") is not None else ""))
+        if host.get("prefill_tiles_per_chunk") is not None:
+            lines.append(
+                f"prefill chunks {host['prefill_tiles_per_chunk']} score tiles/chunk over the "
+                f"plan's layers ({host['prefill_tiles_masked_share']:.1%} masked)   "
+                f"{host['prefill_kv_tile_fetches_per_chunk']} K/V tile fetches/chunk")
         if host.get("latent_pool_bytes") is not None:
             expanded = host.get("mla_expand_tokens_per_chunk")
             lines.append(

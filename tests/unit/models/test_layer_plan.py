@@ -151,6 +151,53 @@ def test_tick_matches_the_forward_through_wrapped_rings_at_different_depths(mode
     assert eng.kv_cache_bytes() == stats["kv_pool_bytes_full"] + stats["kv_pool_bytes_window"]
 
 
+def test_tick_stats_count_the_tiles_the_chunk_kernel_walked(model, params):
+    from deepspeed_tpu.ops.pallas.flash_attention import chunk_tiles
+
+    # one chunk's sums over the plan: two full layers of one key-value head read to the bucket,
+    # three window layers of two over the ring's tail joined to the chunk (the offset an int)
+    W, size, first = 32, 128, 32
+    full = chunk_tiles(W, 4, 1, size, 24, 16, first, itemsize=4)
+    ring = chunk_tiles(W, 4, 2, 8 + W, 24, 16, 8, 0, 8, True, 4)
+    assert full[0] > 0 and ring[0] > 0
+    assert layer_plan.chunk_attention_tiles(model.cfg, W, size, first) == tuple(
+        2 * f + 3 * r for f, r in zip(full, ring))
+
+    eng = ContinuousBatchingEngine(
+        model, config={"dtype": model.cfg.dtype, "mesh": {"shape": {"data": 1, "tensor": 1}}},
+        params=params, max_slots=3, cache_len=128, prefill_chunk=32)
+    names = ("prefill_tiles_visited", "prefill_tiles_masked", "prefill_kv_tile_fetches")
+    assert [eng.tick_stats()[n] for n in names] == [0, 0, 0]
+    calls, walk = [], eng._chunk_tiles
+
+    def recorded(*args):
+        calls.append((args[1:], walk(*args)))
+        return calls[-1][1]
+    eng._chunk_tiles = recorded
+    eng.submit(np.arange(70, dtype=np.int32) % VOCAB, max_new_tokens=8)
+    plain_steps = 0
+    while eng.has_work():
+        before, n = eng.tick_stats(), len(calls)
+        eng.step()
+        after = eng.tick_stats()
+        grew = [after[k] - before[k] for k in names]
+        assert grew == [sum(c[1][i] for c in calls[n:]) for i in range(3)]   # the host walk's sums ...
+        plain_steps += len(calls) == n and after["ticks"] > before["ticks"]
+        assert any(grew) == (len(calls) > n)                                  # ... and nothing on a plain tick
+    stats = eng.tick_stats()
+    assert plain_steps > 0 and stats["fused_prefill_ticks"] == len(calls) == 3
+    assert [args[2] for args, _ in calls] == [0, 32, 64]                      # each chunk's first position    assert stats["prefill_tiles_visited"] >= stats["prefill_tiles_masked"] > 0
+    from deepspeed_tpu.serving.loadgen import format_summary, host_overhead
+
+    said = host_overhead(stats)
+    assert said["prefill_tiles_per_chunk"] == round(stats["prefill_tiles_visited"] / 3, 1)
+    assert said["prefill_kv_tile_fetches_per_chunk"] == round(stats["prefill_kv_tile_fetches"] / 3, 1)
+    printed = format_summary(dict(
+        requests=1, outcomes={"finished": 1}, wall_s=1.0, throughput_tok_s=8.0, goodput_tok_s=8.0,
+        shed_rate=0.0, host=said))
+    assert "prefill chunks " in printed and "K/V tile fetches/chunk" in printed
+
+
 def test_a_stale_ring_from_the_slots_last_request_is_never_attended(model, params):
     rs = np.random.RandomState(1)
     long_first = [rs.randint(0, VOCAB, 90).astype(np.int32), rs.randint(0, VOCAB, 3).astype(np.int32)]

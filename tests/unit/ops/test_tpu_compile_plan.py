@@ -57,15 +57,44 @@ def _compile(topo, fn, *shapes):
     return lowered.compile()
 
 
-@pytest.mark.parametrize("W,T,kv,window", [(1024, 16896, 4, None), (1024, 1152, 8, 128),
-                                           (256, 384, 8, 128), (512, 2048, 4, None)])
-def test_flash_chunk_kernel_compiles_at_the_published_widths(topo, W, T, kv, window):
+def _compile_flash_chunk(topo, W, T, H, kv, dk, dv, window=None, q_off=None, sink=True):
+    """``flash_attention_chunk`` at these shapes for the described chip: the Mosaic call keeps the
+    name every cell's ``flash_roofline.*`` reads, and the step's blocks and state fit VMEM (the
+    compile raises where they do not). ``q_off`` an int: static, as a window layer passes it."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_chunk
 
     bf, i32 = jnp.bfloat16, jnp.int32
-    _compile(topo, lambda q, k, v, a, b, s: flash_attention_chunk(q, k, v, a, b, s, window),
-             ((W, 64, 192), bf), ((kv, T, 192), bf), ((kv, T, 128), bf), ((), i32), ((), i32),
-             ((64,), jnp.float32))
+    sh = SingleDeviceSharding(topo.devices[0])
+    shapes = [((W, H, dk), bf), ((kv, T, dk), bf), ((kv, T, dv), bf), ((), i32), ((), i32),
+              ((H,), jnp.float32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in shapes]
+
+    def fn(q, k, v, a, b, s):
+        return flash_attention_chunk(q, k, v, a if q_off is None else q_off, b, s if sink else None,
+                                     window)
+    with force_interpret(False):
+        lowered = jax.jit(fn).lower(*args)
+    assert "flash_chunk_fwd" in lowered.as_text() and "tpu_custom_call" in lowered.as_text()
+    return lowered.compile()
+
+
+@pytest.mark.parametrize("W,T,kv,window", [(1024, 16896, 4, None), (1024, 1152, 8, 128),
+                                           (256, 384, 8, 128), (512, 2048, 4, None)])
+def test_flash_chunk_kernel_compiles_at_the_published_widths(topo, W, T, kv, window):
+    _compile_flash_chunk(topo, W, T, 64, kv, 192, 128, window)
+
+
+@pytest.mark.parametrize("W", [1024, 512, 256])
+def test_flash_chunk_kernel_compiles_with_the_window_layers_static_offset(topo, W):
+    # as ``layer_plan._attend_cached`` calls it: the ring's tail joined to the chunk, the offset
+    # and the window the same Python int, only the row's first key traced
+    _compile_flash_chunk(topo, W, 128 + W, 64, 8, 192, 128, window=128, q_off=128)
+
+
+@pytest.mark.parametrize("H,kv,width", [(32, 8, 128), (20, 20, 256)],
+                         ids=["granite-32-over-8-at-128", "glm-20-over-20-at-256"])
+def test_flash_chunk_kernel_compiles_at_the_other_plans_shapes(topo, H, kv, width):
+    _compile_flash_chunk(topo, 1024, 16896, H, kv, width, width, sink=False)
 
 
 @pytest.mark.parametrize("tokens", [32, 1056], ids=["decode-rows", "rows-and-a-chunk"])
@@ -130,11 +159,7 @@ def test_mimo_tick_updates_both_pools_in_place(topo, read_len, chunk):
 
 
 def test_flash_chunk_kernel_compiles_at_head_width_256(topo):
-    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_chunk
-
-    bf, i32 = jnp.bfloat16, jnp.int32
-    _compile(topo, lambda q, k, v, a: flash_attention_chunk(q, k, v, a),
-             ((1024, 16, 256), bf), ((2, 16896, 256), bf), ((2, 16896, 256), bf), ((), i32))
+    _compile_flash_chunk(topo, 1024, 16896, 16, 2, 256, 256, sink=False)   # Qwen3-Next: 16 over 2
 
 
 @pytest.mark.parametrize("W", [1024, 256])
