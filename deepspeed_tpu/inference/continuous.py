@@ -537,6 +537,15 @@ class ContinuousBatchingEngine:
 
             self._state_counters = state_counters(self.cfg)
             self._tick_stats.update(dict.fromkeys(self._state_counters, 0))
+        # ... walked several times over the same weights (a looped model): every pass keeps
+        # keys and values of its own, so the rows' read crosses loop_steps x the layers
+        self._loop_steps = self.cfg.loop_steps
+        if self._loop_steps > 1:
+            # passes run (loop_steps a token step: what a later early exit would lower),
+            # and of ONE layer-step's pool the positions the rows' attention fetched
+            # (every slot to the read bucket) against those the live rows hold
+            self._tick_stats.update(loop_passes=0, loop_kv_positions_read=0,
+                                    loop_kv_positions_live=0)
         if self._latent_pool:
             # cached entries the rows' kernel read (each live row to its own
             # length, a latent layer; summed over rows and ticks), and
@@ -1012,7 +1021,13 @@ class ContinuousBatchingEngine:
         is the cached slots the kernel fetched, whole 128-slot blocks a
         live row (summed over rows and a burst's steps; a layer and leaf),
         and ``row_keys_live`` the slots those rows attend: their ratio is
-        what reading by blocks costs over reading by tokens."""
+        what reading by blocks costs over reading by tokens. A looped plan
+        (``loop_steps`` > 1): ``loop_passes``, the passes dispatched
+        (``loop_steps`` a token step); of ONE layer-step's pool,
+        ``loop_kv_positions_read``, the positions the rows' attention
+        fetched (every slot to the tick's read bucket, a step) and
+        ``loop_kv_positions_live``, those the live rows hold; and
+        ``kv_pool_bytes``, the pools as allocated, all passes' layer-steps."""
         s = dict(self._tick_stats)
         s["pipeline_depth"] = self.pipeline_depth
         # NOT the tokens_per_tick knob (the burst width): the observed mean
@@ -1034,6 +1049,8 @@ class ContinuousBatchingEngine:
             s["state_pool_bytes"] = s["kv_pool_bytes_state"]
         if self._latent_pool:
             s["latent_pool_bytes"] = s["kv_pool_bytes_" + kv_cache.LATENT]
+        if self._loop_steps > 1:
+            s["kv_pool_bytes"] = self.kv_cache_bytes()
         return s
 
     def _place(self, req: _Request) -> Optional[tuple]:
@@ -1338,6 +1355,13 @@ class ContinuousBatchingEngine:
             self.cfg, pool.cache, read_len, self.mesh)
         if self._latent_pool:  # a row that is not parked attends its cached entries and the one it writes
             self._tick_stats["mla_row_keys"] += int((pos[pos < pool.length] + 1).sum())
+        if self._loop_steps > 1:
+            st = self._tick_stats
+            st["loop_passes"] += self._loop_steps * advance
+            st["loop_kv_positions_read"] += n * (read_len or pool.length) * advance
+            # each of the tick's steps, a live row attends what it holds and the token it writes
+            st["loop_kv_positions_live"] += int(np.minimum(
+                pos[pos < pool.length, None] + 1 + np.arange(advance), pool.length).sum())
         if kv_cache.rows_read_to_length(self.cfg, pool.cache, read_len, self.mesh):
             # each of the tick's steps, a live row attends what it holds and
             # the token it writes (a row that finishes inside a burst stays

@@ -42,8 +42,18 @@ read in place by ``ops/pallas/mla_attention.py``), a chunk in the EXPANDED
 form (its row's latents up to the chunk's end through ``W_UKV`` into heads,
 then the flash chunk kernel) - two forms of one function.
 
-Three entry points: :func:`forward_plan` (no cache: training, the reference
-comparison), :func:`forward_plan_cached` (the serving tick: every slot's
+A plan may be walked several times over the SAME weights (``loop_steps`` T > 1,
+a looped model): an outer ``lax.scan`` over the passes around :func:`_walk`,
+so a program holds each run's layer body once however many passes there are;
+the final norm closes every pass and its output is what the next pass starts
+from, and every pass keeps keys and values of its own: a keyed pool holds T x
+its kinds' layers, layer i of pass t at ``t x (the pool's layers a pass) + i``
+(:func:`_pass_slot`). Under ``norm_position`` ``"sandwich"`` a layer has four
+norms: ``ln1_post`` / ``ln2_post`` norm what the mixer and the FFN return,
+before the residual add.
+
+Two entry points: :func:`forward_plan` (no cache: training, the reference
+comparison) and :func:`forward_plan_cached` (the serving tick: every slot's
 one decode token at its own depth and, with ``chunk``, ONE admitting
 row's prefill chunk beside them, as one flat list of tokens through the
 projections and FFNs).
@@ -125,11 +135,18 @@ def check_plan(cfg):
             raise ValueError(f"kind {k.name}: ffn {k.ffn!r}")
         if k.ffn == "moe" and cfg.moe_num_experts < 1:
             raise ValueError(f"kind {k.name} routes but moe_num_experts is 0")
-    if (cfg.pos_embedding not in ("rope", "none") or cfg.norm_position != "pre" or cfg.use_bias
-            or cfg.activation != "silu_glu" or not cfg.causal or cfg.kv_cache_dtype != "model"):
+    if (cfg.pos_embedding not in ("rope", "none") or cfg.norm_position not in ("pre", "sandwich")
+            or cfg.use_bias or cfg.activation != "silu_glu" or not cfg.causal
+            or cfg.kv_cache_dtype != "model"):
         raise ValueError("a layer plan takes rotary positions or none at all (pos_embedding "
-                         "'rope' | 'none'), pre-norm blocks, no biases, SwiGLU, causal attention "
-                         "and a KV cache in the model's dtype")
+                         "'rope' | 'none'), pre-norm or sandwich-norm blocks, no biases, SwiGLU, "
+                         "causal attention and a KV cache in the model's dtype")
+    if cfg.loop_steps < 1:
+        raise ValueError(f"loop_steps {cfg.loop_steps}: a plan is walked at least once")
+    if cfg.loop_steps > 1 and any(k.pool == "state" for k in kinds):
+        raise ValueError("a looped plan (loop_steps > 1) takes keyed pools only: whether a "
+                         "recurrent state is kept a pass or carried from pass to pass is a "
+                         "decision no published model here needs, and none is made")
     if cfg.pos_embedding == "none" and any(k.mixer == "mla" for k in kinds):
         raise ValueError("latent attention keeps a rotated key: it needs rotary positions")
 
@@ -168,6 +185,8 @@ def _layer_shapes(cfg, kind):
     out_scale = 1.0 / math.sqrt(2 * cfg.num_layers)
     norm = 0.1 if cfg.norm_one_plus else None   # (1 + w): w zero-centred
     shapes = {("ln1", "scale"): ((D,), norm), ("ln2", "scale"): ((D,), norm)}
+    if cfg.norm_position == "sandwich":
+        shapes.update({("ln1_post", "scale"): ((D,), norm), ("ln2_post", "scale"): ((D,), norm)})
     if kind.mixer == "gdn":
         Hk, Hv, gk, gv = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
         C = 2 * Hk * gk + Hv * gv
@@ -251,6 +270,8 @@ def _layer_shapes(cfg, kind):
 def num_params(cfg) -> int:
     D, V = cfg.hidden_size, cfg.vocab_size
     total = V * D + D + (0 if cfg.tie_embeddings else V * D)
+    if cfg.loop_steps > 1:
+        total += D + 1   # the exit gate
     for kind in cfg.plan:
         total += sum(math.prod(shape) for shape, _ in _layer_shapes(cfg, kind).values())
     return total
@@ -276,6 +297,14 @@ def init_layers(rng, cfg):
             tree.setdefault(group, {})[name] = leaf
         out[kind.name] = tree
     return out
+
+
+def init_exit_gate(rng, cfg):
+    """A looped model's exit gate, one linear map with a bias on the normed
+    state a pass ends in: ``{"w": (D,), "b": ()}``, float32 (the bias 0)."""
+    D = cfg.hidden_size
+    return {"w": jax.random.normal(rng, (D,), jnp.float32) / math.sqrt(D),
+            "b": jnp.zeros((), jnp.float32)}
 
 
 # ---------------------------------------------------------------------------
@@ -739,7 +768,7 @@ def chunk_attention_tiles(cfg, W: int, size: int, first: int):
         else:            # the row as cached; a latent layer expands a key-value head a query head
             kv = nh if kind.mixer == "mla" else kind.kv_heads
             call = chunk_tiles(W, nh, kv, size, dk, dv, first, itemsize=itemsize)
-        total = [t + layers_of(cfg, kind) * c for t, c in zip(total, call)]
+        total = [t + cfg.loop_steps * layers_of(cfg, kind) * c for t, c in zip(total, call)]
     return tuple(total)
 
 
@@ -817,6 +846,61 @@ def _add(x, out, cfg):
     return x + (out if cfg.residual_scale == 1.0 else out * jnp.asarray(cfg.residual_scale, out.dtype))
 
 
+def _norm_as(scope, x, scale, cfg):
+    """``tf._norm`` under ``scope`` in its own scope's place (the innermost
+    scope of an op's path is the one a trace is read by)."""
+    with jax.named_scope(scope):
+        return _tf()._norm.__wrapped__(x, scale, None, cfg)
+
+
+def _post(out, layer_p, name, cfg):
+    """What a sublayer returns, through the sandwich's second norm where the
+    model has one (``ln1_post`` / ``ln2_post``)."""
+    if cfg.norm_position != "sandwich":
+        return out
+    return _norm_as(Scope.NORM_POST, out, layer_p[name]["scale"], cfg)
+
+
+def _pass_slot(per_pass, step, pool_index):
+    """Where layer ``pool_index`` (within its pool, within a pass) of pass
+    ``step`` keeps its keys and values: every pass has ``per_pass`` layers of
+    the pool to itself. Looked up when a program is traced (a test and
+    ``tools/ouro_cell_variant.py`` plant faults here)."""
+    return step * per_pass + pool_index
+
+
+def _passes(cfg, params, carry, one_pass, keep_states=False):
+    """``one_pass(carry, step) -> carry`` (a tuple, ``carry[0]`` the residual
+    stream) ``cfg.loop_steps`` times over the same weights as ONE
+    ``lax.scan``, the final norm after every pass. Returns (carry, the
+    normed state every pass ended in, stacked, if ``keep_states``). A plan
+    walked once is a plain call with ``step`` None and no norm here: its
+    caller's head norms."""
+    if cfg.loop_steps == 1:
+        return one_pass(carry, None), None
+
+    def body(c, step):
+        c = one_pass(c, step)
+        x = _norm_as(Scope.LOOP_NORM, c[0], params["final_norm"]["scale"], cfg)
+        return (x,) + tuple(c[1:]), (x if keep_states else None)
+
+    return jax.lax.scan(body, carry, jnp.arange(cfg.loop_steps, dtype=jnp.int32))
+
+
+def exit_pdf(params, states):
+    """The exit gate's distribution over the passes: ``states`` (T, ..., D),
+    the normed state each pass ended in -> (..., T) float32. ``lambda_t =
+    sigmoid(w . x_t + b)``; pass t < T exits with ``lambda_t prod_{j<t} (1 -
+    lambda_j)``, the last takes what is left."""
+    gate = params["exit_gate"]
+    lam = jax.nn.sigmoid(jnp.einsum("t...d,d->t...", states.astype(jnp.float32),
+                                    gate["w"].astype(jnp.float32)) + gate["b"].astype(jnp.float32))
+    stay = jnp.cumprod(1.0 - lam, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    pdf = jnp.concatenate([(lam * before)[:-1], stay[-2:-1]])
+    return jnp.moveaxis(pdf, 0, -1)
+
+
 def _logits(x, params, cfg):
     logits = _tf()._vocab_head(x, params, cfg, cfg.jnp_dtype)
     return logits if cfg.logit_scale == 1.0 else logits * jnp.asarray(cfg.logit_scale, logits.dtype)
@@ -830,10 +914,11 @@ def _head(x, params, cfg):
 # no cache: training and the reference comparison
 # ---------------------------------------------------------------------------
 
-def forward_plan(params, cfg, tokens, return_hidden=False):
+def forward_plan(params, cfg, tokens, return_hidden=False, return_exit=False):
     """tokens (B, S) -> (logits (B, S, V), 0.0): whole sequences, attention
     by masked einsum, the expert layers' grouped matmul by ``ragged_dot``,
-    which has a gradient."""
+    which has a gradient. ``return_exit`` (a looped model): a third result,
+    the exit gate's distribution over the passes (B, S, T)."""
     tf = _tf()
     dtype = cfg.jnp_dtype
     B, S = tokens.shape
@@ -864,19 +949,23 @@ def forward_plan(params, cfg, tokens, return_hidden=False):
 
     def layer(x, layer_p, kind, _):
         h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg).reshape(B * S, -1)
-        x = _add(x, mix(h, layer_p, kind).reshape(B, S, -1), cfg)
+        x = _add(x, _post(mix(h, layer_p, kind), layer_p, "ln1_post", cfg).reshape(B, S, -1), cfg)
         h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg).reshape(B * S, -1)
         out, _ = _ffn(h, layer_p["mlp"], kind, cfg, None, grad=True)
-        return _add(x, out.reshape(B, S, -1), cfg)
+        return _add(x, _post(out, layer_p, "ln2_post", cfg).reshape(B, S, -1), cfg)
 
     if cfg.remat:
         layer = jax.checkpoint(layer, policy=tf._resolve_remat_policy(cfg.remat_policy),
                                static_argnums=(2,))
-    x = _walk(cfg, tf._cast_layers(params["layers"], dtype), x, layer)
-    x = tf._norm(x, params["final_norm"]["scale"], None, cfg)
+    layers = tf._cast_layers(params["layers"], dtype)
+    (x,), states = _passes(cfg, params, (x,), lambda c, step: (_walk(cfg, layers, c[0], layer),),
+                           keep_states=return_exit)
+    if cfg.loop_steps == 1:
+        x = tf._norm(x, params["final_norm"]["scale"], None, cfg)
+    extra = (exit_pdf(params, states),) if return_exit else ()
     if return_hidden:
-        return x, jnp.float32(0.0)
-    return _logits(x, params, cfg), jnp.float32(0.0)
+        return (x, jnp.float32(0.0)) + extra
+    return (_logits(x, params, cfg), jnp.float32(0.0)) + extra
 
 
 # ---------------------------------------------------------------------------
@@ -981,9 +1070,13 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
         x = _embed(params, cfg, all_toks, dtype)
     valid = all_pos < length
 
-    def layer(carry, layer_p, kind, pool_index):
+    per_pass = {s.name: s.layers // cfg.loop_steps for s in kv_cache.specs(cfg)}
+
+    def layer(carry, layer_p, kind, pool_index, step):
         x, pools, stats = carry
         pool = pools[kind.pool]
+        if step is not None:   # a looped plan: this pass's own layer-step of the pool
+            pool_index = _pass_slot(per_pass[kind.pool], step, pool_index)
         h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg)
         if kind.mixer == "gdn":
             out, pool = _gdn_cached(h, layer_p["gdn"], cfg, pool, pool_index, B, chunk, valid)
@@ -998,17 +1091,21 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
             att, pk, pv = _attend_cached(q, k, v, layer_p["attn"], kind, cfg, pool["k"], pool["v"],
                                          pool_index, pos, chunk, read_len, length)
             out, pool = _attn_out(att, h, layer_p["attn"], cfg), {"k": pk, "v": pv}
-        x = _add(x, out, cfg)
+        x = _add(x, _post(out, layer_p, "ln1_post", cfg), cfg)
         h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg)
         out, st = _ffn(h, layer_p["mlp"], kind, cfg, valid, grad=False)
-        return _add(x, out, cfg), dict(pools, **{kind.pool: pool}), _merge_stats(stats, st)
+        return (_add(x, _post(out, layer_p, "ln2_post", cfg), cfg), dict(pools, **{kind.pool: pool}),
+                _merge_stats(stats, st))
 
-    x, cache, stats = _walk(cfg, tf._cast_layers(params["layers"], dtype),
-                            (x, cache, jnp.zeros((5,), jnp.int32)), layer)
+    layers = tf._cast_layers(params["layers"], dtype)
+    (x, cache, stats), _ = _passes(
+        cfg, params, (x, cache, jnp.zeros((5,), jnp.int32)),
+        lambda c, step: _walk(cfg, layers, c, partial(layer, step=step)))
     if kv_cache.state_spec(cfg) is not None:
         stats = jnp.concatenate([stats, jnp.stack([valid[B:].sum(dtype=jnp.int32),
                                                    valid[:B].sum(dtype=jnp.int32)])])
     rows = x[:B]
     if chunk is not None:  # the admitting row's place is taken by the chunk's sampled column
         rows = jax.lax.dynamic_update_slice(rows, x[B + chunk.emit][None], (chunk.slot, 0))
-    return _head(rows, params, cfg), cache, stats
+    # a looped plan's last pass ended in the final norm already
+    return (_head if cfg.loop_steps == 1 else _logits)(rows, params, cfg), cache, stats

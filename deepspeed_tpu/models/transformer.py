@@ -106,7 +106,9 @@ class TransformerConfig:
     rope_interleaved: bool = False  # GPT-J even/odd pairing (vs llama/neox half-split)
     parallel_residual: bool = False  # x + attn(h) + mlp(h') in one residual (GPT-J/NeoX)
     shared_ln: bool = False  # parallel residual feeds mlp from ln1 too (GPT-J)
-    norm_position: str = "pre"  # pre | post (post: BERT / OPT-350m ordering)
+    # pre | post (post: BERT / OPT-350m ordering) | sandwich (a layer plan's: a norm before
+    # AND after each sublayer, the second on what the sublayer returns, before the residual add)
+    norm_position: str = "pre"
     causal: bool = True  # False = bidirectional encoder attention (BERT)
     type_vocab_size: int = 0  # token-type-embedding vocab (BERT; 0 = off)
     embed_norm: bool = False  # LayerNorm over summed embeddings (BERT, BLOOM)
@@ -212,6 +214,10 @@ class TransformerConfig:
     mla_nope_dim: int = 0
     mla_rope_dim: int = 0
     mla_v_dim: int = 0
+    # a layer plan walked this many times over the SAME weights (a looped model), the final
+    # norm after every pass and feeding the next; every pass keeps keys and values of its own,
+    # so a keyed pool holds loop_steps x its kinds' layers; 1 = every layer once
+    loop_steps: int = 1
     # leaves made in the model dtype at init (a model whose float32 leaves
     # would not fit beside their cast copy)
     init_in_model_dtype: bool = False
@@ -227,6 +233,9 @@ class TransformerConfig:
             from deepspeed_tpu.models.layer_plan import check_plan
 
             check_plan(self)
+        elif self.loop_steps != 1 or self.norm_position == "sandwich":
+            raise ValueError("loop_steps > 1 and norm_position 'sandwich' are a layer plan's "
+                             "(layer_kinds, layer_plan): the one-kind body has neither")
 
     @property
     def uniform_window(self) -> Optional[int]:
@@ -491,9 +500,11 @@ def init(rng, cfg: TransformerConfig):
     r_outer, r_layers = jax.random.split(rng)
     params = init_outer(r_outer, cfg)
     if cfg.layer_kinds is not None:
-        from deepspeed_tpu.models.layer_plan import init_layers
+        from deepspeed_tpu.models.layer_plan import init_exit_gate, init_layers
 
         params["layers"] = init_layers(r_layers, cfg)
+        if cfg.loop_steps > 1:
+            params["exit_gate"] = init_exit_gate(jax.random.fold_in(r_outer, 11), cfg)
         if cfg.norm_one_plus:  # (1 + w): w is stored zero-centred
             params["final_norm"]["scale"] = 0.1 * jax.random.normal(
                 jax.random.fold_in(r_outer, 7), (cfg.hidden_size,), jnp.float32)
@@ -541,7 +552,7 @@ def logical_specs(params, cfg: TransformerConfig):
                      "res_wo": ("mlp", "embed"), "res_bi": ("mlp",), "res_bo": ("embed",),
                      "coef_w": ("embed", None), "coef_b": (None,)}
             return pre + table[last]
-        if "ln1" in names or "ln2" in names:
+        if any(n in names for n in ("ln1", "ln2", "ln1_post", "ln2_post")):
             return pre + ("norm",)
         if "final_norm" in names or "embed_norm" in names:
             return ("norm",)

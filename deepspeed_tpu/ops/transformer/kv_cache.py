@@ -131,7 +131,9 @@ def specs(cfg) -> Tuple[PoolSpec, ...]:
     copies a tick (PR 25). A layer plan, heads before time: at MiMo's 4-8
     heads of 192 / 128 a row's keys of one head are the (T, width) matrix
     the contractions and the chunk kernel want, and time first the compiler
-    copied the whole value pool into and out of every tick (PR 27). A choice
+    copied the whole value pool into and out of every tick (PR 27). A plan
+    walked ``loop_steps`` times keeps every pass's keys and values apart: a
+    keyed pool has ``loop_steps`` x its kinds' layers. A choice
     from the shapes alone, one order for both bodies, is ROADMAP Queue 1
     item 3's to make, here. A rolling one-kind cache is a ring as long as
     its allocation: ``ring`` stays None, the write takes ``ring=True``."""
@@ -150,7 +152,8 @@ def specs(cfg) -> Tuple[PoolSpec, ...]:
         pools[kind.pool] = PoolSpec(kind.pool, n + 1, kind.kv_heads, cfg.head_dim,
                                     cfg.v_head_dim, kind.window or None,
                                     heads_first=True, int8=False)
-    return tuple(pools.values())
+    # a looped plan keeps keys and values a pass: layer i of pass t at t x (layers a pass) + i
+    return tuple(s._replace(layers=cfg.loop_steps * s.layers) for s in pools.values())
 
 
 # -- what the host knows ----------------------------------------------------
@@ -309,7 +312,8 @@ def rows_write_by_blocks(cfg, cache, read_len: Optional[int], mesh=None) -> bool
     (``tick_stats()``'s ``block_write_ticks``)."""
     def one(spec, leaf):
         size = spec.ring or read_len or leaf.shape[spec.time_axis]
-        return takes_block_write(size, _row_bytes(leaf, size, spec.heads_first))
+        return takes_block_write(size, _row_bytes(leaf, size, spec.heads_first),
+                                 ragged=_takes_ragged(leaf))
 
     return not spans_chips(mesh) and any(one(spec, leaf) for spec, sub in _pools(cfg, cache)
                                          for leaf in jax.tree.leaves(sub))
@@ -369,7 +373,8 @@ def write(pool, layer, new, cols, size, *, heads_first: bool, slot=None, start=0
     scattered token by token, so no index is dynamic along the time axis."""
     size = size or pool.shape[3 if heads_first else 2]
     one_token = slot is None and (heads_first or new.shape[1] == 1) and not _split_over_chips
-    if one_token and takes_block_write(size, _row_bytes(pool, size, heads_first)):
+    if one_token and takes_block_write(size, _row_bytes(pool, size, heads_first),
+                                       ragged=_takes_ragged(pool)):
         return _write_blocks(pool, layer, new[:, :, None] if heads_first else new,
                              cols.reshape(-1), size, heads_first)
     if not heads_first:
@@ -439,12 +444,25 @@ def split_over_chips(fn):
     return traced
 
 
-def takes_block_write(size: int, row_bytes: int) -> bool:
+def takes_block_write(size: int, row_bytes: int, ragged: bool = False) -> bool:
     """Whether one token a row goes into its row's block (True) or through
     the whole window (False), for a window of ``size`` slots that holds
     ``row_bytes`` a row of one leaf. A window no longer than a block IS its
-    block; one that is not whole blocks has no aligned last block."""
-    return size > BLOCK and size % BLOCK == 0 and row_bytes >= BLOCK_WRITE_MIN_ROW_BYTES
+    block; one that is not whole blocks has a last block that reaches past
+    it, which only a leaf that ``ragged`` says can take it writes by blocks
+    (:func:`_takes_ragged`)."""
+    return (size > BLOCK and (ragged or size % BLOCK == 0)
+            and row_bytes >= BLOCK_WRITE_MIN_ROW_BYTES)
+
+
+def _takes_ragged(pool) -> bool:
+    """Whether the block write may be handed a window of ``pool`` that is not
+    whole blocks (a 320-slot allocation: its third block holds 64 slots). A
+    leaf whose width is whole 128-lane tiles goes to the kernel as it is
+    written, time down the sublanes, and a block that reaches past the
+    allocation is fetched and stored as far as the allocation goes; a
+    time-minor leaf would need part of a lane tile, and keeps the window."""
+    return pool.shape[4] % LANES == 0
 
 
 def _row_bytes(pool, size: int, heads_first: bool) -> int:
@@ -473,7 +491,7 @@ def takes_length_read(pool, size: Optional[int], *, tokens: int, heads: int,
     if _split_over_chips or isinstance(pool, dict) or not masked_only or tokens != 1:
         return False
     size = size or pool.shape[2]
-    return (pool.shape[3] == heads and pool.shape[4] % LANES != 0
+    return (pool.shape[3] == heads and pool.shape[4] % LANES != 0 and size % BLOCK == 0
             and takes_block_write(size, _row_bytes(pool, size, heads_first=False)))
 
 
@@ -513,7 +531,7 @@ def _write_blocks(pool, layer, token, cols, size, heads_first):
     last = order.index(3 if heads_first else 2)          # where time is in the kernel's array
     rows = pool.shape[1]
     block = (None, None) + tuple(BLOCK if a == last else n for a, n in enumerate(lead.shape) if a > 1)
-    first = jnp.clip(cols // BLOCK, 0, size // BLOCK - 1).astype(jnp.int32)
+    first = jnp.clip(cols // BLOCK, 0, -(-size // BLOCK) - 1).astype(jnp.int32)
     offset = (cols - first * BLOCK).astype(jnp.int32)
     token = token.astype(pool.dtype)[None].transpose(order)[0]      # the pool's axes, one slot of time
     token = jnp.broadcast_to(token, (rows,) + block[2:])

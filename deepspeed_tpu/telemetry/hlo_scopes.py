@@ -59,6 +59,8 @@ class Scope:
     MLA_EXPAND = "mla.expand"    # ... a row's latents through W_UKV into heads, for a chunk (mla_expand)
     ATTN_LATENT = "attn.latent"  # ... attention over the latent pool (mla_decode, or a chunk's)
     NORM = "norm"
+    NORM_POST = "norm.post"      # a sandwich-norm layer's second norms, on what a sublayer returns
+    LOOP_NORM = "loop.norm"      # a looped plan's final norm at the end of a pass
     LM_HEAD = "lm_head"
     LOSS = "loss"
     SAMPLE = "sample"

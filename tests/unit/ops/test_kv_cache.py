@@ -417,3 +417,30 @@ def test_int8_write_quantises_and_the_read_dequantises():
     assert np.all(np.abs(back[0, 3] - np.asarray(new)[0, 0]) <= scales / 2 + 1e-6)
     back[0, 3] = 0
     assert not back.any() and not np.asarray(k["q8"])[0].any() and np.asarray(v["q8"])[1, 0, 3].any()
+
+
+@pytest.mark.parametrize("width,by_blocks", [(128, True), (64, False)], ids=["whole-lanes", "time-minor"])
+def test_a_window_that_is_not_whole_blocks_goes_by_blocks_where_the_leaf_can_take_it(monkeypatch, width,
+                                                                                    by_blocks):
+    """A 320-slot allocation (two blocks and 64 slots): a heads-first leaf
+    whose width is whole lane tiles writes its rows' tokens by blocks, the
+    third block fetched and stored as far as the allocation goes, and equals
+    the window path slot for slot; a time-minor leaf keeps the window path;
+    the rows' read by length never takes such a window."""
+    rs = np.random.RandomState(5)
+    pool = jnp.asarray(rs.normal(size=(2, 3, 2, 320, width)), jnp.float32)
+    new = jnp.asarray(rs.normal(size=(3, 2, width)), jnp.float32)
+    cols = jnp.asarray([300, 5, 320], jnp.int32)                  # the last row is parked
+    window = kv_cache.write(pool, jnp.int32(1), new, cols, 320, heads_first=True)
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 1)
+    assert kv_cache.takes_block_write(320, 1, ragged=kv_cache._takes_ragged(pool)) is by_blocks
+    assert not kv_cache.takes_block_write(320, 1) and kv_cache.takes_block_write(256, 1)
+    calls, kernel = [], kv_cache._write_blocks
+    monkeypatch.setattr(kv_cache, "_write_blocks", lambda *a: calls.append(a[4]) or kernel(*a))
+    block = kv_cache.write(pool, jnp.int32(1), new, cols, 320, heads_first=True)
+    assert calls == ([320] if by_blocks else [])
+    np.testing.assert_array_equal(block, window)
+    assert int((np.asarray(block) != np.asarray(pool)).sum()) == 2 * 2 * width
+    flat = jnp.zeros((1, 3, 320, 2, 64), jnp.float32)             # time before heads, 2 heads of 64
+    assert not kv_cache.takes_length_read(flat, 320, tokens=1, heads=2, masked_only=True)
+    assert kv_cache.takes_length_read(flat, 256, tokens=1, heads=2, masked_only=True)

@@ -375,3 +375,62 @@ def test_granite_tick_updates_both_kinds_of_pool_in_place(topo, read_len, chunk)
     assert not re.findall(r"= bf16\[[45],4096,16768\]\S* slice\(", text)
     assert "ssd_step" in text and ("ssd_chunk_fwd" in text) == (chunk is not None)
     assert ("flash_chunk_fwd" in text) == (chunk is not None)
+
+
+@pytest.mark.parametrize("read_len,chunk", [(None, None), (256, 256)], ids=["plain", "fused256-read256"])
+def test_ouro_tick_walks_one_layer_body_four_times_with_both_pool_leaves_in_place(topo, read_len,
+                                                                                 chunk):
+    """What a loop adds (PR 44): the Ouro-2.6B tick at the benchmark's sizes,
+    the model whole (48 layers x 4 passes, 5.34 GB) and its pool of 192
+    layer-steps (8.05 GB at 16 slots of 320) carried and donated through BOTH
+    scans, the one over the passes and the one over the layers: no temporary
+    of the pool's size, no copy of a leaf, and ONE layer body in the program
+    (flash at 16 heads over 16 key-value heads of 128, a 320-long row). What
+    the temporaries do hold: the stacked ``wq`` / ``wk`` / ``wv`` transposed,
+    0.4 GB each, hoisted out of both loops (PERF.md section 7)."""
+    from benchmark import models_ouro
+    from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
+    from deepspeed_tpu.models import transformer as tf
+
+    with open(os.path.join(ROOT, "benchmark", "configs", "ouro-2.6b.json")) as fh:
+        config = json.load(fh)
+    with open(os.path.join(ROOT, "benchmark", "cells", "serve-ouro-2.6b-chat-batch.json")) as fh:
+        cell = json.load(fh)["serve_looped"]
+    slots, length = cell["slots"], cell["cache_len"]
+    model = models_ouro.build_model(config, max_seq_len=length, remat=False, attn_impl="pallas")
+    cfg = model.cfg
+    mesh = comm.build_mesh({"data": 1, "tensor": 1}, devices=topo.devices[:1])
+    one = NamedSharding(mesh, PartitionSpec())
+    abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    p_sh = jax.tree.map(lambda a: one, abstract)
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one),
+                          abstract)
+    with force_interpret(False):
+        fn, cache_sh, _ = compile_pool_tick_fn(mesh, cfg, p_sh, slots, length, 1, 0.0, 0, 1.0,
+                                               read_len=read_len, chunk=chunk)
+        cache = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            jax.eval_shape(lambda: tf.init_cache(cfg, slots, length)), cache_sh)
+        row = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+        args = [params, cache, row, row, row, row, row, row, jax.ShapeDtypeStruct((2,), jnp.uint32)]
+        if chunk is not None:
+            wide = jax.ShapeDtypeStruct((chunk,), jnp.int32)
+            args += [wide, wide, jax.ShapeDtypeStruct((), jnp.int32), row, row]
+        compiled = fn.lower(*args).compile()
+    comm.destroy()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert pool_bytes == 192 * slots * length * 2 * 16 * 128 * 2     # 1.5 MiB a position
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes                     # 8.05 GB, in place
+    assert mem.temp_size_in_bytes < 1.3e9, mem.temp_size_in_bytes
+    resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert resident < 14.8e9, resident
+    print("ouro tick", read_len, chunk, "temp", mem.temp_size_in_bytes / 1e9, "arguments",
+          mem.argument_size_in_bytes / 1e9, "resident", resident / 1e9)
+    text = compiled.as_text()
+    assert not re.findall(rf"= bf16\[(?:\d+,)?{slots},16,{length},128\]\S* copy\(", text)
+    assert not re.findall(r"= bf16\[(?:\d+,)?2048,5632\]\S* copy\(", text)   # nor of a layer's weights
+    assert ("flash_chunk_fwd" in text) == (chunk is not None)
+    # one layer body: the scan over the passes around the scan over the layers, and no other loop
+    assert len(re.findall(r" while\(", text)) == 2

@@ -1,6 +1,6 @@
 """What the per-configuration variant tools share (``tools/mimo_cell_variant.py``,
 ``tools/qwen3_next_cell_variant.py``, ``tools/glm_cell_variant.py``,
-``tools/granite_cell_variant.py``): one
+``tools/granite_cell_variant.py``, ``tools/ouro_cell_variant.py``): one
 command line that runs a cell through the harness, as the driver runs it, with
 ONE thing swapped for the length of the run, and the swaps that are the same
 for every configuration. A variant is a function of the cell's configuration
