@@ -492,8 +492,11 @@ class ContinuousBatchingEngine:
                             "prefill_pad_tokens": 0,
                             # ticks whose rows' one-token KV write took
                             # the block path (kv_cache.takes_block_write,
-                            # told here from the tick's read bucket)
-                            "block_write_ticks": 0,
+                            # told here from the tick's read bucket), and
+                            # the bytes those writes fetched and stored:
+                            # every row's block, in and out, a leaf, a
+                            # layer-step and a token step
+                            "block_write_ticks": 0, "block_write_bytes": 0,
                             # ticks whose rows' attention read each row to
                             # its own length (kv_cache.takes_length_read),
                             # the slots that kernel fetched (whole blocks a
@@ -1014,7 +1017,10 @@ class ContinuousBatchingEngine:
         admitted-but-not-yet-prefilled requests over all pools, read once
         per ``step()``. ``block_write_ticks``: ticks dispatched on a program
         whose rows' one-token KV write took the block path (the host tells
-        it from the tick's read bucket by ``kv_cache``'s own rule).
+        it from the tick's read bucket by ``kv_cache``'s own rule);
+        ``block_write_bytes``: the bytes those writes fetched and stored
+        (``kv_cache.rows_block_write_bytes`` a token step: every row's block
+        of every leaf that goes by blocks, in and out, over its layer-steps).
         ``length_read_ticks``: ticks dispatched on a program whose rows'
         attention reads each row to its own length (the same way, by
         ``kv_cache.takes_length_read``); of those ticks ``row_keys_read``
@@ -1351,8 +1357,9 @@ class ContinuousBatchingEngine:
             rec = _TickRecord(packed, live, k,
                               self._row_read_bytes(pool, read_len), False)
             advance = k
-        self._tick_stats["block_write_ticks"] += kv_cache.rows_write_by_blocks(
-            self.cfg, pool.cache, read_len, self.mesh)
+        moved = kv_cache.rows_block_write_bytes(self.cfg, pool.cache, read_len, self.mesh)
+        self._tick_stats["block_write_ticks"] += moved > 0
+        self._tick_stats["block_write_bytes"] += moved * advance
         if self._latent_pool:  # a row that is not parked attends its cached entries and the one it writes
             self._tick_stats["mla_row_keys"] += int((pos[pos < pool.length] + 1).sum())
         if self._loop_steps > 1:

@@ -305,18 +305,31 @@ def spans_chips(mesh) -> bool:
     return mesh is not None and mesh.size > 1
 
 
-def rows_write_by_blocks(cfg, cache, read_len: Optional[int], mesh=None) -> bool:
-    """The host's side of :func:`takes_block_write`: whether a program built
-    on ``mesh`` that writes one token a row into ``cache`` at read bucket
-    ``read_len`` (None: the allocation) takes the block path in any leaf
-    (``tick_stats()``'s ``block_write_ticks``)."""
+def rows_block_write_bytes(cfg, cache, read_len: Optional[int], mesh=None) -> int:
+    """The host's side of :func:`takes_block_write`: the bytes the rows'
+    block writes fetch and store in ONE token step of a program built on
+    ``mesh`` that writes one token a row into ``cache`` at read bucket
+    ``read_len`` (None: the allocation); 0 where no leaf takes the block
+    path. Off static shapes: every row's block of :func:`block_slots` slots,
+    all heads, in and out, summed over the leaves that go by blocks and their
+    layer-steps (``tick_stats()``'s ``block_write_bytes``)."""
     def one(spec, leaf):
         size = spec.ring or read_len or leaf.shape[spec.time_axis]
-        return takes_block_write(size, _row_bytes(leaf, size, spec.heads_first),
-                                 ragged=_takes_ragged(leaf))
+        if not takes_block_write(size, _row_bytes(leaf, size, spec.heads_first),
+                                 ragged=_takes_ragged(leaf, spec.heads_first)):
+            return 0
+        layers, rows = leaf.shape[:2]
+        return 2 * layers * rows * _row_bytes(leaf, block_slots(leaf), spec.heads_first)
 
-    return not spans_chips(mesh) and any(one(spec, leaf) for spec, sub in _pools(cfg, cache)
-                                         for leaf in jax.tree.leaves(sub))
+    if spans_chips(mesh):
+        return 0
+    return sum(one(spec, leaf) for spec, sub in _pools(cfg, cache) for leaf in jax.tree.leaves(sub))
+
+
+def rows_write_by_blocks(cfg, cache, read_len: Optional[int], mesh=None) -> bool:
+    """Whether that program takes the block path in any leaf
+    (``tick_stats()``'s ``block_write_ticks``)."""
+    return rows_block_write_bytes(cfg, cache, read_len, mesh) > 0
 
 
 def rows_read_to_length(cfg, cache, read_len: Optional[int], mesh=None) -> bool:
@@ -374,7 +387,7 @@ def write(pool, layer, new, cols, size, *, heads_first: bool, slot=None, start=0
     size = size or pool.shape[3 if heads_first else 2]
     one_token = slot is None and (heads_first or new.shape[1] == 1) and not _split_over_chips
     if one_token and takes_block_write(size, _row_bytes(pool, size, heads_first),
-                                       ragged=_takes_ragged(pool)):
+                                       ragged=_takes_ragged(pool, heads_first)):
         return _write_blocks(pool, layer, new[:, :, None] if heads_first else new,
                              cols.reshape(-1), size, heads_first)
     if not heads_first:
@@ -394,9 +407,17 @@ def write(pool, layer, new, cols, size, *, heads_first: bool, slot=None, start=0
     return jax.lax.dynamic_update_slice(pool, merged[None, None], (layer, slot, 0, start, 0))
 
 
-# The rows' one-token write, by blocks. BLOCK slots are one whole tile of the
-# time-minor layout the chip keeps a time-before-heads pool in: the smallest
-# extent along time that can be updated in place (PR 25).
+# The rows' one-token write, by blocks: the smallest extent along time that
+# the chip updates in place, which follows the leaf's layout there
+# (``block_slots``). A leaf whose width is not whole lanes (GPT-2's 25 x 64,
+# MiMo's 192-wide keys) is kept TIME-minor: time runs along the 128 lanes, a
+# token is one lane of every tile of its block, and the block is BLOCK slots
+# (PR 25, PR 32). A leaf whose width IS whole 128-lane tiles (Ouro's and
+# MiMo's values' 128, Qwen3-Next's 256, the latent pool's 640) is kept as it
+# is written, time down the sublanes: its block is ONE packed sublane tile,
+# 16 slots of bfloat16, 8 of float32, 32 of int8 (PR 45). BLOCK is also the
+# block of the rows' read by length and the unit of WHICH programs write by
+# blocks (``takes_block_write``), whatever the leaf's own block is.
 BLOCK = 128
 # The ONE rule of which path a rows' write takes, over static shapes: by
 # blocks iff one row's window of one leaf holds this many bytes. Measured on
@@ -420,6 +441,10 @@ BLOCK = 128
 # long as the read bucket the kernel loses 5-20 % (XL 1,024: 7.77 against
 # 6.98; it moves 590-650 GB/s of what it fetches where the dots move 760),
 # which no static shape can tell; a call costs ~10 us before its first block.
+# A lane-aligned leaf's blocks are an eighth of those bytes and its call
+# cheaper still (Ouro, 16 rows of 16 heads x 128: 4.4 us a call against
+# 24-27 with 128-slot blocks, PERF.md section 6, PR 45); the threshold was
+# not measured again for them and stays, so the same programs take the path.
 BLOCK_WRITE_MIN_ROW_BYTES = 1 << 19
 
 
@@ -447,22 +472,27 @@ def split_over_chips(fn):
 def takes_block_write(size: int, row_bytes: int, ragged: bool = False) -> bool:
     """Whether one token a row goes into its row's block (True) or through
     the whole window (False), for a window of ``size`` slots that holds
-    ``row_bytes`` a row of one leaf. A window no longer than a block IS its
-    block; one that is not whole blocks has a last block that reaches past
-    it, which only a leaf that ``ragged`` says can take it writes by blocks
-    (:func:`_takes_ragged`)."""
+    ``row_bytes`` a row of one leaf. A window no longer than ``BLOCK`` IS
+    its block (of a time-minor leaf; the rule is one for every leaf); one
+    that is not whole ``BLOCK``s goes by blocks only in a leaf that
+    ``ragged`` says can take it (:func:`_takes_ragged`)."""
     return (size > BLOCK and (ragged or size % BLOCK == 0)
             and row_bytes >= BLOCK_WRITE_MIN_ROW_BYTES)
 
 
-def _takes_ragged(pool) -> bool:
+def _takes_ragged(pool, heads_first: bool = True) -> bool:
     """Whether the block write may be handed a window of ``pool`` that is not
-    whole blocks (a 320-slot allocation: its third block holds 64 slots). A
-    leaf whose width is whole 128-lane tiles goes to the kernel as it is
-    written, time down the sublanes, and a block that reaches past the
-    allocation is fetched and stored as far as the allocation goes; a
-    time-minor leaf would need part of a lane tile, and keeps the window."""
-    return pool.shape[4] % LANES == 0
+    whole ``BLOCK``s. A leaf whose width is whole 128-lane tiles goes to the
+    kernel as it is written, in blocks of one sublane tile of slots
+    (:func:`block_slots`): a 320-slot window is twenty whole blocks of
+    bfloat16, and a window that is not whole blocks of its own (300 slots)
+    only has a last block that reaches past it, inside the allocation, whose
+    slots past the window drop their token. What the guard still keeps on the
+    window path: a time-minor leaf, which would need part of a lane tile, and
+    a lane-aligned leaf whose ALLOCATION is not whole blocks of its own (300
+    slots of bfloat16: the kernel's own copy of the last block would reach
+    past the leaf)."""
+    return pool.shape[4] % LANES == 0 and pool.shape[3 if heads_first else 2] % block_slots(pool) == 0
 
 
 def _row_bytes(pool, size: int, heads_first: bool) -> int:
@@ -504,45 +534,67 @@ def time_minor(pool):
     return pool.transpose(0, 1, 3, 4, 2).reshape(L, B, H * x, T)
 
 
+def block_slots(pool) -> int:
+    """Slots along time of the block the rows' one-token write fetches and
+    stores in leaf ``pool``: the smallest extent along time that the chip
+    updates in place, read off the leaf's layout. A leaf whose width is whole
+    128-lane tiles is kept as it is written, time down the sublanes: one
+    packed sublane tile, 16 slots of bfloat16, 8 of float32, 32 of int8 (time
+    before heads a slot is whole tiles by itself; no cell has such a leaf,
+    and it takes the same block). Any other leaf is kept TIME-minor, a token
+    one lane of every tile of its block: ``BLOCK``."""
+    return BLOCK if pool.shape[4] % LANES else 32 // pool.dtype.itemsize
+
+
 def _write_blocks(pool, layer, token, cols, size, heads_first):
     """Each row's one token (``token`` holds one slot along time, ``cols``
-    (B,)) into the BLOCK slots that hold its column, in place: ONE kernel
-    call, a grid step a row, that fetches the row's block, selects the token
-    in where the slot is the column's, and stores the block back
-    (``kv_block_write``; the pool is aliased to the result and nothing else
-    of it is touched). A column outside ``[0, size)`` clips to the first or
-    last block and hits no slot of it: stored back unchanged.
+    (B,)) into the block of :func:`block_slots` slots that holds its column,
+    in place: ONE kernel call that fetches the row's block of every head,
+    selects the token in where the slot is the column's, and stores the block
+    back (``kv_block_write``; the pool is aliased to the result and nothing
+    else of it is touched). A column outside ``[0, size)`` clips to the first
+    or last block and hits no slot of it.
 
     The kernel has to see the pool in the order the chip keeps it, or the
-    compiler copies the pool in and out. A leaf whose width is whole 128-lane
-    tiles is kept as it is written (MiMo's values, ``(L, B, H, T, 128)``).
-    Any other is kept TIME-minor (GPT-2's ``(L, B, T, H, 64)``, MiMo's keys
-    ``(L, B, H, T, 192)``): it goes in as its ``(L, B, H, x, T)`` transpose,
-    which IS that memory order and costs nothing (no copy in the compiled
-    ticks: ``tests/unit/ops/test_tpu_compile.py``, ``test_tpu_compile_plan.py``).
-    Unrolled XLA ops (a ``dynamic_slice``, select and ``dynamic_update_slice``
-    a row) compile in place too, but the chip runs each update as a copy of
-    the block's separate 2 KB tiles, 7.7 us a row and leaf at XL: no faster
-    than the window (PERF.md section 6, PR 32)."""
-    order = list(range(5))
-    if pool.shape[4] % 128:                              # time-minor on the chip: time goes last
-        order.append(order.pop(3 if heads_first else 2))   # (L, B, H, x, T)
+    compiler copies the pool in and out: a leaf whose width is whole 128-lane
+    tiles as it is written (:func:`_write_tile_blocks`), any other TIME-minor
+    (:func:`_write_lane_blocks`). Unrolled XLA ops (a ``dynamic_slice``,
+    select and ``dynamic_update_slice`` a row) compile in place too, but the
+    chip runs each update as a copy of the block's separate 2 KB tiles, 7.7 us
+    a row and leaf at XL: no faster than the window (PERF.md section 6,
+    PR 32)."""
+    slots = block_slots(pool)
+    first = jnp.clip(cols // slots, 0, -(-size // slots) - 1).astype(jnp.int32)
+    # a window that is not whole blocks: its last block reaches past it, and a column there drops too
+    offset = jnp.where((cols >= 0) & (cols < size), cols - first * slots, -1).astype(jnp.int32)
+    by_layout = _write_lane_blocks if pool.shape[4] % LANES else _write_tile_blocks
+    out = by_layout(pool, (jnp.asarray(layer, jnp.int32).reshape(1), first, offset),
+                    token.astype(pool.dtype), 3 if heads_first else 2)
+    # one value for every later reader: a chunk's write that read the kernel's result and updated
+    # its transpose was given a copy of the pool (the fused tick, compiled for a described v5e)
+    return jax.lax.optimization_barrier(out)
+
+
+def _write_lane_blocks(pool, scalars, token, time):
+    """A TIME-minor leaf (GPT-2's ``(L, B, T, H, 64)``, MiMo's keys ``(L, B,
+    H, T, 192)``; ``time`` its time axis): a token is one lane of every tile
+    of its ``BLOCK`` slots. The leaf goes in as its ``(L, B, H, x, T)``
+    transpose, which IS that memory order and costs nothing (no copy in the
+    compiled ticks: ``tests/unit/ops/test_tpu_compile.py``,
+    ``test_tpu_compile_plan.py``), a pipelined grid step a row, beside the
+    token broadcast to a block (a ``(..., 1)`` column the chip would pad
+    128-fold in HBM anyway)."""
+    order = [a for a in range(5) if a != time] + [time]
     lead = pool.transpose(order)
-    last = order.index(3 if heads_first else 2)          # where time is in the kernel's array
     rows = pool.shape[1]
-    block = (None, None) + tuple(BLOCK if a == last else n for a, n in enumerate(lead.shape) if a > 1)
-    first = jnp.clip(cols // BLOCK, 0, -(-size // BLOCK) - 1).astype(jnp.int32)
-    offset = (cols - first * BLOCK).astype(jnp.int32)
-    token = token.astype(pool.dtype)[None].transpose(order)[0]      # the pool's axes, one slot of time
-    token = jnp.broadcast_to(token, (rows,) + block[2:])
+    block = (None, None) + lead.shape[2:4] + (BLOCK,)
+    token = jnp.broadcast_to(token[None].transpose(order)[0], (rows,) + block[2:])
 
     def index(row, layer_ref, first_ref, offset_ref):
-        at = [layer_ref[0], row, 0, 0, 0]
-        at[last] = first_ref[row]
-        return tuple(at)
+        return layer_ref[0], row, 0, 0, first_ref[row]
 
     def kernel(layer_ref, first_ref, offset_ref, pool_ref, token_ref, out_ref):
-        slot = jax.lax.broadcasted_iota(jnp.int32, pool_ref.shape, last - 2)
+        slot = jax.lax.broadcasted_iota(jnp.int32, pool_ref.shape, 2)
         hit = slot == offset_ref[pl.program_id(0)]
         out_ref[...] = jnp.where(hit, token_ref[...], pool_ref[...])
 
@@ -556,10 +608,87 @@ def _write_blocks(pool, layer, token, cols, size, heads_first):
         out_shape=jax.ShapeDtypeStruct(lead.shape, lead.dtype),
         input_output_aliases={3: 0},
         interpret=resolve_interpret(),
-    )(jnp.asarray(layer, jnp.int32).reshape(1), first, offset, lead, token)
-    # one value for every later reader: a chunk's write that read the kernel's result and updated
-    # its transpose was given a copy of the pool (the fused tick, compiled for a described v5e)
-    return jax.lax.optimization_barrier(out.transpose([order.index(a) for a in range(5)]))
+    )(*scalars, lead, token)
+    return out.transpose([order.index(a) for a in range(5)])
+
+
+# VMEM the rows' blocks of a lane-aligned leaf may hold in one grid step of the block write
+_TILE_WRITE_VMEM_BYTES = 4 << 20
+
+
+def _write_tile_blocks(pool, scalars, token, time):
+    """A leaf whose width is whole 128-lane tiles, kept as it is written
+    (Ouro's ``(L, B, H, T, 128)``, MiMo's values, the latent pool's 640
+    columns; ``time`` its time axis): the block is one packed sublane tile of
+    slots, 64 KiB of Ouro's 16 heads where 128 slots were 512. The token goes
+    in as it is, one slot of time, and is broadcast along the block inside
+    the select; the kernel drives its own DMAs, every row's fetch in flight at
+    once in one grid step (as many rows a step as ``_TILE_WRITE_VMEM_BYTES``
+    holds), and a row that drops its token is neither fetched nor stored. On
+    a v5e at Ouro's shapes a call costs 4.2 us where a pipelined grid step a
+    row over the same blocks cost 7.6 and the 128-slot block 24-27 (PERF.md
+    section 6, PR 45)."""
+    rows, slots = pool.shape[1], block_slots(pool)
+    block_bytes = slots * math.prod(pool.shape[2:]) // pool.shape[time] * pool.dtype.itemsize
+    fits = max(_TILE_WRITE_VMEM_BYTES // block_bytes, 1)
+    step = max(n for n in range(1, rows + 1) if rows % n == 0 and n <= fits)
+    return _tile_blocks_call(*scalars, pool, token, time=time, step=step, interpret=resolve_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("time", "step", "interpret"))
+def _tile_blocks_call(layer, first, offset, pool, token, *, time, step, interpret):
+    """:func:`_write_tile_blocks`' kernel call, ``step`` rows a grid step. A
+    ``jit`` of its own so that it is traced ONCE a shape and process: the
+    kernel's three loops cost a trace 15 ms a call site, 3.7 s of the GLM
+    cell's warm set-up over its 18 tick programs (PERF.md section 6, PR 45);
+    the enclosing program inlines it."""
+    rows, slots = pool.shape[1], block_slots(pool)
+    block = tuple(slots if a == time else n for a, n in enumerate(pool.shape) if a > 1)
+
+    def kernel(layer_ref, first_ref, offset_ref, pool_ref, token_ref, out_ref, buf, sem):
+        slot = jax.lax.broadcasted_iota(jnp.int32, block, time - 2)
+        first_row = pl.program_id(0) * step
+
+        def each_live_row(do):       # a loop, not ``step`` copies of its body: a call lowers in 0.1 s, not 1
+            def one(r, carry):
+                row = first_row + r
+                pl.when(offset_ref[row] >= 0)(functools.partial(do, r, row))
+                return carry
+            jax.lax.fori_loop(0, step, one, 0)
+
+        def blocks_of(ref, row):
+            at = [layer_ref[0], row, slice(None), slice(None), slice(None)]
+            at[time] = pl.ds(pl.multiple_of(first_ref[row] * slots, slots), slots)
+            return ref.at[tuple(at)]
+
+        def fetch(r, row):
+            pltpu.make_async_copy(blocks_of(pool_ref, row), buf.at[r], sem.at[r]).start()
+
+        def select_and_store(r, row):
+            pltpu.make_async_copy(blocks_of(pool_ref, row), buf.at[r], sem.at[r]).wait()
+            buf[r] = jnp.where(slot == offset_ref[row], token_ref[r], buf[r])
+            pltpu.make_async_copy(buf.at[r], blocks_of(out_ref, row), sem.at[r]).start()
+
+        def stored(r, row):
+            pltpu.make_async_copy(buf.at[r], blocks_of(out_ref, row), sem.at[r]).wait()
+
+        each_live_row(fetch)
+        each_live_row(select_and_store)
+        each_live_row(stored)
+
+    return pl.pallas_call(
+        kernel, name="kv_block_write",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(rows // step,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec((step,) + token.shape[1:], lambda g, *_: (g, 0, 0, 0))],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((step,) + block, pool.dtype),
+                            pltpu.SemaphoreType.DMA((step,))]),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={3: 0},
+        interpret=interpret,
+    )(layer, first, offset, pool, token)
 
 
 def _place(win, new, cols):

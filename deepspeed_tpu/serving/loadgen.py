@@ -373,6 +373,9 @@ def host_overhead(tick_stats: dict) -> dict:
     ticks, blocks = tick_stats.get("ticks"), tick_stats.get("block_write_ticks")
     if blocks is not None:
         out["block_write_share"] = round(blocks / ticks, 4) if ticks else None
+        # what a tick that wrote by blocks fetched and stored for it, MB (1e6 bytes)
+        out["block_write_mb_per_tick"] = (
+            round(tick_stats.get("block_write_bytes", 0) / blocks / 1e6, 3) if blocks else None)
     # ticks whose rows' attention read each row to its own length, and what
     # reading by whole blocks fetched over what those rows hold
     by_length = tick_stats.get("length_read_ticks")
@@ -695,6 +698,8 @@ def format_summary(summary: dict) -> str:
                    if host.get("prefill_pad_share") is not None else "")
                 + (f"   block writes {host['block_write_share']:.1%}"
                    if host.get("block_write_share") is not None else "")
+                + (f" ({host['block_write_mb_per_tick']:.1f} MB a tick)"
+                   if host.get("block_write_mb_per_tick") is not None else "")
                 + (f"   length reads {host['length_read_share']:.1%}"
                    if host.get("length_read_share") is not None else "")
                 + (f"   keys read ÷ live {host['row_keys_read_per_live']:.3f}"

@@ -16,7 +16,7 @@ import deepspeed_tpu
 from deepspeed_tpu import comm
 from deepspeed_tpu.inference.continuous import ContinuousBatchingEngine
 from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
-from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+from deepspeed_tpu.models.transformer import LayerKind, TransformerConfig, TransformerModel
 from deepspeed_tpu.ops.transformer import kv_cache
 
 FLOOR = 16   # small tight-read floor so toy pools cross read buckets
@@ -304,3 +304,53 @@ def test_tick_stats_count_the_ticks_whose_rows_wrote_by_blocks(models, monkeypat
     assert st["block_write_ticks"] < st["ticks"]
     host = loadgen.host_overhead(st)
     assert host["block_write_share"] == pytest.approx(st["block_write_ticks"] / st["ticks"], abs=1e-4)
+
+
+def _looped_lane_plan():
+    """Two layers of one kind walked twice, 2 key-value heads of 128: a pool of 4 layer-steps whose
+    two leaves are whole lane tiles wide (8-slot blocks in float32)."""
+    cfg = TransformerConfig(
+        vocab_size=160, hidden_size=64, num_layers=2, num_heads=2, head_size=128, ffn_hidden_size=96,
+        pos_embedding="rope", norm_type="rmsnorm", norm_position="sandwich", activation="silu_glu",
+        tie_embeddings=False, use_bias=False, layer_kinds=(LayerKind(name="f", kv_heads=2),),
+        layer_plan=(0, 0), loop_steps=2, max_seq_len=LENGTH, dtype="float32")
+    model = TransformerModel(cfg)
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("name", ["time_minor", "lane_aligned_plan"])
+def test_tick_stats_count_the_bytes_the_rows_block_writes_moved(models, monkeypatch, name):
+    """``block_write_bytes``: every row's block, in and out, of every leaf
+    and layer-step, in the ticks ``block_write_ticks`` counts, read off static
+    shapes: a time-minor pool's block is 128 slots, a lane-aligned leaf's one
+    sublane tile (8 of float32); 0 where no tick wrote by blocks; and
+    ``ds_loadgen`` prints it as MB a tick."""
+    from deepspeed_tpu.serving import loadgen
+
+    if name == "time_minor":
+        model, params = models("one_chip")
+        rows, leaves, layer_steps, block = 3, 2, 2, 128 * 4 * 16 * 4
+    else:
+        model, params = _looped_lane_plan()
+        rows, leaves, layer_steps, block = 3, 2, 4, 8 * 2 * 128 * 4
+
+    def serve():
+        cb = ContinuousBatchingEngine(
+            model, params=params, max_slots=rows, cache_len=LENGTH, prefill_chunk=128, donate_cache=False,
+            config={"dtype": "float32", "kv_read_floor": FLOOR, "mesh": {"shape": {"data": 1, "tensor": 1}}})
+        _drain(cb, [cb.submit(_prompt(n, seed=n), max_new_tokens=5) for n in (20, 140, 300)])
+        return cb.tick_stats()
+
+    st = serve()                                                  # a toy row is far under the constant
+    assert st["block_write_ticks"] == 0 and st["block_write_bytes"] == 0
+    assert loadgen.host_overhead(st)["block_write_mb_per_tick"] is None
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 0)
+    st = serve()
+    a_tick = rows * block * 2 * leaves * layer_steps
+    assert 0 < st["block_write_ticks"] < st["ticks"]
+    assert st["block_write_bytes"] == st["block_write_ticks"] * a_tick
+    host = loadgen.host_overhead(st)
+    assert host["block_write_mb_per_tick"] == pytest.approx(a_tick / 1e6, abs=1e-3)
+    text = loadgen.format_summary({"outcomes": {}, "requests": 3, "wall_s": 1.0, "throughput_tok_s": 1.0,
+                                   "goodput_tok_s": 1.0, "shed_rate": 0.0, "host": host})
+    assert f"block writes {host['block_write_share']:.1%} ({a_tick / 1e6:.1f} MB a tick)" in text

@@ -330,6 +330,138 @@ def test_block_write_through_update_kv_cache_dense_int8_and_a_ring(monkeypatch, 
                                                          jax.tree.leaves(cache)))
 
 
+def lane_pool(heads_first, dtype, batch, T, width=128, heads=2):
+    """A leaf whose width is whole lane tiles, values in [1, 2) (int8: [0, 100)), and the rows'
+    tokens in [-2, -1) (int8: [-100, 0)): a written element differs from what it replaced."""
+    spec = kv_cache.PoolSpec("p", L, heads, width, width, None, heads_first, False)
+    rs = np.random.RandomState(7)
+    whole = jnp.issubdtype(dtype, jnp.integer)
+    draw = (lambda shape: rs.randint(0, 100, shape)) if whole else (lambda shape: 1 + rs.rand(*shape))
+    pool = jnp.asarray(draw(spec.shape(batch, T, width)), dtype)
+    new = jnp.asarray(-1 - draw((batch, heads, width)), dtype)
+    return pool, new, spec
+
+
+@pytest.mark.parametrize("heads_first", [True, False], ids=["heads_first", "time_first"])
+@pytest.mark.parametrize("T,size", [(320, 256), (320, None), (320, 300), (300, None), (300, 256)],
+                         ids=["256of320", "320", "300of320", "300", "256of300"])
+@pytest.mark.parametrize("dtype,slots", [(jnp.bfloat16, 16), (jnp.float32, 8), (jnp.int8, 32)],
+                         ids=["bfloat16", "float32", "int8"])
+def test_block_write_of_a_lane_aligned_leaf_moves_one_sublane_tile_and_the_windows_bits(
+        monkeypatch, heads_first, T, size, dtype, slots):
+    """A leaf kept as written (width of whole lanes): the block is one packed
+    sublane tile of slots, the token goes in as it is, and the pool is the
+    window path's bit for bit. Columns at a tile's first and last slot and
+    either side of it, the window's last slot, one past it (dropped; in
+    300-of-320 the slot is in the last block's reach), the allocation (a
+    parked row) and a negative one; a window of 300 slots is no multiple of
+    any dtype's block: of 320 its last block reaches past the window, inside
+    the allocation; an ALLOCATION of 300 keeps the window path (the guard
+    ``_takes_ragged`` is still for), while its 256-slot window goes by blocks."""
+    reach = size or T
+    cols = np.asarray([0, 15, 16, 17, 127, 128, reach - 1, reach, T, -1], np.int32)
+    pool, new, spec = lane_pool(heads_first, dtype, len(cols), T)
+    by_blocks = T % slots == 0 or reach % kv_cache.BLOCK == 0
+    assert kv_cache.block_slots(pool) == slots and kv_cache._takes_ragged(pool, heads_first) == (T % slots == 0)
+    toks, at = rows_tokens(new, jnp.asarray(cols), heads_first)
+    calls, kernel = [], kv_cache._write_blocks
+    monkeypatch.setattr(kv_cache, "_write_blocks", lambda *a: calls.append(a[2].shape) or kernel(*a))
+
+    def build():
+        return jax.jit(lambda p: kv_cache.write(p, jnp.int32(1), toks, at, size,
+                                                heads_first=heads_first)), (pool,)
+
+    (window, window_text), (block, block_text) = both_paths(monkeypatch, build)
+    assert (window_text != block_text) == by_blocks
+    token = (len(cols), 2, 1, 128) if heads_first else (len(cols), 1, 2, 128)      # as it is
+    assert set(calls) == ({token} if by_blocks else set())
+    np.testing.assert_array_equal(block.view(np.uint8), window.view(np.uint8))
+    changed = (by_time(block, spec) != by_time(pool, spec))        # (L, B, T, H, x)
+    for b, c in enumerate(cols):
+        hit = changed[1, b].sum(axis=(-1, -2))                     # elements a slot of this row
+        want = np.zeros(T, int)
+        if 0 <= c < reach:
+            want[c] = 2 * 128                                      # heads x width, and nothing else
+        np.testing.assert_array_equal(hit, want)
+    assert not changed[0].any()
+
+
+def block_write_call(fn, *args):
+    """(grid, block shapes, operand shapes, scratch shapes, aliases) of the
+    one ``kv_block_write`` equation in ``fn``'s jaxpr."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call" and eqn.params["name"] == "kv_block_write":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from find(sub)
+
+    (eqn,) = find(jax.make_jaxpr(fn)(*args).jaxpr)
+    grid = eqn.params["grid_mapping"]
+    blocks = [tuple(getattr(d, "block_size", None) for d in m.block_shape) for m in grid.block_mappings]
+    return (grid.grid, blocks, [v.aval.shape for v in eqn.invars],
+            [a.shape for a in grid.scratch_avals], eqn.params["input_output_aliases"])
+
+
+@pytest.mark.parametrize("case,shape,heads_first,want", [
+    # GPT-2's pool (time before heads, 64 wide), MiMo's keys (heads first, 192 wide) and an int8
+    # pool's scales: TIME-minor on the chip, the call the parent made (PR 32): the (L, B, H, x, T)
+    # transpose in 128-slot blocks, a grid step a row, beside the token broadcast to a block
+    ("gpt2", (3, 4, 512, 5, 64), False,
+     ((4,), [(None, None, 5, 64, 128), (None, 5, 64, 128), (None, None, 5, 64, 128)],
+      [(1,), (4,), (4,), (3, 4, 5, 64, 512), (4, 5, 64, 128)], [])),
+    ("mimo_keys", (3, 4, 2, 512, 192), True,
+     ((4,), [(None, None, 2, 192, 128), (None, 2, 192, 128), (None, None, 2, 192, 128)],
+      [(1,), (4,), (4,), (3, 4, 2, 192, 512), (4, 2, 192, 128)], [])),
+    ("int8_scales", (3, 4, 512, 5, 1), False,
+     ((4,), [(None, None, 5, 1, 128), (None, 5, 1, 128), (None, None, 5, 1, 128)],
+      [(1,), (4,), (4,), (3, 4, 5, 1, 512), (4, 5, 1, 128)], [])),
+    # whole lanes: the leaf as written and left where it is (the kernel copies the rows' blocks of
+    # one sublane tile itself, all rows in one grid step), the token as it is
+    ("ouro", (3, 4, 2, 320, 128), True,
+     ((1,), [(3, 4, 2, 320, 128), (4, 2, 1, 128), (3, 4, 2, 320, 128)],
+      [(1,), (4,), (4,), (3, 4, 2, 320, 128), (4, 2, 1, 128)], [(4, 2, 16, 128), (4,)])),
+    ("latent", (3, 4, 1, 512, 640), True,
+     ((1,), [(3, 4, 1, 512, 640), (4, 1, 1, 640), (3, 4, 1, 512, 640)],
+      [(1,), (4,), (4,), (3, 4, 1, 512, 640), (4, 1, 1, 640)], [(4, 1, 16, 640), (4,)])),
+    ("whole_lanes_time_first", (3, 4, 512, 5, 128), False,
+     ((1,), [(3, 4, 512, 5, 128), (4, 1, 5, 128), (3, 4, 512, 5, 128)],
+      [(1,), (4,), (4,), (3, 4, 512, 5, 128), (4, 1, 5, 128)], [(4, 16, 5, 128), (4,)])),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_the_kernels_call_follows_the_leafs_layout(monkeypatch, case, shape, heads_first, want):
+    """The ``kv_block_write`` equation's grid, block shapes, operand shapes
+    and scratch, read off the jaxpr: a time-minor leaf's are the parent's,
+    letter for letter; a lane-aligned leaf's block (the kernel's buffer) is
+    ``block_slots`` high and its token one slot."""
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 0)
+    pool = jnp.zeros(shape, jnp.bfloat16)
+    rows, heads = shape[1], shape[2 if heads_first else 3]
+    toks, at = rows_tokens(jnp.ones((rows, heads, shape[4])), jnp.arange(rows, dtype=jnp.int32), heads_first)
+    *call, aliases = block_write_call(
+        lambda p: kv_cache.write(p, jnp.int32(1), toks, at, None, heads_first=heads_first), pool)
+    assert tuple(call) == want
+    assert aliases == ((3, 0),)                                    # the pool, in place
+
+
+def test_a_lane_aligned_leafs_rows_go_as_many_a_grid_step_as_the_budget_holds(monkeypatch):
+    """Six rows whose blocks are 8 KiB each: all in one step under the
+    budget, three a step at 24 KiB, one a step where not even one fits; the
+    pool is the same every way."""
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 0)
+    pool, new, spec = lane_pool(True, jnp.bfloat16, 6, 256)
+    cols = jnp.asarray([0, 17, 256, 255, -1, 131], jnp.int32)
+    outs = []
+    for budget, steps in ((kv_cache._TILE_WRITE_VMEM_BYTES, 1), (3 * 8192, 2), (5 * 8192, 2), (100, 6)):
+        monkeypatch.setattr(kv_cache, "_TILE_WRITE_VMEM_BYTES", budget)
+        write = lambda p: kv_cache.write(p, jnp.int32(0), new, cols, None, heads_first=True)   # traced anew
+        grid, _, _, scratch, _ = block_write_call(write, pool)
+        assert grid == (steps,) and scratch[0] == (6 // steps, 2, 16, 128)
+        outs.append(np.asarray(write(pool)))
+    for out in outs[1:]:
+        np.testing.assert_array_equal(out.view(np.uint8), outs[0].view(np.uint8))
+    assert int((outs[0] != np.asarray(pool)).sum()) == 4 * 2 * 128        # four rows land
+
+
 @pytest.mark.parametrize("case", ["size128", "size64", "not_whole_blocks", "three_tokens_a_row",
                                   "one_rows_chunk", "pools_span_chips"])
 def test_writes_the_rule_leaves_alone_lower_to_the_window_paths_text(monkeypatch, case):
@@ -422,11 +554,11 @@ def test_int8_write_quantises_and_the_read_dequantises():
 @pytest.mark.parametrize("width,by_blocks", [(128, True), (64, False)], ids=["whole-lanes", "time-minor"])
 def test_a_window_that_is_not_whole_blocks_goes_by_blocks_where_the_leaf_can_take_it(monkeypatch, width,
                                                                                     by_blocks):
-    """A 320-slot allocation (two blocks and 64 slots): a heads-first leaf
-    whose width is whole lane tiles writes its rows' tokens by blocks, the
-    third block fetched and stored as far as the allocation goes, and equals
-    the window path slot for slot; a time-minor leaf keeps the window path;
-    the rows' read by length never takes such a window."""
+    """A 320-slot allocation (two ``BLOCK``s and 64 slots): a heads-first
+    leaf whose width is whole lane tiles writes its rows' tokens by blocks of
+    its own (forty whole 8-slot blocks of float32: none reaches past the
+    leaf) and equals the window path slot for slot; a time-minor leaf keeps
+    the window path; the rows' read by length never takes such a window."""
     rs = np.random.RandomState(5)
     pool = jnp.asarray(rs.normal(size=(2, 3, 2, 320, width)), jnp.float32)
     new = jnp.asarray(rs.normal(size=(3, 2, width)), jnp.float32)

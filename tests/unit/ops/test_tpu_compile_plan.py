@@ -16,6 +16,7 @@ pool in place and the block write at width 640. Nothing runs. Skipped where
 libtpu cannot describe the topology."""
 
 import json
+import math
 import os
 import re
 
@@ -55,6 +56,46 @@ def _compile(topo, fn, *shapes):
         lowered = jax.jit(fn).lower(*args)
     assert "tpu_custom_call" in lowered.as_text()
     return lowered.compile()
+
+
+def _block_writes(text):
+    """How many ``kv_block_write`` calls the compiled program holds, each with
+    its pool operand aliased to its result (updated where it lies)."""
+    calls = [call.split("backend_config=")[0]
+             for call in re.findall(r"%kv_block_write\S* = \S+ custom-call\(.*", text)]
+    assert all("output_to_operand_aliasing={{}: (3, {})}" in call for call in calls), calls
+    return len(calls)
+
+
+@pytest.mark.parametrize("shape,dtype,size", [
+    ((192, 16, 16, 320, 128), jnp.bfloat16, 320),       # Ouro: twenty 16-slot blocks, none past the leaf
+    ((192, 16, 16, 320, 128), jnp.bfloat16, 256),
+    ((2, 32, 8, 16896, 128), jnp.bfloat16, 2048),       # MiMo's values
+    ((3, 8, 2, 16896, 256), jnp.bfloat16, 2048),        # Qwen3-Next
+    ((6, 32, 1, 16896, 640), jnp.bfloat16, 2048),       # the latent pool
+    ((2, 64, 25, 2048, 128), jnp.bfloat16, 2048),       # 100 KiB a row's block: 32 of 64 rows a grid step
+    ((4, 16, 16, 2048, 128), jnp.float32, 512),         # 8-slot blocks
+    ((4, 16, 16, 2048, 128), jnp.int8, 512),            # 32-slot blocks
+], ids=["ouro-320", "ouro-256", "mimo-values", "qwen3-next", "latent-640", "two-steps", "float32", "int8"])
+def test_block_write_of_a_lane_aligned_leaf_compiles_in_place(topo, shape, dtype, size):
+    """The rows' one-token write where the leaf's width is whole lanes
+    (PR 45): one ``kv_block_write`` call whose DMAs move one sublane tile of
+    slots a row, the pool aliased, no temporary at all (the token array of a
+    128-slot block a row is gone) and no copy of the leaf."""
+    from deepspeed_tpu.ops.transformer import kv_cache
+
+    L, B, H, T, x = shape
+    sh = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh)
+            for s, d in ((shape, dtype), ((B, H, x), dtype), ((B,), jnp.int32), ((), jnp.int32))]
+    assert kv_cache.takes_block_write(size, size * H * x * jnp.dtype(dtype).itemsize, ragged=True)
+    with force_interpret(False):
+        compiled = jax.jit(lambda pool, new, cols, layer: kv_cache.write(
+            pool, layer, new, cols, size, heads_first=True), donate_argnums=0).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _block_writes(text) == 1
+    assert mem.temp_size_in_bytes == 0 and mem.alias_size_in_bytes == math.prod(shape) * jnp.dtype(dtype).itemsize
+    assert not re.findall(rf"\[{L},{B},{H},{T},{x}\]\S* copy\(", text)
 
 
 def _compile_flash_chunk(topo, W, T, H, kv, dk, dv, window=None, q_off=None, sink=True):
@@ -154,8 +195,12 @@ def test_mimo_tick_updates_both_pools_in_place(topo, read_len, chunk):
                 + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert resident < 15.75e9
     # the full-length pool is never copied (the ring, 0.1 GB, may be re-laid out for a chunk)
-    copies = re.findall(r"= bf16\[(?:\d+,)?32,4,16896,\d+\]\S* copy\(", compiled.as_text())
+    text = compiled.as_text()
+    copies = re.findall(r"= bf16\[(?:\d+,)?32,4,16896,\d+\]\S* copy\(", text)
     assert not copies, copies
+    # the full layers' rows go by blocks from a 512-slot read on (both leaves, K time-minor in 128-slot
+    # blocks and V in sublane tiles, one layer body a run of full layers), every call in place
+    assert _block_writes(text) in (2, 4)
 
 
 def test_flash_chunk_kernel_compiles_at_head_width_256(topo):
@@ -295,7 +340,8 @@ def test_glm_tick_updates_the_latent_pool_in_place(topo, read_len, chunk):
     assert not re.findall(r"= bf16\[(?:\d+,)?32,1,16896,\d+\]\S* copy\(", text)
     assert "mla_decode" in text and ("flash_chunk_fwd" in text) == (chunk is not None)
     assert ("mla_expand" in text) == (chunk is not None)
-    assert "kv_block_write" in text                                  # 2,048 slots x 1,280 B a row: over the rule
+    assert _block_writes(text) >= 1                                  # 2,048 slots x 1,280 B a row: over the rule
+    assert not re.findall(r"bf16\[32,1,128,640\]", text)             # no 128-slot block a row anywhere
 
 
 SSD_POOL = ((9, 32, 64, 128, 128), jnp.float32)           # Granite 4.0-H Small's state pool: 1.21 GB
@@ -432,5 +478,9 @@ def test_ouro_tick_walks_one_layer_body_four_times_with_both_pool_leaves_in_plac
     assert not re.findall(rf"= bf16\[(?:\d+,)?{slots},16,{length},128\]\S* copy\(", text)
     assert not re.findall(r"= bf16\[(?:\d+,)?2048,5632\]\S* copy\(", text)   # nor of a layer's weights
     assert ("flash_chunk_fwd" in text) == (chunk is not None)
+    # the rows' write (PR 45): K and V once in the one layer body, each pool leaf aliased through the
+    # call, and no temporary of a 128-slot block a row (the token array the parent's call read)
+    assert _block_writes(text) == 2
+    assert not re.findall(rf"bf16\[{slots},16,128,128\]", text)
     # one layer body: the scan over the passes around the scan over the layers, and no other loop
     assert len(re.findall(r" while\(", text)) == 2
