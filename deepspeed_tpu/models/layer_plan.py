@@ -317,14 +317,14 @@ def _tf():
     return tf
 
 
-def _project(h, attn_p, kind, cfg, positions):
-    """h (N, D), positions (N,) -> q (N, nh, dk), k (N, kv, dk), v (N, kv, dv)."""
+def _project(h, attn_p, kind, cfg, positions, product=None):
+    """h (N, D), positions (N,) -> q (N, nh, dk), k (N, kv, dk), v (N, kv, dv); ``product`` as ``tf._qkv``'s."""
     tf = _tf()
     with jax.named_scope(Scope.ATTN_QKV):
-        N = h.shape[0]
-        q = tf._linear(h, attn_p["wq"]).reshape(1, N, cfg.num_heads, cfg.head_dim)
-        k = tf._linear(h, attn_p["wk"]).reshape(1, N, kind.kv_heads, cfg.head_dim)
-        v = tf._linear(h, attn_p["wv"]).reshape(N, kind.kv_heads, cfg.v_head_dim)
+        N, product = h.shape[0], product or tf._linear
+        q = product(h, attn_p["wq"]).reshape(1, N, cfg.num_heads, cfg.head_dim)
+        k = product(h, attn_p["wk"]).reshape(1, N, kind.kv_heads, cfg.head_dim)
+        v = product(h, attn_p["wv"]).reshape(N, kind.kv_heads, cfg.v_head_dim)
         if cfg.qk_norm:  # over each head's width, before it turns
             q = tf._norm(q, attn_p["q_norm"], None, cfg)
             k = tf._norm(k, attn_p["k_norm"], None, cfg)
@@ -1087,7 +1087,7 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
                                     all_pos, chunk, read_len, length)
             pool = {"c": leaf}
         else:
-            q, k, v = _project(h, layer_p["attn"], kind, cfg, all_pos)
+            q, k, v = _project(h, layer_p["attn"], kind, cfg, all_pos, product=tf._heads_product)
             att, pk, pv = _attend_cached(q, k, v, layer_p["attn"], kind, cfg, pool["k"], pool["v"],
                                          pool_index, pos, chunk, read_len, length)
             out, pool = _attn_out(att, h, layer_p["attn"], cfg), {"k": pk, "v": pv}

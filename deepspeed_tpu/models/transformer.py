@@ -899,13 +899,13 @@ def _linear(x, w):
 
 
 @jax.named_scope(Scope.ATTN_QKV)
-def _qkv(h, attn_p, cfg: TransformerConfig, positions):
-    """Project h -> (q, k, v) heads with positional transform applied."""
+def _qkv(h, attn_p, cfg: TransformerConfig, positions, product=_linear):
+    """Project h -> (q, k, v) heads with positional transform applied (``product``: see _heads_product)."""
     B, S, _ = h.shape
     nh, nkv, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    q = _linear(h, attn_p["wq"])
-    k = _linear(h, attn_p["wk"])
-    v = _linear(h, attn_p["wv"])
+    q = product(h, attn_p["wq"])
+    k = product(h, attn_p["wk"])
+    v = product(h, attn_p["wv"])
     if cfg.use_bias:
         q, k, v = q + attn_p["bq"], k + attn_p["bk"], v + attn_p["bv"]
     q = q.reshape(B, S, nh, hd)
@@ -1357,7 +1357,7 @@ def _layer_body_cached(x, layer_params, pool_k, pool_v, layer, cfg: TransformerC
 
     pre_ln = cfg.norm_position == "pre"
     h = _norm(x, ln1["scale"], ln1.get("bias"), cfg) if pre_ln else x
-    q, k, v = _qkv(h, attn_p, cfg, positions)
+    q, k, v = _qkv(h, attn_p, cfg, positions, product=_heads_product)
 
     # PREFILL fast path: pos is the literal int 0 only in the prefill
     # program (compile_decode_fns traces with a Python 0), where attention
@@ -1564,7 +1564,7 @@ def forward_tick_cached(params, cfg: TransformerConfig, tokens, pos, cache, chun
     def layer_fn(x, layer_p, pool_k, pool_v, layer, window):
         attn_p, ln1 = layer_p["attn"], layer_p["ln1"]
         h = _norm(x, ln1["scale"], ln1.get("bias"), cfg) if cfg.norm_position == "pre" else x
-        q, k, v = _qkv(h, attn_p, cfg, positions)                        # (1, B + W, heads, hd)
+        q, k, v = _qkv(h, attn_p, cfg, positions, product=_heads_product)  # (1, B + W, heads, hd)
         rows = lambda a: a[0, :B, None]                                  # (B, 1, heads, hd)
         with jax.named_scope(Scope.ATTN_KV_WRITE):
             pool_k, pool_v = update_kv_cache(pool_k, pool_v, rows(k), rows(v), pos, row_pos,
@@ -1634,3 +1634,27 @@ class TransformerModel:
 
     def num_params(self) -> int:
         return self.cfg.num_params()
+
+
+def _heads_product(x, w):
+    """:func:`_linear` for a q / k / v product of a program that holds a KV
+    pool, whose consumers are heads-first (the pool's leaves, the grouped
+    attention): the ``(..., heads * width)`` result passes a row-major layout
+    constraint before it is split into heads, so the chip's compiler lays
+    the product out for itself and not for them. Left free, it laid the
+    RESULT out heads-outermost and so wanted the weight transposed: a copy
+    of the whole stacked leaf every tick, or of a layer's slice every layer
+    (PERF.md section 6, PR 46). Constrained, the product takes the stacked
+    weight as the engine holds it, by the layer's index, and what is re-laid
+    out for the heads is the result (rows x heads * width: 64 KB at 16 rows).
+    Changes no value. Two cases constrain nothing: a quantized leaf, which
+    goes where it went (no cell measures one), and a program whose pools
+    span several chips, where the partitioner knows no rule for the
+    constraint and gathers a split result to apply it (three all-gathers a
+    layer at tensor width 2)."""
+    from jax.experimental.layout import Layout, with_layout_constraint  # here: no line above moves
+
+    if isinstance(w, dict) or kv_cache.traced_over_chips():
+        return _linear(x, w)
+    out = _linear(x, w)
+    return with_layout_constraint(out, Layout(major_to_minor=tuple(range(out.ndim))))

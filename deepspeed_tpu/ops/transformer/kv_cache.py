@@ -790,3 +790,9 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, pos, positions=None, ring=Fa
         return component(cache, new)
 
     return write_one(k_cache, k_new), write_one(v_cache, v_new)
+
+
+def traced_over_chips() -> bool:
+    """Whether the program being traced holds its pools on more than one chip
+    (:func:`split_over_chips`): all that a traced function sees of its mesh."""
+    return _split_over_chips

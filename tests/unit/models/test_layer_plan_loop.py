@@ -127,7 +127,9 @@ def test_the_lowered_looped_tick_holds_one_layer_body(model, chunk):
 # sha256 of jit(forward_plan_cached).lower(...).as_text() of the toy MiMo and toy Qwen3-Next ticks
 # (4 slots of 128, read 64; plain, and with a 32-token chunk), recorded on the parent of the PR
 # that brought loop_steps and the sandwich norm (409d684): walked once and pre-norm, a plan's
-# tick is the old program, to the text.
+# tick is the old program, to the text — since PR 46 but for the pin on its q / k / v products'
+# results (``tf._heads_product``, a custom call a product): with the plain product in its place
+# the text is still that parent's, and with it the tick gains three calls an attention body.
 PARENTS_TICKS = {
     ("toy-mimo-v2", None): "25f1535cc4e9cedd857f394dd67926511dd83fb17a499e37a331df167f2503fa",
     ("toy-mimo-v2", 32): "6539e866265289e05fb1dbd5ab162c52e4b2c1b6c9d0a1d5f6c8a5b1b19b50f6",
@@ -139,13 +141,16 @@ PARENTS_TICKS = {
 
 
 @pytest.mark.parametrize("name,chunk", sorted(PARENTS_TICKS, key=str))
-def test_a_plan_walked_once_with_pre_norm_is_the_parents_program(name, chunk):
+def test_a_plan_walked_once_with_pre_norm_is_the_parents_program(name, chunk, monkeypatch):
     config = toy(name)
     cfg = compare.builder_of(config).build_model(config, max_seq_len=128, remat=False,
                                                  attn_impl="pallas").cfg
     assert (cfg.loop_steps, cfg.norm_position) == (1, "pre")
     assert sum(s.layers for s in kv_cache.specs(cfg)) == sum(k.pool != "state" for k in cfg.plan)
     assert sum(r.n for r in layer_plan.runs(cfg)) == cfg.num_layers
+    bodies = sum(r.kind.mixer == "attention" for r in layer_plan.runs(cfg))   # a run is one traced body
+    assert lowered(cfg, chunk).count("@LayoutConstraint") == 3 * bodies
+    monkeypatch.setattr(tf, "_heads_product", tf._linear)
     assert hashlib.sha256(lowered(cfg, chunk).encode()).hexdigest() == PARENTS_TICKS[name, chunk]
 
 

@@ -239,7 +239,10 @@ def test_serving_tick_updates_the_kv_pool_in_place(topo, preset, slots, read_len
     call, the pools entering as their (L, B, H * x, T) view, which again
     must be a bitcast (the copy check above); the rows' float32 logits
     (B, heads, 1, T), which the compiler keeps as (B, heads, T), are then
-    nowhere in the program, and at a 128-slot read they are."""
+    nowhere in the program, and at a 128-slot read they are. The q / k / v
+    products take the stacked weights by the layer's index as the engine
+    holds them (PR 46): no layer's (D, D) slice is transposed or read into
+    VMEM by an op of its own."""
     from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
     from deepspeed_tpu.models import transformer as tf
 
@@ -275,6 +278,11 @@ def test_serving_tick_updates_the_kv_pool_in_place(topo, preset, slots, read_len
     kv = rf"{slots},{length},{cfg.kv_heads},{cfg.head_dim}\]"
     copies = re.findall(rf"= bf16\[(?:\d+,)?{kv}\S* copy\(", text)
     assert not copies, copies
+    # nor is a q / k / v weight (PR 46, ``tf._heads_product``): before, a layer's slice of each was
+    # read into VMEM by an op of its own and two or three of them transposed there, every layer
+    D = cfg.hidden_size
+    moved = re.findall(rf"= bf16\[(?:\d+,)?{D},{D}\](?:\S* copy|\{{[^}}]*S\(1\)\}} dynamic-slice)\(", text)
+    assert not moved, moved
     # values of the window's shape among the ops of the rows' / the chunk's write
     scopes = scope_table(text)
     window = re.compile(rf"\s*(?:ROOT\s+)?%?([\w.\-]+) = bf16\[(?:1,)?{slots},{read_len or length},"
