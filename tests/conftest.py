@@ -16,35 +16,43 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
-# Persistent XLA compile cache: compiles survive the per-module
-# clear_caches() below AND rerun invocations (measured ~2x on warm,
-# compile-heavy modules; the build host has one CPU core, so compiles
-# dominate the suite). ~MBs of machine-local artifacts; gitignored.
+# Tests compile their CPU programs without the backend's expensive passes
+# (backend optimisation level 0, LLVM's costly passes off; tracing, the HLO
+# passes, partitioning and the lowered text are as ever). The toy programs
+# run for milliseconds, and under the tier-1 command's six workers the suite
+# is bound by CPU-seconds of compile: XLA:CPU compiles on several threads,
+# six workers ask for more cores than the machine has, and most of a compile
+# is LLVM's optimiser. What is tested is the JAX program; no speed is ever
+# read off a CPU run. The modules whose subject IS the compiled program get
+# the default level back: the table and its hook are below.
+CHEAP_COMPILES = True
+jax.config.update("jax_disable_most_optimizations", CHEAP_COMPILES)
+# Persistent XLA compile cache, one directory per xdist worker, wiped when
+# the session starts: a program compiled twice in one session (the
+# per-module clear_caches() below drops the in-memory executables, and many
+# modules build the same toy programs) is compiled once and loaded after.
+# ~MBs of machine-local artifacts; gitignored.
 _CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".pytest_jax_cache"
 )
-# under pytest-xdist each worker gets its OWN dir: the session-start wipe
-# below would race sibling workers on a shared one, and cross-process
-# entry reuse between live workers is the segfault mode it guards against
+# per WORKER: the session-start wipe below would race sibling workers on a
+# shared directory, and entries written by another live process are the
+# segfault mode described next
 _xdist_worker = os.environ.get("PYTEST_XDIST_WORKER")
 if _xdist_worker:
     _CACHE_DIR += f"-{_xdist_worker}"
-# A cache written by a different jaxlib/CPU hard-aborts (SIGABRT, no
-# traceback) on entry deserialization mid-suite — wipe on stamp mismatch.
 import jaxlib  # noqa: E402
 import platform  # noqa: E402
 import shutil  # noqa: E402
 
 _STAMP = f"{jax.__version__}|{jaxlib.__version__}|{platform.machine()}"  # kept for forensics
-# The cache is SESSION-SCOPED, not cross-run: XLA:CPU executables
-# deserialized from a cache written by ANOTHER process segfault on this
-# jaxlib (reliably reproduced: a fully-green `pytest tests/unit/ops` run
-# followed by an identical rerun on its own cache dies in device_put /
-# engine.step with "Fatal Python error: Segmentation fault"; the
-# jax|jaxlib|arch stamp cannot catch it because the versions match).
-# Same-process re-loads — the per-module clear_caches() below recompiling
-# from the entries THIS run wrote — are safe and are where the ~2x warm
-# speedup actually lives, so wipe at session start and keep the dir on.
+# per SESSION, not across runs: XLA:CPU executables deserialized from a
+# cache written by ANOTHER process segfault on this jaxlib (reliably
+# reproduced: a fully-green `pytest tests/unit/ops` run followed by an
+# identical rerun on its own cache dies in device_put / engine.step with
+# "Fatal Python error: Segmentation fault"; a jax|jaxlib|arch stamp cannot
+# catch it because the versions match). Re-loading what THIS process wrote
+# is safe, so wipe at session start and keep the directory on.
 shutil.rmtree(_CACHE_DIR, ignore_errors=True)
 os.makedirs(_CACHE_DIR, exist_ok=True)
 with open(os.path.join(_CACHE_DIR, ".stamp"), "w") as _fh:
@@ -95,6 +103,47 @@ def _clear_jax_caches_per_module():
     (tests pass in isolation). Module scope keeps intra-module caching."""
     yield
     jax.clear_caches()
+
+
+# What compiles at the backend's DEFAULT level: node ids from the repo's root
+# (a module's path covers its cases; a longer prefix names single cases),
+# each with what it reads off the compiled program. This table is the one
+# place that decides it, tests/benchmark/ included: no environment
+# variable, option or marker.
+DEFAULT_LEVEL = {
+    "tests/unit/ops/test_tpu_compile.py": (
+        "described-v5e compiles: the flag reaches the TPU compiler's options too, and the "
+        "chip-free checks read its layouts, in-place updates, fusions and memory_analysis"
+    ),
+    "tests/unit/ops/test_tpu_compile_plan.py": "the same, for the layer-plan models' ticks and kernels",
+    # A toy cell's timed window (1.0-1.5 s) must see a request end. Unoptimised, a tick of the
+    # interpreted kernels of these three toys runs 4-14 times longer (GLM 14 -> 193 ms, Qwen3-Next
+    # 26 -> 104, Granite 112 -> 788, one process alone) and ends 1, 7 and 0 requests where the
+    # default level ends 38, 21 and 5: execution-bound cases, which the cheap level makes slower.
+    **{
+        f"tests/benchmark/{case}": "a timed window: the unoptimised tick ends one request in it or none"
+        for case in (
+            "test_bench_runners_cpu.py::test_runner_gives_the_contracts_object[toy-glm-longdoc-",
+            "test_bench_runners_cpu.py::test_runner_gives_the_contracts_object[toy-granite-longdoc-",
+            "test_bench_runners_cpu.py::test_runner_gives_the_contracts_object[toy-qwen3-next-longdoc-",
+            "test_bench_glm4_moe_lite.py::test_the_variant_tool_runs_the_toy_cell_and_a_fault_is_refused[",
+            "test_bench_granitemoehybrid.py::test_a_reference_without_the_decay_is_refused_by_the_toy_cells_comparison",
+            "test_bench_qwen3_next.py::test_a_reference_without_the_decay_is_refused_by_the_toy_cells_comparison",
+        )
+    },
+}
+
+
+@pytest.hookimpl(tryfirst=True)
+def pytest_runtest_setup(item):
+    """Set the level a case compiles at before any of its fixtures is built
+    (a module's fixtures are set up with its first case). The flag is no
+    part of jit's in-memory key, so the executables of the other level are
+    dropped where it turns."""
+    cheap = CHEAP_COMPILES and not item.nodeid.startswith(tuple(DEFAULT_LEVEL))
+    if jax.config.read("jax_disable_most_optimizations") != cheap:
+        jax.clear_caches()
+        jax.config.update("jax_disable_most_optimizations", cheap)
 
 
 @pytest.fixture

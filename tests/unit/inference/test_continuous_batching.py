@@ -1,7 +1,6 @@
 """Continuous (in-flight) batching (inference/continuous.py) — slot-pool
 serving beyond the v0.9.1 reference's static-batch generate."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,23 +8,15 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu import comm
 from deepspeed_tpu.inference.continuous import ContinuousBatchingEngine
-from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+from serving_toys import SMALL, built, prompts as _prompts
 
 
 @pytest.fixture(scope="module")
 def setup():
     comm.destroy()
-    cfg = TransformerConfig(vocab_size=128, hidden_size=64, num_layers=2,
-                            num_heads=4, max_seq_len=128, dtype="float32")
-    model = TransformerModel(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    model, params = built(SMALL)
     plain = deepspeed_tpu.init_inference(model, params=params, config={"dtype": "float32"})
     return model, params, plain
-
-
-def _prompts(ns, seed=0):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(0, 128, (n,)).astype(np.int32) for n in ns]
 
 
 class TestContinuousBatching:

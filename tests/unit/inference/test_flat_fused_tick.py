@@ -18,8 +18,11 @@ from deepspeed_tpu.inference.continuous import ContinuousBatchingEngine
 from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
 from deepspeed_tpu.models.transformer import LayerKind, TransformerConfig, TransformerModel
 from deepspeed_tpu.ops.transformer import kv_cache
+from serving_toys import built, drain as _drain, prompt
 
-FLOOR = 16   # small tight-read floor so toy pools cross read buckets
+COARSE = 128  # the default tight-read floor: 128, 256 and the whole pool. What a case gets whose subject
+#               is the stream: one or two crossings, a plain and a fused program each
+FLOOR = 16    # ... and a small floor where the ladder of read buckets is the subject
 LENGTH = 384
 BASE = TransformerConfig(vocab_size=160, hidden_size=64, num_layers=2, num_heads=4,
                          max_seq_len=LENGTH, dtype="float32")
@@ -41,24 +44,15 @@ VARIANTS = {
 @pytest.fixture(scope="module")
 def models():
     comm.destroy()
-    built = {}
-
-    def get(variant="plain"):
-        if variant not in built:
-            spec = VARIANTS[variant]
-            model = TransformerModel(dataclasses.replace(BASE, **spec.get("cfg", {})))
-            built[variant] = (model, model.init(jax.random.PRNGKey(0)))
-        return built[variant]
-
-    return get
+    return lambda variant="plain": built(dataclasses.replace(BASE, **VARIANTS[variant].get("cfg", {})))
 
 
-def _engine(models, variant="plain", **kw):
+def _engine(models, variant="plain", floor=COARSE, **kw):
     """Continuous engine of a variant. Donation off: the CPU backend blocks
     a donated dispatch, and these tests compare schedules."""
     model, params = models(variant)
     spec = VARIANTS[variant]
-    config = {"dtype": "float32", "kv_read_floor": FLOOR, **spec.get("config", {})}
+    config = {"dtype": "float32", "kv_read_floor": floor, **spec.get("config", {})}
     if "tensor" in spec:
         config["mesh"] = {"shape": {"data": 1, "tensor": spec["tensor"]}}
     kw.setdefault("max_slots", 3)
@@ -80,14 +74,7 @@ def _generate(models, variant, prompt, new):
 
 
 def _prompt(n, seed=0):
-    return np.random.RandomState(seed).randint(0, BASE.vocab_size, (n,)).astype(np.int32)
-
-
-def _drain(cb, rids):
-    while cb.has_work():
-        cb.step()
-    done = cb.finished()
-    return [np.asarray(done[r]) for r in rids]
+    return prompt(n, BASE.vocab_size, seed)
 
 
 def _serve_one(cb, prompt, new, live_rows):
@@ -110,7 +97,7 @@ def test_greedy_stream_equals_generate_for_every_chunk_size(models, n, live_rows
     """Prompts of 1 … 300 tokens (300 = three chunks of the 128 cap, the
     last one padded): the fused tick's greedy stream is ``generate``'s."""
     prompt = _prompt(n, seed=n)
-    cb = _engine(models)
+    cb = _engine(models, floor=FLOOR)
     got, _ = _serve_one(cb, prompt, 10, live_rows)
     np.testing.assert_array_equal(got, _generate(models, "plain", prompt, 10))
     st = cb.tick_stats()
@@ -174,7 +161,7 @@ def test_chunk_crossing_a_read_bucket(models):
     buckets (48 … 95 crosses 64), and the tick reads the bucket that covers
     the chunk's END."""
     prompt = _prompt(110, seed=9)
-    cb = _engine(models, prefill_chunk=48)
+    cb = _engine(models, floor=FLOOR, prefill_chunk=48)
     got, _ = _serve_one(cb, prompt, 10, 1)
     np.testing.assert_array_equal(got, _generate(models, "plain", prompt, 10))
     widths = {k[0] for k in cb._pools[0].tick_fns if k[0] is not None}
@@ -262,7 +249,7 @@ def test_lowered_tick_is_one_loop_over_b_plus_w_tokens(read_len):
 
 def test_precompile_counts_two_programs_a_read_bucket(models):
     """A one-kind pool: a plain and ONE fused program a read bucket."""
-    cb = _engine(models, max_slots=2, cache_len=128)
+    cb = _engine(models, floor=FLOOR, max_slots=2, cache_len=128)
     buckets = {cb._read_len(cb._pools[0], e) for e in range(1, 129)}
     assert len(buckets) == 4                                  # 16, 32, 64, all
     assert cb.precompile_tick_programs() == 2 * len(buckets)
@@ -273,7 +260,7 @@ def test_precompile_counts_two_programs_a_read_bucket(models):
 def test_tick_stats_count_real_and_pad_tokens(models):
     """``prefill_chunk_tokens`` + ``prefill_pad_tokens`` = width x fused
     ticks; the pad share is read, not reckoned."""
-    cb = _engine(models, prefill_chunk=64)
+    cb = _engine(models, floor=FLOOR, prefill_chunk=64)
     rids = [cb.submit(_prompt(n, seed=n), max_new_tokens=3) for n in (64, 100, 7)]
     _drain(cb, rids)
     st = cb.tick_stats()
@@ -314,8 +301,7 @@ def _looped_lane_plan():
         pos_embedding="rope", norm_type="rmsnorm", norm_position="sandwich", activation="silu_glu",
         tie_embeddings=False, use_bias=False, layer_kinds=(LayerKind(name="f", kv_heads=2),),
         layer_plan=(0, 0), loop_steps=2, max_seq_len=LENGTH, dtype="float32")
-    model = TransformerModel(cfg)
-    return model, model.init(jax.random.PRNGKey(0))
+    return built(cfg)
 
 
 @pytest.mark.parametrize("name", ["time_minor", "lane_aligned_plan"])
@@ -337,7 +323,7 @@ def test_tick_stats_count_the_bytes_the_rows_block_writes_moved(models, monkeypa
     def serve():
         cb = ContinuousBatchingEngine(
             model, params=params, max_slots=rows, cache_len=LENGTH, prefill_chunk=128, donate_cache=False,
-            config={"dtype": "float32", "kv_read_floor": FLOOR, "mesh": {"shape": {"data": 1, "tensor": 1}}})
+            config={"dtype": "float32", "kv_read_floor": COARSE, "mesh": {"shape": {"data": 1, "tensor": 1}}})
         _drain(cb, [cb.submit(_prompt(n, seed=n), max_new_tokens=5) for n in (20, 140, 300)])
         return cb.tick_stats()
 

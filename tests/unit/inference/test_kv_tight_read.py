@@ -5,7 +5,6 @@ accounting (the CPU-mesh-measurable form of the decode-bandwidth win)."""
 
 import json
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -18,11 +17,9 @@ from deepspeed_tpu.inference.decoding import (
     read_bucket,
     read_stages,
 )
-from deepspeed_tpu.models.transformer import (
-    TransformerConfig,
-    TransformerModel,
-)
+from deepspeed_tpu.models.transformer import TransformerConfig
 from deepspeed_tpu.ops.transformer.kv_cache import read_bytes_per_row as kv_read_bytes_per_row
+from serving_toys import SMALL, built
 
 FLOOR = 16  # small bucket floor so tiny test models cross several buckets
 
@@ -30,11 +27,7 @@ FLOOR = 16  # small bucket floor so tiny test models cross several buckets
 @pytest.fixture(scope="module")
 def setup():
     comm.destroy()
-    cfg = TransformerConfig(vocab_size=128, hidden_size=64, num_layers=2,
-                            num_heads=4, max_seq_len=128, dtype="float32")
-    model = TransformerModel(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    return model, params
+    return built(SMALL)
 
 
 def _engine(model, params, **over):

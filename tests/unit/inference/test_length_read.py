@@ -15,11 +15,12 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu import comm
 from deepspeed_tpu.inference.continuous import ContinuousBatchingEngine
-from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+from deepspeed_tpu.models.transformer import TransformerConfig
 from deepspeed_tpu.ops.transformer import inference_ops, kv_cache
 from deepspeed_tpu.serving import loadgen
+from serving_toys import built, drain, prompt
 
-FLOOR = 16
+FLOOR = 128   # the default: the rule turns at a 256-slot read, and no case here is about the ladder under it
 LENGTH = 384
 BASE = TransformerConfig(vocab_size=160, hidden_size=64, num_layers=2, num_heads=4,
                          max_seq_len=LENGTH, dtype="float32")
@@ -38,15 +39,7 @@ VARIANTS = {
 @pytest.fixture(scope="module")
 def models():
     comm.destroy()
-    built = {}
-
-    def get(variant):
-        if variant not in built:
-            model = TransformerModel(dataclasses.replace(BASE, **VARIANTS[variant].get("cfg", {})))
-            built[variant] = (model, model.init(jax.random.PRNGKey(0)))
-        return built[variant]
-
-    return get
+    return lambda variant: built(dataclasses.replace(BASE, **VARIANTS[variant].get("cfg", {})))
 
 
 def _engine(models, variant, **kw):
@@ -59,7 +52,7 @@ def _engine(models, variant, **kw):
 
 
 def _prompt(n, seed=0):
-    return np.random.RandomState(seed).randint(0, BASE.vocab_size, (n,)).astype(np.int32)
+    return prompt(n, BASE.vocab_size, seed)
 
 
 def _serve(cb, sizes, new):
@@ -71,10 +64,7 @@ def _serve(cb, sizes, new):
         rids.append(cb.submit(_prompt(n, seed=n), max_new_tokens=new))
         cb.step()
         cb.step()
-    while cb.has_work():
-        cb.step()
-    done = cb.finished()
-    return [np.asarray(done[r]) for r in rids]
+    return drain(cb, rids)
 
 
 # -- the rule, at softmax_context ---------------------------------------------

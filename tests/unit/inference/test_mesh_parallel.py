@@ -19,10 +19,7 @@ from deepspeed_tpu import comm
 from deepspeed_tpu.inference.config import InferenceConfig, MeshConfig
 from deepspeed_tpu.inference.continuous import ContinuousBatchingEngine
 from deepspeed_tpu.inference.decoding import decode_kv_bytes, read_bucket
-from deepspeed_tpu.models.transformer import (
-    TransformerConfig,
-    TransformerModel,
-)
+from deepspeed_tpu.models.transformer import TransformerConfig
 from deepspeed_tpu.ops.transformer.kv_cache import read_bytes_per_row as kv_read_bytes_per_row
 from deepspeed_tpu.parallel.partition import (
     DEFAULT_RULES,
@@ -32,23 +29,15 @@ from deepspeed_tpu.parallel.partition import (
     partition_params,
     serving_mesh,
 )
+from serving_toys import SMALL, built, prompts as _prompts, serve as _serve
 
-FLOOR = 16  # small tight-read floor so tiny pools cross read buckets
+FLOOR = 32  # a tight-read floor under the 64-slot pools: ONE crossing (32 -> the whole pool) a stream
 
 
 @pytest.fixture(scope="module")
 def setup():
     comm.destroy()
-    cfg = TransformerConfig(vocab_size=128, hidden_size=64, num_layers=2,
-                            num_heads=4, max_seq_len=128, dtype="float32")
-    model = TransformerModel(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    return model, params
-
-
-def _prompts(ns, seed=0):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(0, 128, (n,)).astype(np.int32) for n in ns]
+    return built(SMALL)
 
 
 def _cb(setup, tensor=None, **kw):
@@ -65,24 +54,6 @@ def _cb(setup, tensor=None, **kw):
     kw.setdefault("cache_len", 64)
     kw.setdefault("donate_cache", False)
     return ContinuousBatchingEngine(model, params=params, config=cfg, **kw)
-
-
-def _serve(cb, submissions, max_ticks=400):
-    """Drive ``cb`` over [(tick, prompt, max_new)]; returns the finished
-    arrays in submission order."""
-    results = {}
-    pending = list(submissions)
-    rid_of = {}
-    tick = 0
-    while pending or cb.has_work():
-        assert tick < max_ticks, "scheduler did not drain"
-        for item in [s for s in pending if s[0] <= tick]:
-            rid_of[id(item)] = cb.submit(item[1], max_new_tokens=item[2])
-        pending = [s for s in pending if s[0] > tick]
-        cb.step()
-        results.update(cb.finished())
-        tick += 1
-    return [results[rid_of[id(s)]] for s in submissions]
 
 
 class TestPartitionRules:

@@ -18,7 +18,8 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu import comm
-from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+from deepspeed_tpu.models.transformer import TransformerConfig
+from serving_toys import built
 
 W = 16
 
@@ -29,8 +30,7 @@ def _model(window=W, **kw):
         num_kv_heads=2, max_seq_len=256, pos_embedding="rope",
         norm_type="rmsnorm", use_bias=False, attn_impl="pallas",
         local_attn_windows=(window, window) if window else None, **kw)
-    model = TransformerModel(cfg)
-    return model, model.init(jax.random.PRNGKey(0))
+    return built(cfg)
 
 
 def _engines(window=W, **cfg_overrides):

@@ -18,27 +18,20 @@ import deepspeed_tpu
 from deepspeed_tpu import comm
 from deepspeed_tpu.inference.continuous import ContinuousBatchingEngine
 from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+from serving_toys import SMALL, built, prompts as _prompts, serve as _serve
 
-FLOOR = 16  # small tight-read floor so tiny pools cross read buckets
+FLOOR = 32  # a tight-read floor under the 64-slot pools: ONE crossing (32 -> the whole pool) a stream
 
 
 @pytest.fixture(scope="module")
 def setup():
     comm.destroy()
-    cfg = TransformerConfig(vocab_size=128, hidden_size=64, num_layers=2,
-                            num_heads=4, max_seq_len=128, dtype="float32")
-    model = TransformerModel(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    model, params = built(SMALL)
     dcfg = TransformerConfig(vocab_size=128, hidden_size=32, num_layers=1,
                              num_heads=4, max_seq_len=128, dtype="float32")
     draft = TransformerModel(dcfg)
     draft_params = draft.init(jax.random.PRNGKey(1))
     return model, params, draft, draft_params
-
-
-def _prompts(ns, seed=0):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(0, 128, (n,)).astype(np.int32) for n in ns]
 
 
 def _cb(setup, spec=None, tensor=None, use_draft=False, **kw):
@@ -60,31 +53,6 @@ def _cb(setup, spec=None, tensor=None, use_draft=False, **kw):
     if use_draft:
         kw.update(draft_model=draft, draft_params=draft_params)
     return ContinuousBatchingEngine(model, params=params, config=cfg, **kw)
-
-
-def _serve(cb, submissions, max_ticks=400):
-    """Drive ``cb`` over [(tick, prompt, max_new)]; returns the finished
-    arrays in submission order. Asserts the step()-stream/finished()
-    contract — a speculative tick emits up to gamma+1 tokens per rid per
-    step and the concatenation must equal the final array."""
-    streams, results = {}, {}
-    pending = list(submissions)
-    rid_of = {}
-    tick = 0
-    while pending or cb.has_work():
-        assert tick < max_ticks, "scheduler did not drain"
-        for item in [s for s in pending if s[0] <= tick]:
-            rid_of[id(item)] = cb.submit(item[1], max_new_tokens=item[2])
-        pending = [s for s in pending if s[0] > tick]
-        for rid, toks in cb.step().items():
-            streams.setdefault(rid, []).extend(toks)
-        results.update(cb.finished())
-        tick += 1
-    for item in submissions:
-        rid = rid_of[id(item)]
-        np.testing.assert_array_equal(
-            np.asarray(streams[rid], np.int32), results[rid][len(item[1]):])
-    return [results[rid_of[id(s)]] for s in submissions]
 
 
 class TestSpecPoolGreedyParity:

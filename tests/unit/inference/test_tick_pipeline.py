@@ -7,33 +7,24 @@ streams are bitwise identical across every mode, greedy AND sampled."""
 
 import json
 
-import jax
 import numpy as np
 import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu import comm
 from deepspeed_tpu.inference.continuous import ContinuousBatchingEngine
-from deepspeed_tpu.models.transformer import TransformerConfig, TransformerModel
+from serving_toys import SMALL, built, prompts as _prompts, serve as _serve
 
-FLOOR = 16  # small tight-read floor so tiny pools cross read buckets
+FLOOR = 32  # a tight-read floor under the 64-slot pools: ONE crossing (32 -> the whole pool) a stream
 
 
 @pytest.fixture(scope="module")
 def setup():
     comm.destroy()
-    cfg = TransformerConfig(vocab_size=128, hidden_size=64, num_layers=2,
-                            num_heads=4, max_seq_len=128, dtype="float32")
-    model = TransformerModel(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    model, params = built(SMALL)
     plain = deepspeed_tpu.init_inference(model, params=params,
                                          config={"dtype": "float32"})
     return model, params, plain
-
-
-def _prompts(ns, seed=0):
-    rs = np.random.RandomState(seed)
-    return [rs.randint(0, 128, (n,)).astype(np.int32) for n in ns]
 
 
 def _cb(setup, **kw):
@@ -43,30 +34,6 @@ def _cb(setup, **kw):
     kw.setdefault("max_slots", 3)
     kw.setdefault("cache_len", 64)
     return ContinuousBatchingEngine(model, params=params, config=cfg, **kw)
-
-
-def _serve(cb, submissions, max_ticks=400):
-    """Drive ``cb`` over [(tick, prompt, max_new)] submissions; returns
-    (streams, results): per-rid concatenated step() emissions and the
-    finished arrays. Asserts the two agree — the step-stream contract."""
-    streams, results = {}, {}
-    pending = list(submissions)  # list order = submission order per tick
-    rid_of = {}
-    tick = 0
-    while pending or cb.has_work():
-        assert tick < max_ticks, "scheduler did not drain"
-        for item in [s for s in pending if s[0] <= tick]:
-            rid_of[id(item)] = cb.submit(item[1], max_new_tokens=item[2])
-        pending = [s for s in pending if s[0] > tick]
-        for rid, toks in cb.step().items():
-            streams.setdefault(rid, []).extend(toks)
-        results.update(cb.finished())
-        tick += 1
-    for item in submissions:
-        rid = rid_of[id(item)]
-        np.testing.assert_array_equal(
-            np.asarray(streams[rid], np.int32), results[rid][len(item[1]):])
-    return [results[rid_of[id(s)]] for s in submissions]
 
 
 class TestPipelineParity:

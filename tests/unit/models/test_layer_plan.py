@@ -232,7 +232,7 @@ def test_training_forward_has_a_loss_and_a_gradient_for_every_leaf():
     model = TransformerModel(config(attn_impl="xla"))
     params = model.init(jax.random.PRNGKey(3))
     batch = {"input_ids": jnp.asarray(np.random.RandomState(3).randint(0, VOCAB, (2, 24)), jnp.int32)}
-    loss, grads = jax.value_and_grad(model.loss)(params, batch)
+    loss, grads = jax.jit(jax.value_and_grad(model.loss))(params, batch)   # one program, not one an operation
     assert np.isfinite(float(loss)) and abs(float(loss) - np.log(VOCAB)) < 1.0
     dead = [jax.tree_util.keystr(k) for k, g in jax.tree_util.tree_leaves_with_path(grads)
             if not float(jnp.abs(g).sum()) > 0]

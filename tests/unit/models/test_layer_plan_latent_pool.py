@@ -9,6 +9,7 @@ truthfully. (The mathematics against the reference: ``tests/benchmark/
 test_bench_glm4_moe_lite.py``.)"""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -150,10 +151,20 @@ def test_a_chunks_expansion_stops_at_the_block_that_holds_its_last_key(size, end
         assert jnp.array_equal(again[0][:, :n], k[:, :n]) and jnp.array_equal(again[1][:, :n], v[:, :n])
 
 
-def tick(cfg, params, toks, pos, cache, chunk=None, read_len=None):
-    return jax.jit(lambda c, t, p, ch: layer_plan.forward_plan_cached(
-        params, cfg, t, p, c, read_len=read_len, chunk=ch))(cache, jnp.asarray(toks, jnp.int32),
-                                                            jnp.asarray(pos, jnp.int32), chunk)
+def _ticker(cfg, read_len):
+    return jax.jit(lambda params, c, t, p, ch: layer_plan.forward_plan_cached(
+        params, cfg, t, p, c, read_len=read_len, chunk=ch))
+
+
+_shared_ticker = functools.cache(_ticker)
+
+
+def tick(cfg, params, toks, pos, cache, chunk=None, read_len=None, traced_anew=False):
+    """One tick. Calls of equal shapes share ONE traced program (a tick of this plan is seconds of
+    trace, lowering and compile, and the cases below make twenty), but for a case that patches
+    what the trace reads: it asks for its own."""
+    run = _ticker(cfg, read_len) if traced_anew else _shared_ticker(cfg, read_len)
+    return run(params, cache, jnp.asarray(toks, jnp.int32), jnp.asarray(pos, jnp.int32), chunk)
 
 
 def chunk_of(tokens, start, width, slot, length):
@@ -213,7 +224,7 @@ def test_the_rows_write_takes_the_block_path_where_the_rule_says_and_writes_the_
     cfg, T = model.cfg, 256
     cache = tf.init_cache(cfg, 3, T)
     pos = [130, T, 7]
-    want = tick(cfg, params, [1, 2, 3], pos, cache)
+    want = tick(cfg, params, [1, 2, 3], pos, cache, traced_anew=True)
     assert not kv_cache.rows_write_by_blocks(cfg, cache, None)
     monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 1 << 10)
     assert kv_cache.rows_write_by_blocks(cfg, cache, None)            # 256 slots: two blocks
@@ -221,7 +232,7 @@ def test_the_rows_write_takes_the_block_path_where_the_rule_says_and_writes_the_
     calls, sound = [], kv_cache._write_blocks
     monkeypatch.setattr(kv_cache, "_write_blocks",
                         lambda pool, *a: calls.append(pool.shape) or sound(pool, *a))
-    got = tick(cfg, params, [1, 2, 3], pos, cache)
+    got = tick(cfg, params, [1, 2, 3], pos, cache, traced_anew=True)
     assert calls and set(calls) == {(3, 3, 1, 256, 128)}    # the kernel (interpreted here), a run of layers
     assert np.array_equal(np.asarray(got[1]["latent"]["c"]), np.asarray(want[1]["latent"]["c"]))
     assert np.allclose(got[0], want[0], atol=1e-6)

@@ -268,8 +268,8 @@ def test_the_tick_leaves_a_parked_rows_state_and_tail_bit_for_bit(model, params)
     cache = tf.init_cache(cfg, 3, 64)
     pos = jnp.asarray([5, 64, 9], jnp.int32)              # row 1 is parked
     before = jax.tree.map(lambda a: a + 1.0, cache["state"])
-    _, after, stats = layer_plan.forward_plan_cached(params, cfg, jnp.zeros(3, jnp.int32), pos,
-                                                     dict(cache, state=before))
+    _, after, stats = jax.jit(lambda cache: layer_plan.forward_plan_cached(   # one program, not one an operation
+        params, cfg, jnp.zeros(3, jnp.int32), pos, cache))(dict(cache, state=before))
     assert stats.shape == (7,) and stats[-2:].tolist() == [0, 2]
     for a, b in zip(jax.tree.leaves(after["state"]), jax.tree.leaves(before)):
         assert np.array_equal(a[:, 1], b[:, 1]) and not np.array_equal(a[:, 0], b[:, 0])

@@ -345,3 +345,9 @@ def test_plan_tick_writes_its_full_pool_by_blocks_in_place(topo, monkeypatch):
     assert len(re.findall(r" custom-call\(.*kv_block_write", text)) == 4      # K, V x two full runs
     assert shaped(f"{slots},2,8,192")                # the ring, through its window
     assert not re.findall(r"= bf16\[\d+,8,1,512,\d+\]\S* copy\(", text)   # no copy of the full pool
+
+
+def test_this_module_compiles_at_the_default_level():
+    """``tests/conftest.py`` compiles the suite's CPU programs cheaply and
+    lists this module among those that keep the backend's default level."""
+    assert jax.config.read("jax_disable_most_optimizations") is False

@@ -516,3 +516,9 @@ def test_ouro_tick_walks_one_layer_body_four_times_with_both_pool_leaves_in_plac
     assert not re.findall(rf"bf16\[{slots},16,128,128\]", text)
     # one layer body: the scan over the passes around the scan over the layers, and no other loop
     assert len(re.findall(r" while\(", text)) == 2
+
+
+def test_this_module_compiles_at_the_default_level():
+    """``tests/conftest.py`` compiles the suite's CPU programs cheaply and
+    lists this module among those that keep the backend's default level."""
+    assert jax.config.read("jax_disable_most_optimizations") is False
