@@ -52,6 +52,12 @@ EVENT_SCHEMAS = {
         "optional": {
             "loss": "number",
             "tokens_per_sec": "number",
+            # a model that counts beside its loss (engine.moe_stats()): totals so far
+            "moe_assignments": "int",
+            "moe_held_assignments": "int",
+            "moe_expert_tokens_most": "int",
+            "moe_expert_layers": "int",
+            "moe_experts_hit": "int",
         },
     },
     "comm_summary": {

@@ -266,7 +266,7 @@ def build_train_artifacts(tp: int = 1, *, layers: int = 1, seq: int = 16,
     if "train_micro" in wanted and engine._micro_fn is not None:
         out.append(extract_artifact(
             "train_micro", "", engine._micro_fn,
-            (params_s, sds(engine.grad_acc), batch_s, rng_s, f32, f32),
+            (params_s, sds(engine.grad_acc), batch_s, rng_s, f32, f32) + sds(engine._counter_args()),
             meta=meta))
     if "train_apply" in wanted and engine._apply_fn is not None:
         out.append(extract_artifact(

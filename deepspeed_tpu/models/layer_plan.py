@@ -52,6 +52,12 @@ its kinds' layers, layer i of pass t at ``t x (the pool's layers a pass) + i``
 norms: ``ln1_post`` / ``ln2_post`` norm what the mixer and the FFN return,
 before the residual add.
 
+A gated short convolution (``mixer="conv"``) is a mixer of the training
+forward only: [b | c | u] = h W_in, a causal depthwise convolution of
+``conv_taps`` taps over b * u, gated by c, through W_out. Its serving row
+would be the convolution's tail and nothing else, which the state pool does
+not hold yet: ``kv_cache.specs`` and :func:`forward_plan_cached` refuse it.
+
 Two entry points: :func:`forward_plan` (no cache: training, the reference
 comparison) and :func:`forward_plan_cached` (the serving tick: every slot's
 one decode token at its own depth and, with ``chunk``, ONE admitting
@@ -124,10 +130,12 @@ def check_plan(cfg):
             raise ValueError(f"state-space kinds of the state pool need ssm heads, head width and "
                              f"state width, one group and a convolution: {sizes}, "
                              f"{cfg.ssm_groups} groups, {cfg.ssm_conv} taps")
+    if any(k.mixer == "conv" for k in kinds) and cfg.conv_taps < 2:
+        raise ValueError(f"a short-convolution kind needs at least two taps: conv_taps {cfg.conv_taps}")
     if cfg.moe_score not in ("sigmoid", "softmax"):
         raise ValueError(f"moe_score {cfg.moe_score!r}")
     for k in kinds:
-        if k.mixer not in ("attention", "gdn", "ssm", "mla"):
+        if k.mixer not in ("attention", "gdn", "ssm", "mla", "conv"):
             raise ValueError(f"kind {k.name}: mixer {k.mixer!r}")
         if k.mixer == "attention" and cfg.num_heads % k.kv_heads:
             raise ValueError(f"kind {k.name}: {cfg.num_heads} heads over {k.kv_heads} kv heads")
@@ -212,6 +220,12 @@ def _layer_shapes(cfg, kind):
             ("ssm", "d"): ((Hs,), 1.0),
             ("ssm", "norm"): ((inner,), None),
             ("ssm", "wo"): ((inner, D), out_scale / math.sqrt(inner)),
+        })
+    elif kind.mixer == "conv":
+        shapes.update({
+            ("conv", "win"): ((D, 3 * D), 1 / math.sqrt(D)),                # b, c (the two gates), u
+            ("conv", "conv"): ((D, cfg.conv_taps), 1 / math.sqrt(cfg.conv_taps)),
+            ("conv", "wo"): ((D, D), out_scale / math.sqrt(D)),
         })
     elif kind.mixer == "mla":
         qr, kr, dn, dr = cfg.mla_q_rank, cfg.mla_kv_rank, cfg.mla_nope_dim, cfg.mla_rope_dim
@@ -390,7 +404,7 @@ def _ffn(h, mlp_p, kind, cfg, valid, grad):
 
     first, count = cfg.held_experts
     chosen, weights = he.route(h, mlp_p["gate"], mlp_p.get("gate_bias"), cfg.moe_top_k,
-                               cfg.moe_score, scale=cfg.moe_routed_scale)
+                               cfg.moe_score, scale=cfg.moe_routed_scale, norm_eps=cfg.moe_norm_eps)
     out, counts = he.held_experts_ffn(
         h, chosen, weights, {n: mlp_p[n] for n in _EXPERT_LEAVES}, first, count,
         grad=grad, valid=valid, layer=mlp_p.get("layer"))
@@ -425,17 +439,22 @@ def _gdn_project(h, p, cfg):
     return qkvz[:, :C], qkvz[:, C:].reshape(-1, Hv, cfg.gdn_value_dim), g, beta
 
 
-def _causal_conv_silu(seq, w, bias=None):
-    """Causal depthwise convolution (plus ``bias`` (C,)) then SiLU: seq (...,
-    T + K - 1, C), its first K - 1 steps the inputs before the first output;
-    w (C, K). Returns (..., T, C)."""
+def _causal_conv(seq, w, bias=None):
+    """Causal depthwise convolution (plus ``bias`` (C,)) as a sum of shifted
+    products: seq (..., T + K - 1, C), its first K - 1 steps the inputs
+    before the first output; w (C, K). Returns (..., T, C), float32."""
     K = w.shape[1]
     T = seq.shape[-2] - (K - 1)
     acc = sum(seq[..., j:j + T, :].astype(jnp.float32) * w[:, j].astype(jnp.float32)
               for j in range(K))
     if bias is not None:
         acc = acc + bias.astype(jnp.float32)
-    return jax.nn.silu(acc).astype(seq.dtype)
+    return acc
+
+
+def _causal_conv_silu(seq, w, bias=None):
+    """:func:`_causal_conv`, then SiLU, in ``seq``'s dtype."""
+    return jax.nn.silu(_causal_conv(seq, w, bias)).astype(seq.dtype)
 
 
 def _gdn_conv(seq, w):
@@ -626,6 +645,23 @@ def _ssm_cached(h, p, cfg, pool, layer, B, chunk, valid):
             y, x = jnp.concatenate([y, yc]), jnp.concatenate([x, xc])
         pool = {"s": states, "conv": jax.lax.dynamic_update_index_in_dim(pool["conv"], tails, layer, 0)}
         return _ssm_out(y, x, z, p, cfg), pool
+
+
+# -- the gated short convolution mixer --
+
+def _conv_plain(h, p, cfg, B, S):
+    """The mixer over whole sequences, nothing before a row's first token:
+    h (B * S, D) -> (B * S, D). [b | c | u] = h W_in; the convolution runs
+    over b * u, with no activation; c gates what it gives; W_out."""
+    tf = _tf()
+    D, K = cfg.hidden_size, cfg.conv_taps
+    with jax.named_scope(Scope.MIX_CONV):
+        bcu = tf._linear(h, p["win"])
+        b, c, u = bcu[:, :D], bcu[:, D:2 * D], bcu[:, 2 * D:]
+        v = jnp.pad((b * u).reshape(B, S, D), ((0, 0), (K - 1, 0), (0, 0)))
+        with jax.named_scope(Scope.CONV_SHORT):
+            z = _causal_conv(v, p["conv"]).astype(h.dtype)
+        return tf._linear(c * z.reshape(B * S, D), p["wo"])
 
 
 # -- the latent-attention mixer (MLA; ops/pallas/mla_attention.py reads the pool for the rows) --
@@ -914,11 +950,22 @@ def _head(x, params, cfg):
 # no cache: training and the reference comparison
 # ---------------------------------------------------------------------------
 
-def forward_plan(params, cfg, tokens, return_hidden=False, return_exit=False):
+def _takes_flash(cfg, kind) -> bool:
+    """Does an attention kind's training forward go through the flash
+    kernels (``cfg.attn_impl == "pallas"``)? By what the call can see: the
+    training kernels have no sink logit and one head width for keys and
+    values, so a kind with either keeps the masked einsum."""
+    return cfg.attn_impl == "pallas" and not kind.sink and cfg.head_dim == cfg.v_head_dim
+
+
+def forward_plan(params, cfg, tokens, return_hidden=False, return_exit=False, return_stats=False):
     """tokens (B, S) -> (logits (B, S, V), 0.0): whole sequences, attention
-    by masked einsum, the expert layers' grouped matmul by ``ragged_dot``,
-    which has a gradient. ``return_exit`` (a looped model): a third result,
-    the exit gate's distribution over the passes (B, S, T)."""
+    by the flash kernels under ``attn_impl="pallas"`` (:func:`_takes_flash`)
+    and by masked einsum otherwise, the expert layers' grouped matmul by
+    ``ragged_dot``, which has a gradient. ``return_exit`` (a looped model): a
+    further result, the exit gate's distribution over the passes (B, S, T).
+    ``return_stats``: a further result, the five routing counters of
+    :func:`_ffn` merged over the expert layers as a tick's are ((5,) int32)."""
     tf = _tf()
     dtype = cfg.jnp_dtype
     B, S = tokens.shape
@@ -935,7 +982,14 @@ def forward_plan(params, cfg, tokens, return_hidden=False, return_exit=False):
             return _ssm_plain(h, layer_p["ssm"], cfg, B, S)
         if kind.mixer == "mla":
             return _mla_plain(h, layer_p["mla"], kind, cfg, B, S, positions)
+        if kind.mixer == "conv":
+            return _conv_plain(h, layer_p["conv"], cfg, B, S)
         q, k, v = _project(h, layer_p["attn"], kind, cfg, positions)
+        if _takes_flash(cfg, kind):
+            with jax.named_scope(Scope.ATTN_WINDOW if kind.window else Scope.ATTN_FULL):
+                att = tf._flash_sharded(*(a.reshape(B, S, *a.shape[1:]) for a in (q, k, v)), cfg,
+                                        causal=True, window=kind.window or None)
+            return _attn_out(att.reshape(B * S, -1), h, layer_p["attn"], cfg)
         ok = kpos <= qpos
         if kind.window:
             ok = ok & (qpos - kpos < kind.window)
@@ -947,22 +1001,26 @@ def forward_plan(params, cfg, tokens, return_hidden=False, return_exit=False):
                 ok[None], layer_p["attn"].get("sink"), _scale(cfg))
         return _attn_out(att.reshape(B * S, -1), h, layer_p["attn"], cfg)
 
-    def layer(x, layer_p, kind, _):
+    def layer(carry, layer_p, kind, _):   # carry: (x,), or (x, the counters so far) where they are asked for
+        x = carry[0]
         h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg).reshape(B * S, -1)
         x = _add(x, _post(mix(h, layer_p, kind), layer_p, "ln1_post", cfg).reshape(B, S, -1), cfg)
         h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg).reshape(B * S, -1)
-        out, _ = _ffn(h, layer_p["mlp"], kind, cfg, None, grad=True)
-        return _add(x, _post(out, layer_p, "ln2_post", cfg).reshape(B, S, -1), cfg)
+        out, st = _ffn(h, layer_p["mlp"], kind, cfg, None, grad=True)
+        x = _add(x, _post(out, layer_p, "ln2_post", cfg).reshape(B, S, -1), cfg)
+        return (x, _merge_stats(carry[1], st)) if return_stats else (x,)
 
     if cfg.remat:
         layer = jax.checkpoint(layer, policy=tf._resolve_remat_policy(cfg.remat_policy),
                                static_argnums=(2,))
     layers = tf._cast_layers(params["layers"], dtype)
-    (x,), states = _passes(cfg, params, (x,), lambda c, step: (_walk(cfg, layers, c[0], layer),),
-                           keep_states=return_exit)
+    carry = (x, jnp.zeros((5,), jnp.int32)) if return_stats else (x,)
+    (x, *stats), states = _passes(cfg, params, carry, lambda c, step: _walk(cfg, layers, c, layer),
+                                  keep_states=return_exit)
     if cfg.loop_steps == 1:
         x = tf._norm(x, params["final_norm"]["scale"], None, cfg)
     extra = (exit_pdf(params, states),) if return_exit else ()
+    extra += tuple(stats)
     if return_hidden:
         return (x, jnp.float32(0.0)) + extra
     return (_logits(x, params, cfg), jnp.float32(0.0)) + extra
@@ -1057,6 +1115,7 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
     the layers; with a state pool also the real tokens the chunk's scan
     took and the rows whose state this tick stepped)."""
     tf = _tf()
+    kv_cache.refuse_unserved(cfg)
     dtype = cfg.jnp_dtype
     B = tokens.shape[0]
     length = kv_cache.alloc_len(cfg, cache)

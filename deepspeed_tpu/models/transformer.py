@@ -40,10 +40,13 @@ class LayerKind:
     attention of some shape, the gated delta rule, whose shape is the
     configuration's ``gdn_*``, a state-space scan (Mamba-2), whose shape is
     its ``ssm_*``, or latent attention, whose shape is its ``mla_*``) and the
-    kind of its FFN. Layers of one kind share parameter shapes and are
+    kind of its FFN; a gated short convolution, whose taps are its
+    ``conv_taps``, is a mixer that trains and is not served yet. Layers of one
+    kind share parameter shapes and are
     stacked together (``params["layers"][name]``); attention layers of the
     same reach (``window`` 0 or not) share a KV pool, delta-rule or
-    state-space layers the state pool, latent layers the latent pool."""
+    state-space layers the state pool, latent layers the latent pool; a
+    short-convolution layer has none."""
     name: str
     kv_heads: int = 1  # of an attention mixer
     window: int = 0  # 0 = full causal attention; W = the last W positions
@@ -53,12 +56,16 @@ class LayerKind:
     ffn_size: Optional[int] = None  # None => cfg.ffn_size
     # attention | gdn (Gated DeltaNet: recurrent state, no keys kept) | ssm (Mamba-2: a
     # state-space scan with a scalar decay a head, no keys kept) | mla (latent
-    # attention: one latent and one rotated key a token, shared by every head)
+    # attention: one latent and one rotated key a token, shared by every head) | conv (a
+    # double-gated causal depthwise convolution of cfg.conv_taps taps: no keys, no scan)
     mixer: str = "attention"
 
     @property
-    def pool(self) -> str:
-        """The cache pool this kind's layers live in."""
+    def pool(self) -> Optional[str]:
+        """The cache pool this kind's layers live in; None for a kind that is
+        not served (``kv_cache.refuse_unserved``)."""
+        if self.mixer == "conv":
+            return None
         if self.mixer in ("gdn", "ssm"):
             return "state"
         if self.mixer == "mla":
@@ -178,6 +185,7 @@ class TransformerConfig:
     moe_shared_size: int = 0  # width of a shared SwiGLU expert; 0 = none
     moe_shared_gated: bool = True  # ... behind a sigmoid gate (False: added as it is)
     moe_routed_scale: float = 1.0  # the normalised top-k weights times this (routed_scaling_factor)
+    moe_norm_eps: float = 0.0  # added to the chosen scores' sum before they are divided by it
     # a plan's attention layers: sigmoid(gate) on the attention output (the gate rides wq's
     # projection as a leaf of its own), RMSNorm over each query and key head
     attn_out_gate: bool = False
@@ -200,6 +208,9 @@ class TransformerConfig:
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_conv: int = 4
+    # Gated short convolution mixer (LayerKind.mixer == "conv"): [b | c | u] = h W_in, a causal
+    # depthwise convolution of this many taps over b * u, gated by c, through W_out
+    conv_taps: int = 3
     # a plan's scalar multipliers: the embedding's output, what a mixer or an
     # FFN adds to the residual stream, the logits (attn_scale is above)
     embed_scale: float = 1.0
@@ -1035,8 +1046,11 @@ def _constrain_batch_sharding(x):
 
 
 def forward(params, cfg: TransformerConfig, tokens, dropout_rng=None,
-            ltd_keep_len=None, pld_theta=None, token_types=None, return_hidden=False):
-    """tokens (B, S) int32 -> (logits (B, S, V), moe_aux_loss scalar).
+            ltd_keep_len=None, pld_theta=None, token_types=None, return_hidden=False,
+            return_stats=False):
+    """tokens (B, S) int32 -> (logits (B, S, V), moe_aux_loss scalar); with
+    ``return_stats`` (a layer plan's) a third result, its expert layers'
+    routing counters (``layer_plan.forward_plan``).
 
     ``ltd_keep_len`` (static int) — random-LTD: each participating layer runs
     on that many randomly kept tokens, outputs scattered back (reference
@@ -1048,7 +1062,10 @@ def forward(params, cfg: TransformerConfig, tokens, dropout_rng=None,
     if cfg.layer_kinds is not None:
         from deepspeed_tpu.models.layer_plan import forward_plan
 
-        return forward_plan(params, cfg, tokens, return_hidden=return_hidden)
+        return forward_plan(params, cfg, tokens, return_hidden=return_hidden,
+                            return_stats=return_stats)
+    if return_stats:
+        raise ValueError("routing counters are a layer plan's: the one-kind body keeps none")
     dtype = cfg.jnp_dtype
     B, S = tokens.shape
     with jax.named_scope(Scope.EMBED):
@@ -1588,20 +1605,22 @@ def forward_tick_cached(params, cfg: TransformerConfig, tokens, pos, cache, chun
     return _head_cached(x, params, cfg), cache
 
 
-def loss_fn(params, cfg: TransformerConfig, batch, rng=None, ltd_keep_len=None, pld_theta=None):
+def loss_fn(params, cfg: TransformerConfig, batch, rng=None, ltd_keep_len=None, pld_theta=None,
+            with_counters=False):
     """Next-token cross entropy. batch: {'input_ids': (B,S) int32} and
     optional 'labels' (shifted internally if absent), 'loss_mask', and
-    'token_type_ids' (BERT-family segment ids)."""
+    'token_type_ids' (BERT-family segment ids). ``with_counters`` (a layer
+    plan's): (loss, the forward's routing counters)."""
     tokens = batch["input_ids"]
-    logits, moe_aux = forward(
+    logits, moe_aux, *stats = forward(
         params, cfg, tokens, dropout_rng=rng,
         ltd_keep_len=ltd_keep_len, pld_theta=pld_theta,
-        token_types=batch.get("token_type_ids"),
+        token_types=batch.get("token_type_ids"), return_stats=with_counters,
     )
     ce = _ce_from_logits(logits, batch, tokens)
     if cfg.moe_num_experts > 0:
         ce = ce + cfg.moe_aux_loss_coef * moe_aux
-    return ce
+    return (ce, stats[0]) if with_counters else ce
 
 
 class TransformerModel:
@@ -1617,11 +1636,29 @@ class TransformerModel:
     def init(self, rng):
         return init(rng, self.cfg)
 
-    def loss(self, params, batch, rng=None, ltd_keep_len=None, pld_theta=None):
+    def loss(self, params, batch, rng=None, ltd_keep_len=None, pld_theta=None,
+             with_counters=False):
         return loss_fn(
             params, self.cfg, batch, rng=rng,
-            ltd_keep_len=ltd_keep_len, pld_theta=pld_theta,
+            ltd_keep_len=ltd_keep_len, pld_theta=pld_theta, with_counters=with_counters,
         )
+
+    # what a layer plan's expert layers count in one forward, summed over the layers (the most
+    # one held expert got in a layer: its largest), in ``loss_with_counters``'s order
+    counter_names = ("moe_assignments", "moe_held_assignments", "moe_expert_tokens_most",
+                     "moe_expert_layers", "moe_experts_hit")
+
+    @property
+    def loss_with_counters(self):
+        """The engine's optional protocol (looked up as ``ltd_keep_len`` is):
+        ``loss`` returning ``(loss, counters (len(counter_names),) int32)``,
+        which the engine sums over micro-steps (``engine.moe_stats()``). None
+        for a model without expert layers in a plan: the engine then compiles
+        the micro-step of a model that never had the attribute."""
+        cfg = self.cfg
+        if cfg.layer_kinds is None or not any(k.ffn == "moe" for k in cfg.plan):
+            return None
+        return partial(self.loss, with_counters=True)
 
     def apply(self, params, tokens, rng=None):
         return apply(params, self.cfg, tokens, dropout_rng=rng)

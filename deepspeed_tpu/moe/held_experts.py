@@ -42,13 +42,15 @@ class Layout(NamedTuple):
 
 
 @jax.named_scope(Scope.MOE_ROUTE)
-def route(h, gate_w, gate_bias, k: int, score: str = "sigmoid", scale: float = 1.0):
+def route(h, gate_w, gate_bias, k: int, score: str = "sigmoid", scale: float = 1.0,
+          norm_eps: float = 0.0):
     """h (N, D) -> (chosen experts (N, k) int32, their weights (N, k)
     float32). Scores in float32 whatever the dtype the weights are stored
     in: ``score`` "sigmoid", chosen by score + ``gate_bias``, or "softmax"
     over ALL the experts (``gate_bias`` None: the k largest probabilities);
-    either way the chosen scores are normalised over the chosen k, and then
-    multiplied by ``scale`` (a model's routed scaling factor)."""
+    either way the chosen scores are normalised over the chosen k (their sum
+    plus ``norm_eps``, where a model publishes one), and then multiplied by
+    ``scale`` (a model's routed scaling factor)."""
     logits = jnp.dot(h.astype(jnp.float32), gate_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if score == "softmax":
@@ -58,7 +60,8 @@ def route(h, gate_w, gate_bias, k: int, score: str = "sigmoid", scale: float = 1
         scores = jax.nn.sigmoid(logits)
         _, chosen = jax.lax.top_k(scores + gate_bias.astype(jnp.float32), k)
     picked = jnp.take_along_axis(scores, chosen, axis=1)
-    weights = picked / picked.sum(axis=1, keepdims=True)
+    total = picked.sum(axis=1, keepdims=True)
+    weights = picked / (total + norm_eps if norm_eps else total)
     return chosen.astype(jnp.int32), weights if scale == 1.0 else weights * scale
 
 

@@ -84,8 +84,19 @@ def _is_plan(cfg) -> bool:
     return getattr(cfg, "layer_kinds", None) is not None
 
 
+def refuse_unserved(cfg):
+    """A plan with a short-convolution kind trains and is not served: say so
+    where a cache or a tick is asked of it."""
+    if _is_plan(cfg) and any(k.mixer == "conv" for k in cfg.layer_kinds):
+        raise NotImplementedError(
+            "a layer plan with a short-convolution kind trains (forward_plan) and is not served "
+            "yet: the state pool has no row that is a convolution's tail and nothing else "
+            "(kv_cache.StateSpec, layer_plan.forward_plan_cached)")
+
+
 def state_spec(cfg) -> Optional[StateSpec]:
     """The state pool of ``cfg``'s cache; None where no layer keeps one."""
+    refuse_unserved(cfg)
     kinds = [k for k in cfg.plan if k.pool == "state"] if _is_plan(cfg) else []
     n = len(kinds)
     if not n:
@@ -140,6 +151,7 @@ def specs(cfg) -> Tuple[PoolSpec, ...]:
     if not _is_plan(cfg):
         return (PoolSpec("kv", cfg.num_layers, cfg.kv_heads, cfg.head_dim, cfg.head_dim, None,
                          heads_first=False, int8=cfg.kv_cache_dtype == "int8"),)
+    refuse_unserved(cfg)
     pools = {}
     for kind in cfg.plan:
         if kind.pool == "state":   # no keys, no time axis: state_spec()

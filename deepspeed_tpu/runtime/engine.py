@@ -402,6 +402,14 @@ class TpuEngine:
 
         self.scale_state: LossScaleState = jax.device_put(self.loss_scaler.init(), self.replicated)
 
+        # --- a model that counts beside its loss (``loss_with_counters`` + ``counter_names``, an
+        # optional protocol looked up as ``ltd_keep_len`` is): the micro-step adds its counters
+        # into this accumulator, 64 bits a counter as (low, high) uint32 words; moe_stats() reads
+        self.counter_acc = None
+        if not self.param_offload and getattr(model, "loss_with_counters", None) is not None:
+            self.counter_acc = jax.device_put(
+                jnp.zeros((2, len(model.counter_names)), jnp.uint32), self.replicated)
+
         # --- lr scheduler
         if lr_scheduler is None and config.scheduler is not None:
             lr_scheduler = create_lr_scheduler(config.scheduler, self.base_lr)
@@ -788,11 +796,18 @@ class TpuEngine:
         accepts_pld = "pld_theta" in loss_sig
         use_pld = self.pld is not None and accepts_pld
 
+        if custom_vag is not None:   # a schedule that makes its own gradients counts nothing
+            self.counter_acc = None
+        counted = model.loss_with_counters if self.counter_acc is not None else None
+
         def build_micro(ltd_keep_len=None):
             """Jitted micro-step; ``ltd_keep_len`` is static (it sets shapes),
-            PLD theta rides as a dynamic operand (no re-jit as it decays)."""
+            PLD theta rides as a dynamic operand (no re-jit as it decays). A
+            model that counts beside its loss (``loss_with_counters``) gets a
+            seventh argument and a third result, its counters' accumulator;
+            any other model compiles the program it always did."""
 
-            def micro_fn(params, grad_acc, batch, rng, scale, pld_theta):
+            def micro_fn(params, grad_acc, batch, rng, scale, pld_theta, *counter_acc):
                 if custom_vag is not None:
                     loss, grads = custom_vag(params, batch, rng, scale)
                 else:
@@ -802,21 +817,28 @@ class TpuEngine:
                     if use_pld:
                         kwargs["pld_theta"] = pld_theta
 
-                    def scaled_loss(p):
-                        return model.loss(p, batch, rng, **kwargs).astype(jnp.float32) * scale
+                    def scaled_loss(p):   # (the loss, a counting model's counters or None)
+                        out = (counted or model.loss)(p, batch, rng, **kwargs)
+                        loss, counters = out if counted is not None else (out, None)
+                        return loss.astype(jnp.float32) * scale, counters
 
-                    loss, grads = jax.value_and_grad(scaled_loss)(params)
+                    (loss, counters), grads = jax.value_and_grad(scaled_loss, has_aux=True)(params)
+                    if counted is not None:   # 64 bits a counter: add, and carry where the low word wrapped
+                        low, high = counter_acc[0]
+                        total = low + counters.astype(jnp.uint32)
+                        counter_acc = (jnp.stack([total, high + (total < low).astype(jnp.uint32)]),)
                 with jax.named_scope(Scope.GRAD_ACCUMULATE):
                     new_acc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32) / predivide, grad_acc, grads)
-                return loss / scale, new_acc
+                return (loss / scale, new_acc) + counter_acc
 
+            extra = (None,) if counted is not None else ()   # the counters, replicated, in and out
             fn = jax.jit(
                 micro_fn,
-                donate_argnums=(1,),
+                donate_argnums=(1, 6) if counted is not None else (1,),
                 in_shardings=(
                     self.param_shardings, self.grad_shardings, self.batch_sharding, None, None, None,
-                ),
-                out_shardings=(self.replicated, self.grad_shardings),
+                ) + extra,
+                out_shardings=(self.replicated, self.grad_shardings) + extra,
             )
             # build journal: the first dispatch of each (ltd grid point)
             # micro program leaves an entry (LTD shape churn shows up as
@@ -1239,9 +1261,11 @@ class TpuEngine:
             micro = self._micro_jits[keep_len] = self._micro_builder(keep_len)
         theta = jnp.float32(self.pld.get_theta() if self.pld is not None else 1.0)
         with host_span("train.micro_dispatch"):
-            loss, self.grad_acc = micro(
-                self.params, self.grad_acc, batch, rng, self.scale_state.scale, theta
-            )
+            loss, self.grad_acc, *counters = micro(
+                self.params, self.grad_acc, batch, rng, self.scale_state.scale, theta,
+                *self._counter_args())
+            if counters:
+                self.counter_acc = counters[0]
         self._pending_loss = loss
         self.timers(EngineTimers.FORWARD).stop()
         return loss
@@ -1421,8 +1445,8 @@ class TpuEngine:
         once per engine."""
         if self._micro_cost_cache is None:
             lowered = self._micro_fn.lower(
-                self.params, self.grad_acc, batch, rng, self.scale_state.scale, jnp.float32(1.0)
-            )
+                self.params, self.grad_acc, batch, rng, self.scale_state.scale, jnp.float32(1.0),
+                *self._counter_args())
             compiled = lowered.compile()
             # ds-audit capture: this is the one place the engine already
             # holds the micro program's lowered artifact — feed the
@@ -1453,7 +1477,8 @@ class TpuEngine:
                 lambda t: jax.tree.map(jnp.zeros_like, t), out_shardings=self.grad_shardings
             )(self.grad_acc)
             t0 = time.time()
-            out_loss, _ = compiled(self.params, zeros, batch, rng, self.scale_state.scale, jnp.float32(1.0))
+            out_loss, *_ = compiled(self.params, zeros, batch, rng, self.scale_state.scale,
+                                    jnp.float32(1.0), *self._counter_args(throwaway=True))
             float(out_loss)
             prof.duration = time.time() - t0
             prof.params = count_params(self.params)
@@ -1568,10 +1593,32 @@ class TpuEngine:
         sharded = self._shard_batch(batch)
         rng = jax.random.PRNGKey(rng_seed)
         theta = jnp.float32(self.pld.get_theta() if self.pld is not None else 1.0)
-        _, acc = self._micro_fn(
-            self.params, zeros, sharded, rng, self.scale_state.scale, theta)
+        _, acc, *_ = self._micro_fn(
+            self.params, zeros, sharded, rng, self.scale_state.scale, theta,
+            *self._counter_args(throwaway=True))
         return crc_digest(
             np.asarray(jax.device_get(l)) for l in jax.tree.leaves(acc))
+
+    def _counter_args(self, throwaway=False) -> tuple:
+        """What the micro program takes after its six arguments: nothing, or
+        the counters' accumulator (``throwaway``: zeros in its place, for a
+        run out of band, since the program donates it)."""
+        if self.counter_acc is None:
+            return ()
+        return (jnp.zeros_like(self.counter_acc),) if throwaway else (self.counter_acc,)
+
+    def moe_stats(self) -> dict:
+        """{name: total since the engine was built} of the counters a model
+        returns beside its loss (``model.counter_names``; a layer plan's
+        expert layers: assignments made, assignments to held experts, the
+        most one held expert got in a layer summed over micro-steps, expert
+        layers run, held experts hit). One host fetch, which waits for the
+        micro-steps in flight; {} for a model that counts nothing."""
+        if self.counter_acc is None:
+            return {}
+        low, high = np.asarray(jax.device_get(self.counter_acc)).astype(np.uint64)
+        return {name: int(h) << 32 | int(l)
+                for name, l, h in zip(self.model.counter_names, low, high)}
 
     def zero_optimization(self) -> bool:
         return self.zero_stage > 0
@@ -1652,6 +1699,7 @@ class TpuEngine:
         }
         if self._pending_loss is not None:
             event["loss"] = float(self._pending_loss)
+        event.update(self.moe_stats())  # a counting model's totals so far; nothing otherwise
         if self._tele_tokens_per_micro:
             tokens = self._tele_tokens_per_micro * self.gradient_accumulation_steps
             event["tokens_per_sec"] = tokens / iter_s if iter_s > 0 else 0.0

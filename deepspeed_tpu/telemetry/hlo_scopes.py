@@ -52,6 +52,8 @@ class Scope:
     SSM_CONV = "ssm.conv"        # ... its causal depthwise convolution and bias
     SSM_SCAN = "ssm.scan"        # ... one prefill chunk's scan (the ssd_chunk_fwd kernel)
     SSM_STEP = "ssm.step"        # ... the rows' one-token state step (the ssd_step kernel)
+    MIX_CONV = "mix.conv"        # a gated short-convolution mixer: the in-projection, the two gates, the out-projection
+    CONV_SHORT = "conv.short"    # ... its causal depthwise taps
     MIX_MLA = "mix.mla"          # a latent-attention mixer: everything between its two norms
     MLA_Q = "mla.q"              # ... the queries: down, norm, up, the rotary part turned
     MLA_LATENT = "mla.latent"    # ... the latent and the shared rotated key of each token

@@ -84,12 +84,12 @@ def train_programs(cell, config, chips):
     import jax.numpy as jnp
 
     import deepspeed_tpu
-    from benchmark import models
+    from benchmark import compare
     from benchmark.runners.train import Runner
 
     t = cell["train"]
-    model = models.build_model(config, max_seq_len=t["seq"], remat=t["remat"],
-                               attn_impl=t["attn_impl"])
+    model = compare.builder_of(config).build_model(config, max_seq_len=t["seq"], remat=t["remat"],
+                                                   attn_impl=t["attn_impl"])
     ds_config = Runner(dict(cell=cell, config=config))._ds_config(chips, 0)
     engine = deepspeed_tpu.initialize(model=model, config=ds_config)[0]
     sds = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
@@ -97,7 +97,8 @@ def train_programs(cell, config, chips):
         (t["micro_batch_per_chip"] * chips, t["seq"]), jnp.int32)}
     scalar = jax.ShapeDtypeStruct((), jnp.float32)
     micro = engine._micro_fn.lower(sds(engine.params), sds(engine.grad_acc), batch,
-                                   sds(engine._next_rng()), scalar, scalar).compile()
+                                   sds(engine._next_rng()), scalar, scalar,
+                                   *sds(engine._counter_args())).compile()
     apply = engine._apply_fn.lower(sds(engine.params), sds(engine.master_params),
                                    sds(engine.opt_state), sds(engine.grad_acc),
                                    sds(engine.scale_state), scalar).compile()
@@ -132,7 +133,8 @@ def main(argv=None):
             line = json.loads([ln for ln in fh.read().splitlines() if ln.strip()][-1])
         ops += [name for name, _ in line["breakdown"]["device_ops"]]
     cell, config, chips = _files(args.manifest, args.cell)
-    programs = (train_programs if cell["runner"] == "train" else serve_programs)(
+    # a training runner's group is "train" whatever the runner is called (train, train_routed)
+    programs = (train_programs if "train" in cell else serve_programs)(
         cell, config, chips)
     tables = {variant: scope_table(c) for variant, c in programs.items()}
 
