@@ -407,7 +407,7 @@ def _ffn(h, mlp_p, kind, cfg, valid, grad):
                                cfg.moe_score, scale=cfg.moe_routed_scale, norm_eps=cfg.moe_norm_eps)
     out, counts = he.held_experts_ffn(
         h, chosen, weights, {n: mlp_p[n] for n in _EXPERT_LEAVES}, first, count,
-        grad=grad, valid=valid, layer=mlp_p.get("layer"))
+        grad=grad, valid=valid, layer=mlp_p.get("layer"), n_experts=cfg.moe_num_experts)
     if cfg.moe_shared_size:  # every chip computes it alike, whatever was routed where
         with jax.named_scope(Scope.MOE_SHARED):
             act = (jax.nn.silu(tf._linear(h, mlp_p["shared_wg"]))

@@ -13,18 +13,18 @@ experts ``[first, first + count)`` and computes their part of the result:
 
 What the absent experts would add is left out (the chips that hold them add
 it, after an exchange this layer does not make on one chip). Nothing has a
-capacity and no token is dropped: the assignments to held experts are
-sorted by expert into a buffer sized for the worst routing (every token
-choosing as many held experts as it can), each expert's rows padded to whole
-row tiles, and one grouped matmul runs over the experts that got rows: the
-Pallas kernel of ``ops/pallas/grouped_matmul.py``, or, where the caller needs
-a gradient (``grad=True``: the training forward), ``jax.lax.ragged_dot``,
-which has one. The kernel has none; it reads a layer straight out of the
-stacked experts and skips the tiles no expert got, and in the serving cell
-of PR 27 a tick with it takes 21.0 ms where ``ragged_dot`` takes 32.6
-(PERF.md section 6, PR 27b).
+capacity and no token is dropped: the assignments to held experts are sorted
+by expert into a buffer, each expert's rows padded to whole row tiles, and
+one grouped matmul runs over the experts that got rows. A tick (``grad=False``)
+sizes the buffer for the worst routing (every token choosing as many held
+experts as it can) and takes the Pallas kernel of ``ops/pallas/grouped_matmul.py``,
+which reads a layer straight out of the stack and skips the tiles no expert got
+(21.0 ms a tick where ``ragged_dot`` takes 32.6: PERF.md section 6, PR 27b) but
+has no gradient. Training (``grad=True``) takes ``jax.lax.ragged_dot``, over a
+bucket of twice the even share, the worst-case buffer when the routing overflows it.
 """
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -111,21 +111,21 @@ def _matmul(x, w, lay: Layout, tm: int, grad: bool, layer):
     return jax.lax.ragged_dot(x, w if layer is None else w[layer], sizes.astype(jnp.int32))
 
 
-def held_experts_ffn(h, chosen, weights, experts, first: int, count: int, *,
-                     grad: bool = False, valid=None, tm: Optional[int] = None, layer=None):
-    """The held experts' part of the layer's output, (N, D), and the tokens
-    each held expert got, (count,). h (N, D); chosen/weights (N, k) from
-    :func:`route`; experts {"wg", "wi": (count, D, F), "wo": (count, F, D)},
-    SwiGLU: Expert(h) = (silu(h wg) * (h wi)) wo. With ``layer`` (a traced
-    scalar) the experts are a stack (L, count, ...) and layer ``layer`` of
-    it is used, read in place by the kernel. ``grad``: the caller will
-    differentiate this (see the module's docstring)."""
+def held_experts_ffn(h, chosen, weights, experts, first: int, count: int, *, grad: bool = False,
+                     valid=None, tm: Optional[int] = None, layer=None, n_experts: int = 0):
+    """The held experts' part of the layer's output, (N, D), and the tokens each
+    held expert got, (count,). h (N, D); chosen/weights (N, k) from :func:`route`;
+    experts {"wg", "wi": (count, D, F), "wo": (count, F, D)}, SwiGLU; with ``layer``
+    (a traced scalar) a stack (L, count, ...) whose layer ``layer`` the kernel reads in
+    place. ``grad``: the caller will differentiate this (``n_experts``: the router's)."""
     N, D = h.shape
     k = chosen.shape[1]
     if tm is None:  # whole MXU tiles for a prefill chunk, the sublane tile for decode rows
         tm = 128 if N * k >= 2048 else 16
     lay = layout(chosen, first, count, tm, valid)
     M = lay.src.shape[0]
+    if grad:  # the serving path below stays as it is, line for line: its kernels' payloads carry them
+        return _trained_ffn(h, chosen, weights, experts, lay, first, tm, layer, n_experts)
     with jax.named_scope(Scope.MOE_EXPERTS):
         x = jnp.take(jnp.concatenate([h, jnp.zeros((1, D), h.dtype)]), lay.src, axis=0)
         act = (jax.nn.silu(_matmul(x, experts["wg"], lay, tm, grad, layer))
@@ -135,3 +135,89 @@ def held_experts_ffn(h, chosen, weights, experts, first: int, count: int, *,
         y = jnp.take(y, jnp.minimum(lay.dest, M - 1).reshape(-1), axis=0).reshape(N, k, D)
         out = jnp.where(mine, y.astype(jnp.float32) * weights[:, :, None], 0.0).sum(axis=1)
     return out.astype(h.dtype), lay.counts
+
+
+def bucket_rows(n_tokens: int, k: int, count: int, n_experts: int, tm: int) -> int:
+    """Rows of the bucket the training path tries first: twice the held
+    experts' even share of the assignments plus each expert's padding."""
+    even = -(-n_tokens * k * count // n_experts)
+    return -(-(2 * even + count * (tm - 1)) // tm) * tm
+
+
+def _rows_ffn(rows: int, first: int, tm: int, h, chosen, weights, experts, lay: Layout):
+    """The layer over the sorted buffer's first ``rows`` rows, which hold every
+    assignment whenever ``lay.num_tiles * tm <= rows`` (each expert's padded
+    rows are packed from row 0). The way back is by rows too: a row adds its
+    result, times its weight, to its token, so nothing here is sized by the
+    N * k assignments, most of them not held."""
+    N, D = h.shape
+    k = chosen.shape[1]
+    src = lay.src[:rows]
+    x = jnp.take(jnp.concatenate([h, jnp.zeros((1, D), h.dtype)]), src, axis=0)
+    act = (jax.nn.silu(_matmul(x, experts["wg"], lay, tm, True, None))
+           * _matmul(x, experts["wi"], lay, tm, True, None))
+    y = _matmul(act, experts["wo"], lay, tm, True, None).astype(jnp.float32)
+    # a row's weight: its token's weight for the expert whose tile the row lies in
+    expert = first + jnp.repeat(lay.tile_group[:rows // tm], tm)
+    theirs = jnp.take(jnp.concatenate([chosen, jnp.full((1, k), -1, chosen.dtype)]), src, axis=0)
+    w = jnp.take(jnp.concatenate([weights, jnp.zeros((1, k), weights.dtype)]), src, axis=0)
+    w = jnp.where(theirs == expert[:, None], w, 0.0).sum(axis=1)
+    y = jnp.where((src < N)[:, None], y * w[:, None], 0.0)     # rows of unused tiles hold anything
+    return jnp.zeros((N + 1, D), jnp.float32).at[src].add(y)[:N].astype(h.dtype)
+
+
+def _choice(bucket: int, first: int, tm: int, lay: Layout):
+    """(the routing's padded rows end inside the bucket, the layer over the
+    bucket, the layer over the whole buffer): ``jax.lax.cond``'s first three."""
+    def whole(h, chosen, weights, experts, lay):
+        with jax.named_scope(Scope.MOE_EXPERTS_WHOLE):
+            return _rows_ffn(lay.src.shape[0], first, tm, h, chosen, weights, experts, lay)
+
+    return lay.num_tiles[0] * tm <= bucket, functools.partial(_rows_ffn, bucket, first, tm), whole
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _bucketed_ffn(bucket: int, first: int, tm: int, h, chosen, weights, experts, lay: Layout):
+    """:func:`_rows_ffn` over the first ``bucket`` rows where the routing's
+    padded rows end inside them, over the whole buffer where they do not:
+    both compiled, one chosen on the device from the counts, each call."""
+    return jax.lax.cond(*_choice(bucket, first, tm, lay), h, chosen, weights, experts, lay)
+
+
+def _bucketed_fwd(bucket, first, tm, *operands):
+    return _bucketed_ffn(bucket, first, tm, *operands), operands
+
+
+def _bucketed_bwd(bucket, first, tm, operands, g):
+    """One branch around the chosen body's own backward. A differentiated
+    ``cond`` has each branch return both branches' residuals, the other's as
+    zeros of its shapes: on the chip 4.5 ms and 3.3 GB a layer more than this
+    (PERF.md section 6, PR 49). Under a checkpoint the replayed forward is
+    dead code, so the products run as often as they did."""
+    h, chosen, weights, experts, lay = operands
+    fits, *bodies = _choice(bucket, first, tm, lay)
+
+    def back(body):
+        return lambda h, weights, experts, g: jax.vjp(
+            lambda h, weights, experts: body(h, chosen, weights, experts, lay), h, weights, experts)[1](g)
+
+    dh, dw, de = jax.lax.cond(fits, *map(back, bodies), h, weights, experts, g)
+    return dh, None, dw, de, None
+
+
+_bucketed_ffn.defvjp(_bucketed_fwd, _bucketed_bwd)
+
+
+def _trained_ffn(h, chosen, weights, experts, lay: Layout, first: int, tm: int, layer, n_experts: int):
+    """``held_experts_ffn`` for a caller that differentiates it: the layer over
+    a bucket of the sorted buffer (:func:`bucket_rows`), over the whole buffer
+    only when the routing overflows the bucket, or when the bucket is no
+    smaller (half the experts held, or ``n_experts`` not said: a model held
+    whole). Nothing has a capacity and no token is dropped either way."""
+    if layer is not None:
+        experts = {n: w[layer] for n, w in experts.items()}
+    count, M = lay.counts.shape[0], lay.src.shape[0]
+    bucket = bucket_rows(h.shape[0], chosen.shape[1], count, n_experts or count, tm)
+    with jax.named_scope(Scope.MOE_EXPERTS):
+        body = functools.partial(_rows_ffn, M) if bucket >= M else functools.partial(_bucketed_ffn, bucket)
+        return body(first, tm, h, chosen, weights, experts, lay), lay.counts

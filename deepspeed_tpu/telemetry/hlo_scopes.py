@@ -42,6 +42,7 @@ class Scope:
     ATTN_WINDOW = "attn.window"  # ... and its sliding-window core
     MOE_ROUTE = "moe.route"      # router scores, top-k, the sort by expert
     MOE_EXPERTS = "moe.experts"  # gather, grouped matmuls over the held experts, combine
+    MOE_EXPERTS_WHOLE = "moe.experts.whole"  # ... a training layer whose routing overflowed its bucket
     MOE_SHARED = "moe.shared"    # the shared expert (and its sigmoid gate, where it has one)
     ATTN_GATE = "attn.gate"      # the sigmoid gate on a plan's attention output
     MIX_GDN = "mix.gdn"          # a gated-delta-rule mixer: projections, gates, output norm

@@ -3,6 +3,8 @@ routing with a selection bias, no capacity and no dropped token, the shares
 of an expert-parallel deployment adding up to the whole layer, and the
 grouped matmul (Pallas, in interpret mode here) against plain matmuls."""
 
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -164,3 +166,110 @@ def test_a_routed_scaling_factor_multiplies_the_normalised_weights_and_nothing_e
     text = lambda **kw: jax.jit(lambda h, g: he.route(h, g, bias, K, score, **kw)).lower(
         layer["h"], layer["gate"]).as_text()
     assert text() == text(scale=1.0) != text(scale=1.8)
+
+
+# ---- the training path: a bucket of the sorted buffer, the whole buffer as the fallback ----------
+
+BN, BK, BFIRST, BCOUNT, BTM = 64, 2, 4, 2, 8   # bucket 48 rows (six tiles), whole buffer 144
+
+
+def routed(c0, c1):
+    """(chosen, weights): the first ``c0`` tokens take held expert 4, the next ``c1`` held expert
+    5, each beside an expert that is not held; every other token takes two that are not."""
+    rs = np.random.RandomState(c0 * 100 + c1)
+    chosen = np.stack([rs.randint(6, E, BN), rs.randint(0, 4, BN)], axis=1)
+    chosen[:c0, 0], chosen[c0:c0 + c1, 1] = 4, 5
+    weights = rs.rand(BN, BK) + 0.1
+    return jnp.asarray(chosen, jnp.int32), jnp.asarray(weights / weights.sum(1, keepdims=True), jnp.float32)
+
+
+@pytest.mark.parametrize("c0,c1,masked,tiles", [
+    (10, 9, False, 4), (24, 24, False, 6), (25, 24, False, 7), (BN, 0, False, 8), (30, 30, True, 6)],
+    ids=["fits", "fills-the-bucket", "one-tile-past", "all-to-one-expert", "valid-mask"])
+def test_the_bucket_and_the_whole_buffer_are_one_layer(layer, c0, c1, masked, tiles):
+    """``grad=True`` over the bucket, or over the whole buffer when the routing's padded rows end
+    past it, against the whole-buffer body and against every held expert on every token: the
+    output, the gradients of ``h`` and of the routing weights, and the experts' own."""
+    assert he.bucket_rows(BN, BK, BCOUNT, E, BTM) == 48 < he.buffer_rows(BN, BK, BCOUNT, BTM) == 144
+    h, ex = layer["h"][:1].repeat(BN, 0) * jnp.linspace(0.5, 1.5, BN)[:, None], share(layer, BFIRST, BCOUNT)
+    chosen, weights = routed(c0, c1)
+    valid = (jnp.arange(BN) % 4 != 1) if masked else None
+    lay = he.layout(chosen, BFIRST, BCOUNT, BTM, valid)
+    assert int(lay.num_tiles[0]) == tiles            # six tiles is what the bucket holds
+    probe = jnp.asarray(np.random.RandomState(3).randn(BN, D), jnp.float32)
+
+    def by_bucket(h, weights, ex):
+        return he.held_experts_ffn(h, chosen, weights, ex, BFIRST, BCOUNT, grad=True, valid=valid,
+                                   tm=BTM, n_experts=E)[0]
+
+    def by_whole_buffer(h, weights, ex):
+        return he._rows_ffn(lay.src.shape[0], BFIRST, BTM, h, chosen, weights, ex, lay)
+
+    def by_every_expert(h, weights, ex):
+        out = jnp.zeros((BN, D))
+        for e in range(BCOUNT):
+            y = (jax.nn.silu(h @ ex["wg"][e]) * (h @ ex["wi"][e])) @ ex["wo"][e]
+            out += y * jnp.where(chosen == BFIRST + e, weights, 0).sum(1)[:, None]
+        return out if valid is None else out * valid[:, None]
+
+    run = lambda fn: jax.jit(jax.value_and_grad(
+        lambda *a: (fn(*a) * probe).sum(), argnums=(0, 1, 2)))(h, weights, ex)
+    got, whole, dense_ = run(jax.checkpoint(by_bucket)), run(by_whole_buffer), run(by_every_expert)
+    assert "stablehlo.case" in jax.jit(by_bucket).lower(h, weights, ex).as_text()
+    gap = lambda a, b: float(jnp.abs(a - b).max() / jnp.abs(b).max())
+    # float32's last digit against the whole buffer (the products' row counts differ, nothing else)
+    assert max(map(gap, jax.tree.leaves(got), jax.tree.leaves(whole))) <= 1e-6
+    assert max(map(gap, jax.tree.leaves(got), jax.tree.leaves(dense_))) <= 1e-5
+    out, counts = he.held_experts_ffn(h, chosen, weights, ex, BFIRST, BCOUNT, grad=True, valid=valid,
+                                      tm=BTM, n_experts=E)
+    held = np.asarray(chosen)[np.ones(BN, bool) if valid is None else np.asarray(valid)]
+    assert list(np.asarray(counts)) == [(held == 4).sum(), (held == 5).sum()]   # no token dropped
+    assert np.allclose(out, by_every_expert(h, weights, ex), atol=2e-5)
+
+
+def test_a_bucket_no_smaller_than_the_buffer_is_one_branch_and_a_tick_is_the_program_it_was(layer):
+    chosen, weights = he.route(layer["h"], layer["gate"], layer["bias"], K)
+    assert he.bucket_rows(N, K, 4, E, 8) == 104 and he.bucket_rows(N, K, 8, E, 8) >= he.buffer_rows(N, K, 8, 8)
+    text = lambda count, **kw: jax.jit(lambda h, w, ex: he.held_experts_ffn(
+        h, chosen, w, ex, 4, count, tm=8, **kw)).lower(layer["h"], weights, share(layer, 4, count)).as_text()
+    assert "stablehlo.case" in text(4, grad=True, n_experts=E)           # 104 of 176 rows: two branches
+    assert "stablehlo.case" not in text(8, grad=True, n_experts=E)       # half the experts held: one
+    assert "stablehlo.case" not in text(4, grad=True)                    # a model held whole: one
+    assert text(4) == text(4, n_experts=E)
+
+    assert hashlib.sha256(tick_text().encode()).hexdigest() == PARENTS_TICK
+
+
+# sha256 of the serving form's lowered text (a layer of a stack read by the kernel, a ``valid``
+# mask), recorded on the parent of the PR that brought the bucket (0c30f23): a tick's layer is that
+# program still, to the text
+PARENTS_TICK = "cda949af33ed8e4cdbe5e06667a44be5c5007f27f340e718481f5e2593f5c902"
+
+
+def tick_text():
+    def tick(h, gate, bias, valid, ex):
+        picked, w = he.route(h, gate, bias, K)
+        return he.held_experts_ffn(h, picked, w, ex, 4, 4, valid=valid, tm=8, layer=jnp.int32(1))
+
+    f = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    stack = {"wg": f(2, 4, D, F), "wi": f(2, 4, D, F), "wo": f(2, 4, F, D)}
+    return jax.jit(tick).lower(f(N, D), f(D, E), f(E), jax.ShapeDtypeStruct((N,), jnp.bool_),
+                               stack).as_text()
+
+
+def test_the_fallback_runs_under_a_scope_of_its_own_forward_and_backward(layer):
+    """A traced run's scope table tells the branches apart: what the whole buffer runs, in the
+    forward and in the backward, reads ``moe.experts.whole``; the bucket's reads ``moe.experts``."""
+    from deepspeed_tpu.telemetry.hlo_scopes import Scope, model_scope, scope_table
+
+    chosen, weights = routed(10, 9)
+    h = jnp.ones((BN, D), jnp.float32)
+    loss = lambda h, w, ex: he.held_experts_ffn(h, chosen, w, ex, BFIRST, BCOUNT, grad=True, tm=BTM,
+                                                n_experts=E)[0].sum()
+    compiled = jax.jit(jax.value_and_grad(jax.checkpoint(loss), argnums=(0, 1, 2))).lower(
+        h, weights, share(layer, BFIRST, BCOUNT)).compile()
+    paths = set(scope_table(compiled).values())
+    of = lambda branch: {model_scope(p) for p in paths if f"/cond/{branch}/" in p}
+    assert of("branch_0_fun") == {Scope.MOE_EXPERTS_WHOLE}      # predicate false: the whole buffer
+    assert of("branch_1_fun") == {Scope.MOE_EXPERTS}
+    assert any("transpose" in p for p in paths if Scope.MOE_EXPERTS_WHOLE in p)

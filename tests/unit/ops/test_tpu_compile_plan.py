@@ -175,6 +175,56 @@ def test_held_experts_layer_compiles_at_the_published_widths(topo, tokens):
     assert compiled.memory_analysis().temp_size_in_bytes < 200e6
 
 
+def test_the_training_expert_layer_compiles_a_bucket_and_the_whole_buffer(topo):
+    """The LFM2 training cell's expert layer (16,384 tokens, top-4 of 64 with 8 held, 2,048 x
+    1,536), forward and gradient under a checkpoint: one conditional with two branches each way,
+    and in the bucket's branch nothing sized by the worst-case buffer (66,560 rows) or by the
+    65,536 assignments."""
+    from deepspeed_tpu.moe import held_experts as he
+
+    N, D, F, E, k, count = 16384, 2048, 1536, 64, 4, 8
+    assert (he.bucket_rows(N, k, count, E, 128), he.buffer_rows(N, k, count, 128)) == (17408, 66560)
+
+    def layer(x, gate, bias, wg, wi, wo):
+        def body(x, gate, wg, wi, wo):
+            chosen, weights = he.route(x, gate, bias, k)
+            return x + he.held_experts_ffn(x, chosen, weights, {"wg": wg, "wi": wi, "wo": wo}, 0, count,
+                                           grad=True, n_experts=E)[0]
+
+        loss = lambda *a: jax.checkpoint(body)(*a).astype(jnp.float32).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(x, gate, wg, wi, wo)
+
+    bf = jnp.bfloat16
+    sh = SingleDeviceSharding(topo.devices[0])
+    shapes = [(N, D), (D, E), (E,), (count, D, F), (count, D, F), (count, F, D)]
+    compiled = jax.jit(layer).lower(*[jax.ShapeDtypeStruct(s, bf, sharding=sh) for s in shapes]).compile()
+    text = compiled.as_text()
+    conds = re.findall(r" conditional\(.*branch_computations=\{%?([\w.\-]+), %?([\w.\-]+)\}", text)
+    assert len(conds) == 2, conds     # the forward's and the backward's; the checkpoint's replay is dead
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"^%?([\w.\-]+) \([^\n]*\) -> [^\n]*\{\n(.*?)^\}", text, re.S | re.M)}
+
+    def reach(name, seen):
+        if name in seen or name not in bodies:
+            return seen
+        seen.add(name)
+        for callee in re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", bodies[name]):
+            reach(callee, seen)
+        return seen
+
+    products = 0
+    for whole, bucket in conds:       # branch 0 is the predicate false: the whole buffer
+        in_bucket = "".join(bodies[c] for c in reach(bucket, set()))
+        in_whole = "".join(bodies[c] for c in reach(whole, set()))
+        assert "[17408," in in_bucket and "[66560," in in_whole
+        assert not re.search(r"\[(?:66560|65536),", in_bucket), re.findall(r".*\[(?:66560|65536),.*", in_bucket)[:3]
+        products += len(re.findall(r"custom-call\(.*ragged-dot", in_bucket))
+    assert products == 15             # the parent's whole-buffer layer makes 15 too (4 forward, 11 back)
+    mem = compiled.memory_analysis()
+    print("temp_size_in_bytes", mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes < 2.3e9, mem.temp_size_in_bytes    # 2.02 GB (the parent's layer: 2.51)
+
+
 @pytest.mark.parametrize("read_len,chunk", [(None, None), (1024, None), (None, 1024), (2048, 256)],
                          ids=["plain", "plain-read1024", "fused1024", "fused256-read2048"])
 def test_mimo_tick_updates_both_pools_in_place(topo, read_len, chunk):
