@@ -11,12 +11,12 @@ SURVEY.md §2.4 #5/#6). Classic FlashAttention-2 scheme:
     and dk/dv (q tiles inner, from the diagonal on) — with f32
     accumulators, GQA head-groups reduced outside.
 
-A GRID step is large and a TILE small (``_Walk``): the step holds a block of
-rows of the outer axis and, where a head's other operands fit VMEM, their
-whole sequence; a loop inside the step walks the score matrix in tiles
-whose float32 intermediates fit the vector registers, and visits only tiles
-that hold an unmasked pair — building a mask only where a mask's edge runs
-through the tile.
+A GRID step is large and a TILE small (``_Walk``): the step fetches a block of
+rows of the outer axis and a MAJOR block of the inner one — a head's whole
+sequences where both are short, else up to ``_MAJOR`` rows — and walks that pair
+of blocks in tiles whose float32 intermediates fit the vector registers: only
+tiles that hold an unmasked pair, a mask built only where a mask's edge runs
+through the tile; what the grid fetches and what the MXU works on are two sizes.
 
 Layout: public API is (B, S, H, hd) (matching models/transformer.py);
 kernels run (B, H, S, hd). On CPU backends the kernels run in Pallas
@@ -39,14 +39,10 @@ from deepspeed_tpu.ops.pallas.interpret import resolve_interpret
 NEG_INF = -1e30
 
 
-def _blk(size: int, cap: int) -> int:
-    return min(cap, size)
-
-
-# Default cap of a GRID block along an axis that is cut, chosen on silicon
-# (v5e, GPT-2 125M shapes, 2026-07-31): 128-row grid steps lose to 512-row
-# ones by 2x in per-step grid/DMA overhead. That says nothing of the TILE a
-# step computes at a time, which is ``_TILE`` (see ``_Walk``; PERF.md §6 PR 41).
+# Default cap of a GRID block where a caller pins one axis or a length has no longer divisor, chosen on silicon
+# (v5e, GPT-2 125M shapes, 2026-07-31): 128-row grid steps lose to 512-row ones by 2x in per-step grid/DMA
+# overhead. A head too long for one grid step takes longer blocks still (``_MAJOR``), and none of this is the
+# TILE a step computes at a time (see ``_Walk``; PERF.md §6 PRs 41 and 50).
 _DEFAULT_BLOCK = 512
 
 
@@ -63,7 +59,7 @@ def _auto_block(size: int, cap: Optional[int]) -> int:
     the sequence exactly). Longer sequences that tile by none of those get
     a loud error instead of a degenerate grid."""
     if cap is not None:
-        return _blk(size, cap)
+        return min(cap, size)
     cap = _DEFAULT_BLOCK
     if size <= cap:
         return size
@@ -86,7 +82,7 @@ _TILE = 128  # rows of an outer tile, and the inner tile where a mask's edge cro
 # §6 PR 41): wider tiles amortise dq's per-row work and cost the other two more than they save
 # (ms a call at 8 x 16 heads, 128 / 256 wide: forward 0.356 / 0.430, dq 0.438 / 0.403, dkv 0.479 / 0.528)
 _WIDE = {"fwd": 128, "dq": 256, "dkv": 128}
-_WHOLE = 2048, 512 << 10  # both axes this short (rows, bytes a head) and in tiles: ONE grid step a head
+_WHOLE = 2048, 512 << 10  # both axes this short (rows; bytes a head, a longer head's blocks' cap too): ONE step a head
 
 
 def _in_tiles(size):
@@ -126,18 +122,18 @@ def _visited(o0, to, ti, n, lo, hi):
 class _Walk:
     """One kernel's walk of the (Sq, Sk) score matrix. The OUTER axis (q for
     the forward and dq kernels, k for dkv) is cut into grid blocks of ``bo``
-    rows and the inner into blocks of ``bi``; the grid's last axis steps
-    through the inner blocks that hold an unmasked pair with the outer block,
-    and no others. A step takes its outer block ``to`` rows at a time and
-    walks the inner block in tiles: ``tw`` wide where no position is masked,
-    ``ti`` where a mask's edge crosses (a mask is built there, and only
-    there), none where every position is masked. What a pair of blocks holds
-    depends only on their offset ``outer - inner``, which takes few values:
-    each is a piece of straight-line code with constant masks, chosen by
-    ``pl.when``. ``tiled``: a head is one grid step and its tiles are small
-    (``_TILE``), the step's state in registers; else a pair of blocks is one
-    tile, the state in VMEM scratch from step to step. Everything follows
-    from the shape, ``causal`` and ``window``."""
+    rows and the inner into (major) blocks of ``bi``; the grid's last axis
+    steps through the inner blocks that hold an unmasked pair with the outer
+    block, and no others. A step takes its outer block ``to`` rows at a time
+    and walks the inner block in tiles: ``tw`` wide where no position is
+    masked, ``ti`` where a mask's edge crosses (a mask is built there, and
+    only there), none where every position is masked. What a pair of blocks
+    holds depends only on their offset ``outer - inner``, which takes few
+    values: each is a piece of straight-line code with constant masks, chosen
+    by ``pl.when``. Blocks that are whole ``_TILE``s are walked in tiles
+    (``in_tiles``), any other pair is ONE tile. ``tiled`` says where the STATE
+    lives: a head is one grid step and a row tile's state never leaves the
+    registers; else it crosses VMEM scratch once a (row tile, grid step)."""
     so: int
     si: int
     bo: int
@@ -148,22 +144,21 @@ class _Walk:
     lo: Optional[int]  # a pair is unmasked iff lo <= outer - inner <= hi
     hi: Optional[int]
     tiled: bool
+    in_tiles: bool = True  # else a pair of blocks is one tile
 
     @classmethod
     def of(cls, sq, sk, bq, bk, causal, window, kernel="fwd"):
         tiled = (bq, bk) == (sq, sk) and _in_tiles(sq) and _in_tiles(sk)
-        lo, hi = (0 if causal else None), (None if window is None else window - 1)
-        so, si, bo, bi = (sq, sk, bq, bk)
+        so, si, bo, bi, lo, hi = sq, sk, bq, bk, (0 if causal else None), (None if window is None else window - 1)
         if kernel == "dkv":
             so, si, bo, bi, lo, hi = sk, sq, bk, bq, (None if hi is None else -hi), (None if lo is None else -lo)
-        if not tiled:
-            return cls(so, si, bo, bi, bo, bi, bi, lo, hi, False)
-        wide = _WIDE[kernel]
-        return cls(so, si, bo, bi, _TILE, _TILE, wide if bi % wide == 0 else _TILE, lo, hi, True)
+        if bo % _TILE or bi % _TILE:  # e.g. a caller's 64-blocks, or one short block of 192 rows
+            return cls(so, si, bo, bi, bo, bi, bi, lo, hi, False, False)
+        rows, wide = (_TILE, _WIDE[kernel]) if tiled else _MAJOR[kernel][2:]
+        to, tw = (rows if bo % rows == 0 else _TILE), (wide if bi % wide == 0 else _TILE)
+        return cls(so, si, bo, bi, to, _TILE, tw, lo, hi, tiled)
 
-    @property
-    def n_outer(self):
-        return self.so // self.bo
+    n_outer = property(lambda self: self.so // self.bo)
 
     def _blocks(self, ob):
         return _visited(ob * self.bo, self.bo, self.bi, self.si // self.bi, self.lo, self.hi)
@@ -232,14 +227,16 @@ class _Walk:
         return out
 
 
-def _grid_blocks(sq, sk, row_bytes, block_q, block_k):
+def _grid_blocks(sq, sk, row_bytes, block_q, block_k, kernel="fwd"):
     """(bq, bk) of a kernel's grid: an explicit block is the caller's; else a head's whole
     sequences where both are short (``_WHOLE``: VMEM holds six of them twice in the dkv kernel)
     and in tiles, so that a head is ONE grid step of straight-line code; else ``_auto_block``
-    on both, and the grid steps through the blocks that hold unmasked pairs."""
+    and longer, the kernel's outer axis and its inner (major) one each by ``_MAJOR``, and the
+    grid steps through the pairs of blocks that hold unmasked pairs."""
     whole = all(_in_tiles(s) and s * row_bytes <= _WHOLE[1] for s in (sq, sk))
-    return tuple(s if whole and cap is None else _auto_block(s, cap)
-                 for s, cap in ((sq, block_q), (sk, block_k)))
+    if whole or block_q is not None or block_k is not None:
+        return tuple(s if whole and cap is None else _auto_block(s, cap) for s, cap in ((sq, block_q), (sk, block_k)))
+    return _major_blocks(sq, sk, row_bytes, kernel)
 
 
 def _mask(d0, shape, outer_axis, lo, hi):
@@ -280,12 +277,32 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, sm_scale, walk):
     """Online softmax over the tiles ``walk`` lists for this step: float32 scores, statistics
     and accumulator, a float32 ``exp``, bfloat16 (the input dtype) into the MXU; ``lse_ref``
-    takes rows where the walk is tiled, else a column as the scratch holds it."""
+    takes rows where the walk is in tiles, else a column as the scratch holds it."""
     j, last, carried = pl.program_id(3), walk.steps - 1, not walk.tiled
-    tq, fold = walk.to, _folds(sm_scale)
+    tq, fold, lanes = walk.to, _folds(sm_scale), carried and walk.in_tiles  # lanes: l is a sum a LANE
 
-    def finish(m, l, acc):
-        return (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype), m + jnp.log(jnp.maximum(l, 1e-20))
+    def finish(rows, m, l, acc):  # a row tile's output, and its log-sum-exp as a row where the walk is in tiles
+        o_ref[0, 0, rows], lse = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype), m + jnp.log(jnp.maximum(l, 1e-20))
+        if walk.in_tiles:
+            lse_ref[0, 0, :, rows] = _flip(lse)
+        else:
+            lse_ref[0, 0, rows] = lse
+
+    @functools.partial(jax.jit, static_argnums=0)  # traced once a kind of tile (d0: its mask), lowered in line a tile
+    def tile(d0, q, k, v, m, l, acc):
+        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)  # (tq, width) f32
+        if not fold:
+            s = s * sm_scale
+        ok = None if d0 is None else _mask(d0, s.shape, 0, walk.lo, walk.hi)
+        if ok is not None:
+            s = jnp.where(ok, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        if ok is not None:  # a row all masked so far keeps l = 0
+            p = jnp.where(ok, p, 0.0)
+        corr = jnp.exp(m - m_new)
+        psum = sum(p[:, c:c + 128] for c in range(0, p.shape[1], 128)) if lanes else jnp.sum(p, axis=-1, keepdims=True)
+        return m_new, l * corr + psum, acc * corr + jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     if carried:  # the online softmax's state crosses grid steps
         m_scr, l_scr, acc_scr = scratch
@@ -300,43 +317,34 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch, sm_scale, walk):
         @pl.when(cond)
         def _compute(tiles=tiles):
             for r0, inner in tiles:
+                if carried and not inner:  # a window's band misses this row tile of the pair
+                    continue
                 rows = slice(r0, r0 + tq)
                 q = q_ref[0, 0, rows]  # dots run in the input dtype (bf16 MXU
                 if fold:               # path, ~4x the f32 rate) with f32 accumulation
                     q = q * sm_scale
-                if carried:
-                    m, l, acc = m_scr[rows, :1], l_scr[rows, :1], acc_scr[rows]
+                if carried:  # a row of m_scr is one value 128 times: the reduction hands on a column the compiler
+                    # knows for lane-replicated (one read off lane 0 is broadcast along the lanes twice a tile)
+                    m, acc = jnp.max(m_scr[rows], axis=-1, keepdims=True), acc_scr[rows]
+                    l = l_scr[rows] if lanes else jnp.max(l_scr[rows], axis=-1, keepdims=True)
                 else:
                     m, l = jnp.full((tq, 1), NEG_INF, jnp.float32), jnp.zeros((tq, 1), jnp.float32)
                     acc = jnp.zeros((tq, v_ref.shape[-1]), jnp.float32)
                 for c0, width, d0 in inner:
-                    k, v = k_ref[0, 0, c0:c0 + width], v_ref[0, 0, c0:c0 + width]
-                    s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)  # (tq, width) f32
-                    if not fold:
-                        s = s * sm_scale
-                    ok = None if d0 is None else _mask(d0, s.shape, 0, walk.lo, walk.hi)
-                    if ok is not None:
-                        s = jnp.where(ok, s, NEG_INF)
-                    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-                    p = jnp.exp(s - m_new)
-                    if ok is not None:  # a row all masked so far keeps l = 0
-                        p = jnp.where(ok, p, 0.0)
-                    corr = jnp.exp(m - m_new)
-                    l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-                    acc = acc * corr + jax.lax.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-                    m = m_new
+                    m, l, acc = tile(d0, q, k_ref[0, 0, c0:c0 + width], v_ref[0, 0, c0:c0 + width], m, l, acc)
                 if carried:
                     m_scr[rows] = jnp.broadcast_to(m, (tq, m_scr.shape[1]))
                     l_scr[rows] = jnp.broadcast_to(l, (tq, l_scr.shape[1]))
                     acc_scr[rows] = acc
-                else:  # one step a head: the log-sum-exp leaves as a row
-                    o_ref[0, 0, rows], lse = finish(m, l, acc)
-                    lse_ref[0, 0, :, rows] = _flip(lse)
+                else:  # one step a head
+                    finish(rows, m, l, acc)
 
     if carried:
         @pl.when(j == last)
         def _finalize():
-            o_ref[0, 0], lse_ref[0, 0] = finish(m_scr[:, :1], l_scr[:, :1], acc_scr[...])  # lse (bq, 1)
+            for rows in (slice(r0, r0 + tq) for r0 in range(0, walk.bo, tq)):
+                l = jnp.sum(l_scr[rows], axis=-1, keepdims=True) if lanes else l_scr[rows, :1]
+                finish(rows, m_scr[rows, :1], l, acc_scr[rows])
 
 
 def _sds(shape, dtype, vma):
@@ -344,10 +352,7 @@ def _sds(shape, dtype, vma):
     vma-checked shard_map (sequence-parallel Ulysses local attention)."""
     if vma is None:
         return jax.ShapeDtypeStruct(shape, dtype)
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
-    except TypeError:  # pre-VMA jax: no varying-axis typing to declare
-        return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
 
 
 # batch, head and outer block independent; the last axis steps through an outer block's inner blocks in order
@@ -369,16 +374,13 @@ def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, vma=None, windo
         name="flash_fwd",
         grid=(B, H, walk.n_outer, walk.steps),
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, _stat_spec(bq, lambda b, h, qi, j: (b, h, qi, 0), walk.tiled)],
+        out_specs=[q_spec, _stat_spec(bq, lambda b, h, qi, j: (b, h, qi, 0), walk.in_tiles)],
         out_shape=[
             _sds((B, H, Sq, hd), q.dtype, vma),
-            _sds((B, H, 1, Sq) if walk.tiled else (B, H, Sq, 1), jnp.float32, vma),
+            _sds((B, H, 1, Sq) if walk.in_tiles else (B, H, Sq, 1), jnp.float32, vma),
         ],
-        scratch_shapes=[] if walk.tiled else [
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, hd), jnp.float32),
-        ],
+        scratch_shapes=[] if walk.tiled else [  # m, l (a row's value, or its sum a lane, over 128 lanes), acc
+            pltpu.VMEM((bq, 128), jnp.float32), pltpu.VMEM((bq, 128), jnp.float32), pltpu.VMEM((bq, hd), jnp.float32)],
         compiler_params=_GRID,
         interpret=interpret,
     )(q, k, v)
@@ -422,13 +424,11 @@ def flash_attention(
     """Flash attention on (B, S, H, head_dim) tensors (GQA via fewer KV heads).
 
     Differentiable (custom VJP with flash backward); runs compiled on TPU and
-    interpreted on CPU backends. ``block_q``/``block_k`` are the GRID blocks:
-    by default whole sequences of <= 1024 in tiles of 128 (a head is one grid
-    step, walked in tiles: ``_Walk``), else the sequence itself when <= 512,
-    else the largest of 512/256/128/64 dividing it; pass values to pin. ``vma``:
-    varying mesh axes to stamp on the kernel outputs when called inside a
-    vma-checked ``shard_map`` (e.g. ``("sequence",)`` for the Ulysses local
-    attention).
+    interpreted on CPU backends. ``block_q``/``block_k`` are the GRID blocks,
+    by default chosen from the shape (``_grid_blocks``) and walked in tiles
+    (``_Walk``); pass values to pin. ``vma``: varying mesh axes to stamp on the
+    kernel outputs when called inside a vma-checked ``shard_map`` (e.g.
+    ``("sequence",)`` for the Ulysses local attention).
 
     ``window``: static sliding-window size — each query attends keys in
     ``(qpos - window, qpos]`` (Mistral-style; the reference's
@@ -834,6 +834,20 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *scratch
     def finish(dq):
         return (dq * sm_scale if fold else dq).astype(dq_ref.dtype)
 
+    @functools.partial(jax.jit, static_argnums=0)  # traced once a kind of tile, lowered in line
+    def tile(d0, q, do, k, v, lse, delta, dq):
+        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
+        if not fold:
+            s = s * sm_scale
+        if d0 is not None:
+            s = jnp.where(_mask(d0, s.shape, 0, walk.lo, walk.hi), s, NEG_INF)
+        p = jnp.exp(s - lse)
+        dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)  # (tq, width)
+        ds = p * (dp - delta)
+        if not fold:
+            ds = ds * sm_scale
+        return dq + jax.lax.dot(ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
+
     if carried:
         dq_scr, = scratch
 
@@ -845,28 +859,19 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *scratch
         @pl.when(cond)
         def _compute(tiles=tiles):
             for r0, inner in tiles:
+                if carried and not inner:
+                    continue
                 rows = slice(r0, r0 + tq)
                 q, do = q_ref[0, 0, rows], do_ref[0, 0, rows]
-                if carried:
-                    lse, delta, dq = lse_ref[0, 0, rows], delta_ref[0, 0, rows], dq_scr[rows]  # (tq, 1)
-                else:
+                if walk.in_tiles:
                     lse, delta = _flip(lse_ref[0, 0, :, rows]), _flip(delta_ref[0, 0, :, rows])
-                    dq = jnp.zeros(q.shape, jnp.float32)
+                else:
+                    lse, delta = lse_ref[0, 0, rows], delta_ref[0, 0, rows]  # (tq, 1)
+                dq = dq_scr[rows] if carried else jnp.zeros(q.shape, jnp.float32)
                 if fold:
                     q = q * sm_scale
                 for c0, width, d0 in inner:
-                    k, v = k_ref[0, 0, c0:c0 + width], v_ref[0, 0, c0:c0 + width]
-                    s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
-                    if not fold:
-                        s = s * sm_scale
-                    if d0 is not None:
-                        s = jnp.where(_mask(d0, s.shape, 0, walk.lo, walk.hi), s, NEG_INF)
-                    p = jnp.exp(s - lse)
-                    dp = jax.lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)  # (tq, width)
-                    ds = p * (dp - delta)
-                    if not fold:
-                        ds = ds * sm_scale
-                    dq = dq + jax.lax.dot(ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
+                    dq = tile(d0, q, do, k_ref[0, 0, c0:c0 + width], v_ref[0, 0, c0:c0 + width], lse, delta, dq)
                 if carried:
                     dq_scr[rows] = dq
                 else:
@@ -892,6 +897,21 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     def stat(ref, cols):
         return ref[0, 0, :, cols] if stat_rows else _flip(ref[0, 0, cols])  # (1, width)
 
+    @functools.partial(jax.jit, static_argnums=0)  # traced once a kind of tile, lowered in line
+    def tile(d0, ks, v, q, do, lse, delta, dk, dv):
+        s = jax.lax.dot_general(ks, q, _NT, preferred_element_type=jnp.float32)  # (tk, width)
+        if not fold:
+            s = s * sm_scale
+        if d0 is not None:
+            s = jnp.where(_mask(d0, s.shape, 0, walk.lo, walk.hi), s, NEG_INF)
+        p = jnp.exp(s - lse).astype(do.dtype)
+        dv = dv + jax.lax.dot(p, do, preferred_element_type=jnp.float32)  # (tk, hd)
+        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+        ds = p.astype(jnp.float32) * (dp - delta)
+        if not fold:
+            ds = ds * sm_scale
+        return dk + jax.lax.dot(ds.astype(q.dtype), q, preferred_element_type=jnp.float32), dv
+
     if carried:
         dk_scr, dv_scr = scratch
 
@@ -904,25 +924,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
         @pl.when(cond)
         def _compute(tiles=tiles):
             for r0, inner in tiles:
+                if carried and not inner:
+                    continue
                 rows = slice(r0, r0 + tk)
                 k, v = k_ref[0, 0, rows], v_ref[0, 0, rows]
                 ks = k * sm_scale if fold else k
                 dk, dv = (dk_scr[rows], dv_scr[rows]) if carried else (jnp.zeros(k.shape, jnp.float32),) * 2
                 for c0, width, d0 in inner:
                     cols = slice(c0, c0 + width)
-                    q, do = q_ref[0, 0, cols], do_ref[0, 0, cols]
-                    s = jax.lax.dot_general(ks, q, _NT, preferred_element_type=jnp.float32)  # (tk, width)
-                    if not fold:
-                        s = s * sm_scale
-                    if d0 is not None:
-                        s = jnp.where(_mask(d0, s.shape, 0, walk.lo, walk.hi), s, NEG_INF)
-                    p = jnp.exp(s - stat(lse_ref, cols)).astype(do.dtype)
-                    dv = dv + jax.lax.dot(p, do, preferred_element_type=jnp.float32)  # (tk, hd)
-                    dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
-                    ds = p.astype(jnp.float32) * (dp - stat(delta_ref, cols))
-                    if not fold:
-                        ds = ds * sm_scale
-                    dk = dk + jax.lax.dot(ds.astype(q.dtype), q, preferred_element_type=jnp.float32)
+                    dk, dv = tile(d0, ks, v, q_ref[0, 0, cols], do_ref[0, 0, cols], stat(lse_ref, cols),
+                                  stat(delta_ref, cols), dk, dv)
                 if carried:
                     dk_scr[rows], dv_scr[rows] = dk, dv
                 else:
@@ -939,16 +950,18 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, vma, window, res, do):
     B, H, Sq, hd = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     group = H // Hkv
-    bq, bk = _grid_blocks(Sq, Sk, hd * q.dtype.itemsize, block_q, block_k)
-
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # (B, H, Sq)
 
     def stats(rows):  # a free reshape as rows; as columns the chip re-lays them out
         return tuple(a[:, :, None, :] if rows else a[..., None] for a in (lse, delta))
 
-    walk = _Walk.of(Sq, Sk, bq, bk, causal, window, "dq")
+    def walk_of(kernel):  # the grid's blocks are the kernel's own: its outer axis in blocks, its inner in major blocks
+        bq, bk = _grid_blocks(Sq, Sk, hd * q.dtype.itemsize, block_q, block_k, kernel)
+        return bq, bk, _Walk.of(Sq, Sk, bq, bk, causal, window, kernel)
+
+    bq, bk, walk = walk_of("dq")
     q_spec = pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi, j: (b, h, qi, 0))
-    stat_spec = _stat_spec(bq, lambda b, h, qi, j: (b, h, qi, 0), walk.tiled)
+    stat_spec = _stat_spec(bq, lambda b, h, qi, j: (b, h, qi, 0), walk.in_tiles)
     kv_spec = pl.BlockSpec((1, 1, bk, hd), lambda b, h, qi, j: (b, h // group, walk.inner_block(qi, j), 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, walk=walk),
@@ -960,9 +973,9 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, vma, window, res, do):
         scratch_shapes=[] if walk.tiled else [pltpu.VMEM((bq, hd), jnp.float32)],
         compiler_params=_GRID,
         interpret=interpret,
-    )(q, k, v, do, *stats(walk.tiled))
+    )(q, k, v, do, *stats(walk.in_tiles))
 
-    walk = _Walk.of(Sq, Sk, bq, bk, causal, window, "dkv")
+    bq, bk, walk = walk_of("dkv")
     stat_rows = bq % 128 == 0 or bq == Sq  # a row's block has to be whole lanes; else columns, turned
     q_index = lambda b, h, ki, j: (b, h, walk.inner_block(ki, j), 0)  # noqa: E731
     q_spec = pl.BlockSpec((1, 1, bq, hd), q_index)
@@ -998,7 +1011,7 @@ def tile_walk(sq, sk, block_q=None, block_k=None, causal=True, window=None, kern
     ``crossed`` tiles build a mask, the others none; a tile not listed is
     neither fetched for nor computed. The host's view of ``_Walk``: the same
     object the kernels unroll."""
-    w = _Walk.of(sq, sk, *_grid_blocks(sq, sk, row_bytes, block_q, block_k), causal, window, kernel)
+    w = _Walk.of(sq, sk, *_grid_blocks(sq, sk, row_bytes, block_q, block_k, kernel), causal, window, kernel)
     out = []
     for (ob, j), off in sorted(w._pairs.items()):
         o0, i0 = ob * w.bo, ob * w.bo - off
@@ -1026,3 +1039,44 @@ def chunk_tiles(W, H, Hkv, T, dk, dv, q_off, k_min=0, window=None, static=False,
     over all its heads: :func:`chunk_walk` summed."""
     tiles, fetched = chunk_walk(W, T, H // Hkv, dk, dv, q_off, k_min, window, static, itemsize)
     return H * len(tiles), H * sum(t[-1] for t in tiles), Hkv * fetched
+
+
+# ---------------------------------------------------------------------------
+# the carried regime's blocks (below everything a layer plan's tick carries: see the note above the backward)
+# ---------------------------------------------------------------------------
+
+# (outer block, longest inner (MAJOR) block, rows of a tile, columns of a tile no mask crosses) of a kernel whose head
+# is too long for ONE grid step. What a step fetches and the pipeline sees is a pair of long blocks; what the MXU
+# and the vector unit work on is a tile of it. Read on v5e, 2026-10-03, at 2 x 32 / 8 heads of 64, S 8,192,
+# causal, bfloat16, ms a call forward / dq / dkv (PERF.md section 6, PR 50; the parent's 512 x 512 blocks, each ONE
+# tile with the state through scratch a tile, 19.81 / 13.35 / 16.35):
+#   * blocks, in 128-row tiles: (512, 512) 12.1 / 13.0 / 17.5, (512, 1024) 9.3 / 11.6 / 15.3, (512, 2048) 8.0 /
+#     10.8 / 14.2, (1024, 1024) 7.7 / 10.7 / 13.5: a row tile's state crosses scratch once every 1,024 keys, 28 of
+#     a head's 64 grid steps lie past the diagonal, and ONE offset of a pair is crossed. (1024, 2048) does not
+#     fit the default scoped VMEM;
+#   * columns of a tile, at (1024, 1024) and 128 rows: forward 128 / 256 / 512 wide 7.7 / 8.2 / 10.5, dq 256 /
+#     512 13.5 / 10.7, dkv 128 / 256 / 512 13.5 / 13.6 / 14.3;
+#   * what must NOT be done: a ``fori_loop`` over the row tiles of a pair (one chain of dependent tiles an
+#     iteration: 16.4 / 13.6 / 19.6, the parent's time) -- eight independent rows in ONE basic block are what
+#     hides a tile's latencies; and a column read off lane 0 of the scratch (a lane broadcast twice a tile).
+# A kernel's straight-line code is traced and lowered on EVERY start, tile body by tile body: 100 bodies a
+# kernel (128 x 128 tiles) cost the cell's set-up 6.6 s of 37, so the tile below is 256 rows (30 / 22 / 30 bodies,
+# +2.8 s): 8.02 / 10.30 / 13.73, the three 32.0 ms where the parent's were 49.5 and 128-row tiles' 31.9.
+_MAJOR = {"fwd": (1024, 1024, 256, 256), "dq": (1024, 1024, 256, 512), "dkv": (1024, 1024, 256, 256)}
+# (The forward keeps the softmax's denominator as a sum a LANE where the state crosses scratch and tiles are whole
+# lanes: whole vectors added a tile and ONE reduction along lanes at the row's end, where a reduction a tile made
+# the forward 13.3 ms for 9.3 at (512, 1024).)
+
+
+def _major_blocks(sq, sk, row_bytes, kernel):
+    """(bq, bk) where a head is not one grid step and the caller gave no block: on the kernel's outer and inner
+    axis the longest block up to ``_MAJOR``'s that divides the axis and keeps a head's block within ``_WHOLE``'s
+    bytes; from ``_DEFAULT_BLOCK`` down ``_auto_block`` (an axis no power of two from 64 up divides raises)."""
+    def block(size, cap):
+        while cap > _DEFAULT_BLOCK and (size % cap or cap * row_bytes > _WHOLE[1]):
+            cap //= 2
+        return cap if cap > _DEFAULT_BLOCK else _auto_block(size, None)
+
+    bo, bi = map(block, (sk, sq) if kernel == "dkv" else (sq, sk), _MAJOR[kernel][:2])
+    return (bi, bo) if kernel == "dkv" else (bo, bi)
+

@@ -103,6 +103,16 @@ TILE_CLASS_CASES = {
     "causal-128": (128, 128, {}, None, True),
     "causal-192": (192, 192, {}, None, True),
     "causal-320": (320, 320, {}, None, True),
+    # a head too long for one grid step: a pair of (outer, major) blocks walked in register tiles, the state
+    # through scratch once a (row tile, grid step). 2,560 = five blocks of 512 on both axes (no longer block
+    # divides it); 3,072 takes blocks of 1,024 on both (whole pieces and the diagonal's)
+    "causal-2560-auto": (2560, 2560, {}, None, True),
+    "grouped-heads-4x1-of-3072": (3072, 3072, {}, 1, True),
+    "window-300-of-3072": (3072, 3072, dict(window=300), None, True),  # shorter than a major block
+    "non-causal-2560": (2560, 2560, dict(causal=False), None, True),   # whole pieces only
+    "causal-2560-keys-4096": (2560, 4096, {}, None, False),            # 512 rows against 1,024 keys a step
+    # a caller's blocks stay the grid's, and are walked in tiles where they are whole tiles
+    "causal-1024-blocks-512": (1024, 1024, dict(block_q=512, block_k=512), None, True),
 }
 
 
@@ -141,6 +151,8 @@ def _unmasked(Sq, Sk, causal, window):
     (1024, 1024, 256, 512, True, 700), (512, 512, None, None, False, None),
     (384, 128, None, None, True, None), (128, 384, None, None, True, None),
     (192, 192, None, None, True, None), (256, 256, 64, 64, True, 17), (576, 576, None, None, True, None),
+    (8192, 8192, None, None, True, None), (8192, 8192, None, None, True, 1000),  # the LFM2 training cell's shape
+    (2560, 2560, None, None, True, None), (2560, 4096, None, None, True, None), (3072, 3072, None, None, False, None),
 ])
 def test_tile_walk_covers_every_unmasked_pair_once(Sq, Sk, bq, bk, causal, window, kernel):
     """The walk, asked on the host with the kernels' own object: every
@@ -164,6 +176,24 @@ def test_tile_walk_at_the_training_cells_shape(kernel):
     tiles = tile_walk(1024, 1024, causal=True, kernel=kernel)
     assert sum(t[4] for t in tiles) == 8 and all(t[2:4] == (128, 128) for t in tiles if t[4])
     assert sum(t[2] * t[3] for t in tiles) == 36 * 128 * 128 <= 0.57 * 1024 * 1024
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_tile_walk_at_the_lfm2_cells_shape(kernel):
+    """S 8,192 causal, a head too long for one grid step: the pairs of 1,024-blocks are walked in register tiles —
+    the regime's row tile (``_MAJOR``: 256 rows, the price of a start's tracing and lowering: PERF.md section 6,
+    PR 50) by 128 columns where the diagonal crosses, else by the kernel's wide tile — 0.516 S^2 computed (136
+    blocks of 512 computed whole were 0.531; 128-row tiles would be 0.508), a mask built on the 64 tiles of
+    256 x 128 the diagonal touches and on no other."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _MAJOR
+
+    _, _, rows, wide = _MAJOR[kernel]
+    tiles = tile_walk(8192, 8192, causal=True, kernel=kernel)
+    outer, inner = (3, 2) if kernel == "dkv" else (2, 3)  # a tile is (q0, k0, queries, keys, crossed); dkv's rows are keys
+    assert {t[outer] for t in tiles} == {rows} and {t[inner] for t in tiles} <= {128, wide}
+    assert sum(t[4] for t in tiles) == 2 * 8192 // rows and all(t[inner] == 128 for t in tiles if t[4])
+    assert sum(t[2] * t[3] for t in tiles) <= 0.52 * 8192 * 8192
+    assert len(tiles) <= 136 * 5  # what a start traces and lowers grows with it
 
 
 class TestFlashResidualsUnderRemat:

@@ -150,10 +150,17 @@ CASES = {
     "flash-bwd-8x1024x25x64": _flash("bwd", 8, 1024, 25, 64),
     # the longest sequence a head walks in ONE grid step (`flash_attention._WHOLE`)
     "flash-bwd-4x2048x16x128": _flash("bwd", 4, 2048, 16, 128),
+    # a head too long for one step (since PR 50): blocks of 1,024 on both axes (`flash_attention._MAJOR`; at head
+    # width 128 as at 64, their bytes within `_WHOLE`'s), each pair walked in tiles of 256 rows with the state in
+    # VMEM scratch once a (row tile, grid step) and the statistics as rows; 4,096 is four such blocks an axis
     "flash-fwd-2x4096x32x128": _flash("fwd", 2, 4096, 32, 128),
     "flash-bwd-2x4096x32x128": _flash("bwd", 2, 4096, 32, 128),
     "flash-window-2x4096x32x128": _flash("window", 2, 4096, 32, 128),
     "flash-gqa-bwd-2x4096x32x128-kv8": _flash("bwd", 2, 4096, 32, 128, kv_heads=8),
+    # the LFM2 training cell's attention layer: 2 rows x 32 query / 8 key-value heads of 64 at S 8,192
+    "flash-fwd-2x8192x32x64-kv8": _flash("fwd", 2, 8192, 32, 64, kv_heads=8),
+    "flash-bwd-2x8192x32x64-kv8": _flash("bwd", 2, 8192, 32, 64, kv_heads=8),
+    "flash-window-2x8192x32x64-kv8": _flash("window", 2, 8192, 32, 64, kv_heads=8),
     "block-sparse-bwd-4x1024x12x64": _block_sparse,
     "flash-bwd-mesh-tensor4": _flash_on_mesh(
         {"tensor": 4}, PartitionSpec(None, None, "tensor", None)),
