@@ -208,6 +208,13 @@ EVENT_SCHEMAS = {
             "spec_gamma": "int",
             "spec_drafted": "int",
             "spec_accepted": "int",
+            # a layer plan's counters, of the ticks the step retired (tick_stats() sums them)
+            "moe_expert_layers": "int",
+            "moe_experts_hit": "int",
+            "moe_buffer_rows": "int",
+            "moe_filled_rows": "int",
+            "ssm_chunk_tokens": "int",
+            "ssm_step_rows": "int",
         },
     },
     "serving_fault": {

@@ -116,6 +116,9 @@ _bucket = read_bucket
 # up to the cap. The speculative pool's chunk still runs the (B, W) segment
 # program under every slot, where a narrow last chunk pays: widths from 16 up.
 _PLAN_CHUNK_FLOOR = 256
+# a layer plan's tick counters that a ``serving_tick`` event carries, where the plan has them
+_PLAN_TICK_FIELDS = ("moe_expert_layers", "moe_experts_hit", "moe_buffer_rows", "moe_filled_rows",
+                     "ssm_chunk_tokens", "ssm_step_rows")
 _SPEC_CHUNK_FLOOR = 16
 
 
@@ -531,7 +534,14 @@ class ContinuousBatchingEngine:
             self._tick_stats.update(
                 moe_ticks=0, moe_assignments=0, moe_held_assignments=0, moe_experts_hit=0,
                 moe_expert_tokens_most_sum=0, moe_expert_tokens_mean_sum=0.0,
-                moe_imbalance_sum=0.0)
+                moe_imbalance_sum=0.0, moe_expert_layers=0)
+            # ... and, where an expert layer is a layer of its own, the buffer rows its
+            # grouped matmuls walked against those an assignment filled (layer_plan.ROW_STATS)
+            from deepspeed_tpu.models.layer_plan import counts_rows
+
+            self._row_stats = counts_rows(self.cfg)
+            if self._row_stats:
+                self._tick_stats.update(moe_buffer_rows=0, moe_filled_rows=0)
         if self._state_pool:
             # as the ticks report them (layer_plan.GDN_STATS): real tokens
             # the chunks' scans took, rows whose state a tick stepped, named
@@ -1155,6 +1165,7 @@ class ContinuousBatchingEngine:
         # dispatched, the remaining in-flight ticks are the drain tail
         block_ms = 0.0
         tokens0, wasted0 = stats["tokens"], stats["wasted_tokens"]
+        plan0 = {name: stats[name] for name in _PLAN_TICK_FIELDS if name in stats}
         drafted0, accepted0 = stats["spec_drafted"], stats["spec_accepted"]
         while self._inflight and (len(self._inflight) > self.pipeline_depth
                                   or not recs):
@@ -1191,6 +1202,8 @@ class ContinuousBatchingEngine:
                     event["spec_gamma"] = self.spec_gamma
                     event["spec_drafted"] = stats["spec_drafted"] - drafted0
                     event["spec_accepted"] = stats["spec_accepted"] - accepted0
+                # what a layer plan's ticks counted, of the ticks this step retired
+                event.update({name: stats[name] - was for name, was in plan0.items()})
                 tele.emit("serving_tick", event)
         return emitted
 
@@ -1546,6 +1559,7 @@ class ContinuousBatchingEngine:
                 made, held, most, layers, hit = (
                     int(v) for v in arr[0, k + 2:k + 2 + TICK_STATS])
                 stats["moe_experts_hit"] += hit
+                stats["moe_expert_layers"] += layers   # expert layers, which need not be all the layers
                 mean = held / max(1, layers * self.cfg.held_experts[1])
                 stats["moe_ticks"] += 1
                 stats["moe_assignments"] += made
@@ -1554,10 +1568,14 @@ class ContinuousBatchingEngine:
                 stats["moe_expert_tokens_mean_sum"] += mean
                 if held:
                     stats["moe_imbalance_sum"] += most / mean
+                at = k + 2 + TICK_STATS
                 if self._state_pool:
-                    at = k + 2 + TICK_STATS
                     for i, name in enumerate(self._state_counters):
                         stats[name] += int(arr[0, at + i])
+                    at += len(self._state_counters)
+                if self._row_stats:   # a row an assignment filled is an assignment to a held expert
+                    stats["moe_buffer_rows"] += int(arr[0, at])
+                    stats["moe_filled_rows"] += held
             hook = self.span_hook
             if hook is not None:
                 t_ret = time.monotonic()

@@ -4,7 +4,7 @@
 (:class:`~deepspeed_tpu.models.transformer.LayerKind`: its mixer, softmax
 attention with its reach, its key-value heads, its rotary base and whether a
 sink joins its softmax, the gated delta rule or a state-space scan; a dense or
-an expert FFN)
+an expert FFN; or ONE of the two alone, a layer of one norm and one residual add)
 and ``layer_plan`` says which kind each layer is. Kinds differ in parameter SHAPES, so the parameters are stacked
 per kind (``params["layers"][kind.name]``, leading axis = that kind's
 layers in model order) and the stack is walked as **one scan per run of
@@ -125,29 +125,37 @@ def check_plan(cfg):
                              f"heads a multiple of key heads, and a convolution: {sizes}, "
                              f"{cfg.gdn_conv} taps")
     if any(k.mixer == "ssm" for k in kinds):
+        from deepspeed_tpu.ops.pallas.ssd import heads_per_tile
+
         sizes = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
-        if min(sizes) < 1 or cfg.ssm_groups != 1 or cfg.ssm_conv < 2:
+        if (min(sizes) < 1 or cfg.ssm_groups < 1 or cfg.ssm_conv < 2 or cfg.ssm_heads % cfg.ssm_groups
+                or (cfg.ssm_heads // cfg.ssm_groups) % heads_per_tile(cfg.ssm_head_dim, cfg.ssm_heads)):
             raise ValueError(f"state-space kinds of the state pool need ssm heads, head width and "
-                             f"state width, one group and a convolution: {sizes}, "
-                             f"{cfg.ssm_groups} groups, {cfg.ssm_conv} taps")
+                             f"state width, groups of whole stored tiles of heads and a "
+                             f"convolution: {sizes}, {cfg.ssm_groups} groups, {cfg.ssm_conv} taps")
     if any(k.mixer == "conv" for k in kinds) and cfg.conv_taps < 2:
         raise ValueError(f"a short-convolution kind needs at least two taps: conv_taps {cfg.conv_taps}")
     if cfg.moe_score not in ("sigmoid", "softmax"):
         raise ValueError(f"moe_score {cfg.moe_score!r}")
     for k in kinds:
-        if k.mixer not in ("attention", "gdn", "ssm", "mla", "conv"):
+        if k.mixer not in ("attention", "gdn", "ssm", "mla", "conv", "none"):
             raise ValueError(f"kind {k.name}: mixer {k.mixer!r}")
         if k.mixer == "attention" and cfg.num_heads % k.kv_heads:
             raise ValueError(f"kind {k.name}: {cfg.num_heads} heads over {k.kv_heads} kv heads")
-        if k.ffn not in ("dense", "moe"):
+        if k.ffn not in ("dense", "moe", "none"):
             raise ValueError(f"kind {k.name}: ffn {k.ffn!r}")
+        if k.mixer == "none" and k.ffn == "none":
+            raise ValueError(f"kind {k.name} has neither a mixer nor an FFN")
         if k.ffn == "moe" and cfg.moe_num_experts < 1:
             raise ValueError(f"kind {k.name} routes but moe_num_experts is 0")
+        if k.ffn_latent < 0 or (k.ffn_latent and k.ffn != "moe"):
+            raise ValueError(f"kind {k.name}: a latent width ({k.ffn_latent}) is an expert FFN's")
     if (cfg.pos_embedding not in ("rope", "none") or cfg.norm_position not in ("pre", "sandwich")
-            or cfg.use_bias or cfg.activation != "silu_glu" or not cfg.causal
+            or cfg.use_bias or cfg.activation not in ("silu_glu", "relu2") or not cfg.causal
             or cfg.kv_cache_dtype != "model"):
         raise ValueError("a layer plan takes rotary positions or none at all (pos_embedding "
-                         "'rope' | 'none'), pre-norm or sandwich-norm blocks, no biases, SwiGLU, "
+                         "'rope' | 'none'), pre-norm or sandwich-norm blocks, no biases, SwiGLU "
+                         "or the un-gated squared ReLU (activation 'silu_glu' | 'relu2'), "
                          "causal attention and a KV cache in the model's dtype")
     if cfg.loop_steps < 1:
         raise ValueError(f"loop_steps {cfg.loop_steps}: a plan is walked at least once")
@@ -192,10 +200,14 @@ def _layer_shapes(cfg, kind):
     D, nh, dk, dv, F = cfg.hidden_size, cfg.num_heads, cfg.head_dim, cfg.v_head_dim, _ffn_size(cfg, kind)
     out_scale = 1.0 / math.sqrt(2 * cfg.num_layers)
     norm = 0.1 if cfg.norm_one_plus else None   # (1 + w): w zero-centred
-    shapes = {("ln1", "scale"): ((D,), norm), ("ln2", "scale"): ((D,), norm)}
+    # a sublayer's norm(s) come with it: ln1 the mixer's, ln2 the FFN's
+    names = [n for n, part in (("ln1", kind.mixer), ("ln2", kind.ffn)) if part != "none"]
     if cfg.norm_position == "sandwich":
-        shapes.update({("ln1_post", "scale"): ((D,), norm), ("ln2_post", "scale"): ((D,), norm)})
-    if kind.mixer == "gdn":
+        names += [n + "_post" for n in names]
+    shapes = {(n, "scale"): ((D,), norm) for n in names}
+    if kind.mixer == "none":
+        pass
+    elif kind.mixer == "gdn":
         Hk, Hv, gk, gv = cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
         C = 2 * Hk * gk + Hv * gv
         shapes.update({
@@ -253,12 +265,16 @@ def _layer_shapes(cfg, kind):
             shapes[("attn", "q_norm")] = shapes[("attn", "k_norm")] = ((dk,), norm)
     if kind.ffn == "moe":
         E, held = cfg.moe_num_experts, cfg.held_experts[1]
+        Dx = kind.ffn_latent or D    # the width the routed experts work at
         shapes.update({
             ("mlp", "gate"): ((D, E), 0.02),
-            ("mlp", "wg"): ((held, D, F), 1 / math.sqrt(D)),
-            ("mlp", "wi"): ((held, D, F), 1 / math.sqrt(D)),
-            ("mlp", "wo"): ((held, F, D), out_scale / math.sqrt(F)),
+            ("mlp", "wg"): ((held, Dx, F), 1 / math.sqrt(Dx)),
+            ("mlp", "wi"): ((held, Dx, F), 1 / math.sqrt(Dx)),
+            ("mlp", "wo"): ((held, F, Dx), out_scale / math.sqrt(F)),
         })
+        if kind.ffn_latent:
+            shapes.update({("mlp", "latent_down"): ((D, Dx), 1 / math.sqrt(D)),
+                           ("mlp", "latent_up"): ((Dx, D), 1 / math.sqrt(Dx))})
         if cfg.moe_score == "sigmoid":
             # the selection bias balancing leaves behind: small beside the scores' spread,
             # large enough to decide some choices
@@ -272,12 +288,14 @@ def _layer_shapes(cfg, kind):
             })
             if cfg.moe_shared_gated:
                 shapes[("mlp", "shared_gate")] = ((D, 1), 1 / math.sqrt(D))
-    else:
+    elif kind.ffn == "dense":
         shapes.update({
             ("mlp", "wg"): ((D, F), 1 / math.sqrt(D)),
             ("mlp", "wi"): ((D, F), 1 / math.sqrt(D)),
             ("mlp", "wo"): ((F, D), out_scale / math.sqrt(F)),
         })
+    if cfg.activation == "relu2":    # W_down relu(W_up x)^2: two matrices, no gate
+        shapes = {at: v for at, v in shapes.items() if at not in (("mlp", "wg"), ("mlp", "shared_wg"))}
     return shapes
 
 
@@ -391,36 +409,57 @@ def _grouped_attention(q, k, v, ok, sink, scale):
     return out.reshape(B, S, nh, v.shape[-1])
 
 
+def _hidden_act(h, p, up, gate):
+    """An FFN's hidden activation by the form its parameters have: SwiGLU
+    where ``p`` holds the gate's matrix, else the un-gated squared ReLU."""
+    tf = _tf()
+    if gate in p:
+        return jax.nn.silu(tf._linear(h, p[gate])) * tf._linear(h, p[up])
+    return jnp.square(jax.nn.relu(tf._linear(h, p[up])))
+
+
 def _ffn(h, mlp_p, kind, cfg, valid, grad):
-    """h (N, D) -> (out (N, D), stats (5,) int32: assignments made, to held
-    experts, the most one held expert got, expert layers, held experts hit).
+    """h (N, D) -> (out (N, D), stats (:func:`ffn_stats_len`,) int32:
+    assignments made, to held experts, the most one held expert got, expert
+    layers, held experts hit; where the plan counts rows, also the buffer
+    rows the grouped matmul walked: the rows it filled are the assignments
+    to held experts).
     ``grad``: the caller differentiates this (the training forward)."""
     tf = _tf()
     if kind.ffn == "dense":
         with jax.named_scope(Scope.MLP):
-            act = jax.nn.silu(tf._linear(h, mlp_p["wg"])) * tf._linear(h, mlp_p["wi"])
-            return tf._linear(act, mlp_p["wo"]), jnp.zeros((5,), jnp.int32)
+            act = _hidden_act(h, mlp_p, "wi", "wg")
+            return tf._linear(act, mlp_p["wo"]), jnp.zeros((ffn_stats_len(cfg),), jnp.int32)
     from deepspeed_tpu.moe import held_experts as he
 
     first, count = cfg.held_experts
     chosen, weights = he.route(h, mlp_p["gate"], mlp_p.get("gate_bias"), cfg.moe_top_k,
                                cfg.moe_score, scale=cfg.moe_routed_scale, norm_eps=cfg.moe_norm_eps)
+    u = h
+    if kind.ffn_latent:   # the routed experts work in a latent; the router and the shared expert do not
+        with jax.named_scope(Scope.MOE_LATENT):
+            u = tf._linear(h, mlp_p["latent_down"])
     out, counts = he.held_experts_ffn(
-        h, chosen, weights, {n: mlp_p[n] for n in _EXPERT_LEAVES}, first, count,
+        u, chosen, weights, {n: mlp_p[n] for n in _EXPERT_LEAVES if n in mlp_p}, first, count,
         grad=grad, valid=valid, layer=mlp_p.get("layer"), n_experts=cfg.moe_num_experts)
+    if kind.ffn_latent:   # linear: once over the experts' weighted sum
+        with jax.named_scope(Scope.MOE_LATENT):
+            out = tf._linear(out, mlp_p["latent_up"])
     if cfg.moe_shared_size:  # every chip computes it alike, whatever was routed where
         with jax.named_scope(Scope.MOE_SHARED):
-            act = (jax.nn.silu(tf._linear(h, mlp_p["shared_wg"]))
-                   * tf._linear(h, mlp_p["shared_wi"]))
+            act = _hidden_act(h, mlp_p, "shared_wi", "shared_wg")
             if cfg.moe_shared_gated:
                 gate = jax.nn.sigmoid(tf._linear(h, mlp_p["shared_gate"]).astype(jnp.float32))
                 out = out + (tf._linear(act, mlp_p["shared_wo"]) * gate).astype(out.dtype)
             else:
                 out = out + tf._linear(act, mlp_p["shared_wo"]).astype(out.dtype)
     made = (h.shape[0] if valid is None else valid.sum(dtype=jnp.int32)) * cfg.moe_top_k
-    return out, jnp.stack([jnp.asarray(made, jnp.int32), counts.sum(dtype=jnp.int32),
-                           counts.max().astype(jnp.int32), jnp.int32(1),
-                           (counts > 0).sum(dtype=jnp.int32)])
+    stats = [jnp.asarray(made, jnp.int32), counts.sum(dtype=jnp.int32),
+             counts.max().astype(jnp.int32), jnp.int32(1), (counts > 0).sum(dtype=jnp.int32)]
+    if counts_rows(cfg):   # each expert's rows padded to whole row tiles
+        tm = he.row_tile(h.shape[0], cfg.moe_top_k)
+        stats.append(((counts + tm - 1) // tm * tm).sum(dtype=jnp.int32))
+    return out, jnp.stack(stats)
 
 
 # -- the gated delta rule mixer (ops/pallas/gated_delta.py has the rule itself) --
@@ -569,23 +608,35 @@ def _ssm_lanes(per_head, cfg):
 
 def _ssm_parts(u, dt, p, cfg):
     """Convolved u (N, C), dt (N, H) -> (x (N, inner), dt x, a = dt A (N, H),
-    B, C (N, state width)), float32. The heads stay side by side along the
-    channels (a (N, H, 64) array would leave half of every lane tile empty
-    and cost a re-layout each way: PERF.md section 6, PR 42)."""
-    N = cfg.ssm_state
+    B, C (N, groups x state width), group by group), float32. The heads stay
+    side by side along the channels (a (N, H, 64) array would leave half of
+    every lane tile empty and cost a re-layout each way: PERF.md section 6,
+    PR 42)."""
+    N = cfg.ssm_groups * cfg.ssm_state
     u = u.astype(jnp.float32)
     x = u[:, :-2 * N]
     a = -jnp.exp(p["a_log"].astype(jnp.float32)) * dt
     return x, _ssm_lanes(dt, cfg) * x, a, u[:, -2 * N:-N], u[:, -N:]
 
 
+def _group_mean_square(y, cfg):
+    """Mean square of y (N, inner) over each group's channels, on each of
+    them: (N, inner), or (N, 1) where the one group is the whole width.
+    Looked up when a program is traced (a test plants a fault here)."""
+    if cfg.ssm_groups == 1:
+        return (y * y).mean(-1, keepdims=True)
+    by_group = y.reshape(y.shape[0], cfg.ssm_groups, -1)
+    return jnp.repeat((by_group * by_group).mean(-1), by_group.shape[-1], axis=-1)
+
+
 def _ssm_out(y, x, z, p, cfg):
     """y, x, z (N, inner) -> (N, D): the skip ``D x``, the gate silu(z), THEN
-    one RMSNorm over the whole inner width, through Wo."""
+    an RMSNorm over each group's share of the inner width (one group: over
+    all of it), through Wo."""
     tf = _tf()
     y = y + _ssm_lanes(p["d"].astype(jnp.float32)[None], cfg) * x
     y = y * jax.nn.silu(z.astype(jnp.float32))
-    y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + cfg.norm_eps) * p["norm"].astype(jnp.float32)
+    y = y * jax.lax.rsqrt(_group_mean_square(y, cfg) + cfg.norm_eps) * p["norm"].astype(jnp.float32)
     return tf._linear(y.astype(z.dtype), p["wo"])
 
 
@@ -609,10 +660,11 @@ def _ssm_plain(h, p, cfg, B, S):
         x, _, a, Bm, Cm = _ssm_parts(u.reshape(B * S, -1), dt, p, cfg)
         rows = lambda v: v.reshape((B, S) + v.shape[1:])
         heads = (cfg.ssm_heads, cfg.ssm_head_dim)
+        by_group = lambda v: v.reshape(B, S, cfg.ssm_groups, cfg.ssm_state)
         zero = jnp.zeros(heads + (cfg.ssm_state,), jnp.float32)
         with jax.named_scope(Scope.SSM_SCAN):
             y = jax.vmap(lambda *v: ssd_recurrence(*v, zero)[0])(
-                x.reshape((B, S) + heads), rows(dt), rows(a), rows(Bm), rows(Cm))
+                x.reshape((B, S) + heads), rows(dt), rows(a), by_group(Bm), by_group(Cm))
         return _ssm_out(y.reshape(x.shape), x, z, p, cfg)
 
 
@@ -809,12 +861,25 @@ def chunk_attention_tiles(cfg, W: int, size: int, first: int):
 
 
 GDN_STATS = 2   # beside the routing counters: real tokens the chunk's scan took, rows stepped
+ROW_STATS = 1   # ... and LAST: buffer rows the grouped matmuls walked (those filled: the held assignments)
+
+
+def counts_rows(cfg) -> bool:
+    """Do ``cfg``'s expert layers count :data:`ROW_STATS`? Where an expert
+    layer is a layer of its own (a kind with no mixer): the plans whose every
+    layer has both sublayers keep the ticks they had, to the text."""
+    return any(k.mixer == "none" and k.ffn == "moe" for k in cfg.layer_kinds)
+
+
+def ffn_stats_len(cfg) -> int:
+    return 5 + (ROW_STATS if counts_rows(cfg) else 0)
 
 
 def stats_len(cfg) -> int:
-    """Counters a tick of ``cfg`` returns: the five routing counters, and
-    where it has a state pool :data:`GDN_STATS` more."""
-    return 5 + (GDN_STATS if kv_cache.state_spec(cfg) is not None else 0)
+    """Counters a tick of ``cfg`` returns: the five routing counters, where
+    it has a state pool :data:`GDN_STATS` more, and where its expert layers
+    count rows (:func:`counts_rows`) :data:`ROW_STATS` more."""
+    return ffn_stats_len(cfg) + (GDN_STATS if kv_cache.state_spec(cfg) is not None else 0)
 
 
 def state_counters(cfg) -> tuple:
@@ -826,7 +891,7 @@ def state_counters(cfg) -> tuple:
 
 def _merge_stats(a, b):
     return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2]), a[3] + b[3],
-                      a[4] + b[4]])
+                      a[4] + b[4]] + [a[i] + b[i] for i in range(5, a.shape[0])])
 
 
 _EXPERT_LEAVES = ("wg", "wi", "wo")
@@ -843,7 +908,7 @@ def _walk(cfg, layers, carry, layer_fn):
         stack = layers[run.kind.name]
         experts = {}
         if run.kind.ffn == "moe":
-            experts = {n: stack["mlp"][n] for n in _EXPERT_LEAVES}
+            experts = {n: stack["mlp"][n] for n in _EXPERT_LEAVES if n in stack["mlp"]}
             stack = dict(stack, mlp={n: p for n, p in stack["mlp"].items()
                                      if n not in _EXPERT_LEAVES})
 
@@ -1003,8 +1068,11 @@ def forward_plan(params, cfg, tokens, return_hidden=False, return_exit=False, re
 
     def layer(carry, layer_p, kind, _):   # carry: (x,), or (x, the counters so far) where they are asked for
         x = carry[0]
-        h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg).reshape(B * S, -1)
-        x = _add(x, _post(mix(h, layer_p, kind), layer_p, "ln1_post", cfg).reshape(B, S, -1), cfg)
+        if kind.mixer != "none":
+            h = tf._norm(x, layer_p["ln1"]["scale"], None, cfg).reshape(B * S, -1)
+            x = _add(x, _post(mix(h, layer_p, kind), layer_p, "ln1_post", cfg).reshape(B, S, -1), cfg)
+        if kind.ffn == "none":   # the mixer alone: one norm, one residual add
+            return (x,) + tuple(carry[1:])
         h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg).reshape(B * S, -1)
         out, st = _ffn(h, layer_p["mlp"], kind, cfg, None, grad=True)
         x = _add(x, _post(out, layer_p, "ln2_post", cfg).reshape(B, S, -1), cfg)
@@ -1014,13 +1082,13 @@ def forward_plan(params, cfg, tokens, return_hidden=False, return_exit=False, re
         layer = jax.checkpoint(layer, policy=tf._resolve_remat_policy(cfg.remat_policy),
                                static_argnums=(2,))
     layers = tf._cast_layers(params["layers"], dtype)
-    carry = (x, jnp.zeros((5,), jnp.int32)) if return_stats else (x,)
+    carry = (x, jnp.zeros((ffn_stats_len(cfg),), jnp.int32)) if return_stats else (x,)
     (x, *stats), states = _passes(cfg, params, carry, lambda c, step: _walk(cfg, layers, c, layer),
                                   keep_states=return_exit)
     if cfg.loop_steps == 1:
         x = tf._norm(x, params["final_norm"]["scale"], None, cfg)
     extra = (exit_pdf(params, states),) if return_exit else ()
-    extra += tuple(stats)
+    extra += tuple(st[:5] for st in stats)   # the five routing counters (counter_names)
     if return_hidden:
         return (x, jnp.float32(0.0)) + extra
     return (_logits(x, params, cfg), jnp.float32(0.0)) + extra
@@ -1113,7 +1181,8 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
     expert assignments made / to held experts / the most one held expert got
     in a layer / expert layers / held experts that got a token, summed over
     the layers; with a state pool also the real tokens the chunk's scan
-    took and the rows whose state this tick stepped)."""
+    took and the rows whose state this tick stepped; where the expert layers
+    count rows, :data:`ROW_STATS` last)."""
     tf = _tf()
     kv_cache.refuse_unserved(cfg)
     dtype = cfg.jnp_dtype
@@ -1133,6 +1202,10 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
 
     def layer(carry, layer_p, kind, pool_index, step):
         x, pools, stats = carry
+        if kind.mixer == "none":   # the FFN alone: one norm, one residual add, no row in any pool
+            h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg)
+            out, st = _ffn(h, layer_p["mlp"], kind, cfg, valid, grad=False)
+            return _add(x, _post(out, layer_p, "ln2_post", cfg), cfg), pools, _merge_stats(stats, st)
         pool = pools[kind.pool]
         if step is not None:   # a looped plan: this pass's own layer-step of the pool
             pool_index = _pass_slot(per_pass[kind.pool], step, pool_index)
@@ -1151,6 +1224,8 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
                                          pool_index, pos, chunk, read_len, length)
             out, pool = _attn_out(att, h, layer_p["attn"], cfg), {"k": pk, "v": pv}
         x = _add(x, _post(out, layer_p, "ln1_post", cfg), cfg)
+        if kind.ffn == "none":   # the mixer alone
+            return x, dict(pools, **{kind.pool: pool}), stats
         h = tf._norm(x, layer_p["ln2"]["scale"], None, cfg)
         out, st = _ffn(h, layer_p["mlp"], kind, cfg, valid, grad=False)
         return (_add(x, _post(out, layer_p, "ln2_post", cfg), cfg), dict(pools, **{kind.pool: pool}),
@@ -1158,11 +1233,11 @@ def forward_plan_cached(params, cfg, tokens, pos, cache, read_len: Optional[int]
 
     layers = tf._cast_layers(params["layers"], dtype)
     (x, cache, stats), _ = _passes(
-        cfg, params, (x, cache, jnp.zeros((5,), jnp.int32)),
+        cfg, params, (x, cache, jnp.zeros((ffn_stats_len(cfg),), jnp.int32)),
         lambda c, step: _walk(cfg, layers, c, partial(layer, step=step)))
     if kv_cache.state_spec(cfg) is not None:
-        stats = jnp.concatenate([stats, jnp.stack([valid[B:].sum(dtype=jnp.int32),
-                                                   valid[:B].sum(dtype=jnp.int32)])])
+        state = jnp.stack([valid[B:].sum(dtype=jnp.int32), valid[:B].sum(dtype=jnp.int32)])
+        stats = jnp.concatenate([stats[:5], state, stats[5:]] if counts_rows(cfg) else [stats, state])
     rows = x[:B]
     if chunk is not None:  # the admitting row's place is taken by the chunk's sampled column
         rows = jax.lax.dynamic_update_slice(rows, x[B + chunk.emit][None], (chunk.slot, 0))

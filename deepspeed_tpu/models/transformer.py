@@ -46,25 +46,32 @@ class LayerKind:
     stacked together (``params["layers"][name]``); attention layers of the
     same reach (``window`` 0 or not) share a KV pool, delta-rule or
     state-space layers the state pool, latent layers the latent pool; a
-    short-convolution layer has none."""
+    short-convolution layer has none. A layer may be ONE sublayer alone, with
+    one norm and one residual add: a mixer without an FFN (``ffn="none"``), or
+    an FFN without a mixer (``mixer="none"``), which keeps nothing in any pool."""
     name: str
     kv_heads: int = 1  # of an attention mixer
     window: int = 0  # 0 = full causal attention; W = the last W positions
     rope_theta: float = 10000.0
     sink: bool = False  # a learned per-head logit joins the softmax's denominator
-    ffn: str = "dense"  # dense | moe (sigmoid top-k over the experts held)
+    ffn: str = "dense"  # dense | moe (sigmoid top-k over the experts held) | none (the mixer alone)
     ffn_size: Optional[int] = None  # None => cfg.ffn_size
+    # an expert FFN whose routed experts work in a latent of this width: one projection down
+    # before them and one up after them, shared by the experts; the router and the shared
+    # expert read the model's width (0: the experts work at the model's width)
+    ffn_latent: int = 0
     # attention | gdn (Gated DeltaNet: recurrent state, no keys kept) | ssm (Mamba-2: a
     # state-space scan with a scalar decay a head, no keys kept) | mla (latent
     # attention: one latent and one rotated key a token, shared by every head) | conv (a
-    # double-gated causal depthwise convolution of cfg.conv_taps taps: no keys, no scan)
+    # double-gated causal depthwise convolution of cfg.conv_taps taps: no keys, no scan) |
+    # none (the FFN alone: no row in any pool)
     mixer: str = "attention"
 
     @property
     def pool(self) -> Optional[str]:
         """The cache pool this kind's layers live in; None for a kind that is
-        not served (``kv_cache.refuse_unserved``)."""
-        if self.mixer == "conv":
+        not served (``kv_cache.refuse_unserved``) or keeps nothing (no mixer)."""
+        if self.mixer in ("conv", "none"):
             return None
         if self.mixer in ("gdn", "ssm"):
             return "state"
@@ -84,7 +91,7 @@ class TransformerConfig:
     max_seq_len: int = 1024
     pos_embedding: str = "learned"  # learned | rope | alibi | none
     norm_type: str = "layernorm"  # layernorm | rmsnorm
-    activation: str = "gelu"  # gelu | relu | silu_glu (SwiGLU)
+    activation: str = "gelu"  # gelu | relu | silu_glu (SwiGLU) | relu2 (a plan's: W_down relu(W_up x)^2)
     tie_embeddings: bool = True
     dtype: str = "float32"  # compute/storage dtype for params & activations
     rope_theta: float = 10000.0
@@ -201,8 +208,9 @@ class TransformerConfig:
     gdn_conv: int = 4
     # Mamba-2 mixer (LayerKind.mixer == "ssm"): heads x head width is its inner
     # width, every head keeps a (head width, state width) float32 state, ``B``
-    # and ``C`` are shared by the heads of a group, and the causal depthwise
-    # convolution (with a bias) runs over inner width + 2 x groups x state width
+    # and ``C`` are shared by the heads of a group (as is the gated norm: over
+    # each group's channels), and the causal depthwise convolution (with a
+    # bias) runs over inner width + 2 x groups x state width
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
@@ -336,7 +344,10 @@ class TransformerConfig:
         every step whether or not embeddings are tied) + causal attention."""
         n = self.num_params() - self.vocab_size * self.hidden_size * (1 if self.tie_embeddings else 2)
         lm_head_flops = 6 * self.vocab_size * self.hidden_size
-        attn_flops = 12 * self.num_layers * self.hidden_size * seq_len  # 2*2*3 per token pair
+        # 2*2*3 per token pair, in the layers that attend (a plan may hold layers that do not)
+        attending = (self.num_layers if self.layer_kinds is None else
+                     sum(k.mixer in ("attention", "mla") for k in self.plan))
+        attn_flops = 12 * attending * self.hidden_size * seq_len
         return 6.0 * n + lm_head_flops + attn_flops
 
 
@@ -558,6 +569,7 @@ def logical_specs(params, cfg: TransformerConfig):
                      "gate_bias": (None,),
                      "shared_wi": ("embed", "mlp"), "shared_wg": ("embed", "mlp"),
                      "shared_wo": ("mlp", "embed"), "shared_gate": ("embed", None),
+                     "latent_down": ("embed", None), "latent_up": (None, "embed"),
                      # PR-MoE residual MLP + mixing coefficient (dense)
                      "res_wi": ("embed", "mlp"), "res_wg": ("embed", "mlp"),
                      "res_wo": ("mlp", "embed"), "res_bi": ("mlp",), "res_bo": ("embed",),

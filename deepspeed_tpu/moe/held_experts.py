@@ -115,21 +115,21 @@ def held_experts_ffn(h, chosen, weights, experts, first: int, count: int, *, gra
                      valid=None, tm: Optional[int] = None, layer=None, n_experts: int = 0):
     """The held experts' part of the layer's output, (N, D), and the tokens each
     held expert got, (count,). h (N, D); chosen/weights (N, k) from :func:`route`;
-    experts {"wg", "wi": (count, D, F), "wo": (count, F, D)}, SwiGLU; with ``layer``
-    (a traced scalar) a stack (L, count, ...) whose layer ``layer`` the kernel reads in
-    place. ``grad``: the caller will differentiate this (``n_experts``: the router's)."""
+    experts {"wg", "wi": (count, D, F), "wo": (count, F, D)}, SwiGLU (no "wg": relu(.)^2,
+    :func:`_hidden`); with ``layer`` (a traced scalar) a stack (L, count, ...) whose layer
+    the kernel reads in place. ``grad``: the caller differentiates this (``n_experts``: the router's)."""
     N, D = h.shape
     k = chosen.shape[1]
-    if tm is None:  # whole MXU tiles for a prefill chunk, the sublane tile for decode rows
-        tm = 128 if N * k >= 2048 else 16
+    if tm is None:
+        tm = row_tile(N, k)
     lay = layout(chosen, first, count, tm, valid)
     M = lay.src.shape[0]
     if grad:  # the serving path below stays as it is, line for line: its kernels' payloads carry them
         return _trained_ffn(h, chosen, weights, experts, lay, first, tm, layer, n_experts)
     with jax.named_scope(Scope.MOE_EXPERTS):
         x = jnp.take(jnp.concatenate([h, jnp.zeros((1, D), h.dtype)]), lay.src, axis=0)
-        act = (jax.nn.silu(_matmul(x, experts["wg"], lay, tm, grad, layer))
-               * _matmul(x, experts["wi"], lay, tm, grad, layer))
+        # the experts' hidden activation, by the form their parameters have
+        act = _hidden(x, experts, functools.partial(_matmul, lay=lay, tm=tm, grad=grad, layer=layer))
         y = _matmul(act, experts["wo"], lay, tm, grad, layer)
         mine = (lay.dest < M)[:, :, None]                      # rows of unused tiles hold anything
         y = jnp.take(y, jnp.minimum(lay.dest, M - 1).reshape(-1), axis=0).reshape(N, k, D)
@@ -154,8 +154,7 @@ def _rows_ffn(rows: int, first: int, tm: int, h, chosen, weights, experts, lay: 
     k = chosen.shape[1]
     src = lay.src[:rows]
     x = jnp.take(jnp.concatenate([h, jnp.zeros((1, D), h.dtype)]), src, axis=0)
-    act = (jax.nn.silu(_matmul(x, experts["wg"], lay, tm, True, None))
-           * _matmul(x, experts["wi"], lay, tm, True, None))
+    act = _hidden(x, experts, functools.partial(_matmul, lay=lay, tm=tm, grad=True, layer=None))
     y = _matmul(act, experts["wo"], lay, tm, True, None).astype(jnp.float32)
     # a row's weight: its token's weight for the expert whose tile the row lies in
     expert = first + jnp.repeat(lay.tile_group[:rows // tm], tm)
@@ -221,3 +220,26 @@ def _trained_ffn(h, chosen, weights, experts, lay: Layout, first: int, tm: int, 
     with jax.named_scope(Scope.MOE_EXPERTS):
         body = functools.partial(_rows_ffn, M) if bucket >= M else functools.partial(_bucketed_ffn, bucket)
         return body(first, tm, h, chosen, weights, experts, lay), lay.counts
+
+
+def _hidden(x, experts, matmul):
+    """The experts' hidden activation from the form of their parameters: with
+    a gate's matrix ``wg`` SwiGLU, ``silu(x wg) * (x wi)``; without one the
+    un-gated squared ReLU, ``relu(x wi)^2`` (two matrices an expert)."""
+    if "wg" in experts:
+        return jax.nn.silu(matmul(x, experts["wg"])) * matmul(x, experts["wi"])
+    return jnp.square(jax.nn.relu(matmul(x, experts["wi"])))
+
+
+def row_tile(n_tokens: int, k: int) -> int:
+    """Rows of a tile of the sorted buffer: whole MXU tiles (128) where a
+    prefill chunk rides the tick, the sublane tile (16) for decode rows alone.
+    ``n_tokens * k >= 2048`` said "a chunk" while no model chose more than ten
+    experts a token; at top-22, 128 decode rows make 2,816 assignments, of
+    which an expert is expected to get 5.5, and a 128-row tile an expert hit
+    is twenty-three parts padding. So the tokens themselves must fill two MXU
+    tiles as well (every tick of the plans served before this rule keeps its
+    tile: those over 2,048 assignments hold 288 tokens and more). A rule on
+    the assignments EXPECTED on held experts (``n k count / E``) alone cannot
+    keep them: PERF.md section 6, PR 51."""
+    return 128 if n_tokens * k >= 2048 and n_tokens >= 256 else 16

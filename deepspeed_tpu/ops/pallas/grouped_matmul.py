@@ -28,14 +28,15 @@ from deepspeed_tpu.ops.pallas.interpret import resolve_interpret
 
 def _tile(size: int, cap: int) -> int:
     """``size`` itself under the cap, else the largest of cap, cap/2, ...
-    128 dividing it."""
+    512 dividing it, else the largest multiple of 128 under the cap dividing
+    it (2,688 = 3 x 896: halving alone ends at 128, 21 blocks a row tile and
+    weight rows of 256 bytes a DMA)."""
     if size <= cap:
         return size
-    t = cap
-    while t >= 128:
+    halves = [t for t in (cap, cap // 2) if t >= 512]
+    for t in halves + list(range(cap - cap % 128, 0, -128)):
         if size % t == 0:
             return t
-        t //= 2
     raise ValueError(f"grouped matmul cannot tile a dimension of {size}")
 
 

@@ -3,7 +3,8 @@
 A head keeps a state ``S`` of (head width P, state width N), float32. Token
 t, with its input ``x_t`` (P,), step ``dt_t > 0``, log decay ``a_t = dt_t A``
 (``A < 0``, one scalar a head) and the ``B_t``, ``C_t`` (N,) every head of
-the group shares:
+its group shares (``ssm_groups`` groups of consecutive heads, each with a
+``B`` and a ``C`` of its own):
 
     S = exp(a_t) S + (dt_t x_t) B_t^T;   y_t = S C_t
 
@@ -18,9 +19,11 @@ with ``LANES // P`` heads side by side, ``(N, g P)`` a group of g heads
 (Granite 4.0-H: two heads of 64 in 128 lanes, 64 such pairs a row and
 layer). Every per-head and per-channel quantity (the decay, ``dt x``, the
 output) then lies along the lanes, as a projection gives it; only ``B`` and
-``C``, which all heads share, have to run down the sublanes, ONE matrix a
-row for every head; and no tile of the pool is half empty (a state stored
-(N, 64) would be padded to 128 lanes: twice the pool).
+``C``, which the heads of a group share, have to run down the sublanes, ONE
+matrix a row and group (a group is whole stored tiles, and a kernel's block
+of them lies within one group: ``layer_plan.check_plan``); and no tile of
+the pool is half empty (a state stored (N, 64) would be padded to 128 lanes:
+twice the pool).
 
 **The rows' step** (:func:`ssd_step_pool`, the Mosaic kernel ``ssd_step``):
 one token a row on a layer of the stacked pool, in place: every row's state
@@ -35,7 +38,7 @@ c_j <= 0`` or as ``c_i`` itself) and ``X`` the rows ``dt_t x_t``:
     Y = (C B^T . L) X + exp(c) . (C S)          L_ij = exp(c_i - c_j), i >= j
     S = exp(c_last) S + B^T (exp(c_last - c) . X)
 
-``G = C B^T`` is one matrix for all heads (batched XLA, no state in it).
+``G = C B^T`` is one matrix for all heads of a group (batched XLA, no state in it).
 The kernel is a grid over blocks of head groups, the sub-chunks walked in
 order with the block's states in VMEM scratch from the first to the last;
 it reads the row's states out of the stacked pool and writes them back in
@@ -69,15 +72,29 @@ def heads_per_tile(head_dim: int, heads: int) -> int:
 
 
 def ssd_recurrence(x, dt, a, B, C, state):
-    """The definition. x (T, H, P); dt, a (T, H); B, C (T, N) (one group);
-    state (H, P, N). Returns (y (T, H, P), state), float32."""
+    """The definition. x (T, H, P); dt, a (T, H); B, C (T, G, N), head h of
+    group h // (H / G) (or (T, N): one group); state (H, P, N). Returns (y
+    (T, H, P), state), float32."""
+    H = x.shape[1]
+    B, C = (v if v.ndim == 3 else v[:, None] for v in (B, C))
+
     def step(S, tok):
         x_t, dt_t, a_t, b_t, c_t = tok
-        S = jnp.exp(a_t)[:, None, None] * S + (dt_t[:, None] * x_t)[:, :, None] * b_t[None, None, :]
-        return S, jnp.einsum("hpn,n->hp", S, c_t, precision=HIGHEST)
+        b_t, c_t = (jnp.repeat(v, H // v.shape[0], axis=0) for v in (b_t, c_t))       # (H, N)
+        S = jnp.exp(a_t)[:, None, None] * S + (dt_t[:, None] * x_t)[:, :, None] * b_t[:, None, :]
+        return S, jnp.einsum("hpn,hn->hp", S, c_t, precision=HIGHEST)
 
     state, y = jax.lax.scan(step, state.astype(F32), tuple(v.astype(F32) for v in (x, dt, a, B, C)))
     return y, state
+
+
+def _group_of(block_tiles: int, tiles_per_group: int, groups: int):
+    """(rows of the grouped operands a row, block of stored tiles) -> the index
+    of the block's group among the (rows x groups) matrices of ``B`` / ``C``
+    laid group after group. One group: the row itself, the index map it was."""
+    if groups == 1:
+        return lambda r, b: r
+    return lambda r, b: r * groups + b * block_tiles // tiles_per_group
 
 
 def to_pool(state, g: int):
@@ -114,21 +131,25 @@ def _step_kernel(layer_ref, s_ref, dec_ref, xd_ref, b_ref, c_ref, s_out_ref, y_r
 def ssd_step_pool(pool, layer, xd, a, B, C, *, tiles: int = 16, interpret: Optional[bool] = None):
     """One token a row on layer ``layer`` of the stacked pool (L, R, T, N,
     W), IN PLACE. xd (R, H P) = dt x, the heads side by side; a (R, H) the
-    log decay; B, C (R, N). The Mosaic kernel ``ssd_step``, a grid step a
-    row and ``tiles`` stored tiles, reads each state once and stores it once
-    (the pool is aliased to the result and no other layer of it is touched).
-    Returns (y (R, H P) float32, pool)."""
+    log decay; B, C (R, G N), group after group. The Mosaic kernel
+    ``ssd_step``, a grid step a row and ``tiles`` stored tiles of one group,
+    reads each state once and stores it once (the pool is aliased to the
+    result and no other layer of it is touched). Returns (y (R, H P)
+    float32, pool)."""
     _, R, T, N, W = pool.shape
     H = a.shape[1]
     P = xd.shape[1] // H
-    tiles = math.gcd(tiles, T)
+    G = B.shape[1] // N
+    tiles = math.gcd(tiles, T // G)
+    group = _group_of(tiles, T // G, G)
     dec = _lanes(jnp.exp(a.astype(F32)), P)[:, None]                               # (R, 1, H P)
     xd = xd.astype(F32)[:, None]
-    b = jnp.broadcast_to(B.astype(F32)[:, :, None], (R, N, W))
-    c = jnp.broadcast_to(C.astype(F32)[:, None, :], (R, 8, N))
+    b = jnp.broadcast_to(B.astype(F32).reshape(R * G, N)[:, :, None], (R * G, N, W))
+    c = jnp.broadcast_to(C.astype(F32).reshape(R * G, N)[:, None, :], (R * G, 8, N))
     state = pl.BlockSpec((None, None, tiles, N, W), lambda r, t, layer_ref: (layer_ref[0], r, t, 0, 0))
     lanes = pl.BlockSpec((None, 1, tiles * W), lambda r, t, layer_ref: (r, 0, t))
-    whole = lambda rows, cols: pl.BlockSpec((None, rows, cols), lambda r, t, layer_ref: (r, 0, 0))
+    whole = lambda rows, cols: pl.BlockSpec((None, rows, cols),
+                                            lambda r, t, layer_ref: (group(r, t), 0, 0))
     pool, y = pl.pallas_call(
         functools.partial(_step_kernel, tiles=tiles, width=W),
         name="ssd_step",
@@ -195,14 +216,16 @@ def ssd_chunk_pool(pool, layer, slot, xd, a, B, C, *, sub: int = SUB, tiles: int
                    interpret: Optional[bool] = None):
     """One row's W tokens from, and into, row ``slot`` of layer ``layer`` of
     the stacked pool (L, R, T, N, width), IN PLACE. xd (W, H P) = dt x, the
-    heads side by side; a (W, H) the log decay; B, C (W, N). W is padded to
-    whole sub-chunks with tokens that leave the state alone. Returns (y (W,
-    H P) float32, pool)."""
+    heads side by side; a (W, H) the log decay; B, C (W, G N), group after
+    group. W is padded to whole sub-chunks with tokens that leave the state
+    alone. Returns (y (W, H P) float32, pool)."""
     W, H = a.shape
     P = xd.shape[1] // H
     _, _, T, N, width = pool.shape
     per_tile = width // P
-    tiles = math.gcd(tiles, T)
+    groups = B.shape[1] // N
+    tiles = math.gcd(tiles, T // groups)
+    group = _group_of(tiles, T // groups, groups)
     Q = min(sub, max(8, 1 << (W - 1).bit_length()))
     n = -(-W // Q)
     cut = lambda v: jnp.pad(v.astype(F32), [(0, n * Q - W)] + [(0, 0)] * (v.ndim - 1)).reshape(
@@ -210,10 +233,17 @@ def ssd_chunk_pool(pool, layer, slot, xd, a, B, C, *, sub: int = SUB, tiles: int
     xd, a, B, C = cut(xd), cut(a), cut(B), cut(C)
     c = jnp.cumsum(a, axis=1)                                               # (n, Q, H)
     cq = _lanes(c[:, -1:], P)                                               # (n, 1, H P)
-    G = jnp.einsum("nik,njk->nij", C, B, precision=HIGHEST)
+    if groups == 1:
+        G = jnp.einsum("nik,njk->nij", C, B, precision=HIGHEST)
+    else:   # a (sub-chunk, group) after another: C B^T, C and B^T of each
+        C, B = (v.reshape(n, Q, groups, N) for v in (C, B))
+        G = jnp.einsum("nigk,njgk->ngij", C, B, precision=HIGHEST).reshape(n * groups, Q, Q)
+        C = C.transpose(0, 2, 1, 3).reshape(n * groups, Q, N)
+        BT = B.transpose(0, 2, 3, 1).reshape(n * groups, N, Q)
     at = jnp.stack([jnp.asarray(layer, jnp.int32), jnp.asarray(slot, jnp.int32)])
     state = pl.BlockSpec((None, None, tiles, N, width), lambda b, i, at: (at[0], at[1], b, 0, 0))
     shared = lambda rows, cols: pl.BlockSpec((None, rows, cols), lambda b, i, at: (i, 0, 0))
+    grouped = lambda rows, cols: pl.BlockSpec((None, rows, cols), lambda b, i, at: (group(i, b), 0, 0))
     lanes = lambda rows: pl.BlockSpec((None, rows, tiles * width), lambda b, i, at: (i, 0, b))
     pool, y = pl.pallas_call(
         functools.partial(_chunk_kernel, n_sub=n, tiles=tiles, width=width, per_tile=per_tile),
@@ -222,7 +252,7 @@ def ssd_chunk_pool(pool, layer, slot, xd, a, B, C, *, sub: int = SUB, tiles: int
             num_scalar_prefetch=1, grid=(T // tiles, n),
             in_specs=[state, shared(Q, H),
                       pl.BlockSpec((None, tiles * per_tile, Q), lambda b, i, at: (i, b, 0)),
-                      lanes(1), shared(Q, Q), shared(Q, N), shared(N, Q), lanes(Q)],
+                      lanes(1), grouped(Q, Q), grouped(Q, N), grouped(N, Q), lanes(Q)],
             out_specs=[state, lanes(Q)],
             scratch_shapes=[pltpu.VMEM((tiles, N, width), F32)]),
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
@@ -230,5 +260,5 @@ def ssd_chunk_pool(pool, layer, slot, xd, a, B, C, *, sub: int = SUB, tiles: int
         input_output_aliases={1: 0},
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
-    )(at, pool, c, c.swapaxes(1, 2), cq, G, C, B.swapaxes(1, 2), xd)
+    )(at, pool, c, c.swapaxes(1, 2), cq, G, C, B.swapaxes(1, 2) if groups == 1 else BT, xd)
     return y.reshape(n * Q, H * P)[:W], pool

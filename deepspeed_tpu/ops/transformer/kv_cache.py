@@ -154,7 +154,7 @@ def specs(cfg) -> Tuple[PoolSpec, ...]:
     refuse_unserved(cfg)
     pools = {}
     for kind in cfg.plan:
-        if kind.pool == "state":   # no keys, no time axis: state_spec()
+        if kind.pool in ("state", None):   # no keys, no time axis: state_spec(); no mixer, no row
             continue
         n = pools[kind.pool].layers if kind.pool in pools else 0
         if kind.pool == LATENT:   # one head, one leaf: latent_width()

@@ -44,6 +44,7 @@ class Scope:
     MOE_EXPERTS = "moe.experts"  # gather, grouped matmuls over the held experts, combine
     MOE_EXPERTS_WHOLE = "moe.experts.whole"  # ... a training layer whose routing overflowed its bucket
     MOE_SHARED = "moe.shared"    # the shared expert (and its sigmoid gate, where it has one)
+    MOE_LATENT = "moe.latent"    # the projections into and out of the latent the routed experts work in
     ATTN_GATE = "attn.gate"      # the sigmoid gate on a plan's attention output
     MIX_GDN = "mix.gdn"          # a gated-delta-rule mixer: projections, gates, output norm
     GDN_CONV = "gdn.conv"        # ... its causal depthwise convolution

@@ -76,7 +76,7 @@ GDN = LayerKind("gdn", mixer="gdn", ffn="moe", ffn_size=32)
 
 @pytest.mark.parametrize("bad,why", [
     (dict(ssm_heads=0), "state-space kinds"),
-    (dict(ssm_groups=2), "one group"),
+    (dict(ssm_groups=2), "groups of whole stored tiles"),   # 8 heads of 16 are ONE stored tile
     (dict(ssm_conv=1), "a convolution"),
     (dict(pos_embedding="learned"), "rotary positions or none at all"),
     (dict(layer_plan=(0,) * 10), "full-attention layer"),

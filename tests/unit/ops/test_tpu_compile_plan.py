@@ -572,3 +572,63 @@ def test_this_module_compiles_at_the_default_level():
     """``tests/conftest.py`` compiles the suite's CPU programs cheaply and
     lists this module among those that keep the backend's default level."""
     assert jax.config.read("jax_disable_most_optimizations") is False
+
+
+@pytest.mark.parametrize("chunk", [None, 512], ids=["plain", "fused512"])
+def test_nemotron_tick_of_one_sublayer_a_layer_keeps_pools_and_weights_in_place(topo, chunk):
+    """What layers of ONE sublayer, eight groups of ``B`` and ``C`` and
+    experts in a latent add (PR 51): the Nemotron-3-Super tick at the
+    benchmark's cut (128 slots of 4,096; eleven runs of one layer, each
+    reading its layer out of its kind's stack) with the key-value pool of its
+    one attention layer and the float32 state pool of its five Mamba-2 layers
+    in place, no layer's weights copied out of a stack, both ``ssd`` kernels,
+    the grouped matmul and the flash chunk kernel inside."""
+    from benchmark import models_nemotron_h
+    from deepspeed_tpu.inference.decoding import compile_pool_tick_fn
+    from deepspeed_tpu.models import transformer as tf
+
+    with open(os.path.join(ROOT, "benchmark", "configs", "nemotron-3-super-120b-a12b.json")) as fh:
+        config = json.load(fh)
+    slots, length = 128, 4096
+    model = models_nemotron_h.build_model(config, max_seq_len=length, remat=False, attn_impl="pallas")
+    cfg = model.cfg
+    mesh = comm.build_mesh({"data": 1, "tensor": 1}, devices=topo.devices[:1])
+    one = NamedSharding(mesh, PartitionSpec())
+    abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    p_sh = jax.tree.map(lambda a: one, abstract)
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=one),
+                          abstract)
+    with force_interpret(False):
+        fn, cache_sh, _ = compile_pool_tick_fn(mesh, cfg, p_sh, slots, length, 1, 0.0, 0, 1.0,
+                                               read_len=None, chunk=chunk)
+        cache = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            jax.eval_shape(lambda: tf.init_cache(cfg, slots, length)), cache_sh)
+        row = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one)
+        args = [params, cache, row, row, row, row, row, row, jax.ShapeDtypeStruct((2,), jnp.uint32)]
+        if chunk is not None:
+            wide = jax.ShapeDtypeStruct((chunk,), jnp.int32)
+            args += [wide, wide, jax.ShapeDtypeStruct((), jnp.int32), row, row]
+        compiled = fn.lower(*args).compile()
+    comm.destroy()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert pool_bytes == 128 * 2 * 4096 * 256 * 2 + 5 * 128 * (128 * 64 * 128 * 4 + 3 * 10240 * 2)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes                     # 0.54 + 2.72 GB, in place
+    assert mem.temp_size_in_bytes < 0.4e9, mem.temp_size_in_bytes    # 0.05 plain, 0.27 with a chunk
+    resident = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert resident < 15.0e9, resident                               # ISSUE 51's line for 128 slots
+    print("nemotron tick", chunk, "temp", mem.temp_size_in_bytes / 1e9, "arguments",
+          mem.argument_size_in_bytes / 1e9, "resident", resident / 1e9)
+    text = compiled.as_text()
+    # neither pool is copied, no slab of the state pool is sliced out, and a run of one layer reads
+    # its layer where the stack lies: no copy of a mixer's, a latent's or a shared expert's matrix
+    assert not re.findall(r"= bf16\[(?:\d+,)?128,2,4096,\d+\]\S* copy\(", text)
+    assert not re.findall(r"= f32\[(?:\d+,)?128,64,128,128\]\S* (?:copy|dynamic-slice)\(", text)
+    for shape in ("4096,18560", "8192,4096", "4096,5376", "5376,4096", "4096,1024", "1024,4096",
+                  "128,1024,2688", "128,2688,1024"):
+        assert not re.findall(r"= bf16\[(?:\d+,)?%s\]\S* copy\(" % shape, text), shape
+    assert not _qkv_weights_moved(text, abstract)
+    assert "ssd_step" in text and "moe_grouped_matmul" in text
+    assert ("ssd_chunk_fwd" in text) == ("flash_chunk_fwd" in text) == (chunk is not None)
