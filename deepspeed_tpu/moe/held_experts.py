@@ -17,11 +17,11 @@ capacity and no token is dropped: the assignments to held experts are sorted
 by expert into a buffer, each expert's rows padded to whole row tiles, and
 one grouped matmul runs over the experts that got rows. A tick (``grad=False``)
 sizes the buffer for the worst routing (every token choosing as many held
-experts as it can) and takes the Pallas kernel of ``ops/pallas/grouped_matmul.py``,
-which reads a layer straight out of the stack and skips the tiles no expert got
-(21.0 ms a tick where ``ragged_dot`` takes 32.6: PERF.md section 6, PR 27b) but
-has no gradient. Training (``grad=True``) takes ``jax.lax.ragged_dot``, over a
-bucket of twice the even share, the worst-case buffer when the routing overflows it.
+experts as it can), takes the Pallas kernel of ``ops/pallas/grouped_matmul.py``, which reads a layer
+straight out of the stack and skips the tiles no expert got (21.0 ms a tick where ``ragged_dot`` takes 32.6:
+PERF.md section 6, PR 27b) but has no gradient, and comes back by assignment: a token's k rows gathered with
+the assignment axis leading, then summed (:func:`_combine`). Training (``grad=True``) takes ``ragged_dot`` over
+a bucket of twice the even share (the whole buffer when the routing overflows it); back by rows (:func:`_rows_ffn`).
 """
 
 import functools
@@ -123,17 +123,17 @@ def held_experts_ffn(h, chosen, weights, experts, first: int, count: int, *, gra
     if tm is None:
         tm = row_tile(N, k)
     lay = layout(chosen, first, count, tm, valid)
-    M = lay.src.shape[0]
-    if grad:  # the serving path below stays as it is, line for line: its kernels' payloads carry them
+    # every line from `_matmul` to `row_tile` keeps its NUMBER: the grouped matmuls' payloads carry their call stack's
+    if grad:
         return _trained_ffn(h, chosen, weights, experts, lay, first, tm, layer, n_experts)
     with jax.named_scope(Scope.MOE_EXPERTS):
         x = jnp.take(jnp.concatenate([h, jnp.zeros((1, D), h.dtype)]), lay.src, axis=0)
         # the experts' hidden activation, by the form their parameters have
         act = _hidden(x, experts, functools.partial(_matmul, lay=lay, tm=tm, grad=grad, layer=layer))
         y = _matmul(act, experts["wo"], lay, tm, grad, layer)
-        mine = (lay.dest < M)[:, :, None]                      # rows of unused tiles hold anything
-        y = jnp.take(y, jnp.minimum(lay.dest, M - 1).reshape(-1), axis=0).reshape(N, k, D)
-        out = jnp.where(mine, y.astype(jnp.float32) * weights[:, :, None], 0.0).sum(axis=1)
+        # the way back to the tokens is a sum over a LEADING assignment axis (:func:`_combine`, last in
+        # this file): (N, k, D) lays k on the sublanes, which the chip can only do by a padded copy of it all
+        out = _combine(y, lay.dest, weights)
     return out.astype(h.dtype), lay.counts
 
 
@@ -243,3 +243,17 @@ def row_tile(n_tokens: int, k: int) -> int:
     the assignments EXPECTED on held experts (``n k count / E``) alone cannot
     keep them: PERF.md section 6, PR 51."""
     return 128 if n_tokens * k >= 2048 and n_tokens >= 256 else 16
+
+
+def _combine(y, dest, weights):
+    """The way back from the sorted buffer to the tokens: y (M, D) the buffer's rows, dest (N, k)
+    each assignment's row (M: not held), weights (N, k) float32 -> (N, D) float32, a token's k rows
+    times their weights, summed. The assignment axis LEADS: the gather by ``dest.T`` writes (k, N, D)
+    itself and the sum runs over whole (N, D) planes, which the compiler fuses into one pass over the
+    rows. (N, k, D) puts k on the sublanes of an (8, 128) tile: at top-10 the chip wrote the gathered
+    rows out in float32, copied them into a layout padded to 16 and summed the copy, 1.42 ms a layer
+    of the Granite cell's fused tick where this takes 0.12 (PERF.md section 6, PR 52)."""
+    M = y.shape[0]
+    rows = jnp.take(y, jnp.minimum(dest, M - 1).T, axis=0)
+    mine = (dest < M).T[:, :, None]                            # rows of unused tiles hold anything
+    return jnp.where(mine, rows.astype(jnp.float32) * weights.T[:, :, None], 0.0).sum(axis=0)

@@ -168,6 +168,71 @@ def test_a_routed_scaling_factor_multiplies_the_normalised_weights_and_nothing_e
     assert text() == text(scale=1.0) != text(scale=1.8)
 
 
+# ---- the serving path's way back: a token's k rows summed over a leading assignment axis ---------
+
+@pytest.mark.parametrize("held", ["half", "all"])
+@pytest.mark.parametrize("masked", [False, True], ids=["every-row", "parked-rows"])
+@pytest.mark.parametrize("k,n,dtype", [
+    (4, 37, jnp.float32), (8, 48, jnp.float32), (10, 37, jnp.float32), (22, 32, jnp.float32),
+    (10, 48, jnp.bfloat16), (22, 37, jnp.bfloat16)], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_the_ticks_combine_is_every_held_expert_on_every_token(k, n, dtype, masked, held):
+    """The serving layer (``grad=False``) against every held expert on every token, at the top-k of
+    the plans served (4 GLM, 8 MiMo, 10 Granite and Qwen3-Next, 22 Nemotron), tokens a multiple of
+    the 16-row tile and not: float32 to 2e-5, bfloat16 within its rounding of the k-term sum. A
+    token left out adds exactly 0, and so does an assignment to an expert not held."""
+    n_experts, d, f, tm = 32, 32, 24, 8
+    first, count = (8, 16) if held == "half" else (0, n_experts)
+    rs = np.random.RandomState(1000 * k + n)
+    draw = lambda *shape, scale=1.0: jnp.asarray(rs.randn(*shape) * scale, jnp.float32)
+    h, gate, bias = draw(n, d), draw(d, n_experts, scale=0.3), draw(n_experts, scale=0.01)
+    ex = {"wg": draw(count, d, f, scale=0.2), "wi": draw(count, d, f, scale=0.2),
+          "wo": draw(count, f, d, scale=0.2)}
+    valid = jnp.asarray(np.arange(n) % 5 != 2) if masked else None
+
+    def by_every_expert(h, ex, chosen, weights):
+        y = jnp.einsum("enf,efd->end", jax.nn.silu(jnp.einsum("nd,edf->enf", h, ex["wg"]))
+                       * jnp.einsum("nd,edf->enf", h, ex["wi"]), ex["wo"])
+        theirs = jnp.where(chosen[None] == first + jnp.arange(count)[:, None, None], weights[None], 0).sum(2)
+        out = (y * theirs[:, :, None]).sum(0)
+        return out if valid is None else out * valid[:, None]
+
+    @jax.jit
+    def both(h, ex):                                      # one program a case: the routing, both sides
+        chosen, weights = he.route(h, gate, bias, k)
+        h, ex = jax.tree.map(lambda a: a.astype(dtype), (h, ex))           # as stored
+        got = he.held_experts_ffn(h, chosen, weights, ex, first, count, valid=valid, tm=tm)
+        want = by_every_expert(*jax.tree.map(lambda a: a.astype(jnp.float32), (h, ex)), chosen, weights)
+        return got, want, chosen
+
+    (out, counts), want, chosen = both(h, ex)
+    assert out.dtype == dtype and out.shape == (n, d)
+    # bfloat16: each of the k rows is rounded once where the buffer stores it, the sum once more
+    atol = 2e-5 if dtype == jnp.float32 else 2.0 ** -7 * float(jnp.abs(want).max())
+    assert np.abs(np.asarray(out, np.float32) - np.asarray(want)).max() <= atol
+    chosen = np.asarray(chosen)
+    seen = chosen[np.ones(n, bool) if valid is None else np.asarray(valid)]
+    assert list(np.asarray(counts)) == [(seen == e).sum() for e in range(first, first + count)]
+    if masked:
+        assert not np.asarray(out[~valid], np.float32).any()            # exactly 0, not a rounding
+    if held == "half":
+        # tokens none of whose experts are held: every one of their k assignments adds exactly 0
+        away = ~((chosen >= first) & (chosen < first + count)).any(1)
+        assert not np.asarray(out, np.float32)[away].any()
+
+
+def test_a_row_of_an_unused_tile_adds_nothing_whatever_it_holds():
+    """Rows past the tiles in use hold whatever the kernel left there; ``dest`` names none of them
+    for a held assignment, and a not-held one (``dest == M``) is clipped onto the LAST row, which
+    may hold anything: NaN there must not reach a token."""
+    y = jnp.full((24, 8), jnp.nan, jnp.float32).at[:3].set(jnp.arange(24.0).reshape(3, 8))
+    dest = jnp.asarray([[0, 24, 24], [24, 1, 2], [24, 24, 24]], jnp.int32)
+    w = jnp.asarray([[0.5, 0.3, 0.2], [0.1, 0.6, 0.3], [0.2, 0.3, 0.5]], jnp.float32)
+    out = np.asarray(he._combine(y, dest, w))
+    assert out.dtype == np.float32 and np.array_equal(out[2], np.zeros(8))
+    assert np.allclose(out[0], 0.5 * np.arange(8)) and np.allclose(
+        out[1], 0.6 * np.arange(8, 16) + 0.3 * np.arange(16, 24))
+
+
 # ---- the training path: a bucket of the sorted buffer, the whole buffer as the fallback ----------
 
 BN, BK, BFIRST, BCOUNT, BTM = 64, 2, 4, 2, 8   # bucket 48 rows (six tiles), whole buffer 144
@@ -241,9 +306,10 @@ def test_a_bucket_no_smaller_than_the_buffer_is_one_branch_and_a_tick_is_the_pro
 
 
 # sha256 of the serving form's lowered text (a layer of a stack read by the kernel, a ``valid``
-# mask), recorded on the parent of the PR that brought the bucket (0c30f23): a tick's layer is that
-# program still, to the text
-PARENTS_TICK = "cda949af33ed8e4cdbe5e06667a44be5c5007f27f340e718481f5e2593f5c902"
+# mask): what the training path's PRs must leave alone. Recorded in PR 52, which meant to change
+# it (the way back over a leading assignment axis, ``_combine``); with the parent's three lines in
+# ``_combine``'s place it was the text of 0c30f23, the parent of the PR that brought the bucket
+PARENTS_TICK = "8667aea09e4fd19317f421599209fa8f9ad1d5128428918dc2cce1f40eb7ddc2"
 
 
 def tick_text():

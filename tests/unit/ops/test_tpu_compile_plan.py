@@ -175,6 +175,35 @@ def test_held_experts_layer_compiles_at_the_published_widths(topo, tokens):
     assert compiled.memory_analysis().temp_size_in_bytes < 200e6
 
 
+@pytest.mark.parametrize("N,k,D,F,E,count,gated", [
+    (1056, 10, 4096, 768, 72, 36, True), (640, 22, 1024, 2688, 512, 128, False),
+    (1056, 4, 2048, 1536, 64, 64, True)], ids=["granite-top10", "nemotron-top22-latent", "glm-top4"])
+def test_the_serving_expert_layers_way_back_never_lays_top_k_on_the_sublanes(topo, N, k, D, F, E, count, gated):
+    """A fused tick's expert layer at the cells' shapes: no ``reshape`` or ``copy`` of the gathered
+    rows as a rank-3 array (with k second-minor the chip pads top-10 to 16 in a copy of it all:
+    0.68 ms a Granite layer and as much again around it, PERF.md section 6, PR 52), and the way back alone
+    (``held_experts._combine``) needs less scratch than the gather's own output."""
+    from deepspeed_tpu.moe import held_experts as he
+
+    def layer(h, gate, bias, valid, *stacks):
+        chosen, weights = he.route(h, gate, bias, k)
+        experts = dict(zip(("wg", "wi", "wo") if gated else ("wi", "wo"), stacks))
+        return he.held_experts_ffn(h, chosen, weights, experts, 0, count, valid=valid, layer=jnp.int32(1))
+
+    bf = jnp.bfloat16
+    stacks = [((2, count, D, F), bf)] * (2 if gated else 1) + [((2, count, F, D), bf)]
+    text = _compile(topo, layer, ((N, D), bf), ((D, E), bf), ((E,), bf), ((N,), jnp.bool_), *stacks).as_text()
+    gathered = [m.group(0) for m in re.finditer(
+        r"= \w+\[(\d+),(\d+),(\d+)\]\{[^}]*\} (?:reshape|copy)\(", text)
+        if sorted(map(int, m.groups())) == sorted((N, k, D))]
+    assert not gathered, gathered
+    M = he.buffer_rows(N, k, count, he.row_tile(N, k))
+    sh = SingleDeviceSharding(topo.devices[0])
+    alone = jax.jit(he._combine).lower(*[jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in (
+        ((M, D), bf), ((N, k), jnp.int32), ((N, k), jnp.float32))]).compile()
+    assert alone.memory_analysis().temp_size_in_bytes < N * k * D * 2, alone.memory_analysis()
+
+
 def test_the_training_expert_layer_compiles_a_bucket_and_the_whole_buffer(topo):
     """The LFM2 training cell's expert layer (16,384 tokens, top-4 of 64 with 8 held, 2,048 x
     1,536), forward and gradient under a checkpoint: one conditional with two branches each way,
