@@ -477,8 +477,17 @@ class ContinuousBatchingEngine:
         self._tick_stats = {"ticks": 0, "steps": 0, "dispatch_ms": 0.0,
                             "block_ms": 0.0, "tokens": 0, "wasted_tokens": 0,
                             "capacity_tokens": 0, "fused_prefill_ticks": 0,
-                            "max_inflight": 0, "spec_drafted": 0,
-                            "spec_accepted": 0,
+                            "spec_drafted": 0, "spec_accepted": 0,
+                            # the host ledger's rows that belong to the
+                            # tick loop (docs/telemetry.md "The serving
+                            # loop's ledger"): the admission loop inside
+                            # dispatch_ms, _retire after its fetch, the
+                            # wall time with no tick in flight, and the
+                            # ticks whose result was on hand before the
+                            # host asked for it
+                            "admit_ms": 0.0, "attribute_ms": 0.0,
+                            "inflight_empty_ms": 0.0,
+                            "ticks_ready_at_retire": 0,
                             # the two kinds of tick told apart (counted at
                             # dispatch like ``ticks``; blocked ms charged
                             # where each tick retires, by its own kind)
@@ -606,6 +615,13 @@ class ContinuousBatchingEngine:
         # rebuilds instead (bitwise-safe: see docs/serving.md recovery)
         self.poisoned = False
         self._tick_index = 0  # step() calls attempted (fault-plan clock)
+        # the host ledger (tick_stats() ``inflight_empty_ms``): since when
+        # no tick has been in flight — stamped at the fetch that emptied
+        # ``_inflight``, closed by the dispatch that fills it again — and
+        # what the last step() charged to dispatch, block and attribution
+        # together (the serving layer takes it off its own step's wall)
+        self._inflight_empty_t = self._fetched_t = time.perf_counter()
+        self.last_step_ms = 0.0
         # one memory_snapshot per engine generation: the live ops plane's
         # HBM attribution baseline (serving recovery emits the "rebuild"
         # one after re-injecting its hub into a replacement engine); the
@@ -1010,15 +1026,17 @@ class ContinuousBatchingEngine:
         discarded. The engine stays ``poisoned``-marked territory: only
         call this when the engine is being abandoned."""
         lost = len(self._inflight)
+        if lost:
+            self._inflight_empty_t = time.perf_counter()
         self._inflight.clear()
         return lost
 
     def tick_stats(self) -> dict:
         """Host-overhead accounting for the tick loop: dispatch vs blocked
-        milliseconds, tokens emitted / wasted past done flags, pipeline
-        depth actually reached. ``overlap_frac`` is the fraction of
-        host-side tick-loop time NOT spent blocked on device results
-        (1.0 = the device never made the host wait); ``block_ms_per_token``
+        milliseconds, tokens emitted / wasted past done flags.
+        ``overlap_frac`` is the fraction of host-side tick-loop time NOT
+        spent blocked on device results (1.0 = the device never made the
+        host wait); ``block_ms_per_token``
         is the loadgen A/B headline — host-blocked ms per decoded token.
         Tick kinds: ``plain_ticks + fused_prefill_ticks == ticks`` and
         ``block_ms_plain + block_ms_fused == block_ms`` (each retired
@@ -1043,8 +1061,15 @@ class ContinuousBatchingEngine:
         ``loop_kv_positions_read``, the positions the rows' attention
         fetched (every slot to the tick's read bucket, a step) and
         ``loop_kv_positions_live``, those the live rows hold; and
-        ``kv_pool_bytes``, the pools as allocated, all passes' layer-steps."""
+        ``kv_pool_bytes``, the pools as allocated, all passes' layer-steps.
+        The host ledger's rows of the tick loop (docs/telemetry.md "The
+        serving loop's ledger"): ``admit_ms``, the admission loop (inside
+        ``dispatch_ms``); ``attribute_ms``, ``_retire`` after its fetch
+        returned; ``inflight_empty_ms``, the wall time with no tick in
+        flight, up to this read; ``ticks_ready_at_retire``, ticks whose
+        result was on hand before the host came to fetch it."""
         s = dict(self._tick_stats)
+        s["inflight_empty_ms"] = self.inflight_empty_ms()
         s["pipeline_depth"] = self.pipeline_depth
         # NOT the tokens_per_tick knob (the burst width): the observed mean
         s["mean_emitted_per_tick"] = (round(s["tokens"] / s["ticks"], 3)
@@ -1068,6 +1093,14 @@ class ContinuousBatchingEngine:
         if self._loop_steps > 1:
             s["kv_pool_bytes"] = self.kv_cache_bytes()
         return s
+
+    def inflight_empty_ms(self) -> float:
+        """Wall time so far with no tick in flight, the open stretch
+        counted up to this read."""
+        ms = self._tick_stats["inflight_empty_ms"]
+        if not self._inflight:
+            ms += (time.perf_counter() - self._inflight_empty_t) * 1000.0  # ds-lint: disable=unsynced-timing
+        return ms
 
     def _place(self, req: _Request) -> Optional[tuple]:
         """(pool_index, slot) in the smallest-length pool that fits the
@@ -1124,6 +1157,7 @@ class ContinuousBatchingEngine:
                     still_pending.append(req)
                     continue
                 self._admit(req, *placed)
+        t_admitted = time.perf_counter()
         self._pending = still_pending
         stats = self._tick_stats
         stats["prefill_q_depth_sum"] += sum(len(p.prefill_q) for p in self._pools)
@@ -1139,7 +1173,10 @@ class ContinuousBatchingEngine:
         # the dispatch span is INTENTIONALLY unsynced: it measures host
         # enqueue work while the device runs ahead (the whole point of the
         # overlap); the block span in _retire ends at a real host fetch
-        dispatch_ms = (time.perf_counter() - t0) * 1000.0  # ds-lint: disable=unsynced-timing
+        t_dispatched = time.perf_counter()
+        dispatch_ms = (t_dispatched - t0) * 1000.0  # ds-lint: disable=unsynced-timing
+        # (the ledger's rows are the HOST's wall time on purpose, like dispatch_ms)
+        admit_ms = (t_admitted - t0) * 1000.0  # ds-lint: disable=unsynced-timing
         if recs:
             if self.span_hook is not None:
                 # window-span clock zero for this tick's records: one
@@ -1147,6 +1184,11 @@ class ContinuousBatchingEngine:
                 t_disp = time.monotonic()
                 for r in recs.values():
                     r.t0 = t_disp
+            if not self._inflight:
+                # the chip has work again: the stretch with nothing in
+                # flight ends on the clock read that closed the dispatch
+                since = self._inflight_empty_t
+                stats["inflight_empty_ms"] += (t_dispatched - since) * 1000.0
             self._inflight.append(recs)
         stats["steps"] += 1
         stats["ticks"] += len(recs)
@@ -1159,7 +1201,7 @@ class ContinuousBatchingEngine:
         stats["fused_prefill_ticks"] += n_fused
         stats["plain_ticks"] += len(recs) - n_fused
         stats["dispatch_ms"] += dispatch_ms
-        stats["max_inflight"] = max(stats["max_inflight"], len(self._inflight))
+        stats["admit_ms"] += admit_ms
 
         # retire down to the pipeline depth; when nothing new was
         # dispatched, the remaining in-flight ticks are the drain tail
@@ -1167,10 +1209,15 @@ class ContinuousBatchingEngine:
         tokens0, wasted0 = stats["tokens"], stats["wasted_tokens"]
         plan0 = {name: stats[name] for name in _PLAN_TICK_FIELDS if name in stats}
         drafted0, accepted0 = stats["spec_drafted"], stats["spec_accepted"]
+        attributed0 = stats["attribute_ms"]
         while self._inflight and (len(self._inflight) > self.pipeline_depth
                                   or not recs):
             block_ms += self._retire(self._inflight.popleft(), emitted)
+            if not self._inflight:
+                self._inflight_empty_t = self._fetched_t  # nothing in flight since that fetch returned
         stats["block_ms"] += block_ms
+        attribute_ms = stats["attribute_ms"] - attributed0
+        self.last_step_ms = dispatch_ms + block_ms + attribute_ms
 
         tele = self._eng.telemetry
         if tele.enabled:
@@ -1537,10 +1584,14 @@ class ContinuousBatchingEngine:
             if self.fault_hook is not None:
                 self.fault_hook("retire", {"tick": self._tick_index,
                                            "pool": pi})
+            # the result on hand before the host asks for it: the host, not
+            # the device, set this tick's pace (one non-blocking query)
+            stats["ticks_ready_at_retire"] += rec.packed.is_ready()
             t0 = time.perf_counter()
             with host_span("tick.retire"):
                 arr = np.asarray(rec.packed)  # the single device get per tick
-            dt = time.perf_counter() - t0
+            self._fetched_t = t1 = time.perf_counter()
+            dt = t1 - t0
             if self.fetch_timeout_s is not None and dt > self.fetch_timeout_s:
                 # post-hoc watchdog: the fetch DID return, but far past
                 # budget — on a preempted/unhealthy device the next one
@@ -1553,90 +1604,99 @@ class ContinuousBatchingEngine:
                     f"unhealthy, tick pipeline abandoned")
             block_ms += dt * 1000.0
             stats["block_ms_fused" if rec.fused else "block_ms_plain"] += dt * 1000.0
-            k = rec.k
-            g = rec.spec
-            if self._moe_stats:
-                made, held, most, layers, hit = (
-                    int(v) for v in arr[0, k + 2:k + 2 + TICK_STATS])
-                stats["moe_experts_hit"] += hit
-                stats["moe_expert_layers"] += layers   # expert layers, which need not be all the layers
-                mean = held / max(1, layers * self.cfg.held_experts[1])
-                stats["moe_ticks"] += 1
-                stats["moe_assignments"] += made
-                stats["moe_held_assignments"] += held
-                stats["moe_expert_tokens_most_sum"] += most
-                stats["moe_expert_tokens_mean_sum"] += mean
-                if held:
-                    stats["moe_imbalance_sum"] += most / mean
-                at = k + 2 + TICK_STATS
-                if self._state_pool:
-                    for i, name in enumerate(self._state_counters):
-                        stats[name] += int(arr[0, at + i])
-                    at += len(self._state_counters)
-                if self._row_stats:   # a row an assignment filled is an assignment to a held expert
-                    stats["moe_buffer_rows"] += int(arr[0, at])
-                    stats["moe_filled_rows"] += held
-            hook = self.span_hook
-            if hook is not None:
-                t_ret = time.monotonic()
-                tick_kind = ("spec_verify_round" if g else
-                             "prefill_chunk" if rec.fused else "decode_window")
-            for slot, req in rec.live.items():
-                if pool.active.get(slot) is not req:
-                    # cancelled / already finished while this tick was in
-                    # flight: the whole row-tick computed past the done
-                    # flag — that IS the pipelining waste, count it
-                    stats["wasted_tokens"] += k
-                    continue
-                n = int(arr[slot, k])
-                stats["tokens"] += n
-                stats["wasted_tokens"] += k - n
-                if g:
-                    accepted = int(arr[slot, g + 3])
-                    stats["spec_drafted"] += g
-                    stats["spec_accepted"] += accepted
-                    req.spec_drafted += g
-                    req.spec_accepted += accepted
-                    # reconcile the dispatch mirrors: the round really
-                    # advanced pos by accepted+1 (the mirror assumed
-                    # gamma+1) and emitted n (the mirror assumed 1)
-                    pool.disp_pos[slot] -= g - accepted
-                    pool.disp_gen[slot] += n - 1
-                    # rec.row_bytes is the WHOLE round's streamed bytes
-                    # (one gamma+1-wide target window + the draft steps)
-                    req.kv_bytes_read += rec.row_bytes
-                else:
-                    # the row STREAMED k read windows whether or not it
-                    # accepted all k tokens (burst tails past done are
-                    # wasted work, not free work) — kv_bytes_read reports
-                    # physical HBM traffic
-                    req.kv_bytes_read += k * rec.row_bytes
-                if hook is not None:
-                    # coalesce this retired tick into the request's open
-                    # window (flush on kind change / window cap; _finish
-                    # flushes the tail) — pure host arithmetic on values
-                    # the attribution above already fetched
-                    if req.win_kind is not None and req.win_kind != tick_kind:
-                        self._flush_window(req)
-                    if req.win_kind is None:
-                        req.win_kind = tick_kind
-                        req.win_t0 = rec.t0
-                    req.win_t1 = t_ret
-                    req.win_ticks += 1
-                    req.win_tokens += n
-                    if g:
-                        req.win_drafted += g
-                        req.win_accepted += accepted
-                    if req.win_ticks >= self.span_window_ticks:
-                        self._flush_window(req)
-                if n:
-                    toks = [int(t) for t in arr[slot, :n]]
-                    req.generated.extend(toks)
-                    emitted.setdefault(req.rid, []).extend(toks)
-                if arr[slot, k + 1]:
-                    req.done = True
-                    self._finish(pool, slot)
+            with host_span("tick.attribute"):
+                self._attribute(pool, rec, arr, emitted)
+            stats["attribute_ms"] += (time.perf_counter() - t1) * 1000.0  # ds-lint: disable=unsynced-timing
         return block_ms
+
+    def _attribute(self, pool: _Pool, rec: _TickRecord, arr: np.ndarray,
+                   emitted: Dict[int, List[int]]):
+        """The host's work on one fetched tick: its counters, each live
+        row's tokens, finished rows (``tick_stats()`` ``attribute_ms``)."""
+        stats = self._tick_stats
+        k = rec.k
+        g = rec.spec
+        if self._moe_stats:
+            made, held, most, layers, hit = (
+                int(v) for v in arr[0, k + 2:k + 2 + TICK_STATS])
+            stats["moe_experts_hit"] += hit
+            stats["moe_expert_layers"] += layers   # expert layers, which need not be all the layers
+            mean = held / max(1, layers * self.cfg.held_experts[1])
+            stats["moe_ticks"] += 1
+            stats["moe_assignments"] += made
+            stats["moe_held_assignments"] += held
+            stats["moe_expert_tokens_most_sum"] += most
+            stats["moe_expert_tokens_mean_sum"] += mean
+            if held:
+                stats["moe_imbalance_sum"] += most / mean
+            at = k + 2 + TICK_STATS
+            if self._state_pool:
+                for i, name in enumerate(self._state_counters):
+                    stats[name] += int(arr[0, at + i])
+                at += len(self._state_counters)
+            if self._row_stats:   # a row an assignment filled is an assignment to a held expert
+                stats["moe_buffer_rows"] += int(arr[0, at])
+                stats["moe_filled_rows"] += held
+        hook = self.span_hook
+        if hook is not None:
+            t_ret = time.monotonic()
+            tick_kind = ("spec_verify_round" if g else
+                         "prefill_chunk" if rec.fused else "decode_window")
+        for slot, req in rec.live.items():
+            if pool.active.get(slot) is not req:
+                # cancelled / already finished while this tick was in
+                # flight: the whole row-tick computed past the done
+                # flag — that IS the pipelining waste, count it
+                stats["wasted_tokens"] += k
+                continue
+            n = int(arr[slot, k])
+            stats["tokens"] += n
+            stats["wasted_tokens"] += k - n
+            if g:
+                accepted = int(arr[slot, g + 3])
+                stats["spec_drafted"] += g
+                stats["spec_accepted"] += accepted
+                req.spec_drafted += g
+                req.spec_accepted += accepted
+                # reconcile the dispatch mirrors: the round really
+                # advanced pos by accepted+1 (the mirror assumed
+                # gamma+1) and emitted n (the mirror assumed 1)
+                pool.disp_pos[slot] -= g - accepted
+                pool.disp_gen[slot] += n - 1
+                # rec.row_bytes is the WHOLE round's streamed bytes
+                # (one gamma+1-wide target window + the draft steps)
+                req.kv_bytes_read += rec.row_bytes
+            else:
+                # the row STREAMED k read windows whether or not it
+                # accepted all k tokens (burst tails past done are
+                # wasted work, not free work) — kv_bytes_read reports
+                # physical HBM traffic
+                req.kv_bytes_read += k * rec.row_bytes
+            if hook is not None:
+                # coalesce this retired tick into the request's open
+                # window (flush on kind change / window cap; _finish
+                # flushes the tail) — pure host arithmetic on values
+                # the attribution above already fetched
+                if req.win_kind is not None and req.win_kind != tick_kind:
+                    self._flush_window(req)
+                if req.win_kind is None:
+                    req.win_kind = tick_kind
+                    req.win_t0 = rec.t0
+                req.win_t1 = t_ret
+                req.win_ticks += 1
+                req.win_tokens += n
+                if g:
+                    req.win_drafted += g
+                    req.win_accepted += accepted
+                if req.win_ticks >= self.span_window_ticks:
+                    self._flush_window(req)
+            if n:
+                toks = [int(t) for t in arr[slot, :n]]
+                req.generated.extend(toks)
+                emitted.setdefault(req.rid, []).extend(toks)
+            if arr[slot, k + 1]:
+                req.done = True
+                self._finish(pool, slot)
 
     def _flush_window(self, req: "_Request"):
         """Emit the request's open tick window through ``span_hook`` and

@@ -93,7 +93,7 @@ from deepspeed_tpu.serving.request import (
     Admission,
     ServeRequest,
 )
-from deepspeed_tpu.telemetry.spans import SpanEmitter, host_span
+from deepspeed_tpu.telemetry.spans import SpanEmitter, host_mark, host_span
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -264,6 +264,23 @@ class ServingEngine:
         self._span_sampler = span_sampler
         self._spans = SpanEmitter(self._tele, clock=clock)
         self._drain_t0: Optional[float] = None  # drain() start, for drain_wait
+        # -- the host ledger (docs/telemetry.md "The serving loop's ledger") --
+        # Every millisecond of this server's wall time in exactly one row of
+        # tick_stats(), always on. The rows kept here are sums of differences
+        # of clock reads, each boundary ONE read shared by the two rows it
+        # parts; the tick loop's rows (dispatch_ms, block_ms, attribute_ms)
+        # are the batcher's. ``_outside`` is the stretch open outside step():
+        # (its start — a step()'s return, or the submit that ended an
+        # emptiness —, whether it is an emptiness: no request queued, pending,
+        # in a slot or in flight; else the caller's time with one held), and
+        # None while a step() runs. One attribute, so a reader on another
+        # thread sees both halves of one boundary.
+        self._ledger = {"empty_ms": 0.0, "schedule_ms": 0.0, "emit_ms": 0.0,
+                        "step_other_ms": 0.0, "between_steps_ms": 0.0}
+        self._outside: Optional[tuple] = (self._created, True)
+        # starved_ms counts from here: the batcher's stretch with nothing in
+        # flight is older than this server (precompile, the caller's set-up)
+        self._inflight_empty_base = engine.inflight_empty_ms()
         if self._tele.enabled:
             engine.span_hook = self._span_hook
 
@@ -332,6 +349,8 @@ class ServingEngine:
             # migration still land on the SAME trace
             req.trace_id = f"{self._trace_scope()}{rid}"
         self._requests[rid] = req
+        if self._outside is not None and self._outside[1]:
+            self._refill(now)
         # empty queue + a fitting free slot: hand straight to the engine —
         # the strongest statement submit can truthfully make (with a
         # non-empty queue the policy decides, so the verdict is "queued")
@@ -348,58 +367,118 @@ class ServingEngine:
         """One serving tick: expire deadline-blown queued work, place
         queued requests into free slots in policy order (bounded by the
         aging barrier), then one engine tick. Returns {rid: [tokens]}
-        emitted this tick, keyed by SERVING rid."""
-        now = self._clock()
+        emitted this tick, keyed by SERVING rid.
+
+        The host ledger's boundaries are this method's four clock reads:
+        entry (the stretch outside ends), after scheduling, the tick's
+        return, and its own return (the next stretch outside starts). A
+        step() that raises leaves its time to the stretch outside."""
+        with host_span("serve.step"):
+            now = self._clock()
+            led = self._ledger
+            since, empty = self._outside
+            led["empty_ms" if empty else "between_steps_ms"] += (now - since) * 1000.0
+            self._outside = None
+            try:
+                out, t_end = self._step(now)
+            except BaseException:
+                self._outside = (now, False)
+                raise
+            idle = not self.has_work()
+            self._outside = (t_end, idle)
+            if idle:
+                host_mark("serve.emptied")
+        return out
+
+    def _step(self, now: float):
+        """``step()`` between its first clock read and its last, which it
+        returns beside the tokens: the ledger's rows of one step."""
+        led = self._ledger
         with host_span("serve.schedule"):
             self._expire(now)
             self._schedule(now)
-        out: Dict[int, List[int]] = {}
-        if self._cb.has_work():
+        t_tick = tnow = self._clock()
+        led["schedule_ms"] += (t_tick - now) * 1000.0
+        tick_ms = 0.0   # what the batcher's own rows hold of this step
+        ran = self._cb.has_work()
+        if ran:
             emitted, ticked = self._guarded_tick()
-            if ticked:
-                # the engine admits every placeable pending request at the
-                # top of its tick, and we only hand over what fits — so
-                # after the tick the staged reservations are real slots
-                # (pool_state now counts them) or already finished-and-
-                # freed. A recovered (re-admitted) tick keeps its staged
-                # reservations: the rebuilt engine has not ticked yet.
-                self._staged.clear()
             tnow = self._clock()
-            for erid, toks in emitted.items():
-                req = self._running.get(erid)
-                if req is None:
-                    continue  # not ours (direct engine.submit user)
-                if req.first_token_t is None and toks:
-                    req.first_token_t = tnow
-                req.tokens.extend(toks)
-                self._recovery_log.extend(req.rid, toks)
-                out[req.rid] = list(toks)
-                if req.on_token is not None:
-                    for tok in toks:
-                        req.on_token(req.rid, tok)
-            for erid, result in self._cb.finished().items():
-                req = self._running.pop(erid, None)
-                if req is None:
-                    continue
-                self._finish_request(req, result, tnow)
-            if ticked and self._tele.enabled:
-                s = self._cb.tick_stats()
-                if s.get("spec_drafted"):
-                    # live acceptance rate for /metrics + /statusz: the
-                    # one number that says whether speculation is earning
-                    # its verify FLOPs right now
-                    self._tele.registry.gauge("serve_spec_acceptance").set(
-                        round(s["spec_accepted"] / s["spec_drafted"], 4))
-        if (self._drain_t0 is not None and self._draining
-                and not self.has_work()):
-            # the drain completed this tick: close the ops-scoped
-            # drain_wait span (how long removal-from-rotation stalled on
-            # in-flight work)
-            self._spans.emit("drain_wait", f"{self._trace_scope()}ops",
-                             self._drain_t0, self._clock())
-            self._drain_t0 = None
-        self._update_gauges()
+            if ticked:
+                tick_ms = self._cb.last_step_ms
+        with host_span("serve.emit"):
+            out = self._fan_out(emitted, ticked, tnow) if ran else {}
+            if (self._drain_t0 is not None and self._draining
+                    and not self.has_work()):
+                # the drain completed this tick: close the ops-scoped
+                # drain_wait span (how long removal-from-rotation stalled on
+                # in-flight work)
+                self._spans.emit("drain_wait", f"{self._trace_scope()}ops",
+                                 self._drain_t0, self._clock())
+                self._drain_t0 = None
+            self._update_gauges()
+        t_end = self._clock()
+        led["emit_ms"] += (t_end - tnow) * 1000.0
+        led["step_other_ms"] += (tnow - t_tick) * 1000.0 - tick_ms
+        return out, t_end
+
+    def _fan_out(self, emitted, ticked: bool, tnow: float) -> Dict[int, List[int]]:
+        """A tick's tokens to their requests, and the finished ones retired."""
+        out: Dict[int, List[int]] = {}
+        if ticked:
+            # the engine admits every placeable pending request at the
+            # top of its tick, and we only hand over what fits — so
+            # after the tick the staged reservations are real slots
+            # (pool_state now counts them) or already finished-and-
+            # freed. A recovered (re-admitted) tick keeps its staged
+            # reservations: the rebuilt engine has not ticked yet.
+            self._staged.clear()
+        for erid, toks in emitted.items():
+            req = self._running.get(erid)
+            if req is None:
+                continue  # not ours (direct engine.submit user)
+            if req.first_token_t is None and toks:
+                req.first_token_t = tnow
+            req.tokens.extend(toks)
+            self._recovery_log.extend(req.rid, toks)
+            out[req.rid] = list(toks)
+            if req.on_token is not None:
+                for tok in toks:
+                    req.on_token(req.rid, tok)
+        for erid, result in self._cb.finished().items():
+            req = self._running.pop(erid, None)
+            if req is None:
+                continue
+            self._finish_request(req, result, tnow)
+        if ticked and self._tele.enabled:
+            s = self._cb.tick_stats()
+            if s.get("spec_drafted"):
+                # live acceptance rate for /metrics + /statusz: the
+                # one number that says whether speculation is earning
+                # its verify FLOPs right now
+                self._tele.registry.gauge("serve_spec_acceptance").set(
+                    round(s["spec_accepted"] / s["spec_drafted"], 4))
         return out
+
+    def _refill(self, now: float):
+        """An emptiness ends at ``now``: the stretch since the step() that
+        left the server holding nothing goes to ``empty_ms``, and the
+        caller's time with a request held starts."""
+        self._ledger["empty_ms"] += (now - self._outside[0]) * 1000.0
+        self._outside = (now, False)
+        host_mark("serve.refilled")
+
+    def _settle(self):
+        """A request left outside step() (cancelled, released, abandoned, a
+        resume the engine refused): if the server now holds nothing, the
+        caller's stretch ends here and an emptiness starts."""
+        outside = self._outside
+        if outside is None or outside[1] or self.has_work():
+            return
+        now = self._clock()
+        self._ledger["between_steps_ms"] += (now - outside[0]) * 1000.0
+        self._outside = (now, True)
+        host_mark("serve.emptied")
 
     def _finish_request(self, req: ServeRequest, result, now: float):
         """The ONE FINISHED transition (normal retirement and recovered-
@@ -670,6 +749,10 @@ class ServingEngine:
         # commit: the one multi-step mutation a scrape must never observe
         # half-done (the _ops_lock read/swap discipline)
         with self._ops_lock:
+            # starved_ms goes on where it stood: the lost engine's stretch
+            # with nothing in flight (the rebuild is part of it) carries over
+            carried = self._cb.inflight_empty_ms() - self._inflight_empty_base
+            self._inflight_empty_base = new.inflight_empty_ms() - carried
             self._cb = new
             self._prefix_pids = prefix_pids
             self._running = running
@@ -1007,11 +1090,34 @@ class ServingEngine:
         where each ticked pool contributes slots × burst). This is the
         in-process view of what ``ds_trace_report --serve`` computes from
         ``serving_tick`` trace events, and what ``ds_loadgen``'s
-        ``--pipeline-depth`` A/B compares."""
+        ``--pipeline-depth`` A/B compares.
+
+        The host ledger (docs/telemetry.md "The serving loop's ledger"):
+        ``empty_ms + schedule_ms + dispatch_ms + block_ms + attribute_ms +
+        emit_ms + step_other_ms + between_steps_ms`` is the wall time
+        between two reads — ``empty_ms`` and ``between_steps_ms`` the time
+        outside ``step()`` with no request held and with one, the rest a
+        ``step()``'s (``step_other_ms`` its wall less the named rows: a
+        retry's backoff, a rebuild). A rebuilt engine starts its own rows
+        (``dispatch_ms``, ``block_ms``, ``attribute_ms``) at zero, as all its
+        counters. ``starved_ms``: a request was held and no tick was in
+        flight — what the host costs the chip."""
         with self._ops_lock:  # exporter-thread read discipline
             s = self._cb.tick_stats()
+            base = self._inflight_empty_base
+            ledger, outside = dict(self._ledger), self._outside
         cap = s.get("capacity_tokens", 0)
         s["utilization"] = round(s["tokens"] / cap, 4) if cap else 0.0
+        # the host ledger: this server's rows beside the batcher's, the
+        # stretch open outside step() counted up to this read
+        s.update(ledger)
+        if outside is not None:
+            since, empty = outside
+            s["empty_ms" if empty else "between_steps_ms"] += (
+                (self._clock() - since) * 1000.0)
+        # a request was held and the chip had nothing to run: the wall time
+        # with no tick in flight less the time with no request at all
+        s["starved_ms"] = max(0.0, s["inflight_empty_ms"] - base - s["empty_ms"])
         return s
 
     def status(self, rid: int) -> str:
@@ -1097,6 +1203,7 @@ class ServingEngine:
                 "tokens_emitted": len(req.tokens),
             })
         self._update_gauges()
+        self._settle()
         return True
 
     # -- fleet membership (serving/router.py) ---------------------------
@@ -1205,6 +1312,8 @@ class ServingEngine:
             # span parents on it, bridging the replicas in one timeline
             req.span_parent = parent_span
         self._requests[rid] = req
+        if self._outside is not None and self._outside[1]:
+            self._refill(now)
         try:
             if not self._queue and self._fits_now(need):
                 self._handover(req, now)
@@ -1216,6 +1325,7 @@ class ServingEngine:
             # engine refused the resume (rid collision, degraded cache):
             # leave no state behind — the router tries the next survivor
             self._requests.pop(rid, None)
+            self._settle()
             raise
         self._update_gauges()
         return Admission(status=status, rid=rid)
@@ -1264,6 +1374,7 @@ class ServingEngine:
                 pass
         self._recovery_log.retire(rid)
         self._update_gauges()
+        self._settle()
         return req
 
     def abandon(self, detail: str) -> Dict[int, ServeRequest]:
@@ -1277,6 +1388,7 @@ class ServingEngine:
         for req in live:
             self._mark_lost(req, detail)
         self._update_gauges()
+        self._settle()
         return {r.rid: r for r in live}
 
     # -- internals ------------------------------------------------------

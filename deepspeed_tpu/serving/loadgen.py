@@ -323,6 +323,28 @@ def _pcts(vals: List[float]) -> dict:
     return {"p50": percentile(vals, 50.0), "p99": percentile(vals, 99.0)}
 
 
+# ``ServingEngine.tick_stats()``'s host ledger: the rows whose sum is the
+# server's wall time between two reads (docs/telemetry.md "The serving
+# loop's ledger"); ``admit_ms`` lies inside ``dispatch_ms``
+LEDGER_ROWS = ("empty_ms", "between_steps_ms", "schedule_ms", "dispatch_ms",
+               "block_ms", "attribute_ms", "emit_ms", "step_other_ms")
+
+
+def ledger_delta(stats1: dict, stats0: Optional[dict] = None) -> Optional[dict]:
+    """The host ledger between two ``tick_stats()`` reads (or since the
+    server was built): each row's ms, their sum ``wall_ms``, and
+    ``starved_ms``, ``admit_ms``, ``steps``, ``ticks``,
+    ``ticks_ready_at_retire`` over the same stretch. None where the
+    snapshot lacks the rows (a bare batcher's, an older program's)."""
+    keys = LEDGER_ROWS + ("starved_ms", "admit_ms", "steps", "ticks",
+                          "ticks_ready_at_retire")
+    if any(key not in stats1 for key in keys):
+        return None
+    out = {key: stats1[key] - (stats0[key] if stats0 else 0) for key in keys}
+    out["wall_ms"] = sum(out[key] for key in LEDGER_ROWS)
+    return out
+
+
 def host_overhead(tick_stats: dict) -> dict:
     """Host-overhead columns from a ``ServingEngine.tick_stats()`` (or the
     bare engine's) snapshot: mean dispatch vs blocked ms per scheduler
@@ -395,6 +417,16 @@ def host_overhead(tick_stats: dict) -> dict:
                                               if fused else None)
     if "utilization" in tick_stats:
         out["tick_utilization"] = tick_stats["utilization"]
+    # where the server's wall time went, as shares of it; how much of it the
+    # chip had nothing to run though a request was held; ticks the host paced
+    led = ledger_delta(tick_stats)
+    if led is not None and led["wall_ms"] > 0:
+        wall = led["wall_ms"]
+        out["ledger_wall_ms"] = round(wall, 3)
+        out["ledger_shares"] = {key[:-3]: round(led[key] / wall, 4) for key in LEDGER_ROWS}
+        out["starved_share"] = round(led["starved_ms"] / wall, 4)
+        out["host_bound_tick_share"] = (round(led["ticks_ready_at_retire"] / ticks, 4)
+                                        if ticks else None)
     if tick_stats.get("spec_gamma"):
         out["spec_gamma"] = tick_stats["spec_gamma"]
         out["spec_mode"] = tick_stats.get("spec_mode")
@@ -704,6 +736,15 @@ def format_summary(summary: dict) -> str:
                    if host.get("length_read_share") is not None else "")
                 + (f"   keys read ÷ live {host['row_keys_read_per_live']:.3f}"
                    if host.get("row_keys_read_per_live") is not None else ""))
+        if host.get("ledger_shares"):
+            lines.append(
+                "host ledger    "
+                + "  ".join(f"{name.replace('_', ' ')} {share:.1%}"
+                            for name, share in host["ledger_shares"].items())
+                + f"   of {host['ledger_wall_ms'] / 1000.0:.1f} s;   starved "
+                  f"{host['starved_share']:.2%}"
+                + (f"   ticks ready at retire {host['host_bound_tick_share']:.1%}"
+                   if host.get("host_bound_tick_share") is not None else ""))
         if host.get("prefill_tiles_per_chunk") is not None:
             lines.append(
                 f"prefill chunks {host['prefill_tiles_per_chunk']} score tiles/chunk over the "

@@ -53,6 +53,14 @@ def host_span(name: str):
     return _trace_annotation(HOST_SPAN_PREFIX + name)
 
 
+def host_mark(name: str):
+    """A zero-length ``dstpu:<name>`` annotation, as ``dstpu:clock_sync`` is:
+    an instant whose stretch no context manager can hold (the ends of a
+    server's emptiness, ``serve.emptied`` / ``serve.refilled``)."""
+    with host_span(name):
+        pass
+
+
 class SpanEmitter:
     """Emit closed spans for one scope through a telemetry hub.
 

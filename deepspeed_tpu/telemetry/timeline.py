@@ -20,6 +20,7 @@ tables live HERE for that reason; ``telemetry/spans.py`` (the write
 side) imports them from this module, never the reverse.
 """
 
+import heapq
 import json
 from typing import Dict, Iterable, List, Optional
 
@@ -360,6 +361,17 @@ def place_on_xplane(spans: Iterable["Span"], offset_ns: int) -> List[tuple]:
              int(round((s.t1 - s.t0) * 1e9))) for s in spans]
 
 
+def _merged(intervals) -> List[list]:
+    """Sorted, disjoint [start, end] lists covering the same instants."""
+    merged: List[list] = []
+    for s, e in sorted(i for i in intervals if i[1] > i[0]):
+        if merged and s <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], e)
+        else:
+            merged.append([s, e])
+    return merged
+
+
 def blame_idle_gaps(busy: List[tuple], host_events: List[tuple],
                     min_gap_ns: int = 1_000_000) -> List[dict]:
     """Every device-idle gap longer than ``min_gap_ns`` between the first
@@ -372,12 +384,7 @@ def blame_idle_gaps(busy: List[tuple], host_events: List[tuple],
     best one covers: a request-long ``decode_window`` covers every gap of
     its lifetime whole and says nothing, the 120 ms ``dstpu:`` span inside
     it that covers 98 % is what the host was doing."""
-    merged: List[list] = []
-    for s, e in sorted(b for b in busy if b[1] > b[0]):
-        if merged and s <= merged[-1][1]:
-            merged[-1][1] = max(merged[-1][1], e)
-        else:
-            merged.append([s, e])
+    merged = _merged(busy)
     rows = []
     for (_, g0), (g1, _) in zip(merged, merged[1:]):
         if g1 - g0 <= min_gap_ns:
@@ -390,3 +397,113 @@ def blame_idle_gaps(busy: List[tuple], host_events: List[tuple],
         rows.append({"start_ns": g0, "gap_ms": (g1 - g0) / 1e6, "span": blamed,
                      "covered": covered / (g1 - g0)})
     return rows
+
+
+# The serving loop's host spans (``spans.host_span`` sites of ``serving/
+# engine.py`` and ``inference/continuous.py``) under the names of the host
+# ledger's rows (``ServingEngine.tick_stats()``; docs/telemetry.md "The serving
+# loop's ledger"): the ledger's ``dispatch_ms`` is ``admit`` + ``dispatch``
+# here, a ``step()`` outside its inner spans is ``step_other``. Any other
+# ``dstpu:`` span is a phase under its own name.
+PHASE_OF_SPAN = {
+    "serve.step": "step_other",
+    "serve.schedule": "schedule",
+    "tick.admit": "admit",
+    "tick.dispatch.plain": "dispatch",
+    "tick.dispatch.fused": "dispatch",
+    "tick.retire": "block",
+    "tick.attribute": "attribute",
+    "serve.emit": "emit",
+}
+STEP_SPAN = HOST_SPAN_PREFIX + "serve.step"
+EMPTIED_MARK = HOST_SPAN_PREFIX + "serve.emptied"
+REFILLED_MARK = HOST_SPAN_PREFIX + "serve.refilled"
+EMPTY, BETWEEN_STEPS, NO_SPAN = "empty", "between_steps", "(no span)"
+
+
+def _empty_stretches(host_events, lo: int, hi: int) -> List[list]:
+    """[start, end] of the server's emptinesses inside [lo, hi], from the
+    ``serve.emptied`` / ``serve.refilled`` markers; a trace that opens on a
+    ``refilled`` began empty, one that closes on an ``emptied`` ends so."""
+    marks = sorted((s, name == EMPTIED_MARK) for name, s, _ in host_events
+                   if name in (EMPTIED_MARK, REFILLED_MARK))
+    out, since = [], (lo if marks and not marks[0][1] else None)
+    for t, emptied in marks:
+        if emptied:
+            since = t if since is None else since
+        elif since is not None:
+            out.append([since, t])
+            since = None
+    if since is not None:
+        out.append([since, hi])
+    return out
+
+
+def idle_by_phase(busy: List[tuple], host_events: List[tuple],
+                  window: Optional[tuple] = None) -> Dict[str, float]:
+    """Seconds of device-idle time by what the host was doing, SPLIT: each
+    idle nanosecond goes to the innermost ``dstpu:`` span that covers it
+    (``PHASE_OF_SPAN`` names the serving loop's; the latest to start wins
+    where spans nest), to ``empty`` between a ``serve.emptied`` and the next
+    ``serve.refilled`` marker, to ``between_steps`` outside every
+    ``serve.step`` span of a trace that holds one, and to ``(no span)``
+    otherwise. ``busy`` and ``host_events`` as for ``blame_idle_gaps``, which
+    awards a whole gap to one span and stays beside this for the question
+    "which span"; here the rows sum to the idle time. Idle is what ``busy``
+    leaves of ``window`` ((start_ns, end_ns); default: from the first busy
+    interval's start to the last one's end)."""
+    merged = _merged(busy)
+    if window is None:
+        if not merged:
+            return {}
+        window = (merged[0][0], merged[-1][1])
+    lo, hi = window
+    gaps, at = [], lo
+    for s, e in merged:
+        if s > at:
+            gaps.append((at, min(s, hi)))
+        at = max(at, e)
+        if at >= hi:
+            break
+    if at < hi:
+        gaps.append((at, hi))
+    gaps = [g for g in gaps if g[1] > g[0]]
+    if not gaps:
+        return {}
+
+    marks = (EMPTIED_MARK, REFILLED_MARK, CLOCK_SYNC_PREFIX)
+    spans = sorted((s, s + d, name) for name, s, d in host_events
+                   if d > 0 and not name.startswith(marks))
+    steps = _merged((s, e) for s, e, name in spans if name == STEP_SPAN)
+    # what an instant no span covers reads: empty, between two steps, or nothing known
+    ground = [(s, e, EMPTY) for s, e in _empty_stretches(host_events, lo, hi)]
+    if steps:
+        ground.append((min(lo, steps[0][0]), max(hi, steps[-1][1]), BETWEEN_STEPS))
+
+    cuts = sorted({lo, hi}
+                  | {t for s, e, _ in spans for t in (s, e) if lo < t < hi}
+                  | {t for s, e, _ in ground for t in (s, e) if lo < t < hi}
+                  | {t for g in gaps for t in g})
+    out: Dict[str, float] = {}
+    active: list = []          # (-start, end, name): the top is the innermost still open
+    si = gi = 0
+    for t0, t1 in zip(cuts, cuts[1:]):
+        while si < len(spans) and spans[si][0] <= t0:
+            s, e, name = spans[si]
+            heapq.heappush(active, (-s, e, name))
+            si += 1
+        while gi < len(gaps) and gaps[gi][1] <= t0:
+            gi += 1
+        if gi == len(gaps):
+            break
+        if not (gaps[gi][0] <= t0 and t1 <= gaps[gi][1]):
+            continue           # the device was busy
+        while active and active[0][1] <= t0:
+            heapq.heappop(active)
+        if active:
+            name = active[0][2][len(HOST_SPAN_PREFIX):]
+            phase = PHASE_OF_SPAN.get(name, name)
+        else:
+            phase = next((p for s, e, p in ground if s <= t0 and t1 <= e), NO_SPAN)
+        out[phase] = out.get(phase, 0.0) + (t1 - t0) / 1e9
+    return out

@@ -249,8 +249,11 @@ class TestTickTelemetry:
                                        "trace_file": str(trace)}})
         for p in prompts:
             cb.submit(p, max_new_tokens=12)
+        starved = []   # the wall time with no tick in flight, read while rows still decode
         while cb.has_work():
             cb.step()
+            if cb._inflight:
+                starved.append(cb.tick_stats()["inflight_empty_ms"])
         done = cb.finished()
         stats = cb.tick_stats()
         assert stats["ticks"] > 0 and stats["steps"] >= stats["ticks"]
@@ -258,7 +261,10 @@ class TestTickTelemetry:
             len(p) for p in prompts)
         assert stats["wasted_tokens"] > 0  # EOS mid-burst wastes burst tail
         assert stats["dispatch_ms"] > 0 and stats["block_ms"] >= 0
-        assert stats["pipeline_depth"] == 1 and stats["max_inflight"] >= 1
+        # pipelined: from the first dispatch to the last retire a tick was always in flight
+        assert stats["pipeline_depth"] == 1 and len(starved) > 1 and starved[-1] == starved[0]
+        assert stats["inflight_empty_ms"] > starved[-1] and stats["attribute_ms"] > 0
+        assert 0 < stats["admit_ms"] < stats["dispatch_ms"]
         assert 0.0 <= stats["overlap_frac"] <= 1.0
         assert stats["block_ms_per_token"] is not None
         reg = cb._eng.telemetry.registry.dump()
@@ -279,10 +285,12 @@ class TestTickTelemetry:
         return and results never lag."""
         cb = _cb(setup, max_slots=1, pipeline_depth=0)
         rid = cb.submit(_prompts((4,), 11)[0], max_new_tokens=3)
-        seen = 0
+        seen, starved = 0, [cb.tick_stats()["inflight_empty_ms"]]
         while cb.has_work():
             out = cb.step()
             seen += len(out.get(rid, []))
             assert not cb._inflight
+            starved.append(cb.tick_stats()["inflight_empty_ms"])
         assert seen == 3
-        assert cb.tick_stats()["max_inflight"] <= 1
+        # ... so the chip has nothing to run from each fetch to the next dispatch
+        assert all(b > a for a, b in zip(starved, starved[1:]))

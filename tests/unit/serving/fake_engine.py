@@ -46,6 +46,15 @@ class FakeEngine:
         # ServingEngine runs on, so span times share its domain.
         self.span_hook = None
         self.clock = clock
+        # the host ledger, mirrored: ``tick_cost`` = (dispatch_s, block_s,
+        # attribute_s) a tick, spent by calling ``advance`` (the injected
+        # clock's own) and charged to the rows the real batcher keeps; the
+        # fake "device" runs only while the host is blocked on it, so no tick
+        # is in flight the rest of the time
+        self.tick_cost = None
+        self.advance = None
+        self.last_step_ms = 0.0
+        self._created = clock()
         # request lifecycle, mirroring the real engine: ``submit``'s
         # ``on_prefill_start`` is called once, when the request's prefill
         # starts; ``prefill_wait_ticks`` > 0 holds an admitted request that
@@ -66,7 +75,8 @@ class FakeEngine:
         self._inflight = deque()
         self._tick_index = 0
         self._stats = {"ticks": 0, "steps": 0, "dispatch_ms": 0.0,
-                       "block_ms": 0.0, "tokens": 0, "wasted": 0,
+                       "block_ms": 0.0, "attribute_ms": 0.0, "admit_ms": 0.0,
+                       "ticks_ready_at_retire": 0, "tokens": 0, "wasted": 0,
                        "capacity_tokens": 0, "spec_drafted": 0,
                        "spec_accepted": 0}
         self._prefixes = {}
@@ -190,6 +200,11 @@ class FakeEngine:
             self._results[rid] = np.concatenate(
                 [req["prompt"], np.asarray(req["emitted"], np.int32)])
             self._emit_request_event(rid, req)
+        if self.tick_cost is not None:
+            for row, cost in zip(("dispatch_ms", "block_ms", "attribute_ms"), self.tick_cost):
+                self.advance(cost)
+                self._stats[row] += cost * 1000.0
+            self.last_step_ms = sum(self.tick_cost) * 1000.0
         self._stats["ticks"] += 1
         self._stats["steps"] += 1
         self._stats["tokens"] += sum(len(t) for t in out.values())
@@ -225,8 +240,12 @@ class FakeEngine:
         return 0
 
     # -- accounting -----------------------------------------------------
+    def inflight_empty_ms(self) -> float:
+        return (self.clock() - self._created) * 1000.0 - self._stats["block_ms"]
+
     def tick_stats(self) -> dict:
         s = dict(self._stats)
+        s["inflight_empty_ms"] = self.inflight_empty_ms()
         s["pipeline_depth"] = self.pipeline_depth
         s["mean_emitted_per_tick"] = (round(s["tokens"] / s["ticks"], 3)
                                       if s["ticks"] else 0.0)

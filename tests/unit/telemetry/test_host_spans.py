@@ -27,6 +27,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
 
 SERVE_SPANS = ("dstpu:serve.schedule", "dstpu:tick.admit", "dstpu:tick.dispatch.fused",
                "dstpu:tick.dispatch.plain", "dstpu:tick.retire")
+# since PR 53 a step() is tiled: the whole call, and inside it scheduling, the tick's four
+# phases and the fan-out; an emptiness's two ends are zero-length markers
+STEP_SPAN, INNER_SPANS = "dstpu:serve.step", SERVE_SPANS + ("dstpu:tick.attribute", "dstpu:serve.emit")
+MARKS = ("dstpu:serve.emptied", "dstpu:serve.refilled")
 TRAIN_SPANS = ("dstpu:train.next_batch", "dstpu:train.micro_dispatch",
                "dstpu:train.apply_dispatch", "dstpu:train.loss_fetch")
 
@@ -98,6 +102,80 @@ def test_serving_loop_spans_are_in_the_xplane(serve_capture):
     # prompts of 20, 5 and 9 tokens in 16-wide chunks: 2 + 1 + 1 fused dispatches
     assert len(events["dstpu:tick.dispatch.fused"]) == 4
     assert 0 < len(events["dstpu:tick.retire"]) <= dispatched
+
+
+def test_the_spans_tile_a_step_and_markers_end_an_emptiness(serve_capture):
+    events = serve_capture["events"]
+    steps = sorted(events[STEP_SPAN])
+    assert len(steps) == len(events["dstpu:serve.schedule"]) == len(events["dstpu:serve.emit"])
+    assert len(events["dstpu:tick.attribute"]) == len(events["dstpu:tick.retire"])
+    inner = sorted((s, s + d) for name in INNER_SPANS for s, d in events[name])
+    assert all(a[1] <= b[0] for a, b in zip(inner, inner[1:]))           # one after the other
+    covered = 0
+    for s0, d in steps:                                                  # each inside ONE step
+        mine = [(a, b) for a, b in inner if s0 <= a and b <= s0 + d]
+        covered += sum(b - a for a, b in mine)
+        assert mine[0][0] - s0 < 0.2 * d + 50_000 and len(mine) >= 2
+    assert sum(len(events[name]) for name in INNER_SPANS) == len(inner) == sum(
+        1 for a, b in inner if any(s0 <= a and b <= s0 + d for s0, d in steps))
+    assert covered >= 0.8 * sum(d for _, d in steps)                     # little of a step is unnamed
+    # three requests at once, served until none is held: one emptiness ended, one began
+    (refilled,), (emptied,) = events[MARKS[1]], events[MARKS[0]]
+    # (the step that leaves the server empty says so as its last act)
+    assert refilled[0] < steps[0][0] and inner[-1][1] <= emptied[0] <= steps[-1][0] + steps[-1][1]
+    assert refilled[1] < 1_000_000 and emptied[1] < 1_000_000
+
+
+def test_idle_by_phase_reads_the_capture_and_the_tool_prints_it(serve_capture, capsys):
+    spec = importlib.util.spec_from_file_location(
+        "_tl_cli_by_phase", os.path.join(REPO, "tools", "ds_trace_timeline.py"))
+    cli = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cli)
+    tm = cli.load_timeline_module()
+    rep = cli.by_phase_report(tm, serve_capture["logdir"])
+    assert rep["host_spans"] > 0 and 0 < rep["idle_s"] <= rep["extent_s"]
+    assert sum(rep["idle_by_phase"].values()) == pytest.approx(rep["idle_s"])
+    assert set(rep["idle_by_phase"]) <= set(tm.PHASE_OF_SPAN.values()) | {
+        tm.EMPTY, tm.BETWEEN_STEPS, tm.NO_SPAN} | {n for n in rep["idle_by_phase"]
+                                                   if n.startswith(("build.", "setup."))}
+    assert cli.main(["--xplane", serve_capture["logdir"], "--by-phase"]) == 0   # no JSONL needed
+    assert "device idle by host phase" in capsys.readouterr().out
+
+
+MS = 1_000_000
+HAND_BUSY = [(0, 10 * MS), (15 * MS, 20 * MS), (40 * MS, 41 * MS)]
+HAND_HOST = [("dstpu:serve.step", 9 * MS, 8 * MS), ("dstpu:tick.retire", 9 * MS, 2 * MS),
+             ("dstpu:tick.attribute", 11 * MS, 1 * MS), ("dstpu:serve.emit", 12 * MS, 4 * MS),
+             ("dstpu:serve.emptied", 17 * MS, 100), ("dstpu:serve.refilled", 30 * MS, 90),
+             ("dstpu:serve.step", 31 * MS, 10 * MS), ("dstpu:tick.dispatch.plain", 32 * MS, 9 * MS),
+             ("dstpu:build.pool_tick", 33 * MS, 5 * MS)]
+
+
+@pytest.mark.parametrize("case, host, window, want", [
+    # a gap split over the phases that overlap it: the fetch's tail, the attribution, the fan-out
+    ("a gap split", HAND_HOST[:4], (0, 20 * MS), {"block": 0.001, "attribute": 0.001, "emit": 0.003}),
+    # an emptiness between its markers; outside every step after it; the innermost span where
+    # they nest (a build inside a dispatch inside a step)
+    ("empty, between, nesting", HAND_HOST, None,
+     {"block": 0.001, "attribute": 0.001, "emit": 0.003, "empty": 0.010, "between_steps": 0.001,
+      "step_other": 0.001, "dispatch": 0.001 + 0.002, "build.pool_tick": 0.005}),
+    # a gap no span touches, in a trace that holds no step at all
+    ("no span", [("dstpu:train.next_batch", 10 * MS, 2 * MS)], (0, 20 * MS),
+     {"train.next_batch": 0.002, "(no span)": 0.003}),
+    # a window that opens in an emptiness (its first marker is a refill) and closes in one
+    ("open ends", [("dstpu:serve.refilled", 12 * MS, 50), ("dstpu:serve.step", 12 * MS, 4 * MS),
+                   ("dstpu:serve.emptied", 21 * MS, 50)], (0, 50 * MS),
+     {"empty": 0.002 + 0.019 + 0.009, "step_other": 0.003, "between_steps": 0.001}),
+])
+def test_idle_by_phase_splits_each_gap_and_its_rows_sum_to_the_idle_time(case, host, window, want):
+    from deepspeed_tpu.telemetry.timeline import idle_by_phase
+
+    got = idle_by_phase(HAND_BUSY, host, window=window)
+    assert got == pytest.approx(want), case
+    lo, hi = window or (0, 41 * MS)
+    busy = sum(min(e, hi) - max(s, lo) for s, e in HAND_BUSY if s < hi and e > lo)
+    assert sum(got.values()) == pytest.approx((hi - lo - busy) / 1e9)
+    assert idle_by_phase([], host) == {} and idle_by_phase([(0, 5)], host) == {}
 
 
 def test_only_the_captured_run_left_spans(serve_capture):

@@ -18,6 +18,7 @@ Usage:
     python tools/ds_trace_timeline.py runs/trace.jsonl --trace r0/5 --json
     python tools/ds_trace_timeline.py runs/trace.jsonl --strict  # orphans -> exit 1
     python tools/ds_trace_timeline.py runs/trace.jsonl --xplane runs/xla_trace
+    python tools/ds_trace_timeline.py --xplane runs/xla_trace --by-phase
 
 ``--xplane`` takes the ``jax.profiler`` capture (its ``.xplane.pb`` or the
 directory it was written under) that ``Telemetry.start_capture`` made
@@ -27,6 +28,12 @@ reading, so every JSONL span is placed on the profiler's axis, and each
 device-idle gap over 1 ms is printed with the span (the program's
 ``dstpu:`` host spans or a placed JSONL span) to blame: the narrowest of
 those that cover nearly as much of the gap as any does.
+``--by-phase`` SPLITS the capture's device-idle seconds over what the host
+was doing (``timeline.idle_by_phase``): the serving loop's ``dstpu:`` spans
+under the names of ``tick_stats()``'s host ledger, ``empty`` between a
+``dstpu:serve.emptied`` and a ``dstpu:serve.refilled`` marker,
+``between_steps`` outside every ``dstpu:serve.step``. The spans are in the
+xplane, so it needs no JSONL and no ``clock_sync``.
 Reading an xplane needs jax (``jax.profiler.ProfileData``); nothing else
 here does.
 
@@ -193,6 +200,27 @@ def xplane_report(tm, events, xplane_path):
     }
 
 
+def by_phase_report(tm, xplane_path):
+    """The ``--by-phase`` view as a dict: the capture's idle seconds split
+    over the host's phases, beside the busy and the whole extent."""
+    host, busy = read_xplane(xplane_path)
+    phases = tm.idle_by_phase(busy, host)
+    extent = (max(e for _, e in busy) - min(s for s, _ in busy)) / 1e9 if busy else 0.0
+    return {"extent_s": extent, "idle_s": sum(phases.values()), "host_spans": len(host),
+            "idle_by_phase": dict(sorted(phases.items(), key=lambda kv: -kv[1]))}
+
+
+def format_by_phase(rep):
+    idle, extent = rep["idle_s"], rep["extent_s"]
+    lines = [f"== device idle by host phase: {idle:.4f} s idle of {extent:.4f} s "
+             f"({idle / extent:.1%}), {rep['host_spans']} dstpu: host spans =="
+             if extent else "== no device op in the capture =="]
+    for phase, seconds in rep["idle_by_phase"].items():
+        lines.append(f"  {phase:<16} {seconds:>10.4f} s  {seconds / idle:>6.1%} of idle  "
+                     f"{seconds / extent:>6.1%} of the extent")
+    return "\n".join(lines) + "\n"
+
+
 def format_xplane(rep):
     lines = [f"== on the profiler's axis: offset {rep['clock_offset_ns']} ns "
              f"(clock_sync monotonic_ns={rep['clock_sync_monotonic_ns']}; "
@@ -210,7 +238,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser(
         description="per-request span timelines + Perfetto export from a "
                     "deepspeed_tpu telemetry JSONL trace")
-    ap.add_argument("trace", help="path to the JSONL trace file")
+    ap.add_argument("trace", nargs="?", default=None,
+                    help="path to the JSONL trace file (not needed by --by-phase)")
     ap.add_argument("--trace-id", dest="trace_id", default=None,
                     metavar="TID",
                     help="drill into one trace_id (e.g. 'r0/5' or "
@@ -224,12 +253,32 @@ def main(argv=None):
                     help="the jax.profiler capture made beside this trace: "
                          "place the JSONL spans on its axis and blame each "
                          "device-idle gap over 1 ms (needs jax)")
+    ap.add_argument("--by-phase", action="store_true", dest="by_phase",
+                    help="with --xplane: split the capture's device-idle "
+                         "seconds over the host's phases (the serving "
+                         "ledger's rows); needs no JSONL trace")
     ap.add_argument("--strict", action="store_true",
                     help="exit 1 if any timeline has orphan spans (CI "
                          "round-trip gate)")
     args = ap.parse_args(argv)
 
     tm = load_timeline_module()
+    if args.by_phase:
+        if args.xplane is None:
+            ap.error("--by-phase reads a profiler capture: give --xplane")
+        try:
+            rep = by_phase_report(tm, args.xplane)
+        except OSError as e:
+            print(f"error: --xplane: {e}", file=sys.stderr)
+            return 2
+        if args.as_json:
+            print(json.dumps({"by_phase": rep}, indent=2))
+        else:
+            sys.stdout.write(format_by_phase(rep))
+        if args.trace is None:
+            return 0
+    elif args.trace is None:
+        ap.error("a JSONL trace is needed (only --xplane DIR --by-phase goes without)")
     try:
         events = list(tm.iter_events(args.trace))
     except OSError as e:
