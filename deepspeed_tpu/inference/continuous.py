@@ -504,11 +504,14 @@ class ContinuousBatchingEngine:
                             "prefill_pad_tokens": 0,
                             # ticks whose rows' one-token KV write took
                             # the block path (kv_cache.takes_block_write,
-                            # told here from the tick's read bucket), and
-                            # the bytes those writes fetched and stored:
-                            # every row's block, in and out, a leaf, a
-                            # layer-step and a token step
-                            "block_write_ticks": 0, "block_write_bytes": 0,
+                            # told here from the tick's read bucket), the
+                            # live rows whose blocks those writes moved (a
+                            # parked row or an empty slot moves nothing) and
+                            # the bytes they fetched and stored: a live
+                            # row's block, in and out, a leaf, a layer-step
+                            # and a token step
+                            "block_write_ticks": 0, "block_write_rows": 0,
+                            "block_write_bytes": 0,
                             # ticks whose rows' attention read each row to
                             # its own length (kv_cache.takes_length_read),
                             # the slots that kernel fetched (whole blocks a
@@ -1046,9 +1049,14 @@ class ContinuousBatchingEngine:
         per ``step()``. ``block_write_ticks``: ticks dispatched on a program
         whose rows' one-token KV write took the block path (the host tells
         it from the tick's read bucket by ``kv_cache``'s own rule);
-        ``block_write_bytes``: the bytes those writes fetched and stored
-        (``kv_cache.rows_block_write_bytes`` a token step: every row's block
-        of every leaf that goes by blocks, in and out, over its layer-steps).
+        ``block_write_rows``: the LIVE rows those ticks wrote, a row a
+        token step (the kernel moves a live row's block and nothing for a
+        parked row or an empty slot; ÷ (``block_write_ticks`` x slots) is
+        the share of the pool's rows it touched); ``block_write_bytes``: the
+        bytes those writes fetched and stored
+        (``kv_cache.rows_block_write_bytes`` a live row and token step: its
+        block of every leaf that goes by blocks, in and out, over its
+        layer-steps).
         ``length_read_ticks``: ticks dispatched on a program whose rows'
         attention reads each row to its own length (the same way, by
         ``kv_cache.takes_length_read``); of those ticks ``row_keys_read``
@@ -1418,8 +1426,10 @@ class ContinuousBatchingEngine:
                               self._row_read_bytes(pool, read_len), False)
             advance = k
         moved = kv_cache.rows_block_write_bytes(self.cfg, pool.cache, read_len, self.mesh)
+        wrote = int((pos < pool.length).sum()) * advance if moved else 0   # live rows, a token step each
         self._tick_stats["block_write_ticks"] += moved > 0
-        self._tick_stats["block_write_bytes"] += moved * advance
+        self._tick_stats["block_write_rows"] += wrote
+        self._tick_stats["block_write_bytes"] += moved * wrote
         if self._latent_pool:  # a row that is not parked attends its cached entries and the one it writes
             self._tick_stats["mla_row_keys"] += int((pos[pos < pool.length] + 1).sum())
         if self._loop_steps > 1:

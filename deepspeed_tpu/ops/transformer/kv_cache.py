@@ -319,19 +319,19 @@ def spans_chips(mesh) -> bool:
 
 def rows_block_write_bytes(cfg, cache, read_len: Optional[int], mesh=None) -> int:
     """The host's side of :func:`takes_block_write`: the bytes the rows'
-    block writes fetch and store in ONE token step of a program built on
-    ``mesh`` that writes one token a row into ``cache`` at read bucket
-    ``read_len`` (None: the allocation); 0 where no leaf takes the block
-    path. Off static shapes: every row's block of :func:`block_slots` slots,
-    all heads, in and out, summed over the leaves that go by blocks and their
-    layer-steps (``tick_stats()``'s ``block_write_bytes``)."""
+    block write fetches and stores for ONE live row (a parked row or an empty
+    slot moves nothing) in ONE token step of a program built on ``mesh`` that
+    writes one token a row into ``cache`` at read bucket ``read_len`` (None:
+    the allocation); 0 where no leaf takes the block path. Off static shapes:
+    the row's block of :func:`block_slots` slots, all heads, in and out,
+    summed over the leaves that go by blocks and their layer-steps
+    (``tick_stats()``'s ``block_write_bytes``, a live row and a token step)."""
     def one(spec, leaf):
         size = spec.ring or read_len or leaf.shape[spec.time_axis]
         if not takes_block_write(size, _row_bytes(leaf, size, spec.heads_first),
-                                 ragged=_takes_ragged(leaf, spec.heads_first)):
+                                 _takes_ragged(leaf, spec.heads_first), leaf.shape[4] % LANES != 0):
             return 0
-        layers, rows = leaf.shape[:2]
-        return 2 * layers * rows * _row_bytes(leaf, block_slots(leaf), spec.heads_first)
+        return 2 * leaf.shape[0] * _row_bytes(leaf, block_slots(leaf), spec.heads_first)
 
     if spans_chips(mesh):
         return 0
@@ -390,8 +390,8 @@ def window(pool, layer, size, *, heads_first: bool, slot=None, start=0):
 def write(pool, layer, new, cols, size, *, heads_first: bool, slot=None, start=0):
     """``new`` into slots ``cols`` of ``[layer]``, in place: one token a row,
     or with ``slot`` W tokens into that ONE row at ``start + cols``. One
-    token a row of a long window goes into the row's own time block
-    (:func:`_write_blocks`, by :func:`takes_block_write`). Every other
+    token a row of a window that is long enough goes into the row's own time
+    block (:func:`_write_blocks`, by :func:`takes_block_write`). Every other
     write goes through the window :func:`window` reads: slice, select and
     update fuse into one pass over it; several tokens a row are laid out
     along it by a one-hot contraction (exact: one term a slot), never
@@ -399,7 +399,7 @@ def write(pool, layer, new, cols, size, *, heads_first: bool, slot=None, start=0
     size = size or pool.shape[3 if heads_first else 2]
     one_token = slot is None and (heads_first or new.shape[1] == 1) and not _split_over_chips
     if one_token and takes_block_write(size, _row_bytes(pool, size, heads_first),
-                                       ragged=_takes_ragged(pool, heads_first)):
+                                       _takes_ragged(pool, heads_first), pool.shape[4] % LANES != 0):
         return _write_blocks(pool, layer, new[:, :, None] if heads_first else new,
                              cols.reshape(-1), size, heads_first)
     if not heads_first:
@@ -432,31 +432,31 @@ def write(pool, layer, new, cols, size, *, heads_first: bool, slot=None, start=0
 # blocks (``takes_block_write``), whatever the leaf's own block is.
 BLOCK = 128
 # The ONE rule of which path a rows' write takes, over static shapes: by
-# blocks iff one row's window of one leaf holds this many bytes. Measured on
-# a v5e (plain ticks, PERF.md section 6, PR 32): the window path moves a
-# row's window twice at the chip's bandwidth (GPT-2 XL, 25 x 64 bf16: 1.25 us
-# a row, layer and leaf for every 128 slots of the window; gpt2-medium 0.8);
-# the block path is one kernel call a layer and leaf that costs 1.33 us a row
-# at XL's 410 KB block (0.92 at gpt2-medium's 262 KB) plus the tokens'
-# broadcast, whatever the window's length: 2.66 ms a tick at XL against
-# 3.95 / 7.81 / 15.36 ms for windows of 256 / 512 / 1,024 slots, 2.25 ms at
-# gpt2-medium's 40 rows against 3.05 / 6.21 / 12.23. The smallest row
-# measured, gpt2-medium at 256 slots (512 KiB), still gains 0.8 ms a tick;
-# below it nothing was measured and the window path stays.
-# The rows' READ by length (``takes_length_read``, PR 39) stands on the same
-# constant, and its measurement did not move it (a v5e, the kernel against
-# the two dots and their softmax over the window, ms over all layers,
-# PERF.md section 6, PR 39): at the smallest row measured, gpt2-medium's 40
-# rows at 256 slots, 0.23 against 1.78 with 2 rows live, 0.38 with 8, and
-# 1.88 against 1.78 only when all 40 rows fill the window; XL's 16 rows at
-# 256 slots 1.72-2.13 against 2.17-2.30 even full. Where EVERY row is as
-# long as the read bucket the kernel loses 5-20 % (XL 1,024: 7.77 against
-# 6.98; it moves 590-650 GB/s of what it fetches where the dots move 760),
-# which no static shape can tell; a call costs ~10 us before its first block.
-# A lane-aligned leaf's blocks are an eighth of those bytes and its call
-# cheaper still (Ouro, 16 rows of 16 heads x 128: 4.4 us a call against
-# 24-27 with 128-slot blocks, PERF.md section 6, PR 45); the threshold was
-# not measured again for them and stays, so the same programs take the path.
+# blocks iff one row's window of one leaf holds this many bytes, and of a
+# TIME-minor leaf HALF of them. Measured on a v5e (PERF.md section 6, PR 54,
+# call 1; us a call = a layer and leaf, its columns' arithmetic and tokens'
+# transpose with it): the window path moves every row's window twice at the
+# chip's bandwidth, whatever the rows hold (gpt2-medium's 40 rows of 16 x 64
+# bf16: 34.0 / 66.1 / 129.1 / 254.3 for 128 / 256 / 512 / 1,024 slots; GPT-2
+# XL's 16 of 25 x 64: 22.1 / 41.8 / 82.1 / 159.7); the time-minor kernel
+# moves the LIVE rows' blocks, whatever the window's length: gpt2-medium 2.2
+# with no row live, 4.0 with 1, 4.5 with 2, 9.7 with 8, 35.5 with all 40
+# (0.85 a row; PR 32's grid step a row cost 48.8 whatever the rows held), XL
+# 4.2 with 1, 12.9 with 8, 22.7 with all 16 (PR 32's: 28.6); MiMo's keys, 32
+# rows: the 128-slot ring of 8 x 192 41.2 with all live, 38.8 with 30, 7.5
+# with 4 (window 40.0), the full pool's 4 x 192 at 256 slots 21.9 (window
+# 39.8). So with EVERY row live a 128-slot window costs the same either way
+# (+3-4 %) and every parked row is 0.85 us saved; the smallest row measured,
+# gpt2-medium's 128 slots (256 KiB), is half the constant. Below it nothing
+# was measured and toy models keep the XLA program they lower to.
+# The rows' READ by length (``takes_length_read``, PR 39) stands on the
+# constant itself and above one block, as it did: at gpt2-medium's 40 rows
+# of 256 slots 0.23 ms over all layers against the window's 1.78 with 2 rows
+# live, 1.88 against 1.78 only when all 40 fill the window; where EVERY row
+# is as long as the read bucket it loses 5-20 % (XL 1,024: 7.77 against
+# 6.98), which no static shape can tell. A lane-aligned leaf's blocks are an
+# eighth of a time-minor leaf's bytes and its call cheaper still (Ouro: 4.4 us
+# against 24-27, PR 45); the threshold was not measured again for them.
 BLOCK_WRITE_MIN_ROW_BYTES = 1 << 19
 
 
@@ -481,15 +481,15 @@ def split_over_chips(fn):
     return traced
 
 
-def takes_block_write(size: int, row_bytes: int, ragged: bool = False) -> bool:
+def takes_block_write(size: int, row_bytes: int, ragged: bool = False, time_minor: bool = False) -> bool:
     """Whether one token a row goes into its row's block (True) or through
     the whole window (False), for a window of ``size`` slots that holds
-    ``row_bytes`` a row of one leaf. A window no longer than ``BLOCK`` IS
-    its block (of a time-minor leaf; the rule is one for every leaf); one
-    that is not whole ``BLOCK``s goes by blocks only in a leaf that
-    ``ragged`` says can take it (:func:`_takes_ragged`)."""
-    return (size > BLOCK and (ragged or size % BLOCK == 0)
-            and row_bytes >= BLOCK_WRITE_MIN_ROW_BYTES)
+    ``row_bytes`` a row of one leaf. Of a leaf kept as written a window no
+    longer than ``BLOCK`` stays a window; a ``time_minor`` leaf's, which IS its
+    block, goes too (the kernel moves the LIVE rows' blocks), from half the
+    bytes on. One that is not whole ``BLOCK``s: only where ``ragged``."""
+    least = BLOCK_WRITE_MIN_ROW_BYTES // 2 if time_minor else BLOCK_WRITE_MIN_ROW_BYTES
+    return (size > BLOCK or time_minor) and (ragged or size % BLOCK == 0) and row_bytes >= least
 
 
 def _takes_ragged(pool, heads_first: bool = True) -> bool:
@@ -561,11 +561,12 @@ def block_slots(pool) -> int:
 def _write_blocks(pool, layer, token, cols, size, heads_first):
     """Each row's one token (``token`` holds one slot along time, ``cols``
     (B,)) into the block of :func:`block_slots` slots that holds its column,
-    in place: ONE kernel call that fetches the row's block of every head,
-    selects the token in where the slot is the column's, and stores the block
-    back (``kv_block_write``; the pool is aliased to the result and nothing
-    else of it is touched). A column outside ``[0, size)`` clips to the first
-    or last block and hits no slot of it.
+    in place: ONE kernel call that fetches the block of every head of each
+    row whose token LANDS, selects the token in where the slot is the
+    column's, and stores the block back (``kv_block_write``; the pool is
+    aliased to the result and nothing else of it is touched). A column outside
+    ``[0, size)`` (a parked row, an empty slot) gets offset -1: its row is
+    neither fetched nor stored, in either layout (PR 45, PR 54).
 
     The kernel has to see the pool in the order the chip keeps it, or the
     compiler copies the pool in and out: a leaf whose width is whole 128-lane
@@ -573,8 +574,7 @@ def _write_blocks(pool, layer, token, cols, size, heads_first):
     (:func:`_write_lane_blocks`). Unrolled XLA ops (a ``dynamic_slice``,
     select and ``dynamic_update_slice`` a row) compile in place too, but the
     chip runs each update as a copy of the block's separate 2 KB tiles, 7.7 us
-    a row and leaf at XL: no faster than the window (PERF.md section 6,
-    PR 32)."""
+    a row and leaf at XL: no faster than the window (PERF.md section 6, PR 32)."""
     slots = block_slots(pool)
     first = jnp.clip(cols // slots, 0, -(-size // slots) - 1).astype(jnp.int32)
     # a window that is not whole blocks: its last block reaches past it, and a column there drops too
@@ -590,38 +590,38 @@ def _write_blocks(pool, layer, token, cols, size, heads_first):
 def _write_lane_blocks(pool, scalars, token, time):
     """A TIME-minor leaf (GPT-2's ``(L, B, T, H, 64)``, MiMo's keys ``(L, B,
     H, T, 192)``; ``time`` its time axis): a token is one lane of every tile
-    of its ``BLOCK`` slots. The leaf goes in as its ``(L, B, H, x, T)``
-    transpose, which IS that memory order and costs nothing (no copy in the
-    compiled ticks: ``tests/unit/ops/test_tpu_compile.py``,
-    ``test_tpu_compile_plan.py``), a pipelined grid step a row, beside the
-    token broadcast to a block (a ``(..., 1)`` column the chip would pad
-    128-fold in HBM anyway)."""
-    order = [a for a in range(5) if a != time] + [time]
-    lead = pool.transpose(order)
-    rows = pool.shape[1]
-    block = (None, None) + lead.shape[2:4] + (BLOCK,)
-    token = jnp.broadcast_to(token[None].transpose(order)[0], (rows,) + block[2:])
+    of its ``BLOCK`` slots. The leaf goes to the kernel as its ``(L, B, H, x,
+    T)`` transpose, which IS that memory order and costs nothing (no copy in
+    the compiled ticks: ``tests/unit/ops/test_tpu_compile.py``,
+    ``test_tpu_compile_plan.py``), and the kernel moves the blocks of the LIVE
+    rows only (:func:`_lane_blocks_call`, at the end of this file, where a new
+    kernel moves no other kernel's lines: one invocation a layer and leaf, the
+    rows whose token lands listed first, their blocks through as many VMEM
+    buffers as ``_TILE_WRITE_VMEM_BYTES`` holds, never more than the rows, by
+    DMAs of its own; a parked row or an empty slot costs a scalar comparison,
+    and a call with no live row moves nothing). The tokens go in as one column
+    a row with the rows along the lanes, 262 KB a call at gpt2-medium, where
+    PR 32's form (a pipelined grid step a row, which fetched and stored EVERY
+    row's block beside the row's token blown up to a block in HBM, 10 MB a
+    call) cost 48.8 us a call at gpt2-medium's 40 rows whatever they held.
 
-    def index(row, layer_ref, first_ref, offset_ref):
-        return layer_ref[0], row, 0, 0, first_ref[row]
-
-    def kernel(layer_ref, first_ref, offset_ref, pool_ref, token_ref, out_ref):
-        slot = jax.lax.broadcasted_iota(jnp.int32, pool_ref.shape, 2)
-        hit = slot == offset_ref[pl.program_id(0)]
-        out_ref[...] = jnp.where(hit, token_ref[...], pool_ref[...])
-
-    out = pl.pallas_call(
-        kernel, name="kv_block_write",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(rows,),
-            in_specs=[pl.BlockSpec(block, index),
-                      pl.BlockSpec((None,) + block[2:], lambda row, *_: (row, 0, 0, 0))],
-            out_specs=pl.BlockSpec(block, index)),
-        out_shape=jax.ShapeDtypeStruct(lead.shape, lead.dtype),
-        input_output_aliases={3: 0},
-        interpret=resolve_interpret(),
-    )(*scalars, lead, token)
-    return out.transpose([order.index(a) for a in range(5)])
+    On a v5e (PERF.md section 6, PR 54, call 1; the table above
+    ``BLOCK_WRITE_MIN_ROW_BYTES``), us a call with its columns' arithmetic
+    and its tokens' transpose: gpt2-medium 2.2 with no row live, 4.0 with
+    one, 4.5 with two, 9.7 with eight, 35.5 with all 40: 2.2 a call, 1.8 the
+    first row's two dependent DMAs, 0.85 a row after it (262 KB in and out
+    at 620 GB/s); GPT-2 XL's 16 rows of 410 KB 22.7 with every row live
+    where the grid step a row cost 28.6; MiMo's keys (32 rows, 4 heads of
+    192, 196 KB) 23.4 against 34.3. The ring's depth does not matter
+    between 2 and 8 MiB (medium, all 40 live: 35.2 / 35.5 / 35.7 us at 2 / 4
+    / 8 MiB; 39.8 at 1 MiB, four buffers): the lane-aligned kernel's budget
+    serves both. In the chat cell, 1.3 rows of 40 live, the rows' write is
+    48 calls x ~4.2 us = 0.2 ms a tick where it was 2.3. With every row
+    live a 128-slot window costs the window path's time (+3-4 %): the rule
+    (``takes_block_write``) sends it here for the rows that are not."""
+    block_bytes = BLOCK * math.prod(pool.shape[2:]) // pool.shape[time] * pool.dtype.itemsize
+    ring = max(min(_TILE_WRITE_VMEM_BYTES // block_bytes, pool.shape[1]), 1)
+    return _lane_blocks_call(*scalars, pool, token, time=time, ring=ring, interpret=resolve_interpret())
 
 
 # VMEM the rows' blocks of a lane-aligned leaf may hold in one grid step of the block write
@@ -808,3 +808,88 @@ def traced_over_chips() -> bool:
     """Whether the program being traced holds its pools on more than one chip
     (:func:`split_over_chips`): all that a traced function sees of its mesh."""
     return _split_over_chips
+
+
+@functools.partial(jax.jit, static_argnames=("time", "ring", "interpret"))
+def _lane_blocks_call(layer, first, offset, pool, token, *, time, ring, interpret):
+    """:func:`_write_lane_blocks`' kernel call: ONE invocation, no grid step a
+    row. The rows whose offset is not negative are listed first (SMEM); their
+    blocks ``(H, x, BLOCK)`` of the ``(L, B, H, x, T)`` transpose go through a
+    ring of ``ring`` VMEM buffers by DMAs the kernel issues itself: up to
+    ``ring`` fetches in flight at the start, then a row's select and the start
+    of its store, then the wait for the store ``ring // 2`` rows back, whose
+    buffer takes the next fetch. The tokens come as ONE ``(H, x, 128)`` tile a
+    128 rows with the ROWS on the lanes (a small transpose outside the kernel);
+    a live row's column is picked out by a lane mask and a lane maximum (one
+    term is not the fill: exact in every dtype, -0.0 included) and broadcast
+    along the block's lanes inside the select. A ``jit`` of its own and loops,
+    not copies of a body, for :func:`_tile_blocks_call`'s reasons."""
+    order = [a for a in range(5) if a != time] + [time]
+    lead = pool.transpose(order)                                     # (L, B, H, x, T): a bitcast on the chip
+    rows, block = pool.shape[1], lead.shape[2:4] + (BLOCK,)
+    tiles = -(-rows // LANES)
+    by_lane = jnp.pad(token.reshape((rows,) + block[:2]), ((0, tiles * LANES - rows), (0, 0), (0, 0)))
+    by_lane = by_lane.reshape((tiles, LANES) + block[:2]).transpose(0, 2, 3, 1)
+    lag = ring // 2
+
+    def kernel(layer_ref, first_ref, offset_ref, pool_ref, token_ref, out_ref, live_ref, buf, sem):
+        lane = jax.lax.broadcasted_iota(jnp.int32, block, 2)
+
+        def list_live(row, n):
+            live = offset_ref[row] >= 0
+
+            @pl.when(live)
+            def _():
+                live_ref[n] = row
+            return n + live.astype(jnp.int32)
+
+        n_live = jax.lax.fori_loop(0, rows, list_live, jnp.int32(0))
+
+        def block_of(ref, j):
+            row = live_ref[j]
+            return ref.at[layer_ref[0], row, :, :, pl.ds(pl.multiple_of(first_ref[row] * BLOCK, BLOCK), BLOCK)]
+
+        def fetch(j):       # the j-th live row's block into its buffer of the ring
+            return pltpu.make_async_copy(block_of(pool_ref, j), buf.at[j % ring], sem.at[0, j % ring])
+
+        def store(j):
+            return pltpu.make_async_copy(buf.at[j % ring], block_of(out_ref, j), sem.at[1, j % ring])
+
+        def each(lo, hi, do):
+            jax.lax.fori_loop(lo, hi, lambda j, carry: do(j) or carry, 0)
+
+        def select_and_store(j):
+            row = live_ref[j]
+            fetch(j).wait()
+            mine = jnp.where(lane == row % LANES, token_ref[row // LANES].astype(jnp.float32), -jnp.inf)
+            column = jnp.max(mine, axis=2, keepdims=True).astype(buf.dtype)        # (H, x, 1)
+            buf[j % ring] = jnp.where(lane == offset_ref[row], column, buf[j % ring])
+            store(j).start()
+
+            @pl.when(j >= lag)      # the store ``lag`` rows back has had its time: its buffer takes the next fetch
+            def _():
+                store(j - lag).wait()
+
+                @pl.when(j - lag + ring < n_live)
+                def _():
+                    fetch(j - lag + ring).start()
+
+        each(0, jnp.minimum(n_live, ring), lambda j: fetch(j).start())
+        each(0, n_live, select_and_store)
+        each(jnp.maximum(n_live - lag, 0), n_live, lambda j: store(j).wait())
+
+    out = pl.pallas_call(
+        kernel, name="kv_block_write",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(by_lane.shape, lambda g, *_: (0, 0, 0, 0))],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SMEM((rows,), jnp.int32),
+                            pltpu.VMEM((ring,) + block, pool.dtype),
+                            pltpu.SemaphoreType.DMA((2, ring))]),
+        out_shape=jax.ShapeDtypeStruct(lead.shape, lead.dtype),
+        input_output_aliases={3: 0},
+        interpret=interpret,
+    )(layer, first, offset, lead, by_lane)
+    return out.transpose([order.index(a) for a in range(5)])

@@ -180,11 +180,12 @@ def test_eos_on_the_first_token(models):
 
 @pytest.mark.parametrize("variant", ["one_chip", "int8_one_chip", "tensor2"])
 def test_streams_through_the_block_write_above_one_block(models, monkeypatch, variant):
-    """Read buckets 256 and the whole 384-slot pool with the rule's constant
-    at zero: the rows' tokens go into each row's 128-slot block (plain and
-    fused ticks), the streams are ``generate``'s, and ``tick_stats()`` counts
-    the ticks dispatched on such programs and no other. A pool split over
-    two chips keeps the window write (the kernel cannot be partitioned)."""
+    """Read buckets 128, 256 and the whole 384-slot pool with the rule's
+    constant at zero: the live rows' tokens go into each row's 128-slot block
+    (plain and fused ticks; since PR 54 a time-minor pool's 128-slot window
+    too), the streams are ``generate``'s, and ``tick_stats()`` counts the
+    ticks dispatched on such programs. A pool split over two chips keeps the
+    window write (the kernel cannot be partitioned)."""
     prompt = _prompt(300, seed=300)
     want = _generate(models, variant, prompt, 10)        # window path: before the constant moves
     before = _engine(models, variant)
@@ -196,11 +197,12 @@ def test_streams_through_the_block_write_above_one_block(models, monkeypatch, va
     np.testing.assert_array_equal(got, want)
     assert all(o.size for o in others)
     st = cb.tick_stats()
-    by_blocks = sum(1 for (_, read_len) in cb._pools[0].tick_fns if read_len in (256, None))
-    assert by_blocks >= 2 and st["block_write_ticks"] < st["ticks"]
+    by_blocks = sum(1 for (_, read_len) in cb._pools[0].tick_fns if read_len in (128, 256, None))
+    assert by_blocks >= 3 and (st["block_write_ticks"] == st["ticks"]) == (variant != "tensor2")
     assert (st["block_write_ticks"] > 0) == (variant != "tensor2")
-    # the ticks below 256 slots (the other requests' first steps) went through the window
-    assert any(read_len is not None and read_len <= 128 for (_, read_len) in cb._pools[0].tick_fns)
+    # the other requests' first steps read one block: the kernel's too, and their rows alone are live
+    assert any(read_len == 128 for (_, read_len) in cb._pools[0].tick_fns)
+    assert 0 < st["block_write_rows"] < 3 * st["block_write_ticks"] or variant == "tensor2"
 
 
 # -- the program -----------------------------------------------------------
@@ -271,13 +273,19 @@ def test_tick_stats_count_real_and_pad_tokens(models):
 
 def test_tick_stats_count_the_ticks_whose_rows_wrote_by_blocks(models, monkeypatch):
     """``block_write_ticks`` is the host's reading of ``kv_cache``'s own rule
-    at each tick's read bucket: with the constant at the bytes of a 256-slot
-    row of this pool, the ticks that read 256 slots or the whole pool count
-    and the shorter buckets do not; ``ds_loadgen`` prints the share."""
+    at each tick's read bucket: a time-minor pool's window goes by blocks from
+    HALF the constant's bytes a row (PR 54), so with the constant at the bytes
+    of a 512-slot row of this pool the ticks that read 256 slots or the whole
+    pool count and the 128-slot bucket does not; at a 256-slot row's bytes the
+    128-slot ticks count too; ``ds_loadgen`` prints the share."""
     from deepspeed_tpu.serving import loadgen
 
     row_256 = 256 * BASE.num_heads * (BASE.hidden_size // BASE.num_heads) * 4
     monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", row_256)
+    assert kv_cache.takes_block_write(128, row_256 // 2, time_minor=True)
+    assert not kv_cache.takes_block_write(128, row_256 // 2 - 1, time_minor=True)
+    assert not kv_cache.takes_block_write(128, 1 << 40)              # a leaf kept as written
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 2 * row_256)
     cb = _engine(models, "one_chip")
     seen = []
     dispatch = cb._tick_fn
@@ -293,6 +301,32 @@ def test_tick_stats_count_the_ticks_whose_rows_wrote_by_blocks(models, monkeypat
     assert host["block_write_share"] == pytest.approx(st["block_write_ticks"] / st["ticks"], abs=1e-4)
 
 
+def test_a_tick_counts_its_live_rows_blocks_and_a_128_slot_tick_is_a_block_write_tick(models, monkeypatch):
+    """Two requests in a pool of eight rows, every tick at the 128-slot read
+    bucket: each is a block-write tick (PR 54: a time-minor leaf's 128-slot
+    window goes to the kernel), a tick whose rows are 2 of 8 live counts 2
+    rows' blocks and their bytes (in and out, K and V, two layers, a block of
+    128 slots x 4 heads x 16 float32), and a tick that carries only a chunk
+    counts no row."""
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 0)
+    cb = _engine(models, "one_chip", max_slots=8)
+    keys = ("ticks", "block_write_ticks", "block_write_rows", "block_write_bytes")
+    a_row = 2 * 2 * 2 * 128 * 4 * 16 * 4
+    for n in (5, 8):
+        cb.submit(_prompt(n, seed=n), max_new_tokens=6)
+    deltas, before = [], cb.tick_stats()
+    while cb.has_work():
+        cb.step()
+        after = cb.tick_stats()
+        deltas.append(tuple(after[k] - before[k] for k in keys))
+        before = after
+    assert {read_len for (_, read_len) in cb._pools[0].tick_fns} == {128}
+    assert set(deltas) <= {(0, 0, 0, 0)} | {(1, 1, live, live * a_row) for live in (0, 1, 2)}
+    assert (1, 1, 2, 2 * a_row) in deltas and (1, 1, 0, 0) in deltas      # 2 of 8 live; the first chunk alone
+    # a request's first token comes from its chunk's tick; its row then writes a token a tick, five times
+    assert after["block_write_rows"] == 2 * 5 and after["block_write_ticks"] == after["ticks"]
+
+
 def _looped_lane_plan():
     """Two layers of one kind walked twice, 2 key-value heads of 128: a pool of 4 layer-steps whose
     two leaves are whole lane tiles wide (8-slot blocks in float32)."""
@@ -306,11 +340,12 @@ def _looped_lane_plan():
 
 @pytest.mark.parametrize("name", ["time_minor", "lane_aligned_plan"])
 def test_tick_stats_count_the_bytes_the_rows_block_writes_moved(models, monkeypatch, name):
-    """``block_write_bytes``: every row's block, in and out, of every leaf
-    and layer-step, in the ticks ``block_write_ticks`` counts, read off static
-    shapes: a time-minor pool's block is 128 slots, a lane-aligned leaf's one
-    sublane tile (8 of float32); 0 where no tick wrote by blocks; and
-    ``ds_loadgen`` prints it as MB a tick."""
+    """``block_write_bytes``: each LIVE row's block (``block_write_rows``, a
+    row a token step: a parked row or an empty slot moves nothing), in and
+    out, of every leaf and layer-step, in the ticks ``block_write_ticks``
+    counts, read off static shapes: a time-minor pool's block is 128 slots, a
+    lane-aligned leaf's one sublane tile (8 of float32); 0 where no tick wrote
+    by blocks; and ``ds_loadgen`` prints it as MB a tick."""
     from deepspeed_tpu.serving import loadgen
 
     if name == "time_minor":
@@ -328,13 +363,16 @@ def test_tick_stats_count_the_bytes_the_rows_block_writes_moved(models, monkeypa
         return cb.tick_stats()
 
     st = serve()                                                  # a toy row is far under the constant
-    assert st["block_write_ticks"] == 0 and st["block_write_bytes"] == 0
+    assert st["block_write_ticks"] == 0 and st["block_write_bytes"] == 0 and st["block_write_rows"] == 0
     assert loadgen.host_overhead(st)["block_write_mb_per_tick"] is None
     monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 0)
     st = serve()
-    a_tick = rows * block * 2 * leaves * layer_steps
-    assert 0 < st["block_write_ticks"] < st["ticks"]
-    assert st["block_write_bytes"] == st["block_write_ticks"] * a_tick
+    a_row = block * 2 * leaves * layer_steps
+    assert 0 < st["block_write_ticks"] <= st["ticks"]
+    # a request decodes 4 tokens after its prefill's first, and not every row is live in every tick
+    assert st["block_write_ticks"] < st["block_write_rows"] < rows * st["block_write_ticks"]
+    assert st["block_write_bytes"] == st["block_write_rows"] * a_row
+    a_tick = st["block_write_bytes"] / st["block_write_ticks"]
     host = loadgen.host_overhead(st)
     assert host["block_write_mb_per_tick"] == pytest.approx(a_tick / 1e6, abs=1e-3)
     text = loadgen.format_summary({"outcomes": {}, "requests": 3, "wall_s": 1.0, "throughput_tok_s": 1.0,

@@ -259,9 +259,9 @@ class TestBothOrders:
 BIG_T = 512
 
 
-def big_pool(heads_first, dtype, batch, seed=0):
-    spec = kv_cache.PoolSpec("p", L, 5, X, X, None, heads_first, False)
-    values = np.random.RandomState(seed).normal(size=spec.shape(batch, BIG_T, X)) * 20
+def big_pool(heads_first, dtype, batch, seed=0, heads=5, width=X):
+    spec = kv_cache.PoolSpec("p", L, heads, width, width, None, heads_first, False)
+    values = np.random.RandomState(seed).normal(size=spec.shape(batch, BIG_T, width)) * 20
     return jnp.asarray(values, dtype), spec
 
 
@@ -276,16 +276,47 @@ def both_paths(monkeypatch, build):
     return out["window"], out["block"]
 
 
-@pytest.mark.parametrize("heads_first", [False, True], ids=["time_first", "heads_first"])
-@pytest.mark.parametrize("size", [256, 512, None], ids=["size256", "size512", "full"])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8], ids=str)
-def test_block_write_leaves_the_window_writes_bits(monkeypatch, heads_first, size, dtype):
-    """Columns 0, 127, 128, size - 1, size (dropped), the pool's length (a
-    parked row), a negative one, and rows in different blocks in one call."""
+def _rows_columns(rows, reach):
+    """The columns of a call's eight rows. ``mixed``: 0, 127, 128, the
+    window's last slot, one past it (dropped), the pool's length (a parked
+    row), a negative one, and rows in different blocks in one call; ``none``:
+    every row parked, below 0 or past the window (the kernel moves nothing);
+    ``one`` / ``few``: one or a scattered three live among parked rows;
+    ``all``: every row live."""
+    parked = [BIG_T, -1, reach, BIG_T + 7, -5, BIG_T, reach, -1]
+    return np.asarray({
+        "mixed": [0, 127, 128 % reach, reach - 1, reach, BIG_T, -1, 300 % reach],
+        "none": parked,
+        "one": parked[:5] + [reach - 1] + parked[6:],
+        "few": [parked[0], 127, parked[2], parked[3], 0, parked[5], reach - 1, parked[7]],
+        "all": [0, 127, 128 % reach, reach - 1, 1, 300 % reach, 64, 126],
+    }[rows], np.int32)
+
+
+# every order, window (a 128-slot one IS its block: PR 54) and dtype with the mixed rows; the rows that
+# are none / one / a few / all live, and the cells' two time-minor shapes (GPT-2's time before heads at
+# 64 wide, MiMo's keys heads first at 192 wide), in bfloat16 at the shortest and the whole window
+BLOCK_WRITES = [(hf, size, dtype, "mixed", 5, X)
+                for dtype in (jnp.float32, jnp.bfloat16, jnp.int8) for size in (128, 256, 512, None)
+                for hf in (False, True)]
+BLOCK_WRITES += [(hf, size, jnp.bfloat16, rows, 5, X) for rows in ("none", "one", "few", "all")
+                 for size in (128, None) for hf in (False, True)]
+BLOCK_WRITES += [(hf, size, jnp.bfloat16, rows, 2, width) for hf, width in ((False, 64), (True, 192))
+                 for size in (128, None) for rows in ("mixed", "few")]
+
+
+@pytest.mark.parametrize(
+    "heads_first, size, dtype, rows, heads, width", BLOCK_WRITES,
+    ids=["-".join(["heads_first" if hf else "time_first", f"size{size or 'full'}", jnp.dtype(dtype).name, rows,
+                   f"{heads}x{width}"]) for hf, size, dtype, rows, heads, width in BLOCK_WRITES])
+def test_block_write_leaves_the_window_writes_bits(monkeypatch, heads_first, size, dtype, rows, heads, width):
+    """The kernel's pool is the window write's, bit for bit (``_rows_columns``:
+    which rows are live), and the kernel touches nothing but the live rows'
+    slots."""
     reach = size or BIG_T
-    cols = np.asarray([0, 127, 128, reach - 1, reach, BIG_T, -1, 300 % reach], np.int32)
-    pool, spec = big_pool(heads_first, dtype, len(cols))
-    new = jnp.asarray(np.random.RandomState(1).normal(size=(len(cols), 5, X)) * 20, dtype)
+    cols = _rows_columns(rows, reach)
+    pool, spec = big_pool(heads_first, dtype, len(cols), heads=heads, width=width)
+    new = jnp.asarray(np.random.RandomState(1).normal(size=(len(cols), heads, width)) * 20, dtype)
     toks, at = rows_tokens(new, jnp.asarray(cols), heads_first)
 
     def build():
@@ -304,16 +335,18 @@ def test_block_write_leaves_the_window_writes_bits(monkeypatch, heads_first, siz
 
 @pytest.mark.parametrize("name", ["dense", "int8", "grouped"])
 @pytest.mark.parametrize("ring", [False, True], ids=["rows", "ring"])
-def test_block_write_through_update_kv_cache_dense_int8_and_a_ring(monkeypatch, name, ring):
-    """The one-kind body's entry: rows at their own depths (the tick), and a
-    rolling cache's scalar-depth step whose columns wrap; an int8 pool is
-    written component by component (``q8`` and ``s``)."""
+@pytest.mark.parametrize("length", [256, 128], ids=["len256", "len128"])
+def test_block_write_through_update_kv_cache_dense_int8_and_a_ring(monkeypatch, name, ring, length):
+    """The one-kind body's entry: rows at their own depths (the tick; the
+    last row is parked), and a rolling cache's scalar-depth step whose
+    columns wrap; an int8 pool is written component by component (``q8`` and
+    ``s``); a cache of ONE block (PR 54: its window goes to the kernel)."""
     cfg = LAYOUTS[name]()
     cache = jax.tree.map(
         lambda a: jnp.asarray(np.random.RandomState(2).randint(-90, 90, a.shape), a.dtype),
-        kv_cache.init(cfg, 4, 256))
+        kv_cache.init(cfg, 4, length))
     new = jax.random.normal(jax.random.PRNGKey(0), (4, 1, cfg.kv_heads, cfg.head_dim), jnp.float32)
-    pos = jnp.int32(300) if ring else jnp.asarray([0, 127, 128, 256], jnp.int32)
+    pos = jnp.int32(300) if ring else jnp.asarray([0, 127, 128 % length, length], jnp.int32)
 
     def build():
         def step(k, v):
@@ -405,17 +438,20 @@ def block_write_call(fn, *args):
 
 @pytest.mark.parametrize("case,shape,heads_first,want", [
     # GPT-2's pool (time before heads, 64 wide), MiMo's keys (heads first, 192 wide) and an int8
-    # pool's scales: TIME-minor on the chip, the call the parent made (PR 32): the (L, B, H, x, T)
-    # transpose in 128-slot blocks, a grid step a row, beside the token broadcast to a block
+    # pool's scales: TIME-minor on the chip. Recorded anew in PR 54 (PR 32's call was a grid step a
+    # row beside the token broadcast to a block, (4, 5, 64, 128)): ONE step, the (L, B, H, x, T)
+    # transpose left where it is, the tokens as one (H, x, 128) tile with the rows on the lanes, and
+    # for scratch the list of live rows, a ring of 128-slot blocks (all four rows' fit) and the
+    # DMAs' semaphores, a fetch's and a store's a buffer
     ("gpt2", (3, 4, 512, 5, 64), False,
-     ((4,), [(None, None, 5, 64, 128), (None, 5, 64, 128), (None, None, 5, 64, 128)],
-      [(1,), (4,), (4,), (3, 4, 5, 64, 512), (4, 5, 64, 128)], [])),
+     ((1,), [(3, 4, 5, 64, 512), (1, 5, 64, 128), (3, 4, 5, 64, 512)],
+      [(1,), (4,), (4,), (3, 4, 5, 64, 512), (1, 5, 64, 128)], [(4,), (4, 5, 64, 128), (2, 4)])),
     ("mimo_keys", (3, 4, 2, 512, 192), True,
-     ((4,), [(None, None, 2, 192, 128), (None, 2, 192, 128), (None, None, 2, 192, 128)],
-      [(1,), (4,), (4,), (3, 4, 2, 192, 512), (4, 2, 192, 128)], [])),
+     ((1,), [(3, 4, 2, 192, 512), (1, 2, 192, 128), (3, 4, 2, 192, 512)],
+      [(1,), (4,), (4,), (3, 4, 2, 192, 512), (1, 2, 192, 128)], [(4,), (4, 2, 192, 128), (2, 4)])),
     ("int8_scales", (3, 4, 512, 5, 1), False,
-     ((4,), [(None, None, 5, 1, 128), (None, 5, 1, 128), (None, None, 5, 1, 128)],
-      [(1,), (4,), (4,), (3, 4, 5, 1, 512), (4, 5, 1, 128)], [])),
+     ((1,), [(3, 4, 5, 1, 512), (1, 5, 1, 128), (3, 4, 5, 1, 512)],
+      [(1,), (4,), (4,), (3, 4, 5, 1, 512), (1, 5, 1, 128)], [(4,), (4, 5, 1, 128), (2, 4)])),
     # whole lanes: the leaf as written and left where it is (the kernel copies the rows' blocks of
     # one sublane tile itself, all rows in one grid step), the token as it is
     ("ouro", (3, 4, 2, 320, 128), True,
@@ -430,9 +466,9 @@ def block_write_call(fn, *args):
 ], ids=lambda v: v if isinstance(v, str) else "")
 def test_the_kernels_call_follows_the_leafs_layout(monkeypatch, case, shape, heads_first, want):
     """The ``kv_block_write`` equation's grid, block shapes, operand shapes
-    and scratch, read off the jaxpr: a time-minor leaf's are the parent's,
-    letter for letter; a lane-aligned leaf's block (the kernel's buffer) is
-    ``block_slots`` high and its token one slot."""
+    and scratch, read off the jaxpr: a time-minor leaf's (PR 54) has no grid
+    step a row and no token blown up to a block; a lane-aligned leaf's block
+    (the kernel's buffer) is ``block_slots`` high and its token one slot."""
     monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 0)
     pool = jnp.zeros(shape, jnp.bfloat16)
     rows, heads = shape[1], shape[2 if heads_first else 3]
@@ -441,6 +477,31 @@ def test_the_kernels_call_follows_the_leafs_layout(monkeypatch, case, shape, hea
         lambda p: kv_cache.write(p, jnp.int32(1), toks, at, None, heads_first=heads_first), pool)
     assert tuple(call) == want
     assert aliases == ((3, 0),)                                    # the pool, in place
+
+
+@pytest.mark.parametrize("heads_first", [False, True], ids=["time_first", "heads_first"])
+def test_a_time_minor_leafs_live_rows_go_through_as_many_buffers_as_the_budget_holds(monkeypatch, heads_first):
+    """Eight rows of which six are live, their 128-slot blocks 20 KiB each:
+    a ring of eight buffers under the budget (never more than the rows), of
+    three at 60 KiB (the store one row back is waited for before its buffer
+    takes the fourth row's fetch), of two, and of one where not even one fits
+    (fetch, select, store, a row at a time); the pool is the same every way,
+    and the window write's."""
+    pool, spec = big_pool(heads_first, jnp.float32, 8)
+    cols = jnp.asarray([0, 127, 128, BIG_T, 511, -1, 300, 129], jnp.int32)
+    new = jnp.asarray(np.random.RandomState(1).normal(size=(8, 5, X)), jnp.float32)
+    toks, at = rows_tokens(new, cols, heads_first)
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 1 << 60)
+    window = np.asarray(kv_cache.write(pool, jnp.int32(2), toks, at, None, heads_first=heads_first))
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 0)
+    a_block = 128 * 5 * X * 4
+    for budget, ring in ((kv_cache._TILE_WRITE_VMEM_BYTES, 8), (3 * a_block, 3), (2 * a_block + 1, 2), (100, 1)):
+        monkeypatch.setattr(kv_cache, "_TILE_WRITE_VMEM_BYTES", budget)
+        write = lambda p: kv_cache.write(p, jnp.int32(2), toks, at, None, heads_first=heads_first)   # traced anew
+        grid, _, _, scratch, _ = block_write_call(write, pool)
+        assert grid == (1,) and scratch == [(8,), (ring, 5, X, 128), (2, ring)]
+        np.testing.assert_array_equal(np.asarray(write(pool)).view(np.uint8), window.view(np.uint8))
+    assert int((window != np.asarray(pool)).sum()) == 6 * 5 * X
 
 
 def test_a_lane_aligned_leafs_rows_go_as_many_a_grid_step_as_the_budget_holds(monkeypatch):
@@ -462,19 +523,23 @@ def test_a_lane_aligned_leafs_rows_go_as_many_a_grid_step_as_the_budget_holds(mo
     assert int((outs[0] != np.asarray(pool)).sum()) == 4 * 2 * 128        # four rows land
 
 
-@pytest.mark.parametrize("case", ["size128", "size64", "not_whole_blocks", "three_tokens_a_row",
+@pytest.mark.parametrize("case", ["size128_of_whole_lanes", "size64", "not_whole_blocks", "three_tokens_a_row",
                                   "one_rows_chunk", "pools_span_chips"])
 def test_writes_the_rule_leaves_alone_lower_to_the_window_paths_text(monkeypatch, case):
-    """A window no longer than a block, one that is not whole blocks,
-    several tokens a row, the chunk's one-row write, and a program whose
-    pools are split over several chips (the partitioner cannot split the
-    kernel): the text the parent's ``write`` lowers to, with the constant at
-    zero."""
-    pool, _ = big_pool(False, jnp.float32, 4)
-    size = {"size128": 128, "size64": 64, "not_whole_blocks": 200}.get(case, 256)
+    """A window shorter than a block, a 128-slot window of a leaf kept as
+    written (its block is a sublane tile: the window stays; a TIME-minor
+    leaf's 128-slot window, this test's ``size128`` until PR 54, goes to the
+    kernel now and is a case of
+    ``test_block_write_leaves_the_window_writes_bits``), one that is not whole
+    blocks, several tokens a row, the chunk's one-row write, and a program
+    whose pools are split over several chips (the partitioner cannot split
+    the kernel): the text the parent's ``write`` lowers to, with the constant
+    at zero."""
+    pool, _ = big_pool(False, jnp.float32, 4, width=128 if case == "size128_of_whole_lanes" else X)
+    size = {"size128_of_whole_lanes": 128, "size64": 64, "not_whole_blocks": 200}.get(case, 256)
     S = 3 if case in ("three_tokens_a_row", "one_rows_chunk") else 1
     rows = 1 if case == "one_rows_chunk" else 4
-    new = jnp.ones((rows, S, 5, X))
+    new = jnp.ones((rows, S, 5, pool.shape[4]))
     cols = jnp.arange(rows * S, dtype=jnp.int32).reshape(rows, S)
     slot = jnp.int32(2) if case == "one_rows_chunk" else None
 
@@ -501,17 +566,32 @@ def test_the_rule_reads_static_shapes_and_the_host_reads_the_same_rule(monkeypat
     assert not kv_cache.takes_block_write(128, 1 << 40) and not kv_cache.takes_block_write(1000, 1 << 40)
     assert kv_cache.takes_block_write(256, least) and not kv_cache.takes_block_write(256, least - 1)
     assert not kv_cache.takes_block_write(512, 512 * 4 * 16 * 4)     # this file's toy pool
+    # a TIME-minor leaf (PR 54: the kernel moves the live rows' blocks alone): its 128-slot window too,
+    # from half the bytes on, which is gpt2-medium's 128-slot row (256 KiB); MiMo's keys from 256 slots
+    # of the full pool (4 heads) and in the window layers' 128-slot ring (8 heads); never part of a block
+    minor = lambda size, slot_bytes: kv_cache.takes_block_write(size, size * slot_bytes, time_minor=True)
+    assert minor(128, medium) and minor(128, xl) and minor(1024, medium) and 128 * medium == least // 2
+    assert minor(256, mimo_k) and not minor(128, mimo_k) and minor(128, 2 * mimo_k)
+    assert not minor(64, 1 << 30) and not minor(200, 1 << 30) and not minor(128, least // 2 // 128 - 1)
+    assert not kv_cache.takes_block_write(512, 512 * 4 * 16 * 4, time_minor=True)
     # the host's side, from a cache's own leaves: any leaf by blocks; a ring never; several chips never
     monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 256 * 4 * 16 * 2)
     cfg = one_kind(max_seq_len=512)
     cache = kv_cache.init(cfg, 2, 512)
     assert kv_cache.rows_write_by_blocks(cfg, cache, None)
     assert kv_cache.rows_write_by_blocks(cfg, cache, 256)
-    assert not kv_cache.rows_write_by_blocks(cfg, cache, 128)
+    assert kv_cache.rows_write_by_blocks(cfg, cache, 128)         # time-minor: half the bytes will do
+    assert not kv_cache.rows_write_by_blocks(cfg, cache, 64)
+    # ... and its bytes are ONE live row's: a block of 128 slots, in and out, K and V, two layers
+    assert kv_cache.rows_block_write_bytes(cfg, cache, 128) == 2 * 2 * 2 * 128 * 4 * 16 * 2
+    assert kv_cache.rows_block_write_bytes(cfg, cache, None) == kv_cache.rows_block_write_bytes(cfg, cache, 128)
+    monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 256 * 4 * 16 * 2 + 2)
+    assert kv_cache.rows_write_by_blocks(cfg, cache, 256) and not kv_cache.rows_write_by_blocks(cfg, cache, 128)
     plan = plan_config(max_seq_len=512)
     monkeypatch.setattr(kv_cache, "BLOCK_WRITE_MIN_ROW_BYTES", 256 * 1 * 16 * 4)
     assert kv_cache.rows_write_by_blocks(plan, kv_cache.init(plan, 2, 512), 256)   # the full pool
-    assert not kv_cache.rows_write_by_blocks(plan, kv_cache.init(plan, 2, 512), 128)
+    assert kv_cache.rows_write_by_blocks(plan, kv_cache.init(plan, 2, 512), 128)   # its values, 16 wide
+    assert not kv_cache.rows_write_by_blocks(plan, kv_cache.init(plan, 2, 512), 64)
     two = comm.build_mesh({"data": 1, "tensor": 2}, devices=jax.devices()[:2])
     one = comm.build_mesh({"data": 1, "tensor": 1}, devices=jax.devices()[:1])
     assert kv_cache.spans_chips(two) and not kv_cache.spans_chips(one) and not kv_cache.spans_chips(None)

@@ -218,14 +218,17 @@ def test_layer_checkpoint_keeps_the_flash_residuals_dense(compiled):
     assert 0 < grown <= 0.9e9, grown
 
 
-@pytest.mark.parametrize("preset,slots,read_len,chunk,by_blocks", [
+@pytest.mark.parametrize("preset,slots,read_len,chunk,by_length", [
     ("gpt2-1.5b", 16, 256, None, True), ("gpt2-1.5b", 16, None, None, True),
     ("gpt2-1.5b", 16, 512, 128, True), ("gpt2-1.5b", 16, None, 128, True),
     ("gpt2-350m", 40, None, None, True), ("gpt2-1.5b", 16, 128, None, False),
     ("gpt2-350m", 40, None, 128, True), ("gpt2-350m", 40, 256, None, True),
+    ("gpt2-1.5b", 16, 128, 128, False), ("gpt2-350m", 40, 128, None, False),
+    ("gpt2-350m", 40, 128, 128, False),
 ], ids=["plain-read256", "plain-read1024", "fused128-read512", "fused128-read1024",
-        "chat-plain-read1024", "plain-read128", "chat-fused128-read1024", "chat-plain-read256"])
-def test_serving_tick_updates_the_kv_pool_in_place(topo, preset, slots, read_len, chunk, by_blocks):
+        "chat-plain-read1024", "plain-read128", "chat-fused128-read1024", "chat-plain-read256",
+        "fused128-read128", "chat-plain-read128", "chat-fused128-read128"])
+def test_serving_tick_updates_the_kv_pool_in_place(topo, preset, slots, read_len, chunk, by_length):
     """The gpt2-xl serving tick (16 slots x 1024, the benchmark's batch
     cell) and gpt2-medium's (40 x 1024, the chat cell) for the chip: the
     chip lays the pool bf16[48,16,1024,25,64] out TIME-minor (heads-minor
@@ -234,16 +237,18 @@ def test_serving_tick_updates_the_kv_pool_in_place(topo, preset, slots, read_len
     whole pool heads-minor and back — pool-sized copies and 12 GB of
     temporaries. The tick must compile to an in-place update: no ``copy`` of
     the pool's or a layer's shape, the pool aliased to the output,
-    temporaries far under the pool's 5 GB. ``by_blocks`` is what
-    ``kv_cache.takes_block_write`` decides at these shapes: above one
-    128-slot block the rows' tokens go in through ``kv_block_write`` (one
-    call for K, one for V, in the layer loop; the pool enters it as its
-    (L, B, H, x, T) transpose, which must be a bitcast here, not a copy) and
-    no op of ``attn.kv_write`` yields a value of the window's shape; a
-    128-slot read keeps the window's in-place rewrite. The same rule's
-    shapes send the rows' READ through ``decode_rows`` (PR 39,
-    ``kv_cache.takes_length_read``): once in the layer loop, K and V in one
-    call, the pools entering as their (L, B, H * x, T) view, which again
+    temporaries far under the pool's 5 GB. At every read bucket of these
+    shapes, the 128-slot one too since PR 54 (``kv_cache.takes_block_write``
+    with ``time_minor``), the rows' tokens go in through ``kv_block_write``
+    (one call for K, one for V, in the layer loop; the pool enters it as its
+    (L, B, H, x, T) transpose, which must be a bitcast here, not a copy; the
+    tokens as ONE (1, H, x, 128) tile with the rows on the lanes, and PR 32's
+    (rows, H, x, 128) broadcast, 10 MB a call at gpt2-medium, is nowhere) and
+    no op of ``attn.kv_write`` yields a value of the window's shape.
+    ``by_length`` is what ``kv_cache.takes_length_read`` decides, on the
+    rule it had: above one 128-slot block the rows' READ goes through
+    ``decode_rows`` (PR 39): once in the layer loop, K and V in one call, the
+    pools entering as their (L, B, H * x, T) view, which again
     must be a bitcast (the copy check above); the rows' float32 logits
     (B, heads, 1, T), which the compiler keeps as (B, heads, T), are then
     nowhere in the program, and at a 128-slot read they are. The q / k / v
@@ -296,11 +301,16 @@ def test_serving_tick_updates_the_kv_pool_in_place(topo, preset, slots, read_len
                         rf"{cfg.kv_heads},{cfg.head_dim}\]")
     rewritten = [m.group(1) for m in map(window.match, text.splitlines())
                  if m and "attn.kv_write" in scopes.get(m.group(1), "")]
-    assert bool(rewritten) != by_blocks, rewritten
-    assert len(re.findall(r" custom-call\(.*kv_block_write", text)) == (2 if by_blocks else 0)
-    assert len(re.findall(r" custom-call\(.*decode_rows", text)) == (1 if by_blocks else 0)
+    assert not rewritten, rewritten
+    assert len(re.findall(r" custom-call\(.*kv_block_write", text)) == 2
+    # the tokens the kernel is handed: one tile with the rows on the lanes, no block a row
+    tile = re.compile(rf"\s*(?:ROOT\s+)?%?([\w.\-]+) = bf16\[(\d+),{cfg.kv_heads},{cfg.head_dim},128\]")
+    tokens = {m.group(2) for m in map(tile.match, text.splitlines())
+              if m and "attn.kv_write" in scopes.get(m.group(1), "")}
+    assert tokens == {"1"}, tokens
+    assert len(re.findall(r" custom-call\(.*decode_rows", text)) == (1 if by_length else 0)
     logits = re.findall(rf"= f32\[{slots},{cfg.num_heads},(?:1,)?{read_len or length}\]", text)
-    assert bool(logits) != by_blocks, logits[:3]
+    assert bool(logits) != by_length, logits[:3]
 
 
 def test_plan_tick_writes_its_full_pool_by_blocks_in_place(topo, monkeypatch):

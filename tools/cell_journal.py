@@ -116,6 +116,11 @@ def ledger_report(noted, line):
            "starved_share": led["starved_ms"] / window_ms,
            "ticks_ready_at_retire": led["ticks_ready_at_retire"],
            "host_bound_tick_share": led["ticks_ready_at_retire"] / max(1, led["ticks"])}
+    since = lambda key: noted["stats1"].get(key, 0) - noted["stats0"].get(key, 0)
+    by_blocks, slots = since("block_write_ticks"), since("capacity_tokens") / max(1, led["ticks"])
+    # the rows' write by blocks: the live rows whose blocks the kernel moved, of the rows those ticks held
+    rep["block_write"] = {"ticks": by_blocks, "rows": since("block_write_rows"), "bytes": since("block_write_bytes"),
+                          "share_of_rows": since("block_write_rows") / max(1.0, by_blocks * slots)}
     if "host" in noted:
         from benchmark.reduce import reductions as R
 
@@ -147,6 +152,9 @@ def format_ledger(rep):
     lines.append(f"  starved {rep['starved_share']:.3%} of the window ({rep['starved_ms']:.1f} ms)   "
                  f"ticks ready at retire {rep['ticks_ready_at_retire']} = "
                  f"{rep['host_bound_tick_share']:.2%} of ticks")
+    bw = rep["block_write"]
+    lines.append(f"  rows' write by blocks: {bw['ticks']} ticks, {bw['rows']} live rows' blocks = "
+                 f"{bw['share_of_rows']:.2%} of those ticks' rows, {bw['bytes'] / 1e6:.1f} MB fetched and stored")
     tr = rep.get("trace")
     if tr:
         lines.append(f"== the trace's {tr['window_s']:.3f} s window: idle share {tr['harness_idle_share']:.2%} "
