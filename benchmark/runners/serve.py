@@ -26,6 +26,54 @@ from benchmark.harness import BenchmarkError, span
 clock = time.monotonic  # the serving engine's own clock: its admit times compare with ours
 
 
+# the rows of ``ServingEngine.tick_stats()`` whose sum is the server's wall time between two reads
+ROWS = ("empty_ms", "between_steps_ms", "schedule_ms", "dispatch_ms", "block_ms", "attribute_ms",
+        "emit_ms", "step_other_ms")
+
+
+def ledger_observations(stats0, stats1, window_s):
+    """Where the server's wall time went between the window's two reads of the program's
+    host ledger (its docs/telemetry.md, "The serving loop's ledger"), as observations a
+    ``"reduction": "value"`` metric file can read. A program whose ``tick_stats()`` lacks a
+    key gives no reading for what is made of it.
+
+    ``step_schedule_ms``      ms a ``step()`` in expiry, policy order and handovers
+    ``tick_admit_ms``         ms a ``step()`` in the batcher's admission loop (inside
+                              ``tick_dispatch_ms``)
+    ``tick_attribute_ms``     ms a ``step()`` in the host's work on fetched results
+    ``step_emit_ms``          ms a ``step()`` from the tick's return to its own: fan-out to
+                              requests, finishing, gauges
+    ``step_between_ms``       ms a ``step()`` outside it while a request is held: the caller's
+                              time (here this runner's own loop)
+    ``step_other_ms``         ms a ``step()`` of its wall that no row above names
+    ``empty_share_pct``       % of the window outside ``step()`` with no request held at all
+    ``starved_share_pct``     % of the window in which a request was held and no tick was in
+                              flight: what the host costs the chip
+    ``host_bound_tick_pct``   % of the ticks whose result was on hand before the host came to
+                              fetch it: the host, not the device, set that tick's pace
+    ``ledger_residual_pct``   the rows' sum against the window's wall on this runner's clock,
+                              % of the window: the identity's error
+    """
+    delta = lambda key: (stats1[key] - stats0[key] if key in stats0 and key in stats1 else None)
+    per = lambda total, count: total / count if total is not None and count else None
+    share = lambda ms: 100.0 * ms / (window_s * 1e3) if ms is not None else None
+    steps, ticks = delta("steps"), delta("ticks")
+    ready = delta("ticks_ready_at_retire")
+    rows = [delta(key) for key in ROWS]
+    return dict(
+        empty_share_pct=share(delta("empty_ms")),
+        starved_share_pct=share(delta("starved_ms")),
+        host_bound_tick_pct=100.0 * ready / ticks if ready is not None and ticks else None,
+        step_schedule_ms=per(delta("schedule_ms"), steps),
+        tick_admit_ms=per(delta("admit_ms"), steps),
+        tick_attribute_ms=per(delta("attribute_ms"), steps),
+        step_emit_ms=per(delta("emit_ms"), steps),
+        step_between_ms=per(delta("between_steps_ms"), steps),
+        step_other_ms=per(delta("step_other_ms"), steps),
+        ledger_residual_pct=(share(sum(rows) - window_s * 1e3)
+                             if all(ms is not None for ms in rows) else None))
+
+
 class Record:
     __slots__ = ("request", "due", "submitted", "rid", "times", "state", "admit", "tokens",
                  "prefill_start", "first_token")
@@ -247,6 +295,7 @@ class Runner:
             slot_use_pct=(100.0 * (stats1["tokens"] - stats0["tokens"]) / capacity
                           if capacity else None),
             mean_live_rows=float(np.mean(self.live_rows)) if self.live_rows else None,
+            max_live_rows=int(max(self.live_rows)) if self.live_rows else None,
             mean_live_kv_tokens=float(np.mean(self.live_kv)) if self.live_kv else None,
             drain_s=t_end - t_close)
         # the two kinds of tick and the prefill queue (the program's counters since PR 24); a
@@ -261,6 +310,7 @@ class Runner:
                                   if fused is not None and plain is not None and fused + plain
                                   else None),
             prefill_q_depth_mean=per(delta("prefill_q_depth_sum"), delta("steps")))
+        obs.update(ledger_observations(stats0, stats1, window_s))
         e2e = {"setup_s": setup_s, "serve_tokens_per_s": obs["serve_tokens_per_s"]}
         if not closed:
             worst = t_end  # a request that never answered waited at least until now
@@ -277,9 +327,15 @@ class Runner:
                                          if r.prefill_start is not None and r.admit is not None]),
                 prefill_p95_ms=p95([(r.first_token - r.prefill_start) * 1e3 for r in measured
                                     if r.first_token is not None and r.prefill_start is not None]))
+            ended = sum(rec.state == "finished" and t_open <= rec.times[-1] < t_close
+                        for rec in self.records if rec.times)
             e2e.update(ttft_p95_ms=float(np.percentile(ttft, 95)),
+                       ttft_p50_ms=float(np.median(ttft)),
                        gap_p95_ms=float(np.percentile(gaps, 95)))
-            obs.update(ttft_p50_ms=float(np.median(ttft)), gap_p50_ms=float(np.median(gaps)),
+            obs.update(requests_ended_in_window=ended,
+                       requests_shed=sum(str(rec.state).startswith("shed") for rec in measured),
+                       ttft_p50_ms=e2e["ttft_p50_ms"], ttft_p95_ms=e2e["ttft_p95_ms"],
+                       gap_p50_ms=float(np.median(gaps)),
                        gaps_measured=int(gaps.size),
                        generator_late_p95_ms=float(np.percentile(late, 95)),
                        generator_late_max_ms=float(late.max()),

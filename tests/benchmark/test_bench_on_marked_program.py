@@ -65,7 +65,7 @@ def test_a_line_holds_no_metric_the_manifest_does_not_list(lines, cell):
 
 def test_untraced_lines_hold_end_to_end_metrics_only(lines):
     line = lines("toy-chat", 0)
-    assert set(line["metrics"]) == {"ttft_p95_ms", "gap_p95_ms", "setup_s"}
+    assert set(line["metrics"]) == {"ttft_p50_ms", "gap_p95_ms", "setup_s"}
 
 
 def test_idle_gaps_are_charged_to_the_benchmarks_own_spans_only(lines):

@@ -83,6 +83,19 @@ def test_zero3_cell_reports_its_collectives(results):
     assert 0 <= metrics["collective_exposed.train"]["value"] <= 100
 
 
+# the open loop's own readings (PR 56): how long this process once did not run, and, from the program's
+# host ledger, which the plain serve runner reads for every serving cell, the share of ticks the host paced
+OPEN_LOOP_READINGS = {"generator_late_max_ms.chat": (0.0, 1e4), "host_bound_tick_pct.chat": (0.0, 100.0)}
+
+
+@pytest.mark.parametrize("name", sorted(OPEN_LOOP_READINGS))
+def test_an_open_loop_line_holds_the_freezes_counter_and_the_host_paced_share(results, name):
+    low, high = OPEN_LOOP_READINGS[name]
+    assert low <= results("toy-chat", 1)["metrics"][name]["value"] <= high
+    # the chat cell's metrics list the chat cell alone: a closed loop's line holds none of them
+    assert name not in results("toy-batch", 1)["metrics"]
+
+
 def _compare_line(capsys):
     return next(json.loads(l) for l in capsys.readouterr().out.splitlines()
                 if l.startswith('{"phase": "compare"'))

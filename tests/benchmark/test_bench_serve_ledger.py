@@ -1,10 +1,11 @@
-"""The runner that reads the program's host ledger (``runners/serve_ledger.py``,
-PR 53) on the toy chat cell, through the harness's Python entry point on the CPU
-(the cell's ``runner`` set by an override: no committed cell names this runner
-yet, so no toy cell file may): which observations it adds, that the line's
-metrics are the parent runner's, and that the ledger's rows sum to the window,
-never a device number. The same runner on a ``tick_stats()`` without the
-ledger's keys (the parent's program) adds nothing it can not read."""
+"""The program's host ledger as the serving runner reads it (PR 53's
+``runners/serve_ledger.py``; since PR 56 ``serve.ledger_observations``, read by the
+plain ``serve`` runner for every serving cell, and ``serve_ledger`` is that runner
+under its old name) on the toy chat cell, through the harness's Python entry point
+on the CPU: which observations it adds, that the line's metrics are the cell's, and
+that the ledger's rows sum to the window, never a device number. The same runner on
+a ``tick_stats()`` without the ledger's keys (an older program) adds nothing it can
+not read."""
 
 import os
 
@@ -104,12 +105,9 @@ def test_a_program_without_the_ledgers_keys_gives_the_parents_line(lines):
     assert all(obs[name] is None for name in PER_STEP + SHARES + ["ledger_residual_pct"])
 
 
-def test_the_runner_reads_its_own_groups_where_a_cell_and_a_configuration_name_them():
-    groups = lambda **extra: dict({"serve": {"slots": 4}}, **extra)
-    ctx = dict(cell=groups(), config=dict(compare=groups()))
-    assert serve_ledger.Runner(ctx).s == {"slots": 4}
-    ctx = dict(cell=groups(serve_ledger={"slots": 16}),
-               config=dict(compare=groups(serve_ledger={"margin": 0.25})))
-    runner = serve_ledger.Runner(ctx)
-    assert runner.s == {"slots": 16}
-    assert runner.ctx["config"]["compare"]["serve"] == {"margin": 0.25}
+def test_the_old_name_is_the_plain_runner():
+    from benchmark.runners import serve
+
+    assert serve_ledger.Runner is serve.Runner
+    assert serve_ledger.ledger_observations is serve.ledger_observations
+    assert serve_ledger.ROWS == serve.ROWS and len(serve.ROWS) == 8
