@@ -81,7 +81,7 @@ import functools
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -172,6 +172,15 @@ class _Request:
     win_accepted: int = 0
 
 
+class _TickCosts(NamedTuple):
+    """What one dispatch of a tick program counts that follows from its
+    (pool, chunk width, read bucket) alone (``_Pool.tick_costs``)."""
+
+    row_read_bytes: int       # KV bytes one row streams a step (``kv_bytes_read``)
+    block_write_bytes: int    # ``kv_cache.rows_block_write_bytes``: 0 = the window write
+    reads_to_length: bool     # ``kv_cache.rows_read_to_length``
+
+
 class _TickRecord:
     """Host bookkeeping for one DISPATCHED (possibly in-flight) pool tick:
     the packed result future plus everything needed to attribute it when
@@ -219,9 +228,8 @@ class _Pool:
         # row-state sharding) so the first tick never pays a reshard.
         from jax.sharding import NamedSharding, PartitionSpec
 
-        row_sh = NamedSharding(engine.mesh, PartitionSpec())
-        self.last_tok_dev = jax.device_put(jnp.zeros(n_slots, jnp.int32), row_sh)
-        self.done_dev = jax.device_put(jnp.ones(n_slots, jnp.int32), row_sh)
+        self.row_sh = NamedSharding(engine.mesh, PartitionSpec())
+        self.last_tok_dev, self.done_dev = self.fresh_rows()
         self.set_row_fn = compile_row_update_fn(engine.mesh, engine.cfg,
                                                 n_slots,
                                                 donate=engine.donate_cache)
@@ -234,10 +242,7 @@ class _Pool:
         # geometry plus its own segment program for draft prefill
         self.draft_cache = None
         if engine.spec_gamma:
-            self.pos_dev = jax.device_put(
-                jnp.full(n_slots, length, jnp.int32), row_sh)
-            self.gen_dev = jax.device_put(jnp.zeros(n_slots, jnp.int32),
-                                          row_sh)
+            self.pos_dev, self.gen_dev = self.fresh_spec_rows()
             self.spec_set_row_fn = compile_spec_row_update_fn(
                 engine.mesh, engine.cfg, n_slots,
                 donate=engine.donate_cache)
@@ -315,9 +320,26 @@ class _Pool:
         # bounded by the (chunk bucket × read bucket) family size, never
         # evicted (an LRU consulted per tick could recompile mid-serve)
         self.tick_fns: Dict[tuple, object] = {}
+        # beside each program, under its key: what a dispatch of it counts
+        # off (pool, chunk, read_len) alone, worked out once as it is built
+        self.tick_costs: Dict[tuple, _TickCosts] = {}
 
     def free_slots(self) -> List[int]:
         return [s for s in range(self.n_slots) if s not in self.active]
+
+    def fresh_rows(self):
+        """``(last_tok, done)`` as a pool starts with them, every slot free,
+        placed as the tick programs take and return them (the pool's own,
+        and a warm-up's throwaway pair: the programs donate them)."""
+        n = self.n_slots
+        return (jax.device_put(jnp.zeros(n, jnp.int32), self.row_sh),
+                jax.device_put(jnp.ones(n, jnp.int32), self.row_sh))
+
+    def fresh_spec_rows(self):
+        """``(pos, gen)`` of a speculative pool, every row parked, likewise."""
+        n = self.n_slots
+        return (jax.device_put(jnp.full(n, self.length, jnp.int32), self.row_sh),
+                jax.device_put(jnp.zeros(n, jnp.int32), self.row_sh))
 
 
 class ContinuousBatchingEngine:
@@ -888,7 +910,7 @@ class ContinuousBatchingEngine:
         positions[0, :n] = np.arange(n, dtype=np.int32)
         small = kv_cache.init(self.cfg, 1, bucket)
         logits, small = prefill_fn(
-            self._eng.params, jnp.asarray(toks), jnp.asarray(positions), small
+            self._eng.params, toks, positions, small
         )
         pid = self._next_pid  # counter, not len(): eviction must never recycle a live id
         self._next_pid += 1
@@ -1309,6 +1331,10 @@ class ContinuousBatchingEngine:
                 self.temperature, self.top_k, self.top_p,
                 eos_token_id=self.eos_token_id, read_len=read_len,
                 chunk=chunk, donate=self.donate_cache)[0]
+            pool.tick_costs[key] = _TickCosts(
+                self._row_read_bytes(pool, read_len),
+                kv_cache.rows_block_write_bytes(self.cfg, pool.cache, read_len, self.mesh),
+                kv_cache.rows_read_to_length(self.cfg, pool.cache, read_len, self.mesh))
             # build journal: the program's first dispatch leaves an entry
             # keyed by the full shapes key — a rebuilt engine re-compiling
             # the family is flagged recompile (the runtime view of
@@ -1337,7 +1363,12 @@ class ContinuousBatchingEngine:
     def _dispatch_tick(self, pool: _Pool) -> Optional[_TickRecord]:
         """Dispatch one tick for ``pool`` WITHOUT waiting for anything:
         inputs come from the host dispatch mirrors plus the device-threaded
-        state futures. Returns None when the pool has nothing to run."""
+        state futures. Returns None when the pool has nothing to run.
+
+        The host inputs go to the program as the NumPy arrays they are: the
+        jitted call places them itself, and nothing here makes a
+        ``device_put``. They are allocated anew every tick and NOT written
+        once handed over: a transfer may still be reading them."""
         n, k = pool.n_slots, self.tokens_per_tick
         pos = np.full(n, pool.length, np.int32)   # parked rows: writes drop
         gen = np.zeros(n, np.int32)
@@ -1371,6 +1402,7 @@ class ContinuousBatchingEngine:
             extent = max(extent, cpos0 + nreal)
             read_len = self._read_len(pool, extent)
             fn = self._tick_fn(pool, read_len, chunk=W)
+            costs = pool.tick_costs[W, read_len]
             st = self._tick_stats
             st["prefill_chunk_tokens"] += nreal
             st["prefill_pad_tokens"] += W - nreal
@@ -1402,30 +1434,26 @@ class ContinuousBatchingEngine:
                 live[aslot] = admit
             packed, pool.cache, pool.last_tok_dev, pool.done_dev = fn(
                 params, pool.cache, pool.last_tok_dev, pool.done_dev,
-                jnp.asarray(pos), jnp.asarray(gen), jnp.asarray(quota),
-                jnp.asarray(rids), self._base_key, jnp.asarray(chunk_toks),
-                jnp.asarray(chunk_pos), aslot, jnp.asarray(emit_col),
-                jnp.asarray(emit_mask))
+                pos, gen, quota, rids, self._base_key, chunk_toks,
+                chunk_pos, aslot, emit_col, emit_mask)
             admit.chunks.pop(0)
             if not admit.chunks:
                 pool.prefill_q.popleft()
                 admit.chunks = None
                 pool.disp_pos[aslot] = cpos0 + nreal  # full prompt cached
                 pool.disp_gen[aslot] = admit.gen_base + 1  # the emitted first token
-            rec = _TickRecord(packed, live, 1,
-                              self._row_read_bytes(pool, read_len), True)
+            rec = _TickRecord(packed, live, 1, costs.row_read_bytes, True)
             advance = 1
         else:
             read_len = self._read_len(pool, extent)
             fn = self._tick_fn(pool, read_len)
+            costs = pool.tick_costs[None, read_len]
             packed, pool.cache, pool.last_tok_dev, pool.done_dev = fn(
                 params, pool.cache, pool.last_tok_dev, pool.done_dev,
-                jnp.asarray(pos), jnp.asarray(gen), jnp.asarray(quota),
-                jnp.asarray(rids), self._base_key)
-            rec = _TickRecord(packed, live, k,
-                              self._row_read_bytes(pool, read_len), False)
+                pos, gen, quota, rids, self._base_key)
+            rec = _TickRecord(packed, live, k, costs.row_read_bytes, False)
             advance = k
-        moved = kv_cache.rows_block_write_bytes(self.cfg, pool.cache, read_len, self.mesh)
+        moved = costs.block_write_bytes
         wrote = int((pos < pool.length).sum()) * advance if moved else 0   # live rows, a token step each
         self._tick_stats["block_write_ticks"] += moved > 0
         self._tick_stats["block_write_rows"] += wrote
@@ -1439,7 +1467,7 @@ class ContinuousBatchingEngine:
             # each of the tick's steps, a live row attends what it holds and the token it writes
             st["loop_kv_positions_live"] += int(np.minimum(
                 pos[pos < pool.length, None] + 1 + np.arange(advance), pool.length).sum())
-        if kv_cache.rows_read_to_length(self.cfg, pool.cache, read_len, self.mesh):
+        if costs.reads_to_length:
             # each of the tick's steps, a live row attends what it holds and
             # the token it writes (a row that finishes inside a burst stays
             # where it is: counted as if it went on)
@@ -1521,8 +1549,7 @@ class ContinuousBatchingEngine:
             seg_pos = np.full(n, pool.length, np.int32)
             seg_pos[admit.slot] = cpos0
             _, pool.cache = pool.segment_fn(
-                self._eng.params, jnp.asarray(seg_toks), pool.cache,
-                jnp.asarray(seg_pos))
+                self._eng.params, seg_toks, pool.cache, seg_pos)
             fused = True
             if not admit.chunks:
                 pool.prefill_q.popleft()
@@ -1552,8 +1579,8 @@ class ContinuousBatchingEngine:
              pool.done_dev, pool.pos_dev, pool.gen_dev) = fn(
                 self._eng.params, self._draft_eng.params, pool.cache,
                 pool.draft_cache, pool.last_tok_dev, pool.done_dev,
-                pool.pos_dev, pool.gen_dev, jnp.asarray(quota),
-                jnp.asarray(rids), jnp.asarray(run_mask), self._base_key)
+                pool.pos_dev, pool.gen_dev, quota, rids, run_mask,
+                self._base_key)
         else:
             drafts = np.zeros((n, g), np.int32)
             order = self._eng.config.speculative.ngram_max_order
@@ -1569,8 +1596,7 @@ class ContinuousBatchingEngine:
              pool.pos_dev, pool.gen_dev) = fn(
                 self._eng.params, pool.cache, pool.last_tok_dev,
                 pool.done_dev, pool.pos_dev, pool.gen_dev,
-                jnp.asarray(quota), jnp.asarray(rids),
-                jnp.asarray(run_mask), jnp.asarray(drafts), self._base_key)
+                quota, rids, run_mask, drafts, self._base_key)
         # dispatch mirrors: pos becomes an UPPER bound (the device advances
         # by accepted+1 <= gamma+1, used only for read-geometry selection)
         # and gen a LOWER bound (every active round emits >= 1); _retire
@@ -1863,8 +1889,7 @@ class ContinuousBatchingEngine:
             seg_pos = np.full(pool.n_slots, pool.length, np.int32)
             seg_pos[slot] = start
             _, pool.cache = pool.segment_fn(
-                self._eng.params, jnp.asarray(seg_toks), pool.cache,
-                jnp.asarray(seg_pos))
+                self._eng.params, seg_toks, pool.cache, seg_pos)
         else:
             b = _bucket(m - 1, pool.length)
             prefill_fn = self._prefill_for_bucket(b)
@@ -1875,9 +1900,7 @@ class ContinuousBatchingEngine:
             positions = np.full((1, b), b, np.int32)
             positions[0, :m - 1] = np.arange(m - 1, dtype=np.int32)
             small = kv_cache.init(self.cfg, 1, b)
-            _, small = prefill_fn(
-                self._eng.params, jnp.asarray(ptoks),
-                jnp.asarray(positions), small)
+            _, small = prefill_fn(self._eng.params, ptoks, positions, small)
             pool.cache = insert_fn(pool.cache, small, slot)
 
     def _admit_spec(self, req: _Request, pool: _Pool, pi: int, slot: int,
@@ -1902,8 +1925,7 @@ class ContinuousBatchingEngine:
                 dpos = np.full(pool.n_slots, pool.length, np.int32)
                 dpos[slot] = 0
                 _, pool.draft_cache = pool.draft_segment_fn(
-                    self._draft_eng.params, jnp.asarray(dtoks),
-                    pool.draft_cache, jnp.asarray(dpos))
+                    self._draft_eng.params, dtoks, pool.draft_cache, dpos)
         if self.fused_prefill and m > 1:
             req.chunks = self._chunk_schedule(pool, toks[:-1], start)
             pool.prefill_q.append(req)
@@ -1950,18 +1972,16 @@ class ContinuousBatchingEngine:
                         kv_cache.init(self.cfg, pool.n_slots, pool.length),
                         pool.cache_sh)
 
-                    def zeros():
-                        # donated operands must not alias the plain ones —
-                        # fresh buffers per argument
-                        return jnp.zeros(pool.n_slots, jnp.int32)
-
-                    parked = jnp.full(pool.n_slots, pool.length, jnp.int32)
-                    args = (self._eng.params, cache, zeros(),
-                            jnp.ones(pool.n_slots, jnp.int32), parked,
-                            zeros(), zeros(), zeros(), self._base_key)
+                    # the KINDS of argument a served tick passes (placed
+                    # row state, NumPy host inputs): the call's fast-path
+                    # entry made here is the one the first served tick hits
+                    zeros = functools.partial(np.zeros, pool.n_slots, np.int32)
+                    parked = np.full(pool.n_slots, pool.length, np.int32)
+                    args = (self._eng.params, cache, *pool.fresh_rows(),
+                            parked, zeros(), zeros(), zeros(), self._base_key)
                     if ch is not None:
-                        args += (jnp.zeros(ch, jnp.int32),
-                                 jnp.full(ch, pool.length, jnp.int32), 0,
+                        args += (np.zeros(ch, np.int32),
+                                 np.full(ch, pool.length, np.int32), 0,
                                  zeros(), zeros())
                     jax.block_until_ready(fn(*args)[0])
                     count += 1
@@ -1981,23 +2001,19 @@ class ContinuousBatchingEngine:
             cache = jax.device_put(
                 kv_cache.init(self.cfg, n, pool.length), pool.cache_sh)
 
-            def zeros():
-                # donated operands must not alias — fresh buffers each
-                return jnp.zeros(n, jnp.int32)
-
-            parked = jnp.full(n, pool.length, jnp.int32)
+            # the kinds of argument a served round passes, as above
+            zeros = functools.partial(np.zeros, n, np.int32)
+            rows = (*pool.fresh_rows(), *pool.fresh_spec_rows())
             if self.spec_mode == "draft":
                 dcache = jax.device_put(
                     kv_cache.init(self.draft_cfg, n, pool.length),
                     pool.draft_cache_sh)
                 args = (self._eng.params, self._draft_eng.params, cache,
-                        dcache, zeros(), jnp.ones(n, jnp.int32), parked,
-                        zeros(), zeros(), zeros(), zeros(), self._base_key)
-            else:
-                args = (self._eng.params, cache, zeros(),
-                        jnp.ones(n, jnp.int32), parked, zeros(), zeros(),
-                        zeros(), zeros(), jnp.zeros((n, g), jnp.int32),
+                        dcache, *rows, zeros(), zeros(), zeros(),
                         self._base_key)
+            else:
+                args = (self._eng.params, cache, *rows, zeros(), zeros(),
+                        zeros(), np.zeros((n, g), np.int32), self._base_key)
             jax.block_until_ready(fn(*args)[0])
             count += 1
             if progress is not None:
@@ -2013,8 +2029,8 @@ class ContinuousBatchingEngine:
                 cache = jax.device_put(
                     kv_cache.init(self.cfg, n, pool.length), pool.cache_sh)
                 _, c2 = pool.segment_fn(
-                    self._eng.params, jnp.zeros((n, W), jnp.int32), cache,
-                    jnp.full(n, pool.length, jnp.int32))
+                    self._eng.params, np.zeros((n, W), np.int32), cache,
+                    np.full(n, pool.length, np.int32))
                 jax.block_until_ready(c2)
                 count += 1
                 if progress is not None:

@@ -6,13 +6,17 @@ burst width) may change WHEN a token surfaces, never WHAT it is — token
 streams are bitwise identical across every mode, greedy AND sampled."""
 
 import json
+import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu import comm
 from deepspeed_tpu.inference.continuous import ContinuousBatchingEngine
+from deepspeed_tpu.telemetry import compile_log
 from serving_toys import SMALL, built, prompts as _prompts, serve as _serve
 
 FLOOR = 32  # a tight-read floor under the 64-slot pools: ONE crossing (32 -> the whole pool) a stream
@@ -294,3 +298,99 @@ class TestTickTelemetry:
         assert seen == 3
         # ... so the chip has nothing to run from each fetch to the next dispatch
         assert all(b > a for a, b in zip(starved, starved[1:]))
+
+
+class _Watched:
+    """One served window of plain and fused ticks after
+    ``precompile_tick_programs`` and a warm-up request, with everything the
+    cases below read noted on the way. ``as_arrays=True`` serves the same
+    window in the form the dispatch had before: ``jnp.asarray`` of each host
+    array, then the jitted call."""
+
+    def __init__(self, setup, monkeypatch, as_arrays=False):
+        cb = _cb(setup, temperature=0.9, top_k=20, top_p=0.9, seed=11, prefill_chunk=16)
+        cb.precompile_tick_programs()
+        _serve(cb, [(0, _prompts((3,), 9)[0], 2)])   # the first admission builds ``row_update``
+        pool = cb._pools[0]
+        self.programs = dict(pool.tick_fns)
+        self.handed = []       # (the array a tick was given, its contents then)
+        self.written = []      # those that read otherwise when their step ended
+        self.puts = 0          # device_puts made under _dispatch_tick
+        inside = [0]
+
+        def watching(fn):
+            def call(*args):
+                host = [a for a in args if isinstance(a, np.ndarray)]
+                self.handed += [(a, a.copy()) for a in host]
+                if as_arrays:
+                    args = [jnp.asarray(a) if isinstance(a, np.ndarray) else a for a in args]
+                return fn(*args)
+            return call
+
+        def counting(put):
+            def call(*args, **kw):
+                self.puts += inside[0]
+                return put(*args, **kw)
+            return call
+
+        dispatch, step = cb._dispatch_tick, cb.step
+
+        def dispatching(p):
+            inside[0] += 1
+            try:
+                return dispatch(p)
+            finally:
+                inside[0] -= 1
+
+        def stepping():
+            out = step()
+            self.written += [a for a, was in self.handed if not np.array_equal(a, was)]
+            return out
+
+        for key, fn in self.programs.items():
+            pool.tick_fns[key] = watching(fn)
+        cb._dispatch_tick, cb.step = dispatching, stepping
+        # ``jnp.asarray`` reaches ``device_put`` through the module it lives in
+        monkeypatch.setattr(jax._src.api, "device_put", counting(jax._src.api.device_put))
+        monkeypatch.setattr(jax, "device_put", counting(jax.device_put))
+        self.sizes0 = {key: fn._cache_size() for key, fn in self.programs.items()}
+        stats0, t_open = cb.tick_stats(), time.monotonic()   # the journal's clock
+        subs = list(zip((0, 0, 0, 1, 3, 4), _prompts((5, 9, 3, 40, 7, 4), 1), (12, 20, 8, 10, 6, 9)))
+        self.streams = _serve(cb, subs)
+        monkeypatch.undo()
+        stats = cb.tick_stats()
+        self.plain, self.fused, self.built = (
+            stats[k] - stats0[k] for k in ("plain_ticks", "fused_prefill_ticks", "programs_built"))
+        self.sizes = {key: fn._cache_size() for key, fn in self.programs.items()}
+        self.journalled = [e for e in compile_log.journal() if e["t"] >= t_open]
+
+
+@pytest.fixture(scope="module")
+def watched(setup):
+    with pytest.MonkeyPatch.context() as mp:
+        yield _Watched(setup, mp), _Watched(setup, mp, as_arrays=True)
+
+
+@pytest.mark.parametrize("holds", ["no_device_put", "no_new_entry", "arrays_left_alone", "same_streams"])
+def test_a_ticks_host_inputs_cross_inside_the_call(watched, holds):
+    """A steady tick's dispatch hands its host inputs to the program as the
+    NumPy arrays they are: (a) no ``device_put`` is made under
+    ``_dispatch_tick`` (the form with ``jnp.asarray`` makes four a plain tick
+    and eight a fused one: the counter sees them), (b) warm-up made the
+    call's one entry (no program's ``_cache_size()`` grows, nothing is built),
+    (c) nothing writes an array after the call, (d) the streams are those of
+    the ``jnp.asarray`` form."""
+    now, before = watched
+    assert now.plain > 0 and now.fused > 0 and (now.plain, now.fused) == (before.plain, before.fused)
+    if holds == "no_device_put":
+        assert now.puts == 0
+        assert before.puts == 4 * before.plain + 8 * before.fused
+    elif holds == "no_new_entry":
+        assert now.sizes == now.sizes0 and set(now.sizes.values()) == {1}
+        assert now.built == 0 and not now.journalled
+    elif holds == "arrays_left_alone":
+        assert len(now.handed) == 4 * now.plain + 8 * now.fused and not now.written
+        assert all(a.dtype == np.int32 for a, _ in now.handed)
+    else:
+        for a, b in zip(now.streams, before.streams):
+            np.testing.assert_array_equal(a, b)
